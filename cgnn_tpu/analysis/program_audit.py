@@ -236,11 +236,10 @@ def build_entry_programs(config: AuditConfig | None = None,
     """-> (programs, meta): the repo's real entry programs, lowered.
 
     Known backend gaps become ``skip`` records (listed in the ledger
-    meta, never silently absent): the dense-layout train step needs a
-    jax whose ``linear_call`` differentiates (this container's 0.4.37
-    does not; CI's does), and the DP/edge-sharded steps need
-    ``jax.shard_map`` plus >= 2 devices. Everything else must lower —
-    an unexpected failure is a GA-LOWER finding, not a skip."""
+    meta, never silently absent): the DP/edge-sharded steps need >= 2
+    devices, and the Pallas kernels lower only on TPU. Everything else
+    must lower — an unexpected failure is a GA-LOWER finding, not a
+    skip."""
     import tempfile
 
     import jax
@@ -348,16 +347,6 @@ def build_entry_programs(config: AuditConfig | None = None,
     if len(jax.devices()) < 2:
         shard_gap = (f"needs >= 2 devices, have {len(jax.devices())} "
                      f"(CI sets --xla_force_host_platform_device_count)")
-    elif not hasattr(jax, "shard_map"):
-        # the parallel/compat.py shim RUNS these bodies on legacy
-        # experimental shard_map, but legacy lowering drops the
-        # donation aliasing from the module text (jax.buffer_donor
-        # without tf.aliasing_output) — auditing it here would flag a
-        # version artifact, not a repo bug; CI's jax audits the real
-        # thing
-        shard_gap = ("legacy experimental shard_map (pre-jax.shard_map) "
-                     "does not propagate donation aliasing into the "
-                     "lowered module; CI audits these")
     if shard_gap is None:
         from cgnn_tpu.parallel.data_parallel import (
             make_parallel_train_step,
@@ -450,7 +439,7 @@ def build_entry_programs(config: AuditConfig | None = None,
     else:
         add_skip("conv/fused_pallas_fwd",
                  "Pallas TPU kernels lower only on a tpu backend "
-                 "(config.py backend rule); CI's TPU leg audits it")
+                 "(config.py backend rule)")
 
     # -- predict: every (rung, staging form) in the warm ladder — the
     # forms dimension now includes 'raw' (ISSUE 11: the in-program
@@ -562,8 +551,9 @@ def build_entry_programs(config: AuditConfig | None = None,
 
 
 def lower_programs(programs: list[Program]) -> list[AuditFinding]:
-    """Fill ``lowered``/``text`` per program; known backend gaps become
-    skips, anything else a GA-LOWER finding."""
+    """Fill ``lowered``/``text`` per program; a program that fails to
+    lower is a GA-LOWER finding (known backend gaps were registered as
+    skips by ``build_entry_programs`` and are not attempted)."""
     findings = []
     for p in programs:
         if p.skip is not None:
@@ -571,11 +561,6 @@ def lower_programs(programs: list[Program]) -> list[AuditFinding]:
         try:
             p.lowered = p.jitted.lower(*p.args)
             p.text = p.lowered.as_text()
-        except NotImplementedError as e:
-            # the in-container jax 0.4.37 dense-layout linear_call gap
-            # (CHANGES.md PR 1: the cause of the 43 seed failures) —
-            # recorded, surfaced in the ledger meta, lowered in CI
-            p.skip = f"backend cannot lower: {e}"
         except Exception as e:  # noqa: BLE001 - findings, not crashes
             findings.append(AuditFinding(
                 "GA-LOWER", p.name,

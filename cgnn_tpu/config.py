@@ -79,6 +79,42 @@ class ModelConfig:
             return self
         return dataclasses.replace(self, cgconv_window=0)
 
+    def kernel_impls(self) -> tuple[str | None, str | None]:
+        """(fused_epilogue, cgconv) implementations that will RUN on this
+        backend. The Pallas kernels lower only on TPU; 'xla' is their
+        numerically identical twin, so a TPU-trained checkpoint stays
+        loadable for CPU prediction/fine-tuning. On TPU 'pallas' means
+        the compiled kernel — never an interpreted one."""
+        import jax
+
+        def resolve(requested: str) -> str | None:
+            if requested == "pallas" and jax.default_backend() != "tpu":
+                return "xla"
+            return requested or None
+
+        return resolve(self.fused_epilogue), resolve(self.cgconv_impl)
+
+    def impl_summary(self) -> str:
+        """One line naming what :meth:`build` selects on this backend
+        (entry points log it at model build; chip_smoke.py reads it)."""
+        import jax
+
+        fused, cgconv = self.kernel_impls()
+
+        def show(requested: str, chosen: str | None) -> str:
+            if chosen and chosen != requested:
+                return f"{chosen} (XLA twin; {requested} requested)"
+            return chosen or "off"
+
+        return (
+            f"model impl: backend={jax.default_backend()} "
+            f"dtype={self.dtype} "
+            f"layout={'dense' if self.dense_m else 'coo'} "
+            f"aggregation={self.aggregation or 'xla'} "
+            f"cgconv={show(self.cgconv_impl, cgconv)} "
+            f"fused_epilogue={show(self.fused_epilogue, fused)}"
+        )
+
     def build(self, head=None, edge_axis_name: str | None = None):
         """``edge_axis_name`` activates edge-sharded graph parallelism
         (psum over that mesh axis inside every conv). It is a runtime
@@ -96,17 +132,7 @@ class ModelConfig:
                 n_h=self.n_h,
                 dtype=jnp.bfloat16 if self.dtype == "bfloat16" else jnp.float32,
             )
-        import jax
-
-        fused = self.fused_epilogue or None
-        if fused == "pallas" and jax.default_backend() != "tpu":
-            # the Pallas kernels lower only on TPU; 'xla' is numerically
-            # identical, so a TPU-trained checkpoint stays loadable for
-            # CPU prediction/fine-tuning
-            fused = "xla"
-        cgconv = self.cgconv_impl or None
-        if cgconv == "pallas" and jax.default_backend() != "tpu":
-            cgconv = "xla"  # same backend rule as fused_epilogue
+        fused, cgconv = self.kernel_impls()
         return CrystalGraphConvNet(
             atom_fea_len=self.atom_fea_len,
             n_conv=self.n_conv,
@@ -129,10 +155,13 @@ class ModelConfig:
 
 def build_model(model_cfg: "ModelConfig", data_cfg: "DataConfig",
                 task: str = "regression",
-                edge_axis_name: str | None = None):
+                edge_axis_name: str | None = None, log_fn=None):
     """Build the model for a task; the force task needs the edge featurization
     hyperparameters in-model (distances are recomputed differentiably from
-    positions — models/forcefield.py)."""
+    positions — models/forcefield.py). ``log_fn`` receives the one line
+    naming the kernel implementations selected for this backend."""
+    if log_fn is not None:
+        log_fn(model_cfg.impl_summary())
     if task == "force":
         if edge_axis_name is not None:
             raise NotImplementedError(

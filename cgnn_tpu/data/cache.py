@@ -12,6 +12,7 @@ plus offset tables — O(1) metadata, zero-copy row slicing on load.
 
 from __future__ import annotations
 
+import multiprocessing
 import os
 from concurrent.futures import ProcessPoolExecutor
 from typing import Sequence
@@ -61,6 +62,11 @@ def save_graph_cache(graphs: Sequence[CrystalGraph], path: str) -> None:
         payload["positions"] = np.concatenate([g.positions for g in graphs])
         payload["lattices"] = np.stack([g.lattice for g in graphs])
         payload["offsets"] = np.concatenate([g.offsets for g in graphs])
+        if all(g.numbers is not None for g in graphs):
+            # species ride with the geometry: together they are the raw
+            # wire form (data/rawbatch.py raw_from_graph)
+            payload["numbers"] = np.concatenate(
+                [np.asarray(g.numbers, np.int32) for g in graphs])
     if all(g.forces is not None for g in graphs):
         payload["forces"] = np.concatenate([g.forces for g in graphs])
     tmp = path + ".tmp"
@@ -88,7 +94,19 @@ def load_graph_cache(path: str) -> list[CrystalGraph]:
     target_mask = np.asarray(z["target_mask"])
     cif_ids = np.asarray(z["cif_ids"])
     has_geom = bool(int(z["has_geometry"]))
-    distances = z["distances"] if "distances" in z else None
+
+    # every member is read ONCE, here: NpzFile re-reads the whole array on
+    # each z[...] access and a per-graph slice keeps it alive, so a lookup
+    # inside the loop below costs graphs x array bytes of host memory
+    def member(key):
+        return z[key] if key in z else None
+
+    distances = member("distances")
+    numbers = member("numbers")
+    forces = member("forces")
+    positions = member("positions") if has_geom else None
+    lattices = member("lattices") if has_geom else None
+    offsets = member("offsets") if has_geom else None
     from cgnn_tpu.data import invariants
 
     graphs = []
@@ -104,10 +122,11 @@ def load_graph_cache(path: str) -> list[CrystalGraph]:
                 cif_id=str(cif_ids[i]),
                 target_mask=target_mask[i],
                 distances=None if distances is None else distances[ne],
-                positions=z["positions"][ns] if has_geom else None,
-                lattice=np.asarray(z["lattices"][i]) if has_geom else None,
-                offsets=z["offsets"][ne] if has_geom else None,
-                forces=z["forces"][ns] if "forces" in z else None,
+                positions=None if positions is None else positions[ns],
+                lattice=None if lattices is None else lattices[i],
+                offsets=None if offsets is None else offsets[ne],
+                forces=None if forces is None else forces[ns],
+                numbers=None if numbers is None else numbers[ns],
             )
         )
     # sample-validate under --check-invariants: a truncated or bit-rotted
@@ -187,6 +206,12 @@ def featurize_directory_parallel(
     if workers <= 1:
         consume(map(_featurize_one, jobs))
     else:
-        with ProcessPoolExecutor(max_workers=workers) as pool:
+        # spawn, not fork: the caller may already hold an accelerator
+        # (train.py initialises its backend first) and is multithreaded
+        # by then — forked children would inherit both
+        with ProcessPoolExecutor(
+            max_workers=workers,
+            mp_context=multiprocessing.get_context("spawn"),
+        ) as pool:
             consume(pool.map(_featurize_one, jobs, chunksize=32))
     return graphs, failures

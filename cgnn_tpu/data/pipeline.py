@@ -5,7 +5,7 @@ device; that is enough for training, where a multi-ms fused train step
 amortizes a single packer. The forward path has no such luck: a predict
 step is sub-ms, so at inference the device drains batches faster than
 one thread can pack them and the chip sits idle on the host's critical
-path (BENCH_r05: 112,305 structs/s device rate vs 1,461 end-to-end —
+path (round 5, PERF.md: 112,305 structs/s device rate vs 1,461 end-to-end —
 98.7% host). ``parallel_pack`` generalizes the producer pattern to a
 POOL of packer threads with order-restoring reassembly:
 

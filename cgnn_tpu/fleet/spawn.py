@@ -29,6 +29,38 @@ _SERVE_PY = os.path.join(
         os.path.abspath(__file__)))), "serve.py")
 
 
+_PROBE = ("import jax; d = jax.local_devices(); "
+          "print(d[0].platform, len(d))")
+
+
+def require_chips(n_replicas: int) -> None:
+    """Refuse a fleet that needs more accelerator chips than the host has.
+
+    A chip belongs to one process at a time and every replica is its own
+    serve.py process, so ``n_replicas`` processes need ``n_replicas``
+    chips (an in-process replica mode is ROADMAP B3). The platform and
+    chip count come from a short-lived child: the fleet parent must
+    never initialise a JAX backend itself, or it would hold the chip its
+    replicas need. ``JAX_PLATFORMS=cpu`` (tests, the smoke scripts) has
+    no chips to contend for and skips the probe. Raises RuntimeError."""
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
+        return
+    probe = subprocess.run([sys.executable, "-c", _PROBE],
+                           capture_output=True, text=True, timeout=300)
+    if probe.returncode != 0:
+        raise RuntimeError(
+            f"fleet: device probe failed:\n{probe.stderr[-2000:]}")
+    platform, count = probe.stdout.split()[-2:]
+    if platform != "cpu" and n_replicas > int(count):
+        raise RuntimeError(
+            f"fleet: {n_replicas} replica processes requested but this "
+            f"host has {count} {platform} chip(s); a chip belongs to one "
+            f"process at a time (in-process replicas: ROADMAP B3). Lower "
+            f"--replicas/--max-replicas, or set JAX_PLATFORMS=cpu for a "
+            f"CPU fleet"
+        )
+
+
 class ReplicaProcess:
     """One serve.py subprocess bound to a fixed port (stable across
     restarts, so the router's endpoint list never changes)."""
@@ -65,7 +97,6 @@ class ReplicaProcess:
                "--host", self.host, "--port", str(self.port),
                *self.serve_args]
         env = dict(os.environ if self.env is None else self.env)
-        env.setdefault("JAX_PLATFORMS", "cpu")
         log = (open(self.log_path, "ab")
                if self.log_path else subprocess.DEVNULL)
         try:
@@ -218,7 +249,9 @@ def spawn_fleet(
 ) -> list:
     """Boot ``n`` replicas on consecutive ports and wait until every
     one reports ready. Raises RuntimeError (after terminating the
-    stragglers) when any replica fails to come up."""
+    stragglers) when any replica fails to come up, or when the host has
+    fewer accelerator chips than replicas (:func:`require_chips`)."""
+    require_chips(n)
     procs = []
     for i in range(n):
         log_path = (os.path.join(log_dir, f"replica-{i}.log")

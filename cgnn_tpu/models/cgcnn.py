@@ -186,12 +186,8 @@ class CGConv(nn.Module):
             # linear_call (gather_transpose) does not insert the implicit
             # replicated->varying cast standard ops get, so cast explicitly:
             # the cast's transpose is the psum that completes each shard's
-            # partial [N, F] node cotangent (compat: identity on jax
-            # without pcast, where check_rep is off and the psum comes
-            # from the P() in-spec transpose — parallel/compat.py)
-            from cgnn_tpu.parallel.compat import pcast
-
-            nodes_v = pcast(nodes, axis, to="varying")
+            # partial [N, F] node cotangent
+            nodes_v = jax.lax.pcast(nodes, axis, to="varying")
             if in_slots is not None:
                 # per-shard two-tier mappings arrive with a leading
                 # singleton from the shard-stack axis (graph.py

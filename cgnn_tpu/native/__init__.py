@@ -2,14 +2,16 @@
 
 The TPU compute path is XLA/Pallas; these kernels cover the *host-side*
 runtime hot loops the reference delegates to C-backed libraries
-(SURVEY.md §2 native table). Each binding degrades gracefully: if no
-compiler is available the numpy implementation is used instead.
+(SURVEY.md §2 native table). Without a compiler on PATH the numpy
+implementation is used instead; with one, a failed build is an error
+(a silently slower featurizer is not a fallback anyone asked for).
 """
 
 from __future__ import annotations
 
 import ctypes
 import os
+import shutil
 import subprocess
 import threading
 
@@ -24,21 +26,28 @@ _build_failed = False
 
 
 def _build() -> str | None:
-    """Compile the shared library if missing/stale; None on failure."""
-    try:
-        if os.path.exists(_LIB_PATH) and os.path.getmtime(
-            _LIB_PATH
-        ) >= os.path.getmtime(_SRC):
-            return _LIB_PATH
-        cmd = [
-            "g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-            _SRC, "-o", _LIB_PATH + ".tmp",
-        ]
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(_LIB_PATH + ".tmp", _LIB_PATH)
+    """Compile the shared library if missing/stale; None when there is
+    no compiler. Raises RuntimeError when the compiler fails."""
+    if os.path.exists(_LIB_PATH) and os.path.getmtime(
+        _LIB_PATH
+    ) >= os.path.getmtime(_SRC):
         return _LIB_PATH
-    except Exception:  # noqa: BLE001 — any failure means "no native backend"
+    if shutil.which("g++") is None:
         return None
+    # per-process temp name: concurrent first uses (pool workers) each
+    # build their own copy and the last rename wins
+    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp]
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, text=True,
+                       timeout=120)
+    except (subprocess.CalledProcessError, subprocess.TimeoutExpired) as e:
+        raise RuntimeError(
+            f"native neighbor search failed to build ({' '.join(cmd)}):\n"
+            f"{getattr(e, 'stderr', '') or e}"
+        ) from e
+    os.replace(tmp, _LIB_PATH)
+    return _LIB_PATH
 
 
 def get_native_lib() -> ctypes.CDLL | None:

@@ -43,7 +43,6 @@ from cgnn_tpu.observe.hist import (
     snapshots_from_family,
 )
 from cgnn_tpu.observe.gauges import (
-    device_hbm_table_bytes,
     hbm_gauges,
     padding_gauges,
 )
@@ -114,7 +113,6 @@ __all__ = [
     "parse_parent",
     "parse_prometheus_text",
     "setup_json_logging",
-    "device_hbm_table_bytes",
     "enable_debug_nans",
     "hbm_gauges",
     "jsonfinite",

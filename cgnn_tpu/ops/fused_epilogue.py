@@ -50,7 +50,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 # apply/dz kernels block the node axis at this many rows; node capacities
-# are 8-aligned, not 128-aligned, so kernels row-mask the tail block
+# are 8-aligned, not 128-aligned, so kernels row-mask the tail block.
+# On the v5e (jax 0.9.0) this compiles for bf16 only: 256 f32 rows of
+# [M=12, 2F=128] ask the dz kernel for 17.15 MB of the 16 MB scoped VMEM,
+# and at 128 rows the bf16 kernel returns NaN (PERF.md, bring-up)
 _BLOCK_N = 256
 
 

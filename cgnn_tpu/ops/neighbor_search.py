@@ -145,7 +145,7 @@ def _search_kernel(frac_ref, lat_ref, amask_ref, offs_ref, nbr_ref,
     c = s_cap * k
     frac = frac_ref[0]
     lat = lat_ref[0]
-    amask = amask_ref[0]
+    amask = amask_ref[0, 0]
     d = _candidate_distances(frac, lat, offs_ref[...])
     valid = _candidate_valid(amask, spec) & (d <= jnp.float32(spec.radius))
     key = jnp.where(valid, d, jnp.float32(jnp.inf))
@@ -166,7 +166,7 @@ def _search_kernel(frac_ref, lat_ref, amask_ref, offs_ref, nbr_ref,
     nbr_ref[0] = jnp.where(em > 0, nbr, rows)
     dist_ref[0] = jnp.stack(dist_cols, axis=1)
     em_ref[0] = em
-    ne_ref[0, 0] = em.sum().astype(jnp.int32)
+    ne_ref[0] = em.sum(keepdims=True).astype(jnp.int32)
 
 
 def _search_pallas(frac, lats, amask, spec: RawSpec, offsets_f32,
@@ -180,23 +180,26 @@ def _search_pallas(frac, lats, amask, spec: RawSpec, offsets_f32,
         in_specs=[
             pl.BlockSpec((1, s_cap, 3), lambda g: (g, 0, 0)),
             pl.BlockSpec((1, 3, 3), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, s_cap), lambda g: (g, 0)),
+            # [G, 1, S] / [G, 1, 1]: a block's last two dims must equal
+            # the array's (or be (8, 128)-aligned), so the per-structure
+            # axis cannot be one of them
+            pl.BlockSpec((1, 1, s_cap), lambda g: (g, 0, 0)),
             pl.BlockSpec((spec.n_images, 3), lambda g: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, s_cap, m), lambda g: (g, 0, 0)),
             pl.BlockSpec((1, s_cap, m), lambda g: (g, 0, 0)),
             pl.BlockSpec((1, s_cap, m), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, 1), lambda g: (g, 0)),
+            pl.BlockSpec((1, 1, 1), lambda g: (g, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((g_cap, s_cap, m), jnp.int32),
             jax.ShapeDtypeStruct((g_cap, s_cap, m), jnp.float32),
             jax.ShapeDtypeStruct((g_cap, s_cap, m), jnp.float32),
-            jax.ShapeDtypeStruct((g_cap, 1), jnp.int32),
+            jax.ShapeDtypeStruct((g_cap, 1, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(frac, lats, amask.astype(jnp.float32), offsets_f32)
+    )(frac, lats, amask.astype(jnp.float32)[:, None, :], offsets_f32)
     # the overflow flag reads only the lattice: a tiny vectorized jnp
     # computation, shared verbatim with the XLA variant instead of
     # burning an image-cap constant into the kernel
@@ -208,7 +211,7 @@ def _search_pallas(frac, lats, amask, spec: RawSpec, offsets_f32,
     overflow = (jnp.any(need > jnp.asarray(spec.images, jnp.float32),
                         axis=1)
                 & jnp.any(amask > 0, axis=1))
-    return nbr, dist, em, ne[:, 0], overflow
+    return nbr, dist, em, ne[:, 0, 0], overflow
 
 
 def neighbor_search(frac, lats, amask, spec: RawSpec,

@@ -64,37 +64,6 @@ from cgnn_tpu.ops.segment import gather, gather_transpose
 
 _TN = 128  # node rows per block AND per window tile (lane width)
 
-# interpret-mode escape hatch: newer jax has
-# pltpu.force_tpu_interpret_mode(); this container's 0.4.37 does not
-# (the reason the older pallas tests are among the pre-existing seed
-# failures), but pallas_call(interpret=True) works everywhere — so this
-# module threads an explicit flag and exposes a context manager that
-# uses whichever mechanism the running jax supports.
-_INTERPRET = False
-
-
-class interpret_mode:
-    """Run this module's kernels interpreted (CPU-testable) — the
-    version-portable twin of ``pltpu.force_tpu_interpret_mode()``."""
-
-    def __enter__(self):
-        global _INTERPRET
-        self._ctx = None
-        force = getattr(pltpu, "force_tpu_interpret_mode", None)
-        if force is not None:
-            self._ctx = force()
-            self._ctx.__enter__()
-        self._prev = _INTERPRET
-        _INTERPRET = True
-        return self
-
-    def __exit__(self, *exc):
-        global _INTERPRET
-        _INTERPRET = self._prev
-        if self._ctx is not None:
-            return self._ctx.__exit__(*exc)
-        return False
-
 
 def window_width(max_graph_nodes: int) -> int:
     """Static window bound for a dataset (see ops/pallas_gather.py)."""
@@ -145,7 +114,9 @@ def _gate_sum(y, mask):
     # float under an x64 session lowers an f64 constant (GA-F64).
     f = y.shape[-1] // 2
     msg = jax.nn.sigmoid(y[..., :f]) * jax.nn.softplus(y[..., f:])
-    keep = (mask > 0)[..., None]
+    # expand the f32 mask, THEN compare: Mosaic has no layout for a
+    # reshape of an i1 vector ([TN, M] -> [TN, M, 1])
+    keep = mask[..., None] > 0
     return jnp.where(keep, msg, jnp.float32(0.0)).sum(axis=1)
 
 
@@ -245,11 +216,11 @@ def _z_block(b, nodes_ref, edges_ref, cst_ref, vj, n, f, g):
     ``n`` are zeroed at the source (out-of-range reads are garbage) —
     their z values are then finite and the edge-mask selects drop them.
     """
-    keep = _row_keep(b, _TN, n) > 0  # [TN, 1]
+    keep = _row_keep(b, _TN, n)  # [TN, 1] f32 (i1 vectors do not reshape)
     k = cst_ref[: 2 * f + g, :]
-    nodes_blk = jnp.where(keep, nodes_ref[...].astype(jnp.float32),
+    nodes_blk = jnp.where(keep > 0, nodes_ref[...].astype(jnp.float32),
                           jnp.float32(0.0))
-    edges_blk = jnp.where(keep[..., None],
+    edges_blk = jnp.where(keep[..., None] > 0,
                           edges_ref[...].astype(jnp.float32),
                           jnp.float32(0.0))
     vi_term = jnp.dot(nodes_blk, k[:f, :],
@@ -359,7 +330,6 @@ def _pallas_passes(nodes, edges, kernel, bias, neighbors, edge_mask,
         functools.partial(kernel_fn, n=n, f=f, g=g),
         grid_spec=grid_spec,
         out_shape=out_shape[0],
-        interpret=_INTERPRET,
     )(
         ws,
         neighbors.astype(jnp.int32).reshape(n, m),

@@ -66,9 +66,12 @@ def _kernel(ws_ref, nbr_ref, ntile_ref, out_ref, *, tn, m):
         ntile_ref[:],
         (((2,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
-        # HIGHEST: default MXU precision rounds f32 operands to bf16,
-        # which would silently break the bit-exactness claim for f32
-        precision=jax.lax.Precision.HIGHEST,
+        # HIGHEST for f32: default MXU precision rounds f32 operands to
+        # bf16, which would silently break the bit-exactness claim.
+        # bf16 operands are exact at default precision, and Mosaic
+        # refuses a bf16 x bf16 matmul at fp32 contract precision
+        precision=(jax.lax.Precision.HIGHEST
+                   if ntile_ref.dtype == jnp.float32 else None),
     ).astype(out_ref.dtype)
 
     @pl.when(w == 0)

@@ -31,40 +31,12 @@ _VALID_IMPLS = ("xla", "pallas", "sort")
 # math but rejects second-order AD.
 _TRANSPOSE_IMPL = "linear_call"
 
-# jax 0.4.37 (this container) ships linear_call WITHOUT a
-# differentiation rule ("Differentiation rule for 'linear_call' not
-# implemented") — the second half of the 43 pre-existing seed failures
-# (the first was shard_map resolution, parallel/compat.py). Probed once,
-# lazily; when the rule is missing, gather_transpose binds an equivalent
-# custom primitive with the SAME transpose body registered directly
-# (impl/abstract/jvp/transpose/lowering) — which, like linear_call,
-# composes with repeated differentiation (grad-over-grad pins this in
-# tests). CI's newer jax never takes this path.
-_LINEAR_CALL_GRAD: bool | None = None
-
-
-def _linear_call_differentiable() -> bool:
-    global _LINEAR_CALL_GRAD
-    if _LINEAR_CALL_GRAD is None:
-        import numpy as np
-
-        idx = jnp.asarray(np.zeros(1, np.int32))
-        try:
-            jax.grad(lambda n: jax.custom_derivatives.linear_call(
-                lambda r, x: jnp.take(x, r[0], axis=0),
-                lambda r, ct: jax.ops.segment_sum(ct, r[0], num_segments=1),
-                (idx,), n).sum())(jnp.zeros((1, 1), jnp.float32))
-            _LINEAR_CALL_GRAD = True
-        except NotImplementedError:
-            _LINEAR_CALL_GRAD = False
-    return _LINEAR_CALL_GRAD
-
 
 def _transpose_cotangent(ct, slots, msk, o_slots, o_nodes, o_mask,
                          num_nodes: int):
     """The shared cotangent transpose ([E, F] -> [N, F]) — ONE body for
-    every AD mechanism (linear_call / custom_vjp / the compat primitive)
-    so an A/B isolates the mechanism, never the math.
+    every AD mechanism (linear_call / custom_vjp) so an A/B isolates the
+    mechanism, never the math.
 
     in_slots arrives pre-flattened (pack_graphs): a device-side
     [N, In] -> [N*In] flatten is a tiled->linear relayout that measured
@@ -83,61 +55,6 @@ def _transpose_cotangent(ct, slots, msk, o_slots, o_nodes, o_mask,
             rows, o_nodes, num_segments=num_nodes, indices_are_sorted=True,
         )
     return grad
-
-
-_GATHER_TR_P = None
-
-
-def _gather_transpose_primitive():
-    """Build (once) the compat primitive for jax without the linear_call
-    differentiation rule. Operands: (nodes, neighbors, in_slots, in_mask
-    [, over_slots, over_nodes, over_mask]) with static ``has_over``;
-    only ``nodes`` is linear."""
-    global _GATHER_TR_P
-    if _GATHER_TR_P is not None:
-        return _GATHER_TR_P
-    from jax import core
-    from jax.interpreters import ad, mlir
-
-    p = core.Primitive("cgnn_gather_transpose")
-
-    def _impl(nodes, neighbors, *rest, has_over):
-        return jnp.take(nodes, neighbors, axis=0)
-
-    p.def_impl(_impl)
-
-    def _abstract(nodes, neighbors, *rest, has_over):
-        return core.ShapedArray(
-            (neighbors.shape[0],) + tuple(nodes.shape[1:]), nodes.dtype
-        )
-
-    p.def_abstract_eval(_abstract)
-    mlir.register_lowering(p, mlir.lower_fun(_impl, multiple_results=False))
-
-    def _jvp(primals, tangents, *, has_over):
-        out = p.bind(*primals, has_over=has_over)
-        dn = tangents[0]
-        if type(dn) is ad.Zero:
-            return out, ad.Zero.from_value(out)
-        return out, p.bind(dn, *primals[1:], has_over=has_over)
-
-    ad.primitive_jvps[p] = _jvp
-
-    def _transpose(ct, nodes, neighbors, in_slots, in_mask, *over,
-                   has_over):
-        assert ad.is_undefined_primal(nodes), (
-            "gather_transpose is linear in nodes only"
-        )
-        o_slots, o_nodes, o_mask = over if has_over else (None, None, None)
-        grad = _transpose_cotangent(
-            ct, in_slots, in_mask, o_slots, o_nodes, o_mask,
-            nodes.aval.shape[0],
-        )
-        return (grad,) + (None,) * (3 + len(over))
-
-    ad.primitive_transposes[p] = _transpose
-    _GATHER_TR_P = p
-    return p
 
 
 def set_transpose_impl(impl: str) -> None:
@@ -220,15 +137,6 @@ def gather_transpose(
 
         g.defvjp(g_fwd, g_bwd)
         return g(nodes)
-
-    if not _linear_call_differentiable():
-        # jax without the linear_call diff rule (in-container 0.4.37):
-        # same math, bound through the compat primitive above
-        p = _gather_transpose_primitive()
-        if over_slots is not None:
-            return p.bind(nodes, neighbors, in_slots, in_mask,
-                          over_slots, over_nodes, over_mask, has_over=True)
-        return p.bind(nodes, neighbors, in_slots, in_mask, has_over=False)
 
     def fwd(res, n):
         nbrs = res[0]

@@ -12,7 +12,6 @@ pod slice adds a DCN axis to the same mesh; the step body is unchanged.
 composable with data parallelism as a 2-D ``('data', 'graph')`` mesh.
 """
 
-from cgnn_tpu.parallel.compat import shard_map, pcast, HAS_NATIVE_SHARD_MAP
 from cgnn_tpu.parallel.mesh import make_mesh, device_count
 from cgnn_tpu.parallel.data_parallel import (
     stack_batches,
@@ -34,9 +33,6 @@ from cgnn_tpu.parallel.edge_parallel import (
 )
 
 __all__ = [
-    "shard_map",
-    "pcast",
-    "HAS_NATIVE_SHARD_MAP",
     "make_mesh",
     "device_count",
     "stack_batches",

@@ -20,7 +20,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from cgnn_tpu.parallel import compat
 from cgnn_tpu.data.graph import (
     CrystalGraph,
     GraphBatch,
@@ -32,7 +31,7 @@ from cgnn_tpu.data.graph import (
 from cgnn_tpu.resilience import faultinject
 from cgnn_tpu.train.state import TrainState
 from cgnn_tpu.train.step import (
-    TRAIN_STEP_DONATE,
+    jit_sharded_train_step,
     make_eval_step,
     make_train_step,
 )
@@ -262,14 +261,14 @@ def make_parallel_train_step(
     def body(state: TrainState, stacked: GraphBatch):
         return inner(state, _squeeze0(stacked))
 
-    smapped = compat.shard_map(
+    smapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P(axes)),
         out_specs=(P(), P()),
         check_vma=False,  # grads/stats are pmean-ed -> replicated outputs
     )
-    jitted = jax.jit(smapped, donate_argnums=TRAIN_STEP_DONATE)
+    jitted = jit_sharded_train_step(smapped, mesh)
 
     def guarded(state: TrainState, stacked: GraphBatch):
         # --check-invariants last line of defense for direct callers that
@@ -312,7 +311,7 @@ def make_parallel_eval_step(
     def body(state: TrainState, stacked: GraphBatch):
         return inner(state, _squeeze0(stacked))
 
-    smapped = compat.shard_map(
+    smapped = jax.shard_map(
         body, mesh=mesh, in_specs=(P(), P(axes)), out_specs=P(),
         check_vma=False,
     )
@@ -663,8 +662,8 @@ def fit_data_parallel(
         # step carrying the global sums. The scan driver is excluded on
         # purpose: it stages its own in-scan tap (wrapping here too would
         # double-record every step).
-        train_step = jax.jit(telemetry.wrap_train_body(train_step),
-                             donate_argnums=TRAIN_STEP_DONATE)
+        train_step = jit_sharded_train_step(
+            telemetry.wrap_train_body(train_step), mesh)
         eval_step = jax.jit(telemetry.wrap_eval_body(eval_step))
     if monitor is not None and monitor.post_restore is None:
         # a rollback restores onto the default device; re-place it

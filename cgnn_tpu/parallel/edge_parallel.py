@@ -33,11 +33,10 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from cgnn_tpu.parallel import compat
 from cgnn_tpu.data.graph import GraphBatch
 from cgnn_tpu.train.state import TrainState
 from cgnn_tpu.train.step import (
-    TRAIN_STEP_DONATE,
+    jit_sharded_train_step,
     make_eval_step,
     make_train_step,
 )
@@ -280,13 +279,13 @@ def make_edge_parallel_train_step(
         make_train_step(classification, grad_health=grad_health), guard
     )
 
-    smapped = compat.shard_map(
+    smapped = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(P(), _specs(graph_axis, dense=dense)),
         out_specs=(P(), P()),
     )
-    return jax.jit(smapped, donate_argnums=TRAIN_STEP_DONATE)
+    return jit_sharded_train_step(smapped, mesh)
 
 
 def make_edge_parallel_eval_step(
@@ -296,7 +295,7 @@ def make_edge_parallel_eval_step(
     dense: bool = False,
 ) -> Callable:
     inner = make_eval_step(classification)
-    smapped = compat.shard_map(
+    smapped = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(P(), _specs(graph_axis, dense=dense, with_transpose=False)),
@@ -347,13 +346,13 @@ def make_dp_edge_parallel_train_step(
     def body(state: TrainState, stacked: GraphBatch):
         return inner(state, _squeeze0(stacked))
 
-    smapped = compat.shard_map(
+    smapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), _specs(graph_axis, data_axis, dense=dense)),
         out_specs=(P(), P()),
     )
-    return jax.jit(smapped, donate_argnums=TRAIN_STEP_DONATE)
+    return jit_sharded_train_step(smapped, mesh)
 
 
 def make_dp_edge_parallel_eval_step(
@@ -373,7 +372,7 @@ def make_dp_edge_parallel_eval_step(
     def body(state: TrainState, stacked: GraphBatch):
         return inner(state, _squeeze0(stacked))
 
-    smapped = compat.shard_map(
+    smapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), _specs(graph_axis, data_axis, dense=dense,

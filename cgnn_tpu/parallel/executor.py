@@ -54,7 +54,6 @@ from typing import Callable, Sequence
 
 import numpy as np
 
-from cgnn_tpu.parallel import compat
 
 
 class MeshExecutor:
@@ -145,7 +144,7 @@ class MeshExecutor:
             return jax.tree_util.tree_map(
                 lambda x: x[None], predict_body(state, sub))
 
-        return jax.jit(compat.shard_map(
+        return jax.jit(jax.shard_map(
             stacked_body, mesh=self.mesh,
             in_specs=(P(), P(self.axis)), out_specs=P(self.axis),
             check_vma=False,  # no collectives in the forward body

@@ -10,9 +10,11 @@ loss) and selects old-vs-new state with ``jnp.where`` — a pure in-graph
 select, so it works inside the donated-carry whole-epoch scans and
 under ``shard_map`` (the inputs to the check are replicated post-pmean
 values, so every shard takes the same branch). When no fault fires the
-select is the identity and the training trajectory is BIT-identical to
-the unguarded body (pinned by tests/test_resilience.py, like the
-telemetry tap). A skipped step leaves ``state.step`` unchanged and
+select is the identity: nothing is skipped and the update is the inner
+body's. The guarded step is a separate XLA program from the unguarded
+one, so the two may round that update differently (1 ulp per step on
+jax 0.9.0's CPU backend); tests/test_resilience.py pins the losses to
+1e-3 across the two and the skip itself bit-exact. A skipped step leaves ``state.step`` unchanged and
 zeroes its metric contributions (count included), and reports
 ``guard_skipped_sum``/``_count`` through the normal metric plumbing —
 visible per-step at ``--telemetry step`` and in every epoch aggregate.

@@ -183,18 +183,28 @@ class DeviceSet:
     # ---- accounting ----
 
     def stats(self) -> list[dict]:
-        """One record per device (the /stats + run-summary payload)."""
+        """One record per device (the /stats + run-summary payload):
+        the dispatch accounting, and the device memory the backend
+        reports (``hbm_*``; absent on the CPU backend)."""
+        from cgnn_tpu.observe.gauges import hbm_gauges
+
         wall = max(time.perf_counter() - self._t0, 1e-9)
+        hbm = hbm_gauges(self.devices)
         with self._lock:
             return [
                 {
                     "device_id": i,
                     "device": str(d),
+                    "platform": d.platform,
+                    "kind": d.device_kind,
                     "dispatches": self._dispatches[i],
                     "busy_s": round(self._busy_s[i], 4),
                     "occupancy": min(1.0, self._busy_s[i] / wall),
                     "inflight": self._inflight[i],
                     "max_window_depth": self._max_depth[i],
+                    **{f"hbm_{k}": hbm[i][k]
+                       for k in ("bytes_in_use", "peak_bytes_in_use",
+                                 "bytes_limit") if k in hbm[i]},
                 }
                 for i, d in enumerate(self.devices)
             ]

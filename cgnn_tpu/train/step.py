@@ -167,9 +167,25 @@ def jit_train_step(body: Callable):
     telemetry-wrapped (observe) — anything with the train-step carry
     signature. Used by train/loop.py, scripts/hlo_dump.py, and the
     program auditor, so a single-device train step reaches XLA exactly
-    one way; the shard_map wrappers in parallel/ jit themselves but
-    share the TRAIN_STEP_DONATE contract."""
+    one way; the shard_map wrappers in parallel/ go through
+    ``jit_sharded_train_step`` and share the TRAIN_STEP_DONATE contract."""
     return jax.jit(body, donate_argnums=TRAIN_STEP_DONATE)
+
+
+def jit_sharded_train_step(body: Callable, mesh):
+    """The jit wrapper for shard_map-wrapped (state, batch) -> (state,
+    metrics) steps whose results are replicated over ``mesh``.
+
+    The result shardings are STATED, not left to propagation: in a
+    program with more than one partition jax writes the donation into
+    the module (``tf.aliasing_output``, what GA-DONATION audits) only
+    for results whose sharding it knows; for the rest it emits
+    ``jax.buffer_donor`` and defers the aliasing decision to XLA."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    replicated = NamedSharding(mesh, PartitionSpec())
+    return jax.jit(body, donate_argnums=TRAIN_STEP_DONATE,
+                   out_shardings=(replicated, replicated))
 
 
 def make_predict_step(expander: Callable | None = None,

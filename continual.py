@@ -19,9 +19,10 @@ Run it BESIDE the serving fleet, against the same checkpoint dir:
 from __future__ import annotations
 
 import argparse
-import os
 import sys
 import threading
+
+from cgnn_tpu.runtime import start as start_runtime
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -57,8 +58,10 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
-    if args.device == "cpu":
-        os.environ["JAX_PLATFORMS"] = "cpu"
+    refusal = start_runtime(args.device, None)
+    if refusal:
+        print(refusal, file=sys.stderr)
+        return 2
 
     from cgnn_tpu.continual import ContinualTrainer
     from cgnn_tpu.resilience.preempt import PreemptionHandler

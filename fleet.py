@@ -181,12 +181,11 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     from cgnn_tpu.fleet.http import make_fleet_http_server
     from cgnn_tpu.fleet.replica import ReplicaState
     from cgnn_tpu.fleet.router import FleetRouter
-    from cgnn_tpu.fleet.spawn import spawn_fleet
+    from cgnn_tpu.fleet.spawn import require_chips, spawn_fleet
     from cgnn_tpu.observe import json_log_fn
     from cgnn_tpu.resilience.preempt import PreemptionHandler
 
@@ -211,6 +210,9 @@ def main(argv=None) -> int:
         # are CANDIDATES until the canary controller promotes them
         serve_args.append("--reload-gated")
     try:
+        if args.autoscale:
+            # the ceiling the autoscaler may grow to, spares included
+            require_chips(args.max_replicas + args.warm_pool)
         procs = spawn_fleet(
             args.ckpt_dir, args.replicas,
             base_port=args.replica_base_port, host=args.host,

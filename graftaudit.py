@@ -83,7 +83,6 @@ def main(argv=None) -> int:
             print(f"{check}\n    {CHECKS[check]}\n")
         return 0
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     # deterministic device inventory for CPU audits: the mesh-sharded
     # predict programs (ISSUE 10) need >= 2 devices to lower, and the
     # committed ledger carries their GA-SHARD-budgeted rows — a

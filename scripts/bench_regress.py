@@ -2,7 +2,7 @@
 """Bench-round regression diff: the latest ``BENCH_r*.json`` vs the
 previous one, failing loudly on >20% regression of any named key.
 
-The BENCH trajectory (BENCH_r01..r05) is the repo's performance memory,
+A BENCH_r*.json trajectory is a repo's performance memory,
 but nothing READ it — a silent 20% throughput slide would ship (PERF.md
 §8 only caught the r3->r4 drift because a human went looking). This
 script is the automated reader:

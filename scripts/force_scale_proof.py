@@ -9,7 +9,7 @@ layout with the linear_call two-tier transpose under the second-order
 force objective, snug packing, size-class buckets, and the scan epoch
 driver — the exact composition `train.py --task force --scan-epochs`
 runs. Records the force-MAE convergence curve AND end-to-end throughput
-in one artifact (config #2's SCALE_PROOF_MP146K.json, for the force task).
+in one artifact (what scale_proof.py writes for config #2, for the force task).
 
 MD17's headline sets are 50k-600k frames of 9-21-atom molecules, with
 train/test drawn from the SAME molecule's trajectory — a per-molecule
@@ -45,6 +45,11 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from cgnn_tpu.observe.metrics_io import jsonfinite  # noqa: E402
+from cgnn_tpu.runtime import (  # noqa: E402
+    COMPILE_CACHE_HELP,
+    configure_compile_cache,
+    pin_platform,
+)
 
 
 def main(argv=None) -> int:
@@ -64,20 +69,16 @@ def main(argv=None) -> int:
     p.add_argument("--device", choices=["auto", "cpu"], default="auto")
     p.add_argument("--no-scan", action="store_true",
                    help="per-step loop instead of the scan epoch driver")
-    p.add_argument("--compile-cache", type=str, default="/tmp/jax_cache")
+    p.add_argument("--compile-cache", type=str, default=None,
+                   metavar="DIR", help=COMPILE_CACHE_HELP)
     p.add_argument("--out", default="")
     args = p.parse_args(argv)
-    if args.device == "cpu":
-        os.environ["JAX_PLATFORMS"] = "cpu"
+    pin_platform(args.device)
     import jax
 
-    if args.device == "cpu":
-        jax.config.update("jax_platforms", "cpu")
-    compile_cache_warm = False
-    if args.compile_cache:
-        compile_cache_warm = bool(os.path.isdir(args.compile_cache)
-                                  and os.listdir(args.compile_cache))
-        jax.config.update("jax_compilation_cache_dir", args.compile_cache)
+    cache_dir = configure_compile_cache(args.compile_cache)
+    compile_cache_warm = bool(cache_dir and os.path.isdir(cache_dir)
+                              and os.listdir(cache_dir))
     import numpy as np
 
     from cgnn_tpu.data.dataset import FeaturizeConfig, load_trajectory

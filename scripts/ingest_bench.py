@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Host-ingest micro-benchmark: pack + end-to-end inference rates.
 
-The ISSUE-4 regression guard: BENCH_r05 showed the forward path 98.7%
+The ISSUE-4 regression guard: round 5 (PERF.md) showed the forward path 98.7%
 host-bound (device 112,305 structs/s, end-to-end 1,461), and the fix —
 compact staging + parallel packers + pooled buffers — lives entirely in
 host code that CPU CI exercises faithfully. This script measures the
@@ -106,7 +106,6 @@ def _pack_all(graphs, shape_set, workers):
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
     import numpy as np
 

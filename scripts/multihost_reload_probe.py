@@ -31,8 +31,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 
 def main() -> int:
     ckpt_dir = sys.argv[1]

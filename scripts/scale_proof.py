@@ -34,6 +34,11 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from cgnn_tpu.observe.metrics_io import jsonfinite  # noqa: E402
+from cgnn_tpu.runtime import (  # noqa: E402
+    COMPILE_CACHE_HELP,
+    configure_compile_cache,
+    pin_platform,
+)
 
 
 def main(argv=None) -> int:
@@ -53,38 +58,24 @@ def main(argv=None) -> int:
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--device", choices=["auto", "cpu"], default="auto")
     p.add_argument("--layout", choices=["dense", "coo"], default="dense")
-    p.add_argument("--compile-cache", type=str, default="/tmp/jax_cache",
+    p.add_argument("--compile-cache", type=str, default=None,
                    metavar="DIR",
-                   help="persistent XLA compile cache ('' disables); "
-                        "warmth is recorded in the output JSON")
+                   help=COMPILE_CACHE_HELP + "; warmth is recorded in the "
+                        "output JSON")
     p.add_argument("--compact", choices=["auto", "on", "off"],
                    default="auto",
                    help="stage raw atoms+distances and featurize on device "
                         "(data/compact.py); auto = on when scan+dense "
                         "supports it")
     args = p.parse_args(argv)
-    if args.device == "cpu":
-        os.environ["JAX_PLATFORMS"] = "cpu"
+    pin_platform(args.device)
     import jax
 
-    if args.device == "cpu":
-        jax.config.update("jax_platforms", "cpu")
-    compile_cache_warm = False
-    if args.compile_cache:
-        try:
-            # persistent compile cache: scan-program compiles (tens of
-            # seconds each through a high-latency link) become disk hits
-            # on re-runs; warmth is recorded in the output JSON so cold
-            # and warm first-epoch numbers are never silently mixed
-            compile_cache_warm = bool(os.path.isdir(args.compile_cache)
-                                      and os.listdir(args.compile_cache))
-            jax.config.update("jax_compilation_cache_dir",
-                              args.compile_cache)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 1.0
-            )
-        except Exception as e:  # noqa: BLE001 — cache is best-effort
-            print(f"compilation cache unavailable: {e}", file=sys.stderr)
+    # warmth is recorded in the output JSON so cold and warm first-epoch
+    # numbers are never silently mixed
+    cache_dir = configure_compile_cache(args.compile_cache)
+    compile_cache_warm = bool(cache_dir and os.path.isdir(cache_dir)
+                              and os.listdir(cache_dir))
     import numpy as np
 
     from cgnn_tpu.data.cache import load_graph_cache, save_graph_cache

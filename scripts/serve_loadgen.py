@@ -1574,7 +1574,6 @@ def _run_fleet(args) -> dict:
         canary_ctl.start()
         cont_log_path = os.path.join(log_dir, "continual.log")
         cont_env = dict(os.environ)
-        cont_env["JAX_PLATFORMS"] = "cpu"
         # round 2 trains on deliberately corrupted labels: the
         # regressing candidate the canary gate MUST refuse
         cont_env["CGNN_TPU_FAULTS"] = "label_noise=2:10.0"
@@ -2365,7 +2364,6 @@ def _run_http(args) -> dict:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     if args.make_ckpt:
         make_synth_ckpt(args.make_ckpt, seed=args.seed)
         return 0

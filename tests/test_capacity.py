@@ -38,9 +38,21 @@ def _fit_scan(graphs, compact=None, epochs=2):
     return res, logs
 
 
-def test_check_passes_when_budget_unknown(monkeypatch):
-    monkeypatch.setattr(loop_mod, "device_hbm_budget", lambda *a: None)
+def test_cpu_backend_has_no_device_budget():
+    # host memory IS the CPU backend's device memory: nothing to size
+    assert loop_mod.device_hbm_budget() is None
     assert check_device_resident_fit(10**15)
+
+
+def test_accelerator_without_memory_stats_is_an_error():
+    class Opaque:
+        platform = "tpu"
+
+        def memory_stats(self):
+            return None
+
+    with pytest.raises(RuntimeError, match="memory_stats"):
+        loop_mod.device_hbm_budget(Opaque())
 
 
 def test_check_math(monkeypatch):

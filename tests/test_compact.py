@@ -206,15 +206,6 @@ def test_graph_compactable_probe(graphs, spec):
     assert g._compact_ok[0] is spec2._probe_token
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="the 2% val-MAE drift pin is calibrated against current jax "
-           "numerics (CI): training is chaotically sensitive to the "
-           "expander's <=1-ulp jnp.exp-vs-np.exp edge difference, and "
-           "on jax 0.4.37 the 3-epoch trajectory lands at ~3.2% (train "
-           "losses still agree to 4 digits; exact pack/geometry parity "
-           "is pinned by the tests above, which run everywhere)",
-)
 def test_fit_compact_matches_full(graphs):
     """Single-bucket scan training: compact staging must produce the same
     trajectory as full staging up to edge-feature roundoff."""

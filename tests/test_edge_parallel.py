@@ -36,21 +36,6 @@ pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs 8 virtual devices"
 )
 
-# Graph-sharded TRAINING gradient parity needs the vma-typed shard_map
-# transpose (pcast-to-varying inserting the completing psums at the
-# right interior points). The parallel/compat.py shim runs these bodies
-# on jax 0.4.37's experimental shard_map, but the old transpose leaves
-# cross-shard cotangent terms incomplete (~1e-4 relative — measured,
-# see compat.pcast), so the exact-parity pins hold only on a jax with
-# native jax.shard_map (CI). Forward/eval sharding and in-body-reduced
-# DP training (test_parallel.py) are exact everywhere.
-needs_vma_transpose = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="graph-sharded backward is approximate on pre-vma shard_map "
-           "(parallel/compat.py); exact parity pinned in CI",
-)
-
-
 def _setup(batch_size=16, n_graphs=16):
     graphs = load_synthetic(
         n_graphs, FeaturizeConfig(radius=5.0, max_num_nbr=8), seed=0
@@ -84,9 +69,6 @@ def test_pad_edges_divisible_preserves_semantics():
     assert (np.asarray(padded.centers[e:]) == batch.node_capacity - 1).all()
     # sortedness invariant survives
     assert (np.diff(np.asarray(padded.centers)) >= 0).all()
-
-
-@needs_vma_transpose
 def test_edge_parallel_train_step_matches_single_device():
     _, batch, targets, tx = _setup()
     batch = pad_edges_divisible(batch, 8)
@@ -129,9 +111,6 @@ def test_edge_parallel_eval_matches_single_device():
     mesh = Mesh(np.array(jax.devices()), ("graph",))
     m2 = make_edge_parallel_eval_step(mesh)(state_gp, shard_batch(batch, mesh))
     assert float(m1["mae_sum"]) == pytest.approx(float(m2["mae_sum"]), rel=1e-5)
-
-
-@needs_vma_transpose
 def test_fit_data_parallel_2d_mesh_matches_plain_dp():
     """Full fit loop through a ('data','graph') mesh == plain-DP fit:
     same seed -> same batch order -> identical training trajectory."""
@@ -230,9 +209,6 @@ def test_shard_transpose_mapping_is_complete():
     with pytest.raises(invariants.BatchInvariantError):
         invariants.check_batch(
             dataclasses.replace(prepped, in_slots=bad_slots))
-
-
-@needs_vma_transpose
 def test_dense_sharded_train_step_matches_single_device():
     """The dense fast path composed with graph sharding: one training step
     on a 4-shard mesh == the unsharded dense step (params, stats, loss)."""
@@ -274,9 +250,6 @@ def test_dense_sharded_eval_matches_single_device():
     )
     assert float(m1["mae_sum"]) == pytest.approx(float(m2["mae_sum"]),
                                                  rel=1e-5)
-
-
-@needs_vma_transpose
 def test_fit_dense_graph_sharded_matches_plain_dp():
     """Full fit through ('data','graph') with the DENSE layout == plain-DP
     dense fit: same capacities -> same batches -> identical trajectory.
@@ -475,9 +448,6 @@ def test_fit_dense_graph_sharded_scan_buckets_trains():
     h = result["history"]
     assert np.isfinite(h[-1]["train_loss"])
     assert h[-1]["train_loss"] < h[0]["train_loss"]
-
-
-@needs_vma_transpose
 def test_2d_data_x_graph_mesh_matches_plain_dp():
     graphs, _, targets, tx = _setup(batch_size=8, n_graphs=32)
     nc, ec = capacities_for(graphs, 8)

@@ -485,9 +485,9 @@ class TestFusedCGConv:
         self._check("xla")
 
     def test_pallas_impl_matches_unfused(self):
-        from cgnn_tpu.ops.pallas_cgconv import interpret_mode
+        from jax.experimental.pallas import tpu as pltpu
 
-        with interpret_mode():
+        with pltpu.force_tpu_interpret_mode():
             self._check("pallas")
 
     def test_pallas_bounded_window_matches_unfused(self):
@@ -495,17 +495,19 @@ class TestFusedCGConv:
         window_width(max graph nodes) must reproduce the full-range
         gather exactly — an undersized bound would silently zero
         out-of-window neighbors, so coverage is pinned here."""
-        from cgnn_tpu.ops.pallas_cgconv import interpret_mode, window_width
+        from jax.experimental.pallas import tpu as pltpu
 
-        with interpret_mode():
+        from cgnn_tpu.ops.pallas_cgconv import window_width
+
+        with pltpu.force_tpu_interpret_mode():
             self._check("pallas", window=window_width(6))
 
     def test_pallas_no_transpose_slots(self):
         """Forward-only batches (in_cap=0, the serving ladder) take the
         plain-gather backward; values must not care."""
-        from cgnn_tpu.ops.pallas_cgconv import interpret_mode
+        from jax.experimental.pallas import tpu as pltpu
 
-        with interpret_mode():
+        with pltpu.force_tpu_interpret_mode():
             self._check("pallas", in_cap=0)
 
     def test_window_starts_cover_every_graph_span(self):

@@ -97,9 +97,7 @@ class TestBrokenProgramFixtures:
         assert check_donation(p) == []
 
     def test_f64_sneak_is_flagged(self):
-        from jax.experimental import enable_x64
-
-        with enable_x64():
+        with jax.enable_x64(True):
             f64_aval = jax.ShapeDtypeStruct((4,), np.float64)
             step = jax.jit(lambda x: x * 2.0)
             p = _program("toy/f64", _lowered_text(step, f64_aval))
@@ -188,7 +186,6 @@ class TestShardBudgetFixtures:
     def _mesh_fixtures(self):
         from jax.sharding import PartitionSpec as P
 
-        from cgnn_tpu.parallel import compat
         from cgnn_tpu.parallel.executor import MeshExecutor
 
         ex = MeshExecutor(jax.devices())
@@ -197,12 +194,12 @@ class TestShardBudgetFixtures:
         def body(w, b):
             return (b @ w).sum(axis=-1)
 
-        good = jax.jit(compat.shard_map(
+        good = jax.jit(jax.shard_map(
             body, mesh=ex.mesh, in_specs=(P(), P("data")),
             out_specs=P("data"), check_vma=False))
         # the classic mistake: the batch staged WITHOUT its sharding —
         # every device holds (and reads) the full stack
-        bad = jax.jit(compat.shard_map(
+        bad = jax.jit(jax.shard_map(
             lambda w, b: body(w, b)[:1], mesh=ex.mesh,
             in_specs=(P(), P()), out_specs=P("data"), check_vma=False))
         w_av = jax.ShapeDtypeStruct((64, 64), np.float32)

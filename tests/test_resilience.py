@@ -281,10 +281,16 @@ class TestCrashSafeCheckpoint:
 
 
 class TestDivergenceGuard:
-    def test_guard_noop_is_bit_identical(self, tiny_dataset):
-        """No fault -> the guarded trajectory equals the unguarded one
-        bit for bit, per-step loop and whole-epoch scan alike (the same
-        pin the telemetry tap carries)."""
+    def test_guard_noop_leaves_trajectory_unchanged(self, tiny_dataset):
+        """No fault -> the guard's select is the identity and nothing is
+        skipped, per-step loop and whole-epoch scan alike. Guarded and
+        unguarded steps are two XLA programs, and the compiler may round
+        the same update differently in each (1 ulp per step on jax
+        0.9.0's CPU backend; Adam turns that into O(lr) drift on
+        parameters whose exact gradient is zero, e.g. fc_full's bias
+        ahead of a BatchNorm) — so the pin is on what the model computes,
+        to 1e-3 (Adam amplifies the ulps), not on parameter bits. The bit-exact pin
+        lives in the skip test below, where both sides are ONE program."""
         train_g, val_g, _ = tiny_dataset
         nc, ec = _caps(train_g)
 
@@ -298,12 +304,13 @@ class TestDivergenceGuard:
             return state, result
 
         for scan in (False, True):
-            s_off, r_off = run(False, scan)
-            s_on, r_on = run(True, scan)
-            _assert_trees_equal(s_off.params, s_on.params)
+            _, r_off = run(False, scan)
+            _, r_on = run(True, scan)
             for h0, h1 in zip(r_off["history"], r_on["history"]):
-                assert h1["train"]["loss"] == h0["train"]["loss"]
                 assert h1["train"]["guard_skipped"] == 0.0
+                for phase in ("train", "val"):
+                    np.testing.assert_allclose(
+                        h1[phase]["loss"], h0[phase]["loss"], rtol=1e-3)
 
     def test_nan_batch_skip_equals_manual_skip_bit_exact(self, tiny_dataset):
         """A NaN batch under the guard leaves the state EXACTLY as if the
