@@ -501,7 +501,10 @@ def make_expander(spec: CompactSpec) -> Callable[[CompactBatch], GraphBatch]:
     energy-family models never read them (models/cgcnn.py), and staging
     zeros for them would defeat the point.
     """
+    import jax
     import jax.numpy as jnp
+
+    from cgnn_tpu.observe import phases
 
     table = np.asarray(spec.vocab.table, np.float32)
     mu = np.asarray(spec.gauss_filter, np.float32)
@@ -509,35 +512,36 @@ def make_expander(spec: CompactSpec) -> Callable[[CompactBatch], GraphBatch]:
     edge_dtype = spec.edge_dtype
 
     def expand(cb: CompactBatch) -> GraphBatch:
-        n, m = cb.distances.shape
-        node_mask = cb.node_mask.astype(jnp.float32)
-        nodes = jnp.asarray(table)[cb.atom_idx] * node_mask[:, None]
-        emask = cb.edge_mask.astype(jnp.float32)
-        d = cb.distances[..., None]
-        efea = jnp.exp(-((d - jnp.asarray(mu)) ** 2) * inv_var2)
-        efea = (efea * emask[..., None]).astype(edge_dtype)
-        centers = jnp.arange(n * m, dtype=jnp.int32) // m
-        return GraphBatch(
-            nodes=nodes,
-            edges=efea,
-            centers=centers,
-            neighbors=cb.neighbors,
-            node_graph=cb.node_graph,
-            node_mask=node_mask,
-            edge_mask=emask.reshape(-1),
-            graph_mask=cb.graph_mask,
-            targets=cb.targets,
-            target_mask=cb.target_mask,
-            positions=None,
-            lattices=None,
-            edge_offsets=None,
-            node_targets=None,
-            in_slots=cb.in_slots,
-            in_mask=cb.in_mask,
-            over_slots=cb.over_slots,
-            over_nodes=cb.over_nodes,
-            over_mask=cb.over_mask,
-        )
+        with jax.named_scope(phases.EXPAND):
+            n, m = cb.distances.shape
+            node_mask = cb.node_mask.astype(jnp.float32)
+            nodes = jnp.asarray(table)[cb.atom_idx] * node_mask[:, None]
+            emask = cb.edge_mask.astype(jnp.float32)
+            d = cb.distances[..., None]
+            efea = jnp.exp(-((d - jnp.asarray(mu)) ** 2) * inv_var2)
+            efea = (efea * emask[..., None]).astype(edge_dtype)
+            centers = jnp.arange(n * m, dtype=jnp.int32) // m
+            return GraphBatch(
+                nodes=nodes,
+                edges=efea,
+                centers=centers,
+                neighbors=cb.neighbors,
+                node_graph=cb.node_graph,
+                node_mask=node_mask,
+                edge_mask=emask.reshape(-1),
+                graph_mask=cb.graph_mask,
+                targets=cb.targets,
+                target_mask=cb.target_mask,
+                positions=None,
+                lattices=None,
+                edge_offsets=None,
+                node_targets=None,
+                in_slots=cb.in_slots,
+                in_mask=cb.in_mask,
+                over_slots=cb.over_slots,
+                over_nodes=cb.over_nodes,
+                over_mask=cb.over_mask,
+            )
 
     return expand
 

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from cgnn_tpu.data.graph import GraphBatch
+from cgnn_tpu.observe import phases
 from cgnn_tpu.ops.norm import MaskedBatchNorm
 from cgnn_tpu.ops.segment import (
     aggregate_edge_messages,
@@ -178,9 +179,10 @@ class CGConv(nn.Module):
             m = self.dense_m
             n_full = nodes.shape[0]
             fdim = nodes.shape[-1]
-            e = edges.astype(nodes.dtype)
-            if e.ndim == 2:
-                e = e.reshape(-1, m, e.shape[-1])
+            with jax.named_scope(phases.CONV_FC_FULL):
+                e = edges.astype(nodes.dtype)
+                if e.ndim == 2:
+                    e = e.reshape(-1, m, e.shape[-1])
             n_strip = e.shape[0]
             idx = jax.lax.axis_index(axis)
             # linear_call (gather_transpose) does not insert the implicit
@@ -188,31 +190,35 @@ class CGConv(nn.Module):
             # the cast's transpose is the psum that completes each shard's
             # partial [N, F] node cotangent
             nodes_v = jax.lax.pcast(nodes, axis, to="varying")
-            if in_slots is not None:
+            if in_slots is not None and in_slots.shape[0] != 1:
                 # per-shard two-tier mappings arrive with a leading
                 # singleton from the shard-stack axis (graph.py
-                # shard_transpose_slots): squeeze to this shard's mapping.
-                # A non-singleton means the mapping was built for a
+                # shard_transpose_slots), squeezed below to this shard's
+                # mapping. A non-singleton means the mapping was built for a
                 # different shard count than this mesh — [0] would then
                 # silently drop cotangents, so refuse at trace time.
-                if in_slots.shape[0] != 1:
-                    raise ValueError(
-                        f"per-shard transpose mapping was built for "
-                        f"{in_slots.shape[0]}x this mesh's graph-shard "
-                        f"count (pack with transpose_shards == the mesh's "
-                        f"'graph' axis size)"
-                    )
-                v_j = gather_transpose(
-                    nodes_v, neighbors, in_slots[0], in_mask[0],
-                    over_slots=None if over_slots is None else over_slots[0],
-                    over_nodes=None if over_nodes is None else over_nodes[0],
-                    over_mask=None if over_mask is None else over_mask[0],
-                ).reshape(n_strip, m, fdim)
-            else:  # eval batches carry no transpose mapping
-                v_j = gather(nodes_v, neighbors).reshape(n_strip, m, fdim)
-            nodes_strip = jax.lax.dynamic_slice_in_dim(
-                nodes, idx * n_strip, n_strip
-            )
+                raise ValueError(
+                    f"per-shard transpose mapping was built for "
+                    f"{in_slots.shape[0]}x this mesh's graph-shard "
+                    f"count (pack with transpose_shards == the mesh's "
+                    f"'graph' axis size)"
+                )
+            with jax.named_scope(phases.CONV_GATHER):
+                if in_slots is not None:
+                    v_j = gather_transpose(
+                        nodes_v, neighbors, in_slots[0], in_mask[0],
+                        over_slots=(None if over_slots is None
+                                    else over_slots[0]),
+                        over_nodes=(None if over_nodes is None
+                                    else over_nodes[0]),
+                        over_mask=None if over_mask is None else over_mask[0],
+                    ).reshape(n_strip, m, fdim)
+                else:  # eval batches carry no transpose mapping
+                    v_j = gather(nodes_v, neighbors).reshape(
+                        n_strip, m, fdim)
+                nodes_strip = jax.lax.dynamic_slice_in_dim(
+                    nodes, idx * n_strip, n_strip
+                )
             z = _SplitFcFull(2 * f, dtype=self.dtype, name="fc_full")(
                 nodes_strip, v_j, e
             )
@@ -221,19 +227,21 @@ class CGConv(nn.Module):
                 z = MaskedBatchNorm(
                     dtype=self.dtype, name="bn1", axis_name=axis
                 )(z, mask=emask, use_running_average=not train)
-            gate, core = jnp.split(z, 2, axis=-1)
-            msg = nn.sigmoid(gate) * nn.softplus(core)
-            # zero cotangent on padding slots — load-bearing for the
-            # scatter-free backward exactly as in the unsharded branch
-            msg = msg * emask[..., None].astype(msg.dtype)
-            agg_strip = msg.sum(axis=1)  # [N/D, F], complete per node
-            agg = jax.lax.psum(
-                jax.lax.dynamic_update_slice_in_dim(
-                    jnp.zeros((n_full, f), agg_strip.dtype), agg_strip,
-                    idx * n_strip, axis=0,
-                ),
-                axis,
-            )
+            with jax.named_scope(phases.CONV_GATE):
+                gate, core = jnp.split(z, 2, axis=-1)
+                msg = nn.sigmoid(gate) * nn.softplus(core)
+                # zero cotangent on padding slots — load-bearing for the
+                # scatter-free backward exactly as in the unsharded branch
+                msg = msg * emask[..., None].astype(msg.dtype)
+            with jax.named_scope(phases.CONV_AGGREGATE):
+                agg_strip = msg.sum(axis=1)  # [N/D, F], complete per node
+                agg = jax.lax.psum(
+                    jax.lax.dynamic_update_slice_in_dim(
+                        jnp.zeros((n_full, f), agg_strip.dtype), agg_strip,
+                        idx * n_strip, axis=0,
+                    ),
+                    axis,
+                )
         elif self.dense_m is not None and self.cgconv_impl is not None:
             # WHOLE-conv fused kernel (ops/pallas_cgconv.py): gather +
             # fc_full + BN1 + gate + mask + sum as ONE custom-VJP op —
@@ -279,29 +287,31 @@ class CGConv(nn.Module):
             m = self.dense_m
             n = nodes.shape[0]
             fdim = nodes.shape[-1]
-            if in_slots is not None:
-                # scatter-free backward via the packed transpose mapping
-                # (two-tier when the batch carries overflow slots). NOTE:
-                # a slot-space variant (2-D index gathers keeping both
-                # directions in [N, M, F]) was tried to kill the relayout
-                # copies and measured 19% SLOWER end-to-end (17.2 vs 14.5
-                # ms/step, r3 trace5) — multi-dim gather lowering costs
-                # more than the copies it saves; keep the flat form.
-                v_j = gather_transpose(
-                    nodes, neighbors, in_slots, in_mask,
-                    over_slots=over_slots, over_nodes=over_nodes,
-                    over_mask=over_mask,
-                ).reshape(n, m, fdim)
-            else:
-                v_j = gather(nodes, neighbors).reshape(n, m, fdim)
-            # dense batches carry edges pre-shaped [N, M, G] (pack_graphs)
-            e = edges.astype(nodes.dtype)
-            if e.ndim == 2:  # direct pack_graphs callers with flat edges
-                e = e.reshape(n, m, -1)
-            # sliced matmuls: no [N, M, 2F+G] concat, v_i term per-node
-            z = _SplitFcFull(2 * f, dtype=self.dtype, name="fc_full")(
-                nodes, v_j, e
-            )
+            with jax.named_scope(phases.CONV_GATHER):
+                if in_slots is not None:
+                    # scatter-free backward via the packed transpose mapping
+                    # (two-tier when the batch carries overflow slots). NOTE:
+                    # a slot-space variant (2-D index gathers keeping both
+                    # directions in [N, M, F]) was tried to kill the relayout
+                    # copies and measured 19% SLOWER end-to-end (17.2 vs 14.5
+                    # ms/step, r3 trace5) — multi-dim gather lowering costs
+                    # more than the copies it saves; keep the flat form.
+                    v_j = gather_transpose(
+                        nodes, neighbors, in_slots, in_mask,
+                        over_slots=over_slots, over_nodes=over_nodes,
+                        over_mask=over_mask,
+                    ).reshape(n, m, fdim)
+                else:
+                    v_j = gather(nodes, neighbors).reshape(n, m, fdim)
+            with jax.named_scope(phases.CONV_FC_FULL):
+                # dense batches carry edges pre-shaped [N, M, G] (pack_graphs)
+                e = edges.astype(nodes.dtype)
+                if e.ndim == 2:  # direct pack_graphs callers with flat edges
+                    e = e.reshape(n, m, -1)
+                # sliced matmuls: no [N, M, 2F+G] concat, v_i term per-node
+                z = _SplitFcFull(2 * f, dtype=self.dtype, name="fc_full")(
+                    nodes, v_j, e
+                )
             if self.use_batchnorm and self.fused_epilogue is not None:
                 # one custom-VJP op for BN1+gate+mask+sum with minimal
                 # activation passes (ops/fused_epilogue.py). Parameter
@@ -325,45 +335,54 @@ class CGConv(nn.Module):
                         z, mask=edge_mask.reshape(n, m),
                         use_running_average=not train,
                     )
-                gate, core = jnp.split(z, 2, axis=-1)
-                msg = nn.sigmoid(gate) * nn.softplus(core)
-                # LOAD-BEARING for gradients, not just values:
-                # gather_transpose's scatter-free VJP assumes zero cotangent
-                # on padding edge slots, which THIS mask (together with
-                # masked BN statistics) guarantees. Removing it would
-                # silently corrupt node gradients (ops/segment.py
-                # gather_transpose docstring; parity test:
-                # tests/test_batching.py two-tier backward).
-                msg = msg * edge_mask.reshape(n, m, 1).astype(msg.dtype)
-                agg = msg.sum(axis=1)
+                with jax.named_scope(phases.CONV_GATE):
+                    gate, core = jnp.split(z, 2, axis=-1)
+                    msg = nn.sigmoid(gate) * nn.softplus(core)
+                    # LOAD-BEARING for gradients, not just values:
+                    # gather_transpose's scatter-free VJP assumes zero
+                    # cotangent on padding edge slots, which THIS mask
+                    # (together with masked BN statistics) guarantees.
+                    # Removing it would silently corrupt node gradients
+                    # (ops/segment.py gather_transpose docstring; parity
+                    # test: tests/test_batching.py two-tier backward).
+                    msg = msg * edge_mask.reshape(n, m, 1).astype(msg.dtype)
+                with jax.named_scope(phases.CONV_AGGREGATE):
+                    agg = msg.sum(axis=1)
         else:
-            v_i = gather(nodes, centers)
-            v_j = gather(nodes, neighbors)
-            z = jnp.concatenate([v_i, v_j, edges.astype(nodes.dtype)], axis=-1)
+            with jax.named_scope(phases.CONV_GATHER):
+                v_i = gather(nodes, centers)
+                v_j = gather(nodes, neighbors)
+                z = jnp.concatenate(
+                    [v_i, v_j, edges.astype(nodes.dtype)], axis=-1)
             z = nn.Dense(2 * f, dtype=self.dtype, name="fc_full")(z)
             if self.use_batchnorm:
                 z = MaskedBatchNorm(
                     dtype=self.dtype, name="bn1", axis_name=self.edge_axis_name
                 )(z, mask=edge_mask, use_running_average=not train)
-            gate, core = jnp.split(z, 2, axis=-1)
-            msg = nn.sigmoid(gate) * nn.softplus(core)
-            msg = msg * edge_mask[:, None].astype(msg.dtype)
-            agg = aggregate_edge_messages(
-                msg,
-                centers,
-                nodes.shape[0],
-                impl=self.aggregation_impl,
-                indices_are_sorted=self.assume_sorted_edges,
-            )
-            if self.edge_axis_name is not None:
-                # partial per-node sums from this edge shard -> full sums
-                agg = jax.lax.psum(agg, self.edge_axis_name)
+            with jax.named_scope(phases.CONV_GATE):
+                gate, core = jnp.split(z, 2, axis=-1)
+                msg = nn.sigmoid(gate) * nn.softplus(core)
+                msg = msg * edge_mask[:, None].astype(msg.dtype)
+            with jax.named_scope(phases.CONV_AGGREGATE):
+                agg = aggregate_edge_messages(
+                    msg,
+                    centers,
+                    nodes.shape[0],
+                    impl=self.aggregation_impl,
+                    indices_are_sorted=self.assume_sorted_edges,
+                )
+                if self.edge_axis_name is not None:
+                    # partial per-node sums from this edge shard -> full sums
+                    agg = jax.lax.psum(agg, self.edge_axis_name)
         if self.use_batchnorm:
             agg = MaskedBatchNorm(dtype=self.dtype, name="bn2")(
                 agg, mask=node_mask, use_running_average=not train
             )
-        out = nn.softplus(nodes + agg)
-        return out * node_mask[:, None].astype(out.dtype)
+        # the residual and its softplus belong to bn2's phase: they read
+        # its output once more and nothing else
+        with jax.named_scope(phases.CONV_BN2):
+            out = nn.softplus(nodes + agg)
+            return out * node_mask[:, None].astype(out.dtype)
 
 
 class CrystalGraphConvNet(nn.Module):
@@ -396,10 +415,11 @@ class CrystalGraphConvNet(nn.Module):
     def __call__(
         self, batch: GraphBatch, train: bool = False, return_node_features: bool = False
     ):
-        nodes = nn.Dense(self.atom_fea_len, dtype=self.dtype, name="embedding")(
-            batch.nodes.astype(self.dtype)
-        )
-        nodes = nodes * batch.node_mask[:, None].astype(nodes.dtype)
+        with jax.named_scope(phases.EMBED):
+            nodes = nn.Dense(
+                self.atom_fea_len, dtype=self.dtype, name="embedding"
+            )(batch.nodes.astype(self.dtype))
+            nodes = nodes * batch.node_mask[:, None].astype(nodes.dtype)
         for i in range(self.n_conv):
             nodes = CGConv(
                 features=self.atom_fea_len,
@@ -427,32 +447,35 @@ class CrystalGraphConvNet(nn.Module):
                 over_mask=batch.over_mask,
             )
         # per-crystal masked mean pooling (reference `pooling`)
-        crys = segment_mean(
-            nodes,
-            batch.node_graph,
-            batch.graph_capacity,
-            weights=batch.node_mask.astype(nodes.dtype),
-        )
-        crys = nn.Dense(self.h_fea_len, dtype=self.dtype, name="conv_to_fc")(
-            nn.softplus(crys)
-        )
-        crys = nn.softplus(crys)
-        if self.classification and self.dropout_rate > 0:
-            crys = nn.Dropout(self.dropout_rate, deterministic=not train)(crys)
-        if self.head is not None:
-            out = self.head(crys)
-        else:
-            for i in range(self.n_h - 1):
-                crys = nn.softplus(
-                    nn.Dense(self.h_fea_len, dtype=self.dtype, name=f"fc_{i}")(crys)
-                )
-            out_dim = self.num_classes if self.classification else self.num_targets
-            out = nn.Dense(out_dim, dtype=self.dtype, name="fc_out")(crys)
-            if self.classification:
-                out = nn.log_softmax(out, axis=-1)
-        out = out * batch.graph_mask[:, None].astype(out.dtype)
-        # promote low-precision (bf16) compute back to f32; keep f64 as-is
-        out = out.astype(jnp.promote_types(jnp.float32, out.dtype))
+        with jax.named_scope(phases.POOL_HEAD):
+            crys = segment_mean(
+                nodes,
+                batch.node_graph,
+                batch.graph_capacity,
+                weights=batch.node_mask.astype(nodes.dtype),
+            )
+            crys = nn.Dense(
+                self.h_fea_len, dtype=self.dtype, name="conv_to_fc"
+            )(nn.softplus(crys))
+            crys = nn.softplus(crys)
+            if self.classification and self.dropout_rate > 0:
+                crys = nn.Dropout(
+                    self.dropout_rate, deterministic=not train)(crys)
+            if self.head is not None:
+                out = self.head(crys)
+            else:
+                for i in range(self.n_h - 1):
+                    crys = nn.softplus(nn.Dense(
+                        self.h_fea_len, dtype=self.dtype, name=f"fc_{i}"
+                    )(crys))
+                out_dim = (self.num_classes if self.classification
+                           else self.num_targets)
+                out = nn.Dense(out_dim, dtype=self.dtype, name="fc_out")(crys)
+                if self.classification:
+                    out = nn.log_softmax(out, axis=-1)
+            out = out * batch.graph_mask[:, None].astype(out.dtype)
+            # promote low-precision (bf16) compute back to f32; keep f64 as-is
+            out = out.astype(jnp.promote_types(jnp.float32, out.dtype))
         if return_node_features:
             return out, nodes
         return out

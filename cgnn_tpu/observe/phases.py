@@ -1,0 +1,167 @@
+"""Model phases of a compiled step: one vocabulary, defined here.
+
+The device profiler names what ran by HLO instruction (``fusion.652``), which
+says nothing to a reader of the model. The compiled program knows better:
+every instruction's metadata carries the ``op_name`` JAX built from the name
+stack — flax module scopes (``conv_1/bn1``), transforms (``jvp``,
+``transpose``) and the ``jax.named_scope`` s the program adds where flax says
+nothing (models/cgcnn.py, train/step.py, resilience/guard.py,
+data/compact.py, train/loop.py). ``classify`` maps such a path to one phase
+of ``PHASES`` and a direction; ``phase_table`` does so for every instruction
+of an optimized HLO module that the device can report as an event.
+
+Scopes are metadata only: they change no compiled code (pinned by
+tests/test_observe.py). The persistent compile cache keys on the program
+and not on its metadata, so a program cached before a scope was added or
+renamed comes back with the old names: clear the cache after touching one.
+"""
+
+from __future__ import annotations
+
+import re
+
+# scopes the program opens by name (jax.named_scope); the rest of the
+# vocabulary is read off flax module names below
+EXPAND = "expand"
+EMBED = "embed"
+CONV_GATHER = "conv.gather"
+CONV_FC_FULL = "conv.fc_full"
+CONV_BN1 = "conv.bn1"
+CONV_GATE = "conv.gate"
+CONV_AGGREGATE = "conv.aggregate"
+CONV_BN2 = "conv.bn2"
+POOL_HEAD = "pool_head"
+LOSS = "loss"
+OPTIMIZER = "optimizer"
+SCAN = "scan"
+OTHER = "other"
+
+PHASES = (EXPAND, EMBED, CONV_GATHER, CONV_FC_FULL, CONV_BN1, CONV_GATE,
+          CONV_AGGREGATE, CONV_BN2, POOL_HEAD, LOSS, OPTIMIZER, SCAN, OTHER)
+FWD, BWD = "fwd", "bwd"
+
+# a path component -> its phase: the named scopes themselves, and the flax
+# module names of models/cgcnn.py (bn1/bn2/fc_full exist only inside a conv)
+_TOKEN_PHASE = {p: p for p in PHASES if p != OTHER} | {
+    "embedding": EMBED,
+    "fc_full": CONV_FC_FULL,
+    "bn1": CONV_BN1,
+    "bn2": CONV_BN2,
+    "conv_to_fc": POOL_HEAD,
+    "fc_out": POOL_HEAD,
+}
+_HEAD_FC = re.compile(r"fc_\d+$")
+_LOOP = {"while", "body", "cond"}
+
+
+def classify(op_name: str) -> tuple[str, str]:
+    """``op_name`` (an instruction's metadata path) -> (phase, direction).
+
+    The innermost component that names a phase wins. A path that is only the
+    loop's own machinery (``jit(f)/while/body/dynamic_slice``) is ``scan``;
+    anything else without a phase is ``other``. ``bwd`` is a path through a
+    ``transpose(`` — the backward pass of reverse-mode autodiff.
+    """
+    # XLA joins the names of instructions it merged with ';': the first is
+    # the one the merged instruction mostly is
+    op_name = op_name.partition(";")[0]
+    direction = BWD if "transpose(" in op_name else FWD
+    # jit(f)/transpose(jvp(Model))/conv_1/bn1/mul -> components
+    parts = [t for t in re.split(r"[/()]", op_name) if t]
+    for tok in reversed(parts):
+        phase = _TOKEN_PHASE.get(tok)
+        if phase is None and _HEAD_FC.match(tok):
+            phase = POOL_HEAD
+        if phase is not None:
+            return phase, direction
+    if parts[:1] == ["jit"]:
+        parts = parts[2:]  # the wrapper and the function's name
+    rest = [t for t in parts if t not in _LOOP]
+    if len(rest) < len(parts) and len(rest) <= 1:
+        return SCAN, direction
+    return OTHER, direction
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(ROOT\s+)?%?([\w.\-]+)\s+=\s+(.*)$")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_CALLED = re.compile(r"\b(calls|to_apply)=%?([\w.\-]+)")
+# `` copy(%fusion.648)`` after the result's type; a layout's ``T(8,128)``
+# follows no blank
+_OPERANDS = re.compile(r"\s[a-z][\w\-]*\(([^()]*)\)")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+
+
+def _parse(hlo_text: str) -> dict:
+    """{computation: {"instrs": {name: rest of line}, "root": name}}."""
+    comps: dict = {}
+    cur = None
+    for line in hlo_text.splitlines():
+        if cur is None:
+            m = _COMPUTATION.match(line)
+            if m:
+                cur = comps[m.group(1)] = {"instrs": {}, "root": None}
+            continue
+        if line.startswith("}"):
+            cur = None
+            continue
+        m = _INSTRUCTION.match(line)
+        if m:
+            cur["instrs"][m.group(2)] = m.group(3)
+            if m.group(1):
+                cur["root"] = m.group(2)
+    return comps
+
+
+def _resolve(comps: dict, comp: str, name: str, depth: int = 0) -> str:
+    """An instruction's op_name: its own; else, for one that calls a
+    computation (a fusion), that computation's root's; else its operands',
+    the first that has one. So what only moves data (a layout copy or a
+    prefetch the compiler inserted, a get-tuple-element, a tuple) counts
+    with the phase that produced the data; what hangs on nothing but a
+    parameter has no name."""
+    rest = comps[comp]["instrs"].get(name)
+    if rest is None or depth > 8:
+        return ""
+    m = _OP_NAME.search(rest)
+    if m:
+        return m.group(1)
+    called = _CALLED.search(rest)
+    if called and called.group(2) in comps:
+        inner = comps[called.group(2)]
+        if inner["root"]:
+            return _resolve(comps, called.group(2), inner["root"], depth + 1)
+    operands = _OPERANDS.search(rest)
+    for operand in _OPERAND.findall(operands.group(1) if operands else ""):
+        found = _resolve(comps, comp, operand, depth + 1)
+        if found:
+            return found
+    return ""
+
+
+def phase_table(compiled_hlo_text: str) -> dict:
+    """Optimized HLO text (``compiled.as_text()``) -> {instruction name:
+    (phase, direction)} for every instruction the device can report as an
+    event of its own: those of the entry computation and of loop bodies and
+    conditions, not those inside a fusion or a reducer.
+
+    A fusion takes its own ``op_name`` (XLA copies it from the fusion's root)
+    and, where it has none, its root's; an instruction that has none and
+    calls nothing (a copy the compiler inserted) takes its operand's
+    (``_resolve``); one with no name to be found is ``other``.
+    """
+    comps = _parse(compiled_hlo_text)
+    nested = set()
+    for comp in comps.values():
+        for rest in comp["instrs"].values():
+            for kind, target in _CALLED.findall(rest):
+                if kind == "calls" or " call(" not in rest:
+                    nested.add(target)
+    table = {}
+    for cname, comp in comps.items():
+        if cname in nested:
+            continue
+        for name in comp["instrs"]:
+            op_name = _resolve(comps, cname, name)
+            table[name] = classify(op_name) if op_name else (OTHER, FWD)
+    return table

@@ -13,7 +13,13 @@ runtime request:
   (capped at ``max_duration_s`` — an operator typo must not leave the
   profiler running for an hour), stop it, and — when a span tracer is
   attached — export the CURRENT host span buffer alongside it, so the
-  device trace and the host orchestration window land together.
+  device trace and the host orchestration window land together. They
+  are on two clocks: ``host_trace.json`` is the span ring's
+  (``perf_counter`` since the tracer was built; what ``/trace`` and
+  ``trace_join`` read), the ``.xplane.pb`` is the profiler's. The spans
+  opened during the capture are ALSO in the ``.xplane.pb``, as
+  ``cgnn:<name>`` events of the ``/host:CPU`` plane (observe/spans.py):
+  lay those, not ``host_trace.json``, over the device lines.
 - The gate is a non-blocking lock: a capture that arrives while one is
   running is REJECTED (:class:`ProfileBusy`) rather than stacked —
   ``jax.profiler`` supports one trace at a time, and queueing captures

@@ -7,8 +7,15 @@ nesting is tracked per thread and exported as complete events (``"ph":
 "X"``) in the Chrome trace event format, which Perfetto and
 ``chrome://tracing`` open directly.
 
-Timestamps are ``time.perf_counter`` microseconds relative to tracer
-construction (Chrome traces only need a consistent monotonic base).
+Two clocks. The ring's timestamps (``trace.json``, ``GET /trace``, a
+capture's ``host_trace.json``) are ``time.perf_counter`` microseconds
+relative to tracer construction: consistent among themselves, and with no
+fixed relation to a device trace. So every ``span()`` also opens a
+``jax.profiler.TraceAnnotation`` named ``cgnn:<name>``: while a profiler
+session runs, the same span lands on the host plane of the ``.xplane.pb``,
+on the clock the device lines are drawn on, and can be laid over them
+(outside a session the annotation records nothing). Retro-stamped
+``complete()`` spans and ``instant()`` s exist in the ring only.
 """
 
 from __future__ import annotations
@@ -16,11 +23,22 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 from typing import Iterator
 
 from cgnn_tpu.observe.metrics_io import jsonfinite
+
+
+def _annotation(name: str):
+    """``name`` on the profiler's clock. A process that never imported
+    JAX (the fleet router) has no profiler session to land in, and stays
+    free of JAX."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation("cgnn:" + name)
 
 
 class SpanTracer:
@@ -79,14 +97,16 @@ class SpanTracer:
             return self._tids.setdefault(ident, len(self._tids))
 
     @contextlib.contextmanager
-    def span(self, name: str, **args) -> Iterator[None]:
+    def span(self, name: str, **args) -> Iterator[dict]:
         """Time a block; ``args`` become the event's args dict (viewable
-        in the Perfetto detail pane)."""
+        in the Perfetto detail pane). The dict is yielded, so the block can
+        add what it only learns on the way (``as args: args["n"] = n``)."""
         depth = getattr(self._depth, "value", 0)
         self._depth.value = depth + 1
         start = self._now_us()
         try:
-            yield
+            with _annotation(name):
+                yield args
         finally:
             self._depth.value = depth
             event = {
@@ -96,7 +116,7 @@ class SpanTracer:
                 "dur": self._now_us() - start,
                 "pid": 0,
                 "tid": self._tid(),
-                "args": {k: v for k, v in args.items()} | {"depth": depth},
+                "args": args | {"depth": depth},
             }
             self._append(event)
 

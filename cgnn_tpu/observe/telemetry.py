@@ -211,6 +211,13 @@ class Telemetry:
         return body if self.stream is None else self.stream.wrap_eval(
             body, phase)
 
+    @property
+    def warming(self) -> bool:
+        """Inside a ``warmup()``: what is dispatched now is not run work
+        (the scan driver emits no ``scan.chunk`` span for it)."""
+        with self._lock:
+            return self._warmups > 0
+
     @contextlib.contextmanager
     def warmup(self) -> Iterator[None]:
         """Mute the step stream AND the dispatch counters for

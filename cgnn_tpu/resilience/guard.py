@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from cgnn_tpu.observe import phases
 from cgnn_tpu.observe.health import nonfinite_count
 
 
@@ -50,36 +51,37 @@ def guard_step(body: Callable) -> Callable:
 
     def guarded(state, batch):
         new_state, metrics = body(state, batch)
-        bad = nonfinite_count(new_state.params)
-        bad = bad + nonfinite_count(new_state.batch_stats)
-        if "loss_sum" in metrics:
-            bad = bad + (
-                ~jnp.isfinite(jnp.asarray(metrics["loss_sum"], jnp.float32))
-            ).astype(jnp.float32)
-        ok = bad == 0
+        # the check and the select ride with the update they guard
+        with jax.named_scope(phases.OPTIMIZER):
+            bad = nonfinite_count(new_state.params)
+            bad = bad + nonfinite_count(new_state.batch_stats)
+            if "loss_sum" in metrics:
+                loss_sum = jnp.asarray(metrics["loss_sum"], jnp.float32)
+                bad = bad + (~jnp.isfinite(loss_sum)).astype(jnp.float32)
+            ok = bad == 0
 
-        def keep(new, old):
-            return jnp.where(ok, new, old)
+            def keep(new, old):
+                return jnp.where(ok, new, old)
 
-        def select(new, old):
-            return jax.tree_util.tree_map(keep, new, old)
+            def select(new, old):
+                return jax.tree_util.tree_map(keep, new, old)
 
-        out_state = new_state.replace(
-            # step stays put on a skip: the retried-batch rng fold_in and
-            # the lr schedule see a trajectory without the bad step
-            step=keep(new_state.step, state.step),
-            params=select(new_state.params, state.params),
-            batch_stats=select(new_state.batch_stats, state.batch_stats),
-            opt_state=select(new_state.opt_state, state.opt_state),
-        )
-        okf = ok.astype(jnp.float32)
-        # zero the skipped step's metric sums AND counts (a NaN loss must
-        # not poison the epoch aggregate; where, not multiply — NaN*0=NaN)
-        metrics = {
-            k: jnp.where(ok, v, jnp.zeros_like(v)) for k, v in metrics.items()
-        }
-        metrics["guard_skipped_sum"] = 1.0 - okf
-        metrics["guard_skipped_count"] = jnp.float32(1.0)
+            out_state = new_state.replace(
+                # step stays put on a skip: the retried-batch rng fold_in and
+                # the lr schedule see a trajectory without the bad step
+                step=keep(new_state.step, state.step),
+                params=select(new_state.params, state.params),
+                batch_stats=select(new_state.batch_stats, state.batch_stats),
+                opt_state=select(new_state.opt_state, state.opt_state),
+            )
+            okf = ok.astype(jnp.float32)
+            # zero the skipped step's metric sums AND counts (a NaN loss
+            # must not poison the epoch aggregate; where, not multiply —
+            # NaN*0=NaN)
+            metrics = {k: jnp.where(ok, v, jnp.zeros_like(v))
+                       for k, v in metrics.items()}
+            metrics["guard_skipped_sum"] = 1.0 - okf
+            metrics["guard_skipped_count"] = jnp.float32(1.0)
         return out_state, metrics
 
     return guarded

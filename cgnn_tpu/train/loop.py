@@ -57,7 +57,7 @@ if _WINDOW < 1:
 
     warnings.warn("CGNN_TPU_WINDOW must be >= 1; clamping to 1")
     _WINDOW = 1
-from cgnn_tpu.observe import Telemetry
+from cgnn_tpu.observe import Telemetry, phases
 from cgnn_tpu.resilience import faultinject
 from cgnn_tpu.train.state import TrainState
 from cgnn_tpu.train.step import (
@@ -265,9 +265,8 @@ def run_epoch(
     sums = fetch_device_sums(dev_sums)
     _sync_window(time.perf_counter())
     if telemetry is not None:
-        # dispatch-share + host-wait counters (flushed in the run summary)
+        # dispatch-share counter (flushed in the run summary)
         telemetry.counter_add("per_step_steps", it + 1)
-        telemetry.counter_add("data_wait_s", meters["data_time"].sum)
     return state, means_from_sums(sums, it + 1)
 
 
@@ -370,6 +369,45 @@ class PendingPairMetrics:
         return self._out
 
 
+def program_name(key, train: bool) -> str:
+    """The name of one scan program, as the profiler's ``XLA Modules``
+    line shows it (``jit_scan_train_n23944_l2``): train or eval, the
+    bucket's node capacity, the chunk length. ``key`` is ``(shape key,
+    length)`` with the shape key of ``batch_shape_key``."""
+    shape_key, length = key
+    # nodes [.., N, F] of a GraphBatch, distances [.., N, M] of a compact one
+    shape = shape_key[1] if shape_key[0] == "compact" else shape_key[0]
+    return (f"scan_{'train' if train else 'eval'}_n{int(shape[-2])}"
+            f"_l{int(length)}")
+
+
+def _staging_args(batches: list) -> dict:
+    return {"groups": len({batch_shape_key(b) for b in batches}),
+            "batches": len(batches), "bytes": int(staged_nbytes(batches))}
+
+
+@contextlib.contextmanager
+def _compile_events():
+    """What XLA did while open: ``{"compiled": a program was compiled,
+    "cache_read": one came from the persistent cache}``; both false when
+    jit already held every program."""
+    seen = {"compiled": False, "cache_read": False}
+
+    def on(name: str, _secs: float, **_kw) -> None:
+        if "cache_retrieval" in name:
+            seen["cache_read"] = True
+        elif "backend_compile" in name:
+            seen["compiled"] = True
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        yield seen
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
+        # backend_compile wraps compile-or-read; a read says so itself
+        seen["compiled"] &= not seen["cache_read"]
+
+
 class ScanEpochDriver:
     """Whole-epoch dispatch for device-resident datasets: one ``lax.scan``
     per bucket shape per epoch instead of one dispatch per step.
@@ -450,8 +488,12 @@ class ScanEpochDriver:
         # seconds, reset by the caller when desired
         self.timings: dict[str, float] = {}
         t0 = time.perf_counter()
-        self._train_groups = self._stack_groups(train_batches)
-        self._val_groups = self._stack_groups(val_batches)
+        with self._span("scan.stage") as args:
+            self._train_groups = self._stack_groups(train_batches)
+            self._val_groups = self._stack_groups(val_batches)
+            if args is not None:
+                args.update(_staging_args(
+                    [*train_batches, *val_batches]))
         self.timings["init_stack_stage_s"] = time.perf_counter() - t0
         self._train_body, self._eval_body = train_body, eval_body
         self._train_scans: dict = {}
@@ -459,6 +501,13 @@ class ScanEpochDriver:
         # one-epoch-ahead schedules, keyed (id(groups), train, first) —
         # see _build_sched/_drive
         self._sched_cache: dict = {}
+
+    def _span(self, name: str):
+        """A set-up span of the program's tracer (and, through it, of the
+        profiler's clock), yielding its args dict; with telemetry off a
+        context that yields None."""
+        tel = self._telemetry
+        return tel.span(name) if tel is not None else contextlib.nullcontext()
 
     def _stack_groups(self, batches: list) -> dict:
         """Group same-shape batches, stack on a leading axis, stage to HBM.
@@ -492,7 +541,9 @@ class ScanEpochDriver:
         if key not in cache:
             def scan_fn(state, stacked, perm):
                 def step(carry, i):
-                    batch = jax.tree_util.tree_map(lambda x: x[i], stacked)
+                    with jax.named_scope(phases.SCAN):
+                        batch = jax.tree_util.tree_map(
+                            lambda x: x[i], stacked)
                     if train:
                         carry, metrics = body(carry, batch)
                         if self._tap is not None:
@@ -507,10 +558,13 @@ class ScanEpochDriver:
                     return carry, metrics
 
                 state2, ms = jax.lax.scan(step, state, perm)
-                return state2, jax.tree_util.tree_map(
-                    lambda m: m.sum(0), ms
-                )
+                with jax.named_scope(phases.SCAN):
+                    return state2, jax.tree_util.tree_map(
+                        lambda m: m.sum(0), ms
+                    )
 
+            # the name the profiler's module line and the compile log show
+            scan_fn.__name__ = program_name(key, train)
             cache[key] = jax.jit(
                 scan_fn,
                 donate_argnums=TRAIN_STEP_DONATE if train else (),
@@ -651,10 +705,11 @@ class ScanEpochDriver:
         # warmup dispatches run the REAL compiled programs — mute the
         # step stream so compile-time executions don't pollute the
         # per-step record stream
+        tel = self._telemetry
         warm_ctx = (
-            self._telemetry.warmup() if self._telemetry is not None
-            else contextlib.nullcontext()
+            tel.warmup() if tel is not None else contextlib.nullcontext()
         )
+        spans = tel.spans if tel is not None else None
         with warm_ctx:
             for key, stacked in self._train_groups.items():
                 n = int(jax.tree_util.tree_leaves(stacked)[0].shape[0])
@@ -667,10 +722,42 @@ class ScanEpochDriver:
                     perm = jax.device_put(
                         np.arange(ln, dtype=np.int32) % n
                     )
-                    scratch, _ = fn(scratch, stacked, perm)
+                    if spans is None:
+                        scratch, _ = fn(scratch, stacked, perm)
+                        continue
+                    name = program_name((key, ln), True)
+                    with spans.span("warm.program", module=name,
+                                    length=ln) as args:
+                        with _compile_events() as seen:
+                            scratch, _ = fn(scratch, stacked, perm)
+                        args.update(seen)
+                    self._emit_program(spans, fn, name, key, ln,
+                                       (scratch, stacked, perm))
             # eval programs + the pair plumbing compile on a normal epoch
-            self.run_epoch_pair(scratch, first=True)
+            with self._span("warm.epoch"):
+                self.run_epoch_pair(scratch, first=True)
+            if spans is not None:
+                for (key, ln), fn in self._eval_scans.items():
+                    perm = jax.device_put(np.zeros(ln, np.int32))
+                    self._emit_program(
+                        spans, fn, program_name((key, ln), False), key, ln,
+                        (scratch, self._val_groups[key], perm))
         return state
+
+    @staticmethod
+    def _emit_program(spans, fn, name: str, key, length: int,
+                      example: tuple) -> None:
+        """One ``scan.program`` instant: the program's module name and the
+        phase of each of its instructions (observe/phases.py), for whoever
+        reads a device trace of it. The device events of this runtime carry
+        the instruction and no ``op_name``, so the table is read off the
+        compiled module's text; jit holds the executable by now, so nothing
+        compiles here, and the time it takes is a span of its own."""
+        with spans.span("warm.phase_map", module=name):
+            text = fn.lower(*example).compile().as_text()
+            table = phases.phase_table(text)
+        spans.instant("scan.program", module="jit_" + name, key=repr(key),
+                      length=int(length), table=table)
 
     def _drive(self, state: TrainState, groups, scans, body, train, first,
                prebuild: bool = True):
@@ -715,8 +802,11 @@ class ScanEpochDriver:
         dev_sums: dict | None = None
         n_chunks = 0
         executed = 0
-        spans = (self._telemetry.spans
-                 if self._telemetry is not None else None)
+        # warm-up dispatches are not run work: no span for them, as
+        # Telemetry.warmup() already keeps them out of the counters
+        tel = self._telemetry
+        spans = (tel.spans if tel is not None and not tel.warming
+                 else None)
 
         def run_queues(qs, weighted):
             nonlocal state, dev_sums, n_chunks, executed
@@ -746,14 +836,15 @@ class ScanEpochDriver:
                 fn = self._scan_fn(
                     scans, (key, len(chunk)), body, train
                 )
-                t0 = time.perf_counter() if spans is not None else 0.0
-                state, chunk_sums = fn(state, stacked, chunk)
-                if spans is not None:
-                    # host-side dispatch cost per chunk, visible in the
-                    # Chrome trace next to the device timeline (§6c)
-                    spans.complete("scan.chunk", t0, time.perf_counter(),
-                                   steps=int(chunk.shape[0]),
-                                   train=train)
+                if spans is None:
+                    state, chunk_sums = fn(state, stacked, chunk)
+                else:
+                    # the host's side of one dispatch, in trace.json and
+                    # (as cgnn:scan.chunk) on the profiler's clock beside
+                    # the device's launch of the same program
+                    with spans.span("scan.chunk",
+                                    steps=int(chunk.shape[0]), train=train):
+                        state, chunk_sums = fn(state, stacked, chunk)
                 dev_sums = accumulate_on_device(dev_sums, chunk_sums)
                 n_chunks += 1
                 executed += int(chunk.shape[0])
@@ -788,7 +879,6 @@ class ScanEpochDriver:
             + n_chunks
         if self._telemetry is not None:
             self._telemetry.counter_add("scan_steps", executed)
-            self._telemetry.counter_add(f"scan_{phase}_dispatches", n_chunks)
         return state, dev_sums, executed
 
     def train_epoch(self, state: TrainState, first: bool):

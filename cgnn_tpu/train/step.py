@@ -20,6 +20,7 @@ from jax import lax
 
 from cgnn_tpu.data.graph import GraphBatch
 from cgnn_tpu.data.rawbatch import RawBatch
+from cgnn_tpu.observe import phases
 from cgnn_tpu.train.state import TrainState
 
 
@@ -98,8 +99,9 @@ def make_train_step(
                 mutable=["batch_stats"],
                 rngs=rngs,
             )
-            loss, metrics = compute_loss(out, batch, state.normalizer)
-            return loss * loss_scale, (metrics, mutated["batch_stats"])
+            with jax.named_scope(phases.LOSS):
+                loss, metrics = compute_loss(out, batch, state.normalizer)
+                return loss * loss_scale, (metrics, mutated["batch_stats"])
 
         (loss, (metrics, new_stats)), grads = jax.value_and_grad(
             loss_with_aux, has_aux=True
@@ -112,7 +114,8 @@ def make_train_step(
                 grads = lax.pmean(grads, axis_name)
             new_stats = lax.pmean(new_stats, axis_name)
             metrics = lax.psum(metrics, axis_name)
-        new_state = state.apply_gradients(grads, new_stats)
+        with jax.named_scope(phases.OPTIMIZER):
+            new_state = state.apply_gradients(grads, new_stats)
         if grad_health:
             from cgnn_tpu.observe.health import grad_health_metrics
 
@@ -141,7 +144,8 @@ def make_eval_step(
 
     def eval_step(state: TrainState, batch: GraphBatch):
         out = state.apply_fn(state.variables(), batch, train=False)
-        _, metrics = compute_loss(out, batch, state.normalizer)
+        with jax.named_scope(phases.LOSS):
+            _, metrics = compute_loss(out, batch, state.normalizer)
         if axis_name is not None:
             metrics = lax.psum(metrics, axis_name)
         return metrics

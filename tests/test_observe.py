@@ -403,3 +403,293 @@ class TestDataParallelStepStream:
         events = [r for r in read_jsonl(str(tmp_path / "metrics.jsonl"))
                   if r.get("event") == "step" and r.get("phase") == "train"]
         assert len(events) >= n_steps
+
+
+# op_names as XLA records them: the flax-scoped ones are lines of
+# HLO_TRAIN_STEP.txt.gz (a compiled flagship step), the named-scope ones
+# are what today's scan program compiles to
+_SCAN = "jit(scan_train_n672_l2)/while/body/closed_call/"
+_FWD = _SCAN + "jvp(CrystalGraphConvNet)/"
+_BWD = _SCAN + "transpose(jvp(CrystalGraphConvNet))/"
+_CLASSIFIED = [
+    ("jit(train_step)/jvp(CrystalGraphConvNet)/conv_1/bn1/mul",
+     ("conv.bn1", "fwd")),
+    ("jit(train_step)/transpose(jvp(CrystalGraphConvNet))/conv_2/bn1/"
+     "reduce_sum", ("conv.bn1", "bwd")),
+    ("jit(train_step)/jvp(CrystalGraphConvNet)/conv_0/bn2/jit(_where)/"
+     "select_n", ("conv.bn2", "fwd")),
+    (_BWD + "conv_0/bn2/add_any", ("conv.bn2", "bwd")),
+    (_FWD + "conv_1/conv.bn2/jit(softplus)/log1p", ("conv.bn2", "fwd")),
+    ("jit(train_step)/jvp(CrystalGraphConvNet)/conv_0/fc_full/dot_general",
+     ("conv.fc_full", "fwd")),
+    ("jit(train_step)/transpose(jvp(CrystalGraphConvNet))/conv_1/fc_full/"
+     "dot_general", ("conv.fc_full", "bwd")),
+    (_FWD + "conv_0/conv.fc_full/convert_element_type",
+     ("conv.fc_full", "fwd")),
+    (_FWD + "conv_0/conv.gather/jit(_take)/gather", ("conv.gather", "fwd")),
+    (_BWD + "conv_0/conv.gather/reduce_sum", ("conv.gather", "bwd")),
+    (_FWD + "conv_0/conv.gate/exp", ("conv.gate", "fwd")),
+    (_BWD + "conv_0/conv.gate/jit(softplus)/mul", ("conv.gate", "bwd")),
+    (_FWD + "conv_1/conv.aggregate/reduce_sum", ("conv.aggregate", "fwd")),
+    (_BWD + "conv_1/conv.aggregate/broadcast_in_dim",
+     ("conv.aggregate", "bwd")),
+    ("jit(train_step)/jvp(CrystalGraphConvNet)/embedding/"
+     "convert_element_type", ("embed", "fwd")),
+    (_BWD + "embed/embedding/dot_general", ("embed", "bwd")),
+    ("jit(train_step)/jvp(CrystalGraphConvNet)/conv_to_fc/add",
+     ("pool_head", "fwd")),
+    ("jit(train_step)/transpose(jvp(CrystalGraphConvNet))/fc_out/"
+     "dot_general", ("pool_head", "bwd")),
+    (_FWD + "pool_head/fc_0/dot_general", ("pool_head", "fwd")),
+    (_FWD + "pool_head/broadcast_in_dim;scan/squeeze", ("pool_head", "fwd")),
+    (_SCAN + "expand/exp", ("expand", "fwd")),
+    (_SCAN + "jvp(loss)/abs", ("loss", "fwd")),
+    (_SCAN + "transpose(jvp(loss))/div", ("loss", "bwd")),
+    (_SCAN + "optimizer/is_finite", ("optimizer", "fwd")),
+    (_SCAN + "scan/dynamic_slice", ("scan", "fwd")),
+    ("jit(scan_train_n672_l2)/scan/reduce_sum", ("scan", "fwd")),
+    ("jit(scan_train_n672_l2)/while/body/dynamic_update_slice",
+     ("scan", "fwd")),
+    ("jit(scan_train_n672_l2)/while/cond/lt", ("scan", "fwd")),
+    ("jit(scan_train_n672_l2)/while", ("scan", "fwd")),
+    # unscoped: the optimizer and loss of the step before it had scopes,
+    # a reducer's parameters, an argument's name
+    ("jit(train_step)/add", ("other", "fwd")),
+    (_SCAN + "mul", ("other", "fwd")),
+    ("reduce_sum", ("other", "fwd")),
+    ("state.params['conv_0']['bn1']['scale']", ("other", "fwd")),
+]
+
+
+class TestPhases:
+    @pytest.mark.parametrize("op_name,want", _CLASSIFIED)
+    def test_classify(self, op_name, want):
+        from cgnn_tpu.observe import phases
+
+        assert phases.classify(op_name) == want
+        assert want[0] in phases.PHASES
+
+    def test_classified_names_cover_every_phase_both_ways(self):
+        from cgnn_tpu.observe import phases
+
+        seen = {want for _, want in _CLASSIFIED}
+        assert {p for p, _ in seen} == set(phases.PHASES)
+        two_way = {"conv.gather", "conv.fc_full", "conv.bn1", "conv.gate",
+                   "conv.aggregate", "conv.bn2", "embed", "pool_head",
+                   "loss"}
+        assert {p for p, d in seen if d == "bwd"} == two_way
+
+    def test_phase_table_on_a_compiled_program(self):
+        """Instructions of the entry and of the loop body are in the table
+        under the phase of their scope; a fusion takes its root's; what is
+        inside a fusion or a reducer is not an event and not in it."""
+        from cgnn_tpu.observe import phases
+
+        def loss(w, x):
+            with jax.named_scope("Model"):
+                with jax.named_scope("conv.gate"):
+                    y = jnp.tanh(x @ w)
+            with jax.named_scope("loss"):
+                return (y ** 2).sum()
+
+        def scan_tiny(w, xs):
+            def body(c, x):
+                g = jax.grad(loss)(c, x)
+                with jax.named_scope("optimizer"):
+                    return c - 0.1 * g, g.sum()
+            return jax.lax.scan(body, w, xs)
+
+        text = jax.jit(scan_tiny).lower(
+            jnp.ones((4, 4)), jnp.ones((3, 5, 4))).compile().as_text()
+        table = phases.phase_table(text)
+        assert text.startswith("HloModule jit_scan_tiny")
+        found = set(table.values())
+        assert {("conv.gate", "fwd"), ("conv.gate", "bwd"),
+                ("optimizer", "fwd"), ("scan", "fwd")} <= found
+        comps = phases._parse(text)
+        fusions = [n for c in comps.values()
+                   for n, rest in c["instrs"].items() if " fusion(" in rest]
+        assert fusions, "the CPU compiler fuses this program"
+        assert {table[n] for n in fusions if n in table} >= {
+            ("conv.gate", "bwd"), ("optimizer", "fwd"), ("scan", "fwd")}
+        # a fused computation's own instructions are nobody's events
+        inner = {n for cname, c in comps.items() if "fused" in cname
+                 for n in c["instrs"]}
+        assert inner and not inner & set(table)
+        assert all(len(v) == 2 and v[0] in phases.PHASES
+                   and v[1] in ("fwd", "bwd") for v in table.values())
+
+    def test_phase_table_by_hand(self):
+        """TPU-style text: a fusion without a name takes its root's, a
+        tuple root its first named operand's; a prefetch and a layout copy
+        take the phase of what they move (through the layout's own
+        parentheses); what hangs on a parameter alone is ``other``."""
+        from cgnn_tpu.observe import phases
+
+        bn1 = 'metadata={op_name="jit(f)/jvp(M)/conv_0/bn1/mul"}'
+        gate = ('metadata={op_name="jit(f)/transpose(jvp(M))/conv_0/'
+                'conv.gate/mul" stack_frame_id=4}')
+        text = f"""HloModule jit_f, is_scheduled=true
+
+%fused_computation.1 (p.1: f32[4]) -> f32[4] {{
+  %p.1 = f32[4]{{0:T(128)}} parameter(0)
+  ROOT %multiply.3 = f32[4]{{0:T(128)}} multiply(%p.1, %p.1), {bn1}
+}}
+
+%fused_computation.2 (p.2: f32[4]) -> (f32[4], f32[4]) {{
+  %p.2 = f32[4]{{0:T(128)}} parameter(0)
+  %negate.1 = f32[4]{{0:T(128)}} negate(%p.2)
+  %multiply.5 = f32[4]{{0:T(128)}} multiply(%p.2, %p.2), {gate}
+  ROOT %tuple.9 = (f32[4]{{0:T(128)}}, f32[4]{{0:T(128)}}) tuple(%negate.1, %multiply.5)
+}}
+
+ENTRY %main.7 (a.1: f32[4]) -> f32[4] {{
+  %a.1 = f32[4]{{0:T(128)}} parameter(0)
+  %fusion.1 = f32[4]{{0:T(128)}} fusion(%a.1), kind=kLoop, calls=%fused_computation.1
+  %fusion.2 = (f32[4]{{0:T(128)}}, f32[4]{{0:T(128)}}) fusion(%fusion.1), kind=kLoop, calls=%fused_computation.2
+  %get-tuple-element.1 = f32[4]{{0:T(128)}} get-tuple-element(%fusion.2), index=0
+  %copy-start.1 = (f32[4]{{0:T(128)S(1)}}, f32[4]{{0:T(128)}}, u32[]{{:S(2)}}) copy-start(%get-tuple-element.1)
+  %copy-done.1 = f32[4]{{0:T(8,128)(2,1)S(1)}} copy-done(%copy-start.1)
+  %copy.4 = f32[4]{{0:T(8,128)(2,1)}} copy(%fusion.1), backend_config={{"x":"(1)"}}
+  %copy.5 = f32[4]{{0:T(128)}} copy(%a.1)
+  ROOT %add.2 = f32[4]{{0:T(128)}} add(%copy-done.1, %copy.4), metadata={{op_name="jit(f)/add"}}
+}}
+"""
+        assert phases.phase_table(text) == {
+            "a.1": ("other", "fwd"),
+            "fusion.1": ("conv.bn1", "fwd"),
+            "fusion.2": ("conv.gate", "bwd"),
+            "get-tuple-element.1": ("conv.gate", "bwd"),
+            "copy-start.1": ("conv.gate", "bwd"),
+            "copy-done.1": ("conv.gate", "bwd"),
+            "copy.4": ("conv.bn1", "fwd"),
+            "copy.5": ("other", "fwd"),
+            "add.2": ("other", "fwd"),
+        }
+
+    def test_scopes_cover_the_step_and_change_only_metadata(
+            self, tiny_dataset, monkeypatch):
+        """Under 5% of the op_names the traced step gives its instructions
+        fall in ``other``, and the optimized HLO with every named scope
+        taken out (flax's own too) is the same program."""
+        import contextlib
+        import re
+
+        from cgnn_tpu.observe import phases
+        from cgnn_tpu.resilience.guard import guard_step
+
+        train_g, _, _ = tiny_dataset
+        node_cap, edge_cap = capacities_for(train_g, 8)
+        state = _fresh_state(train_g, node_cap, edge_cap)
+        batch = pack_graphs(train_g[:8], node_cap, edge_cap, 8)
+
+        def compiled_text():
+            step = jax.jit(guard_step(make_train_step()))
+            return step.lower(state, batch).compile().as_text()
+
+        scoped = compiled_text()
+        names = [n for n in re.findall(r'op_name="([^"]*)"', scoped)
+                 if n.startswith("jit(")]
+        other = [n for n in names if phases.classify(n)[0] == "other"]
+        assert len(names) > 500
+        assert len(other) < 0.05 * len(names), sorted(set(other))[:20]
+        got = {phases.classify(n) for n in names}
+        assert {"conv.gather", "conv.fc_full", "conv.bn1", "conv.gate",
+                "conv.aggregate", "conv.bn2", "embed", "pool_head", "loss",
+                "optimizer"} <= {p for p, _ in got}
+
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda name: contextlib.nullcontext())
+        bare = compiled_text()
+        assert "conv.gate" in scoped and "conv.gate" not in bare
+
+        def code(text):
+            lines = [re.sub(r",? ?metadata=\{[^}]*\}", "", ln)
+                     for ln in text.splitlines()]
+            return [ln for ln in lines
+                    if ln.startswith((" ", "%", "ENTRY", "}"))]
+
+        assert code(scoped) == code(bare)
+
+
+class TestDriverSpans:
+    def _driver(self, tiny_dataset, telemetry):
+        from cgnn_tpu.resilience.guard import guard_step
+        from cgnn_tpu.train.loop import ScanEpochDriver
+        from cgnn_tpu.train.step import make_eval_step
+
+        train_g, val_g, _ = tiny_dataset
+        node_cap, edge_cap = capacities_for(train_g, 8)
+        state = _fresh_state(train_g, node_cap, edge_cap)
+        pack = lambda gs: pack_graphs(gs, node_cap, edge_cap, 8)  # noqa: E731
+        train_b = [pack(train_g[i:i + 8]) for i in (0, 8, 16)]
+        drv = ScanEpochDriver(
+            guard_step(make_train_step()), make_eval_step(), train_b,
+            [pack(val_g[:8])], np.random.default_rng(0),
+            telemetry=telemetry)
+        return drv, state
+
+    def test_warm_emits_set_up_spans_and_no_chunk(self, tiny_dataset,
+                                                  tmp_path):
+        telemetry = Telemetry("epoch", str(tmp_path / "t"))
+        drv, state = self._driver(tiny_dataset, telemetry)
+        state = drv.warm(state)
+        events = telemetry.spans.events
+        by_name: dict = {}
+        for e in events:
+            by_name.setdefault(e["name"], []).append(e)
+        n_train, n_eval = len(drv._train_scans), len(drv._eval_scans)
+        assert n_train == 2 and n_eval == 1  # lengths {1, 2} of 3 batches
+        assert len(by_name["warm.program"]) == n_train
+        assert len(by_name["warm.phase_map"]) == n_train + n_eval
+        assert len(by_name["warm.epoch"]) == 1
+        assert len(by_name["scan.stage"]) == 1
+        assert "scan.chunk" not in by_name
+        stage = by_name["scan.stage"][0]["args"]
+        assert stage["groups"] == 1 and stage["batches"] == 4
+        assert stage["bytes"] > 0
+        for e in by_name["warm.program"]:
+            assert e["args"]["compiled"] or e["args"]["cache_read"]
+        programs = by_name["scan.program"]
+        modules = sorted(e["args"]["module"] for e in programs)
+        n = next(iter(drv._train_groups))[0][0]
+        assert modules == [f"jit_scan_eval_n{n}_l1", f"jit_scan_train_n{n}_l1",
+                           f"jit_scan_train_n{n}_l2"]
+        for e in programs:
+            assert e["ph"] == "i" and e["args"]["length"] in (1, 2)
+            phases_seen = {v[0] for v in e["args"]["table"].values()}
+            assert {"conv.bn1", "conv.gate", "scan"} <= phases_seen
+        json.dumps(events)  # the ring must stay exportable
+        # the window's dispatches are spans again, one a chunk
+        state, _, _ = drv.run_epoch_pair(state, first=False)
+        chunks = [e for e in telemetry.spans.events
+                  if e["name"] == "scan.chunk"]
+        assert sum(e["args"]["steps"] for e in chunks
+                   if e["args"]["train"]) == 3
+        assert sum(1 for e in chunks if not e["args"]["train"]) == 1
+        assert telemetry.counters()["scan_steps"] == 4
+        telemetry.close()
+
+    def test_without_telemetry_the_dispatch_opens_no_annotation(
+            self, tiny_dataset, tmp_path, monkeypatch):
+        opened = []
+        real = jax.profiler.TraceAnnotation
+
+        class Counting(real):
+            def __init__(self, name, **kw):
+                opened.append(name)
+                super().__init__(name, **kw)
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+        drv, state = self._driver(tiny_dataset, None)
+        state = drv.warm(state)
+        state, _, _ = drv.run_epoch_pair(state, first=False)
+        assert opened == []
+
+        telemetry = Telemetry("epoch", str(tmp_path / "t"))
+        drv, state = self._driver(tiny_dataset, telemetry)
+        state = drv.warm(state)
+        opened.clear()
+        state, _, _ = drv.run_epoch_pair(state, first=False)
+        assert opened and set(opened) == {"cgnn:scan.chunk"}
+        telemetry.close()
