@@ -54,7 +54,9 @@ def check_batch(batch, dense_m: int | None = None):
       inferred from pre-shaped [N, M, G] edges when not given);
     - transpose slots: ``in_slots``/``in_mask`` list every real edge slot
       exactly once under its neighbor node — the completeness property
-      gather_transpose's scatter-free backward silently relies on.
+      gather_transpose's scatter-free backward silently relies on — with
+      each row's real entries first (ops/segment.gather_slot_major masks
+      tier 1 by rank < in-degree).
     """
     if dense_m is None and np.ndim(batch.edges) == 3:
         dense_m = int(np.shape(batch.edges)[1])
@@ -132,6 +134,9 @@ def _check_transpose_mapping(batch, neighbors, real_e, ncap):
         so the completeness contract cannot diverge between the two."""
         if in_mask.shape[0] != ncap:
             _fail(f"{tag}in_slots/in_mask row count != node capacity")
+        if np.any(np.diff(in_mask.astype(np.int8), axis=1) > 0):
+            _fail(f"{tag}in_mask rows are not in-degree prefixes (the "
+                  f"slot-major transpose masks by rank < in-degree)")
         lst = in_slots.reshape(in_mask.shape)[in_mask > 0]
         if lst.size and (lst.min() < 0 or lst.max() >= slot_range):
             _fail(f"{tag}transpose mapping lists a slot outside its "

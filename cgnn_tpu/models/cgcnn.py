@@ -35,6 +35,7 @@ from cgnn_tpu.ops.norm import MaskedBatchNorm
 from cgnn_tpu.ops.segment import (
     aggregate_edge_messages,
     gather,
+    gather_slot_major,
     gather_transpose,
     segment_mean,
 )
@@ -286,23 +287,20 @@ class CGConv(nn.Module):
         elif self.dense_m is not None:
             m = self.dense_m
             n = nodes.shape[0]
-            fdim = nodes.shape[-1]
             with jax.named_scope(phases.CONV_GATHER):
-                if in_slots is not None:
-                    # scatter-free backward via the packed transpose mapping
-                    # (two-tier when the batch carries overflow slots). NOTE:
-                    # a slot-space variant (2-D index gathers keeping both
-                    # directions in [N, M, F]) was tried to kill the relayout
-                    # copies and measured 19% SLOWER end-to-end (17.2 vs 14.5
-                    # ms/step, r3 trace5) — multi-dim gather lowering costs
-                    # more than the copies it saves; keep the flat form.
-                    v_j = gather_transpose(
-                        nodes, neighbors, in_slots, in_mask,
-                        over_slots=over_slots, over_nodes=over_nodes,
-                        over_mask=over_mask,
-                    ).reshape(n, m, fdim)
-                else:
-                    v_j = gather(nodes, neighbors).reshape(n, m, fdim)
+                # rows gathered in the slot-major order fc_full, BN1 and the
+                # gate are laid out in, so no reshape or relayout pass over
+                # [E, F] surrounds the gather in either direction. With the
+                # packed transpose mapping the backward is scatter-free
+                # (two-tier when the batch carries overflow slots); eval
+                # batches carry none and take the same path without one.
+                # (The round-3 "slot-space variant", 19% slower, was another
+                # thing: 2-D index gathers — ops/segment.gather_slot_major.)
+                v_j = gather_slot_major(
+                    nodes, neighbors, m, in_slots, in_mask,
+                    over_slots=over_slots, over_nodes=over_nodes,
+                    over_mask=over_mask,
+                )
             with jax.named_scope(phases.CONV_FC_FULL):
                 # dense batches carry edges pre-shaped [N, M, G] (pack_graphs)
                 e = edges.astype(nodes.dtype)

@@ -33,28 +33,49 @@ _TRANSPOSE_IMPL = "linear_call"
 
 
 def _transpose_cotangent(ct, slots, msk, o_slots, o_nodes, o_mask,
-                         num_nodes: int):
+                         num_nodes: int, degree_axis: int = 1):
     """The shared cotangent transpose ([E, F] -> [N, F]) — ONE body for
-    every AD mechanism (linear_call / custom_vjp) so an A/B isolates the
-    mechanism, never the math.
+    every AD mechanism (linear_call / custom_vjp) and both row orders, so
+    an A/B isolates the mechanism, never the math.
 
+    ``slots`` is flat and ``msk`` says how the gathered rows are viewed:
+    [N, In] (node-major, ``degree_axis`` 1) or [In, N] (slot-major,
+    ``degree_axis`` 0: the sum is In slab adds, no sublane reduce).
     in_slots arrives pre-flattened (pack_graphs): a device-side
-    [N, In] -> [N*In] flatten is a tiled->linear relayout that measured
-    0.75 ms/step under the epoch scan. Accumulation stays in the
-    cotangent dtype: matches the scatter-add's accumulation precision,
-    and an f32 upcast doubles the [N, In, F] intermediate's bytes for no
-    measured accuracy gain (full-step bf16: 16.0 ms vs f32-acc 17.5 ms
-    vs scatter 18.8 ms).
+    [N, In] -> [N*In] flatten of the *gathered rows* is a tiled->linear
+    relayout that measured 0.75 ms/step under the epoch scan.
+    Accumulation stays in the cotangent dtype: matches the scatter-add's
+    accumulation precision, and an f32 upcast doubles the [N, In, F]
+    intermediate's bytes for no measured accuracy gain (full-step bf16:
+    16.0 ms vs f32-acc 17.5 ms vs scatter 18.8 ms).
     """
-    contrib = jnp.take(ct, slots, axis=0).reshape(*msk.shape, ct.shape[-1])
-    grad = (contrib * msk[..., None].astype(ct.dtype)).sum(axis=1)
+    contrib = gather(ct, slots).reshape(*msk.shape, ct.shape[-1])
+    grad = (contrib * msk[..., None].astype(ct.dtype)).sum(axis=degree_axis)
     if o_slots is not None:
-        rows = jnp.take(ct, o_slots, axis=0)
-        rows = rows * o_mask[:, None].astype(ct.dtype)
+        rows = gather(ct, o_slots) * o_mask[:, None].astype(ct.dtype)
         grad = grad + jax.ops.segment_sum(
             rows, o_nodes, num_segments=num_nodes, indices_are_sorted=True,
         )
     return grad
+
+
+def _linear(fwd, trans, res, x):
+    """``fwd(res, x)``, linear in ``x``, with the declared transpose
+    ``trans(res, ct)``, through the selected AD mechanism."""
+    if _TRANSPOSE_IMPL == "custom_vjp":  # round-3 mechanism (A/B only)
+
+        @jax.custom_vjp
+        def g(x):
+            return fwd(res, x)
+
+        g.defvjp(lambda x: (g(x), None), lambda _, ct: (trans(res, ct),))
+        return g(x)
+    return jax.custom_derivatives.linear_call(fwd, trans, res, x)
+
+
+def _gather_rows(res, x):
+    """The forward of both transposable gathers: ``x[res[0]]``."""
+    return gather(x, res[0])
 
 
 def set_transpose_impl(impl: str) -> None:
@@ -75,8 +96,17 @@ def set_default_aggregation_impl(impl: str) -> None:
 
 
 def gather(values: jax.Array, indices: jax.Array) -> jax.Array:
-    """values[indices] — the edge-endpoint gather ([N, F] + [E] -> [E, F])."""
-    return jnp.take(values, indices, axis=0)
+    """values[indices] — the edge-endpoint gather ([N, F] + [E] -> [E, F]).
+
+    ``mode="clip"``: the packers emit only in-range indices (padding slots
+    are self-loops or slot 0; data/invariants.py, pinned by
+    tests/test_batching.py), and ``jnp.take``'s default ``mode="fill"``
+    pays for the case that cannot happen with a ``select_n`` that re-reads
+    and re-writes the whole gathered [E, F] (171 us of an 881 us forward
+    gather phase at E = 287k on v5e; PERF.md §5). Clip is XLA's own clamp
+    of the start index: identical values for every in-range index.
+    """
+    return jnp.take(values, indices, axis=0, mode="clip")
 
 
 def gather_transpose(
@@ -122,33 +152,81 @@ def gather_transpose(
     """
     num_nodes = nodes.shape[0]
 
-    if _TRANSPOSE_IMPL == "custom_vjp":  # round-3 mechanism (A/B only)
-
-        @jax.custom_vjp
-        def g(n):
-            return jnp.take(n, neighbors, axis=0)
-
-        def g_fwd(n):
-            return g(n), None
-
-        def g_bwd(_, ct):
-            return (_transpose_cotangent(ct, in_slots, in_mask, over_slots,
-                                         over_nodes, over_mask, num_nodes),)
-
-        g.defvjp(g_fwd, g_bwd)
-        return g(nodes)
-
-    def fwd(res, n):
-        nbrs = res[0]
-        return jnp.take(n, nbrs, axis=0)
-
     def trans(res, ct):  # ct: [E, F] -> [N, F]
-        _, slots, msk, o_slots, o_nodes, o_mask = res
-        return _transpose_cotangent(ct, slots, msk, o_slots, o_nodes,
-                                    o_mask, num_nodes)
+        return _transpose_cotangent(ct, *res[1:], num_nodes)
 
     res = (neighbors, in_slots, in_mask, over_slots, over_nodes, over_mask)
-    return jax.custom_derivatives.linear_call(fwd, trans, res, nodes)
+    return _linear(_gather_rows, trans, res, nodes)
+
+
+def gather_slot_major(
+    nodes: jax.Array,  # [N, F]
+    neighbors: jax.Array,  # [N*M] i32, dense layout: node n owns [n*M, (n+1)*M)
+    dense_m: int,
+    in_slots: jax.Array | None = None,  # as gather_transpose; None -> plain AD
+    in_mask: jax.Array | None = None,
+    over_slots: jax.Array | None = None,
+    over_nodes: jax.Array | None = None,
+    over_mask: jax.Array | None = None,
+) -> jax.Array:
+    """``nodes[neighbors]`` as [N, M, F], gathered in SLOT-MAJOR row order.
+
+    Every [N, M, .] tensor of the dense conv is laid out slot-major by the
+    TPU compiler (M outermost: fc_full's matmul, BN1 and the gate read
+    [M][N][F] slabs), while the flat node-major gather (``e = n*M + m``)
+    hands it [N*M, F] rows: each direction then pays a reshape that is a
+    real relayout and a transposing copy around the gather. Here the
+    *indices* are transposed instead (an [E] s32 transpose, identical for
+    every conv of a step, so XLA computes it once): the gather's
+    [M*N, F] output already is the [M][N][F] block, its view as
+    [M, N, F] is a bitcast, and the ``moveaxis`` back to the model's
+    logical [N, M, F] is a choice of layout, not a pass.
+
+    The transpose (given ``in_slots``; same contract and two-tier mapping
+    as ``gather_transpose``) mirrors it: the cotangent arrives flattened
+    slot-major, ``in_slots``/``over_slots`` are renumbered on the device
+    (flat slot ``s = n*M + m`` sits at ``m*N + n``), the gathered
+    [In*N, F] is viewed [In, N, F] and the masked sum runs over the OUTER
+    axis. The forward is bit-identical to ``gather_transpose`` (the same
+    rows); the backward sums the same terms in another association.
+
+    This is NOT the round-3 "slot-space variant" that measured 19% slower
+    (17.2 vs 14.5 ms/step, r3 trace5): that one gathered with
+    two-dimensional (node, slot) indices, which changed the gather's
+    lowering. This keeps the flat one-dimensional row gather and changes
+    only the order of its indices.
+
+    Callers that hold the flat [E, F] form (the sharded dense branch, the
+    COO branch, pallas_cgconv's structured twin) keep ``gather`` /
+    ``gather_transpose``.
+    """
+    n, m = nodes.shape[0], dense_m
+
+    def to_slot_major(slots):  # flat node-major slot ids -> slot-major
+        return (slots % m) * n + slots // m
+
+    def view(flat):  # [M*N, F] -> logical [N, M, F]
+        return jnp.moveaxis(flat.reshape(m, n, flat.shape[-1]), 0, 1)
+
+    nbrs_t = neighbors.reshape(n, m).T.reshape(-1)
+    if in_slots is None:  # forward-only batches carry no transpose mapping
+        return view(gather(nodes, nbrs_t))
+    # tier-1 entries reordered [N, In] -> [In, N] as well as renumbered
+    slots_t = to_slot_major(in_slots.reshape(in_mask.shape).T.reshape(-1))
+    o_slots_t = None if over_slots is None else to_slot_major(over_slots)
+    # the [In, N] mask is ``rank < in-degree``: a row's real entries are a
+    # prefix (graph.transpose_slots; data/invariants.py checks it). Not
+    # ``in_mask.T``: for that XLA relayouts the whole STACKED u8 mask of a
+    # scan program once a launch (0.63 ms at [594, 23944, 12] on v5e,
+    # PERF.md §6 PR 25), where this reads it as staged.
+    in_degree = in_mask.astype(jnp.int32).sum(axis=1)
+    mask_t = jnp.arange(in_mask.shape[1])[:, None] < in_degree[None, :]
+
+    def trans(res, ct):  # ct: [M*N, F] slot-major -> [N, F]
+        return _transpose_cotangent(ct, *res[1:], n, degree_axis=0)
+
+    res = (nbrs_t, slots_t, mask_t, o_slots_t, over_nodes, over_mask)
+    return view(_linear(_gather_rows, trans, res, nodes))
 
 
 def segment_sum(data: jax.Array, segment_ids: jax.Array, num_segments: int) -> jax.Array:

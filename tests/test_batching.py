@@ -2,6 +2,7 @@
 SURVEY.md §5 long-context analog)."""
 
 import numpy as np
+import pytest
 
 from cgnn_tpu.data.dataset import (
     FeaturizeConfig,
@@ -359,16 +360,23 @@ def test_per_bucket_in_cap_tracks_bucket_skew():
     assert min(caps) < global_cap  # ...and the other bucket does not
 
 
-def test_two_tier_transpose_backward_matches_plain_gather():
+@pytest.mark.parametrize("form", ["flat", "slot_major"])
+def test_two_tier_transpose_backward_matches_plain_gather(form):
     """Two-tier (tier-1 [N, M] + overflow COO) gather_transpose gradients
     == plain-gather gradients through a full CGConv-like masked consumer,
-    on graphs whose in-degree exceeds dense_m (overflow populated)."""
+    on graphs whose in-degree exceeds dense_m (overflow populated) and
+    whose batch is padded — in the flat node-major form and in the
+    slot-major form the unsharded dense conv takes (gather_slot_major)."""
     import jax
     import jax.numpy as jnp
 
     from cgnn_tpu.data.dataset import load_synthetic_mp
     from cgnn_tpu.data.graph import batch_iterator, capacities_for
-    from cgnn_tpu.ops.segment import gather, gather_transpose
+    from cgnn_tpu.ops.segment import (
+        gather,
+        gather_slot_major,
+        gather_transpose,
+    )
 
     cfg = FeaturizeConfig(radius=6.0, max_num_nbr=12)
     graphs = load_synthetic_mp(64, cfg, seed=3)
@@ -376,27 +384,146 @@ def test_two_tier_transpose_backward_matches_plain_gather():
     b = next(batch_iterator(graphs, 32, nc, ec, dense_m=12, snug=True))
     assert b.over_slots is not None
     assert int(np.asarray(b.over_mask).sum()) > 0, "no overflow exercised"
+    assert int(np.asarray(b.edge_mask).sum()) < b.edge_capacity, "no padding"
 
     nodes = jnp.asarray(
         np.random.default_rng(0).normal(size=(b.node_capacity, 16))
     ).astype(jnp.float32)
-    emask = jnp.asarray(b.edge_mask)
+    emask = jnp.asarray(b.edge_mask).reshape(-1, 12, 1)
+    # a consumer that weighs every slot differently: an order mix-up
+    # between the forms cannot cancel
+    weight = jnp.asarray(np.random.default_rng(1).normal(
+        size=(b.node_capacity, 12, 16))).astype(jnp.float32)
+    mapping = tuple(jnp.asarray(x) for x in (
+        b.in_slots, b.in_mask, b.over_slots, b.over_nodes, b.over_mask))
 
     def loss_two_tier(n):
-        v_j = gather_transpose(
-            n, jnp.asarray(b.neighbors), jnp.asarray(b.in_slots),
-            jnp.asarray(b.in_mask), jnp.asarray(b.over_slots),
-            jnp.asarray(b.over_nodes), jnp.asarray(b.over_mask),
-        )
-        return ((v_j * emask[:, None]) ** 2).sum()
+        if form == "flat":
+            v_j = gather_transpose(n, jnp.asarray(b.neighbors), *mapping)
+            v_j = v_j.reshape(-1, 12, 16)
+        else:
+            v_j = gather_slot_major(n, jnp.asarray(b.neighbors), 12, *mapping)
+        return ((v_j * emask * weight) ** 2).sum()
 
     def loss_plain(n):
-        v_j = gather(n, jnp.asarray(b.neighbors))
-        return ((v_j * emask[:, None]) ** 2).sum()
+        v_j = gather(n, jnp.asarray(b.neighbors)).reshape(-1, 12, 16)
+        return ((v_j * emask * weight) ** 2).sum()
 
+    np.testing.assert_array_equal(  # the same rows: bit-identical forward
+        np.asarray(loss_two_tier(nodes)), np.asarray(loss_plain(nodes)))
     g1 = jax.grad(loss_two_tier)(nodes)
     g2 = jax.grad(loss_plain)(nodes)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), atol=1e-5)
+
+
+def _packed_by(packer):
+    """One packed batch of every packer, as the model receives it."""
+    import jax
+
+    from cgnn_tpu.data.dataset import load_synthetic_mp
+
+    cfg = FeaturizeConfig(radius=6.0, max_num_nbr=12)
+    if packer == "neighbor_search":  # built inside the program (raw wire)
+        from cgnn_tpu.data.dataset import featurize_structure
+        from cgnn_tpu.data.rawbatch import (
+            RawStructure,
+            pack_raw,
+            plan_raw_spec,
+        )
+        from cgnn_tpu.data.synthetic import synthetic_dataset
+        from cgnn_tpu.ops.neighbor_search import make_raw_expander
+
+        items = synthetic_dataset(6, seed=3)
+        graphs = [featurize_structure(s, t, cfg, sid, keep_geometry=True)
+                  for sid, s, t in items]
+        spec = plan_raw_spec(graphs, cfg.gdf(), cfg.radius, 12)
+        raws = [RawStructure.from_structure(s, t, sid) for sid, s, t in items]
+        # 8 slots for 6 structures: two padding structures
+        return jax.jit(make_raw_expander(spec))(pack_raw(raws, 8, spec))[0]
+    graphs = load_synthetic_mp(48, cfg, seed=3)
+    if packer == "coo":
+        nc, ec = capacities_for(graphs, 32)
+        return next(batch_iterator(graphs, 32, nc, ec))
+    nc, ec = capacities_for(graphs, 32, dense_m=12, snug=True)
+    if packer == "dense":
+        return next(batch_iterator(graphs, 32, nc, ec, dense_m=12, snug=True))
+    from cgnn_tpu.data.compact import (
+        CompactSpec,
+        compact_pack_fn,
+        make_expander,
+    )
+
+    spec = CompactSpec.build(graphs, cfg.gdf(), dense_m=12)
+    comp = next(batch_iterator(graphs, 32, nc, ec, dense_m=12, snug=True,
+                               pack_fn=compact_pack_fn(spec)))
+    return jax.jit(make_expander(spec))(comp)
+
+
+@pytest.mark.parametrize(
+    "packer", ["dense", "coo", "compact", "neighbor_search"])
+def test_packed_indices_are_in_range(packer):
+    """ops/segment.gather promises XLA in-range indices (``mode="clip"``
+    pays for no out-of-range select): every index array a packer hands
+    the model addresses a row that exists, padding entries included."""
+    b = _packed_by(packer)
+    n, e = b.node_capacity, b.edge_capacity
+    bounds = {"centers": n, "neighbors": n, "node_graph": b.graph_capacity,
+              "in_slots": e, "over_slots": e, "over_nodes": n}
+    checked = 0
+    for name, bound in bounds.items():
+        idx = getattr(b, name)
+        if idx is None:
+            continue
+        idx = np.asarray(idx)
+        assert idx.size and idx.min() >= 0 and idx.max() < bound, (
+            packer, name, int(idx.min()), int(idx.max()), bound)
+        checked += 1
+    assert checked >= 3
+    if packer in ("dense", "compact"):  # the train packers carry the mapping
+        assert b.in_slots is not None and b.over_slots is not None
+
+
+def _select_under_take(jaxpr, under=False) -> int:
+    """``select_n`` equations inside a ``jit(_take)`` of a jaxpr, at any
+    depth: what ``jnp.take``'s default ``mode="fill"`` leaves behind."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        inside = under or eqn.params.get("name") == "_take"
+        if under and eqn.primitive.name == "select_n":
+            n += 1
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _select_under_take(sub, inside)
+    return n
+
+
+@pytest.mark.parametrize("layout", ["dense", "coo"])
+def test_train_step_gathers_pay_no_out_of_range_select(layout):
+    """No ``_take`` of the train step carries a ``select_n``: on the chip
+    each one re-reads and re-writes a whole gathered [E, F] (PERF.md §5)."""
+    import jax
+    import jax.numpy as jnp
+
+    from cgnn_tpu.models import CrystalGraphConvNet
+    from cgnn_tpu.train import Normalizer, create_train_state, make_optimizer
+    from cgnn_tpu.train.step import make_train_step
+
+    # the counter sees what it is meant to see
+    fill = jax.make_jaxpr(lambda x, i: jnp.take(x, i, axis=0))(
+        jnp.ones((4, 3)), jnp.arange(2))
+    assert _select_under_take(fill.jaxpr) == 1
+
+    b = _packed_by(layout)
+    model = CrystalGraphConvNet(atom_fea_len=16, n_conv=2, h_fea_len=16,
+                                dense_m=12 if layout == "dense" else None)
+    state = create_train_state(
+        model, b, make_optimizer(optim="sgd", lr=0.01),
+        Normalizer(mean=np.zeros(1, np.float32), std=np.ones(1, np.float32)),
+    )
+    step = jax.make_jaxpr(make_train_step())(state, b)
+    assert _select_under_take(step.jaxpr) == 0
 
 
 def test_bf16_edge_storage_packs_validates_and_trains():

@@ -48,6 +48,13 @@ def test_clean_batches_validate(dense_batch):
         (lambda b: b.replace(
             in_slots=np.zeros_like(np.asarray(b.in_slots))),
          "transpose|twice"),
+        # the same complete mapping with every row's entries reversed:
+        # real entries are no longer a prefix of their row
+        (lambda b: b.replace(
+            in_slots=np.asarray(b.in_slots).reshape(
+                np.shape(b.in_mask))[:, ::-1].reshape(-1).copy(),
+            in_mask=np.asarray(b.in_mask)[:, ::-1].copy()),
+         "in-degree prefixes"),
     ],
 )
 def test_corruptions_fail_loudly(dense_batch, corrupt, match):

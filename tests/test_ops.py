@@ -10,6 +10,8 @@ import torch
 from cgnn_tpu.ops.norm import MaskedBatchNorm
 from cgnn_tpu.ops.segment import (
     aggregate_edge_messages,
+    gather,
+    gather_slot_major,
     segment_mean,
     segment_sum,
 )
@@ -43,6 +45,72 @@ class TestSegmentOps:
             jnp.asarray(msgs), jnp.asarray(centers), 16, impl=impl
         )
         np.testing.assert_allclose(got, base, rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("mapped", [False, True])
+    def test_gather_slot_major_is_the_plain_gather_bit_for_bit(
+            self, dtype, mapped):
+        """Slot-major row order changes which row is gathered WHEN, never
+        which: the [N, M, F] result is ``nodes[neighbors]`` exactly."""
+        n, m, f = 40, 6, 8
+        nodes, nbrs, mapping = _dense_gather_case(n, m, f, dtype)
+        got = gather_slot_major(nodes, nbrs, m, *(mapping if mapped else ()))
+        want = nodes[nbrs].reshape(n, m, f)
+        assert got.shape == (n, m, f) and got.dtype == dtype
+        np.testing.assert_array_equal(
+            np.asarray(got.astype(jnp.float32)),
+            np.asarray(want.astype(jnp.float32)))
+
+    def test_gather_slot_major_grad_over_grad(self):
+        """The force task differentiates the positions gradient again
+        (grad-over-grad through linear_call): second-order AD through
+        the slot-major transpose == through the plain gather."""
+        n, m, f = 24, 4, 5
+        nodes, nbrs, mapping = _dense_gather_case(n, m, f, jnp.float32)
+        w = jnp.asarray(np.random.default_rng(7).normal(
+            size=(n, m, f)).astype(np.float32))
+
+        def energy(fn, x, scale):
+            return (jnp.tanh(fn(x * scale)) * w).sum()
+
+        def second_order(fn):
+            inner = jax.grad(lambda x, s: energy(fn, x, s))
+            return jax.grad(lambda s: (inner(nodes, s) ** 2).sum())(
+                jnp.float32(0.7))
+
+        got = second_order(
+            lambda x: gather_slot_major(x, nbrs, m, *mapping))
+        want = second_order(lambda x: gather(x, nbrs).reshape(n, m, f))
+        np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+
+
+def _dense_gather_case(n, m, f, dtype):
+    """Random dense-layout neighbours with their exact transpose mapping:
+    tier 1 holds each node's first ``m`` in-edges, the rest overflow
+    (node-sorted), both padded with masked slot-0 entries."""
+    rng = np.random.default_rng(n * m + f)
+    nbrs = rng.integers(0, n, size=n * m).astype(np.int32)
+    in_slots = np.zeros((n, m), np.int32)
+    in_mask = np.zeros((n, m), np.float32)
+    over = []
+    fill = np.zeros(n, int)
+    for slot, j in enumerate(nbrs):
+        if fill[j] < m:
+            in_slots[j, fill[j]] = slot
+            in_mask[j, fill[j]] = 1.0
+            fill[j] += 1
+        else:
+            over.append((j, slot))
+    assert over, "no overflow exercised"
+    over.sort()
+    pad = 3
+    o_nodes = np.array([j for j, _ in over] + [n - 1] * pad, np.int32)
+    o_slots = np.array([s for _, s in over] + [0] * pad, np.int32)
+    o_mask = np.array([1.0] * len(over) + [0.0] * pad, np.float32)
+    nodes = jnp.asarray(rng.normal(size=(n, f)).astype(np.float32)).astype(dtype)
+    mapping = tuple(jnp.asarray(x) for x in (
+        in_slots.reshape(-1), in_mask, o_slots, o_nodes, o_mask))
+    return nodes, jnp.asarray(nbrs), mapping
 
 
 class TestPallasSegmentSum:
