@@ -194,18 +194,40 @@ def load_trajectory(
     """
     from cgnn_tpu.data.synthetic import synthetic_trajectory
 
+    return _trajectory_graphs(
+        synthetic_trajectory(num_frames, seed=seed, num_atoms=num_atoms,
+                             jitter=jitter), cfg)
+
+
+def _trajectory_graphs(
+    frames, cfg: FeaturizeConfig | None
+) -> list[CrystalGraph]:
+    """[(id, Structure, energy, forces)] -> graphs with geometry and force
+    labels kept (what the force model reads)."""
     cfg = cfg or FeaturizeConfig()
     gdf = cfg.gdf()
     graphs = []
-    for sid, s, energy, forces in synthetic_trajectory(
-        num_frames, seed=seed, num_atoms=num_atoms, jitter=jitter
-    ):
+    for sid, s, energy, forces in frames:
         g = featurize_structure(
             s, energy, cfg, sid, gdf, keep_geometry=True
         )
         g.forces = forces.astype(np.float32)
         graphs.append(g)
     return graphs
+
+
+def load_synthetic_md17(
+    num_frames: int,
+    cfg: FeaturizeConfig | None = None,
+    seed: int = 0,
+) -> list[CrystalGraph]:
+    """MD17's real shape (BASELINE config #5): frames of one aspirin-sized
+    molecule, 21 atoms, each with a total energy and 21 x 3 forces
+    (``synthetic.synthetic_md17``). Geometry and force labels are always
+    kept: the force model reads nothing else."""
+    from cgnn_tpu.data.synthetic import synthetic_md17
+
+    return _trajectory_graphs(synthetic_md17(num_frames, seed=seed), cfg)
 
 
 def train_val_test_split(

@@ -377,10 +377,30 @@ class CGConv(nn.Module):
                 agg, mask=node_mask, use_running_average=not train
             )
         # the residual and its softplus belong to bn2's phase: they read
-        # its output once more and nothing else
-        with jax.named_scope(phases.CONV_BN2):
+        # its output once more and nothing else. Without BatchNorm (the
+        # force model) what they read is the aggregate's
+        with jax.named_scope(
+            phases.CONV_BN2 if self.use_batchnorm else phases.CONV_AGGREGATE
+        ):
             out = nn.softplus(nodes + agg)
             return out * node_mask[:, None].astype(out.dtype)
+
+
+def masked_atom_features(batch: GraphBatch, dtype) -> jax.Array:
+    """This step's atom features in the trunk's dtype, masked BEFORE the cast.
+
+    Under full staging the epoch scan slices one batch a step off the
+    resident stack. A bare ``batch.nodes.astype(dtype)`` (or, in float32,
+    the TPU compiler's own conversion of a matmul's operands) is moved
+    before that slice, found loop-invariant and hoisted: a convert of the
+    whole stack once a launch (bf16[832, 5400, 92], 1.9 ms a step, 23% of
+    the force step on the v5e; PERF.md section 6, PR 27). The multiply by
+    this step's mask depends on the slice, so the cast stays behind it.
+    That is the compiler's behaviour, not a contract:
+    tests/test_tpu_compile.py compiles for the described chip and fails if
+    anything the size of the stack is computed.
+    """
+    return (batch.nodes * batch.node_mask[:, None]).astype(dtype)
 
 
 class CrystalGraphConvNet(nn.Module):
@@ -416,7 +436,7 @@ class CrystalGraphConvNet(nn.Module):
         with jax.named_scope(phases.EMBED):
             nodes = nn.Dense(
                 self.atom_fea_len, dtype=self.dtype, name="embedding"
-            )(batch.nodes.astype(self.dtype))
+            )(masked_atom_features(batch, self.dtype))
             nodes = nodes * batch.node_mask[:, None].astype(nodes.dtype)
         for i in range(self.n_conv):
             nodes = CGConv(

@@ -13,6 +13,7 @@ The conv trunk reuses CGConv; only the edge featurization moves in-model.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any
 
 import jax
@@ -20,8 +21,9 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from cgnn_tpu.data.graph import GraphBatch
-from cgnn_tpu.models.cgcnn import CGConv
+from cgnn_tpu.models.cgcnn import CGConv, masked_atom_features
 from cgnn_tpu.models.heads import ForceHead
+from cgnn_tpu.observe import phases
 from cgnn_tpu.ops.segment import segment_sum
 
 
@@ -77,46 +79,76 @@ class ForceFieldCGCNN(nn.Module):
         """
         if positions is None:
             positions = batch.positions
-        d = edge_distances(batch, positions)
-        edge_fea = gaussian_expand(
-            d.astype(self.dtype), self.dmin, self.dmax, self.step
-        ) * batch.edge_mask[:, None].astype(self.dtype)
-        nodes = nn.Dense(self.atom_fea_len, dtype=self.dtype, name="embedding")(
-            batch.nodes.astype(self.dtype)
+        # float32 means float32: left to its default the TPU rounds the
+        # operands of a float32 matmul to bfloat16, and the forces, a
+        # derivative of the energies, and the gradient through them, a
+        # second one, then read like the bfloat16 trunk's (0.5-0.9% off the
+        # float32 forces against 0.8-1.6%; PERF.md section 2, PR 27). So too
+        # the image shifts of edge_distances: a 17 A lattice vector rounded
+        # to bfloat16 is 0.03 A off. Every dot traced in here, and the
+        # reverse passes' transposes of it, carry the precision.
+        precise = (
+            jax.default_matmul_precision("highest")
+            if jnp.dtype(self.dtype) == jnp.float32
+            else contextlib.nullcontext()
         )
-        nodes = nodes * batch.node_mask[:, None].astype(nodes.dtype)
-        for i in range(self.n_conv):
-            nodes = CGConv(
-                features=self.atom_fea_len,
-                dtype=self.dtype,
-                aggregation_impl=self.aggregation_impl,
-                # BatchNorm breaks train/eval force consistency (see CGConv)
-                use_batchnorm=False,
-                dense_m=self.dense_m,
-                name=f"conv_{i}",
-            )(
-                nodes,
-                edge_fea,
-                batch.centers,
-                batch.neighbors,
-                batch.edge_mask,
-                batch.node_mask,
-                train=train,
-                # dense two-tier transpose slots (None on COO / in_cap=0
-                # batches -> CGConv falls back to the plain gather)
-                in_slots=batch.in_slots,
-                in_mask=batch.in_mask,
-                over_slots=batch.over_slots,
-                over_nodes=batch.over_nodes,
-                over_mask=batch.over_mask,
-            )
-        atom_energy = ForceHead(h_fea_len=self.h_fea_len, dtype=self.dtype)(
-            nodes, batch.node_mask
-        )
-        per_graph = segment_sum(
-            atom_energy.astype(jnp.float32), batch.node_graph, batch.graph_capacity
-        )
-        return per_graph * batch.graph_mask
+        with precise:
+            with jax.named_scope(phases.EDGE_GEOM):
+                # geometry in the positions' own float32, the cast to the
+                # trunk's dtype last: a distance rounded to bfloat16 first
+                # (1/32 A apart between 4 and 8 A, against filters 0.2 A
+                # wide) puts every Gaussian, and the force read off its
+                # slope, at another distance than the frame's
+                d = edge_distances(batch, positions)
+                edge_mask = batch.edge_mask
+                if self.dense_m is not None:
+                    # the conv reads [N, M, K]: the [E] distances take
+                    # that shape before they are expanded, so the relayout
+                    # is of one float an edge, once a step, not of K a conv
+                    d = d.reshape(-1, self.dense_m)
+                    edge_mask = edge_mask.reshape(-1, self.dense_m)
+                edge_fea = gaussian_expand(d, self.dmin, self.dmax, self.step)
+                edge_fea = (edge_fea * edge_mask[..., None]).astype(self.dtype)
+            with jax.named_scope(phases.EMBED):
+                nodes = nn.Dense(
+                    self.atom_fea_len, dtype=self.dtype, name="embedding"
+                )(masked_atom_features(batch, self.dtype))
+                nodes = nodes * batch.node_mask[:, None].astype(nodes.dtype)
+            for i in range(self.n_conv):
+                nodes = CGConv(
+                    features=self.atom_fea_len,
+                    dtype=self.dtype,
+                    aggregation_impl=self.aggregation_impl,
+                    # BatchNorm breaks train/eval force consistency (see
+                    # CGConv)
+                    use_batchnorm=False,
+                    dense_m=self.dense_m,
+                    name=f"conv_{i}",
+                )(
+                    nodes,
+                    edge_fea,
+                    batch.centers,
+                    batch.neighbors,
+                    batch.edge_mask,
+                    batch.node_mask,
+                    train=train,
+                    # dense two-tier transpose slots (None on COO / in_cap=0
+                    # batches -> CGConv falls back to the plain gather)
+                    in_slots=batch.in_slots,
+                    in_mask=batch.in_mask,
+                    over_slots=batch.over_slots,
+                    over_nodes=batch.over_nodes,
+                    over_mask=batch.over_mask,
+                )
+            with jax.named_scope(phases.FORCE_READOUT):
+                atom_energy = ForceHead(
+                    h_fea_len=self.h_fea_len, dtype=self.dtype
+                )(nodes, batch.node_mask)
+                per_graph = segment_sum(
+                    atom_energy.astype(jnp.float32), batch.node_graph,
+                    batch.graph_capacity,
+                )
+                return per_graph * batch.graph_mask
 
 
 def energy_and_forces(

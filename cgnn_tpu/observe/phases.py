@@ -5,8 +5,8 @@ says nothing to a reader of the model. The compiled program knows better:
 every instruction's metadata carries the ``op_name`` JAX built from the name
 stack — flax module scopes (``conv_1/bn1``), transforms (``jvp``,
 ``transpose``) and the ``jax.named_scope`` s the program adds where flax says
-nothing (models/cgcnn.py, train/step.py, resilience/guard.py,
-data/compact.py, train/loop.py). ``classify`` maps such a path to one phase
+nothing (models/cgcnn.py, models/forcefield.py, train/step.py,
+train/force_step.py, resilience/guard.py, data/compact.py, train/loop.py). ``classify`` maps such a path to one phase
 of ``PHASES`` and a direction; ``phase_table`` does so for every instruction
 of an optimized HLO module that the device can report as an event.
 
@@ -24,6 +24,7 @@ import re
 # vocabulary is read off flax module names below
 EXPAND = "expand"
 EMBED = "embed"
+EDGE_GEOM = "edge_geom"  # models/forcefield.py: positions -> edge features
 CONV_GATHER = "conv.gather"
 CONV_FC_FULL = "conv.fc_full"
 CONV_BN1 = "conv.bn1"
@@ -31,14 +32,16 @@ CONV_GATE = "conv.gate"
 CONV_AGGREGATE = "conv.aggregate"
 CONV_BN2 = "conv.bn2"
 POOL_HEAD = "pool_head"
+FORCE_READOUT = "force_readout"  # models/forcefield.py: per-atom energies
 LOSS = "loss"
 OPTIMIZER = "optimizer"
 SCAN = "scan"
 OTHER = "other"
 
-PHASES = (EXPAND, EMBED, CONV_GATHER, CONV_FC_FULL, CONV_BN1, CONV_GATE,
-          CONV_AGGREGATE, CONV_BN2, POOL_HEAD, LOSS, OPTIMIZER, SCAN, OTHER)
-FWD, BWD = "fwd", "bwd"
+PHASES = (EXPAND, EMBED, EDGE_GEOM, CONV_GATHER, CONV_FC_FULL, CONV_BN1,
+          CONV_GATE, CONV_AGGREGATE, CONV_BN2, POOL_HEAD, FORCE_READOUT, LOSS,
+          OPTIMIZER, SCAN, OTHER)
+FWD, BWD, BWD2 = "fwd", "bwd", "bwd2"
 
 # a path component -> its phase: the named scopes themselves, and the flax
 # module names of models/cgcnn.py (bn1/bn2/fc_full exist only inside a conv)
@@ -59,13 +62,29 @@ def classify(op_name: str) -> tuple[str, str]:
 
     The innermost component that names a phase wins. A path that is only the
     loop's own machinery (``jit(f)/while/body/dynamic_slice``) is ``scan``;
-    anything else without a phase is ``other``. ``bwd`` is a path through a
-    ``transpose(`` — the backward pass of reverse-mode autodiff.
+    anything else without a phase is ``other``.
+
+    The direction counts the ``transpose(`` s on the path, the reverse passes
+    of autodiff the instruction lies under: none is ``fwd``, one is ``bwd``,
+    two or more ``bwd2``. A first-order step has the first two. The force
+    step (train/force_step.py) differentiates twice, and its paths read
+
+        jvp(jvp(Model))                        fwd   the energies
+        jvp(transpose(jvp(Model)))             bwd   the inner reverse pass:
+                                                     the forces, -dE/dx
+        transpose(jvp(jvp(Model)))             bwd   the outer reverse pass
+                                                     over the energies
+        transpose(jvp(transpose(jvp(Model))))  bwd2  the outer reverse pass
+                                                     over the forces
+
+    so ``bwd`` there is every first derivative and ``bwd2`` is the second
+    derivative, which only a loss on the forces pays for. (jnp's own
+    ``transpose`` primitive has no parenthesis and counts as nothing.)
     """
     # XLA joins the names of instructions it merged with ';': the first is
     # the one the merged instruction mostly is
     op_name = op_name.partition(";")[0]
-    direction = BWD if "transpose(" in op_name else FWD
+    direction = (FWD, BWD, BWD2)[min(op_name.count("transpose("), 2)]
     # jit(f)/transpose(jvp(Model))/conv_1/bn1/mul -> components
     parts = [t for t in re.split(r"[/()]", op_name) if t]
     for tok in reversed(parts):

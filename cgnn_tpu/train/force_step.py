@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from cgnn_tpu.data.graph import GraphBatch
+from cgnn_tpu.observe import phases
 from cgnn_tpu.train.state import TrainState
 
 
@@ -104,11 +105,13 @@ def make_force_train_step(
             energies, grad_pos, new_stats = _energy_and_grad_pos(
                 state.apply_fn, variables, batch, train=True
             )
-            forces = -grad_pos * batch.node_mask[:, None]
-            loss, metrics = force_loss(
-                energies, forces, batch, state.normalizer, w_energy, w_force
-            )
-            return loss, (metrics, new_stats)
+            with jax.named_scope(phases.LOSS):
+                forces = -grad_pos * batch.node_mask[:, None]
+                loss, metrics = force_loss(
+                    energies, forces, batch, state.normalizer, w_energy,
+                    w_force,
+                )
+                return loss, (metrics, new_stats)
 
         (loss, (metrics, new_stats)), grads = jax.value_and_grad(
             loss_with_aux, has_aux=True
@@ -117,7 +120,8 @@ def make_force_train_step(
             grads = lax.pmean(grads, axis_name)
             new_stats = lax.pmean(new_stats, axis_name)
             metrics = lax.psum(metrics, axis_name)
-        new_state = state.apply_gradients(grads, new_stats)
+        with jax.named_scope(phases.OPTIMIZER):
+            new_state = state.apply_gradients(grads, new_stats)
         if grad_health:
             from cgnn_tpu.observe.health import grad_health_metrics
 
@@ -146,10 +150,11 @@ def make_force_eval_step(
         energies, grad_pos, _ = _energy_and_grad_pos(
             state.apply_fn, state.variables(), batch, train=False
         )
-        forces = -grad_pos * batch.node_mask[:, None]
-        _, metrics = force_loss(
-            energies, forces, batch, state.normalizer, w_energy, w_force
-        )
+        with jax.named_scope(phases.LOSS):
+            forces = -grad_pos * batch.node_mask[:, None]
+            _, metrics = force_loss(
+                energies, forces, batch, state.normalizer, w_energy, w_force
+            )
         if axis_name is not None:
             metrics = lax.psum(metrics, axis_name)
         return metrics

@@ -381,9 +381,20 @@ def program_name(key, train: bool) -> str:
             f"_l{int(length)}")
 
 
+def staged_edge_fea_nbytes(batches) -> int:
+    """Of ``staged_nbytes``, the Gaussian-expanded edge features (the
+    ``edges`` field; none under compact staging, which stages distances).
+    The force model recomputes them from positions and never reads the
+    staged ones (models/forcefield.py), so under full staging this is the
+    share of its resident set that is dead."""
+    return sum(int(b.edges.nbytes) for b in batches
+               if getattr(b, "edges", None) is not None)
+
+
 def _staging_args(batches: list) -> dict:
     return {"groups": len({batch_shape_key(b) for b in batches}),
-            "batches": len(batches), "bytes": int(staged_nbytes(batches))}
+            "batches": len(batches), "bytes": int(staged_nbytes(batches)),
+            "edge_fea_bytes": staged_edge_fea_nbytes(batches)}
 
 
 @contextlib.contextmanager
@@ -494,6 +505,9 @@ class ScanEpochDriver:
             if args is not None:
                 args.update(_staging_args(
                     [*train_batches, *val_batches]))
+                telemetry.counter_add("staged_bytes", args["bytes"])
+                telemetry.counter_add("staged_edge_fea_bytes",
+                                      args["edge_fea_bytes"])
         self.timings["init_stack_stage_s"] = time.perf_counter() - t0
         self._train_body, self._eval_body = train_body, eval_body
         self._train_scans: dict = {}

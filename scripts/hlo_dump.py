@@ -84,13 +84,16 @@ def compile_cell_for_described_chip(cell_name: str, steps: int):
     # be read back without the chip
     jax.config.update("jax_enable_compilation_cache", False)
 
+    import importlib
+
     from benchmark import run
-    from benchmark.kinds import train
     from cgnn_tpu.train import loop
 
     cell = run.Cell(os.path.join(root, "BENCHMARK.json"), cell_name)
     cell.config["data"]["resident_copies"] = 1
-    bench = train.Driver(run.Context(cell, 0, False))
+    kind = importlib.import_module(
+        "benchmark.kinds." + cell.traffic["kind"])
+    bench = kind.Driver(run.Context(cell, 0, False))
     # set-up builds the scan driver itself, so its warm-up (a whole epoch)
     # is switched off on the class; this process does nothing else
     loop.ScanEpochDriver.warm = lambda self, state: state

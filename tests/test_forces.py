@@ -180,3 +180,126 @@ def test_keep_geometry_stores_wrapped_positions():
     rel = g.positions[g.neighbors].astype(np.float64) + shift - g.positions[g.centers].astype(np.float64)
     recomputed = np.linalg.norm(rel, axis=1)
     np.testing.assert_allclose(recomputed, g.distances, rtol=1e-5, atol=1e-5)
+
+
+# ---- the MD17-shaped pool (data/synthetic.py synthetic_md17) -----------
+
+
+@pytest.fixture(scope="module")
+def md17_pool():
+    from cgnn_tpu.data.dataset import load_synthetic_md17
+
+    return load_synthetic_md17(6, FeaturizeConfig(), seed=3)
+
+
+def test_md17_pool_is_one_aspirin_sized_molecule(md17_pool):
+    """21 atoms (9 C, 8 H, 4 O) in every frame, the same species in the same
+    order, a dozen neighbours each, geometry and force labels kept."""
+    for g in md17_pool:
+        assert g.num_nodes == 21 and g.num_edges == 21 * 12
+        assert sorted(g.numbers.tolist()) == [1] * 8 + [6] * 9 + [8] * 4
+        assert np.array_equal(g.numbers, md17_pool[0].numbers)
+        assert g.positions.shape == (21, 3) and g.forces.shape == (21, 3)
+        assert g.lattice is not None and g.offsets.shape == (252, 3)
+        assert np.all(np.bincount(g.centers, minlength=21) == 12)
+    # frames differ by their jitter, not by their molecule
+    assert not np.allclose(md17_pool[0].positions, md17_pool[1].positions)
+    assert np.allclose(md17_pool[0].positions, md17_pool[1].positions,
+                       atol=0.5)
+
+
+def test_md17_pool_has_no_periodic_image_within_the_radius(md17_pool):
+    """The vacuum keeps every image beyond the featurization radius (8 A)
+    and the potential's cutoff: no edge crosses the cell, and the molecule's
+    span leaves more than the radius to its nearest image."""
+    for g in md17_pool:
+        assert not np.any(g.offsets)
+        span = np.max(np.linalg.norm(
+            g.positions[:, None] - g.positions[None], axis=-1))
+        assert np.allclose(g.lattice, np.diag(np.diag(g.lattice)))
+        assert g.lattice[0, 0] - span > FeaturizeConfig().radius
+        assert g.distances.max() < FeaturizeConfig().radius
+
+
+def test_md17_forces_are_minus_the_gradient_of_its_own_energy():
+    """Labels are consistent: central differences of the frame's energy in
+    each coordinate give its force labels."""
+    from cgnn_tpu.data.synthetic import synthetic_md17
+
+    _, s, energy, forces = synthetic_md17(2, seed=5)[1]
+    assert energy == pytest.approx(lj_energy_forces(s)[0])
+    inv_lat = np.linalg.inv(s.lattice)
+    cart, h = s.cart_coords, 1e-5
+    for atom in (0, 7, 20):
+        for axis in range(3):
+            e = []
+            for sign in (+1, -1):
+                c = cart.copy()
+                c[atom, axis] += sign * h
+                e.append(lj_energy_forces(
+                    Structure(s.lattice, c @ inv_lat, s.numbers))[0])
+            assert forces[atom, axis] == pytest.approx(
+                -(e[0] - e[1]) / (2 * h), rel=1e-3, abs=1e-5)
+    np.testing.assert_allclose(forces.sum(axis=0), 0.0, atol=1e-4)
+
+
+def test_md17_pool_is_a_function_of_its_seed(md17_pool):
+    from cgnn_tpu.data.dataset import load_synthetic_md17
+
+    again = load_synthetic_md17(4, FeaturizeConfig(), seed=3)
+    for a, b in zip(again, md17_pool):
+        assert np.array_equal(a.positions, b.positions)
+        assert np.array_equal(a.forces, b.forces)
+        assert a.target == pytest.approx(b.target) and a.cif_id == b.cif_id
+    other = load_synthetic_md17(2, FeaturizeConfig(), seed=4)
+    assert not np.allclose(other[0].positions, md17_pool[0].positions)
+
+
+def _dots(jaxpr):
+    """Every dot_general of a jaxpr and of the jaxprs inside it."""
+    import jax
+
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _dots(sub)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_float32_force_model_asks_for_float32_matmuls(dtype):
+    """Left to its default the TPU rounds a float32 matmul's operands to
+    bfloat16 (PERF.md section 2, PR 27: the forces then read like the
+    bfloat16 trunk's). In float32 every dot of the force train step, the
+    image shifts' and both reverse passes' included, carries precision
+    ``highest``; the bfloat16 trunk asks for nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    from cgnn_tpu.config import DataConfig, ModelConfig, build_model
+    from cgnn_tpu.data.dataset import load_synthetic_md17
+    from cgnn_tpu.data.graph import batch_iterator, capacities_for
+    from cgnn_tpu.train import Normalizer, create_train_state, make_optimizer
+    from cgnn_tpu.train.force_step import make_force_train_step
+
+    graphs = load_synthetic_md17(8)
+    nc, ec = capacities_for(graphs, 4, dense_m=12, snug=True)
+    batch = next(batch_iterator(graphs, 4, nc, ec, dense_m=12, snug=True))
+    model = build_model(
+        ModelConfig(atom_fea_len=8, n_conv=2, h_fea_len=8, dtype=dtype,
+                    dense_m=12), DataConfig(), "force")
+    state = create_train_state(
+        model, batch, make_optimizer(optim="adam", lr=1e-3),
+        Normalizer(mean=jnp.zeros(1), std=jnp.ones(1)))
+    jaxpr = jax.make_jaxpr(make_force_train_step())(state, batch).jaxpr
+    dots = list(_dots(jaxpr))
+    # embedding, 2 x fc_full, 2 readout layers, the image shifts: forward,
+    # and their transposes under one and two reverse passes
+    assert len(dots) > 12
+    highest = (jax.lax.Precision.HIGHEST,) * 2
+    for eqn in dots:
+        got = eqn.params["precision"]
+        if dtype == "float32":
+            assert tuple(got) == highest, eqn
+        else:
+            assert got is None, eqn

@@ -411,6 +411,7 @@ class TestDataParallelStepStream:
 _SCAN = "jit(scan_train_n672_l2)/while/body/closed_call/"
 _FWD = _SCAN + "jvp(CrystalGraphConvNet)/"
 _BWD = _SCAN + "transpose(jvp(CrystalGraphConvNet))/"
+_F2 = "jit(scan_train_n192_l2)/while/body/closed_call/"
 _CLASSIFIED = [
     ("jit(train_step)/jvp(CrystalGraphConvNet)/conv_1/bn1/mul",
      ("conv.bn1", "fwd")),
@@ -452,6 +453,37 @@ _CLASSIFIED = [
      ("scan", "fwd")),
     ("jit(scan_train_n672_l2)/while/cond/lt", ("scan", "fwd")),
     ("jit(scan_train_n672_l2)/while", ("scan", "fwd")),
+    # the second-order (force) step, as its scan program compiles: the
+    # direction is the number of reverse passes the instruction lies under
+    (_F2 + "jvp(jvp(ForceFieldCGCNN))/edge_geom/sqrt", ("edge_geom", "fwd")),
+    (_F2 + "jvp(transpose(jvp(ForceFieldCGCNN)))/edge_geom/add_any",
+     ("edge_geom", "bwd")),
+    (_F2 + "transpose(jvp(transpose(jvp(ForceFieldCGCNN))))/edge_geom/mul",
+     ("edge_geom", "bwd2")),
+    (_F2 + "jvp(jvp(ForceFieldCGCNN))/force_readout/mul",
+     ("force_readout", "fwd")),
+    (_F2 + "transpose(jvp(jvp(ForceFieldCGCNN)))/force_readout/ForceHead_0/"
+     "out/add_any", ("force_readout", "bwd")),
+    (_F2 + "transpose(jvp(transpose(jvp(ForceFieldCGCNN))))/force_readout/"
+     "ForceHead_0/jit(softplus)/mul", ("force_readout", "bwd2")),
+    (_F2 + "jvp(transpose(jvp(ForceFieldCGCNN)))/conv_1/conv.gather/"
+     "convert_element_type", ("conv.gather", "bwd")),
+    (_F2 + "transpose(jvp(transpose(jvp(ForceFieldCGCNN))))/conv_1/"
+     "conv.gather/jit(_take)/gather", ("conv.gather", "bwd2")),
+    (_F2 + "transpose(jvp(transpose(jvp(ForceFieldCGCNN))))/conv_1/"
+     "conv.fc_full/fc_full/broadcast_in_dim", ("conv.fc_full", "bwd2")),
+    (_F2 + "transpose(jvp(transpose(jvp(ForceFieldCGCNN))))/conv_0/"
+     "conv.gate/split", ("conv.gate", "bwd2")),
+    (_F2 + "transpose(jvp(transpose(jvp(ForceFieldCGCNN))))/conv_0/"
+     "conv.aggregate/convert_element_type", ("conv.aggregate", "bwd2")),
+    # without BatchNorm the conv's residual reads the aggregate's output
+    (_F2 + "transpose(jvp(transpose(jvp(ForceFieldCGCNN))))/conv_0/"
+     "conv.aggregate/jit(softplus)/mul", ("conv.aggregate", "bwd2")),
+    # jnp's own transpose primitive is no reverse pass
+    (_F2 + "transpose(jvp(jvp(ForceFieldCGCNN)))/embed/embedding/transpose",
+     ("embed", "bwd")),
+    (_F2 + "jvp(loss)/mul", ("loss", "fwd")),
+    (_F2 + "transpose(jvp(loss))/broadcast_in_dim", ("loss", "bwd")),
     # unscoped: the optimizer and loss of the step before it had scopes,
     # a reducer's parameters, an argument's name
     ("jit(train_step)/add", ("other", "fwd")),
@@ -476,8 +508,14 @@ class TestPhases:
         assert {p for p, _ in seen} == set(phases.PHASES)
         two_way = {"conv.gather", "conv.fc_full", "conv.bn1", "conv.gate",
                    "conv.aggregate", "conv.bn2", "embed", "pool_head",
-                   "loss"}
+                   "loss", "edge_geom", "force_readout"}
         assert {p for p, d in seen if d == "bwd"} == two_way
+        # what the force step differentiates twice: the trunk without
+        # BatchNorm, the geometry and the readout (not the embedding, which
+        # no position moves, nor the loss)
+        assert {p for p, d in seen if d == "bwd2"} == {
+            "edge_geom", "force_readout", "conv.gather", "conv.fc_full",
+            "conv.gate", "conv.aggregate"}
 
     def test_phase_table_on_a_compiled_program(self):
         """Instructions of the entry and of the loop body are in the table
@@ -602,6 +640,69 @@ ENTRY %main.7 (a.1: f32[4]) -> f32[4] {{
                             lambda name: contextlib.nullcontext())
         bare = compiled_text()
         assert "conv.gate" in scoped and "conv.gate" not in bare
+
+        def code(text):
+            lines = [re.sub(r",? ?metadata=\{[^}]*\}", "", ln)
+                     for ln in text.splitlines()]
+            return [ln for ln in lines
+                    if ln.startswith((" ", "%", "ENTRY", "}"))]
+
+        assert code(scoped) == code(bare)
+
+
+    def test_force_step_scopes_directions_and_metadata_only(self, monkeypatch):
+        """The compiled force step (train/force_step.py, guard on) names
+        every phase it has in all three directions, leaves under 5% of its
+        op_names in ``other``, and is the same program with every named
+        scope taken out, the three it added among them."""
+        import contextlib
+        import re
+
+        from cgnn_tpu.config import DataConfig, ModelConfig, build_model
+        from cgnn_tpu.data.dataset import load_synthetic_md17
+        from cgnn_tpu.observe import phases
+        from cgnn_tpu.resilience.guard import guard_step
+        from cgnn_tpu.train import Normalizer, create_train_state
+        from cgnn_tpu.train.force_step import make_force_train_step
+
+        graphs = load_synthetic_md17(8)
+        node_cap, edge_cap = capacities_for(graphs, 8, dense_m=12)
+        batch = pack_graphs(graphs, node_cap, edge_cap, 8, dense_m=12,
+                            over_cap=512)
+        model = build_model(
+            ModelConfig(atom_fea_len=16, n_conv=2, h_fea_len=32, dense_m=12),
+            DataConfig(), "force")
+        state = create_train_state(
+            model, batch, make_optimizer(optim="sgd", lr=1e-3,
+                                         lr_milestones=[10**9]),
+            Normalizer(mean=jnp.zeros(1), std=jnp.ones(1)))
+
+        def compiled_text():
+            step = jax.jit(guard_step(make_force_train_step()))
+            return step.lower(state, batch).compile().as_text()
+
+        scoped = compiled_text()
+        names = [n for n in re.findall(r'op_name="([^"]*)"', scoped)
+                 if n.startswith("jit(")]
+        got = {phases.classify(n) for n in names}
+        other = [n for n in names if phases.classify(n)[0] == "other"]
+        assert len(names) > 500
+        assert len(other) < 0.05 * len(names), sorted(set(other))[:20]
+        for phase in ("edge_geom", "force_readout", "conv.gather",
+                      "conv.fc_full", "conv.gate"):
+            assert {d for p, d in got if p == phase} == {
+                "fwd", "bwd", "bwd2"}, phase
+        assert {d for p, d in got if p == "loss"} == {"fwd", "bwd"}
+        assert ("optimizer", "fwd") in got and ("embed", "bwd") in got
+        # no BatchNorm in this trunk: nothing of it carries a BatchNorm
+        # phase, the residual after the neighbour sum included
+        assert not {p for p, _ in got} & {"conv.bn1", "conv.bn2"}
+
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda name: contextlib.nullcontext())
+        bare = compiled_text()
+        for scope in ("edge_geom", "force_readout", "loss"):
+            assert scope in scoped and f"/{scope}/" not in bare
 
         def code(text):
             lines = [re.sub(r",? ?metadata=\{[^}]*\}", "", ln)

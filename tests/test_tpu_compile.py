@@ -1,0 +1,121 @@
+"""Compiles for the DESCRIBED chip (v5e; no chip attached, nothing runs):
+what only the TPU compiler shows and every later PR should keep.
+
+The one file of the suite that loads the TPU's library: the topology is
+described inside a fixture, never at import, and the compile happens in the
+test's own process (``on-chip-measurement`` guide, section 2).
+"""
+
+from __future__ import annotations
+
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    import importlib.util
+
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("no libtpu in this installation: nothing can compile "
+                    "for the described chip")
+    # with libtpu installed (this container, the driver's) a topology that
+    # cannot be described is a failure, not a skip: these tests are the only
+    # pin of what they guard
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache and
+    can never be read back without the chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("task,dtype", [
+    ("force", "bfloat16"), ("force", "float32"), ("regression", "bfloat16")])
+def test_full_staging_scan_program_converts_no_resident_stack(
+        one_chip, no_compile_cache, task, dtype):
+    """The force task rides full staging: its scan program is handed the
+    whole resident stack of batches and slices one a step. Nothing in the
+    program may compute an array the size of the stacked atom features (of
+    the other members only a u8 mask's relayout is known, PERF.md section 7,
+    and this compile picks entry layouts freely): the first chip run of this path
+    (PR 27) found the atom features' cast to bfloat16 (the model's, or the
+    compiler's own for a float32 matmul's operands) moved before the slice
+    and hoisted out of the loop, a pass over all of bf16[832, 5400, 92] once
+    a launch of two steps, 23% of the step (models/cgcnn.py
+    masked_atom_features masks before it casts, which keeps the cast on the
+    slice). In float32 the model asks for precision highest and the
+    compiler splits each matmul operand into bfloat16 parts: the same trap,
+    three converts wide. The first-order model casts through the same
+    helper: no cell stages it in full, train.py without --compact does."""
+    from cgnn_tpu.config import DataConfig, ModelConfig, build_model
+    from cgnn_tpu.data.dataset import load_synthetic_md17
+    from cgnn_tpu.data.graph import batch_iterator, capacities_for
+    from cgnn_tpu.resilience.guard import guard_step
+    from cgnn_tpu.train import Normalizer, create_train_state, make_optimizer
+    from cgnn_tpu.train.force_step import (
+        make_force_eval_step,
+        make_force_train_step,
+    )
+    from cgnn_tpu.train.loop import ScanEpochDriver
+    from cgnn_tpu.train.step import make_eval_step, make_train_step
+
+    graphs = load_synthetic_md17(32)
+    node_cap, edge_cap = capacities_for(graphs, 8, dense_m=12, snug=True)
+    edge_dtype = jnp.bfloat16 if dtype == "bfloat16" else np.float32
+    batches = list(batch_iterator(graphs, 8, node_cap, edge_cap, dense_m=12,
+                                  snug=True, edge_dtype=edge_dtype))
+    model = build_model(
+        ModelConfig(atom_fea_len=16, n_conv=2, h_fea_len=32, dtype=dtype,
+                    dense_m=12), DataConfig(), task)
+    state = create_train_state(
+        model, batches[0],
+        make_optimizer(optim="adam", lr=1e-3, lr_milestones=[10**9]),
+        Normalizer(mean=jnp.zeros(1), std=jnp.ones(1)))
+    bodies = ((make_force_train_step(), make_force_eval_step())
+              if task == "force" else
+              (make_train_step(False), make_eval_step(False)))
+    driver = ScanEpochDriver(
+        guard_step(bodies[0]), bodies[1], batches, [],
+        np.random.default_rng(0), chunk_steps=2)
+    (key, stacked), = driver._train_groups.items()
+    fn = driver._scan_fn(driver._train_scans, (key, 2), driver._train_body,
+                         True)
+    shapes = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                       sharding=one_chip),
+        (state, stacked, np.zeros(2, np.int32)))
+    text = fn.lower(*shapes).compile().as_text()
+
+    stack, feat = len(batches), graphs[0].atom_fea.shape[1]
+    assert stack > 2  # a stack-sized shape is no step-sized one
+    moved = ("parameter", "get-tuple-element", "bitcast", "tuple",
+             "copy-start", "copy-done")  # hand the stack on, compute nothing
+    computed = [
+        ln.strip()[:160] for ln in text.splitlines()
+        if re.match(rf"\s+(ROOT )?%?[\w.\-]+ = \w+\[{stack},{node_cap},{feat}\]",
+                    ln)
+        and not re.search(r" (" + "|".join(moved) + r")\(", ln)]
+    assert not computed, computed
