@@ -24,7 +24,7 @@ from cgnn_tpu.data.graph import GraphBatch
 from cgnn_tpu.models.cgcnn import CGConv, masked_atom_features
 from cgnn_tpu.models.heads import ForceHead
 from cgnn_tpu.observe import phases
-from cgnn_tpu.ops.segment import segment_sum
+from cgnn_tpu.ops.segment import gather_slot_major, segment_sum
 
 
 def gaussian_expand(d: jax.Array, dmin: float, dmax: float, step: float) -> jax.Array:
@@ -33,15 +33,46 @@ def gaussian_expand(d: jax.Array, dmin: float, dmax: float, step: float) -> jax.
     return jnp.exp(-((d[..., None] - mu) ** 2) / step**2)
 
 
-def edge_distances(batch: GraphBatch, positions: jax.Array) -> jax.Array:
-    """Per-edge periodic distances recomputed from positions (differentiable).
+def edge_distances(
+    batch: GraphBatch, positions: jax.Array, dense_m: int | None = None
+) -> jax.Array:
+    """Periodic edge distances recomputed from positions (differentiable):
+    [E] for a flat COO batch, [N, M] for a dense one (``dense_m``).
 
     ``positions`` is passed explicitly (not read from the batch) so callers
     can take gradients with respect to it.
+
+    The dense edge-slot layout (pack_graphs ``dense_m``) fixes the centre
+    of slot ``e`` as ``e // M`` (data/invariants.py checks it), so the
+    geometry is read off that structure in [N, M, .] form instead of off
+    the flat index vectors: the centre's position is a broadcast (reverse
+    pass: a sum over M), the lattice is looked up once an atom ([N, 3, 3],
+    never [E, 3, 3]), and the neighbours' positions go through the conv's
+    own transposable gather, whose reverse pass is a row gather by
+    ``in_slots`` plus the overflow tier's small segment-sum. The flat
+    form's three [E]-indexed gathers each reverse into an [E]-row scatter
+    at 12-byte rows; those and the s32[E] gather ``node_graph[centers]``
+    were a fifth of the force step on the chip (PERF.md section 6, PR 28).
+
+    The gather's transpose needs a zero cotangent on padded slots, as in
+    the conv: the Gaussians are multiplied by ``edge_mask``, so ``d``'s
+    cotangent is zero there in both reverse passes.
     """
-    lat_e = batch.lattices[batch.node_graph[batch.centers]]  # [E, 3, 3]
-    shift = jnp.einsum("ek,ekj->ej", batch.edge_offsets, lat_e)
-    rel = positions[batch.neighbors] + shift - positions[batch.centers]
+    if dense_m is None:
+        lat_e = batch.lattices[batch.node_graph[batch.centers]]  # [E, 3, 3]
+        shift = jnp.einsum("ek,ekj->ej", batch.edge_offsets, lat_e)
+        rel = positions[batch.neighbors] + shift - positions[batch.centers]
+    else:
+        offsets = batch.edge_offsets.reshape(-1, dense_m, 3)
+        shift = jnp.einsum(
+            "nmk,nkj->nmj", offsets, batch.lattices[batch.node_graph]
+        )
+        nbr_pos = gather_slot_major(
+            positions, batch.neighbors, dense_m, batch.in_slots,
+            batch.in_mask, over_slots=batch.over_slots,
+            over_nodes=batch.over_nodes, over_mask=batch.over_mask,
+        )
+        rel = nbr_pos + shift - positions[:, None, :]
     # epsilon under the sqrt keeps the gradient finite on masked padding
     # edges (rel == 0); real edges have d >> eps so values are unaffected
     return jnp.sqrt(jnp.sum(rel * rel, axis=-1) + 1e-12)
@@ -59,10 +90,13 @@ class ForceFieldCGCNN(nn.Module):
     dtype: Any = jnp.float32
     aggregation_impl: str | None = None
     # dense edge-slot layout (data/graph.py pack_graphs dense_m): the
-    # scatter-free aggregation applies to the force task too — in-model
-    # edge distances compose because dense batches keep the flat
-    # centers/neighbors/edge_offsets vectors in slot order. Requires
-    # batches packed with the same dense_m.
+    # scatter-free aggregation applies to the force task too, and the
+    # in-model geometry relies on the layout's structure, not only on its
+    # order: edge slot e belongs to atom e // M (so ``centers`` is never
+    # read), ``edge_offsets`` and ``neighbors`` view as [N, M, .], and the
+    # batch's transpose mapping (in_slots / over_*) serves the position
+    # gather as it serves the conv's (edge_distances). Requires batches
+    # packed with the same dense_m (invariants.check_batch).
     dense_m: int | None = None
 
     @nn.compact
@@ -99,14 +133,12 @@ class ForceFieldCGCNN(nn.Module):
                 # (1/32 A apart between 4 and 8 A, against filters 0.2 A
                 # wide) puts every Gaussian, and the force read off its
                 # slope, at another distance than the frame's
-                d = edge_distances(batch, positions)
+                d = edge_distances(batch, positions, self.dense_m)
                 edge_mask = batch.edge_mask
                 if self.dense_m is not None:
-                    # the conv reads [N, M, K]: the [E] distances take
-                    # that shape before they are expanded, so the relayout
-                    # is of one float an edge, once a step, not of K a conv
-                    d = d.reshape(-1, self.dense_m)
-                    edge_mask = edge_mask.reshape(-1, self.dense_m)
+                    # the conv reads [N, M, K], the shape the dense
+                    # distances come out in
+                    edge_mask = edge_mask.reshape(d.shape)
                 edge_fea = gaussian_expand(d, self.dmin, self.dmax, self.step)
                 edge_fea = (edge_fea * edge_mask[..., None]).astype(self.dtype)
             with jax.named_scope(phases.EMBED):

@@ -97,15 +97,45 @@ def test_force_training_fits_lj_ground_truth():
     assert float(m1["mae"]) < float(m0["mae"])  # energy improves too
 
 
-def test_dense_force_layout_matches_coo():
+def _crystal_frames(num: int, seed: int):
+    """Periodic crystals the in-model geometry cannot take for molecules:
+    tight cells (3.6-4.6 A against a 6 A radius, so most edges cross a cell
+    face and carry a non-zero image offset, and the 12 nearest images leave
+    in-degrees uneven) mixed with wide, sparse ones (a 9-10 A cell: atoms
+    with fewer than 12 neighbours, so edge slots are padded)."""
+    rng = np.random.default_rng(seed)
+    frames = []
+    for k in range(num):
+        sparse = k % 3 == 2
+        s = random_structure(
+            rng, 2, 3 if sparse else 5,
+            a_range=(9.0, 10.0) if sparse else (3.6, 4.6),
+            min_separation=2.0,
+        )
+        e, f = lj_energy_forces(s)
+        frames.append((f"crystal-{k:03d}", s, e, f))
+    return frames
+
+
+@pytest.mark.parametrize("case", ["cell", "crystal", "forward_only"])
+def test_dense_force_layout_matches_coo(case):
     """--task force --layout dense (VERDICT r3 next-step #4): the dense
     edge-slot layout must reproduce the flat-COO force model exactly —
     energies, forces, AND one composite-loss training step's gradients
-    (the second-order path through linear_call's gather transpose)."""
+    (the second-order path through linear_call's gather transpose).
+
+    The dense model reads its geometry off the layout (models/forcefield.py
+    edge_distances: a broadcast centre, one lattice an atom, the slot-major
+    transposable position gather), the COO model off the flat index
+    vectors. ``cell``: 6 atoms in a 6-7.5 A periodic cell. ``crystal``:
+    cells smaller than the radius, with padded atom and edge slots and
+    rows in the overflow tier of the transpose mapping. ``forward_only``:
+    the crystals packed with ``in_cap=0`` (no transpose mapping: plain
+    autodiff of the gather), energies and forces."""
     import jax
     import jax.numpy as jnp
 
-    from cgnn_tpu.data.dataset import load_trajectory
+    from cgnn_tpu.data.dataset import _trajectory_graphs, load_trajectory
     from cgnn_tpu.data.graph import batch_iterator
     from cgnn_tpu.models.forcefield import ForceFieldCGCNN, energy_and_forces
     from cgnn_tpu.train import Normalizer, create_train_state, make_optimizer
@@ -113,14 +143,34 @@ def test_dense_force_layout_matches_coo():
     from cgnn_tpu.train.loop import capacities_for
 
     cfg = FeaturizeConfig(radius=6.0, max_num_nbr=12)
-    graphs = load_trajectory(24, cfg, seed=5, num_atoms=6)
+    if case == "cell":
+        graphs = load_trajectory(24, cfg, seed=5, num_atoms=6)
+    else:
+        graphs = _trajectory_graphs(_crystal_frames(24, seed=11), cfg)
     norm = Normalizer.fit(np.stack([g.target for g in graphs]))
 
     nc_c, ec_c = capacities_for(graphs, 8)
     coo = next(batch_iterator(graphs, 8, nc_c, ec_c))
     nc_d, ec_d = capacities_for(graphs, 8, dense_m=12)
-    dense = next(batch_iterator(graphs, 8, nc_d, ec_d, dense_m=12))
-    assert dense.in_slots is not None  # two-tier transpose is packed
+    dense = next(batch_iterator(
+        graphs, 8, nc_d, ec_d, dense_m=12,
+        in_cap=0 if case == "forward_only" else None,
+    ))
+    if case == "forward_only":
+        assert dense.in_slots is None and dense.over_slots is None
+    else:
+        assert dense.in_slots is not None  # two-tier transpose is packed
+    if case != "cell":
+        real = np.asarray(dense.edge_mask) > 0
+        assert np.abs(np.asarray(dense.edge_offsets)[real]).sum(-1).mean() > 0.5
+        padded_edges = ~real.reshape(-1, 12)[np.asarray(dense.node_mask) > 0]
+        assert padded_edges.any() and not padded_edges.all()
+        assert (np.asarray(dense.node_mask) == 0).any()
+        in_degree = np.bincount(np.asarray(dense.neighbors)[real])
+        assert in_degree.max() > 12
+        if case == "crystal":  # those rows ride the overflow tier
+            assert int(np.asarray(dense.over_mask).sum()) == int(
+                np.maximum(in_degree - 12, 0).sum()) > 0
 
     m_coo = ForceFieldCGCNN(atom_fea_len=32, n_conv=2, h_fea_len=32, dmax=6.0)
     m_dense = ForceFieldCGCNN(
@@ -136,9 +186,12 @@ def test_dense_force_layout_matches_coo():
         np.asarray(e_c)[gm_c], np.asarray(e_d)[gm_d], rtol=1e-5, atol=1e-5
     )
     nm_c, nm_d = np.asarray(coo.node_mask) > 0, np.asarray(dense.node_mask) > 0
+    assert np.abs(np.asarray(f_c)[nm_c]).max() > 1e-3  # not trivially equal
     np.testing.assert_allclose(
         np.asarray(f_c)[nm_c], np.asarray(f_d)[nm_d], rtol=1e-4, atol=1e-5
     )
+    if case == "forward_only":
+        return
 
     # one training step: params gradients must agree through the nested
     # (positions-then-params) differentiation on both layouts

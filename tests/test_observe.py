@@ -460,6 +460,22 @@ _CLASSIFIED = [
      ("edge_geom", "bwd")),
     (_F2 + "transpose(jvp(transpose(jvp(ForceFieldCGCNN))))/edge_geom/mul",
      ("edge_geom", "bwd2")),
+    # the dense geometry (PR 28): one lattice an atom, the slot-major
+    # position gather, its declared transpose (a row gather by in_slots, a
+    # masked sum, the overflow tier's segment-sum) and that one's transpose
+    (_F2 + "jvp(jvp(ForceFieldCGCNN))/edge_geom/gather", ("edge_geom", "fwd")),
+    (_F2 + "jvp(jvp(ForceFieldCGCNN))/edge_geom/nmk,nkj->nmj/dot_general",
+     ("edge_geom", "fwd")),
+    (_F2 + "jvp(jvp(ForceFieldCGCNN))/edge_geom/jit(_take)/gather",
+     ("edge_geom", "fwd")),
+    (_F2 + "jvp(transpose(jvp(ForceFieldCGCNN)))/edge_geom/jit(_take)/gather",
+     ("edge_geom", "bwd")),
+    (_F2 + "jvp(transpose(jvp(ForceFieldCGCNN)))/edge_geom/reduce_sum",
+     ("edge_geom", "bwd")),
+    (_F2 + "jvp(transpose(jvp(ForceFieldCGCNN)))/edge_geom/scatter-add",
+     ("edge_geom", "bwd")),
+    (_F2 + "transpose(jvp(transpose(jvp(ForceFieldCGCNN))))/edge_geom/"
+     "jit(_take)/gather", ("edge_geom", "bwd2")),
     (_F2 + "jvp(jvp(ForceFieldCGCNN))/force_readout/mul",
      ("force_readout", "fwd")),
     (_F2 + "transpose(jvp(jvp(ForceFieldCGCNN)))/force_readout/ForceHead_0/"
@@ -694,6 +710,16 @@ ENTRY %main.7 (a.1: f32[4]) -> f32[4] {{
                 "fwd", "bwd", "bwd2"}, phase
         assert {d for p, d in got if p == "loss"} == {"fwd", "bwd"}
         assert ("optimizer", "fwd") in got and ("embed", "bwd") in got
+        # the dense geometry's data movement carries its scope through
+        # linear_call's transposes: the position gather in every
+        # direction, the overflow tier's segment-sum under one reverse
+        # pass; and no gather or scatter of the step is left unnamed
+        moves = {(phases.classify(n), n.rsplit("/", 1)[-1]) for n in names
+                 if n.endswith(("/gather", "/scatter-add"))}
+        for direction in ("fwd", "bwd", "bwd2"):
+            assert (("edge_geom", direction), "gather") in moves
+        assert (("edge_geom", "bwd"), "scatter-add") in moves
+        assert not [m for m in moves if m[0][0] == "other"]
         # no BatchNorm in this trunk: nothing of it carries a BatchNorm
         # phase, the residual after the neighbour sum included
         assert not {p for p, _ in got} & {"conv.bn1", "conv.bn2"}

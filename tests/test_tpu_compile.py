@@ -8,6 +8,7 @@ test's own process (``on-chip-measurement`` guide, section 2).
 
 from __future__ import annotations
 
+import functools
 import re
 
 import numpy as np
@@ -52,24 +53,11 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("task,dtype", [
-    ("force", "bfloat16"), ("force", "float32"), ("regression", "bfloat16")])
-def test_full_staging_scan_program_converts_no_resident_stack(
-        one_chip, no_compile_cache, task, dtype):
-    """The force task rides full staging: its scan program is handed the
-    whole resident stack of batches and slices one a step. Nothing in the
-    program may compute an array the size of the stacked atom features (of
-    the other members only a u8 mask's relayout is known, PERF.md section 7,
-    and this compile picks entry layouts freely): the first chip run of this path
-    (PR 27) found the atom features' cast to bfloat16 (the model's, or the
-    compiler's own for a float32 matmul's operands) moved before the slice
-    and hoisted out of the loop, a pass over all of bf16[832, 5400, 92] once
-    a launch of two steps, 23% of the step (models/cgcnn.py
-    masked_atom_features masks before it casts, which keeps the cast on the
-    slice). In float32 the model asks for precision highest and the
-    compiler splits each matmul operand into bfloat16 parts: the same trap,
-    three converts wide. The first-order model casts through the same
-    helper: no cell stages it in full, train.py without --compact does."""
+@functools.cache  # two tests read the float32 force program
+def _full_staging_scan_program(one_chip, task: str, dtype: str):
+    """The scan program ``fit`` builds under full staging (chunk of two
+    steps over the whole resident stack) at a tiny size, compiled for the
+    described chip -> (optimized HLO text, graphs, batches, node capacity)."""
     from cgnn_tpu.config import DataConfig, ModelConfig, build_model
     from cgnn_tpu.data.dataset import load_synthetic_md17
     from cgnn_tpu.data.graph import batch_iterator, capacities_for
@@ -108,6 +96,29 @@ def test_full_staging_scan_program_converts_no_resident_stack(
                                        sharding=one_chip),
         (state, stacked, np.zeros(2, np.int32)))
     text = fn.lower(*shapes).compile().as_text()
+    return text, graphs, batches, node_cap
+
+
+@pytest.mark.parametrize("task,dtype", [
+    ("force", "bfloat16"), ("force", "float32"), ("regression", "bfloat16")])
+def test_full_staging_scan_program_converts_no_resident_stack(
+        one_chip, no_compile_cache, task, dtype):
+    """The force task rides full staging: its scan program is handed the
+    whole resident stack of batches and slices one a step. Nothing in the
+    program may compute an array the size of the stacked atom features (of
+    the other members only a u8 mask's relayout is known, PERF.md section 7,
+    and this compile picks entry layouts freely): the first chip run of this path
+    (PR 27) found the atom features' cast to bfloat16 (the model's, or the
+    compiler's own for a float32 matmul's operands) moved before the slice
+    and hoisted out of the loop, a pass over all of bf16[832, 5400, 92] once
+    a launch of two steps, 23% of the step (models/cgcnn.py
+    masked_atom_features masks before it casts, which keeps the cast on the
+    slice). In float32 the model asks for precision highest and the
+    compiler splits each matmul operand into bfloat16 parts: the same trap,
+    three converts wide. The first-order model casts through the same
+    helper: no cell stages it in full, train.py without --compact does."""
+    text, graphs, batches, node_cap = _full_staging_scan_program(
+        one_chip, task, dtype)
 
     stack, feat = len(batches), graphs[0].atom_fea.shape[1]
     assert stack > 2  # a stack-sized shape is no step-sized one
@@ -119,3 +130,51 @@ def test_full_staging_scan_program_converts_no_resident_stack(
                     ln)
         and not re.search(r" (" + "|".join(moved) + r")\(", ln)]
     assert not computed, computed
+
+
+# an instruction after its ``name = ``: type, dimensions, opcode, operands
+_INSTR = re.compile(r"(\w+)\[([\d,]*)\]\S* ([a-z][\w\-]*)\((.*)$")
+
+
+def test_force_geometry_reads_the_dense_layout(one_chip, no_compile_cache):
+    """The pin that models/forcefield.py edge_distances' dense form engaged
+    in the force train step (PR 28). Written for the flat COO edge list the
+    geometry gathered three times by [E] indices, and on the chip its five
+    longest operations were none of them arithmetic: the two scatter-adds of
+    E 12-byte rows that transpose positions[neighbors] and
+    positions[centers], the scalar gather node_graph[centers] (s32[E]), the
+    lattice gather into f32[E, 3, 3] and the product that read it back, a
+    fifth of the step. Under ``dense_m`` the centre is a broadcast, the
+    lattice is gathered once an atom, and the neighbours' positions go
+    through ops/segment.gather_slot_major with the batch's transpose
+    mapping: the only scatter left under the ``edge_geom`` scope is the
+    overflow tier's segment-sum, over_cap rows wide."""
+    from cgnn_tpu.observe import phases
+
+    text, _graphs, batches, node_cap = _full_staging_scan_program(
+        one_chip, "force", "float32")
+    edge_cap, over_cap = node_cap * 12, batches[0].over_slots.shape[0]
+    assert len({edge_cap, over_cap, node_cap}) == 3  # told apart by size
+
+    scatter_rows, index_gathers, lattices_an_edge, scoped = [], [], [], 0
+    for comp in phases._parse(text).values():
+        parsed = {name: m.groups() for name, rest in comp["instrs"].items()
+                  if (m := _INSTR.match(rest))}
+        for name, (dtype, dims, op, operands) in parsed.items():
+            rest = comp["instrs"][name]
+            if (dtype, dims) == ("f32", f"{edge_cap},3,3"):
+                lattices_an_edge.append(rest[:200])
+            op_name = phases._OP_NAME.search(rest)
+            if not op_name or phases.classify(
+                    op_name.group(1))[0] != phases.EDGE_GEOM:
+                continue
+            scoped += 1
+            if op == "scatter":  # (operand, indices, updates)
+                updates = parsed[phases._OPERAND.findall(operands)[2]]
+                scatter_rows.append(int(updates[1].split(",")[0]))
+            if op == "gather" and dtype == "s32":
+                index_gathers.append(rest[:200])
+    assert scoped > 50  # the scope is there to be read
+    assert scatter_rows == [over_cap], scatter_rows
+    assert not index_gathers, index_gathers
+    assert not lattices_an_edge, lattices_an_edge
