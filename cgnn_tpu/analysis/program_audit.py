@@ -102,13 +102,12 @@ CHECKS = {
     ),
     "GA-ROOFLINE": (
         "a byte-budgeted program's cost-analysis bytes exceed its "
-        "analytic HBM model: the whole-conv fused kernel "
-        "(ops/pallas_cgconv.py) is built on reading its inputs and "
-        "writing ONLY the [N, F] aggregate — a later change that "
-        "silently rematerializes v_j/z (an [N, M, *] intermediate) in "
-        "HBM reintroduces exactly the staging round-trips the kernel "
-        "exists to remove (PERF.md §6b's failure mode), and this check "
-        "blocks CI on it."
+        "analytic HBM model: the in-program neighbor search "
+        "(ops/neighbor_search.py) is budgeted at a bounded number of "
+        "passes over its [S, S*K] candidate plane — a later change that "
+        "featurizes before truncating to the [S, M] survivors "
+        "materializes a per-candidate [S, S*K, G] tensor in HBM, G-fold "
+        "the intended working set, and this check blocks CI on it."
     ),
 }
 
@@ -121,11 +120,6 @@ _ALLOWED_CUSTOM_CALLS = {
     "SPMDFullToShardShape",
     "SPMDShardToFullShape",
     "annotate_device_placement",
-    # Mosaic-compiled Pallas kernels (ops/pallas_cgconv.py and friends)
-    # lower to this target on TPU: a DEVICE kernel, not a host call —
-    # GA-HOSTCALL polices host-callback surfaces, and GA-ROOFLINE is
-    # the check that owns what these kernels do to HBM
-    "tpu_custom_call",
 }
 
 _CUSTOM_CALL_RE = re.compile(r"custom_call\s+@([\w.$]+)")
@@ -236,8 +230,8 @@ def build_entry_programs(config: AuditConfig | None = None,
     """-> (programs, meta): the repo's real entry programs, lowered.
 
     Known backend gaps become ``skip`` records (listed in the ledger
-    meta, never silently absent): the DP/edge-sharded steps need >= 2
-    devices, and the Pallas kernels lower only on TPU. Everything else
+    meta, never silently absent): the DP/edge-sharded steps and the
+    mesh predict programs need >= 2 devices. Everything else
     must lower — an unexpected failure is a GA-LOWER finding, not a
     skip."""
     import tempfile
@@ -381,66 +375,6 @@ def build_entry_programs(config: AuditConfig | None = None,
         add_skip("train/dp", shard_gap)
         add_skip("train/edge", shard_gap)
 
-    # -- the whole-conv fused forward (ops/pallas_cgconv.py; ROADMAP
-    # item 2): byte-budgeted against its analytic one-round-trip model
-    # so a silent [N, M, *] rematerialization blocks CI (GA-ROOFLINE).
-    # The structured 'xla' twin lowers on every backend; the Pallas
-    # kernels lower only on TPU (recorded as a skip elsewhere).
-    from cgnn_tpu.ops.pallas_cgconv import (
-        fused_cgconv_eval,
-        fused_conv_hbm_bytes,
-    )
-
-    fdim = cfg.atom_fea_len
-    gdim = graphs[0].edge_fea.shape[1]
-    byte_model = fused_conv_hbm_bytes(ncd, m, gdim, fdim)
-    # eval mode = ONE apply pass: budget is one read set + the write
-    eval_budget = int(byte_model["reads_per_pass"]
-                      + byte_model["write_bytes"])
-
-    def _fused_fwd_fn(impl):
-        def f(nodes, edges, kernel, bias, scale, bn_bias, mean, var,
-              neighbors, emask):
-            return fused_cgconv_eval(
-                nodes, edges, kernel, bias, scale, bn_bias, neighbors,
-                emask, mean, var, impl=impl, window=0,
-            )
-
-        return jax.jit(f)
-
-    c2 = 2 * fdim
-    fused_avals = (
-        jax.ShapeDtypeStruct((ncd, fdim), np.float32),       # nodes
-        jax.ShapeDtypeStruct((ncd, m, gdim), np.float32),    # edges
-        jax.ShapeDtypeStruct((c2 + gdim, c2), np.float32),   # kernel
-        jax.ShapeDtypeStruct((c2,), np.float32),             # bias
-        jax.ShapeDtypeStruct((c2,), np.float32),             # scale
-        jax.ShapeDtypeStruct((c2,), np.float32),             # bn_bias
-        jax.ShapeDtypeStruct((c2,), np.float32),             # mean
-        jax.ShapeDtypeStruct((c2,), np.float32),             # var
-        jax.ShapeDtypeStruct((ncd * m,), np.int32),          # neighbors
-        jax.ShapeDtypeStruct((ncd, m), np.float32),          # edge mask
-    )
-    # the structured twin is NOT absolute-budgeted (its jnp ops carry
-    # logical [N, M, *] intermediates whose cost-analysis bytes XLA may
-    # or may not fuse away, backend-dependent) — its ledger row is
-    # budget-gated RELATIVELY by diff_ledgers (>20% bytes regression
-    # fails CI), which is what catches a rematerialization creeping
-    # into the structured path on the CPU CI leg.
-    programs.append(Program(
-        name="conv/fused_xla_fwd", jitted=_fused_fwd_fn("xla"),
-        args=fused_avals,
-    ))
-    if jax.default_backend() == "tpu":
-        programs.append(Program(
-            name="conv/fused_pallas_fwd", jitted=_fused_fwd_fn("pallas"),
-            args=fused_avals, byte_budget=eval_budget,
-        ))
-    else:
-        add_skip("conv/fused_pallas_fwd",
-                 "Pallas TPU kernels lower only on a tpu backend "
-                 "(config.py backend rule)")
-
     # -- predict: every (rung, staging form) in the warm ladder — the
     # forms dimension now includes 'raw' (ISSUE 11: the in-program
     # neighbor-search + featurize program per rung) --
@@ -532,14 +466,6 @@ def build_entry_programs(config: AuditConfig | None = None,
             2 if mesh_devices else 1),
         "mesh_devices": mesh_devices,
         "state_leaves": n_leaves,
-        # the fused conv's analytic HBM model (ops/pallas_cgconv.py
-        # fused_conv_hbm_bytes): the GA-ROOFLINE budget for the Pallas
-        # program and the documented target for the structured twin's
-        # relative gate
-        "fused_conv_byte_model": {
-            **byte_model, "eval_budget_bytes": eval_budget,
-            "shape": {"n": ncd, "m": m, "g": gdim, "f": fdim},
-        },
         # the ISSUE-11 neighbor-search byte model (GA-ROOFLINE target)
         "neighbor_search_byte_model": neighbor_search_hbm_bytes(
             g_cap0, raw_spec.snode_cap, raw_spec.n_images,
@@ -776,12 +702,11 @@ def roofline_entry(compiled) -> dict:
     return entry
 
 
-# GA-ROOFLINE slack over the analytic model: cost analysis counts the
-# custom-call surface plus glue ops (index prep, the stats reduction's
-# scalar outputs), and padding rounds block shapes up — 2x headroom
-# stays far below the ~M-fold blowup a rematerialized [N, M, *]
-# intermediate causes (M = 8-12), so the check cannot false-positive on
-# glue yet cannot miss the failure mode it exists for.
+# GA-ROOFLINE slack over the analytic model: cost analysis counts glue
+# ops (index prep, scalar outputs) beside the modelled passes — 2x
+# headroom stays far below the G-fold (~40x) blowup of a per-candidate
+# feature tensor in the neighbor search, so the check cannot
+# false-positive on glue yet cannot miss the failure mode it exists for.
 _ROOFLINE_SLACK = 2.0
 
 
@@ -806,10 +731,10 @@ def check_roofline_budget(p: Program, entry: dict) -> list[AuditFinding]:
         return [AuditFinding(
             "GA-ROOFLINE", p.name,
             f"cost-analysis bytes {measured:.3e} exceed the analytic "
-            f"one-round-trip model ({p.byte_budget:.3e} x "
-            f"{_ROOFLINE_SLACK} slack) — an [N, M, *] intermediate is "
-            f"round-tripping HBM again (the staging cost the fused "
-            f"conv exists to remove; ops/pallas_cgconv.py).",
+            f"byte model ({p.byte_budget:.3e} x {_ROOFLINE_SLACK} "
+            f"slack) — a tensor beyond the modelled working set is "
+            f"round-tripping HBM (for the neighbor search: a "
+            f"per-candidate feature tensor; ops/neighbor_search.py).",
         )]
     return []
 

@@ -1,4 +1,4 @@
-"""CGCNN in Flax: edge-gated graph convolution over flat COO edges.
+"""CGCNN in Flax: edge-gated graph convolution over dense edge slots.
 
 Reference semantics (SURVEY.md §2 component 6, §3.3) per conv layer:
 
@@ -6,16 +6,26 @@ Reference semantics (SURVEY.md §2 component 6, §3.3) per conv layer:
     z      = BatchNorm(Linear(z))          # 2F+G -> 2F, BN over edges
     gate, core = split(z)
     msg    = sigmoid(gate) * softplus(core)
-    agg_i  = sum_j msg_ij                  # per-node scatter-sum
+    agg_i  = sum_j msg_ij                  # per-node sum
     v_i'   = softplus(v_i + BatchNorm(agg_i))
 
 and the full model: Linear(92->F) embedding, n_conv such layers, per-crystal
 mean pooling, softplus MLP head (LogSoftmax head for classification).
 
 TPU-first design choices:
-- flat COO edge list (gather + masked segment-sum on sorted centers) instead
-  of the reference's dense [N, M] gather — composes with bucketed padding and
-  maps directly onto XLA scatter / the Pallas kernel (ops/segment.py);
+- dense edge-slot layout (``dense_m``: node n owns slots [n*M, (n+1)*M)),
+  as the reference's [N, M] neighbour tensors but over bucketed, padded
+  batches: the aggregation is a sum over M and the v_i term a broadcast, so
+  the forward has no scatter; the neighbour gather runs in the slot-major
+  row order the compiler lays [N, M, .] tensors out in, and its transpose is
+  a second gather through a mapping the packer precomputes
+  (ops/segment.py gather_slot_major). This is the body every benchmark cell
+  runs;
+- the same body over node strips when the graph is sharded over a mesh
+  axis (``edge_axis_name``), one psum a conv;
+- a flat COO body (gather + segment-sum over sorted centres) for batches
+  packed without ``dense_m``: the edge-sharded step runs it, and the tests
+  hold the dense body to it;
 - masked BatchNorm / pooling so static-shape padding never leaks into
   statistics (SURVEY.md §7 hard parts #1, #3);
 - optional bfloat16 compute for the MXU, float32 params and statistics.
@@ -81,8 +91,6 @@ class CGConv(nn.Module):
 
     features: int
     dtype: Any = jnp.float32
-    aggregation_impl: str | None = None  # None -> global default (ops/segment.py)
-    assume_sorted_edges: bool = True  # GraphBatch from pack_graphs guarantees it
     # BatchNorm makes per-edge outputs depend on batch statistics; for energy
     # models that's the reference semantics, but a force field must NOT use
     # it: F = -dE/dr picks up gradient terms through the batch moments in
@@ -101,28 +109,6 @@ class CGConv(nn.Module):
     # scatter that runs ~50x below HBM bandwidth (the CUDA atomicAdd
     # analog of SURVEY.md §2 N2, solved the TPU way: layout, not atomics).
     dense_m: int | None = None
-    # fused BN1->gate->mask->sum epilogue (ops/fused_epilogue.py): None
-    # keeps the unfused reference path; 'xla' uses the hand-structured
-    # minimal-pass custom VJP; 'pallas' adds explicit VMEM blocking.
-    # Dense layout + use_batchnorm only; numerics match to f32 roundoff.
-    # MEASURED NEGATIVE on v5e (both impls 5-20% slower than unfused —
-    # the custom-VJP boundary blocks producer/consumer fusion; PERF.md
-    # 6b); default stays None.
-    fused_epilogue: str | None = None
-    # WHOLE-conv fused kernel (ops/pallas_cgconv.py, ROADMAP item 2):
-    # the entire dense branch — gather, fc_full, BN1, gate, mask,
-    # sum-over-M — as one custom-VJP op whose 'pallas' impl runs per
-    # 128-node block entirely in VMEM (v_j and z never exist in HBM;
-    # backward rematerializes). 'xla' is the structured jnp twin (the
-    # §6b methodology: isolates structure from hand scheduling). Dense
-    # layout + BatchNorm, no graph sharding, mutually exclusive with
-    # fused_epilogue. cgconv_window=0 gathers over the whole node range
-    # (always correct; tests); a positive value is the CALLER-guaranteed
-    # neighbor-window bound from pallas_cgconv.window_width(max graph
-    # nodes) — an undersized bound silently zeroes out-of-window
-    # neighbors, so only pass one derived from the real dataset.
-    cgconv_impl: str | None = None
-    cgconv_window: int = 0
 
     @nn.compact
     def __call__(
@@ -143,27 +129,6 @@ class CGConv(nn.Module):
         over_mask: jax.Array | None = None,  # [O]
     ) -> jax.Array:
         f = self.features
-        if self.fused_epilogue is not None and (
-            self.dense_m is None or not self.use_batchnorm
-            or self.edge_axis_name is not None
-        ):
-            raise NotImplementedError(
-                "fused_epilogue requires the dense layout with BatchNorm "
-                "(it fuses the BN1->gate->mask->sum chain) and no graph "
-                "sharding"
-            )
-        if self.cgconv_impl is not None:
-            if (self.dense_m is None or not self.use_batchnorm
-                    or self.edge_axis_name is not None):
-                raise NotImplementedError(
-                    "cgconv_impl (the whole-conv fused kernel) requires "
-                    "the dense layout with BatchNorm and no graph sharding"
-                )
-            if self.fused_epilogue is not None:
-                raise NotImplementedError(
-                    "cgconv_impl subsumes fused_epilogue (the whole conv "
-                    "is one op); pick one"
-                )
         if self.dense_m is not None and self.edge_axis_name is not None:
             # Node-strip sharded dense layout (graph parallelism composed
             # with the fast path; parallel/edge_parallel.py). Shard s owns
@@ -243,47 +208,6 @@ class CGConv(nn.Module):
                     ),
                     axis,
                 )
-        elif self.dense_m is not None and self.cgconv_impl is not None:
-            # WHOLE-conv fused kernel (ops/pallas_cgconv.py): gather +
-            # fc_full + BN1 + gate + mask + sum as ONE custom-VJP op —
-            # v_j and z never exist in HBM ('pallas') or as named
-            # intermediates ('xla' structured twin). Parameter tree
-            # identical to the unfused branch (fc_full + bn1 shells);
-            # BN2 + the residual below are unchanged.
-            from cgnn_tpu.ops.pallas_cgconv import (
-                BN1Params,
-                FcFullParams,
-                fused_cgconv,
-                fused_cgconv_eval,
-            )
-
-            m = self.dense_m
-            n = nodes.shape[0]
-            e = edges
-            if e.ndim == 2:
-                e = e.reshape(n, m, -1)
-            emask2 = edge_mask.reshape(n, m)
-            tr = (None if in_slots is None else
-                  (in_slots, in_mask, over_slots, over_nodes, over_mask))
-            kernel, kbias = FcFullParams(2 * f, name="fc_full")(
-                2 * f + e.shape[-1]
-            )
-            bn1 = BN1Params(name="bn1")
-            scale, bn_bias, r_mean, r_var = bn1(2 * f)
-            if train:
-                agg, mean, var, n_real = fused_cgconv(
-                    nodes, e, kernel, kbias, scale, bn_bias, neighbors,
-                    emask2, tr, impl=self.cgconv_impl,
-                    window=self.cgconv_window, dtype=self.dtype,
-                )
-                bn1(2 * f, update=(mean, var, n_real))
-            else:
-                agg = fused_cgconv_eval(
-                    nodes, e, kernel, kbias, scale, bn_bias, neighbors,
-                    emask2, r_mean, r_var, tr, impl=self.cgconv_impl,
-                    window=self.cgconv_window, dtype=self.dtype,
-                )
-            agg = agg.astype(nodes.dtype)
         elif self.dense_m is not None:
             m = self.dense_m
             n = nodes.shape[0]
@@ -310,42 +234,26 @@ class CGConv(nn.Module):
                 z = _SplitFcFull(2 * f, dtype=self.dtype, name="fc_full")(
                     nodes, v_j, e
                 )
-            if self.use_batchnorm and self.fused_epilogue is not None:
-                # one custom-VJP op for BN1+gate+mask+sum with minimal
-                # activation passes (ops/fused_epilogue.py). Parameter
-                # tree identical to the unfused path (name='bn1'). The
-                # padding-slot zero-cotangent contract below holds here
-                # too: the kernel folds the mask into both the forward
-                # message and dz.
-                from cgnn_tpu.ops.fused_epilogue import FusedBN1GateSum
-
-                agg = FusedBN1GateSum(
-                    impl=self.fused_epilogue, name="bn1"
-                )(
-                    z, edge_mask.reshape(n, m),
+            if self.use_batchnorm:
+                # 3-D BN: statistics over the (N, M) slot axes directly —
+                # flattening to [N*M, 2F] costs a real layout-change copy
+                z = MaskedBatchNorm(dtype=self.dtype, name="bn1")(
+                    z, mask=edge_mask.reshape(n, m),
                     use_running_average=not train,
-                ).astype(nodes.dtype)
-            else:
-                if self.use_batchnorm:
-                    # 3-D BN: statistics over the (N, M) slot axes directly —
-                    # flattening to [N*M, 2F] costs a real layout-change copy
-                    z = MaskedBatchNorm(dtype=self.dtype, name="bn1")(
-                        z, mask=edge_mask.reshape(n, m),
-                        use_running_average=not train,
-                    )
-                with jax.named_scope(phases.CONV_GATE):
-                    gate, core = jnp.split(z, 2, axis=-1)
-                    msg = nn.sigmoid(gate) * nn.softplus(core)
-                    # LOAD-BEARING for gradients, not just values:
-                    # gather_transpose's scatter-free VJP assumes zero
-                    # cotangent on padding edge slots, which THIS mask
-                    # (together with masked BN statistics) guarantees.
-                    # Removing it would silently corrupt node gradients
-                    # (ops/segment.py gather_transpose docstring; parity
-                    # test: tests/test_batching.py two-tier backward).
-                    msg = msg * edge_mask.reshape(n, m, 1).astype(msg.dtype)
-                with jax.named_scope(phases.CONV_AGGREGATE):
-                    agg = msg.sum(axis=1)
+                )
+            with jax.named_scope(phases.CONV_GATE):
+                gate, core = jnp.split(z, 2, axis=-1)
+                msg = nn.sigmoid(gate) * nn.softplus(core)
+                # LOAD-BEARING for gradients, not just values:
+                # gather_transpose's scatter-free VJP assumes zero
+                # cotangent on padding edge slots, which THIS mask
+                # (together with masked BN statistics) guarantees.
+                # Removing it would silently corrupt node gradients
+                # (ops/segment.py gather_transpose docstring; parity
+                # test: tests/test_batching.py two-tier backward).
+                msg = msg * edge_mask.reshape(n, m, 1).astype(msg.dtype)
+            with jax.named_scope(phases.CONV_AGGREGATE):
+                agg = msg.sum(axis=1)
         else:
             with jax.named_scope(phases.CONV_GATHER):
                 v_i = gather(nodes, centers)
@@ -362,13 +270,7 @@ class CGConv(nn.Module):
                 msg = nn.sigmoid(gate) * nn.softplus(core)
                 msg = msg * edge_mask[:, None].astype(msg.dtype)
             with jax.named_scope(phases.CONV_AGGREGATE):
-                agg = aggregate_edge_messages(
-                    msg,
-                    centers,
-                    nodes.shape[0],
-                    impl=self.aggregation_impl,
-                    indices_are_sorted=self.assume_sorted_edges,
-                )
+                agg = aggregate_edge_messages(msg, centers, nodes.shape[0])
                 if self.edge_axis_name is not None:
                     # partial per-node sums from this edge shard -> full sums
                     agg = jax.lax.psum(agg, self.edge_axis_name)
@@ -420,14 +322,9 @@ class CrystalGraphConvNet(nn.Module):
     num_classes: int = 2
     dropout_rate: float = 0.0  # reference applies dropout for classification
     dtype: Any = jnp.float32
-    aggregation_impl: str | None = None
-    assume_sorted_edges: bool = True
     head: nn.Module | None = None  # e.g. MultiTaskHead; replaces fc stack
     edge_axis_name: str | None = None  # edge-sharded graph parallelism
     dense_m: int | None = None  # dense slot layout (see CGConv.dense_m)
-    fused_epilogue: str | None = None  # see CGConv.fused_epilogue
-    cgconv_impl: str | None = None  # whole-conv fused kernel (CGConv)
-    cgconv_window: int = 0  # neighbor-window bound (CGConv.cgconv_window)
 
     @nn.compact
     def __call__(
@@ -442,13 +339,8 @@ class CrystalGraphConvNet(nn.Module):
             nodes = CGConv(
                 features=self.atom_fea_len,
                 dtype=self.dtype,
-                aggregation_impl=self.aggregation_impl,
-                assume_sorted_edges=self.assume_sorted_edges,
                 edge_axis_name=self.edge_axis_name,
                 dense_m=self.dense_m,
-                fused_epilogue=self.fused_epilogue,
-                cgconv_impl=self.cgconv_impl,
-                cgconv_window=self.cgconv_window,
                 name=f"conv_{i}",
             )(
                 nodes,

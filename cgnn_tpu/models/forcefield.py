@@ -88,7 +88,6 @@ class ForceFieldCGCNN(nn.Module):
     dmax: float = 8.0
     step: float = 0.2
     dtype: Any = jnp.float32
-    aggregation_impl: str | None = None
     # dense edge-slot layout (data/graph.py pack_graphs dense_m): the
     # scatter-free aggregation applies to the force task too, and the
     # in-model geometry relies on the layout's structure, not only on its
@@ -150,7 +149,6 @@ class ForceFieldCGCNN(nn.Module):
                 nodes = CGConv(
                     features=self.atom_fea_len,
                     dtype=self.dtype,
-                    aggregation_impl=self.aggregation_impl,
                     # BatchNorm breaks train/eval force consistency (see
                     # CGConv)
                     use_batchnorm=False,

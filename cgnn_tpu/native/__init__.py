@@ -1,6 +1,6 @@
 """Native (C++) host kernels with lazy in-tree builds (ctypes, no pybind11).
 
-The TPU compute path is XLA/Pallas; these kernels cover the *host-side*
+The TPU compute path is XLA; these kernels cover the *host-side*
 runtime hot loops the reference delegates to C-backed libraries
 (SURVEY.md §2 native table). Without a compiler on PATH the numpy
 implementation is used instead; with one, a failed build is an error

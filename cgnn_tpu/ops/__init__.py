@@ -1,10 +1,9 @@
-"""Device-side ops: segment reductions, masked normalization, Pallas kernels.
+"""Device-side ops: gathers, segment reductions, masked normalization.
 
 TPU-native replacement for the reference's native kernel surface
 (SURVEY.md §2 "Native components" table): ATen gather + per-node reduction
-become XLA segment ops (and optionally a Pallas gather-scatter kernel), and
-cuDNN BatchNorm becomes an in-tree masked BatchNorm that keeps padding out of
-the batch statistics.
+become XLA row gathers and segment ops, and cuDNN BatchNorm becomes an
+in-tree masked BatchNorm that keeps padding out of the batch statistics.
 """
 
 from cgnn_tpu.ops.segment import (
@@ -12,7 +11,6 @@ from cgnn_tpu.ops.segment import (
     segment_mean,
     gather,
     aggregate_edge_messages,
-    set_default_aggregation_impl,
 )
 from cgnn_tpu.ops.norm import MaskedBatchNorm
 
@@ -21,6 +19,5 @@ __all__ = [
     "segment_mean",
     "gather",
     "aggregate_edge_messages",
-    "set_default_aggregation_impl",
     "MaskedBatchNorm",
 ]

@@ -25,16 +25,6 @@ on device under the padded-capacity discipline:
   dense layout's self-loop neighbor and zero features (the same padding
   contract ``pack_graphs`` writes).
 
-Two implementations behind one flag (the PR-9 §6b methodology):
-``impl='xla'`` is the vectorized jnp/`lax.sort` form (the default —
-XLA's sort and fusion are hard to beat until a chip A/B says
-otherwise); ``impl='pallas'`` runs each structure as one kernel
-invocation — candidate distances computed in VMEM and the top-M
-selection as ``dense_m`` lexicographic argmin rounds (sort-free, the
-shape a blocked TPU kernel wants) — auto-interpreted off-TPU so CPU CI
-pins variant parity. The two variants select identical edges wherever
-the f32 radius/tie decisions are exact (pinned by test).
-
 Overflow contract (INVARIANTS.md "raw-wire overflow flag"): the program
 re-derives each structure's needed image counts from its STAGED lattice
 (plane-spacing formula, ``data.rawbatch.needed_images_f32``) and flags
@@ -56,7 +46,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental import pallas as pl
 
 from cgnn_tpu.data.elements import full_embedding_table
 from cgnn_tpu.data.graph import GraphBatch
@@ -103,7 +92,7 @@ def _candidate_valid(amask, spec: RawSpec):
     return (valid & ~self_home).reshape(s_cap, s_cap * k)
 
 
-def _search_one_xla(frac, lat, amask, spec: RawSpec, offsets_f32):
+def _search_one(frac, lat, amask, spec: RawSpec, offsets_f32):
     """One structure's search (vmapped over the batch): ->
     (neighbors [S, M] i32 local, distances [S, M] f32,
     edge_mask [S, M] f32, n_edges i32, overflow bool)."""
@@ -133,106 +122,14 @@ def _search_one_xla(frac, lat, amask, spec: RawSpec, offsets_f32):
     return nbr, dist, emask.astype(jnp.float32), n_edges, overflow
 
 
-def _search_kernel(frac_ref, lat_ref, amask_ref, offs_ref, nbr_ref,
-                   dist_ref, em_ref, ne_ref, *, spec: RawSpec):
-    """Pallas kernel: ONE structure per grid step — candidate distances
-    in VMEM, then ``dense_m`` lexicographic argmin rounds (sort-free
-    top-M: each round takes the minimum (distance, candidate) pair per
-    center and masks it out — the selection order is IDENTICAL to the
-    sorted form because (d, c) keys are distinct by construction)."""
-    s_cap, m = spec.snode_cap, spec.dense_m
-    k = spec.n_images
-    c = s_cap * k
-    frac = frac_ref[0]
-    lat = lat_ref[0]
-    amask = amask_ref[0, 0]
-    d = _candidate_distances(frac, lat, offs_ref[...])
-    valid = _candidate_valid(amask, spec) & (d <= jnp.float32(spec.radius))
-    key = jnp.where(valid, d, jnp.float32(jnp.inf))
-    cand = lax.broadcasted_iota(jnp.int32, (s_cap, c), 1)
-    rows = lax.broadcasted_iota(jnp.int32, (s_cap, m), 0)
-    nbr_cols, dist_cols, em_cols = [], [], []
-    for _ in range(m):
-        dmin = jnp.min(key, axis=1, keepdims=True)  # [S, 1]
-        hit = jnp.isfinite(dmin[:, 0])
-        tie = key == dmin
-        cmin = jnp.min(jnp.where(tie, cand, c), axis=1)  # [S]
-        nbr_cols.append(jnp.where(hit, cmin // k, 0))
-        dist_cols.append(jnp.where(hit, dmin[:, 0], jnp.float32(0.0)))
-        em_cols.append(hit.astype(jnp.float32))
-        key = jnp.where(cand == cmin[:, None], jnp.float32(jnp.inf), key)
-    em = jnp.stack(em_cols, axis=1)
-    nbr = jnp.stack(nbr_cols, axis=1)
-    nbr_ref[0] = jnp.where(em > 0, nbr, rows)
-    dist_ref[0] = jnp.stack(dist_cols, axis=1)
-    em_ref[0] = em
-    ne_ref[0] = em.sum(keepdims=True).astype(jnp.int32)
-
-
-def _search_pallas(frac, lats, amask, spec: RawSpec, offsets_f32,
-                   interpret: bool):
-    g_cap, s_cap = amask.shape
-    m = spec.dense_m
-    kern = functools.partial(_search_kernel, spec=spec)
-    nbr, dist, em, ne = pl.pallas_call(
-        kern,
-        grid=(g_cap,),
-        in_specs=[
-            pl.BlockSpec((1, s_cap, 3), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, 3, 3), lambda g: (g, 0, 0)),
-            # [G, 1, S] / [G, 1, 1]: a block's last two dims must equal
-            # the array's (or be (8, 128)-aligned), so the per-structure
-            # axis cannot be one of them
-            pl.BlockSpec((1, 1, s_cap), lambda g: (g, 0, 0)),
-            pl.BlockSpec((spec.n_images, 3), lambda g: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, s_cap, m), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, s_cap, m), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, s_cap, m), lambda g: (g, 0, 0)),
-            pl.BlockSpec((1, 1, 1), lambda g: (g, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((g_cap, s_cap, m), jnp.int32),
-            jax.ShapeDtypeStruct((g_cap, s_cap, m), jnp.float32),
-            jax.ShapeDtypeStruct((g_cap, s_cap, m), jnp.float32),
-            jax.ShapeDtypeStruct((g_cap, 1, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(frac, lats, amask.astype(jnp.float32)[:, None, :], offsets_f32)
-    # the overflow flag reads only the lattice: a tiny vectorized jnp
-    # computation, shared verbatim with the XLA variant instead of
-    # burning an image-cap constant into the kernel
-    need = jax.vmap(
-        lambda la: _needed_images_jnp(la, spec.radius)
-    )(lats)
-    # padding slots never flag (no real atoms — same rule as the XLA
-    # variant)
-    overflow = (jnp.any(need > jnp.asarray(spec.images, jnp.float32),
-                        axis=1)
-                & jnp.any(amask > 0, axis=1))
-    return nbr, dist, em, ne[:, 0, 0], overflow
-
-
-def neighbor_search(frac, lats, amask, spec: RawSpec,
-                    impl: str = "xla", interpret: bool | None = None):
+def neighbor_search(frac, lats, amask, spec: RawSpec):
     """Batched in-program search -> (neighbors [G, S, M] i32 local,
     distances [G, S, M] f32, edge_mask [G, S, M] f32, n_edges [G] i32,
-    overflow [G] bool).
-
-    ``interpret=None`` auto-interprets the Pallas variant off-TPU (the
-    CPU-CI parity path; config.py backend rule)."""
-    if impl not in ("xla", "pallas"):
-        raise ValueError(f"impl must be 'xla' or 'pallas', got {impl!r}")
+    overflow [G] bool)."""
     offsets_f32 = jnp.asarray(
         spec.offsets_grid().astype(np.float32)
     )
-    if impl == "pallas":
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        return _search_pallas(frac, lats, amask, spec, offsets_f32,
-                              interpret)
-    one = functools.partial(_search_one_xla, spec=spec,
+    one = functools.partial(_search_one, spec=spec,
                             offsets_f32=offsets_f32)
     return jax.vmap(one)(frac, lats, amask)
 
@@ -262,8 +159,7 @@ def neighbor_search_hbm_bytes(g_cap: int, s_cap: int, k: int,
     }
 
 
-def make_raw_expander(spec: RawSpec, edge_dtype=jnp.float32,
-                      impl: str = "xla") -> Callable:
+def make_raw_expander(spec: RawSpec, edge_dtype=jnp.float32) -> Callable:
     """Jit-composable RawBatch -> (GraphBatch, overflow [G] bool,
     n_edges [G] i32) reconstruction — the raw-wire sibling of
     ``data.compact.make_expander``.
@@ -283,7 +179,7 @@ def make_raw_expander(spec: RawSpec, edge_dtype=jnp.float32,
     def expand(rb: RawBatch):
         g_cap, s_cap = rb.species.shape
         nbr, dist, emask, n_edges, overflow = neighbor_search(
-            rb.frac, rb.lattices, rb.atom_mask, spec, impl=impl
+            rb.frac, rb.lattices, rb.atom_mask, spec
         )
         node_mask = rb.atom_mask.reshape(-1).astype(jnp.float32)
         nodes = jnp.asarray(table)[rb.species.reshape(-1)] \

@@ -1,18 +1,14 @@
-"""Segment reductions and the edge-aggregation dispatch point.
+"""Gathers, their declared transposes, and segment reductions.
 
 The reference's hottest device loop is the per-edge gather + per-node
-scatter-sum inside its conv layer (SURVEY.md §3.3): on GPU it is ATen
-``index_select`` + ``sum(dim=1)``. The TPU-native equivalents (SURVEY.md §2
-native table) are:
-
-- ``xla``: `jax.ops.segment_sum` over a flat COO edge list. XLA lowers this
-  to a sorted-scatter that fuses with the surrounding elementwise work and is
-  deterministic per compilation (unlike CUDA atomicAdd scatter).
-- ``pallas``: a hand-written gather-scatter kernel (cgnn_tpu.ops.pallas_scatter)
-  for the cases where XLA's scatter is not bandwidth-optimal.
-
-`aggregate_edge_messages` is the single dispatch point; the model layer never
-calls a backend directly, so benchmarking/falling back is a one-flag change.
+sum inside its conv layer (SURVEY.md §3.3): on GPU it is ATen
+``index_select`` + ``sum(dim=1)``. Here the dense conv gathers through
+``gather_slot_major`` (a row gather whose transpose is a second row
+gather through the packer's mapping; the sum over M needs no op of its
+own), the node-strip sharded conv through ``gather_transpose``, and the
+flat COO conv through ``gather`` and ``aggregate_edge_messages``, a
+``segment_sum`` over the sorted centres the packers emit. XLA compiles
+all of it; there is no hand-written kernel.
 """
 
 from __future__ import annotations
@@ -20,23 +16,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-_DEFAULT_IMPL = "xla"
-_VALID_IMPLS = ("xla", "pallas", "sort")
-
-# gather_transpose differentiation mechanism. "linear_call" (default,
-# round 4+) composes with repeated/forward-mode AD (the force task's
-# grad-over-grad needs it); "custom_vjp" is the round-3 implementation,
-# kept ONLY so the interleaved A/B harness (scripts/bench_ab.py) can
-# measure both mechanisms in one process — it emits the same transpose
-# math but rejects second-order AD.
-_TRANSPOSE_IMPL = "linear_call"
-
 
 def _transpose_cotangent(ct, slots, msk, o_slots, o_nodes, o_mask,
                          num_nodes: int, degree_axis: int = 1):
     """The shared cotangent transpose ([E, F] -> [N, F]) — ONE body for
-    every AD mechanism (linear_call / custom_vjp) and both row orders, so
-    an A/B isolates the mechanism, never the math.
+    both row orders.
 
     ``slots`` is flat and ``msk`` says how the gathered rows are viewed:
     [N, In] (node-major, ``degree_axis`` 1) or [In, N] (slot-major,
@@ -59,40 +43,17 @@ def _transpose_cotangent(ct, slots, msk, o_slots, o_nodes, o_mask,
     return grad
 
 
-def _linear(fwd, trans, res, x):
-    """``fwd(res, x)``, linear in ``x``, with the declared transpose
-    ``trans(res, ct)``, through the selected AD mechanism."""
-    if _TRANSPOSE_IMPL == "custom_vjp":  # round-3 mechanism (A/B only)
-
-        @jax.custom_vjp
-        def g(x):
-            return fwd(res, x)
-
-        g.defvjp(lambda x: (g(x), None), lambda _, ct: (trans(res, ct),))
-        return g(x)
-    return jax.custom_derivatives.linear_call(fwd, trans, res, x)
+# ``fwd(res, x)``, linear in ``x``, with the declared transpose
+# ``trans(res, ct)``. A linear op with a declared transpose composes with
+# forward-mode AD and with repeated differentiation, which the force task
+# runs in every step (grad-over-grad: the outer parameter gradient
+# linearizes the inner position gradient; a ``custom_vjp`` rejects that jvp).
+_linear = jax.custom_derivatives.linear_call
 
 
 def _gather_rows(res, x):
     """The forward of both transposable gathers: ``x[res[0]]``."""
     return gather(x, res[0])
-
-
-def set_transpose_impl(impl: str) -> None:
-    global _TRANSPOSE_IMPL
-    if impl not in ("linear_call", "custom_vjp"):
-        raise ValueError(f"unknown transpose impl {impl!r}")
-    _TRANSPOSE_IMPL = impl
-
-
-def set_default_aggregation_impl(impl: str) -> None:
-    """Select the global default edge-aggregation backend ('xla'|'pallas'|'sort')."""
-    global _DEFAULT_IMPL
-    if impl not in _VALID_IMPLS:
-        raise ValueError(f"impl must be one of {_VALID_IMPLS}, got {impl!r}")
-    if impl == "pallas":  # fail eagerly, not from inside a jitted trace
-        import cgnn_tpu.ops.pallas_scatter  # noqa: F401
-    _DEFAULT_IMPL = impl
 
 
 def gather(values: jax.Array, indices: jax.Array) -> jax.Array:
@@ -142,13 +103,8 @@ def gather_transpose(
     BatchNorm statistics exclude padding, so no gradient path reaches a
     padded slot's ``v_j``.
 
-    Implemented with ``jax.custom_derivatives.linear_call`` rather than
-    ``custom_vjp``: the gather is linear in ``nodes``, and a linear op with
-    a declared transpose composes with forward-mode AD and with REPEATED
-    differentiation — which the force task needs (grad-over-grad: the
-    outer params gradient linearizes the inner positions gradient, and
-    ``custom_vjp`` rejects that jvp). The transpose body is the same
-    gather + masked in-degree reduction as before.
+    The gather is linear in ``nodes`` and is declared so (``_linear``),
+    which is what lets the force task differentiate it twice.
     """
     num_nodes = nodes.shape[0]
 
@@ -196,9 +152,8 @@ def gather_slot_major(
     lowering. This keeps the flat one-dimensional row gather and changes
     only the order of its indices.
 
-    Callers that hold the flat [E, F] form (the sharded dense branch, the
-    COO branch, pallas_cgconv's structured twin) keep ``gather`` /
-    ``gather_transpose``.
+    The callers that hold the flat [E, F] form keep ``gather_transpose``
+    (the node-strip sharded dense conv) and ``gather`` (the COO conv).
     """
     n, m = nodes.shape[0], dense_m
 
@@ -255,43 +210,14 @@ def segment_mean(
     return total / jnp.maximum(denom, 1.0)[..., None]
 
 
-def _aggregate_sort(messages: jax.Array, centers: jax.Array, num_nodes: int) -> jax.Array:
-    """Sort-based aggregation: sort edges by center then segment-sum.
-
-    On TPU, scatter over a *sorted* index vector lowers to a cheaper
-    monotonic-update pattern; useful when the batcher cannot pre-sort.
-    """
-    order = jnp.argsort(centers)
-    return jax.ops.segment_sum(
-        jnp.take(messages, order, axis=0),
-        jnp.take(centers, order),
-        num_segments=num_nodes,
-        indices_are_sorted=True,
-    )
-
-
 def aggregate_edge_messages(
-    messages: jax.Array,
-    centers: jax.Array,
-    num_nodes: int,
-    impl: str | None = None,
-    indices_are_sorted: bool = True,
+    messages: jax.Array, centers: jax.Array, num_nodes: int
 ) -> jax.Array:
-    """Scatter-sum per-edge messages into per-node accumulators.
+    """Sum per-edge messages into per-node accumulators (the COO conv).
 
-    The batcher (data/graph.py) emits edges sorted by center node, so the
-    default path tells XLA ``indices_are_sorted`` and avoids a device sort.
+    The packers emit edges sorted by centre node (data/invariants.py), so
+    XLA is told ``indices_are_sorted`` and no device sort runs.
     """
-    impl = impl or _DEFAULT_IMPL
-    if impl == "xla":
-        return jax.ops.segment_sum(
-            messages, centers, num_segments=num_nodes,
-            indices_are_sorted=indices_are_sorted,
-        )
-    if impl == "sort":
-        return _aggregate_sort(messages, centers, num_nodes)
-    if impl == "pallas":
-        from cgnn_tpu.ops.pallas_scatter import segment_sum_pallas
-
-        return segment_sum_pallas(messages, centers, num_nodes)
-    raise ValueError(f"unknown aggregation impl {impl!r}")
+    return jax.ops.segment_sum(
+        messages, centers, num_segments=num_nodes, indices_are_sorted=True
+    )

@@ -291,8 +291,7 @@ class MicroBatcher:
                     f"unknown priority class {c!r} in class_max_wait_ms "
                     f"(have: {list(CLASSES)})")
             self.class_wait[c] = float(ms) / 1000.0
-        # padding-slack backfill switch (the bench.py --ab backfill leg
-        # turns it off for the baseline)
+        # padding-slack backfill switch
         self.backfill = bool(backfill)
         # WFQ tenant weights (share of service per unit weight); tenants
         # absent from the map get weight 1.0

@@ -44,10 +44,8 @@ def structure_fingerprint(graph: CrystalGraph) -> str:
     blake2b, not sha1: faster in software (no SHA-NI dependency — on
     accelerator hosts whose CPUs lack it, sha1 falls off a cliff) and
     this is an in-memory cache key with no persisted state, so the hash
-    can change between releases without a migration. The per-host
-    sha1/blake2b ratio is measured by ``bench.py --ab cachepart``
-    (``fingerprint_hash_us``). digest_size=20 keeps the hex length
-    sha1-compatible for logs and tier prefixes.
+    can change between releases without a migration. digest_size=20
+    keeps the hex length sha1-compatible for logs and tier prefixes.
     """
     h = hashlib.blake2b(digest_size=20)
     for arr in (graph.atom_fea, graph.edge_fea, graph.centers,

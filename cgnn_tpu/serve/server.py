@@ -298,10 +298,9 @@ class InferenceServer:
         # and commutatively, so the `_hist` families are what the
         # router's /metrics/fleet pools into one fleet-wide truth. The
         # SLO burn-rate engine and the embedded time-series ring ride
-        # the same switch (`slo_layer=False` is the A/B baseline,
-        # bench.py --ab slo). Pure host-side bookkeeping: served numbers
-        # are bit-exact either way and nothing is staged into jitted
-        # code.
+        # the same switch (`slo_layer`). Pure host-side bookkeeping:
+        # served numbers are bit-exact either way and nothing is staged
+        # into jitted code.
         from cgnn_tpu.observe.hist import (
             LATENCY_MS_BOUNDS,
             OCCUPANCY_BOUNDS,
@@ -2417,10 +2416,6 @@ def load_server(
             "per-atom output extraction is offline-only (predict.py)"
         )
     model_cfg, data_cfg = cfg["model_cfg"], cfg["data_cfg"]
-    # serving admits any structure that fits the ladder: widen
-    # training-set-derived bounds (ModelConfig.for_arbitrary_inputs —
-    # the cgconv window contract)
-    model_cfg = model_cfg.for_arbitrary_inputs()
     model = build_model(model_cfg, data_cfg, cfg["task"], log_fn=log_fn)
     if calibration is None:
         # keep_geometry: raw-wire spec planning (below) calibrates its

@@ -146,7 +146,7 @@ class ShapeSet:
         return (self.compact is not None
                 and self.compact.graph_compactable(graph))
 
-    def raw_expander(self, impl: str = "xla"):
+    def raw_expander(self):
         """Jit-composable RawBatch -> (GraphBatch, overflow, n_edges)
         for this set's raw spec (None without one) — hand it to
         ``train.step.make_predict_step(raw_expander=...)``."""
@@ -154,8 +154,7 @@ class ShapeSet:
             return None
         from cgnn_tpu.ops.neighbor_search import make_raw_expander
 
-        return make_raw_expander(self.raw, edge_dtype=self.edge_dtype,
-                                 impl=impl)
+        return make_raw_expander(self.raw, edge_dtype=self.edge_dtype)
 
     def admits_raw(self, rs) -> bool:
         """Host pre-check: can this wire-form structure be staged raw

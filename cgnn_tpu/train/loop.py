@@ -334,10 +334,11 @@ class PackOncePlan:
 
 class PendingPairMetrics:
     """A deferred epoch-pair sums fetch running on a background thread
-    (ISSUE 5 satellite: SCAN_COST r5 measured ``pair_fetch_s`` at
-    224.9 ms of a 256 ms bench-scale epoch — almost all of it the fetch
-    WAITING for the epoch's in-flight compute, during which the host sat
-    idle instead of dispatching the next epoch).
+    (ISSUE 5 satellite: the fetch measured 224.9 ms of a 256 ms
+    bench-scale epoch on the retired runtime, ``git show
+    a13ea23:SCAN_COST.json`` — almost all of it the fetch WAITING for the
+    epoch's in-flight compute, during which the host sat idle instead of
+    dispatching the next epoch).
 
     ``result()`` joins the thread and returns ``(train_means,
     val_means)`` — the exact values the synchronous path computes, from
@@ -494,11 +495,6 @@ class ScanEpochDriver:
             else None
         )
         self._stage = stage if stage is not None else jax.device_put
-        # per-phase wall-clock accounting (scripts/scan_cost.py reads this
-        # to attribute the driver's fixed costs); keys are cumulative
-        # seconds, reset by the caller when desired
-        self.timings: dict[str, float] = {}
-        t0 = time.perf_counter()
         with self._span("scan.stage") as args:
             self._train_groups = self._stack_groups(train_batches)
             self._val_groups = self._stack_groups(val_batches)
@@ -508,7 +504,6 @@ class ScanEpochDriver:
                 telemetry.counter_add("staged_bytes", args["bytes"])
                 telemetry.counter_add("staged_edge_fea_bytes",
                                       args["edge_fea_bytes"])
-        self.timings["init_stack_stage_s"] = time.perf_counter() - t0
         self._train_body, self._eval_body = train_body, eval_body
         self._train_scans: dict = {}
         self._eval_scans: dict = {}
@@ -591,7 +586,7 @@ class ScanEpochDriver:
     # of their weight — ending on a single-shape 16-step chunk would skew
     # eval statistics toward one size class (observed: val MAE 2x worse at
     # MP-146k scale until the tail was mixed). Capped at n//4 per group
-    # (SCAN_COST.json r4): a FIXED 8-per-group tail turned small epochs
+    # (r4 scan-cost record): a FIXED 8-per-group tail turned small epochs
     # into mostly single-step dispatching — at the 18-batch bench scale it
     # was the whole 31.5k-vs-50k gap — while a proportional tail keeps the
     # last few steps shape-mixed at every scale
@@ -660,7 +655,7 @@ class ScanEpochDriver:
         # weighted group-pick sequence, PRECOMPUTED here (ISSUE 9
         # satellite): the per-chunk np.array + rng.choice(p=...) that
         # used to run on the DISPATCH path in run_queues (a measurable
-        # host-side fixed cost per chunk — scan_cost.py, PERF.md §6c)
+        # host-side fixed cost per chunk)
         # moves into the schedule build, which _drive prebuilds one
         # epoch AHEAD so it overlaps the in-flight epoch. Same sampler,
         # same weights (remaining steps per group), same rng stream
@@ -686,7 +681,7 @@ class ScanEpochDriver:
     def warm(self, state: TrainState) -> TrainState:
         """Compile every (shape, chunk-length) scan program the driver can
         draw, so no first-compile (seconds through a high-latency link)
-        lands inside a caller's timed region (bench.py, scan_cost.py).
+        lands inside a caller's timed region.
 
         Runs the REAL train bodies (compilation requires execution here),
         but against a disposable on-device copy of ``state``, so the
@@ -781,7 +776,6 @@ class ScanEpochDriver:
         ``prebuild=False`` defers the next-epoch schedule prebuild to the
         caller (run_epoch_pair's async-fetch mode overlaps it with the
         background sums fetch instead)."""
-        t_drive0 = time.perf_counter()
         sched_key = (id(groups), train, first)
         if train:
             sched = self._sched_cache.pop(sched_key, None)
@@ -812,9 +806,8 @@ class ScanEpochDriver:
         # a list-of-dicts device_get at epoch end moved every scalar as
         # its own link round trip, which at bench scale (17 chunks x 4
         # keys) was ~250 ms/epoch: the whole driver-vs-steady gap
-        # (SCAN_COST.json r4; metrics.fetch_device_sums)
+        # (metrics.fetch_device_sums)
         dev_sums: dict | None = None
-        n_chunks = 0
         executed = 0
         # warm-up dispatches are not run work: no span for them, as
         # Telemetry.warmup() already keeps them out of the counters
@@ -823,7 +816,7 @@ class ScanEpochDriver:
                  else None)
 
         def run_queues(qs, weighted):
-            nonlocal state, dev_sums, n_chunks, executed
+            nonlocal state, dev_sums, executed
             rr = 0
             picks = iter(pick_order)
             by_index = list(qs)  # pick_order indexes the BUILD order
@@ -860,16 +853,12 @@ class ScanEpochDriver:
                                     steps=int(chunk.shape[0]), train=train):
                         state, chunk_sums = fn(state, stacked, chunk)
                 dev_sums = accumulate_on_device(dev_sums, chunk_sums)
-                n_chunks += 1
                 executed += int(chunk.shape[0])
                 if not chunks:
                     qs.remove(entry)
 
-        t_sched = time.perf_counter()
         run_queues(queues, weighted=multi and not first)
-        t_chunks = time.perf_counter()
         run_queues(tails, weighted=False)  # mixed single-step tail
-        t_tail = time.perf_counter()
         # prebuild + stage the NEXT train epoch's schedule while this
         # epoch's dispatches are still executing: its H2D transfers ride
         # along the in-flight work instead of stalling the next epoch's
@@ -878,19 +867,6 @@ class ScanEpochDriver:
         if train and not self.aborted and prebuild:
             self._sched_cache[(id(groups), True, False)] = \
                 self._build_sched(groups, True, False)
-        t_prebuild = time.perf_counter()
-        phase = "train" if train else "eval"
-        tm = self.timings
-        tm[f"{phase}_sched_s"] = tm.get(f"{phase}_sched_s", 0.0) \
-            + (t_sched - t_drive0)
-        tm[f"{phase}_chunk_dispatch_s"] = tm.get(
-            f"{phase}_chunk_dispatch_s", 0.0) + (t_chunks - t_sched)
-        tm[f"{phase}_tail_dispatch_s"] = tm.get(
-            f"{phase}_tail_dispatch_s", 0.0) + (t_tail - t_chunks)
-        tm[f"{phase}_prebuild_s"] = tm.get(f"{phase}_prebuild_s", 0.0) \
-            + (t_prebuild - t_tail)
-        tm[f"{phase}_dispatches"] = tm.get(f"{phase}_dispatches", 0.0) \
-            + n_chunks
         if self._telemetry is not None:
             self._telemetry.counter_add("scan_steps", executed)
         return state, dev_sums, executed
@@ -923,9 +899,8 @@ class ScanEpochDriver:
         val_means).
 
         ``async_fetch=True`` (ISSUE 5 satellite) returns ``(state,
-        PendingPairMetrics)`` instead: the sums fetch — SCAN_COST r5's
-        ``pair_fetch_s``, 224.9 ms of a 256 ms bench epoch, almost all
-        of it waiting for the epoch's in-flight compute — runs on a
+        PendingPairMetrics)`` instead: the sums fetch — almost all of it
+        waiting for the epoch's in-flight compute — runs on a
         background thread while the caller keeps dispatching (the next
         epoch's first scans in ``fit``), and the next-epoch schedule
         prebuild moves AFTER the fetch thread starts so it overlaps the
@@ -963,10 +938,7 @@ class ScanEpochDriver:
         combined |= {f"e:{k}": v for k, v in (ev_sums or {}).items()}
 
         def fetch_pair():
-            t0 = time.perf_counter()
             fetched = fetch_device_sums(combined or None)
-            self.timings["pair_fetch_s"] = self.timings.get(
-                "pair_fetch_s", 0.0) + (time.perf_counter() - t0)
             tr = {k[2:]: v for k, v in fetched.items()
                   if k.startswith("t:")}
             ev = {k[2:]: v for k, v in fetched.items()
@@ -1188,6 +1160,7 @@ def fit(
         staging["staged_mb"] = round(staged_bytes / 1e6, 1)
         staging["compact"] = compact is not None
         if check_device_resident_fit(staged_bytes, log_fn=log_fn):
+            t_stage = time.perf_counter()
             with telemetry.span("stage_scan_stacks",
                                 staged_mb=staging["staged_mb"]):
                 driver = ScanEpochDriver(
@@ -1201,10 +1174,10 @@ def fit(
                     telemetry=telemetry,
                     preempt=preempt,
                 )
-            telemetry.sample_hbm("post_staging")
             staging["stack_stage_dispatch_s"] = round(
-                driver.timings["init_stack_stage_s"], 2
+                time.perf_counter() - t_stage, 2
             )
+            telemetry.sample_hbm("post_staging")
         else:
             # LOUD fallback (check_device_resident_fit already logged the
             # numbers): keep the packed batches host-side and restage per
@@ -1280,8 +1253,7 @@ def fit(
             on_epoch_metrics(epoch, train_m, val_m)
         return is_best
 
-    # ISSUE 5 satellite: the epoch-pair sums fetch (SCAN_COST r5:
-    # pair_fetch_s 224.9 ms of a 256 ms bench epoch) moves to a
+    # ISSUE 5 satellite: the epoch-pair sums fetch moves to a
     # background thread whenever the divergence monitor doesn't need the
     # sums before proceeding (--guard rollback). Full one-epoch-deep
     # overlap — epoch N's fetch runs while epoch N+1's scans dispatch —

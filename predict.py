@@ -169,9 +169,6 @@ def _run(args, mgr) -> int:
     data_cfg = DataConfig.from_meta(meta["data"])
     task = meta.get("task", "regression")
     force_task = task == "force"
-    # arbitrary inference inputs: widen training-set-derived bounds
-    # (ModelConfig.for_arbitrary_inputs — the cgconv window contract)
-    model_cfg = model_cfg.for_arbitrary_inputs()
     model = build_model(model_cfg, data_cfg, task, log_fn=print)
 
     if args.cache and not os.path.exists(args.cache):

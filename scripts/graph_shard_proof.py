@@ -31,7 +31,7 @@ structure:
       -> what the graph axis itself costs the dense path (collectives +
          replicated BN2/head + the tier-M transpose backward)
 
-Timing follows bench.py's fencing convention: each round ends in a VALUE
+Timing is fenced: each round ends in a VALUE
 FETCH of the last step's metrics through the donated-state chain.
 
 Prints one JSON line; --out writes it to a file (GRAPH_SHARD_PROOF.json).

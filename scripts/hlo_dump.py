@@ -12,7 +12,7 @@ Writes:
   HLO_TRAIN_STEP.txt   full optimized HLO (the evidence)
   prints one JSON line with the ranked formatting ops
 
-Usage: python scripts/hlo_dump.py [--n 8192] [--fused-epilogue off|xla|pallas]
+Usage: python scripts/hlo_dump.py [--n 8192]
 
 ``--cell NAME`` (PR 25) is another job on the same idea: compile a
 benchmark cell's largest scan program at its REAL size for the DESCRIBED
@@ -195,8 +195,6 @@ def main(argv=None) -> int:
                    help="--cell: the scan program's chunk length")
     p.add_argument("--n", type=int, default=8192)
     p.add_argument("--batch-size", type=int, default=512)
-    p.add_argument("--fused-epilogue", choices=["off", "xla", "pallas"],
-                   default="off")
     p.add_argument("--out", type=str, default=None)
     p.add_argument("--top", type=int, default=20)
     args = p.parse_args(argv)
@@ -225,8 +223,6 @@ def main(argv=None) -> int:
     model = CrystalGraphConvNet(
         atom_fea_len=64, n_conv=3, h_fea_len=128, dtype=jax.numpy.bfloat16,
         dense_m=12,
-        fused_epilogue=None if args.fused_epilogue == "off"
-        else args.fused_epilogue,
     )
     tx = make_optimizer(optim="sgd", lr=0.01, lr_milestones=[10**9])
     state = create_train_state(
@@ -258,7 +254,6 @@ def main(argv=None) -> int:
     total = sum(d["bytes"] for d in findings)
     out = {
         "metric": "hlo_formatting_ops",
-        "fused_epilogue": args.fused_epilogue,
         "device": str(jax.devices()[0].device_kind),
         "hlo_file": args.out,
         "hlo_instructions": len(txt.splitlines()),

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """MAE parity harness: JAX framework vs the in-tree torch CGCNN oracle.
 
-BASELINE.md's acceptance row has two halves: throughput (bench.py) and
+BASELINE.md's acceptance row has two halves: throughput (benchmark/run.py) and
 "formation-energy MAE <= GPU baseline". The reference tree is unavailable
 (SURVEY.md §0), so the GPU baseline is *measured* here by training the
 in-tree torch oracle (tests/oracle/torch_cgcnn.py — the lineage

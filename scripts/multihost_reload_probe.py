@@ -58,8 +58,7 @@ def main() -> int:
     mgr = CheckpointManager(ckpt_dir, log_fn=print)
     meta = mgr.read_meta("latest")
     cfg = plan_from_state(meta)
-    model = build_model(cfg["model_cfg"].for_arbitrary_inputs(),
-                        cfg["data_cfg"], cfg["task"])
+    model = build_model(cfg["model_cfg"], cfg["data_cfg"], cfg["task"])
     graphs = load_synthetic(16, cfg["data_cfg"].featurize_config(), seed=0)
     dense_m = cfg["model_cfg"].dense_m or None
     nc, ec = capacities_for(graphs, 8, dense_m=dense_m, snug=True)

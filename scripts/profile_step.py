@@ -31,8 +31,8 @@ from cgnn_tpu.observe.metrics_io import jsonfinite  # noqa: E402
 
 
 def build_workload(dense_m=12):
-    """The bench.py PRIMARY workload: MP-like distribution, dense layout,
-    snug packing, bf16 edge storage (kept in lockstep with bench.py)."""
+    """The flagship workload (as cell mp.train): MP-like distribution,
+    dense layout, snug packing, bf16 edge storage."""
     import jax
     import numpy as np
 
@@ -74,7 +74,7 @@ def build_state(batches, dense_m=12):
 
 
 def measure_dispatch_loop(state, step, device_batches, real_per_batch, n=60):
-    """Per-step dispatch (bench.py round-2 mode): host dispatches every step."""
+    """Per-step dispatch: host dispatches every step."""
     import jax  # noqa: F401
 
     structures = 0.0

@@ -3,7 +3,7 @@
 
 Real Materials Project data is unavailable offline, so this exercises the
 full pipeline at MP-146k SCALE with the synthetic MP-like distribution
-(lognormal ~30 atoms — the same distribution bench.py measures):
+(lognormal ~30 atoms — the distribution of the cell mp.train):
 
   1. generate + featurize N structures (timed: host preprocessing rate).
      Single-process by design ON THIS HOST: the box exposes one CPU core,
@@ -15,7 +15,7 @@ full pipeline at MP-146k SCALE with the synthetic MP-like distribution
      artifact SURVEY.md §7 phase 4 prescribes)
   3. train --epochs epochs of band-gap-style regression on the visible
      device (timed per epoch: END-TO-END throughput including host packing
-     and prefetch, not just the jitted step bench.py isolates), with
+     and prefetch, not just the jitted step), with
      --pack-once exercising the cached-dataset fast path
 
 Prints one JSON line with every stage's numbers.

@@ -4,7 +4,7 @@
 The ISSUE-1 acceptance criterion: with step-level telemetry on the bench
 PRIMARY workload, per-step records stream from inside the scan AND the
 measured step-time overhead stays < 5%. This harness builds the PRIMARY
-MP-like workload (bench.py distribution), drives ScanEpochDriver epochs
+MP-like workload (the cell mp.train's distribution), drives ScanEpochDriver epochs
 with telemetry off vs step INTERLEAVED in one process (paired runs:
 PERF.md "End-to-end metrics"), and prints
 one JSON line:

@@ -97,3 +97,60 @@ def test_multitask_meta_roundtrip():
     back = ModelConfig.from_meta(cfg.to_meta())
     assert back.multi_task_head is True
     assert back.num_targets == 3
+
+
+_LEGACY_METAS = {
+    # as ModelConfig.to_meta() wrote them before the kernel switches went
+    # (PR 29): every key present, 'aggregation' as a sentinel string
+    "fused-epilogue": dict(dense_m=8, aggregation="__none__",
+                           fused_epilogue="xla", cgconv_impl="",
+                           cgconv_window=0),
+    "whole-conv-kernel": dict(dense_m=8, aggregation="__none__",
+                              fused_epilogue="", cgconv_impl="pallas",
+                              cgconv_window=384),
+    "aggregation-sort": dict(dense_m=0, aggregation="sort",
+                             fused_epilogue="", cgconv_impl="",
+                             cgconv_window=0),
+    "all-off": dict(dense_m=8, aggregation="__none__", fused_epilogue="",
+                    cgconv_impl="", cgconv_window=0),
+}
+
+
+@pytest.mark.parametrize("meta", sorted(_LEGACY_METAS))
+def test_legacy_checkpoint_meta_builds_the_one_model(meta):
+    """A checkpoint whose meta carries the removed kernel switches restores
+    as the one model: ``from_meta`` drops the keys and ``build`` gives the
+    parameter tree (paths, shapes, initial values) a config written today
+    gives — the switches never changed the tree."""
+    import jax
+
+    from cgnn_tpu.data.dataset import load_synthetic
+
+    kept = dict(atom_fea_len=16, n_conv=2, h_fea_len=24, n_h=1,
+                num_targets=1, classification=0, num_classes=2, dropout=0.0,
+                dtype="float32", multi_task_head=0)
+    legacy = kept | _LEGACY_METAS[meta]
+    cfg = ModelConfig.from_meta(legacy)
+    today = ModelConfig(atom_fea_len=16, n_conv=2, h_fea_len=24,
+                        dense_m=legacy["dense_m"])
+    assert cfg == today
+    assert not set(cfg.to_meta()) & (set(_LEGACY_METAS[meta]) - {"dense_m"})
+
+    dense_m = cfg.dense_m or None
+    graphs = load_synthetic(6, FeaturizeConfig(radius=5.0, max_num_nbr=8),
+                            seed=4, max_atoms=6)
+    nc, ec = capacities_for(graphs, 6, dense_m=dense_m)
+    batch = next(batch_iterator(graphs, 6, nc, ec, dense_m=dense_m))
+    got = cfg.build().init(jax.random.key(0), batch)
+    want = today.build().init(jax.random.key(0), batch)
+    assert (jax.tree_util.tree_structure(got)
+            == jax.tree_util.tree_structure(want))
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # the scopes every kernel fork declared, so its checkpoints restore
+    assert set(got["params"]) == {"embedding", "conv_0", "conv_1",
+                                  "conv_to_fc", "fc_out"}
+    assert set(got["params"]["conv_0"]) == {"fc_full", "bn1", "bn2"}
+    out = cfg.build().apply(got, batch)
+    assert np.isfinite(np.asarray(out)).all()

@@ -12,6 +12,7 @@ from cgnn_tpu.ops.segment import (
     aggregate_edge_messages,
     gather,
     gather_slot_major,
+    gather_transpose,
     segment_mean,
     segment_sum,
 )
@@ -35,16 +36,35 @@ class TestSegmentOps:
         got = segment_mean(data, ids, 3, weights=w)
         np.testing.assert_allclose(got, [[3.0], [6.0], [0.0]], atol=1e-6)
 
-    @pytest.mark.parametrize("impl", ["xla", "sort"])
-    def test_aggregate_impls_agree(self, impl):
+    @pytest.mark.parametrize("shape", ["64x16x8", "1000x300x32",
+                                       "2048x513x16", "skew"])
+    def test_aggregate_matches_loop(self, shape):
+        """The COO conv's aggregation is the per-node sum of its edges'
+        messages, at odd shapes and with empty nodes and one hub node of
+        huge degree; its gradient is the gather of the cotangent."""
         rng = np.random.default_rng(1)
-        msgs = rng.normal(size=(64, 8)).astype(np.float32)
-        centers = np.sort(rng.integers(0, 16, size=64)).astype(np.int32)
-        base = segment_sum(jnp.asarray(msgs), jnp.asarray(centers), 16)
+        if shape == "skew":
+            n, f = 260, 8
+            centers = np.sort(np.concatenate([
+                np.full(700, 5),             # hub: degree 700
+                rng.integers(100, 120, 50),  # sparse middle, gaps elsewhere
+                np.full(30, n - 1),          # tail node
+            ])).astype(np.int32)
+        else:
+            e, n, f = map(int, shape.split("x"))
+            centers = np.sort(rng.integers(0, n, size=e)).astype(np.int32)
+        msgs = rng.normal(size=(len(centers), f)).astype(np.float32)
+        expected = np.zeros((n, f), np.float64)
+        for row, i in zip(msgs, centers):
+            expected[i] += row
         got = aggregate_edge_messages(
-            jnp.asarray(msgs), jnp.asarray(centers), 16, impl=impl
-        )
-        np.testing.assert_allclose(got, base, rtol=1e-5, atol=1e-5)
+            jnp.asarray(msgs), jnp.asarray(centers), n)
+        np.testing.assert_allclose(got, expected, rtol=1e-5, atol=1e-4)
+        w = jnp.asarray(rng.normal(size=(n, f)).astype(np.float32))
+        grad = jax.grad(lambda x: (aggregate_edge_messages(
+            x, jnp.asarray(centers), n) * w).sum())(jnp.asarray(msgs))
+        np.testing.assert_array_equal(
+            np.asarray(grad), np.asarray(w)[centers])
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("mapped", [False, True])
@@ -84,6 +104,27 @@ class TestSegmentOps:
         np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
 
 
+def test_linear_gather_survives_forward_over_reverse():
+    """``gather_transpose`` (the flat form the node-strip sharded conv
+    keeps) is declared linear with its transpose, so forward-mode over
+    reverse-mode composes — ``jax.jvp`` of ``jax.grad``, what a
+    ``custom_vjp`` rejects — and equals plain autodiff of ``jnp.take``."""
+    n, m, f = 24, 4, 5
+    nodes, nbrs, mapping = _dense_gather_case(n, m, f, jnp.float32)
+    rng = np.random.default_rng(11)
+    w = jnp.asarray(rng.normal(size=(n * m, f)).astype(np.float32))
+    tangent = jnp.asarray(rng.normal(size=(n, f)).astype(np.float32))
+
+    def hvp(fn):
+        grad = jax.grad(lambda x: (jnp.tanh(fn(x)) * w).sum())
+        return jax.jvp(grad, (nodes,), (tangent,))
+
+    g_got, t_got = hvp(lambda x: gather_transpose(x, nbrs, *mapping))
+    g_want, t_want = hvp(lambda x: jnp.take(x, nbrs, axis=0))
+    np.testing.assert_allclose(g_got, g_want, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(t_got, t_want, rtol=1e-5, atol=1e-6)
+
+
 def _dense_gather_case(n, m, f, dtype):
     """Random dense-layout neighbours with their exact transpose mapping:
     tier 1 holds each node's first ``m`` in-edges, the rest overflow
@@ -111,64 +152,6 @@ def _dense_gather_case(n, m, f, dtype):
     mapping = tuple(jnp.asarray(x) for x in (
         in_slots.reshape(-1), in_mask, o_slots, o_nodes, o_mask))
     return nodes, jnp.asarray(nbrs), mapping
-
-
-class TestPallasSegmentSum:
-    """Interpreter-mode checks (real-chip compile is exercised by bench.py
-    and the TPU smoke script; the CPU suite can only interpret)."""
-
-    def _case(self, e, n, f, seed):
-        rng = np.random.default_rng(seed)
-        msgs = rng.normal(size=(e, f)).astype(np.float32)
-        centers = np.sort(rng.integers(0, n, size=e)).astype(np.int32)
-        return jnp.asarray(msgs), jnp.asarray(centers)
-
-    @pytest.mark.parametrize("e,n,f", [(64, 16, 8), (1000, 300, 32), (2048, 513, 16)])
-    def test_matches_xla(self, e, n, f):
-        from jax.experimental.pallas import tpu as pltpu
-
-        from cgnn_tpu.ops.pallas_scatter import segment_sum_pallas
-
-        msgs, centers = self._case(e, n, f, seed=e)
-        expected = segment_sum(msgs, centers, n)
-        with pltpu.force_tpu_interpret_mode():
-            got = segment_sum_pallas(msgs, centers, n)
-        np.testing.assert_allclose(got, expected, rtol=1e-5, atol=1e-5)
-
-    def test_gradient_is_gather(self):
-        from jax.experimental.pallas import tpu as pltpu
-
-        from cgnn_tpu.ops.pallas_scatter import segment_sum_pallas
-
-        msgs, centers = self._case(200, 40, 8, seed=0)
-
-        with pltpu.force_tpu_interpret_mode():
-            g_pallas = jax.grad(
-                lambda m: jnp.sum(segment_sum_pallas(m, centers, 40) ** 2)
-            )(msgs)
-        g_xla = jax.grad(lambda m: jnp.sum(segment_sum(m, centers, 40) ** 2))(msgs)
-        np.testing.assert_allclose(g_pallas, g_xla, rtol=1e-5, atol=1e-5)
-
-    def test_empty_segments_and_skew(self):
-        """Gaps (empty nodes) and one hub node with huge degree."""
-        from jax.experimental.pallas import tpu as pltpu
-
-        from cgnn_tpu.ops.pallas_scatter import segment_sum_pallas
-
-        rng = np.random.default_rng(1)
-        n = 260
-        centers = np.sort(
-            np.concatenate([
-                np.full(700, 5),          # hub: degree 700 > chunk size
-                rng.integers(100, 120, 50),  # sparse middle, gaps elsewhere
-                np.full(30, n - 1),       # tail node
-            ])
-        ).astype(np.int32)
-        msgs = jnp.asarray(rng.normal(size=(len(centers), 8)).astype(np.float32))
-        expected = segment_sum(msgs, jnp.asarray(centers), n)
-        with pltpu.force_tpu_interpret_mode():
-            got = segment_sum_pallas(msgs, jnp.asarray(centers), n)
-        np.testing.assert_allclose(got, expected, rtol=1e-5, atol=1e-4)
 
 
 class TestMaskedBatchNorm:
@@ -288,165 +271,6 @@ def test_one_pass_bn_matches_two_pass_reference():
     )
 
 
-class TestFusedEpilogue:
-    """ops/fused_epilogue.py vs the unfused MaskedBatchNorm+gate+mask+sum
-    chain (PERF.md §4b, VERDICT r3 next-step #1): values, gradients, and
-    running-stat updates must agree to f32 roundoff, both impls."""
-
-    def _setup(self, seed=0, n=67, m=12, f=32):
-        import jax
-
-        rng = np.random.default_rng(seed)
-        z = rng.normal(0.5, 1.5, size=(n, m, 2 * f)).astype(np.float32)
-        mask = np.zeros((n, m), np.float32)
-        # ragged realistic mask: leading rows real, random slot counts
-        for i in range(n - 7):  # last 7 node slots are padding
-            mask[i, : rng.integers(3, m + 1)] = 1.0
-        scale = rng.normal(1.0, 0.1, 2 * f).astype(np.float32)
-        bias = rng.normal(0.0, 0.1, 2 * f).astype(np.float32)
-        return jax.numpy.asarray(z), jax.numpy.asarray(mask), \
-            jax.numpy.asarray(scale), jax.numpy.asarray(bias)
-
-    @staticmethod
-    def _reference(z, mask, scale, bias):
-        """The unfused chain, as CGConv computes it (one-pass f32 BN)."""
-        import jax
-        import jax.numpy as jnp
-
-        from cgnn_tpu.ops.norm import MaskedBatchNorm
-
-        bn = MaskedBatchNorm()
-        variables = {
-            "params": {"scale": scale, "bias": bias},
-            "batch_stats": {"mean": jnp.zeros_like(scale),
-                            "var": jnp.ones_like(scale)},
-        }
-        y, mutated = bn.apply(variables, z, mask=mask,
-                              use_running_average=False,
-                              mutable=["batch_stats"])
-        f = y.shape[-1] // 2
-        msg = jax.nn.sigmoid(y[..., :f]) * jax.nn.softplus(y[..., f:])
-        msg = msg * mask[..., None]
-        return msg.sum(axis=1), mutated["batch_stats"]
-
-    def _check_impl(self, impl):
-        import jax
-        import jax.numpy as jnp
-
-        from cgnn_tpu.ops.fused_epilogue import fused_epilogue
-
-        z, mask, scale, bias = self._setup()
-
-        def fused_loss(z, scale, bias):
-            agg, mean, var, n_real = fused_epilogue(
-                z, mask, scale, bias, 1e-5, impl)
-            return (agg ** 2).sum(), (agg, mean, var, n_real)
-
-        def ref_loss(z, scale, bias):
-            agg, stats = self._reference(z, mask, scale, bias)
-            return (agg ** 2).sum(), (agg, stats)
-
-        (l1, (agg_f, mean, var, n_real)), g_f = jax.value_and_grad(
-            fused_loss, argnums=(0, 1, 2), has_aux=True)(z, scale, bias)
-        (l2, (agg_r, stats)), g_r = jax.value_and_grad(
-            ref_loss, argnums=(0, 1, 2), has_aux=True)(z, scale, bias)
-
-        np.testing.assert_allclose(np.asarray(agg_f), np.asarray(agg_r),
-                                   rtol=2e-5, atol=2e-5)
-        # padding node rows aggregate to zero... (mask rows are all zero)
-        assert float(np.abs(np.asarray(agg_f)[-7:]).max()) < 1e-5
-        # stats consistent with the unfused module's EMA update at step 1:
-        # running = 0.9*init + 0.1*batch  =>  batch mean = 10*(run - 0.9*0)
-        np.testing.assert_allclose(
-            np.asarray(mean), np.asarray(stats["mean"]) / 0.1,
-            rtol=1e-4, atol=1e-5,
-        )
-        c = float(n_real)
-        unb = np.asarray(var) * c / (c - 1.0)
-        np.testing.assert_allclose(
-            unb, (np.asarray(stats["var"]) - 0.9) / 0.1, rtol=1e-4,
-            atol=1e-4,
-        )
-        for a, b, name in zip(g_f, g_r, ("dz", "dscale", "dbias")):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), rtol=5e-4, atol=5e-5,
-                err_msg=f"fused[{impl}] {name} mismatch",
-            )
-
-    def test_xla_impl_matches_unfused(self):
-        self._check_impl("xla")
-
-    def test_pallas_impl_matches_unfused(self):
-        from jax.experimental.pallas import tpu as pltpu
-
-        with pltpu.force_tpu_interpret_mode():
-            self._check_impl("pallas")
-
-    def test_eval_mode_matches_unfused(self):
-        import jax
-        import jax.numpy as jnp
-
-        from cgnn_tpu.ops.fused_epilogue import fused_epilogue_eval
-        from cgnn_tpu.ops.norm import MaskedBatchNorm
-
-        z, mask, scale, bias = self._setup(seed=3)
-        rng = np.random.default_rng(9)
-        rmean = jnp.asarray(rng.normal(0, 1, z.shape[-1]).astype(np.float32))
-        rvar = jnp.asarray(
-            rng.uniform(0.5, 2.0, z.shape[-1]).astype(np.float32))
-        got = fused_epilogue_eval(z, mask, scale, bias, rmean, rvar, 1e-5)
-        bn = MaskedBatchNorm()
-        variables = {"params": {"scale": scale, "bias": bias},
-                     "batch_stats": {"mean": rmean, "var": rvar}}
-        y = bn.apply(variables, z, mask=mask, use_running_average=True)
-        f = y.shape[-1] // 2
-        ref = (jax.nn.sigmoid(y[..., :f]) * jax.nn.softplus(y[..., f:])
-               * mask[..., None]).sum(axis=1)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-
-    def test_cgconv_fused_matches_unfused_end_to_end(self):
-        """Whole-model check: CrystalGraphConvNet with fused_epilogue='xla'
-        reproduces the unfused model's outputs and parameter gradients on a
-        real packed dense batch (same variable tree — drop-in)."""
-        import jax
-        import jax.numpy as jnp
-
-        from cgnn_tpu.data.dataset import FeaturizeConfig, load_synthetic
-        from cgnn_tpu.data.graph import batch_iterator, capacities_for
-        from cgnn_tpu.models import CrystalGraphConvNet
-
-        cfg = FeaturizeConfig(radius=5.0, max_num_nbr=8)
-        graphs = load_synthetic(12, cfg, seed=2, max_atoms=6)
-        nc, ec = capacities_for(graphs, 12, dense_m=8)
-        batch = next(batch_iterator(graphs, 12, nc, ec, dense_m=8))
-        base = CrystalGraphConvNet(atom_fea_len=16, n_conv=2, h_fea_len=24,
-                                   dense_m=8)
-        fused = CrystalGraphConvNet(atom_fea_len=16, n_conv=2, h_fea_len=24,
-                                    dense_m=8, fused_epilogue="xla")
-        variables = base.init(jax.random.key(0), batch)
-
-        def loss(model, params):
-            out, mut = model.apply(
-                {"params": params, "batch_stats": variables["batch_stats"]},
-                batch, train=True, mutable=["batch_stats"])
-            return (out ** 2).sum(), mut["batch_stats"]
-
-        (l_b, s_b), g_b = jax.value_and_grad(
-            lambda p: loss(base, p), has_aux=True)(variables["params"])
-        (l_f, s_f), g_f = jax.value_and_grad(
-            lambda p: loss(fused, p), has_aux=True)(variables["params"])
-        assert float(l_f) == pytest.approx(float(l_b), rel=1e-5)
-        for a, b in zip(jax.tree_util.tree_leaves(g_b),
-                        jax.tree_util.tree_leaves(g_f)):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-3, atol=1e-5)
-        for a, b in zip(jax.tree_util.tree_leaves(s_b),
-                        jax.tree_util.tree_leaves(s_f)):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-4, atol=1e-5)
-
-
 def test_one_pass_bn_high_mean_no_cancellation():
     """|mean| >> std regime: unshifted f32 E[x^2]-E[x]^2 loses all variance
     bits (var clamps to 0 and rsqrt(eps) AMPLIFIES by ~300x); the
@@ -478,165 +302,142 @@ def test_one_pass_bn_high_mean_no_cancellation():
     assert float(np.abs(got).max()) < 10.0
 
 
-class TestFusedCGConv:
-    """ops/pallas_cgconv.py (the WHOLE-conv fused kernel, ROADMAP item 2)
-    vs the unfused dense CGConv branch: values, parameter gradients,
-    running-stat updates, and eval mode must agree to f32 roundoff for
-    both impls — mirroring TestFusedEpilogue's contract one level up."""
-
-    def _models(self, impl, dense_m=8, window=0):
-        from cgnn_tpu.models import CrystalGraphConvNet
-
-        kw = dict(atom_fea_len=16, n_conv=2, h_fea_len=24, dense_m=dense_m)
-        base = CrystalGraphConvNet(**kw)
-        fused = CrystalGraphConvNet(**kw, cgconv_impl=impl,
-                                    cgconv_window=window)
-        return base, fused
-
-    def _batch(self, n=14, max_atoms=6, dense_m=8, in_cap=None):
-        from cgnn_tpu.data.dataset import FeaturizeConfig, load_synthetic
-        from cgnn_tpu.data.graph import batch_iterator, capacities_for
-
-        cfg = FeaturizeConfig(radius=5.0, max_num_nbr=dense_m)
-        graphs = load_synthetic(n, cfg, seed=2, max_atoms=max_atoms)
-        nc, ec = capacities_for(graphs, n, dense_m=dense_m)
-        return next(batch_iterator(graphs, n, nc, ec, dense_m=dense_m,
-                                   in_cap=in_cap)), graphs
-
-    @staticmethod
-    def _flat(tree):
-        return sorted(
-            ((jax.tree_util.keystr(k), np.asarray(v))
-             for k, v in jax.tree_util.tree_leaves_with_path(tree)),
-            key=lambda kv: kv[0],
-        )
-
-    def _check(self, impl, window=0, in_cap=None):
-        batch, _ = self._batch(in_cap=in_cap)
-        base, fused = self._models(impl, window=window)
-        variables = base.init(jax.random.key(0), batch)
-        vf = fused.init(jax.random.key(0), batch)
-        # identical parameter TREE and identical init VALUES: the fused
-        # path declares the same fc_full/bn1 scopes, so checkpoints
-        # restore across impls
-        for (ka, a), (kb, b) in zip(self._flat(variables["params"]),
-                                    self._flat(vf["params"])):
-            assert ka == kb
-            np.testing.assert_array_equal(a, b, err_msg=ka)
-
-        def loss(model, params):
-            out, mut = model.apply(
-                {"params": params, "batch_stats": variables["batch_stats"]},
-                batch, train=True, mutable=["batch_stats"])
-            return (out ** 2).sum(), mut["batch_stats"]
-
-        (l_b, s_b), g_b = jax.value_and_grad(
-            lambda p: loss(base, p), has_aux=True)(variables["params"])
-        (l_f, s_f), g_f = jax.value_and_grad(
-            lambda p: loss(fused, p), has_aux=True)(variables["params"])
-        assert float(l_f) == pytest.approx(float(l_b), rel=1e-4)
-        for (ka, a), (kb, b) in zip(self._flat(g_b), self._flat(g_f)):
-            np.testing.assert_allclose(
-                a, b, rtol=2e-3, atol=1e-4,
-                err_msg=f"fused-cgconv[{impl}] grad {ka}")
-        for (ka, a), (kb, b) in zip(self._flat(s_b), self._flat(s_f)):
-            np.testing.assert_allclose(
-                a, b, rtol=1e-4, atol=1e-5,
-                err_msg=f"fused-cgconv[{impl}] stats {ka}")
-        # eval (running stats — the serving path, one apply pass)
-        out_b = base.apply(variables, batch, train=False)
-        out_f = fused.apply(variables, batch, train=False)
-        np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_b),
-                                   rtol=1e-4, atol=1e-5)
-
-    def test_xla_impl_matches_unfused(self):
-        self._check("xla")
-
-    def test_pallas_impl_matches_unfused(self):
-        from jax.experimental.pallas import tpu as pltpu
-
-        with pltpu.force_tpu_interpret_mode():
-            self._check("pallas")
-
-    def test_pallas_bounded_window_matches_unfused(self):
-        """The caller-bounded neighbor window (the perf configuration):
-        window_width(max graph nodes) must reproduce the full-range
-        gather exactly — an undersized bound would silently zero
-        out-of-window neighbors, so coverage is pinned here."""
-        from jax.experimental.pallas import tpu as pltpu
-
-        from cgnn_tpu.ops.pallas_cgconv import window_width
-
-        with pltpu.force_tpu_interpret_mode():
-            self._check("pallas", window=window_width(6))
-
-    def test_pallas_no_transpose_slots(self):
-        """Forward-only batches (in_cap=0, the serving ladder) take the
-        plain-gather backward; values must not care."""
-        from jax.experimental.pallas import tpu as pltpu
-
-        with pltpu.force_tpu_interpret_mode():
-            self._check("pallas", in_cap=0)
-
-    def test_window_starts_cover_every_graph_span(self):
-        """_win_starts x window_width coverage proof over adversarial
-        node counts: every block's possible neighbor span (its rows'
-        graph-mates) lies inside [ws[b], ws[b] + W)."""
-        from cgnn_tpu.ops.pallas_cgconv import (
-            _TN,
-            _win_starts,
-            window_width,
-        )
-
-        for maxg in (1, 5, 64, 129, 300):
-            w = window_width(maxg)
-            for n in (8, 120, 128, 136, 1000, 2048):
-                nb = -(-n // _TN)
-                n_pad = nb * _TN
-                win = min(w, n_pad)
-                ws = np.asarray(_win_starts(nb, n_pad, win))
-                for b in range(nb):
-                    lo = max(0, b * _TN - (maxg - 1))
-                    hi = min(n, b * _TN + _TN + maxg - 1)
-                    if hi - lo > win:
-                        continue  # window itself smaller than span:
-                        # excluded by the window>=window_width contract
-                    assert ws[b] <= lo and hi <= ws[b] + win, (
-                        maxg, n, b, ws[b], lo, hi, win)
-
-    def test_fused_conv_byte_model_shape(self):
-        """The graftaudit roofline budget helper stays self-consistent:
-        model_bytes == 2 reads + 1 write (the one-round-trip claim the
-        audit gates against)."""
-        from cgnn_tpu.ops.pallas_cgconv import fused_conv_hbm_bytes
-
-        m = fused_conv_hbm_bytes(1024, 12, 41, 64)
-        assert m["model_bytes"] == 2 * m["reads_per_pass"] + m["write_bytes"]
-        assert m["passes"] == 2
-
-
-def test_windowed_gather_kernel_matches_take():
-    """Pallas windowed one-hot gather (interpret mode on CPU): bit-exact
-    vs jnp.take, including out-of-window padding self-loops -> zeros.
-    (The kernel is a measured negative result for perf — see its module
-    docstring — but stays correct and tested as a scaffold.)"""
-    import jax.numpy as jnp
-    from jax.experimental.pallas import tpu as pltpu
-
-    from cgnn_tpu.ops import pallas_gather
-
-    nc, w = 256, 256
-    rng = np.random.default_rng(0)
-    nodes = jnp.asarray(rng.normal(size=(nc, 8)).astype(np.float32))
-    # neighbors within a window starting at 0 for block 0, 128 for block 1
-    nbr = jnp.asarray(
-        np.concatenate([
-            rng.integers(0, 128, size=128 * 4),
-            rng.integers(128, 256, size=128 * 4),
-        ]).astype(np.int32)
+def _conv_case(mapping):
+    """One packed dense batch with padding nodes and padding slots, and
+    the transpose mapping the case asks for: none (forward-only batches),
+    single-tier ([N, In] at the dataset's in-degree cap) or two-tier
+    ([N, M] plus a non-empty overflow list)."""
+    from cgnn_tpu.data.dataset import FeaturizeConfig, load_synthetic
+    from cgnn_tpu.data.graph import (
+        batch_iterator,
+        capacities_for,
+        in_degree_cap,
     )
-    ws = jnp.asarray(np.array([0, 128], np.int32))
-    with pltpu.force_tpu_interpret_mode():
-        got = pallas_gather.windowed_gather(nodes, nbr, ws, w)
-    ref = jnp.take(nodes, nbr, axis=0).reshape(nc, 4, 8)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+    m = 8
+    cfg = FeaturizeConfig(radius=4.0, max_num_nbr=m)
+    graphs = load_synthetic(14, cfg, seed=2, max_atoms=9)
+    nc, ec = capacities_for(graphs, 14, dense_m=m)
+    in_cap = {"none": 0, "single-tier": in_degree_cap(graphs),
+              "two-tier": None}[mapping]
+    batch = next(batch_iterator(graphs, 14, nc, ec, dense_m=m,
+                                in_cap=in_cap))
+    node_mask = np.asarray(batch.node_mask)
+    edge_mask = np.asarray(batch.edge_mask)
+    assert 0 < node_mask.sum() < node_mask.size, "no padding node"
+    assert (edge_mask.reshape(nc, m)[node_mask > 0] == 0).any(), \
+        "no padding slot on a real node"
+    assert (batch.in_slots is None) == (mapping == "none")
+    assert (batch.over_slots is not None) == (mapping == "two-tier")
+    if mapping == "two-tier":
+        assert np.asarray(batch.over_mask).sum() > 0, "no overflow edge"
+    return batch, m
+
+
+@pytest.mark.parametrize("mapping", ["none", "single-tier", "two-tier"])
+@pytest.mark.parametrize("mode", ["train", "eval"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_dense_conv_matches_coo_conv(dtype, mode, mapping):
+    """The conv every benchmark cell runs (``CGConv`` with ``dense_m``:
+    slot-major gather, split fc_full, 3-D BN1, sum over M) against the
+    flat COO body (gather + concat + Dense + segment-sum), the in-program
+    reference: same parameters, same packed batch. Outputs always; in
+    train mode also the gradients w.r.t. the input nodes and every
+    parameter and the updated bn1 / bn2 running statistics, with and
+    without the packed transpose mapping behind the gather's backward.
+
+    float32 tolerances are those of the kernel tests this replaces (2e-5
+    on values, 5e-4 on gradients). In bfloat16 the two bodies round
+    differently (three sliced matmuls against one over the concat, a sum
+    over M against a segment-sum, each rounded to 8 bits), so they are
+    held to 3e-2 of the largest reference entry (of the module, for a
+    parameter gradient): a masked-out term or a wrong neighbour moves
+    values by their own size."""
+    from cgnn_tpu.models.cgcnn import CGConv
+
+    batch, m = _conv_case(mapping)
+    jdt = jnp.dtype(dtype)
+    f = 16
+    rng = np.random.default_rng(5)
+    node_mask = jnp.asarray(batch.node_mask)
+    nodes = jnp.asarray(
+        rng.normal(size=(node_mask.shape[0], f)).astype(np.float32)
+    ) * node_mask[:, None]
+    dense = CGConv(features=f, dtype=jdt, dense_m=m)
+    coo = CGConv(features=f, dtype=jdt)
+    train = mode == "train"
+
+    def args(edges):
+        return (edges, batch.centers, batch.neighbors, batch.edge_mask,
+                batch.node_mask)
+
+    mapping_kw = dict(in_slots=batch.in_slots, in_mask=batch.in_mask,
+                      over_slots=batch.over_slots,
+                      over_nodes=batch.over_nodes,
+                      over_mask=batch.over_mask)
+    variables = coo.init(jax.random.key(0), nodes.astype(jdt),
+                         *args(batch.flat_edges))
+    v_dense = dense.init(jax.random.key(0), nodes.astype(jdt),
+                         *args(batch.edges), **mapping_kw)
+    assert (jax.tree_util.tree_structure(variables)
+            == jax.tree_util.tree_structure(v_dense))
+    # running statistics and affine parameters away from their (0, 1)
+    # initial values, so eval mode and the EMA update are not trivial
+    leaves, treedef = jax.tree_util.tree_flatten(variables)
+    variables = jax.tree_util.tree_unflatten(treedef, [
+        x + jnp.asarray(rng.uniform(0.05, 0.3, x.shape).astype(np.float32))
+        for x in leaves])
+
+    def run(conv, edges, kw):
+        def loss(params, x):
+            out, mut = conv.apply(
+                {"params": params, "batch_stats": variables["batch_stats"]},
+                x.astype(jdt), *args(edges), train=train,
+                mutable=["batch_stats"], **kw)
+            out = out.astype(jnp.float32)
+            return (out ** 2).sum(), (out, mut["batch_stats"])
+
+        if not train:
+            return loss(variables["params"], nodes)[1], None
+        (_, aux), grads = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)(variables["params"], nodes)
+        return aux, grads
+
+    (out_d, stats_d), g_d = run(dense, batch.edges, mapping_kw)
+    (out_c, stats_c), g_c = run(coo, batch.flat_edges, {})
+
+    def close(got, want, rtol, atol, what, scale=None):
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        if dtype == "bfloat16":
+            scale = float(np.abs(want).max()) if scale is None else scale
+            rtol, atol = 0.0, 3e-2 * max(scale, 1e-3)
+        np.testing.assert_allclose(got, want, rtol=rtol, atol=atol,
+                                   err_msg=what)
+
+    assert float(np.abs(np.asarray(out_c)).max()) > 0.1
+    close(out_d, out_c, 2e-5, 2e-5, "outputs")
+    # padding node rows stay zero
+    assert not np.asarray(out_d)[np.asarray(node_mask) == 0].any()
+    if not train:
+        return
+    flat = lambda tree: sorted(  # noqa: E731
+        (jax.tree_util.keystr(k), v)
+        for k, v in jax.tree_util.tree_leaves_with_path(tree))
+    for (ka, a), (kb, b) in zip(flat(stats_d), flat(stats_c)):
+        assert ka == kb
+        close(a, b, 1e-4, 1e-5, f"running statistics {ka}")
+    (gp_d, gx_d), (gp_c, gx_c) = g_d, g_c
+    assert float(np.abs(np.asarray(gx_c)).max()) > 0.1
+    close(gx_d, gx_c, 5e-4, 5e-5, "gradient w.r.t. nodes")
+    assert len(flat(gp_d)) == 6  # kernel/scale and bias of fc_full, bn1, bn2
+    for module in gp_c:
+        # a module's leaves share one bf16 scale: fc_full's bias gradient
+        # is zero in exact arithmetic (BN1 removes what a bias adds), so
+        # each body returns its own rounding of a cancelling sum
+        scale = max(float(np.abs(np.asarray(v)).max())
+                    for v in jax.tree_util.tree_leaves(gp_c[module]))
+        assert scale > 0.1, module
+        for (ka, a), (kb, b) in zip(flat(gp_d[module]),
+                                    flat(gp_c[module])):
+            assert ka == kb
+            close(a, b, 5e-4, 5e-5, f"gradient {module}{ka}", scale)

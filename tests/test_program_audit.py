@@ -332,10 +332,7 @@ class TestLiveRepo:
 
     def test_skips_are_known_backend_gaps_only(self, live_audit):
         _, ledger, _ = live_audit
-        # conv/fused_pallas_fwd: Mosaic lowers only on a tpu backend
-        # (its structured twin conv/fused_xla_fwd is audited everywhere)
-        known = {"train/dense", "train/dp", "train/edge",
-                 "conv/fused_pallas_fwd", "predict/mesh"}
+        known = {"train/dense", "train/dp", "train/edge", "predict/mesh"}
         assert set(ledger["meta"]["skipped"]) <= known, (
             "unexpected skip — a program stopped lowering: "
             f"{ledger['meta']['skipped']}"

@@ -6,8 +6,8 @@ Flag surface mirrors the reference lineage's ``main.py``/``train.py``
 (``--task``, ``--n-conv``, ``--atom-fea-len``, ``--max-num-nbr``,
 ``--radius``, ``--resume``, ``--lr-milestones`` in epochs, ...), plus the
 TPU-native additions: ``--device``, ``--data-parallel``, ``--bf16``,
-``--aggregation``, and ``--synthetic N`` (offline stand-in for MP/OC20
-downloads, SURVEY.md §7 phase 0).
+and ``--synthetic N`` (offline stand-in for MP/OC20 downloads,
+SURVEY.md §7 phase 0).
 
 Usage:
     python train.py DATA_DIR [flags]         # {id}.cif + id_prop.csv layout
@@ -195,25 +195,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "chip; composes with --data-parallel as a 2-D mesh)")
     p.add_argument("--bf16", action="store_true",
                    help="bfloat16 compute on the MXU (f32 params/stats)")
-    p.add_argument("--aggregation", choices=["xla", "sort", "pallas"],
-                   default=None, help="edge-aggregation backend (flat COO "
-                                      "layout only)")
-    p.add_argument("--fused-epilogue", choices=["off", "xla", "pallas"],
-                   default="off",
-                   help="fuse the BN1->gate->mask->sum chain into one "
-                        "custom-VJP op (dense layout only). MEASURED "
-                        "SLOWER than the default unfused path on v5e — "
-                        "the custom-VJP boundary forfeits XLA's producer/"
-                        "consumer fusion (PERF.md 6b); kept for "
-                        "reproduction/experiments")
-    p.add_argument("--cgconv-impl", choices=["off", "xla", "pallas"],
-                   default="off",
-                   help="WHOLE-conv fused kernel (ops/pallas_cgconv.py): "
-                        "gather+fc_full+BN+gate+sum as one custom-VJP op, "
-                        "v_j/z never in HBM; 'xla' = structured jnp twin, "
-                        "'pallas' = blocked TPU kernels (dense layout "
-                        "only; A/B via bench.py --ab cgconv, verdict in "
-                        "PERF.md)")
     p.add_argument("--compact-staging", choices=["auto", "on", "off"],
                    default="auto",
                    help="stage batches in raw form (atom vocabulary index "
@@ -224,12 +205,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "device. auto = on when supported")
     p.add_argument("--compile-cache", type=str, default=None,
                    metavar="DIR", help=COMPILE_CACHE_HELP)
-    p.add_argument("--layout", choices=["auto", "dense", "coo"], default="auto",
+    p.add_argument("--layout", choices=["dense", "coo"], default="dense",
                    help="edge batch layout: 'dense' (node-major slots, "
                         "scatter-free aggregation — ~2x faster on TPU; "
                         "composes with --graph-shards via node-strip "
-                        "sharding) or 'coo' (flat edge list). Default: "
-                        "dense unless --aggregation overrides the backend")
+                        "sharding) or 'coo' (flat edge list)")
     return p
 
 
@@ -471,55 +451,20 @@ def main(argv=None) -> int:
     classification = args.task == "classification"
     force_task = args.task == "force"
 
-    # dense slot layout: scatter-free aggregation (see data/graph.py); the
-    # flat COO layout remains for edge-sharded meshes and explicit
-    # aggregation-backend experiments. Default for ALL tasks incl. force
-    # since r4: gather_transpose moved to linear_call so the second-order
-    # force differentiation composes (ops/segment.py), parity is pinned to
-    # training-step gradients (tests/test_forces.py), and the bench
-    # measures dense at 1.59x COO on the force workload (BENCH r4).
-    dense_ok = args.aggregation is None
-    if args.layout == "dense" and not dense_ok:
-        print("--layout dense is incompatible with --aggregation",
-              file=sys.stderr)
-        return 2
-    use_dense = dense_ok if args.layout == "auto" else args.layout == "dense"
+    # dense slot layout: scatter-free aggregation (see data/graph.py), the
+    # default for every task incl. force (gather_slot_major is declared
+    # linear, so the second-order force differentiation composes; parity is
+    # pinned to training-step gradients, tests/test_forces.py). The flat COO
+    # layout remains for edge-sharded meshes.
+    use_dense = args.layout == "dense"
     dense_m = args.max_num_nbr if use_dense else 0
-    if args.fused_epilogue != "off" and (
-        not use_dense or force_task or args.graph_shards > 1
-    ):
-        print("--fused-epilogue requires the dense layout with BatchNorm "
-              "and no graph sharding (not --layout coo / --task force / "
-              "--graph-shards)", file=sys.stderr)
-        return 2
-    if args.cgconv_impl != "off" and (
-        not use_dense or force_task or args.graph_shards > 1
-        or args.fused_epilogue != "off"
-    ):
-        print("--cgconv-impl (the whole-conv fused kernel) requires the "
-              "dense layout with BatchNorm, no graph sharding, and no "
-              "--fused-epilogue (it subsumes it)", file=sys.stderr)
-        return 2
-    cgconv_window = 0
-    if args.cgconv_impl != "off":
-        # the in-kernel gather's neighbor-window bound comes from the
-        # REAL dataset (an undersized bound would silently zero
-        # out-of-window neighbors — ops/pallas_cgconv.py contract)
-        from cgnn_tpu.ops.pallas_cgconv import window_width
-
-        cgconv_window = window_width(max(g.num_nodes for g in graphs))
 
     model_cfg = ModelConfig(
         atom_fea_len=args.atom_fea_len, n_conv=args.n_conv,
         h_fea_len=args.h_fea_len, n_h=args.n_h, num_targets=num_targets,
         classification=classification, num_classes=args.num_classes,
         dropout=args.dropout, dtype="bfloat16" if args.bf16 else "float32",
-        aggregation=args.aggregation, multi_task_head=args.multi_task_head,
-        dense_m=dense_m,
-        fused_epilogue="" if args.fused_epilogue == "off"
-        else args.fused_epilogue,
-        cgconv_impl="" if args.cgconv_impl == "off" else args.cgconv_impl,
-        cgconv_window=cgconv_window,
+        multi_task_head=args.multi_task_head, dense_m=dense_m,
     )
     graph_shards = max(1, args.graph_shards)
     if graph_shards > 1:
