@@ -16,13 +16,17 @@ TPU-first design choices:
 - dense edge-slot layout (``dense_m``: node n owns slots [n*M, (n+1)*M)),
   as the reference's [N, M] neighbour tensors but over bucketed, padded
   batches: the aggregation is a sum over M and the v_i term a broadcast, so
-  the forward has no scatter; the neighbour gather runs in the slot-major
-  row order the compiler lays [N, M, .] tensors out in, and its transpose is
-  a second gather through a mapping the packer precomputes
-  (ops/segment.py gather_slot_major). This is the body every benchmark cell
-  runs;
+  the forward has no scatter. fc_full is linear, so both of its node terms
+  are matmuls over [N, F], not over edges: the neighbour term is projected
+  first (nodes @ K_j, [N, 2F]) and THEN gathered, in the slot-major row
+  order the compiler lays [N, M, .] tensors out in; the gathered [N, M, 2F]
+  block is a term of z, and the gather's transpose is a second gather, of
+  dz's rows, through a mapping the packer precomputes (_SplitFcFull;
+  ops/segment.py gather_slot_major). The only per-edge matmul is the edge
+  term e @ K_e. This is the body every benchmark cell runs;
 - the same body over node strips when the graph is sharded over a mesh
-  axis (``edge_axis_name``), one psum a conv;
+  axis (``edge_axis_name``), one psum a conv (every shard projects all the
+  nodes and gathers its strip's rows through the flat gather_transpose);
 - a flat COO body (gather + segment-sum over sorted centres) for batches
   packed without ``dense_m``: the edge-sharded step runs it, and the tests
   hold the dense body to it;
@@ -52,21 +56,38 @@ from cgnn_tpu.ops.segment import (
 
 
 class _SplitFcFull(nn.Module):
-    """``fc_full`` (Linear 2F+G -> 2F) computed as three sliced matmuls.
+    """``fc_full`` (Linear 2F+G -> 2F) with both node terms computed per NODE.
 
     Parameter shapes/names are EXACTLY nn.Dense(2F) on the concatenated
     [v_i, v_j, e] input — checkpoints and oracle weight transplants are
-    unchanged — but the [N, M, 2F+G] concat is never materialized and the
-    v_i slice contracts per NODE ([N,F]@[F,2F], then broadcasts over M):
-    M-fold fewer FLOPs and bytes for that term. Measured: the concat write
-    + read was the largest single HBM cost of the step (trace r3, PERF.md).
+    unchanged — but the [N, M, 2F+G] concat is never materialized and
+    neither node slice of the kernel meets a gathered row:
+
+        z = (v_i @ K_i)[:, None, :] + gather_rows(nodes @ K_j) + e @ K_e + b
+
+    The centre term broadcasts over M. The neighbour term is projected
+    BEFORE the gather, ``nodes[nbr] @ K_j == (nodes @ K_j)[nbr]`` row for
+    row: what is gathered is the projected [N, 2F] (rows of 2F, not F),
+    the gathered [N, M, 2F] block is a term of ``z``, and the same kernel
+    is applied once a node instead of once for each of the ~M edges that
+    point at it. In the reverse passes ``dz`` itself goes through the
+    gather's transpose (no residual: the add keeps none, and the [N, M, F]
+    ``v_j`` that the weight gradient contracted over E is never built);
+    the matmuls left, ``dp @ K_j^T`` and ``nodes^T @ dp``, are per node.
+    The only per-edge matmul is the edge term (contraction G). Measured:
+    the concat write + read was the largest single HBM cost of the step
+    (trace r3); a row gather costs by the row, not by the byte, up to
+    512-byte rows (PERF.md section 5, PR 30).
     """
 
     features: int
     dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, v_i, v_j, e):  # [N,F], [N,M,F], [N,M,G]
+    def __call__(self, v_i, nodes, e, gather_rows):
+        # v_i [N', F]: the centres' rows (``nodes``, or this shard's strip
+        # of them); nodes [N, F]: the rows neighbours are read from;
+        # e [N', M, G]; gather_rows: [N, 2F] -> [N', M, 2F], linear
         f, g = v_i.shape[-1], e.shape[-1]
         kernel = self.param(
             "kernel",
@@ -80,7 +101,7 @@ class _SplitFcFull(nn.Module):
         k = kernel.astype(self.dtype)
         z = (
             (v_i.astype(self.dtype) @ k[:f])[:, None, :]
-            + v_j.astype(self.dtype) @ k[f : 2 * f]
+            + gather_rows(nodes.astype(self.dtype) @ k[f : 2 * f])
             + e.astype(self.dtype) @ k[2 * f :]
         )
         return z + bias.astype(self.dtype)
@@ -144,7 +165,6 @@ class CGConv(nn.Module):
             axis = self.edge_axis_name
             m = self.dense_m
             n_full = nodes.shape[0]
-            fdim = nodes.shape[-1]
             with jax.named_scope(phases.CONV_FC_FULL):
                 e = edges.astype(nodes.dtype)
                 if e.ndim == 2:
@@ -169,24 +189,24 @@ class CGConv(nn.Module):
                     f"count (pack with transpose_shards == the mesh's "
                     f"'graph' axis size)"
                 )
+            over = [None if a is None else a[0]
+                    for a in (over_slots, over_nodes, over_mask)]
+
+            def gather_rows(p):  # [N, 2F], projected -> [N/D, M, 2F]
+                with jax.named_scope(phases.CONV_GATHER):
+                    if in_slots is not None:
+                        rows = gather_transpose(
+                            p, neighbors, in_slots[0], in_mask[0], *over)
+                    else:  # eval batches carry no transpose mapping
+                        rows = gather(p, neighbors)
+                    return rows.reshape(n_strip, m, p.shape[-1])
+
             with jax.named_scope(phases.CONV_GATHER):
-                if in_slots is not None:
-                    v_j = gather_transpose(
-                        nodes_v, neighbors, in_slots[0], in_mask[0],
-                        over_slots=(None if over_slots is None
-                                    else over_slots[0]),
-                        over_nodes=(None if over_nodes is None
-                                    else over_nodes[0]),
-                        over_mask=None if over_mask is None else over_mask[0],
-                    ).reshape(n_strip, m, fdim)
-                else:  # eval batches carry no transpose mapping
-                    v_j = gather(nodes_v, neighbors).reshape(
-                        n_strip, m, fdim)
                 nodes_strip = jax.lax.dynamic_slice_in_dim(
                     nodes, idx * n_strip, n_strip
                 )
             z = _SplitFcFull(2 * f, dtype=self.dtype, name="fc_full")(
-                nodes_strip, v_j, e
+                nodes_strip, nodes_v, e, gather_rows
             )
             emask = edge_mask.reshape(n_strip, m)
             if self.use_batchnorm:
@@ -211,28 +231,32 @@ class CGConv(nn.Module):
         elif self.dense_m is not None:
             m = self.dense_m
             n = nodes.shape[0]
-            with jax.named_scope(phases.CONV_GATHER):
-                # rows gathered in the slot-major order fc_full, BN1 and the
-                # gate are laid out in, so no reshape or relayout pass over
-                # [E, F] surrounds the gather in either direction. With the
+
+            def gather_rows(p):  # [N, 2F], projected -> [N, M, 2F]
+                # rows gathered in the slot-major order z, BN1 and the gate
+                # are laid out in, so no reshape or relayout pass over
+                # [E, 2F] surrounds the gather in either direction. With the
                 # packed transpose mapping the backward is scatter-free
                 # (two-tier when the batch carries overflow slots); eval
                 # batches carry none and take the same path without one.
                 # (The round-3 "slot-space variant", 19% slower, was another
                 # thing: 2-D index gathers — ops/segment.gather_slot_major.)
-                v_j = gather_slot_major(
-                    nodes, neighbors, m, in_slots, in_mask,
-                    over_slots=over_slots, over_nodes=over_nodes,
-                    over_mask=over_mask,
-                )
+                with jax.named_scope(phases.CONV_GATHER):
+                    return gather_slot_major(
+                        p, neighbors, m, in_slots, in_mask,
+                        over_slots=over_slots, over_nodes=over_nodes,
+                        over_mask=over_mask,
+                    )
+
             with jax.named_scope(phases.CONV_FC_FULL):
                 # dense batches carry edges pre-shaped [N, M, G] (pack_graphs)
                 e = edges.astype(nodes.dtype)
                 if e.ndim == 2:  # direct pack_graphs callers with flat edges
                     e = e.reshape(n, m, -1)
-                # sliced matmuls: no [N, M, 2F+G] concat, v_i term per-node
+                # both node terms per node; the neighbour term is gathered
+                # already projected (the innermost scope names the phase)
                 z = _SplitFcFull(2 * f, dtype=self.dtype, name="fc_full")(
-                    nodes, v_j, e
+                    nodes, nodes, e, gather_rows
                 )
             if self.use_batchnorm:
                 # 3-D BN: statistics over the (N, M) slot axes directly —

@@ -5,7 +5,9 @@ sum inside its conv layer (SURVEY.md §3.3): on GPU it is ATen
 ``index_select`` + ``sum(dim=1)``. Here the dense conv gathers through
 ``gather_slot_major`` (a row gather whose transpose is a second row
 gather through the packer's mapping; the sum over M needs no op of its
-own), the node-strip sharded conv through ``gather_transpose``, and the
+own; the rows it moves are the nodes' fc_full projections, 2F wide, and
+the force model's positions: models/cgcnn.py _SplitFcFull),
+the node-strip sharded conv through ``gather_transpose``, and the
 flat COO conv through ``gather`` and ``aggregate_edge_messages``, a
 ``segment_sum`` over the sorted centres the packers emit. XLA compiles
 all of it; there is no hand-written kernel.
@@ -101,7 +103,8 @@ def gather_transpose(
     zero on edge slots missing from the mapping (padding slots). CGConv
     guarantees this: messages are multiplied by ``edge_mask`` and masked
     BatchNorm statistics exclude padding, so no gradient path reaches a
-    padded slot's ``v_j``.
+    padded slot's gathered row (the row is a term of ``z``, so its
+    cotangent is ``dz`` itself).
 
     The gather is linear in ``nodes`` and is declared so (``_linear``),
     which is what lets the force task differentiate it twice.

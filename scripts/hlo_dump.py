@@ -19,8 +19,9 @@ benchmark cell's largest scan program at its REAL size for the DESCRIBED
 chip (no chip needed: the TPU compiler is installed here and compiles for
 ``topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")``;
 ~1 min on this machine's CPU), write its text, and print, a model phase,
-the instructions whose output is [E, F]-sized — the check an issue about
-``conv.gather`` is judged by before any chip run. Run it with
+the instructions whose output is [E, F]-sized and those whose output is
+[E, 2F]-sized (z and the rows the gather writes) — the check an issue
+about ``conv.gather`` is judged by before any chip run. Run it with
 ``JAX_PLATFORMS=cpu``; one such process at a time (libtpu's lock file).
 No cell runs it.
 
@@ -167,20 +168,23 @@ def dump_cell(args) -> int:
     out = args.out or f"{args.cell}_{name}.hlo.txt"
     with open(out, "w") as fh:
         fh.write(text)
-    rows = sized_instructions(text, e * f)
-    # identical (op, shape) lines of a phase fold into one with a count, in
-    # order of first appearance: the three convs repeat each chain
-    by_phase: dict = {}
-    for r in rows:
-        inside = f" {{{','.join(r['inside'])}}}" if r["inside"] else ""
-        line = f"{r['op']}{inside}  {r['shape']}"
-        names = by_phase.setdefault(r["phase"], {}).setdefault(line, [])
-        names.append(r["name"])
-    print(f"{name}: E*F = {e}*{f}; {len(text.splitlines())} lines -> {out}")
-    for phase in sorted(by_phase):
-        print(f"{phase}: {sum(map(len, by_phase[phase].values()))}")
-        for line, names in by_phase[phase].items():
-            print(f"  {len(names)} x {names[0]:<30} {line}")
+    print(f"{name}: E = {e}, F = {f}; {len(text.splitlines())} lines -> {out}")
+    # [E, F]: the gate's halves and the messages; [E, 2F]: z and the rows
+    # the gather writes
+    for width in (f, 2 * f):
+        # identical (op, shape) lines of a phase fold into one with a
+        # count, in order of first appearance: the convs repeat each chain
+        by_phase: dict = {}
+        for r in sized_instructions(text, e * width):
+            inside = f" {{{','.join(r['inside'])}}}" if r["inside"] else ""
+            line = f"{r['op']}{inside}  {r['shape']}"
+            names = by_phase.setdefault(r["phase"], {}).setdefault(line, [])
+            names.append(r["name"])
+        print(f"== outputs of E*{width} elements")
+        for phase in sorted(by_phase):
+            print(f"{phase}: {sum(map(len, by_phase[phase].values()))}")
+            for line, names in by_phase[phase].items():
+                print(f"  {len(names)} x {names[0]:<30} {line}")
     takes = len(re.findall(r'op_name="[^"]*jit\(_take\)[^"]*select_n', text))
     print(f"select_n under jit(_take): {takes}")
     return 0

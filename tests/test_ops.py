@@ -334,38 +334,19 @@ def _conv_case(mapping):
     return batch, m
 
 
-@pytest.mark.parametrize("mapping", ["none", "single-tier", "two-tier"])
-@pytest.mark.parametrize("mode", ["train", "eval"])
-@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_dense_conv_matches_coo_conv(dtype, mode, mapping):
-    """The conv every benchmark cell runs (``CGConv`` with ``dense_m``:
-    slot-major gather, split fc_full, 3-D BN1, sum over M) against the
-    flat COO body (gather + concat + Dense + segment-sum), the in-program
-    reference: same parameters, same packed batch. Outputs always; in
-    train mode also the gradients w.r.t. the input nodes and every
-    parameter and the updated bn1 / bn2 running statistics, with and
-    without the packed transpose mapping behind the gather's backward.
-
-    float32 tolerances are those of the kernel tests this replaces (2e-5
-    on values, 5e-4 on gradients). In bfloat16 the two bodies round
-    differently (three sliced matmuls against one over the concat, a sum
-    over M against a segment-sum, each rounded to 8 bits), so they are
-    held to 3e-2 of the largest reference entry (of the module, for a
-    parameter gradient): a masked-out term or a wrong neighbour moves
-    values by their own size."""
+def _conv_pair(batch, m, jdt, f, batchnorm):
+    """The dense conv and the COO conv with ONE set of variables (the COO
+    conv's, moved off their (0, 1) initial values so that eval mode and the
+    EMA update are not trivial), and the arguments each is called with."""
     from cgnn_tpu.models.cgcnn import CGConv
 
-    batch, m = _conv_case(mapping)
-    jdt = jnp.dtype(dtype)
-    f = 16
     rng = np.random.default_rng(5)
     node_mask = jnp.asarray(batch.node_mask)
     nodes = jnp.asarray(
         rng.normal(size=(node_mask.shape[0], f)).astype(np.float32)
     ) * node_mask[:, None]
-    dense = CGConv(features=f, dtype=jdt, dense_m=m)
-    coo = CGConv(features=f, dtype=jdt)
-    train = mode == "train"
+    dense = CGConv(features=f, dtype=jdt, dense_m=m, use_batchnorm=batchnorm)
+    coo = CGConv(features=f, dtype=jdt, use_batchnorm=batchnorm)
 
     def args(edges):
         return (edges, batch.centers, batch.neighbors, batch.edge_mask,
@@ -379,23 +360,57 @@ def test_dense_conv_matches_coo_conv(dtype, mode, mapping):
                          *args(batch.flat_edges))
     v_dense = dense.init(jax.random.key(0), nodes.astype(jdt),
                          *args(batch.edges), **mapping_kw)
-    assert (jax.tree_util.tree_structure(variables)
-            == jax.tree_util.tree_structure(v_dense))
-    # running statistics and affine parameters away from their (0, 1)
-    # initial values, so eval mode and the EMA update are not trivial
+    # the parameter tree is the concatenated Linear's, leaf for leaf
+    assert (jax.tree_util.tree_map(jnp.shape, variables)
+            == jax.tree_util.tree_map(jnp.shape, v_dense))
     leaves, treedef = jax.tree_util.tree_flatten(variables)
     variables = jax.tree_util.tree_unflatten(treedef, [
         x + jnp.asarray(rng.uniform(0.05, 0.3, x.shape).astype(np.float32))
         for x in leaves])
+    return nodes, variables, (dense, batch.edges, mapping_kw), \
+        (coo, batch.flat_edges, {}), args
+
+
+@pytest.mark.parametrize("batchnorm", [True, False],
+                         ids=["batchnorm", "no-batchnorm"])
+@pytest.mark.parametrize("mapping", ["none", "single-tier", "two-tier"])
+@pytest.mark.parametrize("mode", ["train", "eval"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_dense_conv_matches_coo_conv(dtype, mode, mapping, batchnorm):
+    """The conv every benchmark cell runs (``CGConv`` with ``dense_m``:
+    fc_full's neighbour term projected once a node and gathered slot-major
+    at width 2F, 3-D BN1, sum over M) against the flat COO body (gather +
+    concat + Dense + segment-sum), the in-program reference: same
+    parameters, same packed batch. Outputs always; in train mode also the
+    gradients w.r.t. the input nodes and every parameter and the updated
+    bn1 / bn2 running statistics, with and without the packed transpose
+    mapping (and its overflow tier) behind the gather's backward, with
+    BatchNorm (``mp.train``, ``oc20.train``) and without (the force
+    model's conv).
+
+    float32 tolerances are those of the kernel tests this replaces (2e-5
+    on values, 5e-4 on gradients). In bfloat16 the two bodies round
+    differently (three sliced matmuls against one over the concat, a sum
+    over M against a segment-sum, each rounded to 8 bits), so they are
+    held to 3e-2 of the largest reference entry (of the module, for a
+    parameter gradient): a masked-out term or a wrong neighbour moves
+    values by their own size."""
+    batch, m = _conv_case(mapping)
+    jdt = jnp.dtype(dtype)
+    nodes, variables, dense, coo, args = _conv_pair(batch, m, jdt, 16,
+                                                   batchnorm)
+    node_mask = batch.node_mask
+    train = mode == "train"
+    stats = variables.get("batch_stats", {})
 
     def run(conv, edges, kw):
         def loss(params, x):
             out, mut = conv.apply(
-                {"params": params, "batch_stats": variables["batch_stats"]},
+                {"params": params, "batch_stats": stats},
                 x.astype(jdt), *args(edges), train=train,
                 mutable=["batch_stats"], **kw)
             out = out.astype(jnp.float32)
-            return (out ** 2).sum(), (out, mut["batch_stats"])
+            return (out ** 2).sum(), (out, mut.get("batch_stats", {}))
 
         if not train:
             return loss(variables["params"], nodes)[1], None
@@ -403,8 +418,8 @@ def test_dense_conv_matches_coo_conv(dtype, mode, mapping):
             loss, argnums=(0, 1), has_aux=True)(variables["params"], nodes)
         return aux, grads
 
-    (out_d, stats_d), g_d = run(dense, batch.edges, mapping_kw)
-    (out_c, stats_c), g_c = run(coo, batch.flat_edges, {})
+    (out_d, stats_d), g_d = run(*dense)
+    (out_c, stats_c), g_c = run(*coo)
 
     def close(got, want, rtol, atol, what, scale=None):
         got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
@@ -423,17 +438,20 @@ def test_dense_conv_matches_coo_conv(dtype, mode, mapping):
     flat = lambda tree: sorted(  # noqa: E731
         (jax.tree_util.keystr(k), v)
         for k, v in jax.tree_util.tree_leaves_with_path(tree))
+    assert len(flat(stats_c)) == (4 if batchnorm else 0)
     for (ka, a), (kb, b) in zip(flat(stats_d), flat(stats_c)):
         assert ka == kb
         close(a, b, 1e-4, 1e-5, f"running statistics {ka}")
     (gp_d, gx_d), (gp_c, gx_c) = g_d, g_c
     assert float(np.abs(np.asarray(gx_c)).max()) > 0.1
     close(gx_d, gx_c, 5e-4, 5e-5, "gradient w.r.t. nodes")
-    assert len(flat(gp_d)) == 6  # kernel/scale and bias of fc_full, bn1, bn2
+    # kernel/scale and bias of fc_full, bn1, bn2
+    assert len(flat(gp_d)) == (6 if batchnorm else 2)
     for module in gp_c:
-        # a module's leaves share one bf16 scale: fc_full's bias gradient
-        # is zero in exact arithmetic (BN1 removes what a bias adds), so
-        # each body returns its own rounding of a cancelling sum
+        # a module's leaves share one bf16 scale: under BatchNorm fc_full's
+        # bias gradient is zero in exact arithmetic (BN1 removes what a
+        # bias adds), so each body returns its own rounding of a
+        # cancelling sum
         scale = max(float(np.abs(np.asarray(v)).max())
                     for v in jax.tree_util.tree_leaves(gp_c[module]))
         assert scale > 0.1, module
@@ -441,3 +459,47 @@ def test_dense_conv_matches_coo_conv(dtype, mode, mapping):
                                     flat(gp_c[module])):
             assert ka == kb
             close(a, b, 5e-4, 5e-5, f"gradient {module}{ka}", scale)
+
+
+@pytest.mark.parametrize("mapping", ["none", "single-tier", "two-tier"])
+def test_dense_conv_second_derivative_matches_coo_conv(mapping):
+    """Grad over grad, which the force step runs in every step
+    (train/force_step.py): the inner reverse pass gives d(sum out^2)/d(nodes,
+    edges) (the path to the forces goes through both), the outer one
+    differentiates a loss on that first derivative w.r.t. the parameters
+    and the nodes. In the dense body the inner pass sends ``dz`` through the
+    gather's declared transpose and the outer pass transposes that again (a
+    ``linear_call``; a ``custom_vjp`` would refuse the jvp), at the projected
+    width 2F; the COO body is plain autodiff of ``take`` and
+    ``segment_sum``. float32, no BatchNorm, as the force model builds its
+    convs; held to 5e-4 of each leaf's largest reference entry."""
+    batch, m = _conv_case(mapping)
+    nodes, variables, dense, coo, args = _conv_pair(
+        batch, m, jnp.dtype("float32"), 16, False)
+
+    def run(conv, edges, kw):
+        def energy(x, e, params):
+            out = conv.apply({"params": params}, x, *args(e), train=True,
+                             **kw)
+            return (out ** 2).sum()
+
+        def on_first_derivative(params, x):
+            gx, ge = jax.grad(energy, argnums=(0, 1))(x, edges, params)
+            # per-slot weights, or the loss would be symmetric in the edges
+            w = jnp.arange(ge.size, dtype=ge.dtype).reshape(
+                -1, ge.shape[-1]) % 7 / 7
+            return (gx ** 2).sum() + (w * ge.reshape(w.shape) ** 2).sum()
+
+        return jax.grad(on_first_derivative, argnums=(0, 1))(
+            variables["params"], nodes)
+
+    got, want = run(*dense), run(*coo)
+    leaves = jax.tree_util.tree_leaves_with_path(want)
+    assert len(leaves) == 3  # fc_full's kernel and bias, the nodes
+    for (path, b), a in zip(leaves, jax.tree_util.tree_leaves(got)):
+        b = np.asarray(b)
+        scale = float(np.abs(b).max())
+        assert scale > 0.1, jax.tree_util.keystr(path)
+        np.testing.assert_allclose(
+            np.asarray(a), b, rtol=0, atol=5e-4 * scale,
+            err_msg=f"second derivative {jax.tree_util.keystr(path)}")

@@ -178,3 +178,62 @@ def test_force_geometry_reads_the_dense_layout(one_chip, no_compile_cache):
     assert scatter_rows == [over_cap], scatter_rows
     assert not index_gathers, index_gathers
     assert not lattices_an_edge, lattices_an_edge
+
+
+@pytest.mark.parametrize("task,dtype", [
+    ("regression", "bfloat16"), ("force", "float32")])
+def test_no_matmul_over_gathered_rows(one_chip, no_compile_cache, task,
+                                      dtype):
+    """The pin that fc_full's neighbour term is projected before the gather
+    (models/cgcnn.py _SplitFcFull, PR 30), in the train step and in the
+    force step with its two reverse passes. Before, ``v_j @ K_j`` ran over
+    the gathered [N, M, F] rows: one [E, F] x [F, 2F] matmul a conv
+    forward, ``dz @ K_j^T`` back into [E, F] and the weight gradient
+    ``v_j^T @ dz`` contracted over E in every reverse pass, a fifth to a
+    third of the step at ~2% of the FLOP peak (PERF.md section 6). Now no
+    matmul under ``conv.fc_full`` reads or writes anything with E rows of F
+    (the edge term's rows are G = 41 wide, z's 2F), every row gather under
+    ``conv.gather`` moves rows of 2F, and the forward ones write [E, 2F]:
+    the projected block that is a term of z."""
+    from cgnn_tpu.observe import phases
+
+    text, _graphs, batches, node_cap = _full_staging_scan_program(
+        one_chip, task, dtype)
+    f, m = 16, 12  # the helper's model and layout
+    edge_cap, over_cap = node_cap * m, batches[0].over_slots.shape[0]
+    gauss = batches[0].edges.shape[-1]
+    assert len({f, 2 * f, gauss}) == 3  # E rows are told apart by width
+    n_convs = 2
+
+    def elements(dims):
+        return int(np.prod([int(d) for d in dims.split(",") if d]))
+
+    matmuls, over_edge_rows, gathers = 0, [], []
+    for comp in phases._parse(text).values():
+        parsed = {name: m_.groups() for name, rest in comp["instrs"].items()
+                  if (m_ := _INSTR.match(rest))}
+        for name, (_dt, dims, op, operands) in parsed.items():
+            rest = comp["instrs"][name]
+            op_name = phases._OP_NAME.search(rest)
+            if not op_name:
+                continue
+            phase, direction = phases.classify(op_name.group(1))
+            if op in ("dot", "convolution") and phase == phases.CONV_FC_FULL:
+                matmuls += 1
+                sizes = [elements(dims)] + [
+                    elements(parsed[o][1])
+                    for o in phases._OPERAND.findall(
+                        operands.partition(")")[0]) if o in parsed]
+                assert len(sizes) == 3, rest[:200]  # both operands found
+                if edge_cap * f in sizes:
+                    over_edge_rows.append(rest[:200])
+            if op == "gather" and phase == phases.CONV_GATHER:
+                gathers.append((direction, dims))
+    # three kernel slices a conv forward; their transposes in each reverse
+    # pass (the force step has two)
+    assert matmuls >= 3 * n_convs * (2 if task == "regression" else 3)
+    assert not over_edge_rows, over_edge_rows
+    rows = {f"{edge_cap},{2 * f}", f"{over_cap},{2 * f}"}
+    assert {dims for _, dims in gathers} == rows, gathers
+    assert [d for d in gathers if d[0] == phases.FWD] == [
+        (phases.FWD, f"{edge_cap},{2 * f}")] * n_convs

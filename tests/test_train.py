@@ -353,3 +353,58 @@ class TestFit:
             inf.params, state.params,
         )
         mgr.close()
+
+
+def test_parent_checkpoint_restores_into_the_projected_conv(tmp_path):
+    """A checkpoint written by PR 29's code (tests/fixtures/ckpt_pr29: a
+    real ``CheckpointManager`` save of a dense two-conv model, and beside it
+    the variables and the float32 outputs that code computed) restores into
+    today's model: the same parameter tree, leaf for leaf and byte for
+    byte, and the same outputs. PR 30 moved fc_full's neighbour matmul
+    before the gather; ``fc_full/kernel`` stays the concatenated Linear's
+    [2F+G, 2F], and row for row ``(nodes @ K_j)[nbr]`` is the dot product
+    ``nodes[nbr] @ K_j`` was."""
+    import os
+    import shutil
+
+    from cgnn_tpu.config import ModelConfig
+    from cgnn_tpu.data.graph import batch_iterator
+
+    here = os.path.join(os.path.dirname(__file__), "fixtures", "ckpt_pr29")
+    shutil.copytree(os.path.join(here, "ckpt"), tmp_path / "ckpt")
+    parent = dict(np.load(os.path.join(here, "parent_outputs.npz")))
+    mgr = CheckpointManager(str(tmp_path / "ckpt"))
+    cfg = ModelConfig.from_meta(mgr.read_meta()["model"])
+    assert cfg.dense_m == 8  # the body every cell runs
+    model = cfg.build()
+    # the batch the fixture's outputs were computed on
+    graphs = load_synthetic(6, FeaturizeConfig(radius=4.0, max_num_nbr=8),
+                            seed=4, max_atoms=6)
+    nc, ec = capacities_for(graphs, 6, dense_m=8)
+    batch = next(batch_iterator(graphs, 6, nc, ec, dense_m=8))
+    fresh = create_train_state(
+        model, batch, make_optimizer(optim="sgd", lr=0.01),
+        Normalizer(mean=jnp.zeros(1, jnp.float32),
+                   std=jnp.ones(1, jnp.float32)),
+        rng=jax.random.key(1))
+    restored, _ = mgr.restore(fresh)
+    mgr.close()
+    variables = {"params": restored.params,
+                 "batch_stats": restored.batch_stats}
+    leaves = {jax.tree_util.keystr(k): np.asarray(v) for k, v in
+              jax.tree_util.tree_leaves_with_path(variables)}
+    outputs = {"eval", "train"}
+    assert set(leaves) == set(parent) - outputs
+    assert leaves["['params']['conv_0']['fc_full']['kernel']"].shape == (
+        2 * 8 + batch.edges.shape[-1], 2 * 8)
+    for path, value in leaves.items():
+        assert value.dtype == parent[path].dtype, path
+        np.testing.assert_array_equal(value, parent[path], err_msg=path)
+    got_eval = model.apply(variables, batch, train=False)
+    got_train, _ = model.apply(variables, batch, train=True,
+                               mutable=["batch_stats"])
+    assert float(np.abs(parent["eval"]).max()) > 0.1
+    np.testing.assert_allclose(np.asarray(got_eval), parent["eval"],
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(got_train), parent["train"],
+                               rtol=1e-5, atol=1e-6)
