@@ -262,6 +262,26 @@ def compact_shape_key(batch: CompactBatch) -> tuple:
     )
 
 
+def flat_rows(batch: CompactBatch) -> CompactBatch:
+    """``batch`` (host arrays, any leading stack axes) with its per-slot
+    members — ``distances``, ``edge_mask``, ``in_mask``, ``[..., N, M]`` —
+    merged to ``[..., N*M]``, the shape ``neighbors`` and ``in_slots``
+    already have. The same bytes; ``make_expander`` takes either shape.
+
+    For staging under a mesh (``parallel.data_parallel.shard_scan_stack``):
+    a device's share of a resident stack is ``[B, 1, N, M]``, and with M =
+    12 the minor axis the chip re-laid out the WHOLE stack of distances and
+    of the edge mask once a launch, a quarter of the step (PERF.md section
+    6, PR 32); a flat row is dense as it lies, and only the step's own
+    slice is ever reshaped."""
+    def flat(x):
+        return None if x is None else np.reshape(x, np.shape(x)[:-2] + (-1,))
+
+    return batch.replace(distances=flat(batch.distances),
+                         edge_mask=flat(batch.edge_mask),
+                         in_mask=flat(batch.in_mask))
+
+
 def compact_buffer_key(node_cap: int, dense_m: int, graph_cap: int,
                        tdim: int) -> tuple:
     """Pool key for reusable compact staging buffers (data/pipeline.py
@@ -513,11 +533,16 @@ def make_expander(spec: CompactSpec) -> Callable[[CompactBatch], GraphBatch]:
 
     def expand(cb: CompactBatch) -> GraphBatch:
         with jax.named_scope(phases.EXPAND):
-            n, m = cb.distances.shape
+            # [N, M], or [N*M] as flat_rows stages it (no-ops on the former)
+            m = spec.dense_m
+            distances = cb.distances.reshape(-1, m)
+            in_mask = (None if cb.in_mask is None
+                       else cb.in_mask.reshape(-1, m))
+            n = distances.shape[0]
             node_mask = cb.node_mask.astype(jnp.float32)
             nodes = jnp.asarray(table)[cb.atom_idx] * node_mask[:, None]
-            emask = cb.edge_mask.astype(jnp.float32)
-            d = cb.distances[..., None]
+            emask = cb.edge_mask.reshape(-1, m).astype(jnp.float32)
+            d = distances[..., None]
             efea = jnp.exp(-((d - jnp.asarray(mu)) ** 2) * inv_var2)
             efea = (efea * emask[..., None]).astype(edge_dtype)
             centers = jnp.arange(n * m, dtype=jnp.int32) // m
@@ -537,7 +562,7 @@ def make_expander(spec: CompactSpec) -> Callable[[CompactBatch], GraphBatch]:
                 edge_offsets=None,
                 node_targets=None,
                 in_slots=cb.in_slots,
-                in_mask=cb.in_mask,
+                in_mask=in_mask,
                 over_slots=cb.over_slots,
                 over_nodes=cb.over_nodes,
                 over_mask=cb.over_mask,

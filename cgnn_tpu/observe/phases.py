@@ -36,11 +36,14 @@ FORCE_READOUT = "force_readout"  # models/forcefield.py: per-atom energies
 LOSS = "loss"
 OPTIMIZER = "optimizer"
 SCAN = "scan"
+# train/step.py: the pmean/psum of gradients, statistics and metric sums
+# under data parallelism (the one phase a one-chip program never has)
+DP_ALLREDUCE = "dp.allreduce"
 OTHER = "other"
 
 PHASES = (EXPAND, EMBED, EDGE_GEOM, CONV_GATHER, CONV_FC_FULL, CONV_BN1,
           CONV_GATE, CONV_AGGREGATE, CONV_BN2, POOL_HEAD, FORCE_READOUT, LOSS,
-          OPTIMIZER, SCAN, OTHER)
+          OPTIMIZER, SCAN, DP_ALLREDUCE, OTHER)
 FWD, BWD, BWD2 = "fwd", "bwd", "bwd2"
 
 # a path component -> its phase: the named scopes themselves, and the flax

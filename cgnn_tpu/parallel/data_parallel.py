@@ -53,6 +53,17 @@ def empty_batch_like(batch: GraphBatch) -> GraphBatch:
     host-side stacked batches with an all-padding row.
     """
     ncap = batch.node_capacity
+    if hasattr(batch, "atom_idx"):  # CompactBatch: the same contract
+        from cgnn_tpu.data.compact import _base_neighbors
+
+        empty = jax.tree_util.tree_map(np.zeros_like, batch)
+        return empty.replace(
+            # padding slots point at their own node, as pack_compact's do
+            neighbors=_base_neighbors(
+                ncap, batch.distances.shape[-1]).copy(),
+            over_nodes=(None if batch.over_nodes is None
+                        else np.full_like(batch.over_nodes, ncap - 1)),
+        )
     # dense layout: centers/neighbors are STRUCTURAL (slot k belongs to
     # node k//M; padding = masked self-loops), so the empty batch keeps the
     # ownership pattern; flat COO padding points at the last node slot
@@ -104,6 +115,8 @@ def parallel_batches(
     prep_fn: Callable | None = None,
     node_multiple: int = 1,
     transpose_shards: int = 1,
+    pack_fn: Callable | None = None,
+    telemetry=None,
 ) -> Iterable[GraphBatch]:
     """Yield device-stacked batches: leaves have leading axis [D, ...].
 
@@ -122,19 +135,27 @@ def parallel_batches(
     graph sharding attaches per-shard transpose mappings here);
     ``node_multiple`` rounds bucket-computed node capacities up so strips
     divide evenly (capacities_for).
+
+    ``pack_fn`` is handed to the batch iterators as the one-chip path hands
+    it (``data.compact.compact_pack_fn``: compact staging); a compact batch
+    stacks on the device axis like any other pytree.
+
+    ``telemetry`` counts the training tail that ``drop_last`` discards
+    (``dp_dropped_batches``: per-device batches, once a pass over the data).
     """
     if buckets > 1:
         source = bucketed_batch_iterator(
             graphs, batch_size, buckets, shuffle=shuffle, rng=rng,
             dense_m=dense_m, in_cap=in_cap, snug=snug, stats=stats,
             edge_dtype=edge_dtype, node_multiple=node_multiple,
-            transpose_shards=transpose_shards,
+            transpose_shards=transpose_shards, pack_fn=pack_fn,
         )
     else:
         source = batch_iterator(
             graphs, batch_size, node_cap, edge_cap, shuffle=shuffle, rng=rng,
             dense_m=dense_m, in_cap=in_cap, snug=snug,
             edge_dtype=edge_dtype, transpose_shards=transpose_shards,
+            pack_fn=pack_fn,
         )
         if stats is not None:
             source = stats.wrap(source)
@@ -159,6 +180,9 @@ def parallel_batches(
             if q:
                 q += [empty_batch_like(q[0])] * (n_devices - len(q))
                 yield invariants.maybe_check_any(stack_batches(q), dense_m)
+    elif telemetry is not None:
+        telemetry.counter_add("dp_dropped_batches",
+                              sum(len(q) for q in pending.values()))
 
 
 def is_multiprocess_mesh(mesh: Mesh) -> bool:
@@ -199,8 +223,14 @@ def shard_scan_stack(tree, mesh: Mesh):
     """device_put a STACK of device-stacked batches ([B, D, ...] leaves):
     axis 0 is the scan/step axis (replicated), axis 1 the device axis
     (split over the replica mesh axes) — the staging for ScanEpochDriver
-    under data parallelism."""
+    under data parallelism. A compact stack goes over with flat rows
+    (``data.compact.flat_rows``: the same fields and bytes, laid out so
+    that the chip never re-lays out the resident stack)."""
     axes = _replica_axes(mesh)
+    if hasattr(tree, "atom_idx"):  # CompactBatch (duck-typed, as elsewhere)
+        from cgnn_tpu.data.compact import flat_rows
+
+        tree = flat_rows(tree)
 
     def put(x):
         return jax.device_put(
@@ -212,6 +242,33 @@ def shard_scan_stack(tree, mesh: Mesh):
 
 def _squeeze0(tree):
     return jax.tree_util.tree_map(lambda x: x[0], tree)
+
+
+def _per_shard(inner: Callable, expand: Callable | None) -> Callable:
+    """The body one device runs under shard_map: its row of the stacked
+    batch, expanded from the compact form when that is what was staged."""
+
+    def body(state: TrainState, stacked):
+        batch = _squeeze0(stacked)
+        return inner(state, batch if expand is None else expand(batch))
+
+    return body
+
+
+def allreduce_nbytes(state: TrainState) -> int:
+    """Bytes one replica hands to a training step's all-reduce: the
+    gradient (one entry a parameter) and the running statistics."""
+    return sum(x.nbytes for x in jax.tree_util.tree_leaves(
+        (state.params, state.batch_stats)))
+
+
+def count_deployment(telemetry, state: TrainState, n_replicas: int,
+                     batch_size: int) -> None:
+    """The data-parallel deployment as counters of the run (once a fit)."""
+    telemetry.counter_add("dp_replicas", n_replicas)
+    telemetry.counter_add("dp_global_batch", n_replicas * batch_size)
+    telemetry.counter_add("allreduce_bytes_per_step",
+                          allreduce_nbytes(state))
 
 
 def _replica_axes(mesh: Mesh) -> tuple[str, ...]:
@@ -228,6 +285,7 @@ def make_parallel_train_step(
     inner_step: Callable | None = None,
     grad_health: bool = False,
     guard: bool = False,
+    expand: Callable | None = None,
 ) -> Callable:
     """shard_map-wrapped train step: (replicated state, [D,...] batch).
 
@@ -243,6 +301,11 @@ def make_parallel_train_step(
     body with the in-graph divergence guard (resilience.guard): the
     post-pmean params it checks are replicated, so every device takes the
     same keep-or-skip branch.
+
+    ``expand`` (``data.compact.make_expander``: compact staging) rebuilds
+    the full batch from its raw form INSIDE the per-shard body, after the
+    device row is taken: every chip expands its own row from its own copy
+    of the element table, so no table row and no batch crosses the mesh.
     """
     axes = _replica_axes(mesh)
     if inner_step is not None and axes != ("data",):
@@ -257,9 +320,7 @@ def make_parallel_train_step(
         from cgnn_tpu.resilience.guard import guard_step
 
         inner = guard_step(inner)
-
-    def body(state: TrainState, stacked: GraphBatch):
-        return inner(state, _squeeze0(stacked))
+    body = _per_shard(inner, expand)
 
     smapped = jax.shard_map(
         body,
@@ -298,6 +359,7 @@ def make_parallel_eval_step(
     classification: bool = False,
     loss_fn: Callable | None = None,
     inner_step: Callable | None = None,
+    expand: Callable | None = None,
 ) -> Callable:
     axes = _replica_axes(mesh)
     if inner_step is not None and axes != ("data",):
@@ -307,12 +369,9 @@ def make_parallel_eval_step(
     inner = inner_step or make_eval_step(
         classification, axis_name=axes, loss_fn=loss_fn
     )
-
-    def body(state: TrainState, stacked: GraphBatch):
-        return inner(state, _squeeze0(stacked))
-
     smapped = jax.shard_map(
-        body, mesh=mesh, in_specs=(P(), P(axes)), out_specs=P(),
+        _per_shard(inner, expand), mesh=mesh, in_specs=(P(), P(axes)),
+        out_specs=P(),
         check_vma=False,
     )
     return jax.jit(smapped)
@@ -361,6 +420,7 @@ def fit_data_parallel(
     profile_steps: int = 0,
     profile_dir: str = "",
     edge_dtype=np.float32,
+    compact=None,
     chunk_steps: int | None = None,
     telemetry=None,
     guard: bool = False,
@@ -390,6 +450,16 @@ def fit_data_parallel(
     for device_resident, mesh-shard into HBM) the stacked batches once,
     reshuffling stacked-batch order across epochs.
 
+    What is staged under a mesh is what is staged on one chip. With
+    ``compact`` (a ``data.compact.CompactSpec``; requires ``scan_epochs``
+    and the dense layout, as in train.loop.fit) every device row is a
+    ``CompactBatch`` — vocabulary indices and scalar distances, ~12x fewer
+    bytes — split over the replica axes like any other leaf, and each
+    device rebuilds its own row inside the per-shard step body
+    (``make_parallel_train_step(expand=...)``). Without it, and always
+    under a 'graph' axis (whose edge leaves are split a second time), the
+    rows are full ``GraphBatch``es.
+
     ``telemetry`` mirrors train.loop.fit: spans, padding/HBM gauges, and
     — with ``scan_epochs`` at step level — the in-scan per-step stream
     (the driver taps the post-shard_map metrics, one callback per step).
@@ -411,6 +481,18 @@ def fit_data_parallel(
     if dense_m is not None:
         edge_cap = node_cap * dense_m
     graph_shards = int(mesh.shape.get("graph", 1))
+    pack_fn = expand = None
+    if compact is not None:
+        if not scan_epochs or dense_m is None:
+            raise ValueError("compact staging requires scan_epochs and the "
+                             "dense layout (dense_m), as in train.loop.fit")
+        if graph_shards > 1:
+            raise NotImplementedError(
+                "compact staging is not supported with edge-sharded "
+                "('graph') meshes (full staging only)")
+        from cgnn_tpu.data.compact import compact_pack_fn, make_expander
+
+        pack_fn, expand = compact_pack_fn(compact), make_expander(compact)
     multiproc = is_multiprocess_mesh(mesh)
     if multiproc:
         if graph_shards > 1:
@@ -490,13 +572,15 @@ def fit_data_parallel(
             n_dev = max(1, n_dev // jax.process_count())
         train_step = make_parallel_train_step(
             mesh, classification, inner_step=train_step_fn,
-            grad_health=telemetry.step_level, guard=guard,
+            grad_health=telemetry.step_level, guard=guard, expand=expand,
         )
         eval_step = make_parallel_eval_step(
-            mesh, classification, inner_step=eval_step_fn
+            mesh, classification, inner_step=eval_step_fn, expand=expand,
         )
         shard_put = lambda b: shard_leading_axis(b, mesh)  # noqa: E731
     state = replicate_state(state, mesh)
+    count_deployment(telemetry, state,
+                     n_dev_global if multiproc else n_dev, batch_size)
     best = -np.inf if classification else np.inf
     history = []
     rng = np.random.default_rng(seed)
@@ -524,7 +608,8 @@ def fit_data_parallel(
             shuffle=True, rng=rng, dense_m=dense_m, buckets=buckets,
             snug=snug, stats=pad_stats, edge_dtype=edge_dtype,
             prep_fn=prep_train, node_multiple=node_multiple,
-            transpose_shards=transpose_shards,
+            transpose_shards=transpose_shards, pack_fn=pack_fn,
+            telemetry=telemetry,
         ))
 
     def make_val_it():
@@ -532,7 +617,7 @@ def fit_data_parallel(
             val_graphs, n_dev, batch_size, node_cap, edge_cap,
             pad_incomplete=True, dense_m=dense_m, in_cap=0, buckets=buckets,
             snug=snug, edge_dtype=edge_dtype,
-            prep_fn=prep_val, node_multiple=node_multiple,
+            prep_fn=prep_val, node_multiple=node_multiple, pack_fn=pack_fn,
         )
 
     if multiproc:

@@ -121,9 +121,8 @@ def check_device_resident_fit(staged_bytes: int, n_devices: int = 1,
         f"({_STAGE_FRACTION:.0%} of what "
         f"{jax.devices()[0].device_kind} reports free): "
         f"FALLING BACK to host-side pack-once staging (per-step H2D each "
-        f"epoch). To stage on-device: --compact-staging (~12x smaller; "
-        f"single-device runs today), more data-parallel devices, or a "
-        f"smaller dataset/batch capacity."
+        f"epoch). To stage on-device: --compact-staging (~12x smaller), "
+        f"more data-parallel devices, or a smaller dataset/batch capacity."
     )
     return False
 
@@ -393,6 +392,9 @@ def staged_edge_fea_nbytes(batches) -> int:
 
 
 def _staging_args(batches: list) -> dict:
+    """Args of the ``scan.stage`` span. ``bytes`` is what the host hands
+    over: under a mesh the batches carry the device axis, so it is the
+    GLOBAL total over all chips (a chip holds its share of axis 1)."""
     return {"groups": len({batch_shape_key(b) for b in batches}),
             "batches": len(batches), "bytes": int(staged_nbytes(batches)),
             "edge_fea_bytes": staged_edge_fea_nbytes(batches)}
@@ -448,7 +450,11 @@ class ScanEpochDriver:
         ``expand`` (compact staging, data/compact.py) maps each scanned
         batch to the full GraphBatch INSIDE the jitted scan body — the
         stacked groups then hold the ~12x smaller raw form in HBM and the
-        table-gather + Gaussian expansion fuse into each step.
+        table-gather + Gaussian expansion fuse into each step. One-chip
+        callers only: under a mesh the scanned batch still carries the
+        device axis, and the expander belongs inside the per-shard body
+        (``parallel.make_parallel_train_step(expand=...)``), which stages
+        the same compact form.
 
         ``telemetry`` at step level stages the in-scan metric tap
         (observe.stream) into every scan body: per-step scalars ring out

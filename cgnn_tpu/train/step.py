@@ -109,11 +109,14 @@ def make_train_step(
         if axis_name is not None:
             # DDP-equivalent: average grads across replicas; running stats are
             # also averaged (stronger than torch DDP, which keeps rank-0's);
-            # metric sums add up exactly.
-            if pmean_grads:
-                grads = lax.pmean(grads, axis_name)
-            new_stats = lax.pmean(new_stats, axis_name)
-            metrics = lax.psum(metrics, axis_name)
+            # metric sums add up exactly (the guard reads its loss off them).
+            # One scope for every collective of the step, so a device trace
+            # can say what the mesh costs (phases.DP_ALLREDUCE)
+            with jax.named_scope(phases.DP_ALLREDUCE):
+                if pmean_grads:
+                    grads = lax.pmean(grads, axis_name)
+                new_stats = lax.pmean(new_stats, axis_name)
+                metrics = lax.psum(metrics, axis_name)
         with jax.named_scope(phases.OPTIMIZER):
             new_state = state.apply_gradients(grads, new_stats)
         if grad_health:
@@ -123,9 +126,10 @@ def make_train_step(
             # post-pmean grads): reduce it first so a NaN on ANY shard is
             # visible everywhere instead of shard 0's value escaping the
             # shard_map as the replicated output
-            health_loss = (
-                loss if axis_name is None else lax.pmean(loss, axis_name)
-            )
+            health_loss = loss
+            if axis_name is not None:
+                with jax.named_scope(phases.DP_ALLREDUCE):
+                    health_loss = lax.pmean(loss, axis_name)
             metrics = metrics | grad_health_metrics(
                 grads, state.params, new_state.params, loss=health_loss
             )
@@ -147,7 +151,8 @@ def make_eval_step(
         with jax.named_scope(phases.LOSS):
             _, metrics = compute_loss(out, batch, state.normalizer)
         if axis_name is not None:
-            metrics = lax.psum(metrics, axis_name)
+            with jax.named_scope(phases.DP_ALLREDUCE):
+                metrics = lax.psum(metrics, axis_name)
         return metrics
 
     return eval_step

@@ -502,6 +502,10 @@ _CLASSIFIED = [
     (_F2 + "transpose(jvp(loss))/broadcast_in_dim", ("loss", "bwd")),
     # unscoped: the optimizer and loss of the step before it had scopes,
     # a reducer's parameters, an argument's name
+    # the data-parallel step's collectives (train/step.py), as the
+    # four-device scan program of parallel/data_parallel.py names them
+    (_SCAN + "jit(shmap_body)/dp.allreduce/psum", ("dp.allreduce", "fwd")),
+    (_SCAN + "jit(shmap_body)/dp.allreduce/div", ("dp.allreduce", "fwd")),
     ("jit(train_step)/add", ("other", "fwd")),
     (_SCAN + "mul", ("other", "fwd")),
     ("reduce_sum", ("other", "fwd")),

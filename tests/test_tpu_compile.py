@@ -19,12 +19,11 @@ import jax.numpy as jnp
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     import os
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     import importlib.util
 
@@ -34,9 +33,25 @@ def one_chip():
     # with libtpu installed (this container, the driver's) a topology that
     # cannot be described is a failure, not a skip: these tests are the only
     # pin of what they guard
-    topo = topologies.get_topology_desc(platform="tpu",
+    return topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """The described host's four chips as the 'data' mesh of
+    ``train.py --data-parallel``."""
+    from jax.sharding import Mesh
+
+    assert len(topo.devices) == 4
+    return Mesh(np.array(topo.devices), ("data",))
 
 
 @pytest.fixture()
@@ -237,3 +252,127 @@ def test_no_matmul_over_gathered_rows(one_chip, no_compile_cache, task,
     assert {dims for _, dims in gathers} == rows, gathers
     assert [d for d in gathers if d[0] == phases.FWD] == [
         (phases.FWD, f"{edge_cap},{2 * f}")] * n_convs
+
+
+def _four_chip_program(mesh):
+    """Cell ``mp.train-dp4``'s largest scan program (chunk of four steps
+    over the largest bucket's resident stack) at the cell's real size on the
+    described host's four chips, assembled as ``fit_data_parallel`` and
+    ``benchmark/kinds/dp_train.py`` assemble it: 128 structures a chip,
+    compact staging with flat rows, the guard, the published widths in
+    bfloat16 -> (compiled, node capacity, stack length, bytes of one chip's
+    row of the stack).
+
+    This compile picks the entry layouts it likes best, so a relayout of
+    the staged arrays that the chip pays for is not in its text (PERF.md
+    section 6, PRs 25 and 32: only a traced chip run shows one)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from cgnn_tpu.config import DataConfig, ModelConfig, build_model
+    from cgnn_tpu.data.compact import (
+        CompactSpec,
+        compact_pack_fn,
+        flat_rows,
+        make_expander,
+    )
+    from cgnn_tpu.data.dataset import load_synthetic_mp
+    from cgnn_tpu.data.graph import bucketed_batch_iterator
+    from cgnn_tpu.parallel.data_parallel import (
+        make_parallel_eval_step,
+        make_parallel_train_step,
+        stack_batches,
+    )
+    from cgnn_tpu.train import Normalizer, create_train_state, make_optimizer
+    from cgnn_tpu.train.loop import ScanEpochDriver
+
+    n_dev, per_dev, m = 4, 128, 12
+    # the cell's steps a bucket: ~32 device groups an epoch-copy over three
+    # buckets, 216 copies
+    stack = 2304
+    data_cfg = DataConfig()
+    graphs = load_synthetic_mp(768, data_cfg.featurize_config(), seed=0)
+    spec = CompactSpec.build(graphs, data_cfg.featurize_config().gdf(),
+                             dense_m=m, edge_dtype=jnp.bfloat16)
+    batches = list(bucketed_batch_iterator(
+        graphs, per_dev, 3, dense_m=m, snug=True, edge_dtype=jnp.bfloat16,
+        pack_fn=compact_pack_fn(spec)))
+    largest = max(batches, key=lambda b: b.node_capacity)
+    node_cap = largest.node_capacity
+    assert node_cap > 4000  # the real size: ~30 atoms a structure and more
+    group = stack_batches([largest] * n_dev)  # [D, ...]: shapes only
+    model = build_model(
+        ModelConfig(atom_fea_len=64, n_conv=3, h_fea_len=128,
+                    dtype="bfloat16", dense_m=m), data_cfg, "regression")
+    expand = make_expander(spec)
+    state = create_train_state(
+        model, expand(largest),
+        make_optimizer(optim="sgd", lr=0.01, momentum=0.9,
+                       lr_milestones=[10**9]),
+        Normalizer(mean=jnp.zeros(1), std=jnp.ones(1)))
+    driver = ScanEpochDriver(
+        make_parallel_train_step(mesh, guard=True, expand=expand),
+        make_parallel_eval_step(mesh, expand=expand), [group], [],
+        np.random.default_rng(0), stage=flat_rows,  # shard_scan_stack's form
+        chunk_steps=2)
+    (key, stacked), = driver._train_groups.items()
+    fn = driver._scan_fn(driver._train_scans, (key, 4), driver._train_body,
+                         True)
+    replicated = NamedSharding(mesh, P())
+
+    def staged(x):  # [1, D, ...] -> the real stack, split over the chips
+        where = NamedSharding(
+            mesh, P(None, "data", *([None] * (np.ndim(x) - 2))))
+        return jax.ShapeDtypeStruct((stack,) + np.shape(x)[1:], x.dtype,
+                                    sharding=where)
+
+    shapes = (
+        jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                           sharding=replicated), state),
+        jax.tree_util.tree_map(staged, stacked),
+        jax.ShapeDtypeStruct((4,), np.int32, sharding=replicated),
+    )
+    compiled = fn.lower(*shapes).compile()
+    row = sum(int(np.prod(np.shape(x)[2:])) * x.dtype.itemsize
+              for x in jax.tree_util.tree_leaves(stacked))
+    return compiled, node_cap, stack, row
+
+
+def test_four_chip_scan_program_at_real_size(four_chips, no_compile_cache):
+    """It compiles; a chip holds the program and the stack it is handed in
+    well under its 16 GB; the only collectives are all-reduces and every
+    one of them carries the ``dp.allreduce`` scope (train/step.py), so a
+    device trace can attribute them; nothing gathers a batch or the element
+    table across chips."""
+    from cgnn_tpu.observe import phases
+
+    compiled, node_cap, stack, row = _four_chip_program(four_chips)
+    m = 12
+    mem = compiled.memory_analysis()
+    on_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert mem.argument_size_in_bytes >= stack * row  # one chip's share
+    assert on_chip < 16e9 / 2, on_chip
+
+    text = compiled.as_text()
+    collectives, unscoped, batch_sized = [], [], []
+    wide = re.compile(rf"\[(\d+,)*{node_cap},{m}(,\d+)*\]")
+    for comp in phases._parse(text).values():
+        for name, rest in comp["instrs"].items():
+            op = re.search(r"\s(all-reduce|all-gather|all-to-all|"
+                           r"collective-permute|reduce-scatter|"
+                           r"collective-broadcast)(-start)?\(", rest)
+            if not op:
+                continue
+            collectives.append(op.group(1))
+            op_name = phases._OP_NAME.search(rest)
+            if not op_name or phases.classify(
+                    op_name.group(1))[0] != phases.DP_ALLREDUCE:
+                unscoped.append(rest[:200])
+            if wide.search(rest.partition(op.group(0))[0]):
+                batch_sized.append(rest[:200])
+    print(f"four-chip program: node capacity {node_cap}, {on_chip / 1e9:.2f} "
+          f"GB a chip, {len(collectives)} all-reduces")
+    assert collectives and set(collectives) == {"all-reduce"}, collectives
+    assert not unscoped, unscoped
+    assert not batch_sized, batch_sized

@@ -187,7 +187,10 @@ def build_parser() -> argparse.ArgumentParser:
                    help="per-frame Cartesian jitter (Å) for synthetic MD")
     # TPU-native additions
     p.add_argument("--data-parallel", action="store_true",
-                   help="shard batches over all visible devices (DP over ICI)")
+                   help="shard batches over all visible devices (DP over "
+                        "ICI); --batch-size is then per device, and the "
+                        "staged form, scan driver, guard and --chunk-steps "
+                        "are the one-chip path's")
     p.add_argument("--graph-shards", type=int, default=1, metavar="G",
                    help="shard every batch's edge axis over a G-way 'graph' "
                         "mesh axis (edge-sharded message passing — the "
@@ -201,8 +204,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "+ scalar distance, ~12x fewer bytes) and rebuild "
                         "features inside the jitted scan body "
                         "(data/compact.py). Requires --scan-epochs + dense "
-                        "layout, energy/classification tasks, single "
-                        "device. auto = on when supported")
+                        "layout and an energy/classification task; on one "
+                        "device and under --data-parallel alike (each "
+                        "device expands its own rows), not with "
+                        "--graph-shards. auto = on when supported")
     p.add_argument("--compile-cache", type=str, default=None,
                    metavar="DIR", help=COMPILE_CACHE_HELP)
     p.add_argument("--layout", choices=["dense", "coo"], default="dense",
@@ -643,12 +648,43 @@ def main(argv=None) -> int:
         eval_step_fn = make_force_eval_step(args.energy_weight, args.force_weight)
         step_overrides = {"best_metric": "force_mae"}
 
-    if graph_shards > 1 or (args.data_parallel and len(devices) > 1):
-        if args.compact_staging == "on":
-            print("--compact-staging on is not yet supported with "
-                  "--data-parallel/--graph-shards (full staging only); "
-                  "drop the flag or use auto", file=sys.stderr)
+    def choose_compact() -> int:
+        """Decide the staged form, for one chip and for a 'data' mesh alike
+        (the mesh stages what one chip stages) -> exit code, 0 to go on."""
+        compact_ok = (args.scan_epochs and layout_m is not None
+                      and not force_task)
+        if args.compact_staging == "on" and not compact_ok:
+            print("--compact-staging on requires --scan-epochs, the dense "
+                  "layout, and a non-force task", file=sys.stderr)
             return 2
+        if args.compact_staging != "off" and compact_ok:
+            from cgnn_tpu.data.compact import CompactSpec, CompactUnsupported
+
+            try:
+                step_overrides["compact"] = CompactSpec.build(
+                    train_g + val_g + test_g,
+                    data_cfg.featurize_config().gdf(),
+                    dense_m=layout_m, edge_dtype=edge_dtype,
+                )
+                print("compact staging: on (raw atoms+distances staged; "
+                      "features rebuilt on device)")
+            except CompactUnsupported as e:
+                if args.compact_staging == "on":
+                    raise
+                print(f"compact staging unavailable ({e}); using full "
+                      f"staging", file=sys.stderr)
+        return 0
+
+    if graph_shards > 1 or (args.data_parallel and len(devices) > 1):
+        if graph_shards > 1 and args.compact_staging == "on":
+            print("--compact-staging on is not supported with "
+                  "--graph-shards (full staging only); drop the flag or "
+                  "use auto", file=sys.stderr)
+            return 2
+        if graph_shards == 1:
+            rc = choose_compact()
+            if rc:
+                return rc
         from cgnn_tpu.parallel import fit_data_parallel
         from cgnn_tpu.parallel.mesh import make_2d_mesh
 
@@ -714,28 +750,9 @@ def main(argv=None) -> int:
                 ),
                 "eval_step_fn": eval_step_fn,
             }
-        compact_ok = (args.scan_epochs and layout_m is not None
-                      and not force_task)
-        if args.compact_staging == "on" and not compact_ok:
-            print("--compact-staging on requires --scan-epochs, the dense "
-                  "layout, and a non-force task", file=sys.stderr)
-            return 2
-        if args.compact_staging != "off" and compact_ok:
-            from cgnn_tpu.data.compact import CompactSpec, CompactUnsupported
-
-            try:
-                step_overrides["compact"] = CompactSpec.build(
-                    train_g + val_g + test_g,
-                    data_cfg.featurize_config().gdf(),
-                    dense_m=layout_m, edge_dtype=edge_dtype,
-                )
-                print("compact staging: on (raw atoms+distances staged; "
-                      "features rebuilt on device)")
-            except CompactUnsupported as e:
-                if args.compact_staging == "on":
-                    raise
-                print(f"compact staging unavailable ({e}); using full "
-                      f"staging", file=sys.stderr)
+        rc = choose_compact()
+        if rc:
+            return rc
         state, result = fit(
             state, train_g, val_g, epochs=args.epochs, batch_size=args.batch_size,
             node_cap=node_cap, edge_cap=edge_cap, classification=classification,
