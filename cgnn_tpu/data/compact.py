@@ -33,7 +33,7 @@ import numpy as np
 from flax import struct
 
 from cgnn_tpu.data.featurize import gaussian_expand
-from cgnn_tpu.data.graph import GraphBatch, transpose_slots
+from cgnn_tpu.data.graph import TRANSPOSE_FIELDS, GraphBatch, transpose_slots
 
 
 class CompactUnsupported(ValueError):
@@ -235,7 +235,8 @@ class CompactBatch(struct.PyTreeNode):
     in_mask: Any = None  # [Ncap, M] u8
     over_slots: Any = None  # [O] i32
     over_nodes: Any = None  # [O] i32
-    over_mask: Any = None  # [O] u8
+    over_last: Any = None  # [Ncap] i32
+    over_runs: Any = None  # [K] i32
 
     # PaddingStats/driver interface parity with GraphBatch
     @property
@@ -259,6 +260,7 @@ def compact_shape_key(batch: CompactBatch) -> tuple:
         np.shape(batch.targets),
         None if batch.in_slots is None else np.shape(batch.in_slots),
         None if batch.over_slots is None else np.shape(batch.over_slots),
+        None if batch.over_runs is None else np.shape(batch.over_runs),
     )
 
 
@@ -335,11 +337,13 @@ def pack_compact(
     over_cap: int | None = None,
     edge_dtype=None,  # accepted for pack_fn signature parity; spec wins
     out: CompactBatch | None = None,
+    run_cap: int | None = None,
 ) -> CompactBatch:
     """pack_graphs' compact twin: same slot geometry, raw-form payload.
 
     Raises the same ``TransposeOverflowError`` on two-tier overflow so
-    ``_pack_overflow_safe``'s split-don't-abort recovery applies unchanged.
+    ``_pack_overflow_safe``'s split-don't-abort recovery applies unchanged
+    (and the same ``TransposeRunError`` on a run longer than ``run_cap``).
 
     ``out`` (forward-only batches) recycles a previously allocated buffer
     set (``alloc_compact_buffers``) instead of allocating fresh arrays:
@@ -481,17 +485,15 @@ def pack_compact(
             else:
                 target_mask[gi, : len(t)] = 1.0
 
-    in_slots = in_mask = over_slots = over_nodes = over_mask = None
+    mapping = (None,) * len(TRANSPOSE_FIELDS)
     if in_cap is not None and over_cap is not None:
         raise ValueError("in_cap and over_cap are mutually exclusive")
     if in_cap == 0:  # explicit disable (eval-only batches: no backward)
         in_cap = None
     if in_cap is not None or over_cap is not None:
-        in_slots, in_mask, over_slots, over_nodes, over_mask = (
-            transpose_slots(
-                neighbors, edge_mask.reshape(-1) > 0, node_cap, dense_m,
-                in_cap, over_cap,
-            )
+        mapping = transpose_slots(
+            neighbors, edge_mask.reshape(-1) > 0, node_cap, dense_m,
+            in_cap, over_cap, run_cap,
         )
 
     return CompactBatch(
@@ -504,11 +506,7 @@ def pack_compact(
         graph_mask=graph_mask,
         targets=targets,
         target_mask=target_mask,
-        in_slots=in_slots,
-        in_mask=in_mask,
-        over_slots=over_slots,
-        over_nodes=over_nodes,
-        over_mask=over_mask,
+        **dict(zip(TRANSPOSE_FIELDS, mapping)),
     )
 
 
@@ -565,7 +563,8 @@ def make_expander(spec: CompactSpec) -> Callable[[CompactBatch], GraphBatch]:
                 in_mask=in_mask,
                 over_slots=cb.over_slots,
                 over_nodes=cb.over_nodes,
-                over_mask=cb.over_mask,
+                over_last=cb.over_last,
+                over_runs=cb.over_runs,
             )
 
     return expand

@@ -45,6 +45,18 @@ class TransposeOverflowError(ValueError):
     """
 
 
+class TransposeRunError(ValueError):
+    """A node's run in the two-tier transpose overflow list is longer than
+    ``run_cap`` (``overflow_run_cap``: the data set's largest in-degree
+    beyond ``dense_m``). The backward sums each node's run looking back
+    ``run_cap - 1`` entries, a reach fixed when the program is compiled
+    (ops/segment.py _run_totals), so a longer run would lose gradient: it
+    raises at pack time.
+    Splitting the batch cannot cure it (a run belongs to one graph); it
+    means ``run_cap`` was sized from other graphs than are being packed.
+    """
+
+
 @dataclasses.dataclass
 class CrystalGraph:
     """One featurized crystal (host-side, numpy)."""
@@ -103,12 +115,18 @@ class GraphBatch(struct.PyTreeNode):
     # two-tier transpose overflow (pack_graphs over_cap): when in_slots is
     # sized [Ncap, M] (tier 1 = first M incoming edges; mean in-degree == M
     # but max can be ~2M), the ~7% of edges beyond rank M land here as a
-    # node-sorted COO list consumed by a small sorted segment-sum in the
-    # backward — so tier 1 moves no padding bytes (measured: the [N, 2M]
-    # single-tier gather was the largest op of the whole step, half padding)
-    over_slots: Any = None  # [Ocap] i32 edge-slot indices
+    # node-sorted list: each overflowing node (~40% of atoms) owns one RUN
+    # of at most Kcap entries. The backward gathers the list's rows, sums
+    # every run in place and gathers each node's total through over_last
+    # (ops/segment.py) — so tier 1 moves no padding bytes (measured: the
+    # [N, 2M] single-tier gather was the largest op of the whole step, half
+    # padding) and no direction of the conv holds a scatter
+    over_slots: Any = None  # [Ocap] i32 edge-slot indices (real: a prefix)
     over_nodes: Any = None  # [Ocap] i32 neighbor node (non-decreasing)
-    over_mask: Any = None  # [Ocap] u8
+    over_last: Any = None  # [Ncap] i32 last entry of the node's run; Ocap
+    #   (out of range: reads as a zero row) for a node that owns none
+    over_runs: Any = None  # [Kcap] i32 runs of length k+1 in this batch;
+    #   its LENGTH is the run capacity the program is compiled for
 
     @property
     def node_capacity(self) -> int:
@@ -137,6 +155,11 @@ class GraphBatch(struct.PyTreeNode):
         (host-side numpy view; on device this reshape is a relayout)."""
         e = self.edges
         return e.reshape(-1, np.shape(e)[-1]) if np.ndim(e) == 3 else e
+
+
+# the transpose mapping's members, in the order transpose_slots returns them
+TRANSPOSE_FIELDS = ("in_slots", "in_mask", "over_slots", "over_nodes",
+                    "over_last", "over_runs")
 
 
 def dense_neighbor_views(
@@ -185,6 +208,7 @@ def batch_shape_key(batch) -> tuple:
         str(batch.edges.dtype),
         None if batch.in_slots is None else np.shape(batch.in_slots),
         None if batch.over_slots is None else np.shape(batch.over_slots),
+        None if batch.over_runs is None else np.shape(batch.over_runs),
     )
 
 
@@ -251,6 +275,22 @@ def overflow_cap(
     return _align8(int(max(need, per_graph.max(), 8)))
 
 
+def overflow_run_cap(graphs: Sequence[CrystalGraph], dense_m: int) -> int:
+    """Static capacity for ONE node's run in the overflow list: the data
+    set's (8-aligned) largest in-degree beyond tier 1's ``dense_m``. A
+    property of the data set like ``overflow_cap`` (the backward's reach
+    over the list is compiled from it); a longer run raises
+    ``TransposeRunError`` at pack time."""
+    return max(in_degree_cap(graphs) - dense_m, 1)
+
+
+def overflow_rows(batch) -> int:
+    """Real entries in ``batch``'s overflow list (its prefix), read off the
+    run counts; over every leading stack axis (chips, shards)."""
+    runs = np.asarray(batch.over_runs)
+    return int((runs * np.arange(1, runs.shape[-1] + 1)).sum())
+
+
 def round_to_bucket(n: int, minimum: int = 64, growth: float = 1.3) -> int:
     """Smallest capacity in the geometric bucket ladder that fits ``n``.
 
@@ -274,6 +314,7 @@ def pack_graphs(
     over_cap: int | None = None,
     edge_dtype=np.float32,
     transpose_shards: int = 1,
+    run_cap: int | None = None,
 ) -> GraphBatch:
     """Concatenate graphs into one fixed-capacity GraphBatch (numpy).
 
@@ -297,8 +338,14 @@ def pack_graphs(
     ``in_cap``): tier 1 is ``in_slots`` at width ``dense_m`` (each node's
     first M incoming edges — zero padding bytes at mean in-degree M), and
     the ~7% of edges with within-neighbor rank >= M go to the node-sorted
-    ``over_slots``/``over_nodes`` COO overflow (capacity ``over_cap``, see
-    ``overflow_cap``; overflowing it raises, never truncates).
+    ``over_slots``/``over_nodes`` overflow list (capacity ``over_cap``, see
+    ``overflow_cap``; overflowing it raises, never truncates). ~40% of the
+    atoms of an MP-like crystal own a run of it, 1 to ~10 entries long;
+    ``over_last`` points every node at its run's last entry, where the
+    backward leaves the run's sum, and ``over_runs`` (``run_cap`` long,
+    see ``overflow_run_cap``; ``None`` sizes it from this batch alone)
+    counts the runs by length. A run longer than ``run_cap`` raises
+    ``TransposeRunError``.
 
     ``transpose_shards > 1`` (two-tier only) builds the PER-SHARD stacked
     mappings for node-strip graph sharding directly
@@ -500,8 +547,7 @@ def pack_graphs(
             if g.forces is not None:
                 node_targets[node_offs[gi] : node_offs[gi + 1]] = g.forces
 
-    in_slots = in_mask = None
-    over_slots = over_nodes = over_mask = None
+    mapping = (None,) * len(TRANSPOSE_FIELDS)
     if in_cap is not None and over_cap is not None:
         raise ValueError("in_cap (single-tier) and over_cap (two-tier) are "
                          "mutually exclusive")
@@ -515,18 +561,14 @@ def pack_graphs(
                     "transpose_shards requires the two-tier layout "
                     "(over_cap; in_cap single-tier mappings cannot shard)"
                 )
-            in_slots, in_mask, over_slots, over_nodes, over_mask = (
-                shard_transpose_slots(
-                    neighbors, edge_mask > 0, node_cap, dense_m,
-                    transpose_shards, over_cap,
-                )
+            mapping = shard_transpose_slots(
+                neighbors, edge_mask > 0, node_cap, dense_m,
+                transpose_shards, over_cap, run_cap,
             )
         else:
-            in_slots, in_mask, over_slots, over_nodes, over_mask = (
-                transpose_slots(
-                    neighbors, edge_mask > 0, node_cap, dense_m, in_cap,
-                    over_cap,
-                )
+            mapping = transpose_slots(
+                neighbors, edge_mask > 0, node_cap, dense_m, in_cap,
+                over_cap, run_cap,
             )
 
     return GraphBatch(
@@ -545,11 +587,7 @@ def pack_graphs(
         lattices=lattices,
         edge_offsets=edge_offsets,
         node_targets=node_targets,
-        in_slots=in_slots,
-        in_mask=in_mask,
-        over_slots=over_slots,
-        over_nodes=over_nodes,
-        over_mask=over_mask,
+        **dict(zip(TRANSPOSE_FIELDS, mapping)),
     )
 
 
@@ -560,13 +598,15 @@ def transpose_slots(
     dense_m: int,
     in_cap: int | None,
     over_cap: int | None,
+    run_cap: int | None = None,
 ) -> tuple:
     """Transpose of the neighbor gather: group real edge slots by their
     neighbor node (the scatter-free-backward mapping; see pack_graphs).
 
-    ``neighbors`` [Ecap] i32, ``edge_real`` [Ecap] bool. Returns
-    ``(in_slots, in_mask, over_slots, over_nodes, over_mask)`` — the last
-    three ``None`` unless ``over_cap`` selects the two-tier layout.
+    ``neighbors`` [Ecap] i32, ``edge_real`` [Ecap] bool. Returns the
+    ``TRANSPOSE_FIELDS`` ``(in_slots, in_mask, over_slots, over_nodes,
+    over_last, over_runs)`` — the last four ``None`` unless ``over_cap``
+    selects the two-tier layout.
     Stable-sorting by neighbor + a cumcount gives each real edge its
     row-local position; padding entries stay masked at slot 0.
     Shared by ``pack_graphs`` and the compact-staging packer
@@ -600,7 +640,7 @@ def transpose_slots(
     # mask would stage ~0.5 GB of HBM
     in_slots = np.take(pad, src.ravel(), mode="clip")
     in_mask = tier_valid.astype(np.uint8)
-    over_slots = over_nodes = over_mask = None
+    over_slots = over_nodes = over_last = over_runs = None
     if over_cap is not None:
         # edges with within-neighbor rank >= tier, in sorted positions
         sel2 = np.arange(len(real)) - starts.repeat(counts) >= tier
@@ -610,15 +650,31 @@ def transpose_slots(
                 f"batch has {k} transpose-overflow edges > over_cap="
                 f"{over_cap}; size over_cap with overflow_cap(graphs)"
             )
-        # padding targets the LAST node slot so over_nodes stays
-        # non-decreasing (the sorted-scatter promise; masked zero rows)
+        run_len = np.maximum(counts - tier, 0)  # [node_cap]; sums to k
+        longest = int(run_len.max(initial=0))
+        if run_cap is None:
+            run_cap = max(longest, 1)
+        if longest > run_cap:
+            raise TransposeRunError(
+                f"a node has {longest} transpose-overflow edges > run_cap="
+                f"{run_cap}; size run_cap with overflow_run_cap(graphs)"
+            )
+        # the real entries are a prefix, node-sorted, so a node's entries
+        # are one run. The padding after them names the LAST node slot
+        # (over_nodes stays non-decreasing) and slot 0: its rows are
+        # whatever slot 0 holds, and nothing points at them. They may
+        # CONTINUE a run of the last node, which the backward allows for by
+        # summing each run forwards only: the total at the run's last REAL
+        # entry, where over_last points, holds real entries alone.
         over_slots = np.zeros(over_cap, np.int32)
         over_nodes = np.full(over_cap, node_cap - 1, np.int32)
-        over_mask = np.zeros(over_cap, np.uint8)
         over_slots[:k] = real_sorted[sel2]
         over_nodes[:k] = nb[order][sel2]
-        over_mask[:k] = 1
-    return in_slots, in_mask, over_slots, over_nodes, over_mask
+        over_last = np.where(run_len > 0, np.cumsum(run_len) - 1,
+                             over_cap).astype(np.int32)
+        over_runs = np.bincount(run_len[run_len > 0] - 1,
+                                minlength=run_cap).astype(np.int32)
+    return in_slots, in_mask, over_slots, over_nodes, over_last, over_runs
 
 
 def shard_transpose_slots(
@@ -628,6 +684,7 @@ def shard_transpose_slots(
     dense_m: int,
     n_shards: int,
     over_cap: int,
+    run_cap: int | None = None,
 ) -> tuple:
     """Per-shard two-tier transpose mappings for node-strip graph sharding.
 
@@ -640,17 +697,18 @@ def shard_transpose_slots(
     partial [N, F] node gradient, and the shard_map machinery sums the
     partials (the transpose of the replicated-nodes cast).
 
-    Tier-1 width stays ``dense_m`` and the overflow capacity stays the
-    batch-global ``over_cap``: an edge's within-neighbor rank restricted to
-    one shard never exceeds its global rank, so every (tier, overflow)
+    Tier-1 width stays ``dense_m`` and the overflow capacities stay the
+    batch-global ``over_cap`` and ``run_cap`` (``None``: the longest run of
+    any shard): an edge's within-neighbor rank restricted to
+    one shard never exceeds its global rank, so every (tier, overflow, run)
     bound that held for the unsharded mapping holds per shard — sharding
     introduces NO new overflow failure mode, and the per-shard shapes are
     static functions of (node_cap, dense_m, n_shards) only.
 
     Returns stacked arrays with a leading shard axis, slot indices LOCAL to
     each shard's edge range: ``in_slots [D, node_cap*dense_m]``,
-    ``in_mask [D, node_cap, dense_m]``, ``over_slots/over_nodes/over_mask
-    [D, over_cap]``.
+    ``in_mask [D, node_cap, dense_m]``, ``over_slots/over_nodes
+    [D, over_cap]``, ``over_last [D, node_cap]``, ``over_runs [D, run_cap]``.
     """
     # the REAL precondition: shard boundaries must fall on whole node
     # rows. Checking only edge-capacity divisibility let configs with
@@ -677,11 +735,15 @@ def shard_transpose_slots(
         transpose_slots(
             neighbors[s * e_s : (s + 1) * e_s],
             edge_real[s * e_s : (s + 1) * e_s],
-            node_cap, dense_m, None, over_cap,
+            node_cap, dense_m, None, over_cap, run_cap,
         )
         for s in range(n_shards)
     ]
-    return tuple(np.stack([p[i] for p in parts]) for i in range(5))
+    if run_cap is None:  # one length for the stack: the longest shard's
+        run_cap = max(len(p[5]) for p in parts)
+        parts = [p[:5] + (np.pad(p[5], (0, run_cap - len(p[5]))),)
+                 for p in parts]
+    return tuple(np.stack(field) for field in zip(*parts))
 
 
 def pad_batch(
@@ -898,12 +960,13 @@ def bucketed_batch_iterator(
     # groups — two buckets of small graphs often share (node_cap, edge_cap)
     # after alignment). per_bucket_in_cap forces legacy single-tier slots
     # sized by each bucket's own worst in-degree.
-    over_cap = None
+    over_cap = run_cap = None
     if dense_m is not None and in_cap is None and not per_bucket_in_cap:
         # one uniform capacity sized by the WORST bucket: a large-graph
         # bucket's batches carry far more overflow than the dataset mean
         # (bimodal mixes), and per-bucket caps would split otherwise-equal
         # bucket shapes; the waste is a few KB of i32 per batch
+        run_cap = overflow_run_cap(graphs, dense_m)
         gcap = graph_cap_for(batch_size) if snug else batch_size
         over_cap = max(
             overflow_cap(
@@ -926,8 +989,8 @@ def bucketed_batch_iterator(
             b_in_cap = in_degree_cap(sub)
         it = batch_iterator(sub, batch_size, nc, ec, shuffle=shuffle, rng=rng,
                             dense_m=dense_m, in_cap=b_in_cap, snug=snug,
-                            over_cap=over_cap, edge_dtype=edge_dtype,
-                            pack_fn=pack_fn,
+                            over_cap=over_cap, run_cap=run_cap,
+                            edge_dtype=edge_dtype, pack_fn=pack_fn,
                             transpose_shards=transpose_shards)
         iters.append(stats.wrap(it) if stats is not None else it)
         weights.append(float(len(idxs)))
@@ -1028,6 +1091,7 @@ def _pack_overflow_safe(
     edge_dtype,
     pack_fn=None,
     transpose_shards: int = 1,
+    run_cap=None,
 ):
     """pack_graphs, splitting the batch on a two-tier over_cap overrun.
 
@@ -1043,6 +1107,8 @@ def _pack_overflow_safe(
     pack = pack_fn or pack_graphs
     kw = {"transpose_shards": transpose_shards} if transpose_shards > 1 \
         else {}
+    if over_cap is not None:
+        kw["run_cap"] = run_cap
     try:
         yield pack(bucket, node_cap, edge_cap, graph_cap,
                    dense_m=dense_m, in_cap=in_cap, over_cap=over_cap,
@@ -1061,7 +1127,7 @@ def _pack_overflow_safe(
             yield from _pack_overflow_safe(
                 half, node_cap, edge_cap, graph_cap, dense_m, in_cap,
                 over_cap, edge_dtype, pack_fn=pack_fn,
-                transpose_shards=transpose_shards)
+                transpose_shards=transpose_shards, run_cap=run_cap)
 
 
 def batch_iterator(
@@ -1079,6 +1145,7 @@ def batch_iterator(
     edge_dtype=np.float32,
     pack_fn=None,
     transpose_shards: int = 1,
+    run_cap: int | None = None,
 ):
     """Yield fixed-shape GraphBatches of ``batch_size`` graphs each.
 
@@ -1097,9 +1164,10 @@ def batch_iterator(
     step) — measured 0.69 -> >=0.97 on the MP-like distribution.
 
     Transpose slots (dense layout): ``in_cap=None`` (default) packs the
-    TWO-TIER transpose — tier-1 width ``dense_m`` + overflow COO sized by
-    ``overflow_cap`` — for the scatter-free backward with no in-degree
-    padding bytes; ``in_cap>0`` forces the legacy single-tier layout;
+    TWO-TIER transpose — tier-1 width ``dense_m`` + the overflow list
+    sized by ``overflow_cap`` and ``overflow_run_cap`` — for the
+    scatter-free backward with no in-degree padding bytes; ``in_cap>0``
+    forces the legacy single-tier layout;
     ``in_cap=0`` disables transpose packing (eval-only batches).
     """
     graph_cap = graph_cap_for(batch_size) if snug else batch_size
@@ -1107,6 +1175,8 @@ def batch_iterator(
         over_cap = overflow_cap(graphs, graph_cap, dense_m)
     if in_cap is not None:
         over_cap = None  # explicit single-tier (or in_cap=0: disabled)
+    if over_cap is not None and run_cap is None:
+        run_cap = overflow_run_cap(graphs, dense_m)
     in_cap = in_cap or None  # 0 disables (eval-only batches: no backward)
     order = np.arange(len(graphs))
     if shuffle:
@@ -1129,7 +1199,7 @@ def batch_iterator(
             for packed in _pack_overflow_safe(
                     bucket, node_cap, edge_cap, graph_cap, dense_m, in_cap,
                     over_cap, edge_dtype, pack_fn=pack_fn,
-                    transpose_shards=transpose_shards):
+                    transpose_shards=transpose_shards, run_cap=run_cap):
                 yield invariants.maybe_check(packed, dense_m)
             bucket, nn, ne = [], 0, 0
         bucket.append(g)
@@ -1144,5 +1214,5 @@ def batch_iterator(
         for packed in _pack_overflow_safe(
                 bucket, node_cap, edge_cap, graph_cap, dense_m, in_cap,
                 over_cap, edge_dtype, pack_fn=pack_fn,
-                transpose_shards=transpose_shards):
+                transpose_shards=transpose_shards, run_cap=run_cap):
             yield invariants.maybe_check(packed, dense_m)

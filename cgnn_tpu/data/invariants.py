@@ -56,7 +56,10 @@ def check_batch(batch, dense_m: int | None = None):
       exactly once under its neighbor node — the completeness property
       gather_transpose's scatter-free backward silently relies on — with
       each row's real entries first (ops/segment.gather_slot_major masks
-      tier 1 by rank < in-degree).
+      tier 1 by rank < in-degree), and the overflow list's run structure:
+      real entries a node-sorted prefix, ``over_last`` the end of each
+      owner's run and ``over_cap`` for every other node, no run longer
+      than ``over_runs`` (which counts them by length) allows.
     """
     if dense_m is None and np.ndim(batch.edges) == 3:
         dense_m = int(np.shape(batch.edges)[1])
@@ -144,25 +147,48 @@ def _check_transpose_mapping(batch, neighbors, real_e, ncap):
         parts = [lst + offset]
         rows = [np.repeat(np.arange(ncap), (in_mask > 0).sum(axis=1))]
         if over is not None:
-            osl, ond, omk = over
+            osl, ond, last, runs = over
             chex.assert_shape(ond, osl.shape)
-            chex.assert_shape(omk, osl.shape)
+            chex.assert_shape(last, (ncap,))
             if np.any(np.diff(ond) < 0):
-                _fail(f"{tag}over_nodes is not non-decreasing "
-                      f"(sorted-scatter promise broken)")
-            sel = omk > 0
-            if sel.any() and (osl[sel].min() < 0
-                              or osl[sel].max() >= slot_range):
+                _fail(f"{tag}over_nodes is not non-decreasing (a node's "
+                      f"overflow entries must be one run)")
+            # the run structure the backward's run sum and pointer gather
+            # rely on: the real entries are a prefix of k, node j's run is
+            # the runs[j] entries ending at last[j] and names j throughout,
+            # a node without a run points out of range (reads a zero row),
+            # and no run is longer than the capacity over_runs is sized to
+            owner = last < len(osl)
+            ends = last[owner]
+            if np.any(np.diff(ends) <= 0) or (ends.size and ends[0] < 0):
+                _fail(f"{tag}over_last is not increasing over the nodes "
+                      f"that own a run")
+            if np.any(last[~owner] != len(osl)):
+                _fail(f"{tag}a node without overflow must point at "
+                      f"over_cap (the out-of-range zero row)")
+            run_len = np.diff(ends, prepend=-1)
+            k = int(ends[-1]) + 1 if ends.size else 0
+            if not np.array_equal(ond[:k],
+                                  np.repeat(np.nonzero(owner)[0], run_len)):
+                _fail(f"{tag}over_last does not end each node's run of "
+                      f"over_nodes")
+            if run_len.size and run_len.max() > len(runs):
+                _fail(f"{tag}a run of {run_len.max()} overflow entries "
+                      f"exceeds the run capacity {len(runs)}")
+            if not np.array_equal(
+                    runs, np.bincount(run_len - 1, minlength=len(runs))):
+                _fail(f"{tag}over_runs does not count the runs by length")
+            if k and (osl[:k].min() < 0 or osl[:k].max() >= slot_range):
                 _fail(f"{tag}overflow lists a slot outside its range")
-            parts.append(osl[sel] + offset)
-            rows.append(ond[sel])
+            parts.append(osl[:k] + offset)
+            rows.append(ond[:k])
         return parts, rows
 
     in_mask = np.asarray(batch.in_mask)
     over_all = (
         None if batch.over_slots is None
         else (np.asarray(batch.over_slots), np.asarray(batch.over_nodes),
-              np.asarray(batch.over_mask))
+              np.asarray(batch.over_last), np.asarray(batch.over_runs))
     )
     if in_mask.ndim == 3:
         n_sh = in_mask.shape[0]

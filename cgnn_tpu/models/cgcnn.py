@@ -22,8 +22,12 @@ TPU-first design choices:
   order the compiler lays [N, M, .] tensors out in; the gathered [N, M, 2F]
   block is a term of z, and the gather's transpose is a second gather, of
   dz's rows, through a mapping the packer precomputes (_SplitFcFull;
-  ops/segment.py gather_slot_major). The only per-edge matmul is the edge
-  term e @ K_e. This is the body every benchmark cell runs;
+  ops/segment.py gather_slot_major): M rows a node summed over M, and for
+  the ~7% of edges beyond a node's first M incoming ones a sorted list
+  whose runs are summed a block at a time on the MXU and read back by one
+  row gather a node. No direction of the conv holds a scatter. The only
+  per-edge matmul is the edge term e @ K_e. This is the body every
+  benchmark cell runs;
 - the same body over node strips when the graph is sharded over a mesh
   axis (``edge_axis_name``), one psum a conv (every shard projects all the
   nodes and gathers its strip's rows through the flat gather_transpose);
@@ -147,7 +151,8 @@ class CGConv(nn.Module):
         in_mask: jax.Array | None = None,  # [N, In]
         over_slots: jax.Array | None = None,  # [O] two-tier overflow
         over_nodes: jax.Array | None = None,  # [O]
-        over_mask: jax.Array | None = None,  # [O]
+        over_last: jax.Array | None = None,  # [N] end of each node's run
+        over_runs: jax.Array | None = None,  # [K] (its length: run cap)
     ) -> jax.Array:
         f = self.features
         if self.dense_m is not None and self.edge_axis_name is not None:
@@ -190,7 +195,7 @@ class CGConv(nn.Module):
                     f"'graph' axis size)"
                 )
             over = [None if a is None else a[0]
-                    for a in (over_slots, over_nodes, over_mask)]
+                    for a in (over_slots, over_nodes, over_last, over_runs)]
 
             def gather_rows(p):  # [N, 2F], projected -> [N/D, M, 2F]
                 with jax.named_scope(phases.CONV_GATHER):
@@ -245,7 +250,7 @@ class CGConv(nn.Module):
                     return gather_slot_major(
                         p, neighbors, m, in_slots, in_mask,
                         over_slots=over_slots, over_nodes=over_nodes,
-                        over_mask=over_mask,
+                        over_last=over_last, over_runs=over_runs,
                     )
 
             with jax.named_scope(phases.CONV_FC_FULL):
@@ -378,7 +383,8 @@ class CrystalGraphConvNet(nn.Module):
                 in_mask=batch.in_mask,
                 over_slots=batch.over_slots,
                 over_nodes=batch.over_nodes,
-                over_mask=batch.over_mask,
+                over_last=batch.over_last,
+                over_runs=batch.over_runs,
             )
         # per-crystal masked mean pooling (reference `pooling`)
         with jax.named_scope(phases.POOL_HEAD):

@@ -49,7 +49,9 @@ def edge_distances(
     pass: a sum over M), the lattice is looked up once an atom ([N, 3, 3],
     never [E, 3, 3]), and the neighbours' positions go through the conv's
     own transposable gather, whose reverse pass is a row gather by
-    ``in_slots`` plus the overflow tier's small segment-sum. The flat
+    ``in_slots`` plus the overflow tier (its rows gathered, each node's run
+    of them summed by a 0/1 matmul, the totals gathered through
+    ``over_last``: no scatter; ops/segment.py _run_totals). The flat
     form's three [E]-indexed gathers each reverse into an [E]-row scatter
     at 12-byte rows; those and the s32[E] gather ``node_graph[centers]``
     were a fifth of the force step on the chip (PERF.md section 6, PR 28).
@@ -70,7 +72,8 @@ def edge_distances(
         nbr_pos = gather_slot_major(
             positions, batch.neighbors, dense_m, batch.in_slots,
             batch.in_mask, over_slots=batch.over_slots,
-            over_nodes=batch.over_nodes, over_mask=batch.over_mask,
+            over_nodes=batch.over_nodes, over_last=batch.over_last,
+            over_runs=batch.over_runs,
         )
         rel = nbr_pos + shift - positions[:, None, :]
     # epsilon under the sqrt keeps the gradient finite on masked padding
@@ -168,7 +171,8 @@ class ForceFieldCGCNN(nn.Module):
                     in_mask=batch.in_mask,
                     over_slots=batch.over_slots,
                     over_nodes=batch.over_nodes,
-                    over_mask=batch.over_mask,
+                    over_last=batch.over_last,
+                    over_runs=batch.over_runs,
                 )
             with jax.named_scope(phases.FORCE_READOUT):
                 atom_energy = ForceHead(

@@ -19,8 +19,82 @@ import jax
 import jax.numpy as jnp
 
 
-def _transpose_cotangent(ct, slots, msk, o_slots, o_nodes, o_mask,
-                         num_nodes: int, degree_axis: int = 1):
+# rows of the overflow list summed by one matmul: the MXU's tile on the v5e
+_RUN_BLOCK = 128
+
+
+def _run_windows(o_slots, o_nodes, run_cap: int):
+    """The overflow list cut into the blocks its runs are summed in:
+    ``(window slots [B, halo + 128] i32, same_run [B, 128, halo + 128]
+    bool)`` from the list's slots and (sorted) nodes, both [O].
+
+    A node's entries are one run of at most ``run_cap`` (a Python int:
+    ``over_runs``' length). Block b holds entries [128 b, 128 (b + 1)) and
+    is gathered with the ``run_cap - 1`` (8-aligned) entries before it, a
+    halo: a run may straddle a block's start, and never needs more.
+    ``same_run[b, i, j]`` says that window entry j belongs to block entry
+    i's node and lies at or before it, so ``same_run[b] @ rows[b]`` leaves
+    at every run's LAST entry the run's total. There is always a block
+    past the list's end (B = O // 128 + 1): entries beyond the end sum
+    nothing, their totals are zero, and that is where the pointer of a node
+    that owns no run points (entry O).
+
+    Index arithmetic on the mapping alone, the same for every conv of a
+    step: the gathers compute it beside the forward pass, not in the
+    transpose, so a step computes it once.
+    """
+    block = _RUN_BLOCK
+    halo = -(-(run_cap - 1) // 8) * 8  # whole sublanes
+    if halo > block:
+        raise ValueError(f"run_cap {run_cap} exceeds what a block of "
+                         f"{block} overflow entries can look back over")
+    n_blocks = o_slots.shape[0] // block + 1
+
+    def windows(x, fill):  # [O] -> [n_blocks, halo + block]
+        # window b = entries [b*block - halo, (b+1)*block); ``fill`` before
+        # the list's start and after its end
+        x = jnp.pad(x, (halo, n_blocks * block - x.shape[0]),
+                    constant_values=fill)
+        return jnp.concatenate(
+            [x[:n_blocks * block].reshape(n_blocks, block)[:, :halo],
+             x[halo:].reshape(n_blocks, block)], axis=1)
+
+    ids = windows(o_nodes, -1)
+    mine = ids[:, halo:, None]  # the block's own entries
+    upto = jnp.arange(halo + block)[None, :] <= (
+        halo + jnp.arange(block))[:, None]
+    same_run = (mine == ids[:, None, :]) & upto & (mine >= 0)
+    return windows(o_slots, 0), same_run
+
+
+def _run_totals(ct, o_win, o_same):
+    """``ct``'s rows at the overflow list's slots, every node's run of them
+    summed in place: [E, F] -> [B * 128, F], row e the sum of entry e's
+    run up to and including e (``_run_windows``), rows past the list zero.
+
+    One [128, halo + 128] 0/1 matrix times its gathered rows a block, on
+    the MXU, which idles at 97% in this model: products with 0 and 1 are
+    exact and the sums are float32 (precision ``highest``: a float32
+    cotangent is not rounded to bfloat16 on the way in), rounded once to
+    ``ct``'s dtype. Each total is the sum of its own run's rows and of
+    nothing else: no scatter, and no prefix sum and difference either. On
+    the v5e at ``mp.train``'s 25,512 entries the sums take 38 us (20 at
+    ``force.train``'s 9,712 in float32) and the tier with its two row
+    gathers 130 (45), where four shifted masked adds (a segmented scan)
+    made it 248 (59) and the sorted scatter-add behind ``segment_sum``
+    308 (100) (PERF.md section 6, PR 33).
+    """
+    rows = gather(ct, o_win.reshape(-1)).reshape(*o_win.shape, ct.shape[-1])
+    totals = jnp.einsum(
+        "bij,bjf->bif", o_same.astype(ct.dtype), rows,
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+    return totals.astype(ct.dtype).reshape(-1, ct.shape[-1])
+
+
+def _transpose_cotangent(ct, slots, msk, o_win, o_same, o_last,
+                         degree_axis: int = 1):
     """The shared cotangent transpose ([E, F] -> [N, F]) — ONE body for
     both row orders.
 
@@ -37,11 +111,16 @@ def _transpose_cotangent(ct, slots, msk, o_slots, o_nodes, o_mask,
     """
     contrib = gather(ct, slots).reshape(*msk.shape, ct.shape[-1])
     grad = (contrib * msk[..., None].astype(ct.dtype)).sum(axis=degree_axis)
-    if o_slots is not None:
-        rows = gather(ct, o_slots) * o_mask[:, None].astype(ct.dtype)
-        grad = grad + jax.ops.segment_sum(
-            rows, o_nodes, num_segments=num_nodes, indices_are_sorted=True,
-        )
+    if o_win is not None:
+        # the overflow tier (~7% of ct's rows, a run of 1..run_cap of them
+        # for ~40% of the nodes): gather the rows, sum each node's run in
+        # place, and gather every node's total through its pointer. A node
+        # that owns no run points at entry O, a row of zeros; the list's
+        # own padding rows (whatever slot 0 holds) come after every real
+        # entry, and a run is summed forwards only, so no pointer's total
+        # holds one. Row gathers cost ~1.3-1.8 ns a row on the v5e where
+        # the sorted scatter-add behind ``segment_sum`` cost ~10.
+        grad = grad + gather(_run_totals(ct, o_win, o_same), o_last)
     return grad
 
 
@@ -79,9 +158,10 @@ def gather_transpose(
     in_mask: jax.Array,  # [N, In] — 1 where the slot entry is a real edge
     over_slots: jax.Array | None = None,  # [O] i32 overflow edge slots
     over_nodes: jax.Array | None = None,  # [O] i32 (non-decreasing)
-    over_mask: jax.Array | None = None,  # [O]
+    over_last: jax.Array | None = None,  # [N] i32 end of the node's run
+    over_runs: jax.Array | None = None,  # [K]: K is the longest run allowed
 ) -> jax.Array:
-    """``nodes[neighbors]`` with a SCATTER-FREE (or scatter-light) backward.
+    """``nodes[neighbors]`` with a SCATTER-FREE backward.
 
     The forward is the plain neighbor gather. Its autodiff backward is a
     scatter-add of the [E, F] cotangent into [N, F] — the same XLA scatter
@@ -95,9 +175,14 @@ def gather_transpose(
     TWO-TIER mode (``over_*`` given; pack_graphs ``over_cap``): tier 1 is
     [N, M] (no in-degree padding — the [N, 2M] single-tier gather was the
     step's largest single op at mean in-degree M, half padding bytes), and
-    the ~7% of edges with rank >= M arrive via a node-sorted segment-sum
-    over the small overflow list — a scatter 15x smaller than the one this
-    path replaces.
+    the ~7% of edges with rank >= M arrive through the node-sorted overflow
+    list: a row gather of the list's rows of the cotangent, a sum over
+    each node's run of at most K adjacent rows (``_run_windows``,
+    ``_run_totals``: a 0/1 matrix times the rows, a block of 128 entries
+    at a time; K is ``over_runs``' length, a shape) and a row gather of
+    every node's total through ``over_last``. The ``segment_sum`` that
+    used to close this tier was the conv's last XLA scatter: ~10 ns a row
+    where a gathered row costs ~1.3-2 (PERF.md §5).
 
     Equivalence to the plain gather's VJP requires the cotangent to be
     zero on edge slots missing from the mapping (padding slots). CGConv
@@ -109,12 +194,13 @@ def gather_transpose(
     The gather is linear in ``nodes`` and is declared so (``_linear``),
     which is what lets the force task differentiate it twice.
     """
-    num_nodes = nodes.shape[0]
+    o_win, o_same = (None, None) if over_slots is None else _run_windows(
+        over_slots, over_nodes, over_runs.shape[-1])
 
     def trans(res, ct):  # ct: [E, F] -> [N, F]
-        return _transpose_cotangent(ct, *res[1:], num_nodes)
+        return _transpose_cotangent(ct, *res[1:])
 
-    res = (neighbors, in_slots, in_mask, over_slots, over_nodes, over_mask)
+    res = (neighbors, in_slots, in_mask, o_win, o_same, over_last)
     return _linear(_gather_rows, trans, res, nodes)
 
 
@@ -126,7 +212,8 @@ def gather_slot_major(
     in_mask: jax.Array | None = None,
     over_slots: jax.Array | None = None,
     over_nodes: jax.Array | None = None,
-    over_mask: jax.Array | None = None,
+    over_last: jax.Array | None = None,
+    over_runs: jax.Array | None = None,
 ) -> jax.Array:
     """``nodes[neighbors]`` as [N, M, F], gathered in SLOT-MAJOR row order.
 
@@ -171,7 +258,8 @@ def gather_slot_major(
         return view(gather(nodes, nbrs_t))
     # tier-1 entries reordered [N, In] -> [In, N] as well as renumbered
     slots_t = to_slot_major(in_slots.reshape(in_mask.shape).T.reshape(-1))
-    o_slots_t = None if over_slots is None else to_slot_major(over_slots)
+    o_win, o_same = (None, None) if over_slots is None else _run_windows(
+        to_slot_major(over_slots), over_nodes, over_runs.shape[-1])
     # the [In, N] mask is ``rank < in-degree``: a row's real entries are a
     # prefix (graph.transpose_slots; data/invariants.py checks it). Not
     # ``in_mask.T``: for that XLA relayouts the whole STACKED u8 mask of a
@@ -181,9 +269,9 @@ def gather_slot_major(
     mask_t = jnp.arange(in_mask.shape[1])[:, None] < in_degree[None, :]
 
     def trans(res, ct):  # ct: [M*N, F] slot-major -> [N, F]
-        return _transpose_cotangent(ct, *res[1:], n, degree_axis=0)
+        return _transpose_cotangent(ct, *res[1:], degree_axis=0)
 
-    res = (nbrs_t, slots_t, mask_t, o_slots_t, over_nodes, over_mask)
+    res = (nbrs_t, slots_t, mask_t, o_win, o_same, over_last)
     return view(_linear(_gather_rows, trans, res, nodes))
 
 

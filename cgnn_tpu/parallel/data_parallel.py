@@ -61,8 +61,7 @@ def empty_batch_like(batch: GraphBatch) -> GraphBatch:
             # padding slots point at their own node, as pack_compact's do
             neighbors=_base_neighbors(
                 ncap, batch.distances.shape[-1]).copy(),
-            over_nodes=(None if batch.over_nodes is None
-                        else np.full_like(batch.over_nodes, ncap - 1)),
+            **_empty_overflow(batch),
         )
     # dense layout: centers/neighbors are STRUCTURAL (slot k belongs to
     # node k//M; padding = masked self-loops), so the empty batch keeps the
@@ -90,11 +89,25 @@ def empty_batch_like(batch: GraphBatch) -> GraphBatch:
         in_mask=None if batch.in_mask is None else np.zeros_like(batch.in_mask),
         over_slots=(None if batch.over_slots is None
                     else np.zeros_like(batch.over_slots)),
-        over_nodes=(None if batch.over_nodes is None
-                    else np.full_like(batch.over_nodes, ncap - 1)),
-        over_mask=(None if batch.over_mask is None
-                   else np.zeros_like(batch.over_mask)),
+        over_runs=(None if batch.over_runs is None
+                   else np.zeros_like(batch.over_runs)),
+        **_empty_overflow(batch),
     )
+
+
+def _empty_overflow(batch) -> dict:
+    """The overflow list of an all-padding batch, as ``transpose_slots``
+    packs one with no real entry: every entry names the last node slot (so
+    ``over_nodes`` stays non-decreasing) and no node owns a run (every
+    pointer out of range, where the backward reads a zero row)."""
+    if batch.over_nodes is None:
+        return {}
+    return {
+        "over_nodes": np.full_like(batch.over_nodes,
+                                   batch.node_capacity - 1),
+        "over_last": np.full_like(batch.over_last,
+                                  np.shape(batch.over_slots)[-1]),
+    }
 
 
 def parallel_batches(

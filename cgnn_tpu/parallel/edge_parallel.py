@@ -33,7 +33,11 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from cgnn_tpu.data.graph import GraphBatch
+from cgnn_tpu.data.graph import (
+    TRANSPOSE_FIELDS,
+    GraphBatch,
+    shard_transpose_slots,
+)
 from cgnn_tpu.train.state import TrainState
 from cgnn_tpu.train.step import (
     jit_sharded_train_step,
@@ -46,9 +50,7 @@ EDGE_FIELDS = ("edges", "centers", "neighbors", "edge_mask", "edge_offsets")
 # transpose-slot fields exist only in the dense layout, which edge sharding
 # rejects; specs carry None so the pytrees match COO batches (where they
 # are None)
-_DENSE_ONLY_FIELDS = (
-    "in_slots", "in_mask", "over_slots", "over_nodes", "over_mask",
-)
+_DENSE_ONLY_FIELDS = TRANSPOSE_FIELDS
 _ALL_FIELDS = tuple(f.name for f in dataclasses.fields(GraphBatch))
 
 
@@ -146,9 +148,7 @@ def prepare_dense_sharded(
         )
     if not train or batch.in_slots is None:
         return dataclasses.replace(
-            batch, in_slots=None, in_mask=None, over_slots=None,
-            over_nodes=None, over_mask=None,
-        )
+            batch, **dict.fromkeys(TRANSPOSE_FIELDS))
     if np.ndim(batch.in_mask) == 3:
         # already per-shard (pack_graphs transpose_shards) — but ONLY for
         # the same shard count: a 4-shard mapping split over a 2-way mesh
@@ -170,19 +170,12 @@ def prepare_dense_sharded(
             "with in_cap=None (the default) instead of a single-tier "
             "in_cap"
         )
-    from cgnn_tpu.data.graph import shard_transpose_slots
-
     m = batch.edges.shape[1]
-    in_slots, in_mask, over_slots, over_nodes, over_mask = (
-        shard_transpose_slots(
-            np.asarray(batch.neighbors), np.asarray(batch.edge_mask) > 0,
-            ncap, m, n_shards, len(batch.over_slots),
-        )
+    mapping = shard_transpose_slots(
+        np.asarray(batch.neighbors), np.asarray(batch.edge_mask) > 0,
+        ncap, m, n_shards, len(batch.over_slots), len(batch.over_runs),
     )
-    return dataclasses.replace(
-        batch, in_slots=in_slots, in_mask=in_mask, over_slots=over_slots,
-        over_nodes=over_nodes, over_mask=over_mask,
-    )
+    return dataclasses.replace(batch, **dict(zip(TRANSPOSE_FIELDS, mapping)))
 
 
 def _auto_specs(

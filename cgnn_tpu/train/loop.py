@@ -27,6 +27,7 @@ from cgnn_tpu.data.graph import (
     batch_shape_key,
     bucketed_batch_iterator,
     capacities_for,  # re-exported; moved to data/graph.py
+    overflow_rows,
     round_to_bucket,
 )
 import jax.numpy as jnp
@@ -391,13 +392,32 @@ def staged_edge_fea_nbytes(batches) -> int:
                if getattr(b, "edges", None) is not None)
 
 
+def transpose_overflow_stats(batches) -> dict:
+    """How far the staged batches engage the overflow tier of the gather's
+    transpose (ops/segment.py): ``transpose_overflow_rows`` real entries
+    (rows of a conv's ``dz`` that reach their node through the run sum and
+    the pointer gather; ~7% of the real edges) in ``transpose_overflow_cap``
+    entries of capacity, and ``transpose_overflow_max_run``, the run
+    capacity the programs are compiled for. Summed over every batch (and
+    chip, and shard); zeros for batches that carry no mapping."""
+    over = [b for b in batches if getattr(b, "over_runs", None) is not None]
+    return {
+        "transpose_overflow_rows": sum(overflow_rows(b) for b in over),
+        "transpose_overflow_cap": sum(int(np.size(b.over_slots))
+                                      for b in over),
+        "transpose_overflow_max_run": max(
+            (int(np.shape(b.over_runs)[-1]) for b in over), default=0),
+    }
+
+
 def _staging_args(batches: list) -> dict:
     """Args of the ``scan.stage`` span. ``bytes`` is what the host hands
     over: under a mesh the batches carry the device axis, so it is the
     GLOBAL total over all chips (a chip holds its share of axis 1)."""
     return {"groups": len({batch_shape_key(b) for b in batches}),
             "batches": len(batches), "bytes": int(staged_nbytes(batches)),
-            "edge_fea_bytes": staged_edge_fea_nbytes(batches)}
+            "edge_fea_bytes": staged_edge_fea_nbytes(batches),
+            **transpose_overflow_stats(batches)}
 
 
 @contextlib.contextmanager
@@ -510,6 +530,12 @@ class ScanEpochDriver:
                 telemetry.counter_add("staged_bytes", args["bytes"])
                 telemetry.counter_add("staged_edge_fea_bytes",
                                       args["edge_fea_bytes"])
+                for name in ("transpose_overflow_rows",
+                             "transpose_overflow_cap"):
+                    telemetry.counter_add(name, args[name])
+                # a level, not a sum: a second driver must not double it
+                telemetry.set_gauge("transpose_overflow_max_run",
+                                    args["transpose_overflow_max_run"])
         self._train_body, self._eval_body = train_body, eval_body
         self._train_scans: dict = {}
         self._eval_scans: dict = {}

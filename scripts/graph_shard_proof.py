@@ -93,7 +93,7 @@ def main(argv=None) -> int:
     import numpy as np
 
     from cgnn_tpu.data.dataset import FeaturizeConfig, load_synthetic_mp
-    from cgnn_tpu.data.graph import capacities_for
+    from cgnn_tpu.data.graph import TRANSPOSE_FIELDS, capacities_for
     from cgnn_tpu.models import CrystalGraphConvNet
     from cgnn_tpu.parallel.data_parallel import (
         make_parallel_train_step,
@@ -169,8 +169,7 @@ def main(argv=None) -> int:
         # only trace inside shard_map)
         example = dataclasses.replace(
             jax.tree_util.tree_map(lambda x: x[0], bs[0]),
-            in_slots=None, in_mask=None, over_slots=None, over_nodes=None,
-            over_mask=None)
+            **dict.fromkeys(TRANSPOSE_FIELDS))
         state = replicate_state(
             fresh_state(model, example).replace(apply_fn=apply_model.apply),
             mesh)

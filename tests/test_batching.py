@@ -102,8 +102,11 @@ def test_dense_model_matches_flat_model():
 
 def test_transpose_slots_invariants():
     """The (two-tier) transpose is exact: every real edge slot appears
-    exactly once across tier-1 in_slots + the overflow COO, each in the
-    row/entry of the node it references; padding entries are masked."""
+    exactly once across tier-1 in_slots + the overflow list's real
+    prefix, each in the row/entry of the node it references; every node's
+    overflow entries are one run, ended where over_last points."""
+    from cgnn_tpu.data.graph import overflow_rows
+
     graphs = _mixed_graphs()
     m = CFG.max_num_nbr
     nc, ec = capacities_for(graphs, 8, dense_m=m)
@@ -115,15 +118,30 @@ def test_transpose_slots_invariants():
         listed = np.asarray(b.in_slots).reshape(nc, m)[
             np.asarray(b.in_mask) > 0]
         rows, _ = np.nonzero(np.asarray(b.in_mask) > 0)
-        over = np.asarray(b.over_mask) > 0
-        listed = np.concatenate([listed, np.asarray(b.over_slots)[over]])
-        rows = np.concatenate([rows, np.asarray(b.over_nodes)[over]])
+        k = overflow_rows(b)
+        listed = np.concatenate([listed, np.asarray(b.over_slots)[:k]])
+        rows = np.concatenate([rows, np.asarray(b.over_nodes)[:k]])
         assert sorted(listed.tolist()) == sorted(real.tolist())
         np.testing.assert_array_equal(
             np.asarray(b.neighbors)[listed], rows
         )
-        # overflow list is node-sorted (the scatter's unchecked promise)
-        assert np.all(np.diff(np.asarray(b.over_nodes)) >= 0)
+        # overflow list is node-sorted: a node's entries are one run
+        over_nodes = np.asarray(b.over_nodes)
+        assert np.all(np.diff(over_nodes) >= 0)
+        # over_last: the run's last entry for its owner, out of range
+        # (the zero row) for every other node; runs within the capacity
+        last = np.asarray(b.over_last)
+        extra = np.maximum(
+            np.bincount(np.asarray(b.neighbors)[real], minlength=nc) - m, 0)
+        assert extra.sum() == k and extra.max() <= len(b.over_runs)
+        np.testing.assert_array_equal(
+            last, np.where(extra > 0, np.cumsum(extra) - 1,
+                           len(b.over_slots)))
+        owners = np.nonzero(extra)[0]
+        np.testing.assert_array_equal(over_nodes[last[owners]], owners)
+        np.testing.assert_array_equal(
+            np.asarray(b.over_runs),
+            np.bincount(extra[owners] - 1, minlength=len(b.over_runs)))
 
 
 def test_transpose_backward_matches_plain_gather():
@@ -174,7 +192,11 @@ def test_over_cap_overrun_splits_batch_instead_of_dying():
 
     import pytest
 
-    from cgnn_tpu.data.graph import CrystalGraph, TransposeOverflowError
+    from cgnn_tpu.data.graph import (
+        CrystalGraph,
+        TransposeOverflowError,
+        overflow_rows,
+    )
 
     def star_graph(n, cid):
         # every node sends 2 edges to node 0 -> in-degree(0) = 2n, far
@@ -201,7 +223,7 @@ def test_over_cap_overrun_splits_batch_instead_of_dying():
     assert any("splitting it in half" in str(w.message) for w in caught)
     for b in batches:
         assert np.shape(b.nodes) == (16, 4)
-        assert int((np.asarray(b.over_mask) > 0).sum()) == 8
+        assert overflow_rows(b) == 8
     # an unsplittable single graph re-raises the typed error
     with pytest.raises(TransposeOverflowError):
         list(batch_iterator(
@@ -360,60 +382,181 @@ def test_per_bucket_in_cap_tracks_bucket_skew():
     assert min(caps) < global_cap  # ...and the other bucket does not
 
 
-@pytest.mark.parametrize("form", ["flat", "slot_major"])
-def test_two_tier_transpose_backward_matches_plain_gather(form):
-    """Two-tier (tier-1 [N, M] + overflow COO) gather_transpose gradients
-    == plain-gather gradients through a full CGConv-like masked consumer,
-    on graphs whose in-degree exceeds dense_m (overflow populated) and
-    whose batch is padded — in the flat node-major form and in the
-    slot-major form the unsharded dense conv takes (gather_slot_major)."""
+def _crafted_edges(in_degree: dict, n: int, m: int):
+    """A dense batch's flat ``neighbors`` [n*m] and its real-slot mask
+    with the given in-degrees: sources take turns, each filling its own
+    slots front to back (node 0's slot 0 is real, so the overflow list's
+    padding entries, which name slot 0, gather a row that is NOT zero)."""
+    neighbors = np.arange(n * m, dtype=np.int32) // m  # padding: own node
+    real = np.zeros(n * m, bool)
+    used = np.zeros(n, int)
+    src = 0
+    for dst, deg in in_degree.items():
+        for _ in range(deg):
+            while used[src] == m:
+                src = (src + 1) % n
+            neighbors[src * m + used[src]] = dst
+            real[src * m + used[src]] = True
+            used[src] += 1
+            src = (src + 1) % n
+    assert real[0]
+    return neighbors, real
+
+
+# case -> (in-degrees, node_cap, over_cap, run_cap), at dense_m 2
+_CRAFTED_TIERS = {
+    # a node whose run is exactly the capacity, beside a run of one
+    "run_of_exactly_k": ({3: 7, 10: 3, 0: 2}, 16, 8, 5),
+    # no overflow at all: every entry is padding, every pointer the zero row
+    "no_overflow": ({1: 2, 2: 2, 7: 1, 15: 2}, 16, 8, 3),
+    # the LAST node slot owns a run and the padding entries that follow it
+    # name the same node: they must not reach its total
+    "last_node_then_padding": ({15: 5, 4: 4}, 16, 16, 8),
+    # two full-length runs back to back: neither takes the other's rows
+    "runs_back_to_back": ({5: 8, 6: 8, 0: 1}, 16, 16, 6),
+    # the run sum works on blocks of 128 entries with a halo of run_cap - 1
+    # (8-aligned) before each: node 20's run of 9 = run_cap is entries
+    # 120..128, so its total, the first row of block 1, needs the whole
+    # halo; node 21's run follows inside block 1
+    "run_straddles_block": ({**{i: 8 for i in range(20)}, 20: 11, 21: 8},
+                            96, 144, 9),
+    # a list that ends exactly on a block boundary: the zero row that the
+    # nodes without a run point at is a block of its own
+    "list_fills_its_block": ({i: 10 for i in range(16)}, 96, 128, 8),
+}
+_TIER_CASES = [(form, case)
+               for case in ("mp", *_CRAFTED_TIERS, "sharded")
+               for form in ("flat", "slot_major")
+               if (form, case) != ("slot_major", "sharded")]
+
+
+@pytest.mark.parametrize("form,case", _TIER_CASES)
+def test_two_tier_transpose_backward_matches_plain_gather(form, case):
+    """Two-tier (tier-1 [N, M] + the overflow list's run sums, gathered
+    through over_last) gather_transpose gradients == plain-gather gradients
+    through a full CGConv-like masked consumer — in the flat node-major
+    form and in the slot-major form the unsharded dense conv takes
+    (gather_slot_major). ``mp``: packed crystals whose in-degree exceeds
+    dense_m, in a padded batch; the crafted cases: the edges of the run sum
+    (_CRAFTED_TIERS); ``sharded``: the per-shard mappings of
+    shard_transpose_slots, a strip of the gather each."""
     import jax
     import jax.numpy as jnp
 
     from cgnn_tpu.data.dataset import load_synthetic_mp
-    from cgnn_tpu.data.graph import batch_iterator, capacities_for
+    from cgnn_tpu.data.graph import (
+        batch_iterator,
+        capacities_for,
+        overflow_rows,
+        shard_transpose_slots,
+        transpose_slots,
+    )
     from cgnn_tpu.ops.segment import (
         gather,
         gather_slot_major,
         gather_transpose,
     )
 
-    cfg = FeaturizeConfig(radius=6.0, max_num_nbr=12)
-    graphs = load_synthetic_mp(64, cfg, seed=3)
-    nc, ec = capacities_for(graphs, 32, dense_m=12, snug=True)
-    b = next(batch_iterator(graphs, 32, nc, ec, dense_m=12, snug=True))
-    assert b.over_slots is not None
-    assert int(np.asarray(b.over_mask).sum()) > 0, "no overflow exercised"
-    assert int(np.asarray(b.edge_mask).sum()) < b.edge_capacity, "no padding"
+    shards = 1
+    if case in ("mp", "sharded"):
+        m = 12
+        cfg = FeaturizeConfig(radius=6.0, max_num_nbr=m)
+        graphs = load_synthetic_mp(64, cfg, seed=3)
+        nc, ec = capacities_for(graphs, 32, dense_m=m, snug=True,
+                                node_multiple=2)
+        shards = 2 if case == "sharded" else 1
+        b = next(batch_iterator(graphs, 32, nc, ec, dense_m=m, snug=True,
+                                transpose_shards=shards))
+        assert overflow_rows(b) > 0, "no overflow exercised"
+        assert int(np.asarray(b.edge_mask).sum()) < b.edge_capacity, \
+            "no padding"
+        neighbors, real = np.asarray(b.neighbors), np.asarray(b.edge_mask) > 0
+        mapping = (b.in_slots, b.in_mask, b.over_slots, b.over_nodes,
+                   b.over_last, b.over_runs)
+        if shards > 1:  # the direct pack and the rebuild agree
+            for got, want in zip(mapping, shard_transpose_slots(
+                    neighbors, real, nc, m, shards, len(b.over_slots[0]),
+                    b.over_runs.shape[-1])):
+                np.testing.assert_array_equal(got, want)
+    else:
+        m = 2
+        in_degree, nc, over_cap, run_cap = _CRAFTED_TIERS[case]
+        neighbors, real = _crafted_edges(in_degree, nc, m)
+        mapping = transpose_slots(neighbors, real, nc, m, None, over_cap,
+                                  run_cap)
+        extra = sum(max(d - m, 0) for d in in_degree.values())
+        assert int((mapping[5] * np.arange(1, run_cap + 1)).sum()) == extra
+        assert (extra == over_cap) == (case == "list_fills_its_block")
+    mapping = tuple(jnp.asarray(x) for x in mapping)
 
     nodes = jnp.asarray(
-        np.random.default_rng(0).normal(size=(b.node_capacity, 16))
+        np.random.default_rng(0).normal(size=(nc, 16))
     ).astype(jnp.float32)
-    emask = jnp.asarray(b.edge_mask).reshape(-1, 12, 1)
+    emask = jnp.asarray(real, jnp.float32).reshape(-1, m, 1)
     # a consumer that weighs every slot differently: an order mix-up
     # between the forms cannot cancel
     weight = jnp.asarray(np.random.default_rng(1).normal(
-        size=(b.node_capacity, 12, 16))).astype(jnp.float32)
-    mapping = tuple(jnp.asarray(x) for x in (
-        b.in_slots, b.in_mask, b.over_slots, b.over_nodes, b.over_mask))
+        size=(nc, m, 16))).astype(jnp.float32)
+    neighbors = jnp.asarray(neighbors)
 
     def loss_two_tier(n):
-        if form == "flat":
-            v_j = gather_transpose(n, jnp.asarray(b.neighbors), *mapping)
-            v_j = v_j.reshape(-1, 12, 16)
+        if shards > 1:  # each shard gathers its strip through its mapping
+            strips = neighbors.reshape(shards, -1)
+            v_j = jnp.concatenate([
+                gather_transpose(n, strips[s], *(x[s] for x in mapping))
+                for s in range(shards)])
+        elif form == "flat":
+            v_j = gather_transpose(n, neighbors, *mapping)
         else:
-            v_j = gather_slot_major(n, jnp.asarray(b.neighbors), 12, *mapping)
-        return ((v_j * emask * weight) ** 2).sum()
+            v_j = gather_slot_major(n, neighbors, m, *mapping)
+        return ((v_j.reshape(-1, m, 16) * emask * weight) ** 2).sum()
 
     def loss_plain(n):
-        v_j = gather(n, jnp.asarray(b.neighbors)).reshape(-1, 12, 16)
+        v_j = gather(n, neighbors).reshape(-1, m, 16)
         return ((v_j * emask * weight) ** 2).sum()
 
     np.testing.assert_array_equal(  # the same rows: bit-identical forward
         np.asarray(loss_two_tier(nodes)), np.asarray(loss_plain(nodes)))
     g1 = jax.grad(loss_two_tier)(nodes)
     g2 = jax.grad(loss_plain)(nodes)
+    assert float(jnp.abs(g2).max()) > 0.1
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), atol=1e-5)
+
+
+def test_run_longer_than_run_cap_raises():
+    """A node's overflow run beyond ``run_cap`` would lose gradient (the
+    backward's look-back is compiled for the capacity): the pack raises, by
+    type, from transpose_slots, pack_graphs and the compact packer alike,
+    and batch_iterator does not split its way around it."""
+    from cgnn_tpu.data.dataset import load_synthetic_mp
+    from cgnn_tpu.data.graph import (
+        TransposeRunError,
+        batch_iterator,
+        capacities_for,
+        overflow_run_cap,
+        pack_graphs,
+        transpose_slots,
+    )
+
+    in_degree, _, over_cap, run_cap = _CRAFTED_TIERS["run_of_exactly_k"]
+    neighbors, real = _crafted_edges(in_degree, 16, 2)
+    transpose_slots(neighbors, real, 16, 2, None, over_cap, run_cap)
+    with pytest.raises(TransposeRunError, match="run_cap=4"):
+        transpose_slots(neighbors, real, 16, 2, None, over_cap, run_cap - 1)
+
+    cfg = FeaturizeConfig(radius=6.0, max_num_nbr=12)
+    graphs = load_synthetic_mp(24, cfg, seed=3)
+    k = overflow_run_cap(graphs, 12)
+    nc, ec = capacities_for(graphs, 24, dense_m=12, snug=True)
+    b = pack_graphs(graphs, nc, ec, 32, dense_m=12, over_cap=4096, run_cap=k)
+    longest = int(np.nonzero(np.asarray(b.over_runs))[0].max()) + 1
+    assert 1 < longest <= k == len(b.over_runs)
+    with pytest.raises(TransposeRunError):
+        pack_graphs(graphs, nc, ec, 32, dense_m=12, over_cap=4096,
+                    run_cap=longest - 1)
+    with pytest.raises(TransposeRunError):
+        list(batch_iterator(graphs, 24, nc, ec, dense_m=12, snug=True,
+                            run_cap=longest - 1))
 
 
 def _packed_by(packer):

@@ -22,6 +22,7 @@ from cgnn_tpu.data.graph import (
     bucketed_batch_iterator,
     capacities_for,
     overflow_cap,
+    overflow_rows,
     pack_graphs,
 )
 
@@ -67,7 +68,9 @@ def test_expand_reproduces_pack_graphs(graphs, spec):
     np.testing.assert_array_equal(np.asarray(got.in_mask), full.in_mask)
     np.testing.assert_array_equal(np.asarray(got.over_slots), full.over_slots)
     np.testing.assert_array_equal(np.asarray(got.over_nodes), full.over_nodes)
-    np.testing.assert_array_equal(np.asarray(got.over_mask), full.over_mask)
+    np.testing.assert_array_equal(np.asarray(got.over_last), full.over_last)
+    np.testing.assert_array_equal(np.asarray(got.over_runs), full.over_runs)
+    assert overflow_rows(full) > 0
     np.testing.assert_allclose(np.asarray(got.edges), full.edges, atol=2e-6)
     # geometry comes back None (energy models never read it)
     assert got.positions is None and got.lattices is None

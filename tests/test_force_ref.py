@@ -63,7 +63,9 @@ def test_the_batches_have_padding_and_an_overflow_tier(f32):
     assert np.asarray(batch.edges).ndim == 3
     assert 0 < np.asarray(batch.node_mask).sum() < batch.node_capacity
     assert 0 < np.asarray(batch.graph_mask).sum() < batch.graph_capacity
-    assert np.asarray(batch.over_mask).sum() > 0
+    from cgnn_tpu.data.graph import overflow_rows
+
+    assert overflow_rows(batch) > 0
 
 
 def test_energies_and_forces_agree_in_float32(f32):

@@ -136,7 +136,7 @@ def test_dense_force_layout_matches_coo(case):
     import jax.numpy as jnp
 
     from cgnn_tpu.data.dataset import _trajectory_graphs, load_trajectory
-    from cgnn_tpu.data.graph import batch_iterator
+    from cgnn_tpu.data.graph import batch_iterator, overflow_rows
     from cgnn_tpu.models.forcefield import ForceFieldCGCNN, energy_and_forces
     from cgnn_tpu.train import Normalizer, create_train_state, make_optimizer
     from cgnn_tpu.train.force_step import make_force_train_step
@@ -169,7 +169,7 @@ def test_dense_force_layout_matches_coo(case):
         in_degree = np.bincount(np.asarray(dense.neighbors)[real])
         assert in_degree.max() > 12
         if case == "crystal":  # those rows ride the overflow tier
-            assert int(np.asarray(dense.over_mask).sum()) == int(
+            assert overflow_rows(dense) == int(
                 np.maximum(in_degree - 12, 0).sum()) > 0
 
     m_coo = ForceFieldCGCNN(atom_fea_len=32, n_conv=2, h_fea_len=32, dmax=6.0)
@@ -325,13 +325,19 @@ def test_float32_force_model_asks_for_float32_matmuls(dtype):
     bfloat16 (PERF.md section 2, PR 27: the forces then read like the
     bfloat16 trunk's). In float32 every dot of the force train step, the
     image shifts' and both reverse passes' included, carries precision
-    ``highest``; the bfloat16 trunk asks for nothing."""
+    ``highest``; the bfloat16 trunk asks for nothing. The one matmul that
+    is no layer's — the gather's transpose summing the overflow list's runs
+    with a 0/1 matrix a block (ops/segment.py _run_totals; told by its
+    [blocks, 128, halo + 128] left operand) — asks for ``highest`` whatever the trunk: its
+    sums stand in for a scatter-add's, and the position gather's cotangent
+    is float32 in both trunks."""
     import jax
     import jax.numpy as jnp
 
     from cgnn_tpu.config import DataConfig, ModelConfig, build_model
     from cgnn_tpu.data.dataset import load_synthetic_md17
     from cgnn_tpu.data.graph import batch_iterator, capacities_for
+    from cgnn_tpu.ops.segment import _RUN_BLOCK
     from cgnn_tpu.train import Normalizer, create_train_state, make_optimizer
     from cgnn_tpu.train.force_step import make_force_train_step
 
@@ -350,9 +356,16 @@ def test_float32_force_model_asks_for_float32_matmuls(dtype):
     # and their transposes under one and two reverse passes
     assert len(dots) > 12
     highest = (jax.lax.Precision.HIGHEST,) * 2
+    run_sums = 0
     for eqn in dots:
         got = eqn.params["precision"]
-        if dtype == "float32":
+        lhs = eqn.invars[0].aval.shape
+        if len(lhs) == 3 and lhs[1] == _RUN_BLOCK < lhs[2]:
+            run_sums += 1
+            assert tuple(got) == highest, eqn
+            assert eqn.params["preferred_element_type"] == jnp.float32
+        elif dtype == "float32":
             assert tuple(got) == highest, eqn
         else:
             assert got is None, eqn
+    assert run_sums >= 3  # the two convs' and the position gather's

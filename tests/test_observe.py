@@ -716,13 +716,17 @@ ENTRY %main.7 (a.1: f32[4]) -> f32[4] {{
         assert ("optimizer", "fwd") in got and ("embed", "bwd") in got
         # the dense geometry's data movement carries its scope through
         # linear_call's transposes: the position gather in every
-        # direction, the overflow tier's segment-sum under one reverse
+        # direction, the overflow tier's run sums (a matmul a block of the
+        # list, no scatter: ops/segment.py _run_totals) under one reverse
         # pass; and no gather or scatter of the step is left unnamed
         moves = {(phases.classify(n), n.rsplit("/", 1)[-1]) for n in names
-                 if n.endswith(("/gather", "/scatter-add"))}
+                 if n.endswith(("/gather", "/scatter-add", "/dot_general"))}
         for direction in ("fwd", "bwd", "bwd2"):
             assert (("edge_geom", direction), "gather") in moves
-        assert (("edge_geom", "bwd"), "scatter-add") in moves
+        for phase in ("edge_geom", "conv.gather"):
+            assert ((phase, "bwd"), "dot_general") in moves
+            assert not [m for m in moves
+                        if m[0][0] == phase and m[1] == "scatter-add"]
         assert not [m for m in moves if m[0][0] == "other"]
         # no BatchNorm in this trunk: nothing of it carries a BatchNorm
         # phase, the residual after the neighbour sum included
@@ -799,6 +803,71 @@ class TestDriverSpans:
                    if e["args"]["train"]) == 3
         assert sum(1 for e in chunks if not e["args"]["train"]) == 1
         assert telemetry.counters()["scan_steps"] == 4
+        telemetry.close()
+
+    @pytest.mark.parametrize("staging", ["coo", "dense", "compact", "mesh"])
+    def test_scan_stage_says_how_far_the_overflow_tier_engages(
+            self, tmp_path, staging):
+        """The ``scan.stage`` span and the run summary carry what share of
+        the staged edges reaches its node through the overflow tier of the
+        gather's transpose (ops/segment.py _run_totals): real entries,
+        capacity, and the run capacity the programs are compiled for —
+        summed over batches, and over the chips of a mesh's device axis;
+        zeros for batches that carry no mapping (flat COO)."""
+        from cgnn_tpu.data.compact import CompactSpec, compact_pack_fn
+        from cgnn_tpu.data.dataset import FeaturizeConfig, load_synthetic_mp
+        from cgnn_tpu.data.graph import (
+            batch_iterator,
+            overflow_run_cap,
+        )
+        from cgnn_tpu.parallel.data_parallel import stack_batches
+        from cgnn_tpu.train.loop import ScanEpochDriver
+        from cgnn_tpu.train.step import make_eval_step
+
+        m = 12
+        cfg = FeaturizeConfig(radius=6.0, max_num_nbr=m)
+        train_g = load_synthetic_mp(24, cfg, seed=3)
+        dense = staging != "coo"
+        nc, ec = capacities_for(train_g, 8, dense_m=m if dense else None)
+        pack_fn = None
+        if staging == "compact":
+            pack_fn = compact_pack_fn(CompactSpec.build(
+                train_g, cfg.gdf(), dense_m=m))
+        batches = list(batch_iterator(
+            train_g, 8, nc, ec, dense_m=m if dense else None,
+            pack_fn=pack_fn))
+        assert len(batches) >= 2
+        want = {"transpose_overflow_rows": 0, "transpose_overflow_cap": 0,
+                "transpose_overflow_max_run": 0}
+        if dense:
+            neighbors = [np.asarray(b.neighbors)[
+                np.asarray(b.edge_mask).reshape(-1) > 0] for b in batches]
+            want = {
+                "transpose_overflow_rows": int(sum(
+                    np.maximum(np.bincount(nb) - m, 0).sum()
+                    for nb in neighbors)),
+                "transpose_overflow_cap": sum(
+                    len(b.over_slots) for b in batches),
+                "transpose_overflow_max_run": overflow_run_cap(train_g, m),
+            }
+            assert 0 < want["transpose_overflow_rows"] \
+                < want["transpose_overflow_cap"]
+        if staging == "mesh":  # a device axis of two: totals over chips
+            batches = [stack_batches(batches[:2])]
+        telemetry = Telemetry("epoch", str(tmp_path / "t"))
+        ScanEpochDriver(make_train_step(), make_eval_step(), batches, [],
+                        np.random.default_rng(0), telemetry=telemetry,
+                        stage=lambda x: x)
+        (stage,) = [e for e in telemetry.spans.events
+                    if e["name"] == "scan.stage"]
+        if staging == "mesh":
+            want["transpose_overflow_rows"] = int(sum(
+                np.maximum(np.bincount(nb) - m, 0).sum()
+                for nb in neighbors[:2]))
+            want["transpose_overflow_cap"] = 2 * len(batches[0].over_slots[0])
+        assert {k: stage["args"][k] for k in want} == want
+        summary = {**telemetry.counters(), **telemetry.gauges()}
+        assert {k: int(summary[k]) for k in want} == want
         telemetry.close()
 
     def test_without_telemetry_the_dispatch_opens_no_annotation(

@@ -126,31 +126,20 @@ def test_linear_gather_survives_forward_over_reverse():
 
 
 def _dense_gather_case(n, m, f, dtype):
-    """Random dense-layout neighbours with their exact transpose mapping:
-    tier 1 holds each node's first ``m`` in-edges, the rest overflow
-    (node-sorted), both padded with masked slot-0 entries."""
+    """Random dense-layout neighbours with their exact transpose mapping
+    as the packer builds it: tier 1 holds each node's first ``m`` in-edges,
+    the rest overflow (node-sorted runs, padded with slot-0 entries that
+    name the last node; ``over_last`` ends each run)."""
+    from cgnn_tpu.data.graph import transpose_slots
+
     rng = np.random.default_rng(n * m + f)
     nbrs = rng.integers(0, n, size=n * m).astype(np.int32)
-    in_slots = np.zeros((n, m), np.int32)
-    in_mask = np.zeros((n, m), np.float32)
-    over = []
-    fill = np.zeros(n, int)
-    for slot, j in enumerate(nbrs):
-        if fill[j] < m:
-            in_slots[j, fill[j]] = slot
-            in_mask[j, fill[j]] = 1.0
-            fill[j] += 1
-        else:
-            over.append((j, slot))
-    assert over, "no overflow exercised"
-    over.sort()
-    pad = 3
-    o_nodes = np.array([j for j, _ in over] + [n - 1] * pad, np.int32)
-    o_slots = np.array([s for _, s in over] + [0] * pad, np.int32)
-    o_mask = np.array([1.0] * len(over) + [0.0] * pad, np.float32)
+    extra = int(np.maximum(np.bincount(nbrs, minlength=n) - m, 0).sum())
+    mapping = transpose_slots(nbrs, np.ones(n * m, bool), n, m, None,
+                              over_cap=extra + 3)
+    assert extra > 0, "no overflow exercised"
     nodes = jnp.asarray(rng.normal(size=(n, f)).astype(np.float32)).astype(dtype)
-    mapping = tuple(jnp.asarray(x) for x in (
-        in_slots.reshape(-1), in_mask, o_slots, o_nodes, o_mask))
+    mapping = tuple(jnp.asarray(x) for x in mapping)
     return nodes, jnp.asarray(nbrs), mapping
 
 
@@ -306,31 +295,47 @@ def _conv_case(mapping):
     """One packed dense batch with padding nodes and padding slots, and
     the transpose mapping the case asks for: none (forward-only batches),
     single-tier ([N, In] at the dataset's in-degree cap) or two-tier
-    ([N, M] plus a non-empty overflow list)."""
+    ([N, M] plus a non-empty overflow list at the data set's run capacity;
+    ``two-tier-snug-run``: the capacity is the batch's own longest run, so
+    a node owns a run of exactly K; ``two-tier-empty``: graphs of which no
+    atom has more than M incoming edges, so the list is all padding)."""
     from cgnn_tpu.data.dataset import FeaturizeConfig, load_synthetic
     from cgnn_tpu.data.graph import (
         batch_iterator,
         capacities_for,
         in_degree_cap,
+        max_in_degree,
+        overflow_rows,
     )
 
     m = 8
     cfg = FeaturizeConfig(radius=4.0, max_num_nbr=m)
     graphs = load_synthetic(14, cfg, seed=2, max_atoms=9)
     nc, ec = capacities_for(graphs, 14, dense_m=m)
-    in_cap = {"none": 0, "single-tier": in_degree_cap(graphs),
-              "two-tier": None}[mapping]
+    in_cap = {"none": 0, "single-tier": in_degree_cap(graphs)}.get(mapping)
+    kw = {}
+    if mapping == "two-tier-snug-run":
+        kw = {"run_cap": max_in_degree(graphs) - m}
+    elif mapping == "two-tier-empty":
+        graphs = [g for g in load_synthetic(40, cfg, seed=2, max_atoms=9)
+                  if max_in_degree([g]) <= m][:14]
+        assert len(graphs) == 14
     batch = next(batch_iterator(graphs, 14, nc, ec, dense_m=m,
-                                in_cap=in_cap))
+                                in_cap=in_cap, **kw))
     node_mask = np.asarray(batch.node_mask)
     edge_mask = np.asarray(batch.edge_mask)
     assert 0 < node_mask.sum() < node_mask.size, "no padding node"
     assert (edge_mask.reshape(nc, m)[node_mask > 0] == 0).any(), \
         "no padding slot on a real node"
     assert (batch.in_slots is None) == (mapping == "none")
-    assert (batch.over_slots is not None) == (mapping == "two-tier")
-    if mapping == "two-tier":
-        assert np.asarray(batch.over_mask).sum() > 0, "no overflow edge"
+    assert (batch.over_slots is not None) == mapping.startswith("two-tier")
+    if mapping == "two-tier-empty":
+        assert overflow_rows(batch) == 0 and len(batch.over_slots) >= 8
+    elif mapping.startswith("two-tier"):
+        assert overflow_rows(batch) > 0, "no overflow edge"
+        longest = np.nonzero(np.asarray(batch.over_runs))[0].max() + 1
+        assert (longest == len(batch.over_runs)) \
+            == (mapping == "two-tier-snug-run")
     return batch, m
 
 
@@ -355,7 +360,8 @@ def _conv_pair(batch, m, jdt, f, batchnorm):
     mapping_kw = dict(in_slots=batch.in_slots, in_mask=batch.in_mask,
                       over_slots=batch.over_slots,
                       over_nodes=batch.over_nodes,
-                      over_mask=batch.over_mask)
+                      over_last=batch.over_last,
+                      over_runs=batch.over_runs)
     variables = coo.init(jax.random.key(0), nodes.astype(jdt),
                          *args(batch.flat_edges))
     v_dense = dense.init(jax.random.key(0), nodes.astype(jdt),
@@ -371,9 +377,13 @@ def _conv_pair(batch, m, jdt, f, batchnorm):
         (coo, batch.flat_edges, {}), args
 
 
+_MAPPINGS = ["none", "single-tier", "two-tier", "two-tier-snug-run",
+             "two-tier-empty"]
+
+
 @pytest.mark.parametrize("batchnorm", [True, False],
                          ids=["batchnorm", "no-batchnorm"])
-@pytest.mark.parametrize("mapping", ["none", "single-tier", "two-tier"])
+@pytest.mark.parametrize("mapping", _MAPPINGS)
 @pytest.mark.parametrize("mode", ["train", "eval"])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_dense_conv_matches_coo_conv(dtype, mode, mapping, batchnorm):
@@ -461,7 +471,7 @@ def test_dense_conv_matches_coo_conv(dtype, mode, mapping, batchnorm):
             close(a, b, 5e-4, 5e-5, f"gradient {module}{ka}", scale)
 
 
-@pytest.mark.parametrize("mapping", ["none", "single-tier", "two-tier"])
+@pytest.mark.parametrize("mapping", _MAPPINGS)
 def test_dense_conv_second_derivative_matches_coo_conv(mapping):
     """Grad over grad, which the force step runs in every step
     (train/force_step.py): the inner reverse pass gives d(sum out^2)/d(nodes,

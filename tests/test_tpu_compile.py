@@ -162,16 +162,15 @@ def test_force_geometry_reads_the_dense_layout(one_chip, no_compile_cache):
     fifth of the step. Under ``dense_m`` the centre is a broadcast, the
     lattice is gathered once an atom, and the neighbours' positions go
     through ops/segment.gather_slot_major with the batch's transpose
-    mapping: the only scatter left under the ``edge_geom`` scope is the
-    overflow tier's segment-sum, over_cap rows wide."""
+    mapping, whose overflow tier has been a run sum and a pointer gather
+    since PR 33: no scatter is left under the ``edge_geom`` scope."""
     from cgnn_tpu.observe import phases
 
     text, _graphs, batches, node_cap = _full_staging_scan_program(
         one_chip, "force", "float32")
-    edge_cap, over_cap = node_cap * 12, batches[0].over_slots.shape[0]
-    assert len({edge_cap, over_cap, node_cap}) == 3  # told apart by size
+    edge_cap = node_cap * 12
 
-    scatter_rows, index_gathers, lattices_an_edge, scoped = [], [], [], 0
+    scatters, index_gathers, lattices_an_edge, scoped = [], [], [], 0
     for comp in phases._parse(text).values():
         parsed = {name: m.groups() for name, rest in comp["instrs"].items()
                   if (m := _INSTR.match(rest))}
@@ -184,15 +183,58 @@ def test_force_geometry_reads_the_dense_layout(one_chip, no_compile_cache):
                     op_name.group(1))[0] != phases.EDGE_GEOM:
                 continue
             scoped += 1
-            if op == "scatter":  # (operand, indices, updates)
-                updates = parsed[phases._OPERAND.findall(operands)[2]]
-                scatter_rows.append(int(updates[1].split(",")[0]))
+            if op == "scatter":
+                scatters.append(rest[:200])
             if op == "gather" and dtype == "s32":
                 index_gathers.append(rest[:200])
     assert scoped > 50  # the scope is there to be read
-    assert scatter_rows == [over_cap], scatter_rows
+    assert not scatters, scatters
     assert not index_gathers, index_gathers
     assert not lattices_an_edge, lattices_an_edge
+
+
+@pytest.mark.parametrize("task,dtype", [
+    ("regression", "bfloat16"), ("force", "float32")])
+def test_no_scatter_under_the_conv_gather(one_chip, no_compile_cache, task,
+                                          dtype):
+    """The pin that the gather's declared transpose (ops/segment.py
+    _transpose_cotangent) holds no scatter in the program the chip runs
+    (PR 33), for the ``mp-flagship`` trunk (bfloat16, BatchNorm) and the
+    ``md17-force`` one (float32, two reverse passes): tier 1 is a row gather
+    and a masked sum, the overflow tier a row gather, one batched matmul
+    that sums each node's run of the list, and a row gather through
+    ``over_last``. The sorted scatter-add that XLA made of the tier's
+    ``segment_sum`` cost ~10 ns a row where a gathered row costs ~1.3-1.8
+    (PERF.md section 5), three times a step (five in the force step). The
+    pooling's ``segment_sum`` lives under ``pool_head`` / ``force_readout``
+    and stays: it shows that the count below can see a scatter."""
+    from cgnn_tpu.observe import phases
+
+    text, _graphs, batches, node_cap = _full_staging_scan_program(
+        one_chip, task, dtype)
+    n_blocks = batches[0].over_slots.shape[0] // 128 + 1
+
+    scatters, run_sums = {}, []
+    for comp in phases._parse(text).values():
+        for name, rest in comp["instrs"].items():
+            m = _INSTR.match(rest)
+            op_name = phases._OP_NAME.search(rest)
+            if not m or not op_name:
+                continue
+            phase, direction = phases.classify(op_name.group(1))
+            _dt, dims, op, _operands = m.groups()
+            if op == "scatter":
+                scatters.setdefault(phase, []).append(rest[:160])
+            if (op in ("dot", "convolution") and dims.startswith(
+                    f"{n_blocks},128,")
+                    and phase in (phases.CONV_GATHER, phases.EDGE_GEOM)):
+                run_sums.append((phase, direction))
+    assert scatters, "the pooling's scatter is gone: does the count still see?"
+    assert not {phases.CONV_GATHER, phases.EDGE_GEOM} & set(scatters), scatters
+    # the run sums are there to be seen: one a conv and reverse pass that
+    # needs the nodes' gradient, and in the force step the position gather's
+    assert run_sums.count((phases.CONV_GATHER, phases.BWD)) >= 2, run_sums
+    assert ((phases.EDGE_GEOM, phases.BWD) in run_sums) == (task == "force")
 
 
 @pytest.mark.parametrize("task,dtype", [
@@ -209,13 +251,18 @@ def test_no_matmul_over_gathered_rows(one_chip, no_compile_cache, task,
     matmul under ``conv.fc_full`` reads or writes anything with E rows of F
     (the edge term's rows are G = 41 wide, z's 2F), every row gather under
     ``conv.gather`` moves rows of 2F, and the forward ones write [E, 2F]:
-    the projected block that is a term of z."""
+    the projected block that is a term of z. The reverse pass gathers E
+    rows of dz (tier 1), the overflow list's rows in blocks of 128 with
+    their halo, and one row a node (its run's total; PR 33)."""
     from cgnn_tpu.observe import phases
 
     text, _graphs, batches, node_cap = _full_staging_scan_program(
         one_chip, task, dtype)
     f, m = 16, 12  # the helper's model and layout
     edge_cap, over_cap = node_cap * m, batches[0].over_slots.shape[0]
+    halo = -(-(batches[0].over_runs.shape[0] - 1) // 8) * 8
+    over_rows = (over_cap // 128 + 1) * (halo + 128)  # _run_totals' windows
+    assert len({edge_cap, over_rows, node_cap}) == 3  # told apart by size
     gauss = batches[0].edges.shape[-1]
     assert len({f, 2 * f, gauss}) == 3  # E rows are told apart by width
     n_convs = 2
@@ -248,7 +295,8 @@ def test_no_matmul_over_gathered_rows(one_chip, no_compile_cache, task,
     # pass (the force step has two)
     assert matmuls >= 3 * n_convs * (2 if task == "regression" else 3)
     assert not over_edge_rows, over_edge_rows
-    rows = {f"{edge_cap},{2 * f}", f"{over_cap},{2 * f}"}
+    rows = {f"{edge_cap},{2 * f}", f"{over_rows},{2 * f}",
+            f"{node_cap},{2 * f}"}
     assert {dims for _, dims in gathers} == rows, gathers
     assert [d for d in gathers if d[0] == phases.FWD] == [
         (phases.FWD, f"{edge_cap},{2 * f}")] * n_convs
