@@ -2,12 +2,17 @@
 """Readings that the limits of ``correct`` are set from (PERF.md section 2).
 
     python3 benchmark/control.py --workload <cell> --seeds 1,2,3 [--window S]
+                                 [--manifest <a test's>]
 
 In ONE process, at the cell's own size: for each seed, what a sound run of the
-program reads against the plain reference, and what the control reads — the
-reference put in the program's place and computed in float8 e4m3, the
-precision below the configuration's bfloat16. A benchmark run never runs
-this; it is for the chip, by hand, when a limit is set or questioned.
+program reads against the plain reference, and what each control of the
+cell's kind reads: ``CONTROLS`` in ``kinds/<kind>.py`` maps a name to the
+keywords of ``Driver.check`` under which the reference, computed wrongly,
+stands in the program's place (float8 e4m3 for the bfloat16 cells, bfloat16
+for ``force_train``'s float32, the broken collectives of ``dp_train``, whose
+own ``python3 -m benchmark.kinds.dp_train`` prints the statistics leaf by
+leaf besides). A benchmark run never runs this; it is for the chip, by hand,
+when a limit is set or questioned.
 """
 
 from __future__ import annotations
@@ -25,16 +30,17 @@ sys.path[0] = ROOT
 
 def main(argv=None) -> int:
     p = argparse.ArgumentParser()
+    p.add_argument("--manifest", default=os.path.join(ROOT, "BENCHMARK.json"))
     p.add_argument("--workload", required=True)
     p.add_argument("--seeds", required=True)
     p.add_argument("--window", type=float, default=3.0)
     args = p.parse_args(argv)
     from benchmark import run
-    from benchmark.reference import cgcnn_ref as ref
+    from benchmark.kinds import train
     from cgnn_tpu.runtime import configure_compile_cache
 
     configure_compile_cache(None)
-    cell = run.Cell(os.path.join(ROOT, "BENCHMARK.json"), args.workload)
+    cell = run.Cell(args.manifest, args.workload)
     seeds = [int(s) for s in args.seeds.split(",")]
     ctx = run.Context(cell, seeds[0], False)
     kind = importlib.import_module("benchmark.kinds." + cell.traffic["kind"])
@@ -45,13 +51,17 @@ def main(argv=None) -> int:
         for k, seed in enumerate(seeds):
             if k:
                 driver.reseed(seed)
-            if cell.traffic["kind"] != "train":
+            if not isinstance(driver, train.Driver):
+                # answers come from a window; a training kind's readings
+                # are the first steps', which set-up and reseed() have driven
                 driver.window(args.window, None)
-            sound = {r["name"]: r["value"] for r in driver.check()}
-            control = {r["name"]: r["value"]
-                       for r in driver.check(control_mm=ref.mm_fp8)}
-            out.append({"seed": seed, "program": sound, "control": control})
-            print(json.dumps(out[-1]), flush=True)
+            line = {"seed": seed, "program": {
+                r["name"]: r["value"] for r in driver.check()}}
+            for name, kw in kind.CONTROLS.items():
+                line[name] = {r["name"]: r["value"]
+                              for r in driver.check(**kw)}
+            out.append(line)
+            print(json.dumps(line), flush=True)
             raw = getattr(driver, "raw_readings", None)
             if raw is not None:
                 print("RAW " + json.dumps({"seed": seed, **raw()}),
@@ -60,12 +70,10 @@ def main(argv=None) -> int:
         close = getattr(driver, "close", None)
         if close is not None:
             close()
-    names = list(out[0]["program"])
-    for n in names:
+    for n in out[0]["program"]:
         hi = max(o["program"][n] for o in out)
-        lo = min(o["control"][n] for o in out)
-        print(f"{n}: sound runs' largest {hi:.6g}, control's smallest "
-              f"{lo:.6g}, ratio {lo / hi if hi else float('inf'):.2f}")
+        print(f"{n}: sound runs' largest {hi:.6g}; smallest of " + ", ".join(
+            f"{c} {min(o[c][n] for o in out):.6g}" for c in kind.CONTROLS))
     return 0
 
 

@@ -6,11 +6,19 @@ Both counts are lower bounds on purpose. A share of the roofline is the least
 time over the measured time, and a count that included what today's
 implementation happens to move (the per-edge ``z``, ``z2``, ``msg`` tensors,
 the Gaussian-expanded edge features, layout copies) would let a later fused
-conv read above 100% through no fault of its own.
+conv read above 100% through no fault of its own. For the same reason a bound
+follows the CHEAPEST algorithm known, not the one first written down: the
+conv's ``fc_full`` over ``[v_i, v_j, e_ij]`` is linear, so its neighbour term
+``v_j @ K_j`` can be projected once an ATOM and the projected rows gathered
+(what the program does since PR 30), where it was once counted, and computed,
+once an EDGE. A count of the dearer algorithm is no bound on the cheaper one.
 
 FLOPs (matrix multiplications only; the MXU's peak is the denominator):
   per conv, forward:  v_i term 2*N*F*2F (contracted per atom, then broadcast
-                      over its M slots), v_j term 2*E*F*2F, edge term 2*E*K*2F
+                      over its M slots), v_j term 2*N*F*2F (projected per
+                      atom, then gathered by the neighbour index: the gather
+                      is no matmul, and its bytes are the [N, F] reads the
+                      byte count already has), edge term 2*E*K*2F
   per conv, backward: weight gradients for all three terms, input gradients
                       for the v_i and v_j terms only (edge features are data)
   head:               conv_to_fc 2*G*F*H and fc_out 2*G*H*T, x3 when training
@@ -56,7 +64,7 @@ def step_counts(n: float, e: float, g: float, model: dict, gauss_dim: int,
     t = model.get("num_targets", 1)
     c = model["n_conv"]
     node_term = 2.0 * n * f * 2 * f
-    nbr_term = 2.0 * e * f * 2 * f
+    nbr_term = 2.0 * n * f * 2 * f  # project, then gather: once an atom
     edge_term = 2.0 * e * k * 2 * f
     head = 2.0 * g * f * h + 2.0 * g * h * t
     p = n_params(model, atom_dim, gauss_dim)

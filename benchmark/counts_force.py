@@ -21,8 +21,11 @@ forward so runs, counted in units of its own FLOPs:
 FLOPs (matrix multiplications only; the MXU's peak is the denominator):
   edge term 2*E*K*2F, every conv: its input e_ij depends on the positions and
       on no weight                                  1 + 1 + 1 + 2 = 5 units
-  v_i term 2*N*F*2F and v_j term 2*E*F*2F, convs after the first: v depends
-      on both                                       1 + 1 + 2 + 2 = 6 units
+  v_i term 2*N*F*2F and v_j term 2*N*F*2F (projected once an atom, the
+      projected rows then gathered: the cheapest algorithm known, the
+      program's since PR 30, and the one ``counts.py`` bounds; the gather and
+      its transposes are no matmuls), convs after the first: v depends on
+      both                                          1 + 1 + 2 + 2 = 6 units
   the same terms in the first conv: v there is the embedding, which no
       position moves, so no inner pass and nothing over it
                                                     1 + 0 + 2 + 0 = 3 units
@@ -59,13 +62,22 @@ def n_params(model: dict, atom_dim: int, gauss_dim: int) -> int:
 
 
 def step_counts(n: float, e: float, model: dict, gauss_dim: int,
-                atom_dim: int, *, act_bytes: int = 2) -> dict:
+                atom_dim: int, *, act_bytes: int = 2,
+                nbr_per_edge: bool = True) -> dict:
     """{"flops", "bytes"} for one training step over ``n`` real atoms and
-    ``e`` real edges."""
+    ``e`` real edges.
+
+    ``nbr_per_edge=False`` is the bound (the neighbour term once an atom:
+    project, then gather) and what ``kinds/force_train.py`` asks for. The
+    default counts it once an edge, the algorithm of before PR 30, for one
+    reason: ``tests/test_force_ref.py``, outside the benchmark's paths, pins
+    that number, and the ``benchmark`` PR that corrected the count (PR 34)
+    may edit no file out there. Once that test is gone the keyword goes
+    (PERF.md section 7)."""
     f, h, k = model["atom_fea_len"], model["h_fea_len"], gauss_dim
     c = model["n_conv"]
     node_term = 2.0 * n * f * 2 * f
-    nbr_term = 2.0 * e * f * 2 * f
+    nbr_term = 2.0 * (e if nbr_per_edge else n) * f * 2 * f
     edge_term = 2.0 * e * k * 2 * f
     head = 2.0 * n * f * h + 2.0 * n * h
     flops = (c * 5 * edge_term + (3 + 6 * (c - 1)) * (node_term + nbr_term)

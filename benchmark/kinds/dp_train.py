@@ -14,9 +14,10 @@ checkpoint, each epoch's metric fetch deferred by one epoch.
 ``parallel.data``. ``train.batch_size`` is the GLOBAL batch; a device packs
 ``batch_size / parallel.data`` structures a step.
 
-What ``--seed`` changes: the weights and the driver's shuffle and chunk
-schedule. What it does not: the pool, its packing and its grouping into
-device groups, hence every compiled shape.
+What ``--seed`` changes: the weights and the order in which an epoch visits
+the device groups of a bucket shape. What it does not: the pool, its packing
+and its grouping into device groups, hence every compiled shape, and the
+epoch's chunk lengths and the turn of the shapes (``train.ScheduleRng``).
 
 Counts are summed over the shards. ``train_rate`` counts the real structures
 of all shards; the roofline's least time is one chip's share of a step
@@ -59,14 +60,6 @@ CONTROLS = {
 
 DP_COUNTERS = ("dp_replicas", "dp_global_batch", "dp_dropped_batches",
                "allreduce_bytes_per_step", "staged_bytes")
-
-
-class _StopAtChunk:
-    """What the driver polls at every chunk boundary (its ``preempt``): the
-    traced slice ends an epoch early through it, so that a slice holds a
-    whole number of chunks and the driver reports how many steps ran."""
-
-    requested = False
 
 
 class Driver(train.Driver):
@@ -180,15 +173,14 @@ class Driver(train.Driver):
             state = self._seeded_state(ctx.seed)
             count_deployment(telemetry, state, n_dev, per_dev)
         expand = make_expander(compact)
-        self.stop = _StopAtChunk()
         with ctx.span("pack_stage"):
             self.driver = ScanEpochDriver(
                 make_parallel_train_step(mesh, guard=True, expand=expand),
                 make_parallel_eval_step(mesh, expand=expand),
-                batches, [], np.random.default_rng(ctx.seed),
+                batches, [], self._schedule_rng(),
                 stage=lambda t: shard_scan_stack(t, mesh),
                 chunk_steps=int(self.traffic["chunk_steps"]),
-                telemetry=ctx.telemetry, preempt=self.stop,
+                telemetry=ctx.telemetry, preempt=self.clock,
             )
             # the stacks have arrived before anything is compiled or timed
             jax.block_until_ready(self.driver._train_groups)
@@ -336,11 +328,13 @@ class Driver(train.Driver):
         """``profiler.seconds`` of steady epoch, traced: an epoch of four
         chips is ~7,000 steps of ~800 device operations on each of four
         planes, too many events for one trace, so the slice ends the epoch
-        at a chunk boundary (the driver's own preemption poll), drains the
+        at a chunk boundary (the driver's own preemption poll, which
+        ``train.ChunkClock`` answers: the slice holds a whole number of
+        chunks and the driver reports how many steps ran), drains the
         pipeline, and counts the steps that ran. That epoch is not whole and
         is in no rate; the window's epochs start after it."""
         timer = threading.Timer(profiler.seconds,
-                                setattr, (self.stop, "requested", True))
+                                setattr, (self.clock, "stop", True))
         profiler.start()
         timer.start()
         try:
@@ -349,7 +343,7 @@ class Driver(train.Driver):
         finally:
             timer.cancel()
             profiler.stop()
-            self.stop.requested = False
+            self.clock.stop = False
         self.ctx.obs["counts"]["traced_steps"] = int(done["steps"])
         print(f"traced slice: {int(done['steps'])} steps "
               f"({'cut at a chunk boundary' if self.driver.aborted else 'a whole epoch'})")
@@ -369,12 +363,13 @@ class Driver(train.Driver):
         if profiler is not None:
             self._traced_slice(profiler)
         pending = None
-        t0 = time.perf_counter()
+        t0 = self._open_window()
         deadline = t0 + seconds
         while time.perf_counter() < deadline:
             pending, m = self._epoch(pending)
             note(m)
         note(self._drain(pending))
+        self._note_evidence(t0, stamps[-1])
         elapsed = stamps[-1] - t0
         epochs = len(losses)
         failed = sum(1 for x in losses if not math.isfinite(x))

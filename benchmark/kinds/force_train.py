@@ -12,9 +12,9 @@ its step, its weights, its optimizer (Adam: the first steps read its first
 moment), its reference, its counts, and two more numbers compared: the forces
 themselves and the gradient off the mean energy's direction.
 
-What ``--seed`` changes: the weights and the driver's shuffle and chunk
-schedule. What it does not: the pool and its packing, hence every compiled
-shape.
+What ``--seed`` changes: the weights and the order in which an epoch visits
+the batches. What it does not: the pool and its packing, hence every compiled
+shape (one bucket: every chunk is ``chunk_steps`` long, whatever the seed).
 """
 
 from __future__ import annotations
@@ -31,6 +31,9 @@ from benchmark.weights_force import make_weights
 ENERGY_OFFSET = 0.25
 # the program's staging counters (train/loop.py), copied for the readers
 STAGING_COUNTERS = ("staged_bytes", "staged_edge_fea_bytes")
+# the control (``benchmark/control.py``): every matmul operand rounded to
+# bfloat16, the precision below the float32 this configuration states
+CONTROLS = {"bfloat16": {"control_mm": ref.mm_bf16}}
 
 
 def frame_as_ref(g) -> dict:
@@ -145,9 +148,9 @@ class Driver(train.Driver):
             self.driver = ScanEpochDriver(
                 guard_step(make_force_train_step(*weights)),
                 make_force_eval_step(*weights), batches, [],
-                np.random.default_rng(ctx.seed),
+                self._schedule_rng(),
                 chunk_steps=int(self.traffic["chunk_steps"]),
-                telemetry=ctx.telemetry,
+                telemetry=ctx.telemetry, preempt=self.clock,
             )
             # device_put returns before the transfer ends: the resident set
             # arrives inside the span that staged it, not inside the first
@@ -224,7 +227,7 @@ class Driver(train.Driver):
             self.ctx.obs["counts"]["real_nodes"],
             sum(g.num_edges for m, _ in self.members for g in m),
             self.config["model"], g0.edge_fea.shape[1],
-            g0.atom_fea.shape[1])
+            g0.atom_fea.shape[1], nbr_per_edge=False)
         least, bound = counts.least_seconds(
             per_epoch, counts.peaks_for(jax.devices()[0].device_kind))
         self.ctx.obs["counts"]["least_s_per_traced_steps"] = (
@@ -286,11 +289,9 @@ class Driver(train.Driver):
 
     def check(self, control_mm=None) -> list:
         """The reference follows the same first steps from the same seeded
-        weights on the same batches' frames. With a ``control_mm`` the
-        control stands in the program's place: the reference with every
-        matmul operand rounded to bfloat16, the precision below the float32
-        this configuration states (``control.py`` hands every kind the
-        first-order cells' float8, two below; it is not used here)."""
+        weights on the same batches' frames. With ``control_mm`` the
+        reference computed with that matmul stands in the program's place
+        (``CONTROLS``: every operand rounded to bfloat16)."""
         import jax.numpy as jnp
 
         tr = self.config["train"]
@@ -309,7 +310,7 @@ class Driver(train.Driver):
         self.want = follow()
         got = self.got
         if control_mm is not None:
-            got = self.control = follow(mm=ref.mm_bf16)
+            got = self.control = follow(mm=control_mm)
         return compare(got, self.want, self.config["limits"]["force_train"])
 
 

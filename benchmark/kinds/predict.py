@@ -26,6 +26,9 @@ from benchmark import system
 from benchmark.reference import cgcnn_ref as ref
 from benchmark.weights import make_weights
 
+# the control (``benchmark/control.py``; see ``kinds/train.py``)
+CONTROLS = {"float8": {"control_mm": ref.mm_fp8}}
+
 
 class Driver:
     def __init__(self, ctx):

@@ -32,11 +32,11 @@ import time
 from benchmark.reduce.trace import (
     DEVICE_PREFIX,
     HOST_PLANE,
+    MODULES_LINE,
     OPS_LINE,
     _self_times,
 )
 
-MODULES_LINE = "XLA Modules"
 HOST_PREFIX = "cgnn:"
 NO_TABLE = "no_table"
 UNNAMED = ("other", NO_TABLE)  # time these hold is in no phase of the model

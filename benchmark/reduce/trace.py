@@ -17,13 +17,19 @@ own names.
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
+import statistics
 
 DEVICE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 HOST_PLANE = "/host:CPU"
+# a launch that holds this share of its program's median operation count, or
+# less, lost events (``events_lost``)
+LOST_SHARE = 0.9
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -156,3 +162,48 @@ def summarize(planes: list, top: int = 10) -> dict:
         "device_ops": rank(ops_ns, n),
         "idle_gaps": rank(gaps, 1),
     }
+
+
+def events_lost(planes: list) -> dict:
+    """Whether the trace lost device events, read off the trace alone.
+
+    One program runs the same operations at every launch, so on each device
+    the launches of one program (``XLA Modules`` events of one name, the
+    hash left off) hold equally many ``XLA Ops`` events. A launch that holds
+    ``LOST_SHARE`` of its program's median count or less lost some: the
+    profiler dropped them, the gaps they leave read as idle time, and the
+    run's per-layer numbers are not to be compared (two such runs stand in
+    the ledger: 726 operations a step against 842, and 46% idle in a cell
+    that reads 0.2%). It flags; nothing is retried, dropped or re-weighed.
+
+    -> {"lost": bool, "launches", "short_launches", "least_share",
+    "ops_outside_launches"}; ``lost`` is None where no launch was traced.
+    """
+    launches = short = outside = 0
+    least = 1.0
+    for p in planes:
+        if not p["name"].startswith(DEVICE_PREFIX):
+            continue
+        lines = {ln["name"]: ln["events"] for ln in p["lines"]}
+        mods = sorted(lines.get(MODULES_LINE, []), key=lambda ev: ev[1])
+        starts = sorted(ev[1] for ev in lines.get(OPS_LINE, []))
+        if not mods:
+            continue
+        by_program: dict = {}
+        inside = 0
+        for name, s, d in mods:
+            n = (bisect.bisect_left(starts, s + max(d, 1))
+                 - bisect.bisect_left(starts, s))
+            inside += n
+            by_program.setdefault(name.partition("(")[0], []).append(n)
+        outside += len(starts) - inside
+        for counts in by_program.values():
+            median = statistics.median(counts)
+            launches += len(counts)
+            if median <= 0:
+                continue
+            short += sum(1 for n in counts if n <= LOST_SHARE * median)
+            least = min(least, min(counts) / median)
+    return {"lost": short > 0 if launches else None, "launches": launches,
+            "short_launches": short, "least_share": least,
+            "ops_outside_launches": outside}

@@ -7,8 +7,13 @@ One process that holds the chip: loads, warms, measures, compares, prints,
 exits. It fails (non-zero, no result line) when JAX finds no TPU or fewer
 chips than the cell asks for, and when anything compiled inside the window.
 The last line of standard output is the result object; everything else
-(atoms, padding, counts, every number compared beside its limit) is printed
-on earlier lines.
+(atoms, padding, counts) is printed on earlier lines. Every number compared
+stands beside its limit three times: on a ``compare`` line of standard
+output, among the last lines of standard error, and under ``compared``, the
+result object's last key. ``evidence``, the key before it, is what a kind
+noted of where the window's time went (and, in a traced run, whether the
+trace lost events): no metric reads it; it is there so that a far-off run
+says where.
 
 Everything that belongs to one cell is data: BENCHMARK.json names the cell's
 configuration and traffic, ``traffic/<traffic>.json`` names the kind,
@@ -23,6 +28,7 @@ import argparse
 import contextlib
 import importlib
 import json
+import math
 import os
 import sys
 import time
@@ -87,7 +93,7 @@ class Context:
         self.seed = int(seed)
         self.spans: list = []  # (name, start_s, end_s), the benchmark's own
         self.obs: dict = {"counts": {}, "spans": self.spans, "trace": None,
-                          "program_spans": [], "hists": {}}
+                          "program_spans": [], "hists": {}, "evidence": {}}
         self.telemetry = None  # the program's span tracer, traced runs only
         if trace:
             from cgnn_tpu.observe import Telemetry
@@ -244,8 +250,21 @@ def run_cell(manifest_path: str, workload: str, seed: int, seconds: float,
     else:
         from benchmark.reduce import trace as reduce_trace
 
-        summary = reduce_trace.summarize(reduce_trace.from_xplane(
-            reduce_trace.find_xplane(trace_dir)))
+        planes = reduce_trace.from_xplane(reduce_trace.find_xplane(trace_dir))
+        summary = reduce_trace.summarize(planes)
+        lost = reduce_trace.events_lost(planes)
+        del planes
+        print(f"trace: {lost['launches']} launches, {lost['short_launches']} "
+              f"at or under {reduce_trace.LOST_SHARE} of their program's median "
+              f"operation count (least {lost['least_share']:.3f}), "
+              f"{lost['ops_outside_launches']} operations outside every "
+              f"launch" + ("; THE TRACE LOST EVENTS: this run's per-layer "
+                           "numbers are not to be compared"
+                           if lost["lost"] else ""))
+        ctx.obs["evidence"].update(
+            trace_events_lost=lost["lost"], trace_launches=lost["launches"],
+            trace_short_launches=lost["short_launches"],
+            trace_least_launch_share=lost["least_share"])
         ctx.obs["trace"] = summary
         if ctx.telemetry is not None and ctx.telemetry.spans is not None:
             ctx.obs["program_spans"] = list(ctx.telemetry.spans.events)
@@ -254,6 +273,18 @@ def run_cell(manifest_path: str, workload: str, seed: int, seconds: float,
         device["window_s"] = summary["window_s"]
         result["breakdown"] = {"device_ops": summary["device_ops"],
                                "idle_gaps": summary["idle_gaps"]}
+    if ctx.obs["evidence"]:
+        result["evidence"] = ctx.obs["evidence"]
+    # a NaN is no JSON number: it reads null here, and has failed above
+    result["compared"] = {
+        row["name"]: {"value": (float(row["value"])
+                                if math.isfinite(row["value"]) else None),
+                      "limit": float(row["limit"])}
+        for row in compared}
+    sys.stdout.flush()
+    for row in compared:
+        print(f"compared {row['name']} {row['value']:.6g} limit "
+              f"{row['limit']:.6g}", file=sys.stderr)
     return result, 0
 
 

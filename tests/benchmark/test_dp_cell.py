@@ -1,7 +1,8 @@
 """Kind ``dp_train`` (cell ``mp.train-dp4``) without a chip: the manifest's
-entries for it, and a whole run of the kind at a tiny size on four of the
-suite's virtual devices through ``run.run_cell``. Nothing here reports a time
-or a device metric.
+entries for it (found by name, in the manifest as committed and in the
+rehearsals of ``manifest_cases.py``), and a whole run of the kind at a tiny
+size on four of the suite's virtual devices through ``run.run_cell``. Nothing
+here reports a time or a device metric.
 """
 
 from __future__ import annotations
@@ -17,31 +18,26 @@ ROOT = os.path.dirname(os.path.dirname(HERE))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from manifest_cases import by_name, manifest, manifest_path  # noqa: E402,F401
+
 from benchmark import run  # noqa: E402
 from benchmark.readers import phase  # noqa: E402
 
 TINY = os.path.join(HERE, "fixtures", "manifest_tiny_dp.json")
 
 
-@pytest.fixture(scope="module")
-def manifest():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
-
-
-def test_the_cell_and_its_configuration_as_the_manifest_has_them(manifest):
-    cell = run.Cell(os.path.join(ROOT, "BENCHMARK.json"), "mp.train-dp4")
-    # by name, not by place: the next cell's entries may stand anywhere
-    entry = {w["name"]: w for w in manifest["workloads"]}["mp.train-dp4"]
+def test_the_cell_and_its_configuration_as_the_manifest_has_them(
+        manifest, manifest_path):
+    cell = run.Cell(manifest_path, "mp.train-dp4")
+    entry = by_name(manifest["workloads"])["mp.train-dp4"]
     assert cell.entry == entry and cell.chips == 4
     assert entry["config"] == "mp-flagship-dp4"
     assert cell.traffic["kind"] == "dp_train"
     cfg = cell.config
-    assert cfg["source"] == {c["name"]: c for c in manifest["configs"]}[
+    assert cfg["source"] == by_name(manifest["configs"])[
         "mp-flagship-dp4"]["source"]
     assert len(cfg["source"]) <= 200 and cfg["reduced"] == ["dataset_size"]
-    flagship = run.Cell(os.path.join(ROOT, "BENCHMARK.json"),
-                        "mp.train").config
+    flagship = run.Cell(manifest_path, "mp.train").config
     # the flagship's model, featurisation, precision, layout and optimizer,
     # letter for letter; the same pool, hence the same cache file
     for key in ("builder", "model", "featurize", "precision", "layout"):
@@ -59,79 +55,34 @@ def test_the_cell_and_its_configuration_as_the_manifest_has_them(manifest):
                                       "drop_last", "metrics"}
     assert cfg["limits"]["dp_train"]["replica_param_max_abs_diff"] == 0
     assert cell.traffic["chunk_steps"] == run.Cell(
-        os.path.join(ROOT, "BENCHMARK.json"), "mp.train").traffic[
-            "chunk_steps"]
+        manifest_path, "mp.train").traffic["chunk_steps"]
 
 
-def test_the_cell_s_metrics(manifest):
+def test_the_cell_s_metrics(manifest, manifest_path):
     """It reports train_rate, every per-layer metric ``mp.train`` reports
-    and the one this PR brings; every one of them resolves to a file; one
-    cell in four asks for four chips."""
+    and its own one; every one of them resolves to a file; it is among the
+    cells that ask for four chips, which number what the driver allows: a
+    quarter of the cells, rounded down, and one always."""
     mine = {m["name"] for m in manifest["per_layer"]
             if "mp.train-dp4" in m.get("workloads", [])}
     flagship = {m["name"] for m in manifest["per_layer"]
                 if "mp.train" in m.get("workloads", [])}
     assert mine == flagship | {"allreduce_ms.train"}
-    by_name = {m["name"]: m for m in manifest["per_layer"]}
-    assert by_name["allreduce_ms.train"] == {
+    assert by_name(manifest["per_layer"])["allreduce_ms.train"] == {
         "name": "allreduce_ms.train", "unit": "ms", "better": "lower",
         "source": "device_trace", "layer": "mesh", "moves": "train_rate",
         "workloads": ["mp.train-dp4"]}
-    cell = run.Cell(os.path.join(ROOT, "BENCHMARK.json"), "mp.train-dp4")
+    cell = run.Cell(manifest_path, "mp.train-dp4")
     for m in cell.per_layer():
         spec = run.load_json(os.path.join(cell.layer_dir,
                                           m["name"] + ".json"))
         assert os.path.exists(os.path.join(
             ROOT, "benchmark", "readers", spec["reader"] + ".py")), m
-    e2e = {m["name"]: m for m in manifest["end_to_end"]}
-    assert e2e["train_rate"]["workloads"][-1] == "mp.train-dp4"
-    assert [w["name"] for w in manifest["workloads"]
-            if w["chips"] == 4] == ["mp.train-dp4"]
-    assert len(manifest["workloads"]) // 4 >= 1
-
-
-def test_the_cells_before_this_one_are_what_they_were(manifest):
-    """What ``test_force_cell.py`` asserts of ``force.train``'s entries by
-    their place (the last of the manifest's lists, which they no longer are:
-    new entries go last; ``conftest.py`` has the whole of it), asserted by
-    name."""
-    cell = run.Cell(os.path.join(ROOT, "BENCHMARK.json"), "force.train")
-    entry = {w["name"]: w for w in manifest["workloads"]}["force.train"]
-    assert cell.entry == entry and cell.chips == 1
-    assert cell.traffic["kind"] == "force_train"
-    cfg = cell.config
-    assert cfg["source"] == {c["name"]: c for c in manifest["configs"]}[
-        "md17-force"]["source"]
-    assert cfg["task"] == "force" and cfg["reduced"] == ["dataset_size"]
-    assert cfg["model"] == {"atom_fea_len": 64, "n_conv": 3,
-                            "h_fea_len": 128, "n_h": 1, "num_targets": 1}
-    assert cfg["featurize"] == {"radius": 8.0, "max_num_nbr": 12,
-                                "dmin": 0.0, "step": 0.2}
-    assert cfg["data"]["n"] * cfg["data"]["resident_copies"] >= 211_762
-    assert (cfg["train"]["energy_weight"], cfg["train"]["force_weight"]) \
-        == (1.0, 10.0)
-    assert set(cfg["limits"]["force_train"]) == set(cfg["limits_why"]) == {
-        "loss_rel", "grad_diff_median_leaf", "grad_norm_worst_leaf",
-        "delta_norm_median_leaf", "grad_diff_off_energy_median_leaf",
-        "force_diff_rel"}
-    # its metrics: every *.train metric of mp.train but the BatchNorm one,
-    # and its own three, which no other cell lists and which still follow
-    # one another
-    new = ["edge_geom_ms.train", "force_head_ms.train",
-           "staged_dead_pct.train"]
-    names = [m["name"] for m in manifest["per_layer"]]
-    mine = {m["name"] for m in manifest["per_layer"]
-            if "force.train" in m.get("workloads", [])}
-    flagship = {m["name"] for m in manifest["per_layer"]
-                if "mp.train" in m.get("workloads", [])}
-    assert mine == (flagship - {"conv_bn_ms.train"}) | set(new)
-    at = names.index(new[0])
-    assert names[at:at + 3] == new
-    for m in manifest["per_layer"][at:at + 3]:
-        assert m["workloads"] == ["force.train"]
-        assert m["moves"] == "train_rate"
-    e2e = {m["name"]: m for m in manifest["end_to_end"]}
-    assert "force.train" in e2e["train_rate"]["workloads"]
+    assert "mp.train-dp4" in by_name(manifest["end_to_end"])[
+        "train_rate"]["workloads"]
+    four = [w["name"] for w in manifest["workloads"] if w["chips"] == 4]
+    assert "mp.train-dp4" in four
+    assert len(four) <= max(1, len(manifest["workloads"]) // 4)
 
 
 @pytest.mark.parametrize("seed,trace", [(3_000_000_019, False), (23, True)])

@@ -1,7 +1,8 @@
 """Kind ``force_train`` (cell ``force.train``) without a chip: the manifest's
-entries for it, a whole run of the kind at a tiny size through
-``run.run_cell``, what breaks ``correct``, and the staging counters its
-per-layer metric reads. Nothing here reports a time or a device metric.
+entries for it (found by name, in the manifest as committed and in the
+rehearsals of ``manifest_cases.py``), a whole run of the kind at a tiny size
+through ``run.run_cell``, what breaks ``correct``, and the staging counters
+its per-layer metric reads. Nothing here reports a time or a device metric.
 """
 
 from __future__ import annotations
@@ -17,6 +18,8 @@ ROOT = os.path.dirname(os.path.dirname(HERE))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from manifest_cases import by_name, manifest, manifest_path  # noqa: E402,F401
+
 from benchmark import run  # noqa: E402
 from benchmark.readers import count, phase  # noqa: E402
 
@@ -25,19 +28,16 @@ NEW_METRICS = ("edge_geom_ms.train", "force_head_ms.train",
                "staged_dead_pct.train")
 
 
-@pytest.fixture(scope="module")
-def manifest():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
-
-
-def test_the_cell_and_its_configuration_as_the_manifest_has_them(manifest):
-    cell = run.Cell(os.path.join(ROOT, "BENCHMARK.json"), "force.train")
-    assert cell.entry == manifest["workloads"][-1] and cell.chips == 1
-    assert manifest["configs"][-1]["name"] == "md17-force"
+def test_the_cell_and_its_configuration_as_the_manifest_has_them(
+        manifest, manifest_path):
+    cell = run.Cell(manifest_path, "force.train")
+    entry = by_name(manifest["workloads"])["force.train"]
+    assert cell.entry == entry and cell.chips == 1
+    assert entry["config"] == "md17-force"
     assert cell.traffic["kind"] == "force_train"
     cfg = cell.config
-    assert cfg["source"] == manifest["configs"][-1]["source"]
+    assert cfg["source"] == by_name(manifest["configs"])[
+        "md17-force"]["source"]
     assert cfg["task"] == "force" and cfg["reduced"] == ["dataset_size"]
     # every width as published, none cut
     assert cfg["model"] == {"atom_fea_len": 64, "n_conv": 3,
@@ -58,20 +58,19 @@ def test_the_cell_s_metrics(manifest):
     """It reports train_rate, every *.train per-layer metric but the
     BatchNorm one (the trunk has none: without BatchNorm the conv's residual
     and its softplus carry ``conv.aggregate``'s phase, so the phase sums
-    still add up to the step) and the three this PR brings, which no other
-    cell lists."""
+    still add up to the step) and its own three, which no other cell
+    lists."""
     mine = {m["name"] for m in manifest["per_layer"]
             if "force.train" in m.get("workloads", [])}
     train = {m["name"] for m in manifest["per_layer"]
              if "mp.train" in m.get("workloads", [])}
     assert mine == (train - {"conv_bn_ms.train"}) | set(NEW_METRICS)
-    assert [m["name"] for m in manifest["per_layer"][-3:]] \
-        == list(NEW_METRICS)
-    for m in manifest["per_layer"][-3:]:
-        assert m["workloads"] == ["force.train"]
-        assert m["moves"] == "train_rate"
-    e2e = {m["name"]: m for m in manifest["end_to_end"]}
-    assert "force.train" in e2e["train_rate"]["workloads"]
+    metrics = by_name(manifest["per_layer"])
+    for name in NEW_METRICS:
+        assert metrics[name]["workloads"] == ["force.train"]
+        assert metrics[name]["moves"] == "train_rate"
+    assert "force.train" in by_name(manifest["end_to_end"])[
+        "train_rate"]["workloads"]
 
 
 @pytest.fixture(scope="module")
