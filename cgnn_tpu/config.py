@@ -30,6 +30,13 @@ class ModelConfig:
     # free aggregation, ~2x faster train step on TPU; 0/None = flat COO.
     # Serialized so predict.py packs batches the way the model expects.
     dense_m: int = 0
+    # the normalisation after each conv's neighbour sum: 'batch' (bn2, the
+    # lineage's) or 'layer' (the Open Catalyst CGCNN's LayerNorm; parameters
+    # conv_i/ln in bn2's place). bn1 is BatchNorm either way.
+    node_norm: str = "batch"
+    # softplus on the pooled vector before conv_to_fc (the lineage has one,
+    # the Open Catalyst CGCNN none)
+    pool_softplus: bool = True
 
     def to_meta(self) -> dict:
         return dataclasses.asdict(self)
@@ -41,6 +48,7 @@ class ModelConfig:
         kw["classification"] = bool(kw.get("classification", 0))
         kw["multi_task_head"] = bool(kw.get("multi_task_head", 0))
         kw["dense_m"] = int(kw.get("dense_m", 0))
+        kw["pool_softplus"] = bool(kw.get("pool_softplus", 1))
         return cls(**kw)
 
     def impl_summary(self) -> str:
@@ -84,6 +92,8 @@ class ModelConfig:
             head=head,
             edge_axis_name=edge_axis_name,
             dense_m=self.dense_m or None,
+            node_norm=self.node_norm,
+            pool_softplus=self.pool_softplus,
         )
 
 
@@ -103,6 +113,11 @@ def build_model(model_cfg: "ModelConfig", data_cfg: "DataConfig",
             )
         from cgnn_tpu.models.forcefield import ForceFieldCGCNN
 
+        if data_cfg.var is not None:
+            raise NotImplementedError(
+                "the force model rebuilds its Gaussians at width = step; a "
+                "separate width (var) is not supported for the force task"
+            )
         return ForceFieldCGCNN(
             atom_fea_len=model_cfg.atom_fea_len,
             n_conv=model_cfg.n_conv,
@@ -122,6 +137,8 @@ class DataConfig:
     max_num_nbr: int = 12
     dmin: float = 0.0
     step: float = 0.2
+    # the Gaussians' width in exp(-(d - mu)^2 / var^2); None = ``step``
+    var: float | None = None
 
     def to_meta(self) -> dict:
         return dataclasses.asdict(self)
@@ -139,4 +156,5 @@ class DataConfig:
             max_num_nbr=self.max_num_nbr,
             dmin=self.dmin,
             step=self.step,
+            var=self.var,
         )

@@ -7,7 +7,7 @@ Reference semantics (SURVEY.md §2 component 6, §3.3) per conv layer:
     gate, core = split(z)
     msg    = sigmoid(gate) * softplus(core)
     agg_i  = sum_j msg_ij                  # per-node sum
-    v_i'   = softplus(v_i + BatchNorm(agg_i))
+    v_i'   = softplus(v_i + BatchNorm(agg_i))   # or LayerNorm, or none
 
 and the full model: Linear(92->F) embedding, n_conv such layers, per-crystal
 mean pooling, softplus MLP head (LogSoftmax head for classification).
@@ -49,7 +49,7 @@ from flax import linen as nn
 
 from cgnn_tpu.data.graph import GraphBatch
 from cgnn_tpu.observe import phases
-from cgnn_tpu.ops.norm import MaskedBatchNorm
+from cgnn_tpu.ops.norm import MaskedBatchNorm, MaskedLayerNorm
 from cgnn_tpu.ops.segment import (
     aggregate_edge_messages,
     gather,
@@ -111,17 +111,29 @@ class _SplitFcFull(nn.Module):
         return z + bias.astype(self.dtype)
 
 
+# CGConv.node_norm -> the phase of the conv's tail (the normalisation's module
+# name says its own; the residual and its softplus are scoped by this)
+_TAIL_PHASE = {"batch": phases.CONV_BN2, "layer": phases.CONV_LN,
+               "none": phases.CONV_AGGREGATE}
+
+
 class CGConv(nn.Module):
     """One edge-gated crystal-graph convolution (reference ``ConvLayer``)."""
 
     features: int
     dtype: Any = jnp.float32
-    # BatchNorm makes per-edge outputs depend on batch statistics; for energy
-    # models that's the reference semantics, but a force field must NOT use
-    # it: F = -dE/dr picks up gradient terms through the batch moments in
-    # train mode that vanish under running stats at eval, so the learned
-    # forces disagree between modes (measured: eval force MAE ~5x worse).
+    # bn1, the BatchNorm over a batch's edges. BatchNorm makes per-edge
+    # outputs depend on batch statistics; for energy models that's the
+    # reference semantics, but a force field must NOT use it: F = -dE/dr
+    # picks up gradient terms through the batch moments in train mode that
+    # vanish under running stats at eval, so the learned forces disagree
+    # between modes (measured: eval force MAE ~5x worse).
     use_batchnorm: bool = True
+    # the normalisation of the per-node sum, independent of bn1's: 'batch'
+    # (bn2, the lineage's), 'layer' (LayerNorm over a node's own F features,
+    # the Open Catalyst baseline's: no batch statistic, nothing to mask but
+    # the padded rows' output) or 'none' (the force model)
+    node_norm: str = "batch"
     # edge-sharded graph parallelism (SURVEY.md §5 "long-context analog"):
     # when the edge axis is sharded over this mesh axis, per-node partial
     # aggregates are psum-ed back to full sums and edge-BN moments span all
@@ -303,16 +315,21 @@ class CGConv(nn.Module):
                 if self.edge_axis_name is not None:
                     # partial per-node sums from this edge shard -> full sums
                     agg = jax.lax.psum(agg, self.edge_axis_name)
-        if self.use_batchnorm:
+        if self.node_norm not in _TAIL_PHASE:
+            raise ValueError(f"node_norm must be one of "
+                             f"{sorted(_TAIL_PHASE)}, got {self.node_norm!r}")
+        if self.node_norm == "batch":
             agg = MaskedBatchNorm(dtype=self.dtype, name="bn2")(
                 agg, mask=node_mask, use_running_average=not train
             )
-        # the residual and its softplus belong to bn2's phase: they read
-        # its output once more and nothing else. Without BatchNorm (the
-        # force model) what they read is the aggregate's
-        with jax.named_scope(
-            phases.CONV_BN2 if self.use_batchnorm else phases.CONV_AGGREGATE
-        ):
+        elif self.node_norm == "layer":
+            agg = MaskedLayerNorm(dtype=self.dtype, name="ln")(
+                agg, mask=node_mask
+            )
+        # the residual and its softplus belong to the normalisation's
+        # phase: they read its output once more and nothing else. Without
+        # one (the force model) what they read is the aggregate's
+        with jax.named_scope(_TAIL_PHASE[self.node_norm]):
             out = nn.softplus(nodes + agg)
             return out * node_mask[:, None].astype(out.dtype)
 
@@ -354,6 +371,10 @@ class CrystalGraphConvNet(nn.Module):
     head: nn.Module | None = None  # e.g. MultiTaskHead; replaces fc stack
     edge_axis_name: str | None = None  # edge-sharded graph parallelism
     dense_m: int | None = None  # dense slot layout (see CGConv.dense_m)
+    node_norm: str = "batch"  # after each conv's sum (see CGConv.node_norm)
+    # softplus on the pooled vector before conv_to_fc (txie-93/cgcnn has
+    # one; the Open Catalyst baseline does not)
+    pool_softplus: bool = True
 
     @nn.compact
     def __call__(
@@ -370,6 +391,7 @@ class CrystalGraphConvNet(nn.Module):
                 dtype=self.dtype,
                 edge_axis_name=self.edge_axis_name,
                 dense_m=self.dense_m,
+                node_norm=self.node_norm,
                 name=f"conv_{i}",
             )(
                 nodes,
@@ -394,9 +416,11 @@ class CrystalGraphConvNet(nn.Module):
                 batch.graph_capacity,
                 weights=batch.node_mask.astype(nodes.dtype),
             )
+            if self.pool_softplus:
+                crys = nn.softplus(crys)
             crys = nn.Dense(
                 self.h_fea_len, dtype=self.dtype, name="conv_to_fc"
-            )(nn.softplus(crys))
+            )(crys)
             crys = nn.softplus(crys)
             if self.classification and self.dropout_rate > 0:
                 crys = nn.Dropout(

@@ -155,6 +155,7 @@ class ForceFieldCGCNN(nn.Module):
                     # BatchNorm breaks train/eval force consistency (see
                     # CGConv)
                     use_batchnorm=False,
+                    node_norm="none",
                     dense_m=self.dense_m,
                     name=f"conv_{i}",
                 )(

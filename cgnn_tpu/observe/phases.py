@@ -31,6 +31,9 @@ CONV_BN1 = "conv.bn1"
 CONV_GATE = "conv.gate"
 CONV_AGGREGATE = "conv.aggregate"
 CONV_BN2 = "conv.bn2"
+# models/cgcnn.py: LayerNorm after the neighbour sum where a model has it in
+# bn2's place (the Open Catalyst CGCNN), with the residual and its softplus
+CONV_LN = "conv.ln"
 POOL_HEAD = "pool_head"
 FORCE_READOUT = "force_readout"  # models/forcefield.py: per-atom energies
 LOSS = "loss"
@@ -42,17 +45,18 @@ DP_ALLREDUCE = "dp.allreduce"
 OTHER = "other"
 
 PHASES = (EXPAND, EMBED, EDGE_GEOM, CONV_GATHER, CONV_FC_FULL, CONV_BN1,
-          CONV_GATE, CONV_AGGREGATE, CONV_BN2, POOL_HEAD, FORCE_READOUT, LOSS,
-          OPTIMIZER, SCAN, DP_ALLREDUCE, OTHER)
+          CONV_GATE, CONV_AGGREGATE, CONV_BN2, CONV_LN, POOL_HEAD,
+          FORCE_READOUT, LOSS, OPTIMIZER, SCAN, DP_ALLREDUCE, OTHER)
 FWD, BWD, BWD2 = "fwd", "bwd", "bwd2"
 
 # a path component -> its phase: the named scopes themselves, and the flax
-# module names of models/cgcnn.py (bn1/bn2/fc_full exist only inside a conv)
+# module names of models/cgcnn.py (bn1/bn2/ln/fc_full exist only inside a conv)
 _TOKEN_PHASE = {p: p for p in PHASES if p != OTHER} | {
     "embedding": EMBED,
     "fc_full": CONV_FC_FULL,
     "bn1": CONV_BN1,
     "bn2": CONV_BN2,
+    "ln": CONV_LN,
     "conv_to_fc": POOL_HEAD,
     "fc_out": POOL_HEAD,
 }

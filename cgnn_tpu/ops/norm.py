@@ -1,4 +1,4 @@
-"""Masked BatchNorm — padding-aware batch normalization.
+"""Masked BatchNorm and LayerNorm — padding-aware normalization.
 
 The reference normalizes over all N·M edge slots and all N node slots with
 cuDNN/ATen BatchNorm1d (SURVEY.md §2 component 6). On TPU the batch is padded
@@ -167,4 +167,34 @@ class MaskedBatchNorm(nn.Module):
             y = y * self.param("scale", nn.initializers.ones, (features,), jnp.float32)
         if self.use_bias:
             y = y + self.param("bias", nn.initializers.zeros, (features,), jnp.float32)
+        return y.astype(self.dtype or x.dtype)
+
+
+class MaskedLayerNorm(nn.Module):
+    """LayerNorm over the last axis of rows [..., C] (``torch.nn.LayerNorm``:
+    biased variance, affine), with an optional [...] validity mask.
+
+    A row's moments are its own, so padding cannot pollute anything; the
+    mask only zeroes the padded rows' output, which would otherwise read the
+    bias. Moments in >= float32, as ``MaskedBatchNorm`` keeps its statistics.
+    """
+
+    epsilon: float = 1e-5
+    dtype: jnp.dtype | None = None  # output dtype
+
+    @nn.compact
+    def __call__(self, x: jax.Array, mask: jax.Array | None = None):
+        features = x.shape[-1]
+        stat_dtype = jnp.promote_types(x.dtype, jnp.float32)
+        xf = x.astype(stat_dtype)
+        mean = xf.mean(axis=-1, keepdims=True)
+        centered = xf - mean
+        var = (centered * centered).mean(axis=-1, keepdims=True)
+        y = centered * jax.lax.rsqrt(var + self.epsilon)
+        y = y * self.param("scale", nn.initializers.ones, (features,),
+                           jnp.float32)
+        y = y + self.param("bias", nn.initializers.zeros, (features,),
+                           jnp.float32)
+        if mask is not None:
+            y = y * mask[..., None].astype(stat_dtype)
         return y.astype(self.dtype or x.dtype)

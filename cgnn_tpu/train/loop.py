@@ -410,6 +410,22 @@ def transpose_overflow_stats(batches) -> dict:
     }
 
 
+def conv_shape_gauges(params, dense_m: int | None) -> dict:
+    """The three sizes that price a conv on the chip and that no other
+    counter says: ``conv_row_lanes`` (2F, the width of the row every gather
+    of the conv moves; 128 lanes are one tile), ``edge_gaussians`` (the
+    contraction of the one per-edge matmul) and ``dense_m`` (slots a node;
+    0 = COO). Read off ``fc_full``'s kernel, [2F + G, 2F] in every model of
+    this repo; levels, so gauges (``fit`` and the benchmark's kinds set
+    them beside ``staged_bytes``)."""
+    kernel = params.get("conv_0", {}).get("fc_full", {}).get("kernel")
+    if kernel is None:
+        return {}
+    rows, lanes = (int(d) for d in np.shape(kernel))
+    return {"conv_row_lanes": lanes, "edge_gaussians": rows - lanes,
+            "dense_m": int(dense_m or 0)}
+
+
 def _staging_args(batches: list) -> dict:
     """Args of the ``scan.stage`` span. ``bytes`` is what the host hands
     over: under a mesh the batches carry the device axis, so it is the
@@ -1137,6 +1153,8 @@ def fit(
         )
 
     telemetry = telemetry or Telemetry.disabled()
+    for name, level in conv_shape_gauges(state.params, dense_m).items():
+        telemetry.set_gauge(name, level)
     # raw step BODIES (shared by the per-step jits below and the scan
     # driver, which stages its own in-scan tap); default steps compute
     # grad health in-graph at step-level telemetry — extra metric outputs

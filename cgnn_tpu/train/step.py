@@ -24,8 +24,9 @@ from cgnn_tpu.observe import phases
 from cgnn_tpu.train.state import TrainState
 
 
-def regression_loss(out, batch: GraphBatch, normalizer):
-    """Masked MSE on normalized targets; metrics in original units.
+def _masked_regression(out, batch: GraphBatch, normalizer, penalty):
+    """Mean over the real labels of ``penalty(out - normalized target)``;
+    metrics in original units.
 
     Multi-task outputs (T > 1, BASELINE config #3) additionally report one
     MAE per task column, each averaged over its own label count (labels can
@@ -33,16 +34,31 @@ def regression_loss(out, batch: GraphBatch, normalizer):
     """
     t_norm = normalizer.norm(batch.targets)
     w = batch.target_mask * batch.graph_mask[:, None]
-    se = (out - t_norm) ** 2 * w
+    cost = penalty(out - t_norm) * w
     n = jnp.maximum(w.sum(), 1.0)
-    loss = se.sum() / n
+    loss = cost.sum() / n
     ae = jnp.abs(normalizer.denorm(out) - batch.targets) * w
-    metrics = {"loss_sum": se.sum(), "mae_sum": ae.sum(), "count": w.sum()}
+    metrics = {"loss_sum": cost.sum(), "mae_sum": ae.sum(), "count": w.sum()}
     if out.shape[-1] > 1:
         for t in range(out.shape[-1]):
             metrics[f"mae_task{t}_sum"] = ae[:, t].sum()
             metrics[f"mae_task{t}_count"] = w[:, t].sum()
     return loss, metrics
+
+
+def regression_loss(out, batch: GraphBatch, normalizer):
+    """Masked MSE on normalized targets (the lineage's ``nn.MSELoss``)."""
+    return _masked_regression(out, batch, normalizer, lambda d: d ** 2)
+
+
+def l1_regression_loss(out, batch: GraphBatch, normalizer):
+    """Masked L1 on normalized targets (the Open Catalyst baselines train
+    on the mean absolute error); ``loss_sum`` is then the sum of |d|."""
+    return _masked_regression(out, batch, normalizer, jnp.abs)
+
+
+# train.py --loss -> the regression task's loss
+REGRESSION_LOSSES = {"mse": regression_loss, "l1": l1_regression_loss}
 
 
 def classification_loss(out, batch: GraphBatch, normalizer):

@@ -421,6 +421,11 @@ _CLASSIFIED = [
      "select_n", ("conv.bn2", "fwd")),
     (_BWD + "conv_0/bn2/add_any", ("conv.bn2", "bwd")),
     (_FWD + "conv_1/conv.bn2/jit(softplus)/log1p", ("conv.bn2", "fwd")),
+    # LayerNorm after the sum in bn2's place (the Open Catalyst CGCNN): the
+    # flax module "ln", and the scope its residual and softplus run under
+    (_FWD + "conv_4/ln/rsqrt", ("conv.ln", "fwd")),
+    (_BWD + "conv_4/ln/mul", ("conv.ln", "bwd")),
+    (_FWD + "conv_5/conv.ln/jit(softplus)/log1p", ("conv.ln", "fwd")),
     ("jit(train_step)/jvp(CrystalGraphConvNet)/conv_0/fc_full/dot_general",
      ("conv.fc_full", "fwd")),
     ("jit(train_step)/transpose(jvp(CrystalGraphConvNet))/conv_1/fc_full/"
@@ -527,8 +532,8 @@ class TestPhases:
         seen = {want for _, want in _CLASSIFIED}
         assert {p for p, _ in seen} == set(phases.PHASES)
         two_way = {"conv.gather", "conv.fc_full", "conv.bn1", "conv.gate",
-                   "conv.aggregate", "conv.bn2", "embed", "pool_head",
-                   "loss", "edge_geom", "force_readout"}
+                   "conv.aggregate", "conv.bn2", "conv.ln", "embed",
+                   "pool_head", "loss", "edge_geom", "force_readout"}
         assert {p for p, d in seen if d == "bwd"} == two_way
         # what the force step differentiates twice: the trunk without
         # BatchNorm, the geometry and the readout (not the embedding, which

@@ -339,7 +339,7 @@ def _conv_case(mapping):
     return batch, m
 
 
-def _conv_pair(batch, m, jdt, f, batchnorm):
+def _conv_pair(batch, m, jdt, f, norm):
     """The dense conv and the COO conv with ONE set of variables (the COO
     conv's, moved off their (0, 1) initial values so that eval mode and the
     EMA update are not trivial), and the arguments each is called with."""
@@ -350,8 +350,12 @@ def _conv_pair(batch, m, jdt, f, batchnorm):
     nodes = jnp.asarray(
         rng.normal(size=(node_mask.shape[0], f)).astype(np.float32)
     ) * node_mask[:, None]
-    dense = CGConv(features=f, dtype=jdt, dense_m=m, use_batchnorm=batchnorm)
-    coo = CGConv(features=f, dtype=jdt, use_batchnorm=batchnorm)
+    # ``norm``: True (bn1 and bn2), False (neither, the force model's conv)
+    # or "layernorm" (bn1, and LayerNorm after the sum: the Open Catalyst one)
+    kw = dict(use_batchnorm=bool(norm),
+              node_norm={True: "batch", False: "none"}.get(norm, "layer"))
+    dense = CGConv(features=f, dtype=jdt, dense_m=m, **kw)
+    coo = CGConv(features=f, dtype=jdt, **kw)
 
     def args(edges):
         return (edges, batch.centers, batch.neighbors, batch.edge_mask,
@@ -381,8 +385,8 @@ _MAPPINGS = ["none", "single-tier", "two-tier", "two-tier-snug-run",
              "two-tier-empty"]
 
 
-@pytest.mark.parametrize("batchnorm", [True, False],
-                         ids=["batchnorm", "no-batchnorm"])
+@pytest.mark.parametrize("batchnorm", [True, False, "layernorm"],
+                         ids=["batchnorm", "no-batchnorm", "layernorm"])
 @pytest.mark.parametrize("mapping", _MAPPINGS)
 @pytest.mark.parametrize("mode", ["train", "eval"])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -395,8 +399,8 @@ def test_dense_conv_matches_coo_conv(dtype, mode, mapping, batchnorm):
     gradients w.r.t. the input nodes and every parameter and the updated
     bn1 / bn2 running statistics, with and without the packed transpose
     mapping (and its overflow tier) behind the gather's backward, with
-    BatchNorm (``mp.train``, ``oc20.train``) and without (the force
-    model's conv).
+    BatchNorm (``mp.train``, ``oc20.train``), without (the force model's
+    conv) and with LayerNorm after the sum in bn2's place (``ocp.train``).
 
     float32 tolerances are those of the kernel tests this replaces (2e-5
     on values, 5e-4 on gradients). In bfloat16 the two bodies round
@@ -448,15 +452,17 @@ def test_dense_conv_matches_coo_conv(dtype, mode, mapping, batchnorm):
     flat = lambda tree: sorted(  # noqa: E731
         (jax.tree_util.keystr(k), v)
         for k, v in jax.tree_util.tree_leaves_with_path(tree))
-    assert len(flat(stats_c)) == (4 if batchnorm else 0)
+    assert len(flat(stats_c)) == {True: 4, False: 0, "layernorm": 2}[
+        batchnorm]
     for (ka, a), (kb, b) in zip(flat(stats_d), flat(stats_c)):
         assert ka == kb
         close(a, b, 1e-4, 1e-5, f"running statistics {ka}")
     (gp_d, gx_d), (gp_c, gx_c) = g_d, g_c
     assert float(np.abs(np.asarray(gx_c)).max()) > 0.1
     close(gx_d, gx_c, 5e-4, 5e-5, "gradient w.r.t. nodes")
-    # kernel/scale and bias of fc_full, bn1, bn2
+    # kernel/scale and bias of fc_full, bn1, bn2 (or ln)
     assert len(flat(gp_d)) == (6 if batchnorm else 2)
+    assert ("ln" in gp_d) == (batchnorm == "layernorm")
     for module in gp_c:
         # a module's leaves share one bf16 scale: under BatchNorm fc_full's
         # bias gradient is zero in exact arithmetic (BN1 removes what a

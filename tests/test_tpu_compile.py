@@ -114,6 +114,85 @@ def _full_staging_scan_program(one_chip, task: str, dtype: str):
     return text, graphs, batches, node_cap
 
 
+@functools.cache  # three tests read it
+def _ocp_scan_program(one_chip):
+    """Cell ``ocp.train``'s largest scan program (a chunk of four steps over
+    the larger bucket's resident stack) at the cell's real size, assembled by
+    the cell's own kind (``benchmark/kinds/ocp_train.py``: compact staging,
+    snug packing, 2 buckets, the guard, Adam on the L1 loss, the published
+    widths in bfloat16) and compiled for the described chip -> (optimized HLO
+    text, the bucket's batch, node capacity, F, M, convs, Gaussians,
+    compiled).
+
+    The pool is 64 slabs, not the cell's 2,048 (4 GB of edge features on the
+    host): a bucket's snug node capacity is 32 times its slabs' mean size,
+    which 32 slabs give to ~10% (5,504 here, 5,008 in the cell). The stack
+    is lowered at the cell's length, 32 batches x its resident copies."""
+    import copy
+    import os
+
+    from benchmark import run, system
+    from benchmark.kinds import ocp_train
+    from cgnn_tpu.data import dataset
+    from cgnn_tpu.train import loop
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cell = run.Cell(os.path.join(root, "BENCHMARK.json"), "ocp.train")
+    cell.config = cfg = copy.deepcopy(cell.config)
+    copies = int(cfg["data"]["resident_copies"])
+    cfg["data"].update(n=64, resident_copies=1)
+    graphs = dataset.load_synthetic_oc20_ocp(
+        64, system.featurize_config(cfg), seed=0)
+    bench = ocp_train.Driver(run.Context(cell, 0, False))
+    bench._first_steps = lambda state: state
+    # set-up builds the scan driver itself: its warm-up (a whole epoch) and
+    # the pool's cache file are switched off while it runs
+    warm, load_pool = loop.ScanEpochDriver.warm, system.load_pool
+    loop.ScanEpochDriver.warm = lambda self, state: state
+    system.load_pool = lambda config: (graphs, {"built": True,
+                                                "seconds": 0.0})
+    try:
+        bench.setup()
+    finally:
+        loop.ScanEpochDriver.warm, system.load_pool = warm, load_pool
+    drv, steps = bench.driver, 4
+
+    def node_capacity(key):
+        return int(loop.program_name((key, steps), True)
+                   .split("_n")[1].split("_")[0])
+
+    key = max(drv._train_groups, key=node_capacity)
+    stacked = drv._train_groups[key]
+    fn = drv._scan_fn(drv._train_scans, (key, steps), drv._train_body, True)
+    stack = 32 * copies
+
+    def shape(x, lead=None):
+        dims = np.shape(x) if lead is None else (lead,) + np.shape(x)[1:]
+        return jax.ShapeDtypeStruct(dims, x.dtype, sharding=one_chip)
+
+    compiled = fn.lower(
+        jax.tree_util.tree_map(shape, bench.state),
+        jax.tree_util.tree_map(lambda x: shape(x, stack), stacked),
+        shape(np.zeros(steps, np.int32))).compile()
+    batch = jax.tree_util.tree_map(lambda x: x[0], stacked)
+    m = cfg["model"]
+    return (compiled.as_text(), batch, node_capacity(key),
+            int(m["atom_fea_len"]), int(cfg["layout"]["dense_m"]),
+            int(m["n_conv"]), int(m["num_gaussians"]), compiled)
+
+
+def _conv_program(one_chip, task: str, dtype: str):
+    """-> (optimized HLO text, one batch of the program's stack, node
+    capacity, F, M, convs, Gaussians) for the tiny full-staging programs
+    and, as task 'ocp', for ``ocp.train``'s largest at real size."""
+    if task == "ocp":
+        return _ocp_scan_program(one_chip)[:7]
+    text, _graphs, batches, node_cap = _full_staging_scan_program(
+        one_chip, task, dtype)
+    return (text, batches[0], node_cap, 16, 12, 2,
+            batches[0].edges.shape[-1])
+
+
 @pytest.mark.parametrize("task,dtype", [
     ("force", "bfloat16"), ("force", "float32"), ("regression", "bfloat16")])
 def test_full_staging_scan_program_converts_no_resident_stack(
@@ -194,13 +273,15 @@ def test_force_geometry_reads_the_dense_layout(one_chip, no_compile_cache):
 
 
 @pytest.mark.parametrize("task,dtype", [
-    ("regression", "bfloat16"), ("force", "float32")])
+    ("regression", "bfloat16"), ("force", "float32"), ("ocp", "bfloat16")])
 def test_no_scatter_under_the_conv_gather(one_chip, no_compile_cache, task,
                                           dtype):
     """The pin that the gather's declared transpose (ops/segment.py
     _transpose_cotangent) holds no scatter in the program the chip runs
-    (PR 33), for the ``mp-flagship`` trunk (bfloat16, BatchNorm) and the
-    ``md17-force`` one (float32, two reverse passes): tier 1 is a row gather
+    (PR 33), for the ``mp-flagship`` trunk (bfloat16, BatchNorm), the
+    ``md17-force`` one (float32, two reverse passes) and ``ocp.train``'s
+    largest program at its real size (rows of 768 lanes, 50 slots a node,
+    six convs; PR 35): tier 1 is a row gather
     and a masked sum, the overflow tier a row gather, one batched matmul
     that sums each node's run of the list, and a row gather through
     ``over_last``. The sorted scatter-add that XLA made of the tier's
@@ -210,9 +291,8 @@ def test_no_scatter_under_the_conv_gather(one_chip, no_compile_cache, task,
     and stays: it shows that the count below can see a scatter."""
     from cgnn_tpu.observe import phases
 
-    text, _graphs, batches, node_cap = _full_staging_scan_program(
-        one_chip, task, dtype)
-    n_blocks = batches[0].over_slots.shape[0] // 128 + 1
+    text, batch, _node_cap, *_sizes = _conv_program(one_chip, task, dtype)
+    n_blocks = batch.over_slots.shape[0] // 128 + 1
 
     scatters, run_sums = {}, []
     for comp in phases._parse(text).values():
@@ -238,7 +318,7 @@ def test_no_scatter_under_the_conv_gather(one_chip, no_compile_cache, task,
 
 
 @pytest.mark.parametrize("task,dtype", [
-    ("regression", "bfloat16"), ("force", "float32")])
+    ("regression", "bfloat16"), ("force", "float32"), ("ocp", "bfloat16")])
 def test_no_matmul_over_gathered_rows(one_chip, no_compile_cache, task,
                                       dtype):
     """The pin that fc_full's neighbour term is projected before the gather
@@ -256,16 +336,13 @@ def test_no_matmul_over_gathered_rows(one_chip, no_compile_cache, task,
     their halo, and one row a node (its run's total; PR 33)."""
     from cgnn_tpu.observe import phases
 
-    text, _graphs, batches, node_cap = _full_staging_scan_program(
+    text, batch, node_cap, f, m, n_convs, gauss = _conv_program(
         one_chip, task, dtype)
-    f, m = 16, 12  # the helper's model and layout
-    edge_cap, over_cap = node_cap * m, batches[0].over_slots.shape[0]
-    halo = -(-(batches[0].over_runs.shape[0] - 1) // 8) * 8
+    edge_cap, over_cap = node_cap * m, batch.over_slots.shape[0]
+    halo = -(-(batch.over_runs.shape[0] - 1) // 8) * 8
     over_rows = (over_cap // 128 + 1) * (halo + 128)  # _run_totals' windows
     assert len({edge_cap, over_rows, node_cap}) == 3  # told apart by size
-    gauss = batches[0].edges.shape[-1]
     assert len({f, 2 * f, gauss}) == 3  # E rows are told apart by width
-    n_convs = 2
 
     def elements(dims):
         return int(np.prod([int(d) for d in dims.split(",") if d]))
@@ -293,13 +370,42 @@ def test_no_matmul_over_gathered_rows(one_chip, no_compile_cache, task,
                 gathers.append((direction, dims))
     # three kernel slices a conv forward; their transposes in each reverse
     # pass (the force step has two)
-    assert matmuls >= 3 * n_convs * (2 if task == "regression" else 3)
+    assert matmuls >= 3 * n_convs * (3 if task == "force" else 2)
     assert not over_edge_rows, over_edge_rows
     rows = {f"{edge_cap},{2 * f}", f"{over_rows},{2 * f}",
             f"{node_cap},{2 * f}"}
     assert {dims for _, dims in gathers} == rows, gathers
     assert [d for d in gathers if d[0] == phases.FWD] == [
         (phases.FWD, f"{edge_cap},{2 * f}")] * n_convs
+
+
+def test_ocp_scan_program_at_real_size_fits_the_chip(one_chip,
+                                                     no_compile_cache):
+    """``ocp.train``'s largest program compiles for the described chip at
+    batch 32 and, with the resident stack it is handed (the larger bucket's
+    half of the pool's resident copies), takes between a third and three
+    fifths of the chip's 17.18e9 B: the slabs and six convs' [N, 50, 768]
+    activations kept for the reverse pass (ISSUE 35 reckoned 0.9 MB an atom,
+    5-7 GB in the larger bucket; the compile says 1.2 MB an atom at a node
+    capacity of 5,504). This is the cell's real footprint: the chip's own
+    ``peak_bytes_in_use`` does not count those temporaries (PR 35's first
+    run read 0.94 GB with 0.53 GB staged; PERF.md section 4). Batch 64 would
+    not fit beside them."""
+    (_text, _batch, node_cap, f, m, n_convs, _gauss,
+     compiled) = _ocp_scan_program(one_chip)
+    assert (f, m, n_convs) == (384, 50, 6)
+    assert 4500 < node_cap < 6200  # the real size: ~160 atoms a slab x 32
+    mem = compiled.memory_analysis()
+    on_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"ocp.train's largest program: node capacity {node_cap}, "
+          f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB "
+          f"({mem.temp_size_in_bytes / node_cap / 1e6:.2f} MB an atom), "
+          f"arguments {mem.argument_size_in_bytes / 1e9:.2f} GB, "
+          f"{on_chip / 1e9:.2f} GB on the chip "
+          f"({100 * on_chip / 17.18e9:.1f}%)")
+    assert 0.33 * 17.18e9 < on_chip < 0.60 * 17.18e9, on_chip
+    assert 0.8e6 < mem.temp_size_in_bytes / node_cap < 1.4e6
 
 
 def _four_chip_program(mesh):

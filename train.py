@@ -63,6 +63,16 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--h-fea-len", type=int, default=128)
     p.add_argument("--n-conv", type=int, default=3)
     p.add_argument("--n-h", type=int, default=1)
+    p.add_argument("--node-norm", choices=["batch", "layer"],
+                   default="batch",
+                   help="normalisation after each conv's neighbour sum: "
+                        "BatchNorm (the lineage's bn2) or LayerNorm over a "
+                        "node's features (the Open Catalyst CGCNN)")
+    p.add_argument("--no-pool-softplus", action="store_true",
+                   help="no softplus on the pooled vector before conv_to_fc "
+                        "(the Open Catalyst CGCNN has none)")
+    p.add_argument("--loss", choices=["mse", "l1"], default="mse",
+                   help="regression loss on the standardised targets")
     p.add_argument("--dropout", type=float, default=0.0)
     p.add_argument("--num-classes", type=int, default=2)
     p.add_argument("--multi-task-head", action="store_true",
@@ -73,6 +83,9 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--radius", type=float, default=8.0)
     p.add_argument("--dmin", type=float, default=0.0)
     p.add_argument("--step", type=float, default=0.2)
+    p.add_argument("--gauss-var", type=float, default=None, metavar="W",
+                   help="the Gaussians' width in exp(-(d - mu)^2 / W^2) "
+                        "(default: --step)")
     # input pipeline
     p.add_argument("--cache", type=str, default="",
                    help="graph cache (.npz): loaded if present, else written "
@@ -331,7 +344,7 @@ def main(argv=None) -> int:
 
     data_cfg = DataConfig(
         radius=args.radius, max_num_nbr=args.max_num_nbr,
-        dmin=args.dmin, step=args.step,
+        dmin=args.dmin, step=args.step, var=args.gauss_var,
     )
     t0 = time.perf_counter()
     # trajectory grouping for the force task's leak-aware split (frames of
@@ -470,6 +483,7 @@ def main(argv=None) -> int:
         classification=classification, num_classes=args.num_classes,
         dropout=args.dropout, dtype="bfloat16" if args.bf16 else "float32",
         multi_task_head=args.multi_task_head, dense_m=dense_m,
+        node_norm=args.node_norm, pool_softplus=not args.no_pool_softplus,
     )
     graph_shards = max(1, args.graph_shards)
     if graph_shards > 1:
@@ -647,6 +661,20 @@ def main(argv=None) -> int:
 
         eval_step_fn = make_force_eval_step(args.energy_weight, args.force_weight)
         step_overrides = {"best_metric": "force_mae"}
+    loss_fn = None
+    if args.loss != "mse":
+        if args.task != "regression":
+            print(f"--loss {args.loss} is the regression task's",
+                  file=sys.stderr)
+            return 2
+        from cgnn_tpu.train.step import (
+            REGRESSION_LOSSES,
+            make_eval_step,
+            make_train_step,
+        )
+
+        loss_fn = REGRESSION_LOSSES[args.loss]
+        eval_step_fn = make_eval_step(loss_fn=loss_fn)
 
     def choose_compact() -> int:
         """Decide the staged form, for one chip and for a 'data' mesh alike
@@ -721,6 +749,14 @@ def main(argv=None) -> int:
                     args.energy_weight, args.force_weight, axis_name="data"
                 ),
             }
+        if loss_fn is not None:
+            step_overrides |= {
+                "train_step_fn": make_train_step(
+                    axis_name="data", loss_fn=loss_fn,
+                    grad_health=telemetry.step_level),
+                "eval_step_fn": make_eval_step(axis_name="data",
+                                               loss_fn=loss_fn),
+            }
         fit_state, result = fit_data_parallel(
             fit_state, train_g, val_g, epochs=args.epochs,
             batch_size=args.batch_size,
@@ -748,6 +784,12 @@ def main(argv=None) -> int:
                     args.energy_weight, args.force_weight,
                     grad_health=telemetry.step_level,
                 ),
+                "eval_step_fn": eval_step_fn,
+            }
+        if loss_fn is not None:
+            step_overrides |= {
+                "train_step_fn": make_train_step(
+                    loss_fn=loss_fn, grad_health=telemetry.step_level),
                 "eval_step_fn": eval_step_fn,
             }
         rc = choose_compact()
