@@ -99,8 +99,21 @@ class MaskedBatchNorm(nn.Module):
                 # last), and correctness never depends on the choice of c —
                 # only the cancellation magnitude does. The subtract fuses
                 # into the same single read of x.
+                #
+                # The slice is taken from x in x's OWN dtype and converted
+                # after it (bfloat16 -> float32 is exact and commutes with
+                # a slice: the value is the same bit for bit). Slicing the
+                # converted whole array instead makes XLA hoist that convert
+                # into the producer of x as a second output: the fusion
+                # that writes bfloat16 z then writes a float32 [N, M, 2F]
+                # copy beside it, 769 MB a conv in ocp.train, read back for
+                # its first 150 KB (PERF.md section 6, PR 37). Pinned by
+                # tests/test_tpu_compile.py
+                # test_no_float32_copy_of_z_is_written_beside_z and
+                # tests/test_ops.py test_bn_shift_slices_x_before_it_converts.
                 shift = jax.lax.stop_gradient(
-                    xf[:1].mean(axis=tuple(range(xf.ndim - 1)))
+                    x[:1].astype(stat_dtype).mean(
+                        axis=tuple(range(x.ndim - 1)))
                 )
                 if self.axis_name is not None:
                     # shards must agree on c or their (s1, s2) can't be

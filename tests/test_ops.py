@@ -260,10 +260,15 @@ def test_one_pass_bn_matches_two_pass_reference():
     )
 
 
-def test_one_pass_bn_high_mean_no_cancellation():
+@pytest.mark.parametrize("dims", [(1024, 4), (128, 8, 4)],
+                         ids=["RxC", "NxMxC"])
+def test_one_pass_bn_high_mean_no_cancellation(dims):
     """|mean| >> std regime: unshifted f32 E[x^2]-E[x]^2 loses all variance
     bits (var clamps to 0 and rsqrt(eps) AMPLIFIES by ~300x); the
-    shift-invariant accumulation must keep the output unit-variance.
+    shift-invariant accumulation must keep the output unit-variance, for
+    bn2's rows and bn1's [N, M, C], and the variance that reaches the
+    running statistics is finite and right: the shift's reason to exist
+    (it is read off the leading row-block of x, ops/norm.py).
     Advisor finding r3 (ops/norm.py one-pass cancellation)."""
     import jax
 
@@ -272,23 +277,156 @@ def test_one_pass_bn_high_mean_no_cancellation():
     rng = np.random.default_rng(1)
     # mean 1e4, std 1: mean^2/var = 1e8 > 2^24 — guaranteed f32
     # cancellation without a shift
-    x = (1e4 + rng.normal(0.0, 1.0, size=(1024, 4))).astype(np.float32)
-    mask = np.ones(1024, np.float32)
-    mask[900:] = 0.0
+    x = (1e4 + rng.normal(0.0, 1.0, size=dims)).astype(np.float32)
+    mask = np.ones(dims[:-1], np.float32)
+    mask[dims[0] * 7 // 8:] = 0.0
 
     bn = MaskedBatchNorm()
     variables = bn.init(jax.random.key(0), x, mask=mask)
-    y, _ = bn.apply(
+    y, mutated = bn.apply(
         variables, x, mask=mask, use_running_average=False,
         mutable=["batch_stats"],
     )
     rows = x[mask > 0].astype(np.float64)
     ref = (x.astype(np.float64) - rows.mean(0)) / np.sqrt(rows.var(0) + 1e-5)
-    got = np.asarray(y)[:900]
+    got = np.asarray(y)[mask > 0]
     # unit-scale output, not a 300x blowup; tolerance is loose because the
     # data itself carries only ~3 significant fractional digits in f32
-    np.testing.assert_allclose(got, ref[:900], atol=5e-2)
+    np.testing.assert_allclose(got, ref[mask > 0], atol=5e-2)
     assert float(np.abs(got).max()) < 10.0
+    var = np.asarray(mutated["batch_stats"]["var"])
+    assert np.isfinite(var).all()
+    np.testing.assert_allclose(var, 0.9 + 0.1 * rows.var(0, ddof=1),
+                               rtol=1e-3)
+
+
+_BN_CASES = pytest.mark.parametrize(
+    "dtype,shape,masked,sharded",
+    [pytest.param(d, s, m, a, id=f"{d}-{s}-{'masked' if m else 'unmasked'}-"
+                  f"{'axis_name' if a else 'one-device'}")
+     for d in ("float32", "bfloat16") for s in ("RxC", "NxMxC")
+     for m in (True, False) for a in (False, True)])
+
+
+def _bn_case(dtype, shape, masked, sharded):
+    """-> (x, mask or None, weights of a loss, variables, ``run``) for
+    train-mode ``MaskedBatchNorm`` over rows [R, C] or the conv's [N, M, C]; sharded:
+    two shards on a leading axis, the module under ``axis_name`` inside a
+    ``vmap`` of that name (the moments are the global ones, as under a
+    mesh). ``run(x)`` -> (output, updated running statistics)."""
+    rng = np.random.default_rng(7)
+    dims = {"RxC": (96, 8), "NxMxC": (24, 4, 8)}[shape]
+    dims = ((2,) if sharded else ()) + dims
+    x = jnp.asarray(rng.normal(1.5, 2.0, size=dims).astype(np.float32),
+                    jnp.dtype(dtype))
+    mask = (jnp.asarray((rng.random(dims[:-1]) > 0.3).astype(np.float32))
+            if masked else None)
+    weights = jnp.asarray(rng.normal(size=dims).astype(np.float32))
+    variables = MaskedBatchNorm().init(jax.random.key(0),
+                                       x[0] if sharded else x)
+    mod = MaskedBatchNorm(axis_name="data" if sharded else None)
+
+    def one(variables, x, mask):
+        return mod.apply(variables, x, mask=mask, use_running_average=False,
+                         mutable=["batch_stats"])
+
+    def run(x, variables=variables):
+        if not sharded:
+            return one(variables, x, mask)
+        return jax.vmap(one, in_axes=(None, 0, 0), axis_name="data")(
+            variables, x, mask)
+
+    return x, mask, weights, variables, run
+
+
+def _eqns(jaxpr):
+    """(jaxpr, equation) for every equation of a jaxpr and of the jaxprs in
+    its equations' parameters."""
+    for eqn in jaxpr.eqns:
+        yield jaxpr, eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@_BN_CASES
+def test_bn_shift_slices_x_before_it_converts(dtype, shape, masked, sharded):
+    """The shift of the one-pass moments is the mean of x's leading
+    row-block. It must be sliced from x in x's own dtype and converted after
+    the slice: a slice of the whole array converted to float32 makes XLA
+    hoist that convert into the producer of x, which then writes a float32
+    copy of the whole activation beside it (769 MB a conv in ``ocp.train``,
+    read back for 150 KB; PR 37, pinned on the compiled program by
+    tests/test_tpu_compile.py
+    test_no_float32_copy_of_z_is_written_beside_z). Here, on the jaxpr: one
+    slice reads x itself, and none reads a whole-array
+    ``convert_element_type`` (float32 inputs have no convert to misplace:
+    their cases hold the first half)."""
+    x, _mask, _w, _variables, run = _bn_case(dtype, shape, masked, sharded)
+    block = list(x.shape)
+    block[1 if sharded else 0] = 1
+    slices_of_x, slices_of_a_convert = 0, []
+    for jaxpr, eqn in _eqns(jax.make_jaxpr(run)(x).jaxpr):
+        if (eqn.primitive.name not in ("slice", "dynamic_slice", "gather")
+                or list(eqn.outvars[0].aval.shape) != block):
+            continue
+        source = eqn.invars[0]
+        assert source.aval.shape == x.shape
+        slices_of_x += source.aval.dtype == x.dtype
+        made_by = [e for e in jaxpr.eqns if source in e.outvars]
+        if made_by and made_by[0].primitive.name == "convert_element_type":
+            slices_of_a_convert.append(str(made_by[0]))
+    assert slices_of_x == 1
+    assert not slices_of_a_convert, slices_of_a_convert
+
+
+@_BN_CASES
+def test_one_pass_bn_agrees_with_the_two_pass_form(dtype, shape, masked,
+                                                   sharded):
+    """Outputs, updated running statistics and the gradient of a masked loss
+    (w.r.t. x, scale and bias) of the one-pass moments with their shift
+    against the centered two-pass form (``force_two_pass_stats``), for the
+    [R, C] rows of bn2 and the [N, M, C] of bn1, with and without a mask,
+    on one device and under ``axis_name`` (where the shift is ``pmean``-ed
+    and the sums ``psum``-ed). Tolerances: the file's own (2e-4 in float32,
+    3e-2 of the largest reference entry in bfloat16, where both forms round
+    the same float32 result to 8 bits)."""
+    from cgnn_tpu.ops.norm import force_two_pass_stats
+
+    x, mask, weights, variables, run = _bn_case(dtype, shape, masked,
+                                                sharded)
+    keep = 1.0 if mask is None else mask[..., None]
+
+    def loss(params, x):
+        y, mutated = run(x, {**variables, "params": params})
+        y = y.astype(jnp.float32) * keep
+        return (y * weights).sum() + ((y * weights) ** 2).sum(), (
+            y, mutated["batch_stats"])
+
+    def both():
+        (_, (y, stats)), grads = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)(variables["params"], x)
+        return y, stats, grads
+
+    got = both()
+    force_two_pass_stats(True)
+    try:
+        want = both()
+    finally:
+        force_two_pass_stats(False)
+
+    def close(a, b, what):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.isfinite(b).all() and float(np.abs(b).max()) > 1e-3, what
+        tol = ({"rtol": 2e-4, "atol": 2e-4} if dtype == "float32" else
+               {"rtol": 0.0, "atol": 3e-2 * float(np.abs(b).max())})
+        np.testing.assert_allclose(a, b, err_msg=what, **tol)
+
+    close(got[0], want[0], "outputs")
+    for name in ("mean", "var"):
+        close(got[1][name], want[1][name], f"running {name}")
+    close(got[2][1], want[2][1], "gradient w.r.t. x")
+    for name in ("scale", "bias"):
+        close(got[2][0][name], want[2][0][name], f"gradient {name}")
 
 
 def _conv_case(mapping):

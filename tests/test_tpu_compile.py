@@ -68,6 +68,11 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
+# opcodes that hand an array on and compute nothing
+_MOVED = ("parameter", "get-tuple-element", "bitcast", "tuple", "while",
+          "copy-start", "copy-done")
+
+
 @functools.cache  # two tests read the float32 force program
 def _full_staging_scan_program(one_chip, task: str, dtype: str):
     """The scan program ``fit`` builds under full staging (chunk of two
@@ -216,14 +221,17 @@ def test_full_staging_scan_program_converts_no_resident_stack(
 
     stack, feat = len(batches), graphs[0].atom_fea.shape[1]
     assert stack > 2  # a stack-sized shape is no step-sized one
-    moved = ("parameter", "get-tuple-element", "bitcast", "tuple",
-             "copy-start", "copy-done")  # hand the stack on, compute nothing
     computed = [
         ln.strip()[:160] for ln in text.splitlines()
         if re.match(rf"\s+(ROOT )?%?[\w.\-]+ = \w+\[{stack},{node_cap},{feat}\]",
                     ln)
-        and not re.search(r" (" + "|".join(moved) + r")\(", ln)]
+        and not re.search(r" (" + "|".join(_MOVED) + r")\(", ln)]
     assert not computed, computed
+
+
+def _elements(dims: str) -> int:
+    """'5504,50,768' -> the number of elements."""
+    return int(np.prod([int(d) for d in dims.split(",") if d]))
 
 
 # an instruction after its ``name = ``: type, dimensions, opcode, operands
@@ -344,9 +352,6 @@ def test_no_matmul_over_gathered_rows(one_chip, no_compile_cache, task,
     assert len({edge_cap, over_rows, node_cap}) == 3  # told apart by size
     assert len({f, 2 * f, gauss}) == 3  # E rows are told apart by width
 
-    def elements(dims):
-        return int(np.prod([int(d) for d in dims.split(",") if d]))
-
     matmuls, over_edge_rows, gathers = 0, [], []
     for comp in phases._parse(text).values():
         parsed = {name: m_.groups() for name, rest in comp["instrs"].items()
@@ -359,8 +364,8 @@ def test_no_matmul_over_gathered_rows(one_chip, no_compile_cache, task,
             phase, direction = phases.classify(op_name.group(1))
             if op in ("dot", "convolution") and phase == phases.CONV_FC_FULL:
                 matmuls += 1
-                sizes = [elements(dims)] + [
-                    elements(parsed[o][1])
+                sizes = [_elements(dims)] + [
+                    _elements(parsed[o][1])
                     for o in phases._OPERAND.findall(
                         operands.partition(")")[0]) if o in parsed]
                 assert len(sizes) == 3, rest[:200]  # both operands found
@@ -377,6 +382,82 @@ def test_no_matmul_over_gathered_rows(one_chip, no_compile_cache, task,
     assert {dims for _, dims in gathers} == rows, gathers
     assert [d for d in gathers if d[0] == phases.FWD] == [
         (phases.FWD, f"{edge_cap},{2 * f}")] * n_convs
+
+
+def _result_type(rest: str) -> str:
+    """The type an instruction writes, a tuple's members and all: what
+    stands before its opcode (a layout's ``T(8,128)`` follows no blank)."""
+    return rest[:re.search(r"\s[a-z][\w\-]*\(", rest).start()]
+
+
+_ARRAY = re.compile(r"\b([a-z]+\d+)\[([\d,]*)\]")
+
+
+@pytest.mark.parametrize("program", ["regression-bfloat16", "ocp-bfloat16",
+                                     "four-chip"])
+def test_no_float32_copy_of_z_is_written_beside_z(request, no_compile_cache,
+                                                  program):
+    """The pin that ``MaskedBatchNorm`` takes the shift of its one-pass
+    moments from a slice of x in x's own dtype (ops/norm.py, PR 37), for
+    the ``mp-flagship`` trunk at a tiny size, ``ocp.train``'s largest
+    program at its real size and ``mp.train-dp4``'s at real size on the
+    described host. With the slice taken from the converted whole array
+    (``x.astype(float32)[:1]``) XLA hoisted that convert into the producer
+    of z: the fusion of fc_full's edge term wrote ``(f32[N, M, 2F],
+    bf16[N, M, 2F])``, and the float32 output's one consumer read its first
+    row-block, 150 KB of 769 MB a conv in ``ocp.train``, six times a step,
+    9% of the device's time (PERF.md section 6). Now no instruction that the
+    device runs as an operation of its own (outside a fused computation)
+    writes a float32 array of N*M*2F elements or more, in either direction,
+    and every fusion under ``bn1`` that sums over the whole of z (the
+    moments; the reverse pass's sums) reads it in the compute dtype."""
+    from cgnn_tpu.observe import phases
+
+    if program == "four-chip":
+        compiled, node_cap, _stack, _row = _four_chip_program(
+            request.getfixturevalue("four_chips"))
+        text, f, m, n_convs = compiled.as_text(), 64, 12, 3
+    else:
+        text, _batch, node_cap, f, m, n_convs, _gauss = _conv_program(
+            request.getfixturevalue("one_chip"), *program.split("-"))
+    z = node_cap * m * 2 * f
+
+    comps = phases._parse(text)
+    fused = {target for comp in comps.values()
+             for rest in comp["instrs"].values()
+             for kind, target in phases._CALLED.findall(rest)
+             if kind == "calls" or " call(" not in rest}
+    float32_z, z_written, bn1_sums, bn1_wide = [], 0, 0, []
+    for cname, comp in comps.items():
+        if cname in fused:
+            continue
+        written = {name: _ARRAY.findall(_result_type(rest))
+                   for name, rest in comp["instrs"].items()}
+        for name, rest in comp["instrs"].items():
+            if re.search(r"\s(" + "|".join(_MOVED) + r")\(", rest):
+                continue
+            sizes = [(dt, _elements(dims)) for dt, dims in written[name]]
+            z_written += ("bf16", z) in sizes
+            if any(dt == "f32" and n >= z for dt, n in sizes):
+                float32_z.append(rest[:200])
+            op_name = phases._OP_NAME.search(rest)
+            if not (op_name and " fusion(" in rest and re.search(
+                    r"/bn1/.*reduce_sum", op_name.group(1))):
+                continue
+            operands = phases._OPERANDS.search(rest).group(1)
+            read = [(dt, _elements(dims))
+                    for o in phases._OPERAND.findall(operands)
+                    for dt, dims in written.get(o, [])]
+            # (the mask's sum slices the stacked u8 mask: not an activation)
+            bn1_sums += ("bf16", z) in read
+            bn1_wide += [rest[:200] for dt, n in read
+                         if n >= z and dt in ("f32", "f64")]
+    # the count can see: z itself is written a conv, and bn1's moments (s1
+    # and s2 in one fusion) read it a conv
+    assert z_written >= n_convs, z_written
+    assert bn1_sums >= n_convs, bn1_sums
+    assert not float32_z, float32_z
+    assert not bn1_wide, bn1_wide
 
 
 def test_ocp_scan_program_at_real_size_fits_the_chip(one_chip,
@@ -408,6 +489,7 @@ def test_ocp_scan_program_at_real_size_fits_the_chip(one_chip,
     assert 0.8e6 < mem.temp_size_in_bytes / node_cap < 1.4e6
 
 
+@functools.cache  # two tests read it
 def _four_chip_program(mesh):
     """Cell ``mp.train-dp4``'s largest scan program (chunk of four steps
     over the largest bucket's resident stack) at the cell's real size on the
