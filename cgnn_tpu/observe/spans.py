@@ -14,8 +14,12 @@ fixed relation to a device trace. So every ``span()`` also opens a
 ``jax.profiler.TraceAnnotation`` named ``cgnn:<name>``: while a profiler
 session runs, the same span lands on the host plane of the ``.xplane.pb``,
 on the clock the device lines are drawn on, and can be laid over them
-(outside a session the annotation records nothing). Retro-stamped
-``complete()`` spans and ``instant()`` s exist in the ring only.
+(outside a session the annotation records nothing). The annotation carries
+the scalar args the span was opened with (``epoch=3, chunk=17``) as the
+event's stats, so a ``cgnn:`` event of the xplane has the same ids as the
+ring's; what the block adds to the yielded dict on the way is in the ring
+only. Retro-stamped ``complete()`` spans and ``instant()`` s exist in the
+ring only.
 """
 
 from __future__ import annotations
@@ -31,14 +35,18 @@ from typing import Iterator
 from cgnn_tpu.observe.metrics_io import jsonfinite
 
 
-def _annotation(name: str):
-    """``name`` on the profiler's clock. A process that never imported
-    JAX (the fleet router) has no profiler session to land in, and stays
-    free of JAX."""
+def _annotation(name: str, args: dict):
+    """``name`` on the profiler's clock, with the scalars among ``args``
+    (the profiler encodes them only while a session runs). A process that
+    never imported JAX (the fleet router) has no profiler session to land
+    in, and stays free of JAX."""
     jax = sys.modules.get("jax")
     if jax is None:
         return contextlib.nullcontext()
-    return jax.profiler.TraceAnnotation("cgnn:" + name)
+    return jax.profiler.TraceAnnotation(
+        "cgnn:" + name,
+        **{k: v for k, v in args.items()
+           if isinstance(v, (bool, int, float, str))})
 
 
 class SpanTracer:
@@ -105,7 +113,7 @@ class SpanTracer:
         self._depth.value = depth + 1
         start = self._now_us()
         try:
-            with _annotation(name):
+            with _annotation(name, args):
                 yield args
         finally:
             self._depth.value = depth

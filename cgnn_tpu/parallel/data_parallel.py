@@ -822,11 +822,10 @@ def fit_data_parallel(
         train_loss = train_m.get("loss", np.nan)
 
         if driver is None:
-            with telemetry.span("eval", epoch=epoch):
-                _, val_m = run_epoch(
-                    eval_step, state, val_it, train=False, epoch=epoch,
-                    log_fn=log_fn, telemetry=telemetry,
-                )
+            _, val_m = run_epoch(
+                eval_step, state, val_it, train=False, epoch=epoch,
+                log_fn=log_fn, telemetry=telemetry,
+            )
         best_key = best_metric or ("correct" if classification else "mae")
         metric = val_m.get(best_key, np.nan)
         is_best = metric > best if classification else metric < best

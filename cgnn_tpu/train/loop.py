@@ -558,6 +558,9 @@ class ScanEpochDriver:
         # one-epoch-ahead schedules, keyed (id(groups), train, first) —
         # see _build_sched/_drive
         self._sched_cache: dict = {}
+        # _drive calls outside warm-up so far: the ``epoch`` that the
+        # spans of one driven epoch share
+        self._epochs_driven = 0
 
     def _span(self, name: str):
         """A set-up span of the program's tracer (and, through it, of the
@@ -565,6 +568,17 @@ class ScanEpochDriver:
         context that yields None."""
         tel = self._telemetry
         return tel.span(name) if tel is not None else contextlib.nullcontext()
+
+    def _run_span(self, name: str, **args):
+        """A span of run work, once an epoch or rarer (a chunk's spans are
+        opened in ``run_queues``, which builds nothing when they are off),
+        yielding its args dict, or None where none is opened. Unlike
+        ``_span`` it is muted while warming, as ``scan.chunk`` is: a
+        warm-up epoch is not run work."""
+        tel = self._telemetry
+        if tel is None or tel.spans is None or tel.warming:
+            return contextlib.nullcontext()
+        return tel.spans.span(name, **args)
 
     def _stack_groups(self, batches: list) -> dict:
         """Group same-shape batches, stack on a leading axis, stage to HBM.
@@ -644,12 +658,14 @@ class ScanEpochDriver:
         return min(self.mixed_tail, max(1, n // 4))
 
     def _build_sched(self, groups, train, first):
-        """(queues, tails, steps) with every chunk perm ALREADY staged on
-        device. Called one epoch AHEAD of use (see _drive) so the H2D
-        transfer overlaps the in-flight epoch instead of stalling the
-        device at the epoch boundary — the trace showed the driver's
-        entire fixed cost as one ~90-140 ms device-idle gap at each epoch
-        start (sync fetch + perm staging + dispatch latency round trips).
+        """(queues, tails, steps, pick_order) with every chunk perm ALREADY
+        staged on device. Called one epoch AHEAD of use (see _drive) so
+        that the draws and the H2D transfers ride along the in-flight epoch
+        instead of standing at the head of the next one. Where the device
+        outruns the host (the four-chip mesh) the build still holds the
+        dispatch thread for its whole length with the device's queue
+        empty: spans ``epoch.sched`` (``_sched``) and ``epoch.sched.put``
+        say for how long (PERF.md §5, ``mp.train-dp4``).
         """
         c = self.chunk_steps
         queues = []
@@ -695,19 +711,21 @@ class ScanEpochDriver:
         # one async transfer for every perm (a per-dispatch jnp.asarray
         # would be a fresh synchronous H2D each time); i32 explicitly —
         # np.arange is i64 and would trace distinct (or x64-invalid) scans
-        for entry in queues + tails:
-            entry[2][:] = jax.device_put(
-                [np.ascontiguousarray(ch, dtype=np.int32)
-                 for ch in entry[2]]
-            )
-        # weighted group-pick sequence, PRECOMPUTED here (ISSUE 9
-        # satellite): the per-chunk np.array + rng.choice(p=...) that
-        # used to run on the DISPATCH path in run_queues (a measurable
-        # host-side fixed cost per chunk)
-        # moves into the schedule build, which _drive prebuilds one
-        # epoch AHEAD so it overlaps the in-flight epoch. Same sampler,
-        # same weights (remaining steps per group), same rng stream
-        # shape — the step-sequence distribution is unchanged, and the
+        with self._run_span(
+                "epoch.sched.put",
+                perms=sum(len(entry[2]) for entry in queues + tails),
+                bytes=4 * steps):
+            for entry in queues + tails:
+                entry[2][:] = jax.device_put(
+                    [np.ascontiguousarray(ch, dtype=np.int32)
+                     for ch in entry[2]]
+                )
+        # weighted group-pick sequence, PRECOMPUTED here so that no
+        # np.array + rng.choice(p=...) runs a chunk on the DISPATCH path
+        # in run_queues: it is part of the schedule build, which _drive
+        # prebuilds one epoch AHEAD. Same sampler, same weights
+        # (remaining steps per group), same rng stream shape — the
+        # step-sequence distribution is unchanged, and the
         # sync-vs-async-fetch bit-identity pin still holds because both
         # paths build schedules in the same order.
         if multi and not first:
@@ -725,6 +743,22 @@ class ScanEpochDriver:
                 if not rem[gi]:
                     alive.remove(gi)
         return queues, tails, steps, pick_order
+
+    def _sched(self, groups, train, first, prebuilt: bool):
+        """``_build_sched`` as run work: span ``epoch.sched`` (``prebuilt``:
+        built ahead of the epoch that uses it, or on the miss at its head)
+        and the counters of what it staged."""
+        with self._run_span("epoch.sched", train=train,
+                            prebuilt=prebuilt) as args:
+            sched = self._build_sched(groups, train, first)
+            chunks = sum(len(entry[2]) for entry in sched[0])
+            perms = chunks + sum(len(entry[2]) for entry in sched[1])
+            if args is not None:
+                args.update(chunks=chunks, perms=perms)
+        if self._telemetry is not None:
+            self._telemetry.counter_add("sched_builds", 1)
+            self._telemetry.counter_add("sched_perms_staged", perms)
+        return sched
 
     def warm(self, state: TrainState) -> TrainState:
         """Compile every (shape, chunk-length) scan program the driver can
@@ -823,19 +857,36 @@ class ScanEpochDriver:
         sync for train+eval; train_epoch/eval_epoch: per-phase fetch).
         ``prebuild=False`` defers the next-epoch schedule prebuild to the
         caller (run_epoch_pair's async-fetch mode overlaps it with the
-        background sums fetch instead)."""
+        background sums fetch instead).
+
+        With telemetry on, the whole call is one ``scan.epoch`` span whose
+        ``epoch`` every span of its chunks carries too (warm-up epochs
+        are not run work: no span, and no count)."""
+        with self._run_span("scan.epoch", epoch=self._epochs_driven,
+                            train=train) as ids:
+            if ids is not None:
+                self._epochs_driven += 1
+            return self._dispatch_epoch(state, groups, scans, body, train,
+                                        first, prebuild, ids)
+
+    def _dispatch_epoch(self, state: TrainState, groups, scans, body, train,
+                        first, prebuild: bool, ids: dict | None):
+        """``_drive``'s body. ``ids`` is the args dict of the epoch's
+        ``scan.epoch`` span (its ``epoch`` is handed on to the chunks'
+        spans, and the epoch's chunks and steps are left in it), or None
+        where no span is to be opened."""
         sched_key = (id(groups), train, first)
         if train:
             sched = self._sched_cache.pop(sched_key, None)
             if sched is None:
-                sched = self._build_sched(groups, train, first)
+                sched = self._sched(groups, train, first, prebuilt=False)
         else:
             # the eval schedule is deterministic (first=True, arange
             # perms): build once, reuse every epoch — re-staging identical
             # perms each epoch was pure waste
             sched = self._sched_cache.get(sched_key)
             if sched is None:
-                sched = self._build_sched(groups, train, first)
+                sched = self._sched(groups, train, first, prebuilt=False)
                 self._sched_cache[sched_key] = sched
         queues, tails, _planned_steps, pick_order = sched
         # run_queues consumes the chunk lists: work on shallow DEQUE
@@ -844,27 +895,25 @@ class ScanEpochDriver:
         queues = [(k, st, collections.deque(ch)) for k, st, ch in queues]
         tails = [(k, st, collections.deque(ch)) for k, st, ch in tails]
         multi = train and len(groups) > 1
-        # chunk dispatch is the host-side hot loop (ISSUE 9 satellite —
-        # PERF.md §6c): the weighted group picks were PREDRAWN into
-        # pick_order by _build_sched (one epoch ahead, overlapping the
-        # in-flight epoch), so per chunk this loop does a deque pop, a
-        # dict lookup, the dispatch, and one device-side accumulate.
-        # Chunk metric sums accumulate ON DEVICE (one fused async add
-        # per chunk) and are fetched ONCE, packed into a single array —
-        # a list-of-dicts device_get at epoch end moved every scalar as
-        # its own link round trip, which at bench scale (17 chunks x 4
-        # keys) was ~250 ms/epoch: the whole driver-vs-steady gap
-        # (metrics.fetch_device_sums)
+        # chunk dispatch is the host-side hot loop, and where the device
+        # outruns the host (the four-chip mesh) it sets the pace (PERF.md
+        # §5, ``mp.train-dp4``): the weighted group picks were PREDRAWN
+        # into pick_order by _build_sched (one epoch ahead), so per chunk
+        # this loop does the preemption poll, a deque pop, a dict lookup
+        # and TWO dispatches over all devices: the scan program and one
+        # device-side accumulate of its metric sums (one fused async add),
+        # which are fetched ONCE an epoch, packed into a single array
+        # (metrics.fetch_device_sums). Spans ``scan.chunk`` and
+        # ``scan.accumulate`` time the two; what is left of the
+        # ``scan.epoch`` span is the loop's own.
         dev_sums: dict | None = None
         executed = 0
-        # warm-up dispatches are not run work: no span for them, as
-        # Telemetry.warmup() already keeps them out of the counters
-        tel = self._telemetry
-        spans = (tel.spans if tel is not None and not tel.warming
-                 else None)
+        chunks_run = 0
+        spans = None if ids is None else self._telemetry.spans
+        epoch = None if ids is None else ids["epoch"]
 
         def run_queues(qs, weighted):
-            nonlocal state, dev_sums, executed
+            nonlocal state, dev_sums, executed, chunks_run
             rr = 0
             picks = iter(pick_order)
             by_index = list(qs)  # pick_order indexes the BUILD order
@@ -893,14 +942,23 @@ class ScanEpochDriver:
                 )
                 if spans is None:
                     state, chunk_sums = fn(state, stacked, chunk)
+                    dev_sums = accumulate_on_device(dev_sums, chunk_sums)
                 else:
-                    # the host's side of one dispatch, in trace.json and
-                    # (as cgnn:scan.chunk) on the profiler's clock beside
-                    # the device's launch of the same program
+                    # the host's side of the chunk's two dispatches, in
+                    # trace.json and (as cgnn:scan.chunk, with the same
+                    # ids) on the profiler's clock beside the device's
+                    # launch of the same program; the program's name is
+                    # the jitted function's own (_scan_fn set it)
                     with spans.span("scan.chunk",
-                                    steps=int(chunk.shape[0]), train=train):
+                                    steps=int(chunk.shape[0]), train=train,
+                                    epoch=epoch, chunk=chunks_run,
+                                    program=fn.__name__):
                         state, chunk_sums = fn(state, stacked, chunk)
-                dev_sums = accumulate_on_device(dev_sums, chunk_sums)
+                    with spans.span("scan.accumulate", epoch=epoch,
+                                    chunk=chunks_run):
+                        dev_sums = accumulate_on_device(dev_sums,
+                                                        chunk_sums)
+                chunks_run += 1
                 executed += int(chunk.shape[0])
                 if not chunks:
                     qs.remove(entry)
@@ -914,9 +972,12 @@ class ScanEpochDriver:
         # rng draws consumed in the same order a further epoch would have.)
         if train and not self.aborted and prebuild:
             self._sched_cache[(id(groups), True, False)] = \
-                self._build_sched(groups, True, False)
+                self._sched(groups, True, False, prebuilt=True)
         if self._telemetry is not None:
             self._telemetry.counter_add("scan_steps", executed)
+            self._telemetry.counter_add("scan_chunks", chunks_run)
+        if ids is not None:
+            ids.update(chunks=chunks_run, steps=executed)
         return state, dev_sums, executed
 
     def train_epoch(self, state: TrainState, first: bool):
@@ -959,6 +1020,7 @@ class ScanEpochDriver:
         """
         self.aborted = False
         self.eval_truncated = False
+        pair_epoch = self._epochs_driven  # the train epoch's id
         state, tr_sums, tr_steps = self._drive(
             state, self._train_groups, self._train_scans,
             self._train_body, train=True, first=first,
@@ -985,8 +1047,12 @@ class ScanEpochDriver:
         combined = {f"t:{k}": v for k, v in (tr_sums or {}).items()}
         combined |= {f"e:{k}": v for k, v in (ev_sums or {}).items()}
 
+        # made here, entered on whichever thread fetches
+        fetch_span = self._run_span("epoch.fetch", epoch=pair_epoch)
+
         def fetch_pair():
-            fetched = fetch_device_sums(combined or None)
+            with fetch_span:
+                fetched = fetch_device_sums(combined or None)
             tr = {k[2:]: v for k, v in fetched.items()
                   if k.startswith("t:")}
             ev = {k[2:]: v for k, v in fetched.items()
@@ -997,13 +1063,14 @@ class ScanEpochDriver:
         if not async_fetch:
             train_m, val_m = fetch_pair()
             return state, train_m, val_m
-        pending = PendingPairMetrics(fetch_pair)
+        with self._run_span("epoch.fetch_start", epoch=pair_epoch):
+            pending = PendingPairMetrics(fetch_pair)
         # the deferred prebuild (see _drive): schedule + stage the next
         # train epoch while the fetch thread blocks on this epoch's
         # in-flight compute. Same rng draws, same order as the sync path.
         if not train_aborted:
             self._sched_cache[(id(self._train_groups), True, False)] = \
-                self._build_sched(self._train_groups, True, False)
+                self._sched(self._train_groups, True, False, prebuilt=True)
         return state, pending
 
 
@@ -1380,16 +1447,15 @@ def fit(
                     log_fn=log_fn,
                     telemetry=telemetry,
                 )
-            with telemetry.span("eval", epoch=epoch):
-                _, val_m = run_epoch(
-                    eval_step,
-                    state,
-                    stage(epoch_val),
-                    train=False,
-                    epoch=epoch,
-                    log_fn=log_fn,
-                    telemetry=telemetry,
-                )
+            _, val_m = run_epoch(
+                eval_step,
+                state,
+                stage(epoch_val),
+                train=False,
+                epoch=epoch,
+                log_fn=log_fn,
+                telemetry=telemetry,
+            )
         is_best = finish_epoch(
             epoch, train_m, val_m,
             driver is not None and driver.eval_truncated, t0,

@@ -752,8 +752,23 @@ ENTRY %main.7 (a.1: f32[4]) -> f32[4] {{
         assert code(scoped) == code(bare)
 
 
+# the spans of run work the epoch driver opens (train/loop.py), all muted
+# in warm-up; benchmark/readers/{span,ring,gaps}.py read them by these names
+RUN_SPANS = {"scan.epoch", "scan.chunk", "scan.accumulate", "epoch.sched",
+             "epoch.sched.put", "epoch.fetch_start", "epoch.fetch"}
+
+
+def _inside(child: dict, parent: dict) -> bool:
+    """``child`` is a direct child of ``parent`` in the ring: same thread,
+    one level deeper, within its interval."""
+    return (child["tid"] == parent["tid"]
+            and child["args"]["depth"] == parent["args"]["depth"] + 1
+            and parent["ts"] <= child["ts"]
+            and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"])
+
+
 class TestDriverSpans:
-    def _driver(self, tiny_dataset, telemetry):
+    def _driver(self, tiny_dataset, telemetry, copies=1):
         from cgnn_tpu.resilience.guard import guard_step
         from cgnn_tpu.train.loop import ScanEpochDriver
         from cgnn_tpu.train.step import make_eval_step
@@ -762,7 +777,7 @@ class TestDriverSpans:
         node_cap, edge_cap = capacities_for(train_g, 8)
         state = _fresh_state(train_g, node_cap, edge_cap)
         pack = lambda gs: pack_graphs(gs, node_cap, edge_cap, 8)  # noqa: E731
-        train_b = [pack(train_g[i:i + 8]) for i in (0, 8, 16)]
+        train_b = [pack(train_g[i:i + 8]) for i in (0, 8, 16)] * copies
         drv = ScanEpochDriver(
             guard_step(make_train_step()), make_eval_step(), train_b,
             [pack(val_g[:8])], np.random.default_rng(0),
@@ -877,12 +892,13 @@ class TestDriverSpans:
 
     def test_without_telemetry_the_dispatch_opens_no_annotation(
             self, tiny_dataset, tmp_path, monkeypatch):
-        opened = []
+        opened, kwargs = [], []
         real = jax.profiler.TraceAnnotation
 
         class Counting(real):
             def __init__(self, name, **kw):
                 opened.append(name)
+                kwargs.append(kw)
                 super().__init__(name, **kw)
 
         monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
@@ -891,10 +907,234 @@ class TestDriverSpans:
         state, _, _ = drv.run_epoch_pair(state, first=False)
         assert opened == []
 
+        # with it: the spans of run work and no other, each annotation
+        # with the ids its ring event has (a cgnn: event of the xplane
+        # says which chunk of which epoch it is)
         telemetry = Telemetry("epoch", str(tmp_path / "t"))
         drv, state = self._driver(tiny_dataset, telemetry)
         state = drv.warm(state)
         opened.clear()
+        kwargs.clear()
+        state, pending = drv.run_epoch_pair(state, first=False,
+                                            async_fetch=True)
+        pending.result()
+        assert set(opened) == {"cgnn:" + n for n in RUN_SPANS}
+        ring = [e for e in telemetry.spans.events if e["name"] in RUN_SPANS]
+        assert len(ring) == len(opened)
+        by_ids = {(e["name"], e["args"].get("epoch"), e["args"].get("chunk"))
+                  for e in ring}
+        for name, kw in zip(opened, kwargs):
+            name = name.removeprefix("cgnn:")
+            assert (name, kw.get("epoch"), kw.get("chunk")) in by_ids
+            if name == "scan.chunk":
+                assert set(kw) == {"steps", "train", "epoch", "chunk",
+                                   "program"}
+                assert kw["program"].startswith(
+                    "scan_train_n" if kw["train"] else "scan_eval_n")
+            elif name == "scan.accumulate":
+                assert set(kw) == {"epoch", "chunk"}
+            elif name == "epoch.sched.put":
+                assert kw["perms"] > 0 and kw["bytes"] > 0
+        telemetry.close()
+
+    def test_every_span_of_a_driven_epoch_carries_its_ids(
+            self, tiny_dataset, tmp_path):
+        """The epoch driver accounts for its own host time: one
+        ``scan.epoch`` a ``_drive`` call, under it one ``scan.chunk`` and
+        one ``scan.accumulate`` a chunk with the same ``(epoch, chunk)``,
+        ``epoch.sched`` around every schedule build (with the staging of
+        its perms inside), and the fetch's start and the fetch itself, on
+        whichever thread; warm-up leaves none of them."""
+        telemetry = Telemetry("epoch", str(tmp_path / "t"))
+        drv, state = self._driver(tiny_dataset, telemetry, copies=2)
+        state = drv.warm(state)
+        assert not RUN_SPANS & {e["name"] for e in telemetry.spans.events}
+        assert not {"scan_chunks", "sched_builds", "sched_perms_staged",
+                    "scan_steps"} & set(telemetry.counters())
+
+        # fit's pair with the deferred fetch, then the synchronous pair
+        state, pending = drv.run_epoch_pair(state, first=False,
+                                            async_fetch=True)
+        pending.result()
+        drv._sched_cache.pop((id(drv._train_groups), True, False))
         state, _, _ = drv.run_epoch_pair(state, first=False)
-        assert opened and set(opened) == {"cgnn:scan.chunk"}
+        by_name: dict = {}
+        for e in telemetry.spans.events:
+            if e["name"] in RUN_SPANS:
+                by_name.setdefault(e["name"], []).append(e)
+        assert set(by_name) == RUN_SPANS
+
+        epochs = by_name["scan.epoch"]
+        assert [(e["args"]["epoch"], e["args"]["train"]) for e in epochs] \
+            == [(0, True), (1, False), (2, True), (3, False)]
+        n_nodes = next(iter(drv._train_groups))[0][0]
+        for ep in epochs:
+            def mine(name, epoch=ep["args"]["epoch"]):
+                return sorted((e for e in by_name[name]
+                               if e["args"]["epoch"] == epoch),
+                              key=lambda e: e["args"]["chunk"])
+
+            chunks, accs = mine("scan.chunk"), mine("scan.accumulate")
+            n = ep["args"]["chunks"]
+            assert n == len(chunks) == len(accs) > 0
+            assert [c["args"]["chunk"] for c in chunks] == list(range(n)) \
+                == [a["args"]["chunk"] for a in accs]
+            assert sum(c["args"]["steps"] for c in chunks) \
+                == ep["args"]["steps"] == (6 if ep["args"]["train"] else 1)
+            kind = "train" if ep["args"]["train"] else "eval"
+            for c, a in zip(chunks, accs):
+                assert _inside(c, ep) and _inside(a, ep)
+                assert c["args"]["train"] == ep["args"]["train"]
+                # the accumulate is dispatched after the chunk's program
+                assert c["ts"] + c["dur"] <= a["ts"]
+                assert c["args"]["program"] == (
+                    f"scan_{kind}_n{n_nodes}_l{c['args']['steps']}")
+
+        # every schedule build, and the staging of its perms inside: the
+        # async pair defers the prebuild to run_epoch_pair (under no
+        # scan.epoch); the cache was then emptied, so the sync pair builds
+        # on the miss at its head and prebuilds at its end. The eval
+        # schedule was built in warm-up, once, and is reused.
+        scheds = by_name["epoch.sched"]
+        assert [(e["args"]["train"], e["args"]["prebuilt"])
+                for e in scheds] == [(True, True), (True, False),
+                                     (True, True)]
+        assert not any(_inside(scheds[0], ep) for ep in epochs)
+        assert _inside(scheds[1], epochs[2]) and _inside(scheds[2],
+                                                         epochs[2])
+        assert scheds[1]["ts"] < by_name["scan.chunk"][-4]["ts"]
+        puts = by_name["epoch.sched.put"]
+        assert len(puts) == len(scheds)
+        for sched, put in zip(scheds, puts):
+            assert _inside(put, sched)
+            assert put["args"]["perms"] == sched["args"]["perms"] == 3
+            assert sched["args"]["chunks"] == 3
+            assert put["args"]["bytes"] == 4 * 6  # six int32 steps
+
+        # the fetch: started on the dispatch thread (the async pair only),
+        # run on the fetch thread there and inline in the sync pair
+        (start,) = by_name["epoch.fetch_start"]
+        first, second = by_name["epoch.fetch"]
+        assert start["args"]["epoch"] == first["args"]["epoch"] == 0
+        assert second["args"]["epoch"] == 2
+        assert start["tid"] == epochs[0]["tid"] == second["tid"]
+        assert first["tid"] != start["tid"]
+        assert start["args"]["depth"] == first["args"]["depth"] == 0
+
+        counters = telemetry.counters()
+        assert counters["scan_chunks"] == len(by_name["scan.chunk"]) == 8
+        assert counters["scan_steps"] == 14
+        assert counters["sched_builds"] == 3
+        assert counters["sched_perms_staged"] == 9
+        json.dumps(telemetry.spans.events)
+        telemetry.close()
+
+    def test_without_telemetry_the_chunk_loop_builds_nothing(
+            self, tiny_dataset, monkeypatch):
+        """Off means off: an epoch of two chunks and an epoch of seven
+        construct equally many context managers (the epoch's own, through
+        ``_run_span``), and no span or annotation at all."""
+        import contextlib
+        import types
+
+        from cgnn_tpu.train import loop
+
+        made = {"span": 0, "annotation": 0, "nullcontext": 0}
+
+        def counting(kind, real):
+            def make(*a, **kw):
+                made[kind] += 1
+                return real(*a, **kw)
+            return make
+
+        counts = []
+        for copies in (1, 4):
+            drv, state = self._driver(tiny_dataset, None, copies=copies)
+            state, _, _ = drv.run_epoch_pair(state, first=True)
+            with monkeypatch.context() as mp:
+                mp.setattr(SpanTracer, "span",
+                           counting("span", SpanTracer.span))
+                mp.setattr(jax.profiler, "TraceAnnotation", counting(
+                    "annotation", jax.profiler.TraceAnnotation))
+                # loop.py's own view of contextlib: jax's use of it inside
+                # a dispatch is not the driver's
+                mp.setattr(loop, "contextlib", types.SimpleNamespace(
+                    nullcontext=counting("nullcontext",
+                                         contextlib.nullcontext)))
+                state, pending = drv.run_epoch_pair(state, first=False,
+                                                    async_fetch=True)
+                pending.result()
+            counts.append(dict(made))
+            made.update(dict.fromkeys(made, 0))
+        assert counts[0] == counts[1]
+        assert counts[0]["span"] == counts[0]["annotation"] == 0
+        assert 0 < counts[0]["nullcontext"] <= 8
+
+    def test_telemetry_changes_no_schedule_trajectory_or_metric(
+            self, tiny_dataset, tmp_path):
+        """Spans are the host's notes: the chunks dispatched (which
+        program, which batches, in which order), the parameters after two
+        epochs and the fetched metrics are bit-identical with telemetry on
+        and off, through the deferred fetch and the synchronous one."""
+        def run(telemetry):
+            drv, state = self._driver(tiny_dataset, telemetry, copies=2)
+            real, seen = drv._scan_fn, []
+
+            def recording(cache, key, body, train):
+                fn = real(cache, key, body, train)
+
+                def dispatch(state, stacked, perm):
+                    seen.append((fn.__name__, np.asarray(perm).tolist()))
+                    return fn(state, stacked, perm)
+
+                dispatch.__name__ = fn.__name__
+                return dispatch
+
+            drv._scan_fn = recording
+            state, _, _ = drv.run_epoch_pair(state, first=True)
+            seen.clear()
+            state, pending = drv.run_epoch_pair(state, first=False,
+                                                async_fetch=True)
+            state, train_m, val_m = drv.run_epoch_pair(state, first=False)
+            params = jax.tree_util.tree_map(np.asarray, state.params)
+            return seen, params, (pending.result(), train_m, val_m)
+
+        telemetry = Telemetry("epoch", str(tmp_path / "t"))
+        seen_on, params_on, metrics_on = run(telemetry)
+        assert RUN_SPANS <= {e["name"] for e in telemetry.spans.events}
+        telemetry.close()
+        seen_off, params_off, metrics_off = run(None)
+        assert seen_on == seen_off and len(seen_on) == 8
+        assert metrics_on == metrics_off
+        jax.tree_util.tree_map(np.testing.assert_array_equal,
+                               params_on, params_off)
+
+
+class TestCheckpointSpans:
+    def test_save_and_restore_are_spans_of_the_calling_thread(
+            self, tiny_dataset, tmp_path):
+        """``checkpoint_save`` times what a save holds the training thread
+        for (the device fetch and the finalizer's dispatch, not the write,
+        which the finalizer thread does), ``checkpoint_restore`` a whole
+        restore; the README's ``trace.json`` row promises both."""
+        from cgnn_tpu.train.checkpoint import CheckpointManager
+
+        train_g, _, _ = tiny_dataset
+        node_cap, edge_cap = capacities_for(train_g, 8)
+        state = _fresh_state(train_g, node_cap, edge_cap)
+        telemetry = Telemetry("epoch", str(tmp_path / "t"))
+        mgr = CheckpointManager(str(tmp_path / "ckpt"), telemetry=telemetry)
+        with telemetry.span("epoch", epoch=0):
+            mgr.save(state, {"epoch": 0}, is_best=True)
+        mgr.wait()
+        mgr.restore(state)
+        mgr.close()
+        by_name = {e["name"]: e for e in telemetry.spans.events}
+        save, restore = by_name["checkpoint_save"], by_name[
+            "checkpoint_restore"]
+        assert save["args"]["is_best"] is True and _inside(
+            save, by_name["epoch"])
+        assert restore["args"]["tag"] == "latest"
+        assert restore["args"]["depth"] == 0 and restore["ts"] >= save["ts"]
+        assert save["dur"] > 0 and restore["dur"] > 0
         telemetry.close()
