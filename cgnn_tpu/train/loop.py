@@ -18,6 +18,7 @@ from typing import Callable, Iterable, Sequence
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from cgnn_tpu.data.graph import (
     CrystalGraph,
@@ -553,10 +554,11 @@ class ScanEpochDriver:
                 telemetry.set_gauge("transpose_overflow_max_run",
                                     args["transpose_overflow_max_run"])
         self._train_body, self._eval_body = train_body, eval_body
+        # the programs an epoch runs, by (shape key, length): _window_fn
         self._train_scans: dict = {}
         self._eval_scans: dict = {}
-        # one-epoch-ahead schedules, keyed (id(groups), train, first) —
-        # see _build_sched/_drive
+        # one-epoch-ahead staged schedules, keyed (id(groups), train,
+        # first) — see _sched/_drive
         self._sched_cache: dict = {}
         # _drive calls outside warm-up so far: the ``epoch`` that the
         # spans of one driven epoch share
@@ -608,9 +610,20 @@ class ScanEpochDriver:
     # step sequence tracks the per-step loop's weighted interleave.
     chunk_steps = 2
 
-    def _scan_fn(self, cache: dict, key, body: Callable, train: bool):
+    def _window_fn(self, cache: dict, key, body: Callable, train: bool):
+        """The program an epoch runs for ``key = (shape key, length)``:
+        ``fn(state, stacked, perm_all, cursor) -> (state, sums, cursor +
+        length)``, one ``lax.scan`` over ``length`` batches of ``stacked``
+        with the step metrics summed. ``perm_all`` is the group's whole
+        epoch of batch indices in the order its chunks are dispatched
+        (``_sched`` staged it in one transfer) and ``cursor`` a device
+        scalar that every chunk of the group hands to the next, so a chunk
+        finds its rows on the device and a dispatch moves nothing from the
+        host."""
         if key not in cache:
-            def scan_fn(state, stacked, perm):
+            length = int(key[1])
+
+            def scan_fn(state, stacked, perm_all, cursor):
                 def step(carry, i):
                     with jax.named_scope(phases.SCAN):
                         batch = jax.tree_util.tree_map(
@@ -628,11 +641,14 @@ class ScanEpochDriver:
                             self._tap(metrics, "eval")
                     return carry, metrics
 
+                with jax.named_scope(phases.SCAN):
+                    perm = jax.lax.dynamic_slice_in_dim(
+                        perm_all, cursor, length)
                 state2, ms = jax.lax.scan(step, state, perm)
                 with jax.named_scope(phases.SCAN):
                     return state2, jax.tree_util.tree_map(
                         lambda m: m.sum(0), ms
-                    )
+                    ), cursor + length
 
             # the name the profiler's module line and the compile log show
             scan_fn.__name__ = program_name(key, train)
@@ -641,6 +657,24 @@ class ScanEpochDriver:
                 donate_argnums=TRAIN_STEP_DONATE if train else (),
             )
         return cache[key]
+
+    def _scan_fn(self, cache: dict, key, body: Callable, train: bool):
+        """``fn(state, stacked, perm) -> (state, sums)`` for a caller that
+        brings a chunk's perm of its own (the benchmark's compared steps):
+        ``_window_fn``'s program, the one an epoch runs and ``warm()``
+        compiled, over that perm staged at the front of an otherwise zero
+        ``perm_all`` with the cursor at zero. No second program."""
+        fn = self._window_fn(cache, key, body, train)
+
+        def scan_fn(state, stacked, perm):
+            n = int(jax.tree_util.tree_leaves(stacked)[0].shape[0])
+            perm_all = np.zeros(n, np.int32)
+            perm_all[: int(key[1])] = np.asarray(perm)
+            state, sums, _ = fn(state, stacked,
+                                *self._put_perms(perm_all, stacked))
+            return state, sums
+
+        return scan_fn
 
     # per-group steps reserved for the end of each training epoch and run
     # ONE step at a time, round-robin across groups: BatchNorm's running
@@ -658,14 +692,12 @@ class ScanEpochDriver:
         return min(self.mixed_tail, max(1, n // 4))
 
     def _build_sched(self, groups, train, first):
-        """(queues, tails, steps, pick_order) with every chunk perm ALREADY
-        staged on device. Called one epoch AHEAD of use (see _drive) so
-        that the draws and the H2D transfers ride along the in-flight epoch
-        instead of standing at the head of the next one. Where the device
-        outruns the host (the four-chip mesh) the build still holds the
-        dispatch thread for its whole length with the device's queue
-        empty: spans ``epoch.sched`` (``_sched``) and ``epoch.sched.put``
-        say for how long (PERF.md §5, ``mp.train-dp4``).
+        """The host's draw of one epoch: ``(queues, tails, steps,
+        pick_order)``, the entries of ``queues`` and ``tails`` ``(key,
+        stacked, chunks)`` with every chunk a ``numpy`` view of its
+        group's permutation, an int32 array that owns its data (so
+        ``chunk.base`` is that permutation whole: what ``_sched`` stages).
+        Nothing reaches the device here.
         """
         c = self.chunk_steps
         queues = []
@@ -676,10 +708,11 @@ class ScanEpochDriver:
         for key, stacked in groups.items():
             n = int(jax.tree_util.tree_leaves(stacked)[0].shape[0])
             tail = self._tail_for(n) if multi else 0
-            perm = (
+            # int32 explicitly: np.arange is int64 and would trace
+            # distinct (or x64-invalid) scans
+            perm = np.array(
                 np.arange(n) if (first or not train)
-                else self._rng.permutation(n)
-            )
+                else self._rng.permutation(n), dtype=np.int32)
             head, foot = perm[: n - tail], perm[n - tail :]
             if multi:
                 # randomized chunk lengths from {c/2, c, 2c} (mean ~c;
@@ -708,18 +741,6 @@ class ScanEpochDriver:
                 tails.append((key, stacked, [foot[i : i + 1]
                                              for i in range(len(foot))]))
             steps += n
-        # one async transfer for every perm (a per-dispatch jnp.asarray
-        # would be a fresh synchronous H2D each time); i32 explicitly —
-        # np.arange is i64 and would trace distinct (or x64-invalid) scans
-        with self._run_span(
-                "epoch.sched.put",
-                perms=sum(len(entry[2]) for entry in queues + tails),
-                bytes=4 * steps):
-            for entry in queues + tails:
-                entry[2][:] = jax.device_put(
-                    [np.ascontiguousarray(ch, dtype=np.int32)
-                     for ch in entry[2]]
-                )
         # weighted group-pick sequence, PRECOMPUTED here so that no
         # np.array + rng.choice(p=...) runs a chunk on the DISPATCH path
         # in run_queues: it is part of the schedule build, which _drive
@@ -744,21 +765,60 @@ class ScanEpochDriver:
                     alive.remove(gi)
         return queues, tails, steps, pick_order
 
+    @staticmethod
+    def _put_perms(perm_all, stacked):
+        """One group's schedule on the device, committed whole to every
+        device that holds a part of ``stacked`` (under a mesh: replicated
+        over it, so that no dispatch has to place it): ``(perm_all, a zero
+        cursor)``, both int32, two transfers. ``warm()`` and the epochs
+        stage through here alike: jit keys its programs on where their
+        arguments live and on which of them are committed."""
+        where = jax.tree_util.tree_leaves(stacked)[0].sharding
+        if isinstance(where, NamedSharding):
+            where = NamedSharding(where.mesh, PartitionSpec())
+        return jax.device_put(
+            (np.asarray(perm_all, np.int32), np.zeros((), np.int32)), where)
+
     def _sched(self, groups, train, first, prebuilt: bool):
-        """``_build_sched`` as run work: span ``epoch.sched`` (``prebuilt``:
-        built ahead of the epoch that uses it, or on the miss at its head)
-        and the counters of what it staged."""
+        """One epoch's schedule as run work: ``_build_sched``'s draw, then
+        the staging of its perms, ``(sched, staged)`` with ``staged[key]``
+        the group's ``(perm_all, zero cursor)`` on the device. A group's
+        perms go over as ONE array, in the order the epoch consumes them
+        (the chunks of its queue front to back, then its tail singles:
+        together the group's permutation), whatever the number of chunks:
+        a buffer of a few bytes costs the host 0.16-0.19 ms wherever it is
+        made, and an epoch has thousands of chunks (PERF.md §6, PR 40).
+        Called one epoch AHEAD of use (see _drive); where the device
+        outruns the host (the four-chip mesh) the build still holds the
+        dispatch thread for its whole length with the device's queue
+        empty. Spans ``epoch.sched`` (``prebuilt``: built ahead of the
+        epoch that uses it, or on the miss at its head) and, inside it,
+        ``epoch.sched.put`` say for how long; the counters say what was
+        staged (``sched_perms_staged``: the chunk perms the schedule
+        covers; ``sched_transfers``: the host-to-device transfers made
+        for them)."""
         with self._run_span("epoch.sched", train=train,
                             prebuilt=prebuilt) as args:
             sched = self._build_sched(groups, train, first)
-            chunks = sum(len(entry[2]) for entry in sched[0])
-            perms = chunks + sum(len(entry[2]) for entry in sched[1])
+            queues, tails, steps, _ = sched
+            chunks = sum(len(entry[2]) for entry in queues)
+            perms = chunks + sum(len(entry[2]) for entry in tails)
+            # a group's head chunks and foot singles are views of one
+            # array, in its order
+            perm_of = {key: group_chunks[0].base
+                       for key, _, group_chunks in queues + tails}
+            transfers = 2 * len(perm_of)
+            with self._run_span("epoch.sched.put", perms=perms,
+                                bytes=4 * steps, transfers=transfers):
+                staged = {key: self._put_perms(perm_all, groups[key])
+                          for key, perm_all in perm_of.items()}
             if args is not None:
                 args.update(chunks=chunks, perms=perms)
         if self._telemetry is not None:
             self._telemetry.counter_add("sched_builds", 1)
             self._telemetry.counter_add("sched_perms_staged", perms)
-        return sched
+            self._telemetry.counter_add("sched_transfers", transfers)
+        return sched, staged
 
     def warm(self, state: TrainState) -> TrainState:
         """Compile every (shape, chunk-length) scan program the driver can
@@ -804,35 +864,38 @@ class ScanEpochDriver:
         with warm_ctx:
             for key, stacked in self._train_groups.items():
                 n = int(jax.tree_util.tree_leaves(stacked)[0].shape[0])
+                # staged as an epoch's schedule is: jit keys its programs
+                # on where their arguments live
+                perm_all, cursor = self._put_perms(np.arange(n), stacked)
                 for ln in lengths:
                     if ln > n:
                         continue
-                    fn = self._scan_fn(
-                        self._train_scans, (key, ln), self._train_body, True
-                    )
-                    perm = jax.device_put(
-                        np.arange(ln, dtype=np.int32) % n
-                    )
+                    fn = self._window_fn(
+                        self._train_scans, (key, ln), self._train_body,
+                        True)
                     if spans is None:
-                        scratch, _ = fn(scratch, stacked, perm)
+                        scratch, _, _ = fn(scratch, stacked, perm_all, cursor)
                         continue
                     name = program_name((key, ln), True)
                     with spans.span("warm.program", module=name,
                                     length=ln) as args:
                         with _compile_events() as seen:
-                            scratch, _ = fn(scratch, stacked, perm)
+                            scratch, _, _ = fn(scratch, stacked, perm_all,
+                                               cursor)
                         args.update(seen)
                     self._emit_program(spans, fn, name, key, ln,
-                                       (scratch, stacked, perm))
+                                       (scratch, stacked, perm_all, cursor))
             # eval programs + the pair plumbing compile on a normal epoch
             with self._span("warm.epoch"):
                 self.run_epoch_pair(scratch, first=True)
-            if spans is not None:
+            if spans is not None and self._eval_scans:
+                # as the warm epoch staged them, once for every epoch
+                _, staged = self._sched_cache[
+                    (id(self._val_groups), False, True)]
                 for (key, ln), fn in self._eval_scans.items():
-                    perm = jax.device_put(np.zeros(ln, np.int32))
                     self._emit_program(
                         spans, fn, program_name((key, ln), False), key, ln,
-                        (scratch, self._val_groups[key], perm))
+                        (scratch, self._val_groups[key], *staged[key]))
         return state
 
     @staticmethod
@@ -888,7 +951,11 @@ class ScanEpochDriver:
             if sched is None:
                 sched = self._sched(groups, train, first, prebuilt=False)
                 self._sched_cache[sched_key] = sched
-        queues, tails, _planned_steps, pick_order = sched
+        (queues, tails, _planned_steps, pick_order), staged = sched
+        # each group's place in its perm_all: a device scalar that a chunk
+        # takes and hands on (the staged zero is never donated, so the
+        # cached eval schedule starts from it every epoch)
+        cursors = {key: zero for key, (_, zero) in staged.items()}
         # run_queues consumes the chunk lists: work on shallow DEQUE
         # copies (O(1) popleft — pop(0) shifted the whole list per
         # chunk) so the cached eval schedule survives reuse
@@ -934,32 +1001,34 @@ class ScanEpochDriver:
                     entry = qs[rr % len(qs)]
                     rr += 1
                 key, stacked, chunks = entry
-                chunk = chunks.popleft()  # device-staged perm (see above)
+                # the host's copy of the chunk's perm says how long it is;
+                # the program reads the rows themselves off the device
+                length = len(chunks.popleft())
                 # compile key includes the chunk length (bounded per
                 # group: <= 2c distinct lengths, one remainder, length 1)
-                fn = self._scan_fn(
-                    scans, (key, len(chunk)), body, train
-                )
+                fn = self._window_fn(scans, (key, length), body, train)
+                perm_all = staged[key][0]
                 if spans is None:
-                    state, chunk_sums = fn(state, stacked, chunk)
+                    state, chunk_sums, cursors[key] = fn(
+                        state, stacked, perm_all, cursors[key])
                     dev_sums = accumulate_on_device(dev_sums, chunk_sums)
                 else:
                     # the host's side of the chunk's two dispatches, in
                     # trace.json and (as cgnn:scan.chunk, with the same
                     # ids) on the profiler's clock beside the device's
                     # launch of the same program; the program's name is
-                    # the jitted function's own (_scan_fn set it)
-                    with spans.span("scan.chunk",
-                                    steps=int(chunk.shape[0]), train=train,
+                    # the jitted function's own (_window_fn set it)
+                    with spans.span("scan.chunk", steps=length, train=train,
                                     epoch=epoch, chunk=chunks_run,
                                     program=fn.__name__):
-                        state, chunk_sums = fn(state, stacked, chunk)
+                        state, chunk_sums, cursors[key] = fn(
+                            state, stacked, perm_all, cursors[key])
                     with spans.span("scan.accumulate", epoch=epoch,
                                     chunk=chunks_run):
                         dev_sums = accumulate_on_device(dev_sums,
                                                         chunk_sums)
                 chunks_run += 1
-                executed += int(chunk.shape[0])
+                executed += length
                 if not chunks:
                     qs.remove(entry)
 

@@ -109,14 +109,18 @@ def compile_cell_for_described_chip(cell_name: str, steps: int):
 
     key = max(drv._train_groups, key=node_capacity)
     name = loop.program_name((key, steps), True)
-    fn = drv._scan_fn(drv._train_scans, (key, steps), drv._train_body, True)
+    fn = drv._window_fn(drv._train_scans, (key, steps), drv._train_body,
+                        True)
+    stacked = drv._train_groups[key]
+    stack = int(jax.tree_util.tree_leaves(stacked)[0].shape[0])
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     one_chip = SingleDeviceSharding(topo.devices[0])
     shapes = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
                                        sharding=one_chip),
-        (bench.state, drv._train_groups[key], np.zeros(steps, np.int32)))
+        (bench.state, stacked, np.zeros(stack, np.int32),
+         np.zeros((), np.int32)))
     text = fn.lower(*shapes).compile().as_text()
     n = node_capacity(key)
     m = int(cell.config["layout"]["dense_m"])

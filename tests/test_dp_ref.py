@@ -13,6 +13,7 @@ and the comparison tells each broken collective from a sound one.
 
 from __future__ import annotations
 
+import functools
 import os
 import sys
 
@@ -141,9 +142,12 @@ def test_the_reference_s_forward_is_cgcnn_ref_s(sound):
     assert jnp.isfinite(out).all()
 
 
-def _three_steps(compact: bool):
+@functools.cache  # two tests read the compact run
+def _three_steps(compact: bool, warmed: bool = False):
     """Three one-step programs over the same device groups from the same
-    weights, staged compact or in full -> (params, statistics, losses)."""
+    weights, staged compact or in full -> (params, statistics, losses, what
+    XLA compiled for the three steps). ``warmed``: the driver's ``warm()``
+    ran first, as in the benchmark's set-up."""
     import jax.numpy as jnp
     from jax.sharding import Mesh
 
@@ -162,7 +166,7 @@ def _three_steps(compact: bool):
         replicate_state,
         shard_scan_stack,
     )
-    from cgnn_tpu.train.loop import ScanEpochDriver
+    from cgnn_tpu.train.loop import ScanEpochDriver, _compile_events
 
     cfg = _float32_cell().config
     graphs, _ = system.load_pool(cfg)
@@ -195,16 +199,21 @@ def _three_steps(compact: bool):
                                  g0.edge_fea.shape[1])
     state = replicate_state(system.build_state(
         cfg, system.build_model(cfg), params, stats, 0.0, 1.0), mesh)
+    if warmed:
+        state = driver.warm(state)
     losses = []
-    for key, stacked in list(driver._train_groups.items()) * 2:
-        fn = driver._scan_fn(driver._train_scans, (key, 1),
-                             driver._train_body, True)
-        state, sums = fn(state, stacked, jnp.zeros(1, jnp.int32))
-        losses.append(float(sums["loss_sum"]))
-        if len(losses) == 3:
-            break
+    perm = jnp.zeros(1, jnp.int32)
+    with _compile_events() as seen:
+        for key, stacked in list(driver._train_groups.items()) * 2:
+            fn = driver._scan_fn(driver._train_scans, (key, 1),
+                                 driver._train_body, True)
+            state, sums = fn(state, stacked, perm)
+            losses.append(float(sums["loss_sum"]))
+            if len(losses) == 3:
+                break
     return (jax.tree_util.tree_map(np.asarray, state.params),
-            jax.tree_util.tree_map(np.asarray, state.batch_stats), losses)
+            jax.tree_util.tree_map(np.asarray, state.batch_stats), losses,
+            seen)
 
 
 def test_compact_staging_under_the_mesh_is_full_staging_bit_for_bit():
@@ -215,6 +224,21 @@ def test_compact_staging_under_the_mesh_is_full_staging_bit_for_bit():
     assert compact[2] == full[2]
     for a, b in zip(jax.tree_util.tree_leaves(compact[:2]),
                     jax.tree_util.tree_leaves(full[:2])):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_the_compared_steps_run_the_warmed_programs_under_the_mesh():
+    """``_scan_fn``'s ``(state, stacked, perm)`` form, which the kinds'
+    ``check`` drives, is the program an epoch runs: on a warmed driver under
+    the mesh it compiles nothing and reads nothing from the compile cache
+    (its perm and cursor are staged as ``warm()`` staged them, replicated),
+    and the three steps leave the bits an unwarmed driver's leave."""
+    cold, warm = _three_steps(True), _three_steps(True, warmed=True)
+    assert cold[3]["compiled"] or cold[3]["cache_read"]
+    assert warm[3] == {"compiled": False, "cache_read": False}
+    assert cold[2] == warm[2]
+    for a, b in zip(jax.tree_util.tree_leaves(cold[:2]),
+                    jax.tree_util.tree_leaves(warm[:2])):
         np.testing.assert_array_equal(a, b)
 
 

@@ -316,10 +316,11 @@ class TestScanParityAndNoOp:
         )
         assert drv._tap is None
         key = next(iter(drv._train_groups))
-        fn = drv._scan_fn(drv._train_scans, (key, 1), drv._train_body, True)
+        fn = drv._window_fn(drv._train_scans, (key, 1), drv._train_body,
+                            True)
         text_scan = fn.lower(
             state, drv._train_groups[key],
-            jnp.zeros(1, jnp.int32),
+            jnp.zeros(1, jnp.int32), jnp.int32(0),
         ).as_text()
         assert "callback" not in text_scan.lower()
 
@@ -935,6 +936,7 @@ class TestDriverSpans:
                 assert set(kw) == {"epoch", "chunk"}
             elif name == "epoch.sched.put":
                 assert kw["perms"] > 0 and kw["bytes"] > 0
+                assert kw["transfers"] == 2  # one bucket shape
         telemetry.close()
 
     def test_every_span_of_a_driven_epoch_carries_its_ids(
@@ -950,7 +952,8 @@ class TestDriverSpans:
         state = drv.warm(state)
         assert not RUN_SPANS & {e["name"] for e in telemetry.spans.events}
         assert not {"scan_chunks", "sched_builds", "sched_perms_staged",
-                    "scan_steps"} & set(telemetry.counters())
+                    "sched_transfers", "scan_steps"} & set(
+                        telemetry.counters())
 
         # fit's pair with the deferred fetch, then the synchronous pair
         state, pending = drv.run_epoch_pair(state, first=False,
@@ -1010,6 +1013,8 @@ class TestDriverSpans:
             assert put["args"]["perms"] == sched["args"]["perms"] == 3
             assert sched["args"]["chunks"] == 3
             assert put["args"]["bytes"] == 4 * 6  # six int32 steps
+            # one array and one zero cursor, whatever the chunks
+            assert put["args"]["transfers"] == 2
 
         # the fetch: started on the dispatch thread (the async pair only),
         # run on the fetch thread there and inline in the sync pair
@@ -1026,7 +1031,53 @@ class TestDriverSpans:
         assert counters["scan_steps"] == 14
         assert counters["sched_builds"] == 3
         assert counters["sched_perms_staged"] == 9
+        assert counters["sched_transfers"] == 6
         json.dumps(telemetry.spans.events)
+        telemetry.close()
+
+    @pytest.mark.parametrize("chunk_steps", [1, 4])
+    def test_a_schedule_reaches_the_device_in_two_transfers_a_shape(
+            self, tiny_dataset, tmp_path, chunk_steps):
+        """The mechanism of PR 40, pinned: a schedule's perms go over as
+        one array and one zero cursor a bucket shape, however many chunks
+        they are cut into (``chunk_steps`` 1 makes twelve chunks an epoch
+        here, 4 makes three), and outside the schedule's own staging a
+        warmed driver's epoch moves NOTHING from the host to the device,
+        stated or implied: no per-chunk perm, offset or scalar."""
+        telemetry = Telemetry("epoch", str(tmp_path / "t"))
+        drv, state = self._driver(tiny_dataset, telemetry, copies=4)
+        drv.chunk_steps = chunk_steps
+        state = drv.warm(state)
+
+        sched = drv._sched
+
+        def staging_allowed(*a, **kw):
+            with jax.transfer_guard_host_to_device("allow"):
+                return sched(*a, **kw)
+
+        drv._sched = staging_allowed
+        with jax.transfer_guard_host_to_device("disallow_explicit"):
+            state, pending = drv.run_epoch_pair(state, first=False,
+                                                async_fetch=True)
+            pending.result()
+            state, _, _ = drv.run_epoch_pair(state, first=False)
+
+        events = telemetry.spans.events
+        chunks = [e for e in events if e["name"] == "scan.chunk"
+                  and e["args"]["train"]]
+        assert len(chunks) == 2 * -(-12 // chunk_steps)
+        puts = [e["args"] for e in events if e["name"] == "epoch.sched.put"]
+        # the async pair's deferred prebuild, the sync pair's prebuild (the
+        # first epoch's own schedule and eval's were staged in warm-up)
+        assert len(puts) == 2
+        for put in puts:
+            assert put["transfers"] == 2  # one bucket shape
+            assert put["perms"] == -(-12 // chunk_steps)
+            assert put["bytes"] == 4 * 12
+        counters = telemetry.counters()
+        assert counters["sched_transfers"] == 2 * counters["sched_builds"] == 4
+        assert counters["sched_perms_staged"] == sum(
+            put["perms"] for put in puts)
         telemetry.close()
 
     def test_without_telemetry_the_chunk_loop_builds_nothing(
@@ -1078,19 +1129,21 @@ class TestDriverSpans:
         and off, through the deferred fetch and the synchronous one."""
         def run(telemetry):
             drv, state = self._driver(tiny_dataset, telemetry, copies=2)
-            real, seen = drv._scan_fn, []
+            real, seen = drv._window_fn, []
 
             def recording(cache, key, body, train):
                 fn = real(cache, key, body, train)
 
-                def dispatch(state, stacked, perm):
-                    seen.append((fn.__name__, np.asarray(perm).tolist()))
-                    return fn(state, stacked, perm)
+                def dispatch(state, stacked, perm_all, cursor):
+                    at = int(cursor)
+                    seen.append((fn.__name__, np.asarray(
+                        perm_all)[at:at + key[1]].tolist()))
+                    return fn(state, stacked, perm_all, cursor)
 
                 dispatch.__name__ = fn.__name__
                 return dispatch
 
-            drv._scan_fn = recording
+            drv._window_fn = recording
             state, _, _ = drv.run_epoch_pair(state, first=True)
             seen.clear()
             state, pending = drv.run_epoch_pair(state, first=False,

@@ -109,12 +109,14 @@ def _full_staging_scan_program(one_chip, task: str, dtype: str):
         guard_step(bodies[0]), bodies[1], batches, [],
         np.random.default_rng(0), chunk_steps=2)
     (key, stacked), = driver._train_groups.items()
-    fn = driver._scan_fn(driver._train_scans, (key, 2), driver._train_body,
-                         True)
+    # the form an epoch runs: the group's whole perm and a cursor into it
+    fn = driver._window_fn(driver._train_scans, (key, 2),
+                           driver._train_body, True)
     shapes = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
                                        sharding=one_chip),
-        (state, stacked, np.zeros(2, np.int32)))
+        (state, stacked, np.zeros(len(batches), np.int32),
+         np.zeros((), np.int32)))
     text = fn.lower(*shapes).compile().as_text()
     return text, graphs, batches, node_cap
 
@@ -168,7 +170,8 @@ def _ocp_scan_program(one_chip):
 
     key = max(drv._train_groups, key=node_capacity)
     stacked = drv._train_groups[key]
-    fn = drv._scan_fn(drv._train_scans, (key, steps), drv._train_body, True)
+    fn = drv._window_fn(drv._train_scans, (key, steps), drv._train_body,
+                        True)
     stack = 32 * copies
 
     def shape(x, lead=None):
@@ -178,7 +181,8 @@ def _ocp_scan_program(one_chip):
     compiled = fn.lower(
         jax.tree_util.tree_map(shape, bench.state),
         jax.tree_util.tree_map(lambda x: shape(x, stack), stacked),
-        shape(np.zeros(steps, np.int32))).compile()
+        shape(np.zeros(stack, np.int32)),
+        shape(np.zeros((), np.int32))).compile()
     batch = jax.tree_util.tree_map(lambda x: x[0], stacked)
     m = cfg["model"]
     return (compiled.as_text(), batch, node_capacity(key),
@@ -551,8 +555,8 @@ def _four_chip_program(mesh):
         np.random.default_rng(0), stage=flat_rows,  # shard_scan_stack's form
         chunk_steps=2)
     (key, stacked), = driver._train_groups.items()
-    fn = driver._scan_fn(driver._train_scans, (key, 4), driver._train_body,
-                         True)
+    fn = driver._window_fn(driver._train_scans, (key, 4),
+                           driver._train_body, True)
     replicated = NamedSharding(mesh, P())
 
     def staged(x):  # [1, D, ...] -> the real stack, split over the chips
@@ -566,7 +570,8 @@ def _four_chip_program(mesh):
             lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
                                            sharding=replicated), state),
         jax.tree_util.tree_map(staged, stacked),
-        jax.ShapeDtypeStruct((4,), np.int32, sharding=replicated),
+        jax.ShapeDtypeStruct((stack,), np.int32, sharding=replicated),
+        jax.ShapeDtypeStruct((), np.int32, sharding=replicated),
     )
     compiled = fn.lower(*shapes).compile()
     row = sum(int(np.prod(np.shape(x)[2:])) * x.dtype.itemsize

@@ -234,13 +234,75 @@ class TestFit:
         n_programs = len(d3._train_scans)
         for _ in range(3):
             s3, _, _ = d3.run_epoch_pair(s3, first=False)
-        assert len(d3._train_scans) == n_programs
+        assert len(d3._train_scans) == n_programs > 0
 
         # proportional tail: small groups no longer dispatch mostly
         # single-step scans
         assert d3._tail_for(6) == 1
         assert d3._tail_for(40) == 8   # capped at mixed_tail
         assert d3._tail_for(1) == 1    # never zero for a real group
+
+    def test_scan_fn_runs_the_windows_own_program(self, tiny_dataset):
+        """``_scan_fn``'s ``(state, stacked, perm)`` form, which the
+        benchmark's ``check`` drives, is the program an epoch runs and
+        ``warm()`` compiled, handed the chunk's perm at the front of a
+        ``perm_all`` with the cursor at zero: it adds no program and
+        compiles nothing on a warmed driver, and chunk after chunk it
+        leaves the bits in state and sums that the window's program
+        leaves walking one cursor along the whole perm."""
+        from cgnn_tpu.data.graph import bucketed_batch_iterator
+        from cgnn_tpu.train.loop import ScanEpochDriver, _compile_events
+        from cgnn_tpu.train.step import make_eval_step, make_train_step
+
+        train_g, _, _ = tiny_dataset
+        batches = list(bucketed_batch_iterator(
+            train_g, 8, 1, shuffle=True, rng=np.random.default_rng(0)))
+
+        def fresh():
+            model = CrystalGraphConvNet(atom_fea_len=16, n_conv=1,
+                                        h_fea_len=16)
+            state = create_train_state(
+                model, batches[0], make_optimizer(optim="sgd", lr=0.01),
+                Normalizer.fit(np.stack([g.target for g in train_g])),
+                rng=jax.random.key(0),
+            )
+            # committed to its device, as warm()'s scratch copy is and as
+            # the benchmark's kinds hand theirs on: jit keys its programs
+            # on that too
+            return jax.device_put(state, jax.devices()[0]), ScanEpochDriver(
+                make_train_step(), make_eval_step(), batches, [],
+                np.random.default_rng(7))
+
+        sw, dw = fresh()
+        sp, dp = fresh()
+        sp = dp.warm(sp)
+        programs = dict(dp._train_scans)
+        (key, stacked), = dw._train_groups.items()
+        n = len(batches)
+        perm = np.random.default_rng(1).permutation(n).astype(np.int32)
+        perm_all, cursor = dw._put_perms(perm, stacked)
+        at = 0
+        for length in (2, 1, 2):
+            assert at + length <= n
+            window = dw._window_fn(dw._train_scans, (key, length),
+                                   dw._train_body, True)
+            scan = dp._scan_fn(dp._train_scans, (key, length),
+                               dp._train_body, True)
+            sw, sums_w, cursor = window(sw, stacked, perm_all, cursor)
+            with _compile_events() as seen:
+                sp, sums_p = scan(sp, dp._train_groups[key],
+                                  jnp.asarray(perm[at:at + length]))
+            assert seen == {"compiled": False, "cache_read": False}
+            at += length
+            assert int(cursor) == at
+            assert sums_w.keys() == sums_p.keys()
+            for k in sums_w:
+                np.testing.assert_array_equal(np.asarray(sums_w[k]),
+                                              np.asarray(sums_p[k]))
+            for a, b in zip(jax.tree_util.tree_leaves(sw.params),
+                            jax.tree_util.tree_leaves(sp.params)):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert dp._train_scans == programs
 
     def test_async_pair_fetch_bit_identical(self, tiny_dataset):
         """ISSUE 5 satellite: the background-thread epoch-pair fetch is
