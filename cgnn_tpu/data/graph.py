@@ -198,6 +198,10 @@ def batch_shape_key(batch) -> tuple:
         from cgnn_tpu.data.compact import compact_shape_key
 
         return compact_shape_key(batch)
+    if hasattr(batch, "tokens"):  # TokenBatch (data/tokens.py)
+        from cgnn_tpu.data.tokens import token_shape_key
+
+        return token_shape_key(batch)
     return (
         np.shape(batch.nodes),
         # dtype too: f32 and bf16 edge batches with identical shapes must

@@ -321,13 +321,42 @@ def check_stacked_batch(stacked, dense_m: int | None = None,
     return stacked
 
 
+def check_token_batch(batch):
+    """Validate one host-side ``TokenBatch`` (data/tokens.py): the noised
+    copy and the clean one side by side, ids non-negative, documents as
+    non-decreasing runs from 0, and a weight in the loss wherever the
+    noised token differs from the clean one."""
+    tokens = np.asarray(batch.tokens)
+    seg = np.asarray(batch.segment_ids)
+    w = np.asarray(batch.loss_weight)
+    if tokens.ndim != 2 or tokens.shape[1] != 2 * seg.shape[1] \
+            or seg.shape != w.shape or seg.shape[0] != tokens.shape[0]:
+        _fail(f"token batch shapes disagree: tokens {tokens.shape}, "
+              f"segment_ids {seg.shape}, loss_weight {w.shape}")
+    chex.assert_type([tokens, seg], np.integer)
+    if tokens.min() < 0:
+        _fail("negative token id")
+    if (np.diff(seg, axis=1) < 0).any() or (seg[:, 0] != 0).any():
+        _fail("segment_ids are not non-decreasing runs from 0")
+    if not np.isfinite(w).all() or (w < 0).any():
+        _fail("loss weights must be finite and non-negative")
+    length = seg.shape[1]
+    changed = tokens[:, :length] != tokens[:, length:]
+    if (changed & (w == 0)).any():
+        _fail("a noised token without a weight in the loss")
+    return batch
+
+
 def check_any(batch, dense_m: int | None = None, train: bool = False):
-    """Dispatch on stacking: 1-D node_mask -> single batch, 2-D -> stacked.
+    """Dispatch on the batch's kind and stacking: a token batch; else 1-D
+    node_mask -> single batch, 2-D -> stacked.
 
     Single training batches cannot be empty by construction
     (batch_iterator never yields an empty pack), so ``train`` only adds
     the non-empty-row requirement for stacked batches.
     """
+    if hasattr(batch, "tokens"):
+        return check_token_batch(batch)
     if np.ndim(batch.node_mask) == 1:
         if hasattr(batch, "atom_idx"):
             return check_compact_batch(batch, dense_m)

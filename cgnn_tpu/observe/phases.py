@@ -6,7 +6,8 @@ every instruction's metadata carries the ``op_name`` JAX built from the name
 stack — flax module scopes (``conv_1/bn1``), transforms (``jvp``,
 ``transpose``) and the ``jax.named_scope`` s the program adds where flax says
 nothing (models/cgcnn.py, models/forcefield.py, train/step.py,
-train/force_step.py, resilience/guard.py, data/compact.py, train/loop.py). ``classify`` maps such a path to one phase
+train/force_step.py, resilience/guard.py, data/compact.py, train/loop.py,
+models/sdar.py, ops/moe.py, train/lm_step.py). ``classify`` maps such a path to one phase
 of ``PHASES`` and a direction; ``phase_table`` does so for every instruction
 of an optimized HLO module that the device can report as an event.
 
@@ -42,11 +43,24 @@ SCAN = "scan"
 # train/step.py: the pmean/psum of gradients, statistics and metric sums
 # under data parallelism (the one phase a one-chip program never has)
 DP_ALLREDUCE = "dp.allreduce"
+# models/sdar.py, ops/moe.py, train/lm_step.py: the block-diffusion
+# mixture-of-experts decoder. ``attn.proj`` is the projections, the head
+# norms, RoPE and ``W_o``; ``attn.bd`` the masked attention alone;
+# ``moe.route`` the router, the top-k, the sort and the rows' way out and
+# back; ``moe.expert`` the grouped matmuls; ``lm.head`` the final norm, the
+# head and the loss
+LM_EMBED = "lm.embed"
+ATTN_PROJ = "attn.proj"
+ATTN_BD = "attn.bd"
+MOE_ROUTE = "moe.route"
+MOE_EXPERT = "moe.expert"
+LM_HEAD = "lm.head"
 OTHER = "other"
 
 PHASES = (EXPAND, EMBED, EDGE_GEOM, CONV_GATHER, CONV_FC_FULL, CONV_BN1,
           CONV_GATE, CONV_AGGREGATE, CONV_BN2, CONV_LN, POOL_HEAD,
-          FORCE_READOUT, LOSS, OPTIMIZER, SCAN, DP_ALLREDUCE, OTHER)
+          FORCE_READOUT, LOSS, OPTIMIZER, SCAN, DP_ALLREDUCE, LM_EMBED,
+          ATTN_PROJ, ATTN_BD, MOE_ROUTE, MOE_EXPERT, LM_HEAD, OTHER)
 FWD, BWD, BWD2 = "fwd", "bwd", "bwd2"
 
 # a path component -> its phase: the named scopes themselves, and the flax
@@ -119,23 +133,34 @@ _OPERAND = re.compile(r"%([\w.\-]+)")
 
 
 def _parse(hlo_text: str) -> dict:
-    """{computation: {"instrs": {name: rest of line}, "root": name}}."""
+    """{computation: {"instrs": {name: rest of its text}, "root": name}}.
+
+    An instruction is one line, but for a kernel's custom call: its
+    ``backend_config`` runs over several lines, the last of which (``}},
+    metadata={op_name=...}``) starts in the first column as a computation's
+    closing brace does. Only a line that is a brace alone closes a
+    computation; any other line that opens no instruction belongs to the
+    instruction above it (its ``op_name`` is there)."""
     comps: dict = {}
-    cur = None
+    cur = last = None
     for line in hlo_text.splitlines():
         if cur is None:
             m = _COMPUTATION.match(line)
             if m:
                 cur = comps[m.group(1)] = {"instrs": {}, "root": None}
+                last = None
             continue
-        if line.startswith("}"):
+        if line.rstrip() == "}":
             cur = None
             continue
         m = _INSTRUCTION.match(line)
         if m:
-            cur["instrs"][m.group(2)] = m.group(3)
+            last = m.group(2)
+            cur["instrs"][last] = m.group(3)
             if m.group(1):
-                cur["root"] = m.group(2)
+                cur["root"] = last
+        elif last is not None:
+            cur["instrs"][last] += " " + line.strip()
     return comps
 
 

@@ -1,0 +1,97 @@
+"""``train.py --task blockdiff``: the block-diffusion mixture-of-experts
+decoder (models/sdar.py) through ``fit`` and the scan epoch driver, as every
+task goes: token batches staged resident, ``TrainState`` and
+``make_optimizer``, spans and phases.
+
+The model is a preset (``tiny``, for the CPU; ``sdar-ep8``, one chip's share
+of SDAR-30B-A3B-Chat as ``benchmark/configs/sdar-30b-a3b-ep8.json`` has it)
+or a JSON file of ``SdarConfig``'s fields. The data is a synthetic pool of
+packed, pre-noised sequences (data/tokens.py): a tokenizer and a corpus
+reader are not part of this repo.
+"""
+
+from __future__ import annotations
+
+import functools
+import json
+
+PRESETS = {
+    "tiny": dict(hidden_size=64, num_attention_heads=4,
+                 num_key_value_heads=2, head_dim=16, num_hidden_layers=2,
+                 n_experts=16, num_experts_per_tok=4, experts_held=(0, 4),
+                 moe_intermediate_size=32, vocab_size=256, dtype="float32"),
+    "sdar-ep8": dict(num_hidden_layers=4),  # the dataclass's defaults
+}
+
+
+def model_config(spec: str, bf16: bool):
+    from cgnn_tpu.models.sdar import SdarConfig
+
+    if spec in PRESETS:
+        fields = dict(PRESETS[spec])
+    else:
+        with open(spec) as f:
+            fields = json.load(f)
+        if "experts_held" in fields:
+            fields["experts_held"] = tuple(fields["experts_held"])
+    if bf16:
+        fields["dtype"] = "bfloat16"
+    return SdarConfig(**fields)
+
+
+def run(args, telemetry, preempt=None, log_fn=print) -> int:
+    """The task's ``main``: pool, state, ``fit``. ``args`` are train.py's."""
+    import jax
+    import jax.numpy as jnp
+
+    from cgnn_tpu.data import tokens
+    from cgnn_tpu.models import sdar
+    from cgnn_tpu.train import Normalizer, fit, make_optimizer
+    from cgnn_tpu.train.lm_step import make_lm_eval_step, make_lm_train_step
+    from cgnn_tpu.train.state import TrainState
+
+    cfg = model_config(args.lm_model, args.bf16)
+    n = args.synthetic or 16
+    per_step = args.batch_size
+    length = args.lm_seq_len
+    pool = tokens.make_pool(
+        n, length, vocab_size=cfg.vocab_size, block=cfg.block_length,
+        seed=args.seed, doc_median=length / 2, doc_min=cfg.block_length,
+        doc_max=length)
+    batches = tokens.split_batches(pool, per_step)
+    n_val = max(1, len(batches) // 8)
+    train_b, val_b = batches[:-n_val], batches[-n_val:]
+    log_fn(f"blockdiff: {cfg.n_params() / 1e6:.2f} M parameters "
+           f"({cfg.num_hidden_layers} layers, experts "
+           f"{cfg.experts_held[0]}..{sum(cfg.experts_held) - 1} of "
+           f"{cfg.n_experts} held), {n} sequences of {length} tokens, "
+           f"{len(train_b)} train / {len(val_b)} val steps of {per_step}")
+    tx = make_optimizer(
+        optim=args.optim, lr=args.lr, momentum=args.momentum,
+        weight_decay=args.weight_decay,
+        lr_milestones=[m * len(train_b) for m in args.lr_milestones])
+    params = jax.jit(functools.partial(sdar.init_params, cfg))(
+        jax.random.key(args.seed))
+    state = TrainState(
+        step=jnp.zeros((), jnp.int32), params=params, batch_stats={},
+        opt_state=tx.init(params), normalizer=Normalizer.identity(1),
+        rng=jax.random.key(args.seed),
+        apply_fn=functools.partial(sdar.apply, cfg), tx=tx)
+    tiles = sdar.attention_tiles(cfg, length)
+    state, result = fit(
+        state, [], [], epochs=args.epochs, batch_size=per_step,
+        seed=args.seed, print_freq=0, scan_epochs=True,
+        packed=(train_b, val_b),
+        train_step_fn=make_lm_train_step(cfg, tiles),
+        eval_step_fn=make_lm_eval_step(cfg, tiles), best_metric="loss",
+        chunk_steps=args.chunk_steps, telemetry=telemetry, preempt=preempt,
+        log_fn=log_fn)
+    last = result["history"][-1]
+    for name in ("moe_rows_here", "moe_rows_balanced",
+                 "expert_load_max_over_mean", "bd_tiles_live",
+                 "bd_tiles_grid", "masked_tokens"):
+        telemetry.set_gauge(name, float(last["train"].get(name, 0.0)))
+    log_fn(f"** best val loss {result['best']:.4f}; a step routed "
+           f"{last['train']['moe_rows_here']:.0f} rows to the experts held "
+           f"({last['train']['moe_rows_balanced']:.0f} balanced)")
+    return 0

@@ -374,13 +374,18 @@ class PendingPairMetrics:
 def program_name(key, train: bool) -> str:
     """The name of one scan program, as the profiler's ``XLA Modules``
     line shows it (``jit_scan_train_n23944_l2``): train or eval, the
-    bucket's node capacity, the chunk length. ``key`` is ``(shape key,
-    length)`` with the shape key of ``batch_shape_key``."""
+    bucket's node capacity (of a token batch, its positions), the chunk
+    length. ``key`` is ``(shape key, length)`` with the shape key of
+    ``batch_shape_key``."""
     shape_key, length = key
-    # nodes [.., N, F] of a GraphBatch, distances [.., N, M] of a compact one
-    shape = shape_key[1] if shape_key[0] == "compact" else shape_key[0]
-    return (f"scan_{'train' if train else 'eval'}_n{int(shape[-2])}"
-            f"_l{int(length)}")
+    if shape_key[0] == "tokens":  # tokens [.., S, 2L]
+        rows = int(np.prod(shape_key[1][-2:]))
+    else:
+        # nodes [.., N, F] of a GraphBatch, distances [.., N, M] of a
+        # compact one
+        shape = shape_key[1] if shape_key[0] == "compact" else shape_key[0]
+        rows = int(shape[-2])
+    return f"scan_{'train' if train else 'eval'}_n{rows}_l{int(length)}"
 
 
 def staged_edge_fea_nbytes(batches) -> int:
@@ -563,6 +568,8 @@ class ScanEpochDriver:
         # _drive calls outside warm-up so far: the ``epoch`` that the
         # spans of one driven epoch share
         self._epochs_driven = 0
+        # [train means, val means] of warm()'s epoch
+        self.warm_metrics: list | None = None
 
     def _span(self, name: str):
         """A set-up span of the program's tracer (and, through it, of the
@@ -820,10 +827,15 @@ class ScanEpochDriver:
             self._telemetry.counter_add("sched_transfers", transfers)
         return sched, staged
 
-    def warm(self, state: TrainState) -> TrainState:
+    def warm(self, state: TrainState, consume: bool = False) -> TrainState:
         """Compile every (shape, chunk-length) scan program the driver can
         draw, so no first-compile (seconds through a high-latency link)
         lands inside a caller's timed region.
+
+        ``consume=True`` is for a state too large to hold twice (the
+        block-diffusion decoder's is most of a chip's memory): the programs
+        run on ``state`` itself, which is donated and gone, and the caller
+        builds its state anew afterwards. Returns None then.
 
         Runs the REAL train bodies (compilation requires execution here),
         but against a disposable on-device copy of ``state``, so the
@@ -846,7 +858,7 @@ class ScanEpochDriver:
         # unsafe_buffer_pointer, donation kills the original) — the
         # device_put onto the source sharding makes the replicated/sharded
         # layout explicit on a buffer that is already a fresh copy.
-        scratch = jax.tree_util.tree_map(
+        scratch = state if consume else jax.tree_util.tree_map(
             lambda x: jax.device_put(jnp.array(x), x.sharding)
             if isinstance(x, jax.Array) else x,
             state,
@@ -886,8 +898,12 @@ class ScanEpochDriver:
                     self._emit_program(spans, fn, name, key, ln,
                                        (scratch, stacked, perm_all, cursor))
             # eval programs + the pair plumbing compile on a normal epoch
+            # its means are kept: an epoch in pack order over the caller's
+            # state as the programs above left it, the same whatever rng
+            # the driver was given
             with self._span("warm.epoch"):
-                self.run_epoch_pair(scratch, first=True)
+                _, train_m, val_m = self.run_epoch_pair(scratch, first=True)
+                self.warm_metrics = [train_m, val_m]
             if spans is not None and self._eval_scans:
                 # as the warm epoch staged them, once for every epoch
                 _, staged = self._sched_cache[
@@ -896,7 +912,7 @@ class ScanEpochDriver:
                     self._emit_program(
                         spans, fn, program_name((key, ln), False), key, ln,
                         (scratch, self._val_groups[key], *staged[key]))
-        return state
+        return None if consume else state
 
     @staticmethod
     def _emit_program(spans, fn, name: str, key, length: int,
@@ -1177,8 +1193,16 @@ def fit(
     guard: bool = False,
     monitor=None,
     preempt=None,
+    packed: tuple[list, list] | None = None,
 ) -> tuple[TrainState, dict]:
     """Reference ``main()`` loop: train/validate per epoch, track best.
+
+    ``packed`` (requires ``scan_epochs``) hands over ``(train batches, val
+    batches)`` that a task packed itself: nothing is packed here,
+    ``train_graphs`` and ``val_graphs`` are not read, and everything after
+    the pack (staging, the scan driver, the epochs) is the path of every
+    task. The block-diffusion task's token batches come this way
+    (data/tokens.py): they are no graphs.
 
     ``train_step_fn``/``eval_step_fn`` override the default task steps (the
     force task passes its composite-loss steps); ``best_metric`` overrides
@@ -1244,7 +1268,10 @@ def fit(
     if compact is not None and dense_m is None:
         raise ValueError("compact staging requires the dense layout "
                          "(dense_m)")
-    if node_cap is None or edge_cap is None:
+    if packed is not None and not scan_epochs:
+        raise ValueError("packed batches are driven by the scan driver "
+                         "(scan_epochs)")
+    if packed is None and (node_cap is None or edge_cap is None):
         nc, ec = capacities_for(train_graphs, batch_size, dense_m=dense_m,
                                 snug=snug)
         node_cap, edge_cap = node_cap or nc, edge_cap or ec
@@ -1339,8 +1366,11 @@ def fit(
             expand = make_expander(compact)
         t_pack = time.perf_counter()
         with telemetry.span("pack"):
-            train_list = list(train_batches(rng))
-            val_list = list(val_batches())
+            if packed is not None:
+                train_list, val_list = (list(x) for x in packed)
+            else:
+                train_list = list(train_batches(rng))
+                val_list = list(val_batches())
         staging["pack_s"] = round(time.perf_counter() - t_pack, 2)
         staged_bytes = staged_nbytes(train_list + val_list)
         staging["staged_mb"] = round(staged_bytes / 1e6, 1)

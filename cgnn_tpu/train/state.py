@@ -60,15 +60,19 @@ def make_optimizer(
     lr_milestones: Sequence[int] = (),
     lr_gamma: float = 0.1,
     grad_clip: float = 0.0,
+    b1: float = 0.9,
+    b2: float = 0.999,
 ) -> optax.GradientTransformation:
-    """SGD+momentum or Adam with a MultiStepLR schedule (reference defaults)."""
+    """SGD+momentum or Adam with a MultiStepLR schedule (reference defaults).
+    ``b1``/``b2`` are Adam's and AdamW's decay rates (optax's defaults)."""
     schedule = multistep_lr(lr, lr_milestones, lr_gamma)
     if optim.lower() == "sgd":
         core = optax.sgd(schedule, momentum=momentum)
     elif optim.lower() == "adam":
-        core = optax.adam(schedule)
+        core = optax.adam(schedule, b1=b1, b2=b2)
     elif optim.lower() == "adamw":
-        core = optax.adamw(schedule, weight_decay=weight_decay)
+        core = optax.adamw(schedule, b1=b1, b2=b2,
+                           weight_decay=weight_decay)
     else:
         raise ValueError(f"unknown optimizer {optim!r} (sgd|adam|adamw)")
     parts = []

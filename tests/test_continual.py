@@ -527,8 +527,17 @@ class TestCanaryController:
             time.sleep(0.05)
         manifests = glob.glob(pat)
         assert manifests, f"no rollback bundle matching {pat}"
-        with open(manifests[0]) as f:
-            manifest = json.load(f)
+        # the recorder's thread may still be writing the file it has just
+        # created (seen once under six workers, PR 42): read it whole
+        while True:
+            try:
+                with open(manifests[0]) as f:
+                    manifest = json.load(f)
+                break
+            except json.JSONDecodeError:
+                if time.monotonic() >= deadline:
+                    raise
+                time.sleep(0.05)
         assert self.CAND in json.dumps(manifest)
 
     def test_pin_timeout_rejects_candidate(self):

@@ -413,6 +413,7 @@ _SCAN = "jit(scan_train_n672_l2)/while/body/closed_call/"
 _FWD = _SCAN + "jvp(CrystalGraphConvNet)/"
 _BWD = _SCAN + "transpose(jvp(CrystalGraphConvNet))/"
 _F2 = "jit(scan_train_n192_l2)/while/body/closed_call/"
+_LM = "jit(scan_train_n16384_l2)/while/body/closed_call/"
 _CLASSIFIED = [
     ("jit(train_step)/jvp(CrystalGraphConvNet)/conv_1/bn1/mul",
      ("conv.bn1", "fwd")),
@@ -512,6 +513,30 @@ _CLASSIFIED = [
     # four-device scan program of parallel/data_parallel.py names them
     (_SCAN + "jit(shmap_body)/dp.allreduce/psum", ("dp.allreduce", "fwd")),
     (_SCAN + "jit(shmap_body)/dp.allreduce/div", ("dp.allreduce", "fwd")),
+    # the block-diffusion decoder (models/sdar.py): scopes under the layer
+    # scan, the per-sequence map and their checkpoints
+    (_LM + "jvp(lm.embed)/gather", ("lm.embed", "fwd")),
+    (_LM + "transpose(jvp(lm.embed))/scatter-add", ("lm.embed", "bwd")),
+    (_LM + "jvp(while)/body/while/body/checkpoint/attn.proj/dot_general",
+     ("attn.proj", "fwd")),
+    (_LM + "transpose(jvp(while))/body/while/body/checkpoint/"
+     "rematted_computation/attn.proj/dot_general", ("attn.proj", "bwd")),
+    (_LM + "jvp(while)/body/while/body/checkpoint/attn.bd/vmap(vmap("
+     "jit(splash_mqa)))/pallas_call", ("attn.bd", "fwd")),
+    (_LM + "transpose(jvp(while))/body/while/body/checkpoint/attn.bd/"
+     "pallas_call", ("attn.bd", "bwd")),
+    (_LM + "jvp(while)/body/while/body/checkpoint/moe.route/sort",
+     ("moe.route", "fwd")),
+    (_LM + "transpose(jvp(while))/body/while/body/checkpoint/moe.route/"
+     "gather", ("moe.route", "bwd")),
+    (_LM + "jvp(while)/body/while/body/checkpoint/moe.expert/"
+     "jit(gmm)/pallas_call", ("moe.expert", "fwd")),
+    (_LM + "transpose(jvp(while))/body/while/body/checkpoint/moe.expert/"
+     "jit(tgmm)/pallas_call", ("moe.expert", "bwd")),
+    (_LM + "jvp(lm.head)/while/body/checkpoint/dot_general",
+     ("lm.head", "fwd")),
+    (_LM + "transpose(jvp(lm.head))/while/body/checkpoint/"
+     "rematted_computation/dot_general", ("lm.head", "bwd")),
     ("jit(train_step)/add", ("other", "fwd")),
     (_SCAN + "mul", ("other", "fwd")),
     ("reduce_sum", ("other", "fwd")),
@@ -534,7 +559,9 @@ class TestPhases:
         assert {p for p, _ in seen} == set(phases.PHASES)
         two_way = {"conv.gather", "conv.fc_full", "conv.bn1", "conv.gate",
                    "conv.aggregate", "conv.bn2", "conv.ln", "embed",
-                   "pool_head", "loss", "edge_geom", "force_readout"}
+                   "pool_head", "loss", "edge_geom", "force_readout",
+                   "lm.embed", "attn.proj", "attn.bd", "moe.route",
+                   "moe.expert", "lm.head"}
         assert {p for p, d in seen if d == "bwd"} == two_way
         # what the force step differentiates twice: the trunk without
         # BatchNorm, the geometry and the readout (not the embedding, which
@@ -542,6 +569,37 @@ class TestPhases:
         assert {p for p, d in seen if d == "bwd2"} == {
             "edge_geom", "force_readout", "conv.gather", "conv.fc_full",
             "conv.gate", "conv.aggregate"}
+
+    def test_a_kernel_s_custom_call_spans_lines(self):
+        """A Pallas kernel's ``backend_config`` runs over several lines and
+        its last one (``}}, metadata=...``) starts in the first column: it
+        closes no computation, and the instruction's ``op_name`` is on it
+        (the chip's first trace of ``sdar.train`` lost 71% of its time to
+        this: PERF.md section 6, PR 42)."""
+        from cgnn_tpu.observe import phases
+
+        text = """HloModule jit_step
+
+%body (p: (f32[8,8])) -> (f32[8,8]) {
+  %p = (f32[8,8]{1,0}) parameter(0)
+  %x = f32[8,8]{1,0} get-tuple-element(%p), index=0
+  %gmm.7 = f32[8,8]{1,0} custom-call(%x), custom_call_target="tpu_custom_call", backend_config={"custom_call_config": {"body": "abc
+def
+}}, metadata={op_name="jit(step)/jvp()/while/body/moe.expert/jit(gmm)/pallas_call"}
+  %fusion.9 = f32[8,8]{1,0} fusion(%gmm.7), kind=kLoop, calls=%fused.1, metadata={op_name="jit(step)/jvp()/while/body/moe.route/mul"}
+  ROOT %t = (f32[8,8]{1,0}) tuple(%fusion.9)
+}
+
+ENTRY %main (a: f32[8,8]) -> f32[8,8] {
+  %a = f32[8,8]{1,0} parameter(0)
+  ROOT %w = f32[8,8]{1,0} copy(%a), metadata={op_name="jit(step)/lm.head/add"}
+}
+"""
+        table = phases.phase_table(text)
+        assert table["gmm.7"] == ("moe.expert", "fwd")
+        assert table["fusion.9"] == ("moe.route", "fwd")
+        assert table["w"] == ("lm.head", "fwd")
+        assert table["x"] == ("other", "fwd")
 
     def test_phase_table_on_a_compiled_program(self):
         """Instructions of the entry and of the loop body are in the table

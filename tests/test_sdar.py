@@ -1,0 +1,430 @@
+"""The block-diffusion mixture-of-experts decoder (models/sdar.py,
+ops/bd_attention.py, ops/moe.py, train/lm_step.py, data/tokens.py) at a tiny
+size on the CPU, against the plain reference
+(benchmark/reference/sdar_ref.py), which imports nothing of the program.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.kinds import bd_train  # noqa: E402
+from benchmark.reference import sdar_ref as ref  # noqa: E402
+from cgnn_tpu.data import invariants, tokens  # noqa: E402
+from cgnn_tpu.models import sdar  # noqa: E402
+from cgnn_tpu.ops import moe  # noqa: E402
+from cgnn_tpu.ops.bd_attention import bd_attention, bd_mask, bd_tiles  # noqa: E402
+from cgnn_tpu.train import Normalizer, make_optimizer  # noqa: E402
+from cgnn_tpu.train.lm_step import make_lm_train_step  # noqa: E402
+from cgnn_tpu.train.state import TrainState  # noqa: E402
+
+L, BLOCK = 32, 4
+CFG = sdar.SdarConfig(
+    hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
+    head_dim=16, num_hidden_layers=2, n_experts=16, num_experts_per_tok=4,
+    experts_held=(4, 4), moe_intermediate_size=32, vocab_size=128,
+    block_length=BLOCK, dtype="float32")
+REF_CFG = {
+    "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2,
+    "head_dim": 16, "num_hidden_layers": 2, "num_experts_per_tok": 4,
+    "moe_intermediate_size": 32, "vocab_size": 128, "rms_norm_eps": 1e-6,
+    "rope_theta": 1e6, "experts_held": (4, 4), "block_length": BLOCK}
+ADAMW = dict(lr=1e-3, b1=0.9, b2=0.95, weight_decay=0.1)
+
+
+def _pool(seed=0, n=6):
+    return tokens.make_pool(n, L, vocab_size=CFG.vocab_size, block=BLOCK,
+                            seed=seed, doc_median=12, doc_min=4, doc_max=L)
+
+
+def _params(seed):
+    p = sdar.init_params(CFG, jax.random.key(seed), std=0.3)
+    # norm scales off 1, so that a dropped scale shows
+    return jax.tree_util.tree_map_with_path(
+        lambda path, x: (x * (1.0 + 0.1 * jnp.cos(jnp.arange(
+            x.size, dtype=jnp.float32).reshape(x.shape)))).astype(jnp.float32)
+        if "norm" in str(path[-1]) else x, p)
+
+
+def _state(params):
+    tx = make_optimizer("adamw", lr=ADAMW["lr"], b1=ADAMW["b1"],
+                        b2=ADAMW["b2"], weight_decay=ADAMW["weight_decay"],
+                        lr_milestones=[])
+    return TrainState(
+        step=jnp.zeros((), jnp.int32), params=params, batch_stats={},
+        opt_state=tx.init(params), normalizer=Normalizer.identity(1),
+        rng=jax.random.key(0), apply_fn=functools.partial(sdar.apply, CFG),
+        tx=tx)
+
+
+def _as_ref(b):
+    return {"tokens": b.tokens, "segment_ids": b.segment_ids,
+            "loss_weight": b.loss_weight}
+
+
+# ---- the pool ---------------------------------------------------------
+
+def test_the_pool_is_packed_and_noised_as_the_objective_says():
+    pool = _pool(3, n=64)
+    assert pool.tokens.shape == (64, 2 * L) and pool.tokens.dtype == np.int32
+    noised, clean = pool.tokens[:, :L], pool.tokens[:, L:]
+    mask_id = CFG.mask_id
+    assert clean.max() < mask_id and clean.min() >= 0
+    masked = noised == mask_id
+    assert ((noised == clean) | masked).all()
+    # a weight exactly where the token was masked, 1/t of its block: the
+    # same in every masked token of a block, and at least 1
+    assert ((pool.loss_weight > 0) == masked).all()
+    blocks = pool.loss_weight.reshape(64, L // BLOCK, BLOCK)
+    for blk in blocks.reshape(-1, BLOCK):
+        seen = np.unique(blk[blk > 0])
+        assert len(seen) <= 1 and (seen >= 1.0).all()
+    # no padding; documents are runs from 0 with boundaries on whole blocks
+    seg = pool.segment_ids
+    assert (seg[:, 0] == 0).all() and (np.diff(seg, axis=1) >= 0).all()
+    assert (np.diff(seg, axis=1).reshape(64, -1)[:, BLOCK - 1::BLOCK].sum()
+            == np.diff(seg, axis=1).sum())
+    assert seg.max() >= 1  # some sequence holds several documents
+    # the same seed gives the same pool; another seed another
+    again = _pool(3, n=64)
+    assert all((a == b).all() for a, b in zip(pool, again))
+    assert (pool.tokens != _pool(4, n=64).tokens).any()
+    # E[weight] is 1 a token: the loss is an unbiased bound
+    assert 0.7 < pool.loss_weight.mean() < 1.4
+
+
+def test_invariants_and_shape_key_take_a_token_batch():
+    from cgnn_tpu.data.graph import batch_shape_key
+    from cgnn_tpu.train import loop
+
+    batches = tokens.split_batches(_pool(), 2)
+    assert len(batches) == 3
+    key = batch_shape_key(batches[0])
+    assert key == ("tokens", (2, 2 * L))
+    assert loop.program_name((key, 2), True) == f"scan_train_n{4 * L}_l2"
+    invariants.check_any(batches[0], train=True)
+    bad = batches[0]._replace(loss_weight=np.zeros_like(
+        batches[0].loss_weight))
+    with pytest.raises(invariants.BatchInvariantError, match="weight"):
+        invariants.check_any(bad)
+    bad = batches[0]._replace(segment_ids=batches[0].segment_ids[:, ::-1] + 1)
+    with pytest.raises(invariants.BatchInvariantError, match="runs"):
+        invariants.check_any(bad)
+    # the staging counters read it as they read a graph batch
+    args = loop._staging_args(batches)
+    assert args["groups"] == 1 and args["batches"] == 3
+    assert args["bytes"] == sum(x.nbytes for b in batches for x in b)
+    assert args["edge_fea_bytes"] == 0
+    assert args["transpose_overflow_rows"] == 0
+
+
+# ---- the attention op -------------------------------------------------
+
+def _dense_attention(q, k, v, seg, causal=False):
+    """Plain softmax attention under the reference's dense mask."""
+    group = q.shape[1] // k.shape[1]
+    out = []
+    for s in range(q.shape[0]):
+        mask = ref.dense_mask(L, BLOCK, seg[s], causal)
+        ks, vs = (jnp.repeat(t[s], group, axis=0) for t in (k, v))
+        scores = jnp.where(mask, jnp.einsum("hqd,hkd->hqk", q[s], ks), -1e30)
+        out.append(jnp.einsum("hqk,hkd->hqd", jax.nn.softmax(scores, -1),
+                              vs))
+    return jnp.stack(out)
+
+
+def _qkv(seed):
+    ks = jax.random.split(jax.random.key(seed), 4)
+    q = jax.random.normal(ks[0], (2, 4, 2 * L, 16))
+    k = jax.random.normal(ks[1], (2, 2, 2 * L, 16))
+    v = jax.random.normal(ks[2], (2, 2, 2 * L, 16))
+    return q * 0.25, k, v, jax.random.normal(ks[3], (2, 4, 2 * L, 16))
+
+
+@pytest.mark.parametrize("packed", [False, True])
+def test_attention_agrees_with_the_dense_mask(packed):
+    q, k, v, w = _qkv(0)
+    seg = (jnp.asarray(_pool(1).segment_ids[:2]) if packed
+           else jnp.zeros((2, L), jnp.int32))
+    if packed:
+        assert int(seg.max()) >= 1
+    got = bd_attention(q, k, v, seg, block=BLOCK, impl="blocked")
+    want = _dense_attention(q, k, v, seg)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    f = lambda fn: jax.grad(lambda *a: (fn(*a) * w).sum(), (0, 1, 2))  # noqa: E731
+    g_got = f(lambda q, k, v: bd_attention(q, k, v, seg, block=BLOCK,
+                                           impl="blocked"))(q, k, v)
+    g_want = f(lambda q, k, v: _dense_attention(q, k, v, seg))(q, k, v)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_allclose(a, b, atol=5e-6)
+    # the plain causal mask is another function
+    assert float(jnp.abs(got - _dense_attention(q, k, v, seg,
+                                                causal=True)).max()) > 1e-2
+
+
+# (query, key, visible) over the doubled sequence of L = 32, blocks of 4:
+# position i of the noised half is i, of the clean half L + i
+REGIONS = [
+    ("noised sees its own block, later tokens too", 5, 7, True),
+    ("noised sees its own block, earlier tokens", 6, 4, True),
+    ("noised does not see a later noised block", 5, 8, False),
+    ("noised does not see an earlier noised block", 9, 5, False),
+    ("noised sees the clean keys of earlier blocks", 9, L + 3, True),
+    ("noised does not see its own block's clean keys", 9, L + 8, False),
+    ("noised does not see later clean keys", 9, L + 12, False),
+    ("clean sees its own block, later tokens too", L + 8, L + 11, True),
+    ("clean sees earlier clean blocks", L + 8, L + 1, True),
+    ("clean does not see later clean blocks", L + 8, L + 12, False),
+    ("clean never sees a noised key, its own position's", L + 8, 8, False),
+    ("clean never sees a noised key, an earlier block's", L + 8, 1, False),
+]
+
+
+@pytest.mark.parametrize("says,i,j,visible", REGIONS,
+                         ids=[r[0] for r in REGIONS])
+def test_every_region_of_the_mask(says, i, j, visible):
+    """The program's static mask and the reference's dense one agree on
+    the region, and the op's output at query ``i`` moves with key ``j``'s
+    value if and only if ``j`` is visible."""
+    mask = bd_mask(L, BLOCK)
+    dense = np.asarray(ref.dense_mask(L, BLOCK, jnp.zeros(L, jnp.int32)))
+    assert (mask == dense).all()
+    assert bool(mask[i, j]) is visible
+    q, k, v, _ = _qkv(2)
+    seg = jnp.zeros((2, L), jnp.int32)
+    moved = v.at[:, :, j].add(1.0)
+    a = bd_attention(q, k, v, seg, block=BLOCK, impl="blocked")
+    b = bd_attention(q, k, moved, seg, block=BLOCK, impl="blocked")
+    assert bool(jnp.abs(a - b)[:, :, i].max() > 1e-6) is visible
+
+
+def test_nothing_crosses_a_document():
+    q, k, v, _ = _qkv(3)
+    seg = jnp.asarray(np.repeat([[0, 1]], 2, 0).repeat(L // 2, axis=1)
+                      .astype(np.int32))
+    a = bd_attention(q, k, v, seg, block=BLOCK, impl="blocked")
+    # a key of the first document, clean half: visible to a later block of
+    # its own document, never to the second document's
+    j = L + 2
+    b = bd_attention(q, k, v.at[:, :, j].add(1.0), seg, block=BLOCK,
+                     impl="blocked")
+    moved = np.asarray(jnp.abs(a - b).max(axis=(0, 1, 3)) > 1e-6)
+    assert moved[L + 9] and moved[9]  # own document, later blocks
+    assert not moved[L + L // 2:].any() and not moved[L // 2:L].any()
+
+
+def test_tiles():
+    assert bd_tiles(L, BLOCK) == (1, 1)  # one tile holds this size
+    live, grid = bd_tiles(4096, 4)
+    assert grid == 256 and live == 80  # 8 + 36 + 0 + 36 tiles of 512
+    assert 0.25 < live / grid < 0.36
+
+
+# ---- the expert layer -------------------------------------------------
+
+def _expert_weights(seed, n_experts=16, h=64, inter=32):
+    ks = jax.random.split(jax.random.key(seed), 4)
+    return (jax.random.normal(ks[0], (24, h)),
+            jax.random.normal(ks[1], (h, n_experts)),
+            0.2 * jax.random.normal(ks[2], (n_experts, h, 2 * inter)),
+            0.2 * jax.random.normal(ks[3], (n_experts, inter, h)))
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """Four shares of four experts each: their partial outputs add up to
+    what the reference's uncut layer gives, and the rows they were routed
+    add up to every (token, choice) pair."""
+    x, router, w_gu, w_d = _expert_weights(0)
+    total, rows = 0.0, 0
+    for first in range(0, 16, 4):
+        out, sizes = moe.expert_share(
+            x, router, w_gu[first:first + 4], w_d[first:first + 4],
+            experts_held=(first, 4), k=4, impl="ragged")
+        assert int(sizes.sum()) == 24 * 4
+        rows += int(sizes[first:first + 4].sum())
+        total = total + out
+    assert rows == 24 * 4
+    want = ref.full_expert_layer(x, router, w_gu, w_d, 4)
+    np.testing.assert_allclose(total, want, rtol=2e-5, atol=2e-5)
+    # one share alone is not the layer
+    assert float(jnp.abs(out - want).max()) > 1e-2
+
+
+def test_dropless_under_a_router_forced_onto_one_held_expert():
+    """Every token's first choice is expert 5, held here: all 24 rows reach
+    it, none is dropped, and the output is the reference's."""
+    x, router, w_gu, w_d = _expert_weights(1)
+    router = router.at[:, 5].set(0.0) * 0.01
+    router = router.at[:, 5].set(50.0 * jnp.sign(x.sum(0)))
+    x = jnp.abs(x) * jnp.sign(x.sum(0))[None, :]  # x . router[:, 5] >> 0
+    out, sizes = moe.expert_share(
+        x, router, w_gu[4:8], w_d[4:8], experts_held=(4, 4), k=4,
+        impl="ragged")
+    assert int(sizes[5]) == 24
+    want, most = ref._experts(
+        x, {"router": router, "w_gate_up": w_gu[4:8], "w_down": w_d[4:8]},
+        REF_CFG, ref._mm_f32, None)
+    assert int(most) == 24
+    np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
+    # the reference's control drops rows, and reads otherwise
+    dropped, _ = ref._experts(
+        x, {"router": router, "w_gate_up": w_gu[4:8], "w_down": w_d[4:8]},
+        REF_CFG, ref._mm_f32, 6)
+    assert float(jnp.abs(dropped - want).max()) > 1e-3
+
+
+def test_routing_gradients_reach_the_router():
+    x, router, w_gu, w_d = _expert_weights(2)
+
+    def f(x, router, w_gu, w_d):
+        out, _ = moe.expert_share(x, router, w_gu[4:8], w_d[4:8],
+                                  experts_held=(4, 4), k=4, impl="ragged")
+        return (out ** 2).sum()
+
+    def g(x, router, w_gu, w_d):
+        out, _ = ref._experts(
+            x, {"router": router, "w_gate_up": w_gu[4:8],
+                "w_down": w_d[4:8]}, REF_CFG, ref._mm_f32, None)
+        return (out ** 2).sum()
+
+    got = jax.grad(f, (0, 1, 2, 3))(x, router, w_gu, w_d)
+    want = jax.grad(g, (0, 1, 2, 3))(x, router, w_gu, w_d)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+    assert float(jnp.abs(got[1]).max()) > 0
+    # the experts not held get no gradient
+    assert float(jnp.abs(got[2][:4]).max()) == 0.0
+
+
+def test_swiglu_s_reverse_pass():
+    gu = jax.random.normal(jax.random.key(0), (7, 10))
+    plain = lambda t: jax.nn.silu(t[:, :5]) * t[:, 5:]  # noqa: E731
+    np.testing.assert_allclose(moe.swiglu(gu), plain(gu), rtol=1e-6)
+    w = jnp.arange(35.0).reshape(7, 5)
+    np.testing.assert_allclose(
+        jax.grad(lambda t: (moe.swiglu(t) * w).sum())(gu),
+        jax.grad(lambda t: (plain(t) * w).sum())(gu), rtol=1e-5, atol=1e-6)
+
+
+# ---- the whole step against the reference -----------------------------
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_three_adamw_steps_agree_with_the_reference(seed):
+    """Loss, the first gradient leaf by leaf, and the parameters' change
+    after three AdamW steps, from seeded weights."""
+    params = _params(seed)
+    batches = tokens.split_batches(_pool(seed), 2)
+    want = ref.adamw_steps(
+        jax.tree_util.tree_map(np.asarray, params),
+        [_as_ref(b) for b in batches], REF_CFG, **ADAMW)
+    assert want["expert_rows_most"] > 0
+    step = jax.jit(make_lm_train_step(CFG))
+    state = _state(params)
+    losses = []
+    for t, b in enumerate(batches):
+        state, m = step(state, b)
+        losses.append(float(m["loss_sum"]) / float(m["count"]))
+        if t == 0:
+            grad = bd_train.first_gradient(state.opt_state, ADAMW["b1"])
+    np.testing.assert_allclose(losses, want["loss"], rtol=2e-5)
+    flat_got = jax.tree_util.tree_leaves_with_path(grad)
+    flat_want = dict(jax.tree_util.tree_leaves_with_path(want["grad"]))
+    assert len(flat_got) == 14
+    for path, g in flat_got:
+        w = flat_want[path]
+        assert np.abs(w).max() > 0, path  # every leaf gets a gradient
+        np.testing.assert_allclose(g, w, rtol=2e-3,
+                                   atol=2e-5 * np.abs(w).max(),
+                                   err_msg=str(path))
+    delta = ref.leaf_norms(jax.tree_util.tree_map(
+        lambda a, b: np.asarray(a) - np.asarray(b), state.params, params))
+    for k, v in want["delta_norm"].items():
+        assert delta[k] == pytest.approx(v, rel=2e-3), k
+    assert ref.median_leaf_diff(grad, want["grad"]) < 1e-4
+
+
+def test_the_reference_reads_an_expert_s_run_in_chunks(monkeypatch):
+    """Whatever the chunk, the same function: a chunk past a run's end is
+    skipped, the last one masked."""
+    params = jax.tree_util.tree_map(np.asarray, _params(0))
+    batch = _as_ref(tokens.split_batches(_pool(0), 2)[0])
+    f = lambda: jax.value_and_grad(  # noqa: E731
+        lambda p: ref.batch_loss(p, batch, REF_CFG), has_aux=True)(params)
+    (a, most), ga = f()
+    assert ref.EXPERT_CHUNK == 1024 and 8 < int(most) <= 2 * L
+    monkeypatch.setattr(ref, "EXPERT_CHUNK", 8)
+    (b, most_b), gb = f()
+    assert float(a) == pytest.approx(float(b), rel=1e-6)
+    assert int(most_b) == int(most)
+    for x, y in zip(jax.tree_util.tree_leaves(ga),
+                    jax.tree_util.tree_leaves(gb)):
+        np.testing.assert_allclose(x, y, rtol=1e-4, atol=1e-7)
+
+
+@pytest.mark.parametrize("fault", [
+    {"causal_mask": True}, {"unweighted": True}, {"dropped_rows": 8},
+    {"mm": ref.mm_fp8}])
+def test_each_fault_of_the_reference_is_another_function(fault):
+    params = jax.tree_util.tree_map(np.asarray, _params(0))
+    batch = _as_ref(tokens.split_batches(_pool(0), 2)[0])
+    sound, _ = ref.batch_loss(params, batch, REF_CFG)
+    broken, _ = ref.batch_loss(params, batch, REF_CFG, **fault)
+    assert abs(float(broken) - float(sound)) > 1e-3 * abs(float(sound))
+
+
+def test_bfloat16_compute_stays_near_float32():
+    cfg16 = dataclasses.replace(CFG, dtype="bfloat16")
+    params = _params(0)
+    batch = tokens.split_batches(_pool(0), 2)[0]
+    a, _ = sdar.apply(CFG, {"params": params}, batch)
+    b, sizes = sdar.apply(cfg16, {"params": params}, batch)
+    assert a.shape == b.shape == (2,) and sizes.shape == (2, 16)
+    np.testing.assert_allclose(a, b, rtol=0.05)
+    assert int(sizes.sum()) == 2 * (2 * 2 * L) * 4
+
+
+def test_parameter_count_and_init():
+    real = sdar.SdarConfig()
+    layer = (2048 * 4096 + 2 * 2048 * 512 + 4096 * 2048 + 2 * 128 + 2048
+             + 2048 + 2048 * 128 + 16 * 3 * 2048 * 768)
+    assert layer == 94_638_336
+    assert real.n_params() == 6 * layer + 2 * 18992 * 2048 + 2048
+    assert real.n_params() == 645_623_296
+    assert dataclasses.replace(real, num_hidden_layers=4).n_params() \
+        == 4 * layer + 2 * 18992 * 2048 + 2048
+    p = sdar.init_params(CFG, jax.random.key(0), n_layers_published=48)
+    assert float(p["layers"]["wo"].std()) == pytest.approx(
+        0.02 / (96 ** 0.5), rel=0.1)
+    assert float(p["layers"]["wq"].std()) == pytest.approx(0.02, rel=0.1)
+    assert float(p["final_norm"].min()) == 1.0
+
+
+# ---- the normal path --------------------------------------------------
+
+def test_train_py_trains_the_tiny_preset_through_fit_and_the_scan_driver(
+        capsys, tmp_path):
+    import train
+
+    code = train.main([
+        "--device", "cpu", "--task", "blockdiff", "--synthetic", "24",
+        "-b", "2", "--epochs", "3", "--optim", "AdamW", "--lr", "3e-3",
+        "--weight-decay", "0.1", "--ckpt-dir", str(tmp_path),
+        "--check-invariants", "--no-preempt-handler"])
+    out = capsys.readouterr().out
+    assert code == 0
+    losses = [float(ln.split("train loss ")[1].split()[0])
+              for ln in out.splitlines() if ln.startswith("Epoch ")]
+    assert len(losses) == 3 and losses[-1] < losses[0]
+    assert "blockdiff: 0.12 M parameters" in out or "blockdiff:" in out

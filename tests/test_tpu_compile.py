@@ -617,3 +617,76 @@ def test_four_chip_scan_program_at_real_size(four_chips, no_compile_cache):
     assert collectives and set(collectives) == {"all-reduce"}, collectives
     assert not unscoped, unscoped
     assert not batch_sized, batch_sized
+
+
+def test_sdar_scan_program_at_real_size_fits_the_chip(one_chip,
+                                                      no_compile_cache):
+    """``sdar.train``'s window program (a chunk of two steps of the
+    block-diffusion decoder at the cell's real size: 4 layers, 456.3 M
+    parameters, 2 sequences of 4,096 tokens a step) compiles for the
+    described chip with both kernels in it, under 15 GB by the compile's
+    memory analysis (the state's 5.48 GB aliased in place, ~7.6 GB of
+    temporaries of which 1.83 GB are the gradients), and never holds a
+    ``[*, 8192, 8192]`` score array. At 6 layers the same analysis read
+    16.97 GB (PERF.md section 4, PR 42)."""
+    import dataclasses
+    import json
+    import os
+
+    from benchmark.kinds import bd_train
+    from benchmark.weights import seed_key
+    from benchmark.weights_sdar import StateMaker
+    from cgnn_tpu.data import tokens
+    from cgnn_tpu.models import sdar
+    from cgnn_tpu.train import lm_step, make_optimizer
+    from cgnn_tpu.train.loop import ScanEpochDriver
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "sdar-30b-a3b-ep8.json")) as f:
+        cfg = json.load(f)
+    # the described chip is not the default backend: name the kernels
+    mc = dataclasses.replace(bd_train.model_config(cfg), attn_impl="splash",
+                             moe_impl="megablox")
+    tr, length = cfg["train"], int(cfg["data"]["sequence_length"])
+    tx = make_optimizer(optim="adamw", lr=tr["lr"], b1=tr["b1"], b2=tr["b2"],
+                        weight_decay=tr["weight_decay"], lr_milestones=[])
+    maker = StateMaker(mc, cfg["init"], tx,
+                       functools.partial(sdar.apply, mc))
+    state = jax.eval_shape(maker._build, seed_key(1))
+    assert sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(
+        state.params)) == mc.n_params() == 456_346_624
+    batches = tokens.split_batches(
+        tokens.make_pool(8, length, vocab_size=mc.vocab_size,
+                         block=mc.block_length, seed=0),
+        int(tr["batch_size"]))
+    tiles = sdar.attention_tiles(mc, length)
+    driver = ScanEpochDriver(
+        lm_step.make_lm_train_step(mc, tiles),
+        lm_step.make_lm_eval_step(mc, tiles), batches, [],
+        np.random.default_rng(0), chunk_steps=2)
+    (key, stacked), = driver._train_groups.items()
+    fn = driver._window_fn(driver._train_scans, (key, 2),
+                           driver._train_body, True)
+    assert fn.__name__ == "scan_train_n16384_l2"
+    shapes = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                       sharding=one_chip),
+        (state, stacked, np.zeros(len(batches), np.int32),
+         np.zeros((), np.int32)))
+    # the suite runs under jax_enable_x64 (conftest.py), which no entry
+    # point sets and under which the kernels' lowering never ends
+    with jax.enable_x64(False):
+        compiled = fn.lower(*shapes).compile()
+    mem = compiled.memory_analysis()
+    on_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"sdar.train's window program: state "
+          f"{mem.alias_size_in_bytes / 1e9:.2f} GB aliased, temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.2f} GB, {on_chip / 1e9:.2f} GB "
+          f"on the chip")
+    assert mem.alias_size_in_bytes > 5.4e9  # the state is updated in place
+    assert 8e9 < on_chip < 15e9, on_chip
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 8  # splash and megablox
+    assert not re.search(r"\[(?:\d+,)*8192,8192\]", text)

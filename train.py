@@ -35,10 +35,20 @@ def build_parser() -> argparse.ArgumentParser:
                    help="train on N synthetic OC20-like catalyst slabs "
                         "(50-200+ atom graphs; BASELINE config #4)")
     p.add_argument("--task",
-                   choices=["regression", "classification", "force"],
+                   choices=["regression", "classification", "force",
+                            "blockdiff"],
                    default="regression",
                    help="'force' trains the differentiable force field on "
-                        "energy+force labels (BASELINE config #5)")
+                        "energy+force labels (BASELINE config #5); "
+                        "'blockdiff' the block-diffusion mixture-of-experts "
+                        "decoder on packed token sequences "
+                        "(cgnn_tpu/train/blockdiff.py)")
+    p.add_argument("--lm-model", default="tiny",
+                   help="--task blockdiff: a preset (tiny | sdar-ep8) or a "
+                        "JSON file of models.sdar.SdarConfig's fields")
+    p.add_argument("--lm-seq-len", type=int, default=64,
+                   help="--task blockdiff: tokens a packed sequence "
+                        "(--synthetic N sequences, -b of them a step)")
     p.add_argument("--device", choices=["auto", "cpu", "tpu"], default="auto",
                    help="accelerator (reference flag; 'auto' uses what jax finds)")
     p.add_argument("--epochs", type=int, default=30)
@@ -327,6 +337,17 @@ def main(argv=None) -> int:
     if fault_plan is not None:
         print(f"FAULT INJECTION ACTIVE: {fault_plan.describe()}",
               file=sys.stderr)
+    if args.task == "blockdiff":
+        # no graphs: the task packs token batches itself and hands them to
+        # fit(), the scan driver and the state of every task
+        from cgnn_tpu.train import blockdiff
+
+        try:
+            return blockdiff.run(args, telemetry, preempt=preempt)
+        finally:
+            if live_writer is not None:
+                live_writer.stop()
+            telemetry.close()
 
     if (args.device_resident and not args.no_scan_epochs
             and not args.profile):
