@@ -11,9 +11,9 @@ A layer, on the residual stream ``x [S, 2L, H]``:
 The layers' weights are stacked on a leading axis and the stack is scanned;
 within a layer the step's sequences go one at a time, a ``jax.checkpoint`` a
 layer and sequence: a layer's input is all the reverse pass keeps, and what
-it rebuilds (a sequence's projections, its ``T x k`` routed rows) is one
-sequence's at a time. Weights are float32 and are cast to the compute dtype
-inside the layer; norms, RoPE, the router and the loss are float32.
+it rebuilds (a sequence's projections, the rows routed to the experts held)
+is one sequence's at a time. Weights are float32 and are cast to the compute
+dtype inside the layer; norms, RoPE, the router and the loss are float32.
 
 The vocabulary is a slice too (``vocab_size`` rows are held): ids are drawn
 from the slice and the loss is over the slice.
@@ -105,7 +105,8 @@ def rope(x, positions, theta: float):
 
 
 def _layer(cfg: SdarConfig, x, p, segment_ids):
-    """One layer on ``x [S, N, H]`` (compute dtype) -> (x, group_sizes)."""
+    """One layer on ``x [S, N, H]`` (compute dtype) -> (x, group_sizes, the
+    rung that carried the held experts' rows: ops/moe.py)."""
     dt = cfg.compute_dtype
     s, n, h = x.shape
     hq, hkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
@@ -129,18 +130,19 @@ def _layer(cfg: SdarConfig, x, p, segment_ids):
         x = x + (a @ p["wo"].astype(dt))
     with jax.named_scope(phases.MOE_ROUTE):
         hn = rms_norm(x, p["moe_norm"], cfg.rms_norm_eps).astype(dt)
-    out, group_sizes = moe.expert_share(
+    out, group_sizes, rung = moe.expert_share(
         hn.reshape(s * n, h), p["router"], p["w_gate_up"].astype(dt),
         p["w_down"].astype(dt), experts_held=cfg.experts_held,
         k=cfg.num_experts_per_tok, impl=cfg.moe_impl)
     with jax.named_scope(phases.MOE_ROUTE):
-        x = x + out.reshape(s, n, h).astype(dt)
-    return x, group_sizes
+        x = x + out.reshape(s, n, h)
+    return x, group_sizes, rung
 
 
 def hidden_states(cfg: SdarConfig, params, tokens, segment_ids):
     """``tokens [S, 2L]`` (noised, then clean), ``segment_ids [S, L]`` ->
-    (the last layer's ``x [S, 2L, H]``, ``group_sizes [layers, E]``)."""
+    (the last layer's ``x [S, 2L, H]``, ``group_sizes [layers, E]``, ``rungs
+    [layers, S]``: the rung of each layer's and sequence's expert call)."""
     with jax.named_scope(phases.LM_EMBED):
         x = params["embed"][tokens].astype(cfg.compute_dtype)
 
@@ -152,13 +154,17 @@ def hidden_states(cfg: SdarConfig, params, tokens, segment_ids):
         @functools.partial(jax.checkpoint, prevent_cse=False)
         def one(row):
             x_seq, seg = row
-            out, sizes = _layer(cfg, x_seq[None], p, seg[None])
-            return out[0], sizes
+            out, sizes, rung = _layer(cfg, x_seq[None], p, seg[None])
+            return out[0], sizes, rung
 
-        x, sizes = jax.lax.map(one, (x, segment_ids))
-        return x, sizes.sum(axis=0)
+        # a scan over the sequences, and it has to stay one: under ``vmap``
+        # the expert layer's ``lax.switch`` becomes a select that runs every
+        # rung on every sequence
+        x, sizes, rungs = jax.lax.map(one, (x, segment_ids))
+        return x, (sizes.sum(axis=0), rungs)
 
-    return jax.lax.scan(step, x, params["layers"])
+    x, (group_sizes, rungs) = jax.lax.scan(step, x, params["layers"])
+    return x, group_sizes, rungs
 
 
 # positions of the noised half whose logits are held at once
@@ -204,15 +210,16 @@ def noised_loss_sums(cfg: SdarConfig, params, x, batch):
 
 def apply(cfg: SdarConfig, variables: dict, batch, train: bool = True):
     """The ``apply_fn`` of a ``TrainState``: -> (each sequence's loss ``[S]``
-    float32, ``group_sizes [layers, E]``). The logits are an intermediate
+    float32, ``group_sizes [layers, E]``, ``rungs [layers, S]``:
+    ``hidden_states``). The logits are an intermediate
     (``noised_loss_sums``): at the vocabulary's width a step's would be the
     largest array of the program."""
     del train  # no dropout, no statistics
-    x, group_sizes = hidden_states(cfg, variables["params"], batch.tokens,
-                                   batch.segment_ids)
+    x, group_sizes, rungs = hidden_states(
+        cfg, variables["params"], batch.tokens, batch.segment_ids)
     with jax.named_scope(phases.LM_HEAD):
         return noised_loss_sums(cfg, variables["params"], x, batch), \
-            group_sizes
+            group_sizes, rungs
 
 
 def init_params(cfg: SdarConfig, rng, n_layers_published: int | None = None,

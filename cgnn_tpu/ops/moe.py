@@ -7,15 +7,24 @@ on the rows routed to them, and adds nothing for the absent ones: what comes
 out is this chip's part of the layer's result. On one chip there is no
 exchange, and nothing here stands in for one.
 
-Dropless, with static shapes: all ``T x k`` (token, expert) rows are sorted
-by expert; the grouped matmul is handed the sizes of all ``n_experts`` groups
-and the offset of the first one held, and visits the rows of the held groups
-only (``megablox`` on the TPU; ``lax.ragged_dot`` elsewhere). No row is
-dropped whatever the load, and no count is read on the host.
+Dropless, with static shapes, and the rows that travel are the rows that are
+here. All ``T x k`` (token, expert) pairs are sorted by expert, which leaves
+the held experts' rows one contiguous run of the sorted order; that run is
+carried in a compact buffer of a static capacity (``_held_rows``): a gather
+of ``capacity`` rows out (``spread``), the grouped matmul over them with the
+held groups' sizes and one trailing group that is not held (``megablox`` on
+the TPU visits the held rows only; ``lax.ragged_dot`` elsewhere), and the sum
+of each token's rows back (``combine``). The capacities are a ladder from the
+shapes (``ladder``: twice the balanced load, doubled up to ``T x k``), the
+rung is picked on the device by the count of held rows (``lax.switch``), and
+the last rung holds every pair: no row is dropped whatever the load, and no
+count is read on the host.
 
-Rows travel by permutation, never by scatter: the sort order ``order`` sends
-a token's row out (``x[order // k]``) and its inverse brings an expert's
-output back, so each gather's transpose is the other permutation's gather.
+Rows travel by gather, never by scatter: ``spread`` and ``combine`` are each
+other's transpose and each is built of row gathers (``combine`` re-sorts the
+rows by token, sums equal tokens a block of 128 with a 0/1 matrix on the MXU
+as ``ops/segment.py`` sums the conv's overflow runs, and gathers one row a
+token), so each one's reverse pass is the other.
 """
 
 from __future__ import annotations
@@ -26,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from cgnn_tpu.observe import phases
+from cgnn_tpu.ops.segment import _run_totals, _run_windows, gather
 
 
 def route(logits, k: int):
@@ -36,44 +46,64 @@ def route(logits, k: int):
     return top / top.sum(axis=-1, keepdims=True), experts.astype(jnp.int32)
 
 
+def ladder(rows: int, count: int, n_experts: int) -> tuple:
+    """The capacities ``expert_share`` carries its held rows in, from the
+    shapes alone (``rows`` = ``T x k`` pairs, ``count`` of ``n_experts``
+    experts held): twice the balanced load rounded up to the grouped matmul's
+    512-row tiles, doubled until it holds every pair, which the last rung
+    does. A share that holds half the experts or more has the one rung."""
+    balanced = -(-rows * count // n_experts)
+    rung, rungs = 2 * (-(-balanced // 512) * 512), []
+    while rung < rows:
+        rungs.append(rung)
+        rung *= 2
+    return (*rungs, rows)
+
+
+def row_plan(tok, valid, counts, k: int):
+    """Where ``spread`` and ``combine`` move rows, from the carried rows'
+    tokens alone: ``tok [C]`` the token of each carried row, ``valid [C]``
+    whether the row is a held one, ``counts [T]`` each token's valid rows (at
+    most ``k``). -> ``(tok, valid, win, same, last)``: ``win``, ``same`` the
+    rows re-sorted by token and cut into blocks of 128 with a halo
+    (``ops/segment.py`` ``_run_windows``); ``last [T]`` where a token's run
+    ends in that order, and for a token with no row the entry past the end,
+    whose total is zero."""
+    c, t = tok.shape[0], counts.shape[0]
+    ids, by_tok = jax.lax.sort_key_val(
+        jnp.where(valid, tok, t), jnp.arange(c, dtype=jnp.int32))
+    win, same = _run_windows(by_tok, jnp.where(ids < t, ids, -1), k)
+    last = jnp.where(counts > 0, jnp.cumsum(counts) - 1, c)
+    return tok, valid, win, same, last.astype(jnp.int32)
+
+
 @jax.custom_vjp
-def _permute(x, perm, inverse):
-    """``x[perm]`` for a permutation ``perm`` of the rows (``inverse`` its
-    inverse): the transpose is ``g[inverse]``, a gather too."""
-    return x[perm]
+def spread(x, plan):
+    """A token's row to each of its carried places: ``x [T, H]`` -> ``[C,
+    H]``, row ``r`` token ``tok[r]``'s where ``valid``, else zero (``plan``:
+    ``row_plan``). The transpose is ``combine``."""
+    tok, valid = plan[:2]
+    return jnp.where(valid[:, None], gather(x, tok), 0)
 
 
-def _permute_fwd(x, perm, inverse):
-    return x[perm], (perm, inverse)
+@jax.custom_vjp
+def combine(y, plan):
+    """Every token's valid rows summed: ``y [C, H]`` -> ``[T, H]`` in ``y``'s
+    dtype, each sum accumulated in float32 and rounded once. The rows are
+    gathered in token order a block of 128 (with the halo a run may reach
+    back over), a 0/1 matrix a block leaves each run's total at its last
+    row, and one row a token is gathered from there: ``C + T`` gathered rows
+    and no scatter. The transpose is ``spread``."""
+    _, valid, win, same, last = plan
+    # a row that is not valid holds whatever the kernel left: 0 x NaN
+    return gather(_run_totals(jnp.where(valid[:, None], y, 0), win, same),
+                  last)
 
 
-def _permute_bwd(res, g):
-    perm, inverse = res
-    return g[inverse], None, None
-
-
-_permute.defvjp(_permute_fwd, _permute_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _send(x, order, inverse, k: int):
-    """A token's row to each of its ``k`` places in the sorted order: row
-    ``r`` is token ``order[r] // k``. The transpose brings the ``k`` rows
-    back by the inverse permutation and adds them."""
-    return x[order // k]
-
-
-def _send_fwd(x, order, inverse, k):
-    return x[order // k], (inverse, x.shape[0])
-
-
-def _send_bwd(k, res, g):
-    inverse, t = res
-    back = g[inverse].reshape(t, k, g.shape[-1])
-    return back.sum(axis=1, dtype=jnp.float32).astype(g.dtype), None, None
-
-
-_send.defvjp(_send_fwd, _send_bwd)
+spread.defvjp(lambda x, plan: (spread(x, plan), plan),
+              lambda plan, g: (combine(g, plan), None))
+combine.defvjp(lambda y, plan: (combine(y, plan), plan),
+               lambda plan, g: (spread(g, plan), None))
 
 
 @jax.custom_vjp
@@ -117,67 +147,134 @@ def _tile_of(n: int) -> int:
     return n
 
 
-def grouped_matmul(lhs, rhs, group_sizes, first: int, *, impl: str):
-    """``lhs [R, K]`` rows sorted by group, ``rhs [count, K, N]`` the held
-    groups' matrices, ``group_sizes [E]`` of ALL groups: rows of group
-    ``first + g`` times ``rhs[g]``. Rows of groups not held come back as
-    whatever the kernel left there: the caller masks them."""
+def grouped_matmul(lhs, rhs, group_sizes, *, impl: str):
+    """``lhs [R, K]`` rows sorted by group, ``rhs [count, K, N]`` the first
+    ``count`` groups' matrices, ``group_sizes`` of ALL groups (they add up
+    to ``R``): rows of group ``g`` times ``rhs[g]``. Rows of the groups
+    past ``count`` come back as whatever the kernel left there: the caller
+    masks them."""
     if impl == "megablox":
         from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
         tiling = (512, _tile_of(lhs.shape[1]), _tile_of(rhs.shape[2]))
         return megablox.gmm(lhs, rhs, group_sizes, lhs.dtype, tiling,
-                            jnp.asarray(first, jnp.int32))
+                            jnp.zeros((), jnp.int32))
     if impl == "ragged":
         # every group a matrix, the absent ones zero: the plain form
-        full = jnp.zeros((group_sizes.shape[0], *rhs.shape[1:]), rhs.dtype)
-        full = jax.lax.dynamic_update_slice_in_dim(full, rhs, first, axis=0)
+        absent = group_sizes.shape[0] - rhs.shape[0]
+        full = jnp.pad(rhs, ((0, absent), (0, 0), (0, 0)))
         return jax.lax.ragged_dot(
             lhs, full, group_sizes,
             preferred_element_type=jnp.float32).astype(lhs.dtype)
     raise ValueError(f"no grouped matmul {impl!r}")
 
 
+def _held_rows(capacity: int, held: tuple, k: int, impl: str,
+               x, w_flat, w_gate_up, w_down, counts, order, group_sizes):
+    """The held experts on their rows, carried in ``capacity`` rows: the
+    one body of every rung. Right where the held rows are no more than
+    ``capacity`` (``_held_experts`` picks the rung so; at ``T x k`` they
+    always are). ``w_flat [T x k]`` the routing weights, ``counts [T]`` the
+    held rows of each token, ``order`` the pairs sorted by expert."""
+    first, count = held
+    with jax.named_scope(phases.MOE_ROUTE):
+        sizes = group_sizes[first:first + count]
+        start, n_here = group_sizes[:first].sum(), sizes.sum()
+        # the held rows are sorted entries [start, start + n_here)
+        carried = jax.lax.dynamic_slice(
+            jnp.pad(order, (0, capacity)), (start,), (capacity,))
+        valid = jnp.arange(capacity, dtype=jnp.int32) < n_here
+        plan = row_plan(carried // k, valid, counts, k)
+        rows = spread(x, plan)
+        # the sizes add up to the rows: one more group, which is not held
+        sizes = jnp.concatenate([sizes, (capacity - n_here)[None]])
+    with jax.named_scope(phases.MOE_EXPERT):
+        # rows that are not held hold whatever the kernel's buffer held
+        gu = jnp.where(valid[:, None], grouped_matmul(
+            rows, w_gate_up, sizes, impl=impl), 0)
+        y = grouped_matmul(swiglu(gu), w_down, sizes, impl=impl)
+    with jax.named_scope(phases.MOE_ROUTE):
+        # the weights join the rows in the compute dtype; the sum over a
+        # token's rows accumulates in float32 (combine)
+        y = jnp.where(valid[:, None], y, 0) * gather(
+            w_flat, carried).astype(y.dtype)[:, None]
+        return combine(y, plan)
+
+
+def _rung(rungs: tuple, held: tuple, group_sizes):
+    """The first rung that holds the held rows: its index, int32."""
+    first, count = held
+    n_here = group_sizes[first:first + count].sum()
+    return (n_here > jnp.asarray(rungs[:-1], jnp.int32)).sum(dtype=jnp.int32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _held_experts(rungs: tuple, held: tuple, k: int, impl: str,
+                  floats: tuple, ints: tuple):
+    """``_held_rows`` in the first of ``rungs`` that holds the held rows,
+    picked on the device -> (its output, the rung's index). ``floats`` are
+    ``(x, w_flat, w_gate_up, w_down)``, ``ints`` ``(counts, order,
+    group_sizes)``. The reverse pass is a switch of its own over ``jax.vjp``
+    of the same bodies, from the operands alone: differentiated through, a
+    ``lax.switch`` keeps the union of its branches' residuals and each branch
+    writes zeros for the others' (the full rung's are ``T x k`` rows long)."""
+    index = _rung(rungs, held, ints[-1])
+    bodies = [functools.partial(_held_rows, r, held, k, impl) for r in rungs]
+    return jax.lax.switch(index, bodies, *floats, *ints), index
+
+
+def _held_experts_fwd(rungs, held, k, impl, floats, ints):
+    return _held_experts(rungs, held, k, impl, floats, ints), (floats, ints)
+
+
+def _held_experts_bwd(rungs, held, k, impl, res, cts):
+    floats, ints = res
+
+    def pull(capacity, g, *floats):
+        body = functools.partial(_held_rows, capacity, held, k, impl)
+        return jax.vjp(lambda *f: body(*f, *ints), *floats)[1](g)
+
+    grads = jax.lax.switch(
+        _rung(rungs, held, ints[-1]),
+        [functools.partial(pull, r) for r in rungs], cts[0], *floats)
+    return grads, None
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
 def expert_share(x, router, w_gate_up, w_down, *, experts_held: tuple,
-                 k: int, impl: str = "auto"):
+                 k: int, impl: str = "auto", capacity: int | None = None):
     """``x [T, H]`` (normed hidden states) -> (this share's part of the
-    layer's output ``[T, H]`` float32, ``group_sizes [E]`` int32: the rows
-    each of ALL experts was routed).
+    layer's output ``[T, H]`` in ``x``'s dtype, each token's sum accumulated
+    in float32; ``group_sizes [E]`` int32: the rows each of ALL experts was
+    routed; the index of the rung that carried the held rows).
 
     ``router [H, E]`` float32; ``w_gate_up [count, H, 2I]`` and ``w_down
     [count, I, H]`` in the compute dtype, the experts ``first .. first +
-    count - 1``: ``e(h) = (silu(h W_g) * (h W_u)) W_d``.
+    count - 1``: ``e(h) = (silu(h W_g) * (h W_u)) W_d``. ``capacity`` puts
+    another compact rung under ``T x k`` in the ladder's place (tests, at
+    sizes whose ladder is the one rung).
     """
     first, count = experts_held
-    t, h = x.shape
-    n_experts = router.shape[1]
+    t, n_experts = x.shape[0], router.shape[1]
     if impl == "auto":
         impl = "megablox" if jax.default_backend() == "tpu" else "ragged"
+    rungs = ladder(t * k, count, n_experts)
+    if capacity is not None:
+        rungs = (capacity, t * k) if capacity < t * k else (t * k,)
     with jax.named_scope(phases.MOE_ROUTE):
         logits = jnp.dot(x.astype(jnp.float32), router,
                          precision=jax.lax.Precision.HIGHEST)
         weights, experts = route(logits, k)
         flat = experts.reshape(-1)
         order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.shape[0], dtype=jnp.int32))
         group_sizes = (flat[:, None] == jnp.arange(
             n_experts, dtype=jnp.int32)).sum(axis=0, dtype=jnp.int32)
-        sorted_expert = flat[order]
-        here = ((sorted_expert >= first)
-                & (sorted_expert < first + count))[:, None]
-        rows = jnp.where(here, _send(x, order, inverse, k), 0)
-    with jax.named_scope(phases.MOE_EXPERT):
-        # rows of absent experts hold whatever the kernel's buffer held
-        gu = jnp.where(here, grouped_matmul(rows, w_gate_up, group_sizes,
-                                            first, impl=impl), 0)
-        y = grouped_matmul(swiglu(gu), w_down, group_sizes, first,
-                           impl=impl)
-    with jax.named_scope(phases.MOE_ROUTE):
-        y = jnp.where(here, y, 0)
-        back = _permute(y, inverse, order).reshape(t, k, h)
-        # the weights join the rows in the compute dtype; the sum over a
-        # token's k rows accumulates in float32
-        out = (back * weights.astype(back.dtype)[:, :, None]).sum(
-            axis=1, dtype=jnp.float32)
-    return out, group_sizes
+        counts = ((experts >= first) & (experts < first + count)).sum(
+            axis=1, dtype=jnp.int32)
+        out, rung = _held_experts(
+            rungs, (first, count), k, impl,
+            (x, weights.reshape(-1), w_gate_up, w_down),
+            (counts, order, group_sizes))
+    return out, group_sizes, rung

@@ -87,9 +87,9 @@ def run(args, telemetry, preempt=None, log_fn=print) -> int:
         chunk_steps=args.chunk_steps, telemetry=telemetry, preempt=preempt,
         log_fn=log_fn)
     last = result["history"][-1]
-    for name in ("moe_rows_here", "moe_rows_balanced",
-                 "expert_load_max_over_mean", "bd_tiles_live",
-                 "bd_tiles_grid", "masked_tokens"):
+    for name in ("moe_rows_here", "moe_rows_balanced", "moe_rows_capacity",
+                 "moe_calls_full_rung", "expert_load_max_over_mean",
+                 "bd_tiles_live", "bd_tiles_grid", "masked_tokens"):
         telemetry.set_gauge(name, float(last["train"].get(name, 0.0)))
     log_fn(f"** best val loss {result['best']:.4f}; a step routed "
            f"{last['train']['moe_rows_here']:.0f} rows to the experts held "

@@ -21,20 +21,30 @@ import jax.numpy as jnp
 
 from cgnn_tpu.data.tokens import TokenBatch
 from cgnn_tpu.observe import phases
+from cgnn_tpu.ops import moe
 from cgnn_tpu.train.state import TrainState
 
 
-def routing_metrics(group_sizes, experts_held: tuple, k: int,
+def routing_metrics(group_sizes, rungs, experts_held: tuple, k: int,
                     positions: int) -> dict:
     """What the routers did in one step, as sums (``group_sizes [layers,
-    E]``): rows that landed on the experts held, the rows a balanced router
-    would send them, and the worst layer's largest load over the mean."""
+    E]``, ``rungs [layers, S]``): rows that landed on the experts held, the
+    rows the rungs that carried them hold (ops/moe.py ``ladder``), the calls
+    (a layer and sequence each) that took the last rung, all ``T x k`` rows,
+    the rows a balanced router would send the experts held, and the worst
+    layer's largest load over the mean."""
     first, count = experts_held
     n_layers, n_experts = group_sizes.shape
     here = jax.lax.dynamic_slice_in_dim(group_sizes, first, count, axis=1)
     load = group_sizes.astype(jnp.float32)
+    pairs = positions // rungs.shape[1] * k  # a sequence's (token, choice)s
+    capacities = jnp.asarray(moe.ladder(pairs, count, n_experts),
+                             jnp.float32)
     return {
         "moe_rows_here_sum": here.sum().astype(jnp.float32),
+        "moe_rows_capacity_sum": capacities[rungs].sum(),
+        "moe_calls_full_rung_sum": (
+            rungs == capacities.shape[0] - 1).sum().astype(jnp.float32),
         "moe_rows_balanced_sum": jnp.float32(
             n_layers * positions * k * count / n_experts),
         "expert_load_max_over_mean_sum": (
@@ -49,38 +59,37 @@ def make_lm_train_step(cfg, tiles: tuple[int, int] | None = None) -> Callable:
 
     def train_step(state: TrainState, batch: TokenBatch):
         def loss_with_aux(params):
-            losses, group_sizes = state.apply_fn(
+            losses, *routed = state.apply_fn(
                 {"params": params}, batch, train=True)
-            return losses.mean(), (losses.sum(), group_sizes)
+            return losses.mean(), (losses.sum(), routed)
 
-        (_, (loss_sum, group_sizes)), grads = jax.value_and_grad(
+        (_, (loss_sum, routed)), grads = jax.value_and_grad(
             loss_with_aux, has_aux=True)(state.params)
         with jax.named_scope(phases.OPTIMIZER):
             new_state = state.apply_gradients(grads, state.batch_stats)
-        return new_state, step_metrics(cfg, batch, loss_sum, group_sizes,
-                                       tiles)
+        return new_state, step_metrics(cfg, batch, loss_sum, routed, tiles)
 
     return train_step
 
 
 def make_lm_eval_step(cfg, tiles: tuple[int, int] | None = None) -> Callable:
     def eval_step(state: TrainState, batch: TokenBatch):
-        losses, group_sizes = state.apply_fn(state.variables(), batch,
-                                             train=False)
-        return step_metrics(cfg, batch, losses.sum(), group_sizes, tiles)
+        losses, *routed = state.apply_fn(state.variables(), batch,
+                                         train=False)
+        return step_metrics(cfg, batch, losses.sum(), routed, tiles)
 
     return eval_step
 
 
-def step_metrics(cfg, batch: TokenBatch, loss_sum, group_sizes,
-                 tiles) -> dict:
+def step_metrics(cfg, batch: TokenBatch, loss_sum, routed, tiles) -> dict:
+    """``routed``: the model's ``(group_sizes, rungs)``."""
     with jax.named_scope(phases.LM_HEAD):
         s, n = batch.tokens.shape
         metrics = {
             "loss_sum": loss_sum, "count": jnp.float32(s),
             "masked_tokens_sum": (batch.loss_weight > 0).sum().astype(
                 jnp.float32),
-            **routing_metrics(group_sizes, cfg.experts_held,
+            **routing_metrics(*routed, cfg.experts_held,
                               cfg.num_experts_per_tok, s * n),
         }
         if tiles is not None:

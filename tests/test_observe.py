@@ -533,6 +533,24 @@ _CLASSIFIED = [
      "jit(gmm)/pallas_call", ("moe.expert", "fwd")),
     (_LM + "transpose(jvp(while))/body/while/body/checkpoint/moe.expert/"
      "jit(tgmm)/pallas_call", ("moe.expert", "bwd")),
+    # the expert layer's switch over its rungs (ops/moe.py): what was
+    # moe.route stays moe.route, forward and in the reverse pass's own
+    # switch, whose branches rebuild the rows (``jvp(...)`` under the outer
+    # ``transpose(``: bwd) and pull the cotangent back through them (a
+    # second ``transpose(``: bwd2)
+    (_LM + "jvp(while)/body/while/body/moe.route/cond", ("moe.route", "fwd")),
+    (_LM + "jvp(while)/body/while/body/moe.route/cond/branch_0_fun/"
+     "moe.route/jit(_take)/gather", ("moe.route", "fwd")),
+    (_LM + "jvp(while)/body/while/body/moe.route/cond/branch_2_fun/"
+     "moe.expert/jit(gmm)/pallas_call", ("moe.expert", "fwd")),
+    (_LM + "transpose(jvp(while))/body/while/body/checkpoint/moe.route/"
+     "cond/branch_0_fun/jvp(moe.route)/sort", ("moe.route", "bwd")),
+    (_LM + "transpose(jvp(while))/body/while/body/checkpoint/moe.route/"
+     "cond/branch_1_fun/transpose(jvp(moe.route))/jit(_take)/gather",
+     ("moe.route", "bwd2")),
+    (_LM + "transpose(jvp(while))/body/while/body/checkpoint/moe.route/"
+     "cond/branch_1_fun/transpose(jvp(moe.expert))/jit(tgmm)/pallas_call",
+     ("moe.expert", "bwd2")),
     (_LM + "jvp(lm.head)/while/body/checkpoint/dot_general",
      ("lm.head", "fwd")),
     (_LM + "transpose(jvp(lm.head))/while/body/checkpoint/"
@@ -565,10 +583,11 @@ class TestPhases:
         assert {p for p, d in seen if d == "bwd"} == two_way
         # what the force step differentiates twice: the trunk without
         # BatchNorm, the geometry and the readout (not the embedding, which
-        # no position moves, nor the loss)
+        # no position moves, nor the loss); and the expert layer, whose
+        # reverse pass differentiates its rung's body inside its own switch
         assert {p for p, d in seen if d == "bwd2"} == {
             "edge_geom", "force_readout", "conv.gather", "conv.fc_full",
-            "conv.gate", "conv.aggregate"}
+            "conv.gate", "conv.aggregate", "moe.route", "moe.expert"}
 
     def test_a_kernel_s_custom_call_spans_lines(self):
         """A Pallas kernel's ``backend_config`` runs over several lines and

@@ -249,7 +249,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     x, router, w_gu, w_d = _expert_weights(0)
     total, rows = 0.0, 0
     for first in range(0, 16, 4):
-        out, sizes = moe.expert_share(
+        out, sizes, _ = moe.expert_share(
             x, router, w_gu[first:first + 4], w_d[first:first + 4],
             experts_held=(first, 4), k=4, impl="ragged")
         assert int(sizes.sum()) == 24 * 4
@@ -269,7 +269,7 @@ def test_dropless_under_a_router_forced_onto_one_held_expert():
     router = router.at[:, 5].set(0.0) * 0.01
     router = router.at[:, 5].set(50.0 * jnp.sign(x.sum(0)))
     x = jnp.abs(x) * jnp.sign(x.sum(0))[None, :]  # x . router[:, 5] >> 0
-    out, sizes = moe.expert_share(
+    out, sizes, _ = moe.expert_share(
         x, router, w_gu[4:8], w_d[4:8], experts_held=(4, 4), k=4,
         impl="ragged")
     assert int(sizes[5]) == 24
@@ -289,8 +289,8 @@ def test_routing_gradients_reach_the_router():
     x, router, w_gu, w_d = _expert_weights(2)
 
     def f(x, router, w_gu, w_d):
-        out, _ = moe.expert_share(x, router, w_gu[4:8], w_d[4:8],
-                                  experts_held=(4, 4), k=4, impl="ragged")
+        out, *_ = moe.expert_share(x, router, w_gu[4:8], w_d[4:8],
+                                   experts_held=(4, 4), k=4, impl="ragged")
         return (out ** 2).sum()
 
     def g(x, router, w_gu, w_d):
@@ -306,6 +306,149 @@ def test_routing_gradients_reach_the_router():
     assert float(jnp.abs(got[1]).max()) > 0
     # the experts not held get no gradient
     assert float(jnp.abs(got[2][:4]).max()) == 0.0
+
+
+# ---- the held rows travel compact -------------------------------------
+
+def _load(name):
+    """(x, router, w_gu, w_d, held) of one load, 24 tokens of 4 choices
+    over 16 experts."""
+    x, router, w_gu, w_d = _expert_weights(3)
+    held = {"first_is_zero": (0, 4), "the_last_experts": (12, 4)}.get(
+        name, (4, 4))
+    if name == "all_on_one_held_expert":
+        router = router.at[:, 5].set(50.0 * jnp.sign(x.sum(0)))
+        x = jnp.abs(x) * jnp.sign(x.sum(0))[None, :]
+    if name == "no_row_held":
+        router = router.at[:, 4:8].set(-50.0 * jnp.sign(x.sum(0))[:, None])
+        x = jnp.abs(x) * jnp.sign(x.sum(0))[None, :]
+    return x, router, w_gu, w_d, held
+
+
+@functools.lru_cache(maxsize=None)
+def _reference_of(name):
+    """The reference's output, its gradients and the held rows' count."""
+    x, router, w_gu, w_d, (first, count) = _load(name)
+    cfg = {**REF_CFG, "experts_held": (first, count)}
+
+    def g(x, router, a, b):
+        out, _ = ref._experts(x, {"router": router, "w_gate_up": a,
+                                  "w_down": b}, cfg, ref._mm_f32, None)
+        return (out ** 2).sum(), out
+
+    (_, out), grads = jax.value_and_grad(g, (0, 1, 2, 3), has_aux=True)(
+        x, router, w_gu[first:first + count], w_d[first:first + count])
+    _, experts = moe.route(x @ router, 4)
+    n_here = int(((experts >= first) & (experts < first + count)).sum())
+    return out, grads, n_here
+
+
+LOADS = ("first_above_zero", "first_is_zero", "the_last_experts",
+         "all_on_one_held_expert", "no_row_held")
+# the compact rung's capacity from the held rows' count (24 x 4 = 96 pairs:
+# the ladder's own choice at this size is the one rung)
+RUNGS = {"rows_under_the_rung": lambda n: n + 5,
+         "rows_exactly_at_the_rung": lambda n: max(n, 1),
+         "one_row_over_the_rung": lambda n: max(n - 1, 1),
+         "the_full_rung_forced": lambda n: 96,
+         "the_ladder_s_own_choice": lambda n: None}
+
+
+@pytest.mark.parametrize("load", LOADS)
+@pytest.mark.parametrize("rung", RUNGS)
+def test_every_rung_under_every_load_is_the_reference_s_layer(rung, load):
+    """Whatever capacity carries the held rows, output and gradients (to
+    ``x``, the router and both weight stacks) are the reference's, and the
+    rung's index says which capacity it was."""
+    x, router, w_gu, w_d, (first, count) = _load(load)
+    want, want_grads, n_here = _reference_of(load)
+    assert {"all_on_one_held_expert": n_here >= 24,
+            "no_row_held": n_here == 0}.get(load, 0 < n_here < 48)
+    capacity = RUNGS[rung](n_here)
+    # the compact rung where it holds the rows, else the full one behind it
+    index = int(capacity is not None and n_here > capacity)
+    assert index == (rung == "one_row_over_the_rung" and n_here > 0)
+
+    def f(x, router, a, b):
+        out, sizes, got = moe.expert_share(
+            x, router, a, b, experts_held=(first, count), k=4,
+            impl="ragged", capacity=capacity)
+        return (out ** 2).sum(), (out, sizes, got)
+
+    (_, (out, sizes, got)), grads = jax.jit(jax.value_and_grad(
+        f, (0, 1, 2, 3), has_aux=True))(
+        x, router, w_gu[first:first + count], w_d[first:first + count])
+    assert int(sizes[first:first + count].sum()) == n_here
+    assert int(got) == index
+    np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
+    for a, b in zip(grads, want_grads):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+def _carried_rows(seed=0, c=300, t=50, k=8, h=16):
+    """``c`` carried rows of which 200 are valid: five a token for 40 of
+    the 50 tokens, in an order of their own, so that sorted by token the run
+    of token 25 (rows 125-129) straddles the first block of 128."""
+    rng = np.random.default_rng(seed)
+    counts = np.zeros(t, np.int32)
+    counts[rng.permutation(t)[:40]] = 5
+    tok = np.repeat(np.arange(t), counts)
+    assert tok[127] == tok[128]  # a run over a block's edge
+    tok = np.concatenate([rng.permutation(tok), rng.integers(0, t, c - 200)])
+    valid = np.arange(c) < 200
+    plan = moe.row_plan(jnp.asarray(tok, jnp.int32), jnp.asarray(valid),
+                        jnp.asarray(counts), k)
+    return plan, counts, rng.normal(size=(c, h)).astype(np.float32), \
+        rng.normal(size=(t, h)).astype(np.float32)
+
+
+def test_combine_is_the_segment_sum_of_the_valid_rows():
+    plan, counts, y, _ = _carried_rows()
+    tok, valid = plan[:2]
+    want = jax.ops.segment_sum(jnp.where(valid[:, None], y, 0), tok, 50)
+    np.testing.assert_allclose(moe.combine(jnp.asarray(y), plan), want,
+                               rtol=1e-5, atol=1e-5)
+    assert float(jnp.abs(want[counts == 0]).max()) == 0.0
+    # what a row that is not valid holds never arrives
+    y = np.where(np.asarray(valid)[:, None], y, np.nan)
+    got = moe.combine(jnp.asarray(y), plan)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def test_spread_and_combine_are_each_other_s_transpose():
+    plan, _, y, x = _carried_rows(1)
+    tok, valid = plan[:2]
+    rows = moe.spread(jnp.asarray(x), plan)
+    np.testing.assert_array_equal(
+        rows, np.where(np.asarray(valid)[:, None], x[np.asarray(tok)], 0))
+    back = moe.combine(jnp.asarray(y), plan)
+    assert float((rows * y).sum()) == pytest.approx(
+        float((x * back).sum()), rel=1e-5)
+    # and each one's declared reverse pass is the other
+    np.testing.assert_allclose(
+        jax.vjp(lambda x: moe.spread(x, plan),
+                jnp.asarray(x))[1](jnp.asarray(y))[0], back, rtol=1e-6)
+    np.testing.assert_array_equal(
+        jax.vjp(lambda y: moe.combine(y, plan),
+                jnp.asarray(y))[1](jnp.asarray(x))[0], rows)
+
+
+def test_the_ladder_and_what_the_step_counts_of_it():
+    """The rungs are the shapes': twice the balanced load, doubled up to
+    every pair. The step's sums read the rungs' indices as rows carried and
+    as calls that took the last rung."""
+    from cgnn_tpu.train.lm_step import routing_metrics
+
+    assert moe.ladder(65536, 16, 128) == (16384, 32768, 65536)
+    assert moe.ladder(65536, 64, 128) == (65536,)
+    assert moe.ladder(96, 4, 16) == (96,)
+    assert moe.ladder(4096 * 2, 4, 64) == (1024, 2048, 4096, 8192)
+    sizes = jnp.zeros((2, 128), jnp.int32).at[:, :16].set(1000)
+    m = routing_metrics(sizes, jnp.asarray([[0, 1], [2, 0]], jnp.int32),
+                        (0, 16), 8, 2 * 8192)
+    assert float(m["moe_rows_here_sum"]) == 32000.0
+    assert float(m["moe_rows_capacity_sum"]) == 2 * 16384 + 32768 + 65536
+    assert float(m["moe_calls_full_rung_sum"]) == 1.0
 
 
 def test_swiglu_s_reverse_pass():
@@ -339,6 +482,10 @@ def test_three_adamw_steps_agree_with_the_reference(seed):
         if t == 0:
             grad = bd_train.first_gradient(state.opt_state, ADAMW["b1"])
     np.testing.assert_allclose(losses, want["loss"], rtol=2e-5)
+    # at this size the ladder is the one rung: 2 layers x 2 sequences carry
+    # all 64 x 4 pairs each
+    assert float(m["moe_calls_full_rung_sum"]) == 4.0
+    assert float(m["moe_rows_capacity_sum"]) == 4 * 2 * L * 4
     flat_got = jax.tree_util.tree_leaves_with_path(grad)
     flat_want = dict(jax.tree_util.tree_leaves_with_path(want["grad"]))
     assert len(flat_got) == 14
@@ -388,9 +535,10 @@ def test_bfloat16_compute_stays_near_float32():
     cfg16 = dataclasses.replace(CFG, dtype="bfloat16")
     params = _params(0)
     batch = tokens.split_batches(_pool(0), 2)[0]
-    a, _ = sdar.apply(CFG, {"params": params}, batch)
-    b, sizes = sdar.apply(cfg16, {"params": params}, batch)
+    a, *_ = sdar.apply(CFG, {"params": params}, batch)
+    b, sizes, rungs = sdar.apply(cfg16, {"params": params}, batch)
     assert a.shape == b.shape == (2,) and sizes.shape == (2, 16)
+    assert rungs.shape == (2, 2)  # a layer and sequence each
     np.testing.assert_allclose(a, b, rtol=0.05)
     assert int(sizes.sum()) == 2 * (2 * 2 * L) * 4
 

@@ -628,7 +628,9 @@ def test_sdar_scan_program_at_real_size_fits_the_chip(one_chip,
     memory analysis (the state's 5.48 GB aliased in place, ~7.6 GB of
     temporaries of which 1.83 GB are the gradients), and never holds a
     ``[*, 8192, 8192]`` score array. At 6 layers the same analysis read
-    16.97 GB (PERF.md section 4, PR 42)."""
+    16.97 GB (PERF.md section 4, PR 42). The expert layer's switch over its
+    rungs is there, forward and reverse, and its compact rungs hold no
+    array of all ``T x k`` rows (PR 44)."""
     import dataclasses
     import json
     import os
@@ -690,3 +692,43 @@ def test_sdar_scan_program_at_real_size_fits_the_chip(one_chip,
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 8  # splash and megablox
     assert not re.search(r"\[(?:\d+,)*8192,8192\]", text)
+    # the expert layer's switch (ops/moe.py): one conditional forward and
+    # one in the reverse pass (the rematerialised forward's is dead code),
+    # a branch a rung; only the last rung's branch holds an array of all
+    # T x k = 65,536 rows: a switch differentiated through would write the
+    # full rung's residuals as zeros in the compact ones
+    from cgnn_tpu.observe import phases
+    from cgnn_tpu.ops import moe
+
+    pairs = 2 * length * mc.num_experts_per_tok
+    rungs = moe.ladder(pairs, mc.experts_held[1], mc.n_experts)
+    assert rungs == (16384, 32768, 65536)
+    comps = phases._parse(text)
+    called = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)")
+
+    def lines_under(root):
+        seen, todo, lines = set(), [root], []
+        while todo:
+            comp = todo.pop()
+            if comp in seen or comp not in comps:
+                continue
+            seen.add(comp)
+            for rest in comps[comp]["instrs"].values():
+                lines.append(rest)
+                todo += called.findall(rest)
+        return lines
+
+    all_pairs = re.compile(rf"\[(?:\d+,)*{pairs}(?:,\d+)+\]")
+    switches = [re.search(r"branch_computations=\{([^}]*)\}", rest)
+                for comp in comps.values()
+                for rest in comp["instrs"].values()
+                if re.search(r"\sconditional\(", rest)]
+    assert len(switches) == 2, len(switches)
+    for switch in switches:
+        branches = [b.strip().lstrip("%") for b in
+                    switch.group(1).split(",")]
+        assert len(branches) == len(rungs)
+        wide = [sum(bool(all_pairs.search(ln)) for ln in lines_under(b))
+                for b in branches]
+        print(f"arrays of {pairs} rows a branch: {wide}")
+        assert wide[:-1] == [0] * (len(rungs) - 1) and wide[-1] > 0, wide
