@@ -322,14 +322,17 @@ def check_stacked_batch(stacked, dense_m: int | None = None,
 
 
 def check_token_batch(batch):
-    """Validate one host-side ``TokenBatch`` (data/tokens.py): the noised
-    copy and the clean one side by side, ids non-negative, documents as
-    non-decreasing runs from 0, and a weight in the loss wherever the
-    noised token differs from the clean one."""
+    """Validate one host-side ``TokenBatch`` (data/tokens.py): ids
+    non-negative, documents as non-decreasing runs from 0, weights finite
+    and non-negative; of a block-diffusion batch (the noised copy and the
+    clean one side by side) a weight in the loss wherever the noised token
+    differs from the clean one; of a causal batch none where the next token
+    is another document's or there is none."""
     tokens = np.asarray(batch.tokens)
     seg = np.asarray(batch.segment_ids)
     w = np.asarray(batch.loss_weight)
-    if tokens.ndim != 2 or tokens.shape[1] != 2 * seg.shape[1] \
+    length = seg.shape[-1]
+    if tokens.ndim != 2 or tokens.shape[1] not in (length, 2 * length) \
             or seg.shape != w.shape or seg.shape[0] != tokens.shape[0]:
         _fail(f"token batch shapes disagree: tokens {tokens.shape}, "
               f"segment_ids {seg.shape}, loss_weight {w.shape}")
@@ -340,7 +343,12 @@ def check_token_batch(batch):
         _fail("segment_ids are not non-decreasing runs from 0")
     if not np.isfinite(w).all() or (w < 0).any():
         _fail("loss weights must be finite and non-negative")
-    length = seg.shape[1]
+    if tokens.shape[1] == length:  # causal
+        if (w[:, -1] != 0).any() or (
+                (np.diff(seg, axis=1) != 0) & (w[:, :-1] != 0)).any():
+            _fail("a weight in the loss where the next token is another "
+                  "document's or there is none")
+        return batch
     changed = tokens[:, :length] != tokens[:, length:]
     if (changed & (w == 0)).any():
         _fail("a noised token without a weight in the loss")

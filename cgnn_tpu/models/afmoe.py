@@ -1,0 +1,368 @@
+"""A window-and-full-attention mixture-of-experts decoder (arcee-ai's
+``afmoe`` block: Trinity), as one chip of an expert-parallel layer holds it.
+
+A layer, on the residual stream ``x [S, L, H]`` (``x0 = embed[tokens] *
+sqrt(H)`` under ``mup_enabled``):
+
+    h = RMSNorm(x);  q, k, v, g = h W_q, h W_k, h W_v, h W_g    (no bias)
+    q, k = RMSNorm over each head's dims
+    sliding_attention: q, k = RoPE(q, k);  key j is visible to query i iff
+                       doc(j) = doc(i) and i - window < j <= i
+    full_attention:    no RoPE;  visible iff doc(j) = doc(i) and j <= i
+    a = masked_attention(q / sqrt(d), k, v)               (grouped queries)
+    x += RMSNorm((a * sigmoid(g)) W_o)
+    h = RMSNorm(x)
+    a leading dense layer: m = W_d(silu(h W_gate) * (h W_up))
+    an expert layer:       m = shared(h) + this share of sum_k p_k e_k(h)
+                           (ops/moe.py: sigmoid scores, the ``k`` largest of
+                           score + bias, p the scores' own, normed and scaled)
+    x += RMSNorm(m)
+
+The stack is the leading dense layers, then the expert layers in periods of
+``layer_types`` (window, window, window, full as published). The dense
+layers are one stack that is scanned; the periods are scanned, and inside a
+period each run of layers of one kind is a scan of its own (``periods/run0``
+the three window layers, ``periods/run1`` the full one, each leaf ``[periods,
+layers of the run, ...]``): a layer of each kind is all the program text
+there is, and a deeper stage is a longer leading axis. Within a layer the
+step's sequences go one at a time, a ``jax.checkpoint`` a layer and sequence
+(``lm_blocks.by_sequence``) that keeps the layer's input and the routed
+experts' output (the expert layer's switch is then not run again when the
+reverse pass rebuilds the rest). Weights are float32 and are cast to the
+compute dtype inside the layer; norms, RoPE, the router, the gate's sigmoid
+and the loss are float32.
+
+The selection biases are no parameters: they ride in the state's
+``batch_stats`` (``router_bias [periods, layers a period, E]``), ``apply``
+returns every router's counts, and the step moves the biases
+(train/lm_step.py). The vocabulary is a slice (``vocab_size`` rows are
+held); the loss is next-token cross-entropy over the slice.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import itertools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from cgnn_tpu.models import lm_blocks
+from cgnn_tpu.models.lm_blocks import (
+    by_sequence, chunked_loss_sums, rms_norm, rope,
+)
+from cgnn_tpu.observe import phases
+from cgnn_tpu.ops import moe
+from cgnn_tpu.ops.masked_attention import (
+    StaticMask, mask_tiles, masked_attention,
+)
+
+SLIDING, FULL = "sliding_attention", "full_attention"
+# what a layer's checkpoint keeps beside its input
+ROUTED = "moe.routed"
+# leaves initialised at the output projections' scale (``init_params``)
+OUTPUT_PROJECTIONS = ("wo", "w_down", "mlp_down", "shared_down")
+
+
+@dataclasses.dataclass(frozen=True)
+class AfmoeConfig:
+    # what train/lm_step.py makes of a batch (no field: the model's own)
+    objective = "causal"
+
+    hidden_size: int = 2048
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 4
+    head_dim: int = 128
+    num_hidden_layers: int = 5
+    num_dense_layers: int = 1
+    layer_types: tuple = (SLIDING,) * 4 + (FULL,)
+    sliding_window: int = 2048
+    intermediate_size: int = 6144
+    moe_intermediate_size: int = 1024
+    # the router's outputs (all experts of the layer) and the experts a token
+    # takes; ``experts_held`` = (first, count) is this chip's share
+    n_experts: int = 128
+    num_experts_per_tok: int = 8
+    experts_held: tuple = (0, 8)
+    num_shared_experts: int = 1
+    score_func: str = "sigmoid"
+    route_norm: bool = True
+    route_scale: float = 2.826
+    load_balance_coeff: float = 0.001
+    mup_enabled: bool = True
+    vocab_size: int = 25024
+    rope_theta: float = 1e4
+    rms_norm_eps: float = 1e-5
+    dtype: str = "bfloat16"
+    attn_impl: str = "auto"
+    moe_impl: str = "auto"
+
+    def __post_init__(self):
+        types = tuple(self.layer_types)
+        object.__setattr__(self, "layer_types", types)
+        if len(types) != self.num_hidden_layers \
+                or set(types) - {SLIDING, FULL}:
+            raise ValueError(f"layer_types {types} do not name "
+                             f"{self.num_hidden_layers} layers")
+        if len(set(types[:self.num_dense_layers])) > 1:
+            raise ValueError("the leading dense layers are one scanned "
+                             "stack: they have to be of one kind")
+        if not 0 <= self.num_dense_layers < self.num_hidden_layers:
+            raise ValueError("at least one expert layer")
+
+    @property
+    def period(self) -> tuple:
+        """The expert layers' kinds, one period of them."""
+        types = self.layer_types[self.num_dense_layers:]
+        for n in range(1, len(types) + 1):
+            if len(types) % n == 0 and types == types[:n] * (len(types) // n):
+                return types[:n]
+        raise AssertionError
+
+    @property
+    def runs(self) -> tuple:
+        """A period as runs of layers of one kind: ((kind, layers), ..)."""
+        return tuple((kind, len(list(run)))
+                     for kind, run in itertools.groupby(self.period))
+
+    @property
+    def n_periods(self) -> int:
+        return ((self.num_hidden_layers - self.num_dense_layers)
+                // len(self.period))
+
+    @property
+    def n_expert_layers(self) -> int:
+        return self.num_hidden_layers - self.num_dense_layers
+
+    @property
+    def compute_dtype(self):
+        return jnp.dtype(self.dtype)
+
+    @property
+    def routing(self) -> moe.Router:
+        return moe.Router(score_func=self.score_func, norm=self.route_norm,
+                          norm_eps=1e-20, scale=self.route_scale)
+
+    def _attention_shapes(self) -> dict:
+        h, d = self.hidden_size, self.head_dim
+        hq, hkv = self.num_attention_heads, self.num_key_value_heads
+        return {"attn_norm": (h,), "wq": (h, hq * d), "wk": (h, hkv * d),
+                "wv": (h, hkv * d), "wg": (h, hq * d), "q_norm": (d,),
+                "k_norm": (d,), "wo": (hq * d, h), "post_attn_norm": (h,),
+                "mlp_norm": (h,), "post_mlp_norm": (h,)}
+
+    def shapes(self) -> dict:
+        """The parameter tree's shapes, float32 all."""
+        h, i = self.hidden_size, self.moe_intermediate_size
+        nd, e = self.num_dense_layers, self.experts_held[1]
+        shared = i * self.num_shared_experts
+        expert_layer = {
+            **self._attention_shapes(), "router": (h, self.n_experts),
+            "w_gate_up": (e, h, 2 * i), "w_down": (e, i, h),
+            "shared_gate_up": (h, 2 * shared), "shared_down": (shared, h)}
+        dense_layer = {
+            **self._attention_shapes(),
+            "mlp_gate_up": (h, 2 * self.intermediate_size),
+            "mlp_down": (self.intermediate_size, h)}
+
+        def stacked(layer: dict, *leading) -> dict:
+            return {k: (*leading, *v) for k, v in layer.items()}
+
+        tree = {
+            "embed": (self.vocab_size, h),
+            "periods": {f"run{j}": stacked(expert_layer, self.n_periods, n)
+                        for j, (_, n) in enumerate(self.runs)},
+            "final_norm": (h,),
+            "head": (h, self.vocab_size),
+        }
+        if nd:
+            tree["dense"] = stacked(dense_layer, nd)
+        return tree
+
+    def n_params(self) -> int:
+        return sum(math.prod(s) for s in jax.tree_util.tree_leaves(
+            self.shapes(), is_leaf=lambda x: isinstance(x, tuple)))
+
+    def stats_shapes(self) -> dict:
+        """``batch_stats``: the selection biases, float32."""
+        return {"router_bias": (self.n_periods, len(self.period),
+                                self.n_experts)}
+
+
+def _mask(cfg: AfmoeConfig, kind: str, n: int) -> StaticMask:
+    return StaticMask("causal", n,
+                      window=cfg.sliding_window if kind == SLIDING else 0)
+
+
+def _attention(cfg: AfmoeConfig, kind: str, x, p, segment_ids):
+    """``x += RMSNorm((attention * sigmoid(gate)) W_o)`` on ``x [S, N, H]``."""
+    dt = cfg.compute_dtype
+    s, n, _ = x.shape
+    hq, hkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                  cfg.head_dim)
+    eps = cfg.rms_norm_eps
+    with jax.named_scope(phases.ATTN_PROJ):
+        hn = rms_norm(x, p["attn_norm"], eps).astype(dt)
+        q = (hn @ p["wq"].astype(dt)).reshape(s, n, hq, d)
+        k = (hn @ p["wk"].astype(dt)).reshape(s, n, hkv, d)
+        v = (hn @ p["wv"].astype(dt)).reshape(s, n, hkv, d)
+        gate = hn @ p["wg"].astype(dt)
+        q = rms_norm(q, p["q_norm"], eps)
+        k = rms_norm(k, p["k_norm"], eps)
+        if kind == SLIDING:  # the full layers take no positions (NoPE)
+            positions = jnp.arange(n, dtype=jnp.int32)
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
+        q = q * (1.0 / math.sqrt(d))
+        q, k, v = (jnp.swapaxes(t.astype(dt), 1, 2) for t in (q, k, v))
+    with jax.named_scope(phases.ATTN_WINDOW if kind == SLIDING
+                         else phases.ATTN_FULL):
+        a = masked_attention(q, k, v, segment_ids, _mask(cfg, kind, n),
+                             impl=cfg.attn_impl)
+    with jax.named_scope(phases.ATTN_PROJ):
+        a = jnp.swapaxes(a, 1, 2).reshape(s, n, hq * d)
+        a = (a.astype(jnp.float32)
+             * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(dt)
+        out = a @ p["wo"].astype(dt)
+        return x + rms_norm(out, p["post_attn_norm"], eps).astype(dt)
+
+
+def _mlp(x, gate_up, down):
+    return moe.swiglu(x @ gate_up) @ down
+
+
+def _dense_layer(cfg: AfmoeConfig, kind: str, x, p, segment_ids):
+    """A leading dense layer -> ``(x,)``."""
+    dt, eps = cfg.compute_dtype, cfg.rms_norm_eps
+    x = _attention(cfg, kind, x, p, segment_ids)
+    with jax.named_scope(phases.MLP_DENSE):
+        hn = rms_norm(x, p["mlp_norm"], eps).astype(dt)
+        m = _mlp(hn, p["mlp_gate_up"].astype(dt), p["mlp_down"].astype(dt))
+        return (x + rms_norm(m, p["post_mlp_norm"], eps).astype(dt),)
+
+
+def _expert_layer(cfg: AfmoeConfig, kind: str, x, p, bias, segment_ids):
+    """An expert layer -> (x, group_sizes, the rung that carried the held
+    experts' rows: ops/moe.py)."""
+    dt, eps = cfg.compute_dtype, cfg.rms_norm_eps
+    s, n, h = x.shape
+    x = _attention(cfg, kind, x, p, segment_ids)
+    # the layer's two MLP norms and the sum are booked with the shared
+    # expert, as ``mlp.dense`` holds the dense layer's: ``moe.route`` is the
+    # router and the rows' way out and back alone (ops/moe.py)
+    with jax.named_scope(phases.MOE_SHARED):
+        hn = rms_norm(x, p["mlp_norm"], eps).astype(dt)
+    with jax.named_scope(phases.MOE_EXPERT):
+        w_gate_up, w_down = p["w_gate_up"].astype(dt), p["w_down"].astype(dt)
+    routed, group_sizes, rung = moe.expert_share(
+        hn.reshape(s * n, h), p["router"], w_gate_up, w_down,
+        experts_held=cfg.experts_held, k=cfg.num_experts_per_tok,
+        impl=cfg.moe_impl, routing=cfg.routing, bias=bias)
+    routed = checkpoint_name(routed, ROUTED)
+    with jax.named_scope(phases.MOE_SHARED):
+        # every chip of the layer computes the shared expert whole
+        shared = _mlp(hn, p["shared_gate_up"].astype(dt),
+                      p["shared_down"].astype(dt))
+        m = routed.reshape(s, n, h).astype(jnp.float32) + shared
+        x = x + rms_norm(m, p["post_mlp_norm"], eps).astype(dt)
+    return x, group_sizes, rung
+
+
+def hidden_states(cfg: AfmoeConfig, params, router_bias, tokens,
+                  segment_ids):
+    """``tokens, segment_ids [S, L]`` -> (the last layer's ``x [S, L, H]``,
+    ``group_sizes [expert layers, E]``, ``rungs [expert layers, S]``: the
+    rung of each expert layer's and sequence's call)."""
+    with jax.named_scope(phases.LM_EMBED):
+        x = params["embed"][tokens]
+        if cfg.mup_enabled:
+            x = x * math.sqrt(cfg.hidden_size)
+        x = x.astype(cfg.compute_dtype)
+
+    keep_routed = jax.checkpoint_policies.save_only_these_names(ROUTED)
+
+    def dense_step(x, p):
+        # the casts to the compute dtype stay inside the layer: hoisted out
+        # of the scan they are a second copy of every layer's weights
+        p = jax.lax.optimization_barrier(p)
+        x, = by_sequence(
+            lambda x_seq, seg: _dense_layer(cfg, cfg.layer_types[0], x_seq,
+                                            p, seg), x, segment_ids)
+        return x, None
+
+    def expert_step(kind, x, layer):
+        p, bias = jax.lax.optimization_barrier(layer)
+        x, sizes, rungs = by_sequence(
+            lambda x_seq, seg: _expert_layer(cfg, kind, x_seq, p, bias, seg),
+            x, segment_ids, policy=keep_routed)
+        return x, (sizes.sum(axis=0), rungs)
+
+    def period_step(x, period):
+        p, bias = period
+        routed, at = [], 0
+        for j, (kind, n) in enumerate(cfg.runs):
+            x, of_run = jax.lax.scan(
+                functools.partial(expert_step, kind), x,
+                (p[f"run{j}"], bias[at:at + n]))
+            routed.append(of_run)
+            at += n
+        return x, tuple(jnp.concatenate(parts) for parts in zip(*routed))
+
+    # the loops' own machinery (a layer's input and routed output stacked
+    # for the reverse pass, the gradients stacked and summed over the
+    # sequences) is phase ``scan``; a layer's operations have their own
+    with jax.named_scope(phases.SCAN):
+        if cfg.num_dense_layers:
+            x, _ = jax.lax.scan(dense_step, x, params["dense"])
+        x, (group_sizes, rungs) = jax.lax.scan(
+            period_step, x, (params["periods"], router_bias))
+    return (x, group_sizes.reshape(-1, cfg.n_experts),
+            rungs.reshape(cfg.n_expert_layers, -1))
+
+
+def apply(cfg: AfmoeConfig, variables: dict, batch, train: bool = True):
+    """The ``apply_fn`` of a ``TrainState``: -> (each sequence's loss ``[S]``
+    float32, ``group_sizes [expert layers, E]``, ``rungs [expert layers,
+    S]``: ``hidden_states``). Position ``i`` predicts token ``i + 1`` where
+    ``batch.loss_weight`` says so (data/tokens.py, ``causal``); the logits
+    are an intermediate (``lm_blocks.chunked_loss_sums``)."""
+    del train  # no dropout; the biases move in the step, not here
+    params = variables["params"]
+    x, group_sizes, rungs = hidden_states(
+        cfg, params, variables["batch_stats"]["router_bias"], batch.tokens,
+        batch.segment_ids)
+    with jax.named_scope(phases.LM_HEAD):
+        # the last position's target is no token: its weight is 0
+        targets = jnp.roll(batch.tokens, -1, axis=1)
+        losses = chunked_loss_sums(
+            x, targets, batch.loss_weight, params["final_norm"],
+            params["head"], eps=cfg.rms_norm_eps, dtype=cfg.compute_dtype)
+    return losses, group_sizes, rungs
+
+
+def init_params(cfg: AfmoeConfig, rng, n_layers_published: int | None = None,
+                std: float = 0.02):
+    """normal(``std``) weights, the output projections (``wo`` and every
+    down projection) at ``std / sqrt(2 x published depth)``; norms at 1.
+    float32."""
+    depth = n_layers_published or cfg.num_hidden_layers
+    return lm_blocks.init_params(
+        cfg.shapes(), rng, std=std, out_std=std / math.sqrt(2.0 * depth),
+        output_projections=OUTPUT_PROJECTIONS)
+
+
+def init_stats(cfg: AfmoeConfig) -> dict:
+    """The selection biases at 0."""
+    return {k: jnp.zeros(s, jnp.float32)
+            for k, s in cfg.stats_shapes().items()}
+
+
+def attention_tiles(cfg: AfmoeConfig, seq_len: int) -> dict:
+    """{``window`` | ``full``: (live tiles, grid tiles a head and a
+    sequence, layers of the kind)} (ops/masked_attention.py)."""
+    return {name: (*mask_tiles(_mask(cfg, kind, seq_len)),
+                   cfg.layer_types.count(kind))
+            for name, kind in (("window", SLIDING), ("full", FULL))}

@@ -10,10 +10,10 @@ A layer, on the residual stream ``x [S, 2L, H]``:
 
 The layers' weights are stacked on a leading axis and the stack is scanned;
 within a layer the step's sequences go one at a time, a ``jax.checkpoint`` a
-layer and sequence: a layer's input is all the reverse pass keeps, and what
-it rebuilds (a sequence's projections, the rows routed to the experts held)
-is one sequence's at a time. Weights are float32 and are cast to the compute
-dtype inside the layer; norms, RoPE, the router and the loss are float32.
+layer and sequence (``by_sequence``; the norm, RoPE and the chunked head are
+``models/lm_blocks.py``'s too). Weights are float32 and are cast to the
+compute dtype inside the layer; norms, RoPE, the router and the loss are
+float32.
 
 The vocabulary is a slice too (``vocab_size`` rows are held): ids are drawn
 from the slice and the loss is over the slice.
@@ -22,12 +22,15 @@ from the slice and the loss is over the slice.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 
 import jax
 import jax.numpy as jnp
 
+from cgnn_tpu.models import lm_blocks
+from cgnn_tpu.models.lm_blocks import (
+    by_sequence, chunked_loss_sums, rms_norm, rope,
+)
 from cgnn_tpu.observe import phases
 from cgnn_tpu.ops import moe
 from cgnn_tpu.ops.bd_attention import bd_attention, bd_tiles
@@ -35,6 +38,9 @@ from cgnn_tpu.ops.bd_attention import bd_attention, bd_tiles
 
 @dataclasses.dataclass(frozen=True)
 class SdarConfig:
+    # what train/lm_step.py makes of a batch (no field: the model's own)
+    objective = "blockdiff"
+
     hidden_size: int = 2048
     num_attention_heads: int = 32
     num_key_value_heads: int = 4
@@ -86,24 +92,6 @@ class SdarConfig:
             self.shapes(), is_leaf=lambda x: isinstance(x, tuple)))
 
 
-def rms_norm(x, scale, eps: float):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
-                            + eps)
-    return y * scale
-
-
-def rope(x, positions, theta: float):
-    """``x [S, N, heads, D]`` float32, rotate-half as Qwen's."""
-    d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = positions.astype(jnp.float32)[:, None] * inv[None, :]  # [N, D/2]
-    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)[None, :, None]
-    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[None, :, None]
-    x1, x2 = x[..., : d // 2], x[..., d // 2:]
-    return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
-
-
 def _layer(cfg: SdarConfig, x, p, segment_ids):
     """One layer on ``x [S, N, H]`` (compute dtype) -> (x, group_sizes, the
     rung that carried the held experts' rows: ops/moe.py)."""
@@ -150,62 +138,25 @@ def hidden_states(cfg: SdarConfig, params, tokens, segment_ids):
         # the casts to the compute dtype stay inside the layer: hoisted out
         # of the scan they are a second copy of every layer's weights
         p = jax.lax.optimization_barrier(p)
-
-        @functools.partial(jax.checkpoint, prevent_cse=False)
-        def one(row):
-            x_seq, seg = row
-            out, sizes, rung = _layer(cfg, x_seq[None], p, seg[None])
-            return out[0], sizes, rung
-
-        # a scan over the sequences, and it has to stay one: under ``vmap``
-        # the expert layer's ``lax.switch`` becomes a select that runs every
-        # rung on every sequence
-        x, sizes, rungs = jax.lax.map(one, (x, segment_ids))
+        x, sizes, rungs = by_sequence(
+            lambda x_seq, seg: _layer(cfg, x_seq, p, seg), x, segment_ids)
         return x, (sizes.sum(axis=0), rungs)
 
     x, (group_sizes, rungs) = jax.lax.scan(step, x, params["layers"])
     return x, group_sizes, rungs
 
 
-# positions of the noised half whose logits are held at once
-HEAD_CHUNK = 1024
-
-
-def sequence_loss(logits, targets, loss_weight):
-    """One sequence's loss: ``logits [L, V]`` float32 at the noised half,
-    ``targets [L]`` the clean ids, ``loss_weight [L]`` (``1 / t`` of its
-    block where the token was masked, else 0): ``-(1 / L) sum_i w_i log
-    p(x_0^i)``."""
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    picked = jnp.take_along_axis(logp, targets[:, None], axis=-1)[:, 0]
-    return -(loss_weight * picked).sum() / targets.shape[0]
-
-
 def noised_loss_sums(cfg: SdarConfig, params, x, batch):
     """Each sequence's loss from the last layer's ``x``: the final norm, the
     head over the vocabulary slice and the loss at the noised half only (the
-    clean half is context), ``HEAD_CHUNK`` positions at a time and a
-    ``jax.checkpoint`` each: a chunk's ``[HEAD_CHUNK, V]`` float32 logits
-    are all that is ever held."""
+    clean half is context), a chunk of positions at a time
+    (``lm_blocks.chunked_loss_sums``). The targets are the clean ids,
+    ``loss_weight`` ``1 / t`` of its block where the token was masked."""
     length = batch.loss_weight.shape[-1]
-    chunk = HEAD_CHUNK if length % HEAD_CHUNK == 0 else length
-
-    @functools.partial(jax.checkpoint, prevent_cse=False)
-    def one(row):
-        x_rows, targets, weight = row
-        hn = rms_norm(x_rows, params["final_norm"], cfg.rms_norm_eps)
-        logits = jnp.dot(hn.astype(cfg.compute_dtype),
-                         params["head"].astype(cfg.compute_dtype),
-                         preferred_element_type=jnp.float32)
-        return sequence_loss(logits, targets, weight) * (chunk / length)
-
-    def rows(a):
-        return a.reshape(-1, chunk, *a.shape[2:])
-
-    losses = jax.lax.map(one, (rows(x[:, :length]),
-                               rows(batch.tokens[:, length:]),
-                               rows(batch.loss_weight)))
-    return losses.reshape(x.shape[0], -1).sum(axis=1)
+    return chunked_loss_sums(
+        x[:, :length], batch.tokens[:, length:], batch.loss_weight,
+        params["final_norm"], params["head"], eps=cfg.rms_norm_eps,
+        dtype=cfg.compute_dtype)
 
 
 def apply(cfg: SdarConfig, variables: dict, batch, train: bool = True):
@@ -227,19 +178,9 @@ def init_params(cfg: SdarConfig, rng, n_layers_published: int | None = None,
     """normal(``std``) weights, the output projections (``wo``, ``w_down``)
     at ``std / sqrt(2 x published depth)``; norms at 1. float32."""
     depth = n_layers_published or cfg.num_hidden_layers
-    out_std = std / math.sqrt(2.0 * depth)
-    flat, tree = jax.tree_util.tree_flatten_with_path(
-        cfg.shapes(), is_leaf=lambda x: isinstance(x, tuple))
-    leaves = []
-    for i, (path, shape) in enumerate(flat):
-        name = str(getattr(path[-1], "key", path[-1]))
-        if name.endswith("norm"):
-            leaves.append(jnp.ones(shape, jnp.float32))
-            continue
-        scale = out_std if name in ("wo", "w_down") else std
-        leaves.append(scale * jax.random.normal(
-            jax.random.fold_in(rng, i), shape, jnp.float32))
-    return jax.tree_util.tree_unflatten(tree, leaves)
+    return lm_blocks.init_params(
+        cfg.shapes(), rng, std=std, out_std=std / math.sqrt(2.0 * depth),
+        output_projections=("wo", "w_down"))
 
 
 def attention_tiles(cfg: SdarConfig, seq_len: int) -> tuple[int, int]:

@@ -7,7 +7,7 @@ stack — flax module scopes (``conv_1/bn1``), transforms (``jvp``,
 ``transpose``) and the ``jax.named_scope`` s the program adds where flax says
 nothing (models/cgcnn.py, models/forcefield.py, train/step.py,
 train/force_step.py, resilience/guard.py, data/compact.py, train/loop.py,
-models/sdar.py, ops/moe.py, train/lm_step.py). ``classify`` maps such a path to one phase
+models/sdar.py, models/afmoe.py, ops/moe.py, train/lm_step.py). ``classify`` maps such a path to one phase
 of ``PHASES`` and a direction; ``phase_table`` does so for every instruction
 of an optimized HLO module that the device can report as an event.
 
@@ -55,12 +55,24 @@ ATTN_BD = "attn.bd"
 MOE_ROUTE = "moe.route"
 MOE_EXPERT = "moe.expert"
 LM_HEAD = "lm.head"
+# models/afmoe.py: the window-and-full mixture-of-experts decoder. Its masked
+# attention alone by layer kind (``attn.proj`` takes its gate too), the
+# leading dense layer's MLP and the shared expert, each with its layer's two
+# MLP norms (``moe.shared`` also the sum with the routed rows: ``moe.route``
+# stays the router and the rows' way out and back); the held experts' weight
+# casts are under ``moe.expert`` there, the biases' update under
+# ``optimizer``
+ATTN_WINDOW = "attn.window"
+ATTN_FULL = "attn.full"
+MLP_DENSE = "mlp.dense"
+MOE_SHARED = "moe.shared"
 OTHER = "other"
 
 PHASES = (EXPAND, EMBED, EDGE_GEOM, CONV_GATHER, CONV_FC_FULL, CONV_BN1,
           CONV_GATE, CONV_AGGREGATE, CONV_BN2, CONV_LN, POOL_HEAD,
           FORCE_READOUT, LOSS, OPTIMIZER, SCAN, DP_ALLREDUCE, LM_EMBED,
-          ATTN_PROJ, ATTN_BD, MOE_ROUTE, MOE_EXPERT, LM_HEAD, OTHER)
+          ATTN_PROJ, ATTN_BD, MOE_ROUTE, MOE_EXPERT, LM_HEAD, ATTN_WINDOW,
+          ATTN_FULL, MLP_DENSE, MOE_SHARED, OTHER)
 FWD, BWD, BWD2 = "fwd", "bwd", "bwd2"
 
 # a path component -> its phase: the named scopes themselves, and the flax

@@ -1,11 +1,12 @@
 """One chip's share of an expert-parallel mixture-of-experts layer.
 
 The layer is told which experts it holds (``first``, ``count``). It routes
-every token over ALL ``n_experts`` (softmax over the router's logits, the
-``k`` largest kept and renormalised to sum 1), computes the experts it holds
-on the rows routed to them, and adds nothing for the absent ones: what comes
-out is this chip's part of the layer's result. On one chip there is no
-exchange, and nothing here stands in for one.
+every token over ALL ``n_experts`` (``route``: softmax over the router's
+logits, the ``k`` largest kept and renormalised to sum 1; or a ``Router``'s
+other form: sigmoid scores, a bias that selects, a scale), computes the
+experts it holds on the rows routed to them, and adds nothing for the absent
+ones: what comes out is this chip's part of the layer's result. On one chip
+there is no exchange, and nothing here stands in for one.
 
 Dropless, with static shapes, and the rows that travel are the rows that are
 here. All ``T x k`` (token, expert) pairs are sorted by expert, which leaves
@@ -29,6 +30,7 @@ token), so each one's reverse pass is the other.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -38,12 +40,42 @@ from cgnn_tpu.observe import phases
 from cgnn_tpu.ops.segment import _run_totals, _run_windows, gather
 
 
-def route(logits, k: int):
-    """Router logits ``[T, E]`` float32 -> (``weights [T, k]`` renormalised
-    to sum 1, ``experts [T, k]`` int32), over all ``E``."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    top, experts = jax.lax.top_k(probs, k)
-    return top / top.sum(axis=-1, keepdims=True), experts.astype(jnp.int32)
+@dataclasses.dataclass(frozen=True)
+class Router:
+    """How scores become choices and weights (``route``): ``score_func``
+    ``softmax`` over all experts or ``sigmoid`` of each logit; ``norm``: the
+    ``k`` kept scores divided by their sum (``+ norm_eps``); ``scale``
+    multiplies the weights. The default is Qwen3-MoE's."""
+    score_func: str = "softmax"
+    norm: bool = True
+    norm_eps: float = 0.0
+    scale: float = 1.0
+
+
+def route(logits, k: int, router: Router = Router(), bias=None):
+    """Router logits ``[T, E]`` float32 -> (``weights [T, k]``, ``experts
+    [T, k]`` int32), over all ``E``. With ``bias [E]`` the ``k`` experts are
+    the largest of ``score + bias`` and the weights are the scores' own: the
+    bias selects and does not weigh, and no gradient reaches it."""
+    logits = logits.astype(jnp.float32)
+    if router.score_func == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif router.score_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"no score function {router.score_func!r}")
+    if bias is None:
+        top, experts = jax.lax.top_k(scores, k)
+    else:
+        _, experts = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
+        top = jnp.take_along_axis(scores, experts, axis=-1)
+    if router.norm:
+        total = top.sum(axis=-1, keepdims=True)
+        top = top / (total + router.norm_eps if router.norm_eps else total)
+    if router.scale != 1.0:
+        top = top * router.scale
+    return top, experts.astype(jnp.int32)
 
 
 def ladder(rows: int, count: int, n_experts: int) -> tuple:
@@ -244,7 +276,8 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def expert_share(x, router, w_gate_up, w_down, *, experts_held: tuple,
-                 k: int, impl: str = "auto", capacity: int | None = None):
+                 k: int, impl: str = "auto", capacity: int | None = None,
+                 routing: Router = Router(), bias=None):
     """``x [T, H]`` (normed hidden states) -> (this share's part of the
     layer's output ``[T, H]`` in ``x``'s dtype, each token's sum accumulated
     in float32; ``group_sizes [E]`` int32: the rows each of ALL experts was
@@ -254,7 +287,8 @@ def expert_share(x, router, w_gate_up, w_down, *, experts_held: tuple,
     [count, I, H]`` in the compute dtype, the experts ``first .. first +
     count - 1``: ``e(h) = (silu(h W_g) * (h W_u)) W_d``. ``capacity`` puts
     another compact rung under ``T x k`` in the ladder's place (tests, at
-    sizes whose ladder is the one rung).
+    sizes whose ladder is the one rung). ``routing`` and ``bias [E]``:
+    ``route``'s.
     """
     first, count = experts_held
     t, n_experts = x.shape[0], router.shape[1]
@@ -266,7 +300,7 @@ def expert_share(x, router, w_gate_up, w_down, *, experts_held: tuple,
     with jax.named_scope(phases.MOE_ROUTE):
         logits = jnp.dot(x.astype(jnp.float32), router,
                          precision=jax.lax.Precision.HIGHEST)
-        weights, experts = route(logits, k)
+        weights, experts = route(logits, k, routing, bias)
         flat = experts.reshape(-1)
         order = jnp.argsort(flat, stable=True).astype(jnp.int32)
         group_sizes = (flat[:, None] == jnp.arange(

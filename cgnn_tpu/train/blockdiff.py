@@ -1,13 +1,15 @@
-"""``train.py --task blockdiff``: the block-diffusion mixture-of-experts
-decoder (models/sdar.py) through ``fit`` and the scan epoch driver, as every
-task goes: token batches staged resident, ``TrainState`` and
-``make_optimizer``, spans and phases.
+"""``train.py --task blockdiff`` and ``--task lm``: the decoders
+(models/sdar.py, block diffusion; models/afmoe.py, next-token) through
+``fit`` and the scan epoch driver, as every task goes: token batches staged
+resident, ``TrainState`` and ``make_optimizer``, spans and phases.
 
-The model is a preset (``tiny``, for the CPU; ``sdar-ep8``, one chip's share
-of SDAR-30B-A3B-Chat as ``benchmark/configs/sdar-30b-a3b-ep8.json`` has it)
-or a JSON file of ``SdarConfig``'s fields. The data is a synthetic pool of
-packed, pre-noised sequences (data/tokens.py): a tokenizer and a corpus
-reader are not part of this repo.
+The model is a preset of its task (``PRESETS``: ``tiny`` for the CPU;
+``sdar-ep8``, one chip's share of SDAR-30B-A3B-Chat as
+``benchmark/configs/sdar-30b-a3b-ep8.json`` has it; ``trinity-mini-ep16``,
+one chip's share of Trinity-Mini as ``benchmark/configs/
+trinity-mini-ep16.json`` has it) or a JSON file of the config dataclass's
+fields. The data is a synthetic pool of packed sequences (data/tokens.py): a
+tokenizer and a corpus reader are not part of this repo.
 """
 
 from __future__ import annotations
@@ -15,20 +17,43 @@ from __future__ import annotations
 import functools
 import json
 
+# task -> preset -> the fields of the task's config dataclass that differ
+# from its defaults
 PRESETS = {
-    "tiny": dict(hidden_size=64, num_attention_heads=4,
-                 num_key_value_heads=2, head_dim=16, num_hidden_layers=2,
-                 n_experts=16, num_experts_per_tok=4, experts_held=(0, 4),
-                 moe_intermediate_size=32, vocab_size=256, dtype="float32"),
-    "sdar-ep8": dict(num_hidden_layers=4),  # the dataclass's defaults
+    "blockdiff": {
+        "tiny": dict(hidden_size=64, num_attention_heads=4,
+                     num_key_value_heads=2, head_dim=16, num_hidden_layers=2,
+                     n_experts=16, num_experts_per_tok=4, experts_held=(0, 4),
+                     moe_intermediate_size=32, vocab_size=256,
+                     dtype="float32"),
+        "sdar-ep8": dict(num_hidden_layers=4),
+    },
+    "lm": {
+        "tiny": dict(hidden_size=64, num_attention_heads=4,
+                     num_key_value_heads=2, head_dim=16, num_hidden_layers=5,
+                     num_dense_layers=1, sliding_window=16,
+                     layer_types=("sliding_attention",)
+                     + ("sliding_attention", "full_attention") * 2,
+                     intermediate_size=96, moe_intermediate_size=32,
+                     n_experts=16, num_experts_per_tok=4, experts_held=(0, 4),
+                     vocab_size=256, dtype="float32"),
+        "trinity-mini-ep16": dict(),  # the dataclass's defaults
+    },
 }
 
 
-def model_config(spec: str, bf16: bool):
-    from cgnn_tpu.models.sdar import SdarConfig
+def model_config(task: str, spec: str, bf16: bool):
+    """The task's config dataclass from a preset's name or a JSON file."""
+    if task == "lm":
+        from cgnn_tpu.models.afmoe import AfmoeConfig as Config
+    else:
+        from cgnn_tpu.models.sdar import SdarConfig as Config
 
-    if spec in PRESETS:
-        fields = dict(PRESETS[spec])
+    if spec in PRESETS[task]:
+        fields = dict(PRESETS[task][spec])
+    elif any(spec in presets for presets in PRESETS.values()):
+        raise ValueError(f"--lm-model {spec} is no preset of --task {task} "
+                         f"(its own: {', '.join(PRESETS[task])})")
     else:
         with open(spec) as f:
             fields = json.load(f)
@@ -36,7 +61,7 @@ def model_config(spec: str, bf16: bool):
             fields["experts_held"] = tuple(fields["experts_held"])
     if bf16:
         fields["dtype"] = "bfloat16"
-    return SdarConfig(**fields)
+    return Config(**fields)
 
 
 def run(args, telemetry, preempt=None, log_fn=print) -> int:
@@ -45,23 +70,27 @@ def run(args, telemetry, preempt=None, log_fn=print) -> int:
     import jax.numpy as jnp
 
     from cgnn_tpu.data import tokens
-    from cgnn_tpu.models import sdar
+    from cgnn_tpu.models import afmoe, sdar
     from cgnn_tpu.train import Normalizer, fit, make_optimizer
     from cgnn_tpu.train.lm_step import make_lm_eval_step, make_lm_train_step
     from cgnn_tpu.train.state import TrainState
 
-    cfg = model_config(args.lm_model, args.bf16)
+    task = args.task
+    causal = task == "lm"
+    model = afmoe if causal else sdar
+    cfg = model_config(task, args.lm_model, args.bf16)
     n = args.synthetic or 16
     per_step = args.batch_size
     length = args.lm_seq_len
+    block = 1 if causal else cfg.block_length
     pool = tokens.make_pool(
-        n, length, vocab_size=cfg.vocab_size, block=cfg.block_length,
-        seed=args.seed, doc_median=length / 2, doc_min=cfg.block_length,
-        doc_max=length)
+        n, length, vocab_size=cfg.vocab_size, block=block, seed=args.seed,
+        doc_median=length / 2, doc_min=max(block, 2), doc_max=length,
+        kind="causal" if causal else "blockdiff")
     batches = tokens.split_batches(pool, per_step)
     n_val = max(1, len(batches) // 8)
     train_b, val_b = batches[:-n_val], batches[-n_val:]
-    log_fn(f"blockdiff: {cfg.n_params() / 1e6:.2f} M parameters "
+    log_fn(f"{task}: {cfg.n_params() / 1e6:.2f} M parameters "
            f"({cfg.num_hidden_layers} layers, experts "
            f"{cfg.experts_held[0]}..{sum(cfg.experts_held) - 1} of "
            f"{cfg.n_experts} held), {n} sequences of {length} tokens, "
@@ -70,14 +99,15 @@ def run(args, telemetry, preempt=None, log_fn=print) -> int:
         optim=args.optim, lr=args.lr, momentum=args.momentum,
         weight_decay=args.weight_decay,
         lr_milestones=[m * len(train_b) for m in args.lr_milestones])
-    params = jax.jit(functools.partial(sdar.init_params, cfg))(
+    params = jax.jit(functools.partial(model.init_params, cfg))(
         jax.random.key(args.seed))
     state = TrainState(
-        step=jnp.zeros((), jnp.int32), params=params, batch_stats={},
+        step=jnp.zeros((), jnp.int32), params=params,
+        batch_stats=afmoe.init_stats(cfg) if causal else {},
         opt_state=tx.init(params), normalizer=Normalizer.identity(1),
         rng=jax.random.key(args.seed),
-        apply_fn=functools.partial(sdar.apply, cfg), tx=tx)
-    tiles = sdar.attention_tiles(cfg, length)
+        apply_fn=functools.partial(model.apply, cfg), tx=tx)
+    tiles = model.attention_tiles(cfg, length)
     state, result = fit(
         state, [], [], epochs=args.epochs, batch_size=per_step,
         seed=args.seed, print_freq=0, scan_epochs=True,
@@ -87,11 +117,17 @@ def run(args, telemetry, preempt=None, log_fn=print) -> int:
         chunk_steps=args.chunk_steps, telemetry=telemetry, preempt=preempt,
         log_fn=log_fn)
     last = result["history"][-1]
+    own = (("attn_window_tiles_live", "attn_window_tiles_grid",
+            "attn_full_tiles_live", "attn_full_tiles_grid", "weighted_tokens",
+            "expert_bias_abs_max") if causal
+           else ("bd_tiles_live", "bd_tiles_grid", "masked_tokens"))
     for name in ("moe_rows_here", "moe_rows_balanced", "moe_rows_capacity",
-                 "moe_calls_full_rung", "expert_load_max_over_mean",
-                 "bd_tiles_live", "bd_tiles_grid", "masked_tokens"):
+                 "moe_calls_full_rung", "expert_load_max_over_mean", *own):
         telemetry.set_gauge(name, float(last["train"].get(name, 0.0)))
     log_fn(f"** best val loss {result['best']:.4f}; a step routed "
            f"{last['train']['moe_rows_here']:.0f} rows to the experts held "
-           f"({last['train']['moe_rows_balanced']:.0f} balanced)")
+           f"({last['train']['moe_rows_balanced']:.0f} balanced)"
+           + (f"; largest bias "
+              f"{last['train']['expert_bias_abs_max']:.4f}" if causal
+              else ""))
     return 0

@@ -1,15 +1,21 @@
-"""The block-diffusion language-model step (models/sdar.py), with the same
+"""The language-model steps (models/sdar.py, models/afmoe.py), with the same
 carry as every task's: ``(state, batch) -> (state, metrics)``, metrics as
 sums so that an epoch's are exact.
 
-The loss is BD3-LM's, read at the noised half only:
+The causal loss (models/afmoe.py) is next-token cross-entropy, ``-(1 / L)
+sum_i w_i log p(x^{i+1} | x^{<=i})`` a sequence with ``w_i`` 0 where the next
+token is another document's or there is none; after the optimizer's step the
+routers' selection biases move by the step's counts (``balanced_biases``):
+state that no gradient reaches, carried in ``TrainState.batch_stats`` as the
+conv models carry BatchNorm's statistics. The block-diffusion loss is
+BD3-LM's, read at the noised half only:
 
     -(1 / L) sum_i 1[x_t^i = MASK] (1 / t_b(i)) log p(x_0^i | x_t, x_0)
 
 a sequence, the mean over a step's sequences; ``1[..] / t`` is staged with
 the batch (``loss_weight``). The softmax is over the vocabulary slice held.
 The model's ``apply`` returns each sequence's loss (it computes it a sequence
-at a time, models/sdar.py); the step differentiates their mean.
+at a time); the step differentiates their mean.
 """
 
 from __future__ import annotations
@@ -52,50 +58,98 @@ def routing_metrics(group_sizes, rungs, experts_held: tuple, k: int,
     }
 
 
-def make_lm_train_step(cfg, tiles: tuple[int, int] | None = None) -> Callable:
-    """``cfg`` is the model's (``models.sdar.SdarConfig``); ``tiles`` the
-    attention's (live, grid) tiles a head and a sequence, counted into the
-    metrics where given."""
+def balanced_biases(bias, group_sizes, coeff: float):
+    """The routers' selection biases after a step (models/afmoe.py): with
+    ``n_e`` the step's tokens that chose expert ``e`` of a layer, ``d = coeff
+    * sign(mean(n) - n)`` and ``b += d - mean(d)``: an overloaded expert's
+    bias falls, and the biases of a layer keep their mean. ``bias [..., E]``
+    float32, ``group_sizes`` as many counts."""
+    n = group_sizes.astype(jnp.float32).reshape(bias.shape)
+    d = coeff * jnp.sign(n.mean(axis=-1, keepdims=True) - n)
+    return bias + d - d.mean(axis=-1, keepdims=True)
+
+
+def _balance_coeff(cfg):
+    """The step size of the routers' selection biases, or None for a model
+    that keeps none (its config has no ``load_balance_coeff``)."""
+    return getattr(cfg, "load_balance_coeff", None)
+
+
+def make_lm_train_step(cfg, tiles=None) -> Callable:
+    """``cfg`` is the model's and says what the step is: its ``objective``
+    (``models.sdar.SdarConfig``: ``blockdiff``, BD3-LM's weighted loss;
+    ``models.afmoe.AfmoeConfig``: ``causal``, next-token cross-entropy) and,
+    where it has a ``load_balance_coeff``, that the step moves the routers'
+    biases, which no gradient reaches, after the optimizer's; ``tiles`` the
+    attention's tile counts (the model's ``attention_tiles``), counted into
+    the metrics where given."""
+    coeff = _balance_coeff(cfg)
 
     def train_step(state: TrainState, batch: TokenBatch):
         def loss_with_aux(params):
             losses, *routed = state.apply_fn(
-                {"params": params}, batch, train=True)
+                {"params": params, "batch_stats": state.batch_stats}, batch,
+                train=True)
             return losses.mean(), (losses.sum(), routed)
 
         (_, (loss_sum, routed)), grads = jax.value_and_grad(
             loss_with_aux, has_aux=True)(state.params)
         with jax.named_scope(phases.OPTIMIZER):
-            new_state = state.apply_gradients(grads, state.batch_stats)
-        return new_state, step_metrics(cfg, batch, loss_sum, routed, tiles)
+            stats = state.batch_stats
+            if coeff is not None:
+                stats = {"router_bias": balanced_biases(
+                    stats["router_bias"], routed[0], coeff)}
+            new_state = state.apply_gradients(grads, stats)
+        return new_state, step_metrics(cfg, batch, loss_sum, routed, tiles,
+                                       new_state.batch_stats)
 
     return train_step
 
 
-def make_lm_eval_step(cfg, tiles: tuple[int, int] | None = None) -> Callable:
+def make_lm_eval_step(cfg, tiles=None) -> Callable:
     def eval_step(state: TrainState, batch: TokenBatch):
         losses, *routed = state.apply_fn(state.variables(), batch,
                                          train=False)
-        return step_metrics(cfg, batch, losses.sum(), routed, tiles)
+        return step_metrics(cfg, batch, losses.sum(), routed, tiles,
+                            state.batch_stats)
 
     return eval_step
 
 
-def step_metrics(cfg, batch: TokenBatch, loss_sum, routed, tiles) -> dict:
-    """``routed``: the model's ``(group_sizes, rungs)``."""
+def step_metrics(cfg, batch: TokenBatch, loss_sum, routed, tiles,
+                 stats=None) -> dict:
+    """``routed``: the model's ``(group_sizes, rungs)``; ``stats`` the
+    state's ``batch_stats`` (the routers' biases where the model has them).
+    By ``cfg.objective``: a ``blockdiff`` step counts its masked tokens and
+    the mask's tiles (``tiles`` = (live, grid) a head and a sequence, every
+    layer alike); a ``causal`` one its weighted tokens and the tiles of each
+    kind of layer (``tiles`` = {kind: (live, grid, layers)})."""
     with jax.named_scope(phases.LM_HEAD):
         s, n = batch.tokens.shape
+        causal = cfg.objective == "causal"
+        weighted = (batch.loss_weight > 0).sum().astype(jnp.float32)
         metrics = {
             "loss_sum": loss_sum, "count": jnp.float32(s),
-            "masked_tokens_sum": (batch.loss_weight > 0).sum().astype(
-                jnp.float32),
+            ("weighted_tokens_sum" if causal else "masked_tokens_sum"):
+                weighted,
             **routing_metrics(*routed, cfg.experts_held,
                               cfg.num_experts_per_tok, s * n),
         }
-        if tiles is not None:
-            heads = cfg.num_attention_heads * cfg.num_hidden_layers * s
-            metrics["bd_tiles_live_sum"] = jnp.float32(heads * tiles[0])
-            metrics["bd_tiles_grid_sum"] = jnp.float32(heads * tiles[1])
+        if _balance_coeff(cfg) is not None:
+            metrics["expert_bias_abs_max_sum"] = jnp.abs(
+                stats["router_bias"]).max()
+        heads = cfg.num_attention_heads * s
+        if tiles is not None and causal:
+            for kind, (live, grid, layers) in tiles.items():
+                metrics[f"attn_{kind}_tiles_live_sum"] = jnp.float32(
+                    heads * layers * live)
+                metrics[f"attn_{kind}_tiles_grid_sum"] = jnp.float32(
+                    heads * layers * grid)
+        elif tiles is not None:
+            metrics["bd_tiles_live_sum"] = jnp.float32(
+                heads * cfg.num_hidden_layers * tiles[0])
+            metrics["bd_tiles_grid_sum"] = jnp.float32(
+                heads * cfg.num_hidden_layers * tiles[1])
         # each of these is a step's own number, not a sequence's: its mean
         # over an epoch divides by the steps
         for name in [m for m in metrics if m.endswith("_sum")

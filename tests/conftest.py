@@ -31,3 +31,19 @@ jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 from cgnn_tpu.data import invariants  # noqa: E402
 
 invariants.enable()
+
+
+# the files that take minutes start first: under ``--dist loadfile`` a file is
+# one worker's from start to end, and the suite's wall time is the last
+# file's end (``test_tpu_compile.py`` sorted near the end and ran alone for
+# minutes after every other worker had finished)
+LONGEST_FIRST = ("test_tpu_compile.py", "test_afmoe.py", "test_sdar.py",
+                 "test_benchmark_harness.py", "test_entrypoints.py",
+                 "test_forces.py", "test_batching.py",
+                 "test_trinity_cell.py", "test_dp_ref.py", "test_ops.py")
+
+
+def pytest_collection_modifyitems(items):
+    rank = {name: i for i, name in enumerate(LONGEST_FIRST)}
+    items.sort(key=lambda item: rank.get(item.fspath.basename,
+                                         len(LONGEST_FIRST)))

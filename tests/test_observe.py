@@ -555,6 +555,27 @@ _CLASSIFIED = [
      ("lm.head", "fwd")),
     (_LM + "transpose(jvp(lm.head))/while/body/checkpoint/"
      "rematted_computation/dot_general", ("lm.head", "bwd")),
+    # models/afmoe.py: the masked attention by layer kind, the leading dense
+    # layer's MLP and the shared expert; the periods' runs are scans in a scan
+    (_LM + "jvp(while)/body/while/body/while/body/checkpoint/attn.window/"
+     "vmap(vmap(jit(splash_mqa)))/pallas_call", ("attn.window", "fwd")),
+    (_LM + "transpose(jvp(while))/body/while/body/while/body/checkpoint/"
+     "attn.window/pallas_call", ("attn.window", "bwd")),
+    (_LM + "jvp(while)/body/while/body/while/body/checkpoint/attn.full/"
+     "vmap(vmap(jit(splash_mqa)))/pallas_call", ("attn.full", "fwd")),
+    (_LM + "transpose(jvp(while))/body/while/body/while/body/checkpoint/"
+     "attn.full/pallas_call", ("attn.full", "bwd")),
+    (_LM + "jvp(while)/body/while/body/checkpoint/mlp.dense/dot_general",
+     ("mlp.dense", "fwd")),
+    (_LM + "transpose(jvp(while))/body/while/body/checkpoint/"
+     "rematted_computation/mlp.dense/dot_general", ("mlp.dense", "bwd")),
+    (_LM + "jvp(while)/body/while/body/while/body/checkpoint/moe.shared/"
+     "dot_general", ("moe.shared", "fwd")),
+    (_LM + "transpose(jvp(while))/body/while/body/while/body/checkpoint/"
+     "rematted_computation/moe.shared/dot_general", ("moe.shared", "bwd")),
+    # the held experts' weight casts are the expert phase's there
+    (_LM + "jvp(while)/body/while/body/while/body/checkpoint/moe.expert/"
+     "convert_element_type", ("moe.expert", "fwd")),
     ("jit(train_step)/add", ("other", "fwd")),
     (_SCAN + "mul", ("other", "fwd")),
     ("reduce_sum", ("other", "fwd")),
@@ -579,7 +600,8 @@ class TestPhases:
                    "conv.aggregate", "conv.bn2", "conv.ln", "embed",
                    "pool_head", "loss", "edge_geom", "force_readout",
                    "lm.embed", "attn.proj", "attn.bd", "moe.route",
-                   "moe.expert", "lm.head"}
+                   "moe.expert", "lm.head", "attn.window", "attn.full",
+                   "mlp.dense", "moe.shared"}
         assert {p for p, d in seen if d == "bwd"} == two_way
         # what the force step differentiates twice: the trunk without
         # BatchNorm, the geometry and the readout (not the embedding, which

@@ -732,3 +732,91 @@ def test_sdar_scan_program_at_real_size_fits_the_chip(one_chip,
                 for b in branches]
         print(f"arrays of {pairs} rows a branch: {wide}")
         assert wide[:-1] == [0] * (len(rungs) - 1) and wide[-1] > 0, wide
+
+
+def test_trinity_scan_program_at_real_size_fits_the_chip(one_chip,
+                                                         no_compile_cache):
+    """``trinity.train``'s window program (a chunk of two steps of the
+    window-and-full-attention decoder at the cell's real size: 1 dense + 4
+    expert layers, 504.1 M parameters, 2 sequences of 8,192 tokens a step)
+    compiles for the described chip with its kernels in it (splash under two
+    masks, megablox) and leaves at least 0.5 GB of the chip's 15.75 by the
+    compile's memory analysis, the state's 6.05 GB aliased in place. It
+    never holds a ``[*, 8192, 8192]`` score array, and the expert layer's
+    switch over its rungs is there once a kind of layer (the period's runs
+    are scanned), forward and reverse: the rematerialised forward's is dead
+    code, because the layer's checkpoint keeps the routed output."""
+    import dataclasses
+    import json
+    import os
+
+    from benchmark.kinds import lm_train
+    from benchmark.weights import seed_key
+    from benchmark.weights_afmoe import StateMaker
+    from cgnn_tpu.data import tokens
+    from cgnn_tpu.models import afmoe
+    from cgnn_tpu.ops import moe
+    from cgnn_tpu.train import lm_step, make_optimizer
+    from cgnn_tpu.train.loop import ScanEpochDriver
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "trinity-mini-ep16.json")) as f:
+        cfg = json.load(f)
+    # the described chip is not the default backend: name the kernels
+    mc = dataclasses.replace(lm_train.model_config(cfg), attn_impl="splash",
+                             moe_impl="megablox")
+    tr, length = cfg["train"], int(cfg["data"]["sequence_length"])
+    tx = make_optimizer(optim="adamw", lr=tr["lr"], b1=tr["b1"], b2=tr["b2"],
+                        weight_decay=tr["weight_decay"], lr_milestones=[])
+    maker = StateMaker(mc, cfg["init"], tx,
+                       functools.partial(afmoe.apply, mc))
+    state = jax.eval_shape(maker._build, seed_key(1), jnp.float32(0.0))
+    assert sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(
+        state.params)) == mc.n_params() == 504_147_200
+    assert state.batch_stats["router_bias"].shape == (1, 4, 128)
+    batches = tokens.split_batches(
+        tokens.make_pool(8, length, vocab_size=mc.vocab_size, seed=0,
+                         kind="causal"), int(tr["batch_size"]))
+    tiles = afmoe.attention_tiles(mc, length)
+    assert tiles == {"window": (70, 256, 4), "full": (136, 256, 1)}
+    driver = ScanEpochDriver(
+        lm_step.make_lm_train_step(mc, tiles),
+        lm_step.make_lm_eval_step(mc, tiles), batches, [],
+        np.random.default_rng(0), chunk_steps=2)
+    (key, stacked), = driver._train_groups.items()
+    fn = driver._window_fn(driver._train_scans, (key, 2),
+                           driver._train_body, True)
+    assert fn.__name__ == "scan_train_n16384_l2"
+    shapes = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                       sharding=one_chip),
+        (state, stacked, np.zeros(len(batches), np.int32),
+         np.zeros((), np.int32)))
+    # the suite runs under jax_enable_x64 (conftest.py), which no entry
+    # point sets and under which the kernels' lowering never ends
+    with jax.enable_x64(False):
+        compiled = fn.lower(*shapes).compile()
+    mem = compiled.memory_analysis()
+    on_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"trinity.train's window program: state "
+          f"{mem.alias_size_in_bytes / 1e9:.2f} GB aliased, temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.2f} GB, {on_chip / 1e9:.2f} GB "
+          f"on the chip")
+    assert mem.alias_size_in_bytes > 6.0e9  # the state is updated in place
+    assert 8e9 < on_chip < 15.25e9, on_chip  # 0.5 GB of 15.75 to spare
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 8  # splash and megablox
+    assert not re.search(r"\[(?:\d+,)*8192,8192\]", text)
+    pairs = length * mc.num_experts_per_tok
+    assert moe.ladder(pairs, 8, 128) == (8192, 16384, 32768, 65536)
+    from cgnn_tpu.observe import phases
+
+    switches = [rest for comp in phases._parse(text).values()
+                for rest in comp["instrs"].values()
+                if re.search(r"\sconditional\(", rest)]
+    print(f"{len(switches)} conditionals")
+    # a switch forward and one in the reverse pass, each run of the period
+    assert len(switches) == 2 * len(mc.runs) == 4, len(switches)
+

@@ -36,18 +36,22 @@ def build_parser() -> argparse.ArgumentParser:
                         "(50-200+ atom graphs; BASELINE config #4)")
     p.add_argument("--task",
                    choices=["regression", "classification", "force",
-                            "blockdiff"],
+                            "blockdiff", "lm"],
                    default="regression",
                    help="'force' trains the differentiable force field on "
                         "energy+force labels (BASELINE config #5); "
                         "'blockdiff' the block-diffusion mixture-of-experts "
-                        "decoder on packed token sequences "
-                        "(cgnn_tpu/train/blockdiff.py)")
+                        "decoder on packed token sequences, 'lm' the "
+                        "window-and-full-attention mixture-of-experts "
+                        "decoder on next-token prediction (both "
+                        "cgnn_tpu/train/blockdiff.py)")
     p.add_argument("--lm-model", default="tiny",
-                   help="--task blockdiff: a preset (tiny | sdar-ep8) or a "
-                        "JSON file of models.sdar.SdarConfig's fields")
+                   help="the task's preset (--task blockdiff: tiny | "
+                        "sdar-ep8; --task lm: tiny | trinity-mini-ep16) or "
+                        "a JSON file of the fields of its config dataclass "
+                        "(models.sdar.SdarConfig; models.afmoe.AfmoeConfig)")
     p.add_argument("--lm-seq-len", type=int, default=64,
-                   help="--task blockdiff: tokens a packed sequence "
+                   help="--task blockdiff | lm: tokens a packed sequence "
                         "(--synthetic N sequences, -b of them a step)")
     p.add_argument("--device", choices=["auto", "cpu", "tpu"], default="auto",
                    help="accelerator (reference flag; 'auto' uses what jax finds)")
@@ -337,7 +341,7 @@ def main(argv=None) -> int:
     if fault_plan is not None:
         print(f"FAULT INJECTION ACTIVE: {fault_plan.describe()}",
               file=sys.stderr)
-    if args.task == "blockdiff":
+    if args.task in ("blockdiff", "lm"):
         # no graphs: the task packs token batches itself and hands them to
         # fit(), the scan driver and the state of every task
         from cgnn_tpu.train import blockdiff
