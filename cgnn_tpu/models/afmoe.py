@@ -26,9 +26,10 @@ the three window layers, ``periods/run1`` the full one, each leaf ``[periods,
 layers of the run, ...]``): a layer of each kind is all the program text
 there is, and a deeper stage is a longer leading axis. Within a layer the
 step's sequences go one at a time, a ``jax.checkpoint`` a layer and sequence
-(``lm_blocks.by_sequence``) that keeps the layer's input and the routed
-experts' output (the expert layer's switch is then not run again when the
-reverse pass rebuilds the rest). Weights are float32 and are cast to the
+(``lm_blocks.by_sequence``) that keeps the layer's input, its attention's
+output and the routed experts' output (neither the attention's forward
+kernel nor the expert layer's switch is run again when the reverse pass
+rebuilds the rest). Weights are float32 and are cast to the
 compute dtype inside the layer; norms, RoPE, the router, the gate's sigmoid
 and the loss are float32.
 
@@ -61,7 +62,8 @@ from cgnn_tpu.ops.masked_attention import (
 )
 
 SLIDING, FULL = "sliding_attention", "full_attention"
-# what a layer's checkpoint keeps beside its input
+# what an expert layer's checkpoint keeps beside its input and its
+# attention's output (``lm_blocks.by_sequence``)
 ROUTED = "moe.routed"
 # leaves initialised at the output projections' scale (``init_params``)
 OUTPUT_PROJECTIONS = ("wo", "w_down", "mlp_down", "shared_down")
@@ -282,8 +284,6 @@ def hidden_states(cfg: AfmoeConfig, params, router_bias, tokens,
             x = x * math.sqrt(cfg.hidden_size)
         x = x.astype(cfg.compute_dtype)
 
-    keep_routed = jax.checkpoint_policies.save_only_these_names(ROUTED)
-
     def dense_step(x, p):
         # the casts to the compute dtype stay inside the layer: hoisted out
         # of the scan they are a second copy of every layer's weights
@@ -297,7 +297,7 @@ def hidden_states(cfg: AfmoeConfig, params, router_bias, tokens,
         p, bias = jax.lax.optimization_barrier(layer)
         x, sizes, rungs = by_sequence(
             lambda x_seq, seg: _expert_layer(cfg, kind, x_seq, p, bias, seg),
-            x, segment_ids, policy=keep_routed)
+            x, segment_ids, keep=(ROUTED,))
         return x, (sizes.sum(axis=0), rungs)
 
     def period_step(x, period):
@@ -311,9 +311,10 @@ def hidden_states(cfg: AfmoeConfig, params, router_bias, tokens,
             at += n
         return x, tuple(jnp.concatenate(parts) for parts in zip(*routed))
 
-    # the loops' own machinery (a layer's input and routed output stacked
-    # for the reverse pass, the gradients stacked and summed over the
-    # sequences) is phase ``scan``; a layer's operations have their own
+    # the loops' own machinery (a layer's input and what its checkpoint
+    # keeps stacked for the reverse pass, the gradients stacked and summed
+    # over the sequences) is phase ``scan``; a layer's operations have
+    # their own
     with jax.named_scope(phases.SCAN):
         if cfg.num_dense_layers:
             x, _ = jax.lax.scan(dense_step, x, params["dense"])

@@ -1,7 +1,7 @@
 """What the decoders of this repo share (models/sdar.py, models/afmoe.py):
 the float32 norm and RoPE, the initialiser, the plan that keeps a layer's
-input alone for the reverse pass, and the head over the vocabulary slice a
-chunk at a time.
+input and its attention's output for the reverse pass, and the head over the
+vocabulary slice a chunk at a time.
 """
 
 from __future__ import annotations
@@ -10,6 +10,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from cgnn_tpu.ops.masked_attention import KEPT
 
 
 def rms_norm(x, scale, eps: float):
@@ -48,18 +50,22 @@ def init_params(shapes: dict, rng, *, std: float, out_std: float,
     return jax.tree_util.tree_unflatten(tree, leaves)
 
 
-def by_sequence(layer, x, segment_ids, policy=None):
+def by_sequence(layer, x, segment_ids, keep=()):
     """One layer over the step's sequences one at a time, a
-    ``jax.checkpoint`` a layer and sequence: a layer's input is all the
-    reverse pass keeps, and what it rebuilds (a sequence's projections, the
-    rows routed to the experts held) is one sequence's at a time.
-    ``layer(x [1, N, H], segment_ids [1, ..]) -> (x [1, N, H], *aux)``; ->
-    ``(x [S, N, H], *aux stacked over the sequences)``. ``policy``: what
-    the checkpoint keeps beside the layer's input (``jax.checkpoint``'s).
+    ``jax.checkpoint`` a layer and sequence: the reverse pass keeps a
+    layer's input and what the attention's reverse kernels read of its
+    forward one (``ops/masked_attention.py`` ``KEPT``: the output and the
+    log-sum-exp, so the forward kernel runs once), and what it rebuilds (a
+    sequence's projections, the rows routed to the experts held) is one
+    sequence's at a time. ``layer(x [1, N, H], segment_ids [1, ..]) -> (x
+    [1, N, H], *aux)``; -> ``(x [S, N, H], *aux stacked over the
+    sequences)``. ``keep``: the names (``checkpoint_name``) of what else of
+    its own a model has the checkpoint keep.
 
     A scan over the sequences, and it has to stay one: under ``vmap`` the
     expert layer's ``lax.switch`` becomes a select that runs every rung on
     every sequence."""
+    policy = jax.checkpoint_policies.save_only_these_names(KEPT, *keep)
 
     @functools.partial(jax.checkpoint, prevent_cse=False, policy=policy)
     def one(row):

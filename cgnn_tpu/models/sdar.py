@@ -10,7 +10,8 @@ A layer, on the residual stream ``x [S, 2L, H]``:
 
 The layers' weights are stacked on a leading axis and the stack is scanned;
 within a layer the step's sequences go one at a time, a ``jax.checkpoint`` a
-layer and sequence (``by_sequence``; the norm, RoPE and the chunked head are
+layer and sequence that keeps the layer's input and its attention's output
+(``by_sequence``; the norm, RoPE and the chunked head are
 ``models/lm_blocks.py``'s too). Weights are float32 and are cast to the
 compute dtype inside the layer; norms, RoPE, the router and the loss are
 float32.

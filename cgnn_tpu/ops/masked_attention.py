@@ -9,6 +9,11 @@ On the TPU the op is the splash-attention kernel of
 ``jax.experimental.pallas`` given that mask: it visits the live tiles only
 (``mask_tiles``) and never writes a ``[heads, N, N]`` score array. Elsewhere
 it is the same arithmetic in plain ``jnp``, a block of queries at a time.
+
+What the reverse pass needs of the forward one is named ``KEPT``: the
+kernel's output and its log-sum-exp, the plain path's output. A
+``jax.checkpoint`` whose policy keeps that name (``lm_blocks.by_sequence``)
+rebuilds neither: the reverse kernels read what the forward one wrote.
 """
 
 from __future__ import annotations
@@ -19,7 +24,10 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
+# the name of what the reverse pass reads of the forward one
+KEPT = "attn.kept"
 # one tile of the kernel's grid, queries x keys (forward and backward); the
 # counters of ``mask_tiles`` are in these tiles whatever runs the op
 TILE_Q = 512
@@ -84,6 +92,12 @@ def mask_tiles(mask: StaticMask) -> tuple[int, int]:
     return int(live.sum()), int(live.size)
 
 
+def kept_bytes(heads: int, n: int, head_dim: int, dtype) -> int:
+    """Bytes of ``KEPT`` a sequence: the kernel's output in ``dtype`` and
+    its float32 log-sum-exp, ``heads`` query heads over ``n`` positions."""
+    return heads * n * (head_dim * jnp.dtype(dtype).itemsize + 4)
+
+
 def _blocked(q, k, v, segment_ids, mask: np.ndarray):
     """Plain ``jnp``: ``q [S, Hkv, G, N, D]``, ``k, v [S, Hkv, N, D]``."""
     n = q.shape[-2]
@@ -106,7 +120,7 @@ def _blocked(q, k, v, segment_ids, mask: np.ndarray):
 
     out = jax.lax.map(rows, jnp.arange(0, n, tq))  # [n/tq, S, Hkv, G, tq, D]
     out = jnp.moveaxis(out, 0, 3)
-    return out.reshape(q.shape)
+    return checkpoint_name(out.reshape(q.shape), KEPT)
 
 
 @functools.lru_cache(maxsize=8)
@@ -127,7 +141,8 @@ def _splash_kernel(mask: StaticMask, group: int):
     # would be that trace's tracers, and the kernel outlives it here
     with jax.ensure_compile_time_eval():
         return splash.make_splash_mqa_single_device(
-            mask_lib.MultiHeadMask([one] * group), block_sizes=sizes)
+            mask_lib.MultiHeadMask([one] * group), block_sizes=sizes,
+            residual_checkpoint_name=KEPT)
 
 
 def _splash(q, k, v, segment_ids, mask: StaticMask):

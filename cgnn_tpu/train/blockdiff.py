@@ -122,7 +122,8 @@ def run(args, telemetry, preempt=None, log_fn=print) -> int:
             "expert_bias_abs_max") if causal
            else ("bd_tiles_live", "bd_tiles_grid", "masked_tokens"))
     for name in ("moe_rows_here", "moe_rows_balanced", "moe_rows_capacity",
-                 "moe_calls_full_rung", "expert_load_max_over_mean", *own):
+                 "moe_calls_full_rung", "expert_load_max_over_mean",
+                 "attn_kept_bytes", *own):
         telemetry.set_gauge(name, float(last["train"].get(name, 0.0)))
     log_fn(f"** best val loss {result['best']:.4f}; a step routed "
            f"{last['train']['moe_rows_here']:.0f} rows to the experts held "

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from cgnn_tpu.data.tokens import TokenBatch
 from cgnn_tpu.observe import phases
 from cgnn_tpu.ops import moe
+from cgnn_tpu.ops.masked_attention import kept_bytes
 from cgnn_tpu.train.state import TrainState
 
 
@@ -123,7 +124,9 @@ def step_metrics(cfg, batch: TokenBatch, loss_sum, routed, tiles,
     By ``cfg.objective``: a ``blockdiff`` step counts its masked tokens and
     the mask's tiles (``tiles`` = (live, grid) a head and a sequence, every
     layer alike); a ``causal`` one its weighted tokens and the tiles of each
-    kind of layer (``tiles`` = {kind: (live, grid, layers)})."""
+    kind of layer (``tiles`` = {kind: (live, grid, layers)}). Either counts
+    the bytes its layers' checkpoints keep of the attention
+    (``lm_blocks.by_sequence``), from the shapes."""
     with jax.named_scope(phases.LM_HEAD):
         s, n = batch.tokens.shape
         causal = cfg.objective == "causal"
@@ -138,6 +141,9 @@ def step_metrics(cfg, batch: TokenBatch, loss_sum, routed, tiles,
         if _balance_coeff(cfg) is not None:
             metrics["expert_bias_abs_max_sum"] = jnp.abs(
                 stats["router_bias"]).max()
+        metrics["attn_kept_bytes_sum"] = jnp.float32(
+            cfg.num_hidden_layers * s * kept_bytes(
+                cfg.num_attention_heads, n, cfg.head_dim, cfg.compute_dtype))
         heads = cfg.num_attention_heads * s
         if tiles is not None and causal:
             for kind, (live, grid, layers) in tiles.items():

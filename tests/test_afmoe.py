@@ -24,14 +24,14 @@ if ROOT not in sys.path:
 from benchmark.kinds import bd_train  # noqa: E402
 from benchmark.reference import afmoe_ref as ref  # noqa: E402
 from cgnn_tpu.data import invariants, tokens  # noqa: E402
-from cgnn_tpu.models import afmoe  # noqa: E402
+from cgnn_tpu.models import afmoe, lm_blocks  # noqa: E402
 from cgnn_tpu.ops import moe  # noqa: E402
 from cgnn_tpu.ops.masked_attention import (  # noqa: E402
     StaticMask, mask_tiles, masked_attention,
 )
 from cgnn_tpu.train import Normalizer, make_optimizer  # noqa: E402
 from cgnn_tpu.train.lm_step import (  # noqa: E402
-    balanced_biases, make_lm_train_step,
+    balanced_biases, make_lm_train_step, step_metrics,
 )
 from cgnn_tpu.train.state import TrainState  # noqa: E402
 
@@ -479,6 +479,108 @@ def test_bfloat16_compute_stays_near_float32(followed):
         assert float(m["loss_sum"]) / 2 == pytest.approx(w, rel=0.05)
         got.append(np.asarray(state.batch_stats["router_bias"]))
     assert ref.bias_diff_share(got, want["bias"], 0.001) < 0.25
+
+
+# ---- what a layer's checkpoint keeps ----------------------------------
+
+@pytest.mark.parametrize("dtype,rounding", [("float32", 1e-5),
+                                            ("bfloat16", 0.0)])
+def test_keeping_the_attention_s_output_changes_no_number(monkeypatch,
+                                                          dtype, rounding):
+    """One training step with the attention's output kept for the reverse
+    pass and with a ``by_sequence`` that keeps it under no name (the expert
+    layers' routed output is kept either way): the loss and the biases are
+    equal, and every leaf's gradient is, in float32 to the rounding of the
+    CPU's compiler (tests/test_sdar.py has why)."""
+    cfg = dataclasses.replace(CFG, dtype=dtype)
+    batch = tokens.split_batches(_pool(0), 2)[0]
+    state = _state(_params(0), _bias(0), cfg)
+
+    def step():
+        new, m = jax.jit(make_lm_train_step(cfg))(state, batch)
+        return (float(m["loss_sum"]),
+                np.asarray(new.batch_stats["router_bias"]),
+                bd_train.first_gradient(new.opt_state, ADAMW["b1"]))
+
+    loss, bias, grad = step()
+    monkeypatch.setattr(lm_blocks, "KEPT", "nothing.by.this.name")
+    loss_alone, bias_alone, grad_alone = step()
+    assert loss == loss_alone
+    np.testing.assert_array_equal(bias, bias_alone)
+    flat = jax.tree_util.tree_leaves_with_path(grad)
+    assert len(flat) == 3 + 13 + 2 * 16
+    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(grad_alone)):
+        assert np.abs(w).max() > 0, path
+        np.testing.assert_allclose(g, w, rtol=0,
+                                   atol=rounding * np.abs(w).max(),
+                                   err_msg=str(path))
+
+
+@pytest.mark.parametrize("layer", ["dense", "expert"])
+def test_the_layer_s_checkpoint_keeps_the_attention_s_output(monkeypatch,
+                                                             capsys, layer):
+    """What the reverse pass of a layer under ``by_sequence`` keeps beside
+    the layer's arguments, every sequence's stacked by the scan over them:
+    the attention's named output and, of an expert layer as
+    ``hidden_states`` calls it, the routed experts' output still."""
+    params, bias = _params(0), _bias(0)
+    batch = tokens.split_batches(_pool(0), 2)[0]
+    x = params["embed"][batch.tokens]
+    if layer == "dense":
+        p0 = jax.tree_util.tree_map(lambda a: a[0], params["dense"])
+        keep = ()
+
+        def one(p, x_seq, seg):
+            return afmoe._dense_layer(CFG, S, x_seq, p, seg)
+    else:
+        p0 = jax.tree_util.tree_map(lambda a: a[0, 0],
+                                    params["periods"]["run1"])
+        keep = (afmoe.ROUTED,)
+
+        def one(p, x_seq, seg):
+            return afmoe._expert_layer(CFG, F, x_seq, p, bias[0, 2], seg)
+
+    def kept():
+        jax.ad_checkpoint.print_saved_residuals(
+            lambda x, p: lm_blocks.by_sequence(
+                functools.partial(one, p), x, batch.segment_ids,
+                keep=keep)[0].sum(), x, p0)
+        return sorted(ln.split()[0] for ln in capsys.readouterr().out
+                      .splitlines() if "output of scan" in ln
+                      and not ln.startswith("i32"))  # the documents
+
+    # [S, 1, Hkv, G, L, D], the op's output before it is reshaped; the
+    # routed rows [S, L, H]
+    attn, routed = f"f32[2,1,2,2,{L},16]", f"f32[2,{L},64]"
+    assert kept() == ([attn] if layer == "dense" else [attn, routed])
+    monkeypatch.setattr(lm_blocks, "KEPT", "nothing.by.this.name")
+    assert kept() == ([] if layer == "dense" else [routed])
+
+
+def test_hidden_states_has_the_expert_layers_keep_the_routed_output(
+        monkeypatch):
+    """The names ``hidden_states`` hands ``by_sequence``: none of a dense
+    layer's own, ``ROUTED`` of an expert layer's, each run of the period."""
+    seen = []
+
+    def recorded(layer, x, segment_ids, keep=()):
+        seen.append(keep)
+        return lm_blocks.by_sequence(layer, x, segment_ids, keep)
+
+    monkeypatch.setattr(afmoe, "by_sequence", recorded)
+    batch = tokens.split_batches(_pool(0), 2)[0]
+    jax.eval_shape(functools.partial(afmoe.hidden_states, CFG), _params(0),
+                   _bias(0), batch.tokens, batch.segment_ids)
+    assert seen == [(), (afmoe.ROUTED,), (afmoe.ROUTED,)]
+
+
+def test_what_a_step_counts_of_the_kept_bytes():
+    batch = tokens.split_batches(_pool(0), 2)[0]
+    m = step_metrics(CFG, batch, jnp.float32(0.0), (
+        jnp.ones((6, 16), jnp.int32), jnp.zeros((6, 2), jnp.int32)), None,
+        {"router_bias": _bias(0)})
+    # 7 layers x 2 sequences x 4 heads x L x (16 float32 + a float32)
+    assert float(m["attn_kept_bytes_sum"]) == 7 * 2 * 4 * L * (16 * 4 + 4)
 
 
 def test_parameter_count_and_the_stack():

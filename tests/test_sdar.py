@@ -23,11 +23,14 @@ if ROOT not in sys.path:
 from benchmark.kinds import bd_train  # noqa: E402
 from benchmark.reference import sdar_ref as ref  # noqa: E402
 from cgnn_tpu.data import invariants, tokens  # noqa: E402
-from cgnn_tpu.models import sdar  # noqa: E402
+from cgnn_tpu.models import lm_blocks, sdar  # noqa: E402
 from cgnn_tpu.ops import moe  # noqa: E402
 from cgnn_tpu.ops.bd_attention import bd_attention, bd_mask, bd_tiles  # noqa: E402
+from cgnn_tpu.ops.masked_attention import kept_bytes  # noqa: E402
 from cgnn_tpu.train import Normalizer, make_optimizer  # noqa: E402
-from cgnn_tpu.train.lm_step import make_lm_train_step  # noqa: E402
+from cgnn_tpu.train.lm_step import (  # noqa: E402
+    make_lm_train_step, step_metrics,
+)
 from cgnn_tpu.train.state import TrainState  # noqa: E402
 
 L, BLOCK = 32, 4
@@ -541,6 +544,83 @@ def test_bfloat16_compute_stays_near_float32():
     assert rungs.shape == (2, 2)  # a layer and sequence each
     np.testing.assert_allclose(a, b, rtol=0.05)
     assert int(sizes.sum()) == 2 * (2 * 2 * L) * 4
+
+
+# ---- what the layer's checkpoint keeps --------------------------------
+
+@pytest.mark.parametrize("dtype,rounding", [("float32", 1e-5),
+                                            ("bfloat16", 0.0)])
+def test_keeping_the_attention_s_output_changes_no_number(monkeypatch,
+                                                          dtype, rounding):
+    """One training step with the attention's output kept for the reverse
+    pass and with a ``by_sequence`` that keeps the layer's input alone (no
+    value carries the name it keeps): the same operations on the same
+    values. The loss is equal, and in bfloat16, the cells' precision, so is
+    every leaf's gradient; in float32 to the rounding of the CPU's compiler
+    (7e-7 of a leaf's largest here), which fuses the forward pass it
+    rebuilds in its own way: run op by op the leaves are equal there too
+    (60 s a step)."""
+    cfg = dataclasses.replace(CFG, dtype=dtype)
+    batch = tokens.split_batches(_pool(0), 2)[0]
+    state = dataclasses.replace(
+        _state(_params(0)), apply_fn=functools.partial(sdar.apply, cfg))
+
+    def step():
+        new, m = jax.jit(make_lm_train_step(cfg))(state, batch)
+        return float(m["loss_sum"]), bd_train.first_gradient(
+            new.opt_state, ADAMW["b1"])
+
+    loss, grad = step()
+    monkeypatch.setattr(lm_blocks, "KEPT", "nothing.by.this.name")
+    loss_alone, grad_alone = step()
+    assert loss == loss_alone
+    flat = jax.tree_util.tree_leaves_with_path(grad)
+    assert len(flat) == 14
+    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(grad_alone)):
+        assert np.abs(w).max() > 0, path
+        np.testing.assert_allclose(g, w, rtol=0,
+                                   atol=rounding * np.abs(w).max(),
+                                   err_msg=str(path))
+
+
+def test_the_layer_s_checkpoint_keeps_the_attention_s_output(monkeypatch,
+                                                             capsys):
+    """What the reverse pass of a layer under ``by_sequence`` keeps beside
+    the layer's arguments: the attention's named output, every sequence's
+    stacked by the scan over them; under another name, nothing."""
+    params = _params(0)
+    batch = tokens.split_batches(_pool(0), 2)[0]
+    p0 = jax.tree_util.tree_map(lambda a: a[0], params["layers"])
+    x = params["embed"][batch.tokens]
+
+    def kept():
+        jax.ad_checkpoint.print_saved_residuals(
+            lambda x, p: lm_blocks.by_sequence(
+                lambda x_seq, seg: sdar._layer(CFG, x_seq, p, seg), x,
+                batch.segment_ids)[0].sum(), x, p0)
+        return sorted(ln.split()[0] for ln in capsys.readouterr().out
+                      .splitlines() if "output of scan" in ln
+                      and not ln.startswith("i32"))  # the documents
+
+    # [S, 1, Hkv, G, 2L, D]: the op's output before it is reshaped
+    assert kept() == [f"f32[2,1,2,2,{2 * L},16]"]
+    monkeypatch.setattr(lm_blocks, "KEPT", "nothing.by.this.name")
+    assert kept() == []
+
+
+@pytest.mark.parametrize("dtype,width", [("float32", 4), ("bfloat16", 2)])
+def test_what_a_step_counts_of_the_kept_bytes(dtype, width):
+    """``attn_kept_bytes``: layers x sequences x (the output in the compute
+    dtype + the float32 log-sum-exp), over the doubled positions."""
+    batch = tokens.split_batches(_pool(0), 2)[0]
+    m = step_metrics(dataclasses.replace(CFG, dtype=dtype), batch,
+                     jnp.float32(0.0), (jnp.ones((2, 16), jnp.int32),
+                                        jnp.zeros((2, 2), jnp.int32)), None)
+    assert float(m["attn_kept_bytes_sum"]) == (
+        2 * 2 * 4 * (2 * L) * (16 * width + 4))
+    assert float(m["attn_kept_bytes_count"]) == 1.0
+    # the cell's shapes: 0.55 GB a step
+    assert 4 * 2 * kept_bytes(32, 8192, 128, jnp.bfloat16) == 545_259_520
 
 
 def test_parameter_count_and_init():

@@ -619,18 +619,27 @@ def test_four_chip_scan_program_at_real_size(four_chips, no_compile_cache):
     assert not batch_sized, batch_sized
 
 
+def _kernel_calls(text: str, kernel: str) -> int:
+    """The compiled text's calls of the Pallas kernels named ``kernel*``."""
+    return len(re.findall(rf"%{kernel}[\w.]* = [^\n]*custom-call\(", text))
+
+
 def test_sdar_scan_program_at_real_size_fits_the_chip(one_chip,
                                                       no_compile_cache):
     """``sdar.train``'s window program (a chunk of two steps of the
     block-diffusion decoder at the cell's real size: 4 layers, 456.3 M
     parameters, 2 sequences of 4,096 tokens a step) compiles for the
     described chip with both kernels in it, under 15 GB by the compile's
-    memory analysis (the state's 5.48 GB aliased in place, ~7.6 GB of
-    temporaries of which 1.83 GB are the gradients), and never holds a
-    ``[*, 8192, 8192]`` score array. At 6 layers the same analysis read
-    16.97 GB (PERF.md section 4, PR 42). The expert layer's switch over its
-    rungs is there, forward and reverse, and its compact rungs hold no
-    array of all ``T x k`` rows (PR 44)."""
+    memory analysis (the state's 5.48 GB aliased in place, ~8.3 GB of
+    temporaries of which 1.83 GB are the gradients and 0.55 what the
+    layers' checkpoints keep of the attention: its output and log-sum-exp a
+    layer and sequence, ``lm_blocks.by_sequence``; 13.79 GB in all, 13.09
+    when a layer's input was all that was kept, PR 46), and never holds a
+    ``[*, 8192, 8192]`` score array. The attention's forward kernel is
+    there once: the one the reverse pass would rebuild is dead code. At 6
+    layers the same analysis read 16.97 GB (PERF.md section 4, PR 42). The
+    expert layer's switch over its rungs is there, forward and reverse, and
+    its compact rungs hold no array of all ``T x k`` rows (PR 44)."""
     import dataclasses
     import json
     import os
@@ -692,6 +701,12 @@ def test_sdar_scan_program_at_real_size_fits_the_chip(one_chip,
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 8  # splash and megablox
     assert not re.search(r"\[(?:\d+,)*8192,8192\]", text)
+    splash = {k: _kernel_calls(text, f"splash_mqa_{k}")
+              for k in ("fwd", "dq", "dkv")}
+    print(f"splash kernels: {splash}")
+    # one call site (the layers are scanned): the parent of PR 46, whose
+    # checkpoint kept the layer's input alone, read fwd 2, dq 1, dkv 1
+    assert splash == {"fwd": 1, "dq": 1, "dkv": 1}, splash
     # the expert layer's switch (ops/moe.py): one conditional forward and
     # one in the reverse pass (the rematerialised forward's is dead code),
     # a branch a rung; only the last rung's branch holds an array of all
@@ -741,11 +756,14 @@ def test_trinity_scan_program_at_real_size_fits_the_chip(one_chip,
     expert layers, 504.1 M parameters, 2 sequences of 8,192 tokens a step)
     compiles for the described chip with its kernels in it (splash under two
     masks, megablox) and leaves at least 0.5 GB of the chip's 15.75 by the
-    compile's memory analysis, the state's 6.05 GB aliased in place. It
-    never holds a ``[*, 8192, 8192]`` score array, and the expert layer's
-    switch over its rungs is there once a kind of layer (the period's runs
-    are scanned), forward and reverse: the rematerialised forward's is dead
-    code, because the layer's checkpoint keeps the routed output."""
+    compile's memory analysis, the state's 6.05 GB aliased in place (15.19
+    GB in all; 14.03 before the layers' checkpoints kept the attention's
+    output and log-sum-exp, 0.68 GB a step, PR 46). It never holds a ``[*,
+    8192, 8192]`` score array. The attention's forward kernel and the
+    expert layer's switch over its rungs are there once a call site (the
+    dense stack and the period's two runs are scanned; the switch forward
+    and reverse): the rematerialised forward's are dead code, because the
+    layer's checkpoint keeps the attention's and the routed output."""
     import dataclasses
     import json
     import os
@@ -809,6 +827,12 @@ def test_trinity_scan_program_at_real_size_fits_the_chip(one_chip,
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 8  # splash and megablox
     assert not re.search(r"\[(?:\d+,)*8192,8192\]", text)
+    splash = {k: _kernel_calls(text, f"splash_mqa_{k}")
+              for k in ("fwd", "dq", "dkv")}
+    print(f"splash kernels: {splash}")
+    # three call sites (the dense stack, the window run, the full run): the
+    # parent of PR 46 read fwd 6, dq 3, dkv 3
+    assert splash == {"fwd": 3, "dq": 3, "dkv": 3}, splash
     pairs = length * mc.num_experts_per_tok
     assert moe.ladder(pairs, 8, 128) == (8192, 16384, 32768, 65536)
     from cgnn_tpu.observe import phases
