@@ -53,7 +53,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from cgnn_tpu.models import lm_blocks
 from cgnn_tpu.models.lm_blocks import (
-    by_sequence, chunked_loss_sums, rms_norm, rope,
+    by_sequence, chunked_loss_sums, prepare_heads, rms_norm,
 )
 from cgnn_tpu.observe import phases
 from cgnn_tpu.ops import moe
@@ -208,18 +208,17 @@ def _attention(cfg: AfmoeConfig, kind: str, x, p, segment_ids):
     eps = cfg.rms_norm_eps
     with jax.named_scope(phases.ATTN_PROJ):
         hn = rms_norm(x, p["attn_norm"], eps).astype(dt)
-        q = (hn @ p["wq"].astype(dt)).reshape(s, n, hq, d)
-        k = (hn @ p["wk"].astype(dt)).reshape(s, n, hkv, d)
-        v = (hn @ p["wv"].astype(dt)).reshape(s, n, hkv, d)
+        # the full layers take no positions (NoPE)
+        positions = (jnp.arange(n, dtype=jnp.int32) if kind == SLIDING
+                     else None)
+        q = prepare_heads(hn @ p["wq"].astype(dt), p["q_norm"], positions,
+                          theta=cfg.rope_theta, eps=eps,
+                          scale=1.0 / math.sqrt(d))
+        k = prepare_heads(hn @ p["wk"].astype(dt), p["k_norm"], positions,
+                          theta=cfg.rope_theta, eps=eps)
+        v = jnp.swapaxes((hn @ p["wv"].astype(dt)).reshape(s, n, hkv, d),
+                         1, 2)
         gate = hn @ p["wg"].astype(dt)
-        q = rms_norm(q, p["q_norm"], eps)
-        k = rms_norm(k, p["k_norm"], eps)
-        if kind == SLIDING:  # the full layers take no positions (NoPE)
-            positions = jnp.arange(n, dtype=jnp.int32)
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
-        q = q * (1.0 / math.sqrt(d))
-        q, k, v = (jnp.swapaxes(t.astype(dt), 1, 2) for t in (q, k, v))
     with jax.named_scope(phases.ATTN_WINDOW if kind == SLIDING
                          else phases.ATTN_FULL):
         a = masked_attention(q, k, v, segment_ids, _mask(cfg, kind, n),

@@ -1,6 +1,7 @@
 """What the decoders of this repo share (models/sdar.py, models/afmoe.py):
-the float32 norm and RoPE, the initialiser, the plan that keeps a layer's
-input and its attention's output for the reverse pass, and the head over the
+the float32 norm and RoPE, the one op that makes a projection's output the
+attention's operand, the initialiser, the plan that keeps a layer's input
+and its attention's output for the reverse pass, and the head over the
 vocabulary slice a chunk at a time.
 """
 
@@ -11,6 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from cgnn_tpu.ops import prepare_heads as fused
 from cgnn_tpu.ops.masked_attention import KEPT
 
 
@@ -24,12 +26,39 @@ def rms_norm(x, scale, eps: float):
 def rope(x, positions, theta: float):
     """``x [S, N, heads, D]`` float32, rotate-half as Qwen's."""
     d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = positions.astype(jnp.float32)[:, None] * inv[None, :]  # [N, D/2]
-    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)[None, :, None]
-    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[None, :, None]
+    cos, sin = fused.rope_tables(positions, d, theta)  # [N, D/2]
+    cos = jnp.concatenate([cos, cos], -1)[None, :, None]
+    sin = jnp.concatenate([sin, sin], -1)[None, :, None]
     x1, x2 = x[..., : d // 2], x[..., d // 2:]
     return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+
+
+def heads_fused(n: int, head_dim: int) -> bool:
+    """Whether ``prepare_heads`` takes the kernel on ``n`` positions of
+    ``head_dim``: on the TPU, at the shapes the kernel takes."""
+    return jax.default_backend() == "tpu" and fused.supported(n, head_dim)
+
+
+def prepare_heads(x, norm_scale, positions, *, theta: float, eps: float,
+                  scale: float = 1.0):
+    """A projection's output -> the attention's operand: ``x [S, N, heads *
+    D]`` in the compute dtype, ``norm_scale [D]``, ``positions [N]`` int32
+    or None (a layer that takes no positions) -> ``rope(rms_norm(x)) scale``
+    as ``[S, heads, N, D]`` in the compute dtype. float32 arithmetic; the
+    array is rounded where the matmul left it and where the attention takes
+    it. One pass on the chip (``ops/prepare_heads.py``) where
+    ``heads_fused`` says so, the backend and the shape deciding as in
+    ``masked_attention``'s ``auto``; else the composition below, which is
+    the kernel's reference."""
+    s, n, width = x.shape
+    d = norm_scale.shape[-1]
+    if heads_fused(n, d):
+        return fused.prepare_heads(x, norm_scale, positions, theta, eps,
+                                   scale)
+    y = rms_norm(x.reshape(s, n, width // d, d), norm_scale, eps)
+    if positions is not None:
+        y = rope(y, positions, theta)
+    return jnp.swapaxes((y * scale).astype(x.dtype), 1, 2)
 
 
 def init_params(shapes: dict, rng, *, std: float, out_std: float,

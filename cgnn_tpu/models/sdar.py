@@ -30,7 +30,7 @@ import jax.numpy as jnp
 
 from cgnn_tpu.models import lm_blocks
 from cgnn_tpu.models.lm_blocks import (
-    by_sequence, chunked_loss_sums, rms_norm, rope,
+    by_sequence, chunked_loss_sums, prepare_heads, rms_norm,
 )
 from cgnn_tpu.observe import phases
 from cgnn_tpu.ops import moe
@@ -102,15 +102,14 @@ def _layer(cfg: SdarConfig, x, p, segment_ids):
                   cfg.head_dim)
     with jax.named_scope(phases.ATTN_PROJ):
         hn = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps).astype(dt)
-        q = (hn @ p["wq"].astype(dt)).reshape(s, n, hq, d)
-        k = (hn @ p["wk"].astype(dt)).reshape(s, n, hkv, d)
-        v = (hn @ p["wv"].astype(dt)).reshape(s, n, hkv, d)
         positions = jnp.arange(n, dtype=jnp.int32) % (n // 2)
-        q = rope(rms_norm(q, p["q_norm"], cfg.rms_norm_eps), positions,
-                 cfg.rope_theta) * (1.0 / math.sqrt(d))
-        k = rope(rms_norm(k, p["k_norm"], cfg.rms_norm_eps), positions,
-                 cfg.rope_theta)
-        q, k, v = (jnp.swapaxes(t.astype(dt), 1, 2) for t in (q, k, v))
+        q = prepare_heads(hn @ p["wq"].astype(dt), p["q_norm"], positions,
+                          theta=cfg.rope_theta, eps=cfg.rms_norm_eps,
+                          scale=1.0 / math.sqrt(d))
+        k = prepare_heads(hn @ p["wk"].astype(dt), p["k_norm"], positions,
+                          theta=cfg.rope_theta, eps=cfg.rms_norm_eps)
+        v = jnp.swapaxes((hn @ p["wv"].astype(dt)).reshape(s, n, hkv, d),
+                         1, 2)
     with jax.named_scope(phases.ATTN_BD):
         a = bd_attention(q, k, v, segment_ids, block=cfg.block_length,
                          impl=cfg.attn_impl)

@@ -7,7 +7,8 @@ stack — flax module scopes (``conv_1/bn1``), transforms (``jvp``,
 ``transpose``) and the ``jax.named_scope`` s the program adds where flax says
 nothing (models/cgcnn.py, models/forcefield.py, train/step.py,
 train/force_step.py, resilience/guard.py, data/compact.py, train/loop.py,
-models/sdar.py, models/afmoe.py, ops/moe.py, train/lm_step.py). ``classify`` maps such a path to one phase
+models/sdar.py, models/afmoe.py, ops/moe.py, ops/prepare_heads.py,
+train/lm_step.py). ``classify`` maps such a path to one phase
 of ``PHASES`` and a direction; ``phase_table`` does so for every instruction
 of an optimized HLO module that the device can report as an event.
 

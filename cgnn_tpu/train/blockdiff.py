@@ -123,7 +123,8 @@ def run(args, telemetry, preempt=None, log_fn=print) -> int:
            else ("bd_tiles_live", "bd_tiles_grid", "masked_tokens"))
     for name in ("moe_rows_here", "moe_rows_balanced", "moe_rows_capacity",
                  "moe_calls_full_rung", "expert_load_max_over_mean",
-                 "attn_kept_bytes", *own):
+                 "attn_kept_bytes", "heads_prepared", "heads_prepared_fused",
+                 *own):
         telemetry.set_gauge(name, float(last["train"].get(name, 0.0)))
     log_fn(f"** best val loss {result['best']:.4f}; a step routed "
            f"{last['train']['moe_rows_here']:.0f} rows to the experts held "

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from cgnn_tpu.data.tokens import TokenBatch
+from cgnn_tpu.models import lm_blocks
 from cgnn_tpu.observe import phases
 from cgnn_tpu.ops import moe
 from cgnn_tpu.ops.masked_attention import kept_bytes
@@ -126,7 +127,9 @@ def step_metrics(cfg, batch: TokenBatch, loss_sum, routed, tiles,
     layer alike); a ``causal`` one its weighted tokens and the tiles of each
     kind of layer (``tiles`` = {kind: (live, grid, layers)}). Either counts
     the bytes its layers' checkpoints keep of the attention
-    (``lm_blocks.by_sequence``), from the shapes."""
+    (``lm_blocks.by_sequence``) and the (sequence, layer, operand) calls of
+    ``lm_blocks.prepare_heads``, all and those that took the kernel, from
+    the shapes."""
     with jax.named_scope(phases.LM_HEAD):
         s, n = batch.tokens.shape
         causal = cfg.objective == "causal"
@@ -144,6 +147,10 @@ def step_metrics(cfg, batch: TokenBatch, loss_sum, routed, tiles,
         metrics["attn_kept_bytes_sum"] = jnp.float32(
             cfg.num_hidden_layers * s * kept_bytes(
                 cfg.num_attention_heads, n, cfg.head_dim, cfg.compute_dtype))
+        prepared = 2 * cfg.num_hidden_layers * s  # q and k
+        metrics["heads_prepared_sum"] = jnp.float32(prepared)
+        metrics["heads_prepared_fused_sum"] = jnp.float32(
+            prepared * lm_blocks.heads_fused(n, cfg.head_dim))
         heads = cfg.num_attention_heads * s
         if tiles is not None and causal:
             for kind, (live, grid, layers) in tiles.items():

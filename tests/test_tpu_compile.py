@@ -624,8 +624,50 @@ def _kernel_calls(text: str, kernel: str) -> int:
     return len(re.findall(rf"%{kernel}[\w.]* = [^\n]*custom-call\(", text))
 
 
+def _take_the_head_kernels(monkeypatch):
+    """``lm_blocks.prepare_heads`` asks the default backend, which is the
+    CPU here: the described chip's answer is the shape's alone."""
+    from cgnn_tpu.models import lm_blocks
+    from cgnn_tpu.ops import prepare_heads
+
+    monkeypatch.setattr(lm_blocks, "heads_fused", prepare_heads.supported)
+
+
+def _check_the_prepared_heads(text: str, sites: int, n: int, heads: tuple,
+                              d: int):
+    """``lm_blocks.prepare_heads`` in a decoder's window program of
+    ``sites`` call sites (PR 47): the forward kernel on q and on k a site in
+    the forward pass and again where the layer's checkpoint rebuilds them,
+    the reverse kernel once each, all booked ``attn.proj``; and under
+    ``attn.proj`` nothing the device runs as an operation of its own writes
+    a float32 ``[1, n, heads, d]`` (the norm's and RoPE's passes of before
+    wrote three a site and direction). What is still float32 there at that
+    size is on the attention's other side: the reverse pass's cotangent of
+    the attention's output, ``f32[1, heads, n, d]`` beside its bfloat16."""
+    import collections
+
+    from cgnn_tpu.observe import phases
+
+    table = phases.phase_table(text)
+    booked = {k: collections.Counter(
+        table[name] for name in table
+        if name.startswith(f"prepare_heads_{k}")) for k in ("fwd", "bwd")}
+    print(f"prepare_heads kernels: {booked}")
+    assert booked["fwd"] == {(phases.ATTN_PROJ, phases.FWD): 2 * sites,
+                             (phases.ATTN_PROJ, phases.BWD): 2 * sites}
+    assert booked["bwd"] == {(phases.ATTN_PROJ, phases.BWD): 2 * sites}
+    comps = phases._parse(text)
+    wide = re.compile("|".join(rf"f32\[1,{n},{h},{d}\]" for h in heads))
+    written = [rest[:200] for comp in comps.values()
+               for name, rest in comp["instrs"].items()
+               if table.get(name, ("",))[0] == phases.ATTN_PROJ
+               and wide.search(_result_type(rest))]
+    assert not written, written
+
+
 def test_sdar_scan_program_at_real_size_fits_the_chip(one_chip,
-                                                      no_compile_cache):
+                                                      no_compile_cache,
+                                                      monkeypatch):
     """``sdar.train``'s window program (a chunk of two steps of the
     block-diffusion decoder at the cell's real size: 4 layers, 456.3 M
     parameters, 2 sequences of 4,096 tokens a step) compiles for the
@@ -639,7 +681,9 @@ def test_sdar_scan_program_at_real_size_fits_the_chip(one_chip,
     there once: the one the reverse pass would rebuild is dead code. At 6
     layers the same analysis read 16.97 GB (PERF.md section 4, PR 42). The
     expert layer's switch over its rungs is there, forward and reverse, and
-    its compact rungs hold no array of all ``T x k`` rows (PR 44)."""
+    its compact rungs hold no array of all ``T x k`` rows (PR 44). q and k
+    reach the attention through ``lm_blocks.prepare_heads``'s kernels (PR
+    47: ``_check_the_prepared_heads``)."""
     import dataclasses
     import json
     import os
@@ -659,6 +703,7 @@ def test_sdar_scan_program_at_real_size_fits_the_chip(one_chip,
     # the described chip is not the default backend: name the kernels
     mc = dataclasses.replace(bd_train.model_config(cfg), attn_impl="splash",
                              moe_impl="megablox")
+    _take_the_head_kernels(monkeypatch)
     tr, length = cfg["train"], int(cfg["data"]["sequence_length"])
     tx = make_optimizer(optim="adamw", lr=tr["lr"], b1=tr["b1"], b2=tr["b2"],
                         weight_decay=tr["weight_decay"], lr_milestones=[])
@@ -707,6 +752,8 @@ def test_sdar_scan_program_at_real_size_fits_the_chip(one_chip,
     # one call site (the layers are scanned): the parent of PR 46, whose
     # checkpoint kept the layer's input alone, read fwd 2, dq 1, dkv 1
     assert splash == {"fwd": 1, "dq": 1, "dkv": 1}, splash
+    _check_the_prepared_heads(text, 1, 2 * length, (
+        mc.num_attention_heads, mc.num_key_value_heads), mc.head_dim)
     # the expert layer's switch (ops/moe.py): one conditional forward and
     # one in the reverse pass (the rematerialised forward's is dead code),
     # a branch a rung; only the last rung's branch holds an array of all
@@ -750,7 +797,8 @@ def test_sdar_scan_program_at_real_size_fits_the_chip(one_chip,
 
 
 def test_trinity_scan_program_at_real_size_fits_the_chip(one_chip,
-                                                         no_compile_cache):
+                                                         no_compile_cache,
+                                                         monkeypatch):
     """``trinity.train``'s window program (a chunk of two steps of the
     window-and-full-attention decoder at the cell's real size: 1 dense + 4
     expert layers, 504.1 M parameters, 2 sequences of 8,192 tokens a step)
@@ -763,7 +811,10 @@ def test_trinity_scan_program_at_real_size_fits_the_chip(one_chip,
     expert layer's switch over its rungs are there once a call site (the
     dense stack and the period's two runs are scanned; the switch forward
     and reverse): the rematerialised forward's are dead code, because the
-    layer's checkpoint keeps the attention's and the routed output."""
+    layer's checkpoint keeps the attention's and the routed output. q and k
+    reach the attention through ``lm_blocks.prepare_heads``'s kernels, with
+    positions in the window layers and without in the full one (PR 47:
+    ``_check_the_prepared_heads``)."""
     import dataclasses
     import json
     import os
@@ -784,6 +835,7 @@ def test_trinity_scan_program_at_real_size_fits_the_chip(one_chip,
     # the described chip is not the default backend: name the kernels
     mc = dataclasses.replace(lm_train.model_config(cfg), attn_impl="splash",
                              moe_impl="megablox")
+    _take_the_head_kernels(monkeypatch)
     tr, length = cfg["train"], int(cfg["data"]["sequence_length"])
     tx = make_optimizer(optim="adamw", lr=tr["lr"], b1=tr["b1"], b2=tr["b2"],
                         weight_decay=tr["weight_decay"], lr_milestones=[])
@@ -833,6 +885,8 @@ def test_trinity_scan_program_at_real_size_fits_the_chip(one_chip,
     # three call sites (the dense stack, the window run, the full run): the
     # parent of PR 46 read fwd 6, dq 3, dkv 3
     assert splash == {"fwd": 3, "dq": 3, "dkv": 3}, splash
+    _check_the_prepared_heads(text, 3, length, (
+        mc.num_attention_heads, mc.num_key_value_heads), mc.head_dim)
     pairs = length * mc.num_experts_per_tok
     assert moe.ladder(pairs, 8, 128) == (8192, 16384, 32768, 65536)
     from cgnn_tpu.observe import phases
