@@ -13,7 +13,7 @@ Layout:
     cgnn_tpu.ops       — gathers with declared transposes, segment ops,
                          masked BatchNorm, the in-program neighbor search.
     cgnn_tpu.parallel  — device mesh, data-parallel training over ICI
-                         (shard_map + psum), edge-sharded message passing.
+                         (shard_map + psum).
     cgnn_tpu.train     — training runtime: train state, normalizer,
                          checkpointing (orbax), metrics, loops.
 """

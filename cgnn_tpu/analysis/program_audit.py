@@ -7,7 +7,7 @@ f64 creep doubling HBM traffic, stray host callbacks serializing the
 device stream, and near-duplicate programs compiled per rung from a
 leaked Python scalar. This module lowers the repo's REAL entry
 programs — the train step (plain, guard-wrapped, telemetry-tapped,
-dense, DP/edge-sharded where the backend allows), the serving/predict
+dense, data-parallel where the backend allows), the serving/predict
 program for every (rung, staging form) in the warm shape ladder, and
 the compact expander — via ``jax.jit(...).lower()`` on abstract args
 (no device dispatch), then statically audits the StableHLO/compiled
@@ -230,7 +230,7 @@ def build_entry_programs(config: AuditConfig | None = None,
     """-> (programs, meta): the repo's real entry programs, lowered.
 
     Known backend gaps become ``skip`` records (listed in the ledger
-    meta, never silently absent): the DP/edge-sharded steps and the
+    meta, never silently absent): the data-parallel step and the
     mesh predict programs need >= 2 devices. Everything else
     must lower — an unexpected failure is a GA-LOWER finding, not a
     skip."""
@@ -336,7 +336,7 @@ def build_entry_programs(config: AuditConfig | None = None,
     add("train/dense", jit_train_step(make_train_step()),
         (state_dense_av, abstract_avals(dense_batch)), donated=n_leaves)
 
-    # -- train step: DP / edge-sharded (where the backend allows) --
+    # -- train step: data-parallel (where the backend allows) --
     shard_gap = None
     if len(jax.devices()) < 2:
         shard_gap = (f"needs >= 2 devices, have {len(jax.devices())} "
@@ -346,10 +346,6 @@ def build_entry_programs(config: AuditConfig | None = None,
             make_parallel_train_step,
             stack_batches,
         )
-        from cgnn_tpu.parallel.edge_parallel import (
-            make_edge_parallel_train_step,
-            pad_edges_divisible,
-        )
         from cgnn_tpu.parallel.mesh import make_mesh
 
         n_dev = len(jax.devices())
@@ -357,23 +353,8 @@ def build_entry_programs(config: AuditConfig | None = None,
         stacked_av = abstract_avals(stack_batches([coo_batch] * n_dev))
         add("train/dp", make_parallel_train_step(mesh).jitted,
             (state_coo_av, stacked_av), donated=n_leaves)
-
-        from jax.sharding import Mesh
-
-        gmesh = Mesh(np.array(jax.devices()), ("graph",))
-        model_gp = CrystalGraphConvNet(
-            atom_fea_len=cfg.atom_fea_len, n_conv=cfg.n_conv,
-            h_fea_len=cfg.h_fea_len, edge_axis_name="graph",
-        )
-        state_gp_av = abstract_avals(
-            state_coo.replace(apply_fn=model_gp.apply)
-        )
-        edge_av = abstract_avals(pad_edges_divisible(coo_batch, n_dev))
-        add("train/edge", make_edge_parallel_train_step(gmesh),
-            (state_gp_av, edge_av), donated=n_leaves)
     else:
         add_skip("train/dp", shard_gap)
-        add_skip("train/edge", shard_gap)
 
     # -- predict: every (rung, staging form) in the warm ladder — the
     # forms dimension now includes 'raw' (ISSUE 11: the in-program

@@ -62,12 +62,7 @@ class ModelConfig:
             f"layout={'dense' if self.dense_m else 'coo'}"
         )
 
-    def build(self, head=None, edge_axis_name: str | None = None):
-        """``edge_axis_name`` activates edge-sharded graph parallelism
-        (psum over that mesh axis inside every conv). It is a runtime
-        parallelism choice, not model identity — deliberately NOT part of
-        ``to_meta()``, so checkpoints restore as plain single-device models
-        with identical parameters."""
+    def build(self, head=None):
         from cgnn_tpu.models import CrystalGraphConvNet
 
         if head is None and self.multi_task_head and not self.classification:
@@ -90,7 +85,6 @@ class ModelConfig:
             dropout_rate=self.dropout,
             dtype=jnp.bfloat16 if self.dtype == "bfloat16" else jnp.float32,
             head=head,
-            edge_axis_name=edge_axis_name,
             dense_m=self.dense_m or None,
             node_norm=self.node_norm,
             pool_softplus=self.pool_softplus,
@@ -98,8 +92,7 @@ class ModelConfig:
 
 
 def build_model(model_cfg: "ModelConfig", data_cfg: "DataConfig",
-                task: str = "regression",
-                edge_axis_name: str | None = None, log_fn=None):
+                task: str = "regression", log_fn=None):
     """Build the model for a task; the force task needs the edge featurization
     hyperparameters in-model (distances are recomputed differentiably from
     positions — models/forcefield.py). ``log_fn`` receives the one line
@@ -107,10 +100,6 @@ def build_model(model_cfg: "ModelConfig", data_cfg: "DataConfig",
     if log_fn is not None:
         log_fn(model_cfg.impl_summary())
     if task == "force":
-        if edge_axis_name is not None:
-            raise NotImplementedError(
-                "graph sharding is not supported for the force task"
-            )
         from cgnn_tpu.models.forcefield import ForceFieldCGCNN
 
         if data_cfg.var is not None:
@@ -128,7 +117,7 @@ def build_model(model_cfg: "ModelConfig", data_cfg: "DataConfig",
             dtype=jnp.bfloat16 if model_cfg.dtype == "bfloat16" else jnp.float32,
             dense_m=model_cfg.dense_m or None,
         )
-    return model_cfg.build(edge_axis_name=edge_axis_name)
+    return model_cfg.build()
 
 
 @dataclasses.dataclass

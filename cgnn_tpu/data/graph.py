@@ -109,7 +109,7 @@ class GraphBatch(struct.PyTreeNode):
     # transpose of the neighbor gather (dense layout only, else None):
     # row j lists the edge slots e with neighbors[e] == j, so the gather's
     # backward becomes gather(ct, in_slots) + masked sum — a dense reduce —
-    # instead of an XLA scatter-add (ops/segment.py gather_transpose)
+    # instead of an XLA scatter-add (ops/segment.py gather_slot_major)
     in_slots: Any = None  # [Ncap, In] i32 edge-slot indices
     in_mask: Any = None  # [Ncap, In] u8 (1 = real incoming edge)
     # two-tier transpose overflow (pack_graphs over_cap): when in_slots is
@@ -290,7 +290,7 @@ def overflow_run_cap(graphs: Sequence[CrystalGraph], dense_m: int) -> int:
 
 def overflow_rows(batch) -> int:
     """Real entries in ``batch``'s overflow list (its prefix), read off the
-    run counts; over every leading stack axis (chips, shards)."""
+    run counts; over every leading stack axis (steps, chips)."""
     runs = np.asarray(batch.over_runs)
     return int((runs * np.arange(1, runs.shape[-1] + 1)).sum())
 
@@ -317,7 +317,6 @@ def pack_graphs(
     in_cap: int | None = None,
     over_cap: int | None = None,
     edge_dtype=np.float32,
-    transpose_shards: int = 1,
     run_cap: int | None = None,
 ) -> GraphBatch:
     """Concatenate graphs into one fixed-capacity GraphBatch (numpy).
@@ -336,7 +335,7 @@ def pack_graphs(
     ``in_cap`` (dense layout only) additionally fills ``in_slots``/
     ``in_mask`` — the transpose of the neighbor gather, sized for a maximum
     per-node in-degree of ``in_cap`` (see ``in_degree_cap``) — making the
-    gather's *backward* scatter-free too (ops/segment.py gather_transpose).
+    gather's *backward* scatter-free too (ops/segment.py gather_slot_major).
 
     ``over_cap`` selects the TWO-TIER transpose instead (exclusive with
     ``in_cap``): tier 1 is ``in_slots`` at width ``dense_m`` (each node's
@@ -350,14 +349,6 @@ def pack_graphs(
     see ``overflow_run_cap``; ``None`` sizes it from this batch alone)
     counts the runs by length. A run longer than ``run_cap`` raises
     ``TransposeRunError``.
-
-    ``transpose_shards > 1`` (two-tier only) builds the PER-SHARD stacked
-    mappings for node-strip graph sharding directly
-    (``shard_transpose_slots``) instead of the flat global mapping —
-    avoiding a pack-then-rebuild on the host critical path. A per-shard
-    overflow exceeding ``over_cap`` raises exactly like the global build
-    (a shard's overflow is never larger than the batch's would-be global
-    overflow, so this is at most as strict).
     """
     if not graphs:
         raise ValueError("cannot pack an empty graph list")
@@ -559,21 +550,10 @@ def pack_graphs(
         if dense_m is None:
             raise ValueError("transpose slots require the dense layout "
                              "(dense_m)")
-        if transpose_shards > 1:
-            if over_cap is None:
-                raise ValueError(
-                    "transpose_shards requires the two-tier layout "
-                    "(over_cap; in_cap single-tier mappings cannot shard)"
-                )
-            mapping = shard_transpose_slots(
-                neighbors, edge_mask > 0, node_cap, dense_m,
-                transpose_shards, over_cap, run_cap,
-            )
-        else:
-            mapping = transpose_slots(
-                neighbors, edge_mask > 0, node_cap, dense_m, in_cap,
-                over_cap, run_cap,
-            )
+        mapping = transpose_slots(
+            neighbors, edge_mask > 0, node_cap, dense_m, in_cap,
+            over_cap, run_cap,
+        )
 
     return GraphBatch(
         nodes=nodes,
@@ -681,75 +661,6 @@ def transpose_slots(
     return in_slots, in_mask, over_slots, over_nodes, over_last, over_runs
 
 
-def shard_transpose_slots(
-    neighbors: np.ndarray,
-    edge_real: np.ndarray,
-    node_cap: int,
-    dense_m: int,
-    n_shards: int,
-    over_cap: int,
-    run_cap: int | None = None,
-) -> tuple:
-    """Per-shard two-tier transpose mappings for node-strip graph sharding.
-
-    Under dense-layout graph parallelism (parallel/edge_parallel.py), shard
-    ``s`` owns the contiguous node strip ``[s*N/D, (s+1)*N/D)`` and — by the
-    dense layout's slot-ownership rule — exactly that strip's edge slots.
-    The scatter-free backward then needs, PER SHARD, the edge slots in that
-    shard grouped by neighbor node (over ALL nodes: a strip's edges point
-    anywhere): each shard transposes its own [E/D, F] cotangent into a
-    partial [N, F] node gradient, and the shard_map machinery sums the
-    partials (the transpose of the replicated-nodes cast).
-
-    Tier-1 width stays ``dense_m`` and the overflow capacities stay the
-    batch-global ``over_cap`` and ``run_cap`` (``None``: the longest run of
-    any shard): an edge's within-neighbor rank restricted to
-    one shard never exceeds its global rank, so every (tier, overflow, run)
-    bound that held for the unsharded mapping holds per shard — sharding
-    introduces NO new overflow failure mode, and the per-shard shapes are
-    static functions of (node_cap, dense_m, n_shards) only.
-
-    Returns stacked arrays with a leading shard axis, slot indices LOCAL to
-    each shard's edge range: ``in_slots [D, node_cap*dense_m]``,
-    ``in_mask [D, node_cap, dense_m]``, ``over_slots/over_nodes
-    [D, over_cap]``, ``over_last [D, node_cap]``, ``over_runs [D, run_cap]``.
-    """
-    # the REAL precondition: shard boundaries must fall on whole node
-    # rows. Checking only edge-capacity divisibility let configs with
-    # dense_m % n_shards == 0 but node_cap % n_shards != 0 through (e.g.
-    # node_cap=6, dense_m=8, n_shards=4), cutting strips mid node-row and
-    # surfacing much later as an opaque shard_map/device_put error
-    # (ADVICE r5). node_cap divisibility implies edge divisibility for
-    # the dense layout (e_cap = node_cap * dense_m).
-    if node_cap % n_shards:
-        raise ValueError(
-            f"node_cap {node_cap} not divisible by {n_shards} shards "
-            f"(node-strip sharding owns whole node rows; round node_cap "
-            f"up to a multiple of the shard count)"
-        )
-    e_cap = len(neighbors)
-    if e_cap % n_shards:
-        raise ValueError(
-            f"edge capacity {e_cap} not divisible by {n_shards} shards "
-            f"(expected node_cap * dense_m with node_cap a multiple of "
-            f"the shard count)"
-        )
-    e_s = e_cap // n_shards
-    parts = [
-        transpose_slots(
-            neighbors[s * e_s : (s + 1) * e_s],
-            edge_real[s * e_s : (s + 1) * e_s],
-            node_cap, dense_m, None, over_cap, run_cap,
-        )
-        for s in range(n_shards)
-    ]
-    if run_cap is None:  # one length for the stack: the longest shard's
-        run_cap = max(len(p[5]) for p in parts)
-        parts = [p[:5] + (np.pad(p[5], (0, run_cap - len(p[5]))),)
-                 for p in parts]
-    return tuple(np.stack(field) for field in zip(*parts))
-
-
 def pad_batch(
     graphs: Sequence[CrystalGraph],
     graph_cap: int,
@@ -773,7 +684,6 @@ def capacities_for(
     headroom: float = 1.15,
     dense_m: int | None = None,
     snug: bool = False,
-    node_multiple: int = 1,
 ) -> tuple[int, int]:
     """Pick one (node_cap, edge_cap) for a dataset so every shuffled batch
     fits: batch_size * max-per-graph sizes would be safe but wasteful; use
@@ -792,20 +702,7 @@ def capacities_for(
     >=0.97.
 
     With ``dense_m`` the edge capacity is exactly ``node_cap * dense_m``
-    (the dense slot layout, pack_graphs).
-
-    ``node_multiple`` rounds the node capacity up to a multiple (node-strip
-    graph sharding needs ``node_cap`` divisible by the shard count so every
-    shard owns a whole strip; parallel/edge_parallel.py)."""
-    if node_multiple > 1:
-        def _round_caps(nc, ec):
-            nc2 = -(-nc // node_multiple) * node_multiple
-            if dense_m is not None:
-                return nc2, nc2 * dense_m
-            return nc2, ec
-        nc, ec = capacities_for(graphs, batch_size, headroom,
-                                dense_m=dense_m, snug=snug)
-        return _round_caps(nc, ec)
+    (the dense slot layout, pack_graphs)."""
     nodes = np.array([g.num_nodes for g in graphs])
     if snug:
         # balance capacity to the BATCH COUNT: with B = ceil(n/batch_size)
@@ -935,8 +832,6 @@ def bucketed_batch_iterator(
     per_bucket_in_cap: bool = False,
     edge_dtype=np.float32,
     pack_fn=None,
-    node_multiple: int = 1,
-    transpose_shards: int = 1,
 ):
     """Yield batches using per-size-class static capacities.
 
@@ -987,15 +882,14 @@ def bucketed_batch_iterator(
             continue
         sub = [graphs[int(i)] for i in idxs]
         nc, ec = capacities_for(sub, batch_size, headroom, dense_m=dense_m,
-                                snug=snug, node_multiple=node_multiple)
+                                snug=snug)
         b_in_cap = in_cap
         if dense_m is not None and b_in_cap is None and per_bucket_in_cap:
             b_in_cap = in_degree_cap(sub)
         it = batch_iterator(sub, batch_size, nc, ec, shuffle=shuffle, rng=rng,
                             dense_m=dense_m, in_cap=b_in_cap, snug=snug,
                             over_cap=over_cap, run_cap=run_cap,
-                            edge_dtype=edge_dtype, pack_fn=pack_fn,
-                            transpose_shards=transpose_shards)
+                            edge_dtype=edge_dtype, pack_fn=pack_fn)
         iters.append(stats.wrap(it) if stats is not None else it)
         weights.append(float(len(idxs)))
     active = list(range(len(iters)))
@@ -1094,7 +988,6 @@ def _pack_overflow_safe(
     over_cap,
     edge_dtype,
     pack_fn=None,
-    transpose_shards: int = 1,
     run_cap=None,
 ):
     """pack_graphs, splitting the batch on a two-tier over_cap overrun.
@@ -1109,10 +1002,7 @@ def _pack_overflow_safe(
     packed).
     """
     pack = pack_fn or pack_graphs
-    kw = {"transpose_shards": transpose_shards} if transpose_shards > 1 \
-        else {}
-    if over_cap is not None:
-        kw["run_cap"] = run_cap
+    kw = {} if over_cap is None else {"run_cap": run_cap}
     try:
         yield pack(bucket, node_cap, edge_cap, graph_cap,
                    dense_m=dense_m, in_cap=in_cap, over_cap=over_cap,
@@ -1130,8 +1020,7 @@ def _pack_overflow_safe(
         for half in (bucket[:mid], bucket[mid:]):
             yield from _pack_overflow_safe(
                 half, node_cap, edge_cap, graph_cap, dense_m, in_cap,
-                over_cap, edge_dtype, pack_fn=pack_fn,
-                transpose_shards=transpose_shards, run_cap=run_cap)
+                over_cap, edge_dtype, pack_fn=pack_fn, run_cap=run_cap)
 
 
 def batch_iterator(
@@ -1148,7 +1037,6 @@ def batch_iterator(
     over_cap: int | None = None,
     edge_dtype=np.float32,
     pack_fn=None,
-    transpose_shards: int = 1,
     run_cap: int | None = None,
 ):
     """Yield fixed-shape GraphBatches of ``batch_size`` graphs each.
@@ -1202,8 +1090,7 @@ def batch_iterator(
         ):
             for packed in _pack_overflow_safe(
                     bucket, node_cap, edge_cap, graph_cap, dense_m, in_cap,
-                    over_cap, edge_dtype, pack_fn=pack_fn,
-                    transpose_shards=transpose_shards, run_cap=run_cap):
+                    over_cap, edge_dtype, pack_fn=pack_fn, run_cap=run_cap):
                 yield invariants.maybe_check(packed, dense_m)
             bucket, nn, ne = [], 0, 0
         bucket.append(g)
@@ -1217,6 +1104,5 @@ def batch_iterator(
     if bucket and (not drop_last or len(bucket) >= batch_size):
         for packed in _pack_overflow_safe(
                 bucket, node_cap, edge_cap, graph_cap, dense_m, in_cap,
-                over_cap, edge_dtype, pack_fn=pack_fn,
-                transpose_shards=transpose_shards, run_cap=run_cap):
+                over_cap, edge_dtype, pack_fn=pack_fn, run_cap=run_cap):
             yield invariants.maybe_check(packed, dense_m)

@@ -2,9 +2,9 @@
 
 The jitted step trusts several non-local data-plane invariants that are
 established at pack time and never re-checked (``indices_are_sorted`` is an
-UNCHECKED promise to XLA's TPU scatter; ``gather_transpose``'s custom VJP is
-only correct when the transpose mapping is complete). A corrupted batch —
-a bug in a new iterator, a bad cache file, a miswired shard — would train
+UNCHECKED promise to XLA's TPU scatter; ``gather_slot_major``'s declared
+transpose is only correct when the transpose mapping is complete). A
+corrupted batch — a bug in a new iterator, a bad cache file — would train
 silently wrong. This module is the loud path: ``--check-invariants``
 (train.py) enables validation of every packed batch at iterator exit;
 ``check_batch`` can also be called directly (tests, debugging).
@@ -54,7 +54,7 @@ def check_batch(batch, dense_m: int | None = None):
       inferred from pre-shaped [N, M, G] edges when not given);
     - transpose slots: ``in_slots``/``in_mask`` list every real edge slot
       exactly once under its neighbor node — the completeness property
-      gather_transpose's scatter-free backward silently relies on — with
+      gather_slot_major's scatter-free backward silently relies on — with
       each row's real entries first (ops/segment.gather_slot_major masks
       tier 1 by rank < in-degree), and the overflow list's run structure:
       real entries a node-sorted prefix, ``over_last`` the end of each
@@ -122,103 +122,67 @@ def check_batch(batch, dense_m: int | None = None):
 
 
 def _check_transpose_mapping(batch, neighbors, real_e, ncap):
-    """The gather_transpose completeness property (flat ``neighbors`` [E]
-    and ``real_e`` [E] bool) — shared by GraphBatch and CompactBatch.
-
-    Per-shard stacked mappings (``in_mask`` [D, N, tier] from
-    shard_transpose_slots, node-strip graph sharding) are validated by
-    converting each shard's LOCAL slot indices back to global ids — each
-    shard must list exactly its own slot range's real edges, and the union
-    must satisfy the same completeness property as the flat mapping."""
-    def collect(in_slots, in_mask, over, ncap, slot_range, offset, tag):
-        """One mapping's (listed global slot ids, neighbor rows) — the
-        SHARED collector for the flat mapping (offset 0, full slot range)
-        and each shard of a per-shard stack (local range + shard offset),
-        so the completeness contract cannot diverge between the two."""
-        if in_mask.shape[0] != ncap:
-            _fail(f"{tag}in_slots/in_mask row count != node capacity")
-        if np.any(np.diff(in_mask.astype(np.int8), axis=1) > 0):
-            _fail(f"{tag}in_mask rows are not in-degree prefixes (the "
-                  f"slot-major transpose masks by rank < in-degree)")
-        lst = in_slots.reshape(in_mask.shape)[in_mask > 0]
-        if lst.size and (lst.min() < 0 or lst.max() >= slot_range):
-            _fail(f"{tag}transpose mapping lists a slot outside its "
-                  f"range [0, {slot_range})")
-        parts = [lst + offset]
-        rows = [np.repeat(np.arange(ncap), (in_mask > 0).sum(axis=1))]
-        if over is not None:
-            osl, ond, last, runs = over
-            chex.assert_shape(ond, osl.shape)
-            chex.assert_shape(last, (ncap,))
-            if np.any(np.diff(ond) < 0):
-                _fail(f"{tag}over_nodes is not non-decreasing (a node's "
-                      f"overflow entries must be one run)")
-            # the run structure the backward's run sum and pointer gather
-            # rely on: the real entries are a prefix of k, node j's run is
-            # the runs[j] entries ending at last[j] and names j throughout,
-            # a node without a run points out of range (reads a zero row),
-            # and no run is longer than the capacity over_runs is sized to
-            owner = last < len(osl)
-            ends = last[owner]
-            if np.any(np.diff(ends) <= 0) or (ends.size and ends[0] < 0):
-                _fail(f"{tag}over_last is not increasing over the nodes "
-                      f"that own a run")
-            if np.any(last[~owner] != len(osl)):
-                _fail(f"{tag}a node without overflow must point at "
-                      f"over_cap (the out-of-range zero row)")
-            run_len = np.diff(ends, prepend=-1)
-            k = int(ends[-1]) + 1 if ends.size else 0
-            if not np.array_equal(ond[:k],
-                                  np.repeat(np.nonzero(owner)[0], run_len)):
-                _fail(f"{tag}over_last does not end each node's run of "
-                      f"over_nodes")
-            if run_len.size and run_len.max() > len(runs):
-                _fail(f"{tag}a run of {run_len.max()} overflow entries "
-                      f"exceeds the run capacity {len(runs)}")
-            if not np.array_equal(
-                    runs, np.bincount(run_len - 1, minlength=len(runs))):
-                _fail(f"{tag}over_runs does not count the runs by length")
-            if k and (osl[:k].min() < 0 or osl[:k].max() >= slot_range):
-                _fail(f"{tag}overflow lists a slot outside its range")
-            parts.append(osl[:k] + offset)
-            rows.append(ond[:k])
-        return parts, rows
-
+    """The transposable gather's completeness property (flat ``neighbors``
+    [E] and ``real_e`` [E] bool; ops/segment.py gather_slot_major) — shared
+    by GraphBatch and CompactBatch."""
+    in_slots = np.asarray(batch.in_slots)
     in_mask = np.asarray(batch.in_mask)
-    over_all = (
-        None if batch.over_slots is None
-        else (np.asarray(batch.over_slots), np.asarray(batch.over_nodes),
-              np.asarray(batch.over_last), np.asarray(batch.over_runs))
-    )
-    if in_mask.ndim == 3:
-        n_sh = in_mask.shape[0]
-        if len(real_e) % n_sh:
-            _fail("sharded transpose mapping: edge capacity not divisible "
-                  "by the shard count")
-        e_s = len(real_e) // n_sh
-        in_slots = np.asarray(batch.in_slots).reshape(n_sh, -1)
-        listed_parts, row_parts = [], []
-        for s in range(n_sh):
-            parts, rows_s = collect(
-                in_slots[s], in_mask[s],
-                None if over_all is None else tuple(x[s] for x in over_all),
-                ncap, e_s, s * e_s, f"shard {s} ",
-            )
-            listed_parts += parts
-            row_parts += rows_s
-        listed = np.concatenate(listed_parts)
-        rows = np.concatenate(row_parts)
-    else:
-        parts, rows_p = collect(
-            np.asarray(batch.in_slots), in_mask, over_all, ncap,
-            len(real_e), 0, "",
-        )
-        listed = np.concatenate(parts)
-        rows = np.concatenate(rows_p)
+    n_slots = len(real_e)
+    if in_mask.shape[0] != ncap:
+        _fail("in_slots/in_mask row count != node capacity")
+    if np.any(np.diff(in_mask.astype(np.int8), axis=1) > 0):
+        _fail("in_mask rows are not in-degree prefixes (the "
+              "slot-major transpose masks by rank < in-degree)")
+    lst = in_slots.reshape(in_mask.shape)[in_mask > 0]
+    if lst.size and (lst.min() < 0 or lst.max() >= n_slots):
+        _fail(f"transpose mapping lists a slot outside its "
+              f"range [0, {n_slots})")
+    parts = [lst]
+    rows = [np.repeat(np.arange(ncap), (in_mask > 0).sum(axis=1))]
+    if batch.over_slots is not None:
+        osl, ond, last, runs = (
+            np.asarray(batch.over_slots), np.asarray(batch.over_nodes),
+            np.asarray(batch.over_last), np.asarray(batch.over_runs))
+        chex.assert_shape(ond, osl.shape)
+        chex.assert_shape(last, (ncap,))
+        if np.any(np.diff(ond) < 0):
+            _fail("over_nodes is not non-decreasing (a node's "
+                  "overflow entries must be one run)")
+        # the run structure the backward's run sum and pointer gather
+        # rely on: the real entries are a prefix of k, node j's run is
+        # the runs[j] entries ending at last[j] and names j throughout,
+        # a node without a run points out of range (reads a zero row),
+        # and no run is longer than the capacity over_runs is sized to
+        owner = last < len(osl)
+        ends = last[owner]
+        if np.any(np.diff(ends) <= 0) or (ends.size and ends[0] < 0):
+            _fail("over_last is not increasing over the nodes "
+                  "that own a run")
+        if np.any(last[~owner] != len(osl)):
+            _fail("a node without overflow must point at "
+                  "over_cap (the out-of-range zero row)")
+        run_len = np.diff(ends, prepend=-1)
+        k = int(ends[-1]) + 1 if ends.size else 0
+        if not np.array_equal(ond[:k],
+                              np.repeat(np.nonzero(owner)[0], run_len)):
+            _fail("over_last does not end each node's run of "
+                  "over_nodes")
+        if run_len.size and run_len.max() > len(runs):
+            _fail(f"a run of {run_len.max()} overflow entries "
+                  f"exceeds the run capacity {len(runs)}")
+        if not np.array_equal(
+                runs, np.bincount(run_len - 1, minlength=len(runs))):
+            _fail("over_runs does not count the runs by length")
+        if k and (osl[:k].min() < 0 or osl[:k].max() >= n_slots):
+            _fail("overflow lists a slot outside its range")
+        parts.append(osl[:k])
+        rows.append(ond[:k])
+    listed = np.concatenate(parts)
+    rows = np.concatenate(rows)
     if listed.size != int(real_e.sum()):
         _fail(
             f"transpose mapping lists {listed.size} edges but the batch "
-            f"has {int(real_e.sum())} real edges (gather_transpose "
+            f"has {int(real_e.sum())} real edges (gather_slot_major "
             f"backward would drop/duplicate gradient)"
         )
     if listed.size:

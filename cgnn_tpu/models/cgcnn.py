@@ -28,12 +28,9 @@ TPU-first design choices:
   row gather a node. No direction of the conv holds a scatter. The only
   per-edge matmul is the edge term e @ K_e. This is the body every
   benchmark cell runs;
-- the same body over node strips when the graph is sharded over a mesh
-  axis (``edge_axis_name``), one psum a conv (every shard projects all the
-  nodes and gathers its strip's rows through the flat gather_transpose);
 - a flat COO body (gather + segment-sum over sorted centres) for batches
-  packed without ``dense_m``: the edge-sharded step runs it, and the tests
-  hold the dense body to it;
+  packed without ``dense_m``: the reference the tests hold the dense body
+  to (``--layout coo``);
 - masked BatchNorm / pooling so static-shape padding never leaks into
   statistics (SURVEY.md §7 hard parts #1, #3);
 - optional bfloat16 compute for the MXU, float32 params and statistics.
@@ -54,7 +51,6 @@ from cgnn_tpu.ops.segment import (
     aggregate_edge_messages,
     gather,
     gather_slot_major,
-    gather_transpose,
     segment_mean,
 )
 
@@ -134,11 +130,6 @@ class CGConv(nn.Module):
     # the Open Catalyst baseline's: no batch statistic, nothing to mask but
     # the padded rows' output) or 'none' (the force model)
     node_norm: str = "batch"
-    # edge-sharded graph parallelism (SURVEY.md §5 "long-context analog"):
-    # when the edge axis is sharded over this mesh axis, per-node partial
-    # aggregates are psum-ed back to full sums and edge-BN moments span all
-    # shards. Only valid inside shard_map with the axis bound.
-    edge_axis_name: str | None = None
     # dense slot layout (pack_graphs dense_m): node n owns edge slots
     # [n*M, (n+1)*M). Aggregation becomes a plain sum over M — no scatter
     # in the forward, and its transpose is a broadcast — and the per-edge
@@ -158,7 +149,7 @@ class CGConv(nn.Module):
         node_mask: jax.Array,  # [N]
         train: bool = False,
         in_slots: jax.Array | None = None,  # [N*In] i32 flat transpose of
-        #   neighbors (pack_graphs stores it flat; gather_transpose wants
+        #   neighbors (pack_graphs stores it flat; gather_slot_major wants
         #   flat indices — the on-device 2-D->1-D reshape costs a relayout)
         in_mask: jax.Array | None = None,  # [N, In]
         over_slots: jax.Array | None = None,  # [O] two-tier overflow
@@ -167,85 +158,7 @@ class CGConv(nn.Module):
         over_runs: jax.Array | None = None,  # [K] (its length: run cap)
     ) -> jax.Array:
         f = self.features
-        if self.dense_m is not None and self.edge_axis_name is not None:
-            # Node-strip sharded dense layout (graph parallelism composed
-            # with the fast path; parallel/edge_parallel.py). Shard s owns
-            # the contiguous node strip [s*N/D, (s+1)*N/D) and — by dense
-            # slot ownership — exactly its [N/D, M] edge slots, so the
-            # per-node message sum is COMPLETE shard-locally (no psum for
-            # aggregation, unlike the COO edge-sharded branch). The one
-            # per-conv collective is the psum of the zero-padded strip
-            # aggregates back to full [N, F] (its transpose distributes the
-            # next conv's cotangent). BN1 moments span shards via
-            # axis_name; BN2 + the residual run on the replicated full
-            # aggregate, bit-identical to the unsharded dense path.
-            axis = self.edge_axis_name
-            m = self.dense_m
-            n_full = nodes.shape[0]
-            with jax.named_scope(phases.CONV_FC_FULL):
-                e = edges.astype(nodes.dtype)
-                if e.ndim == 2:
-                    e = e.reshape(-1, m, e.shape[-1])
-            n_strip = e.shape[0]
-            idx = jax.lax.axis_index(axis)
-            # linear_call (gather_transpose) does not insert the implicit
-            # replicated->varying cast standard ops get, so cast explicitly:
-            # the cast's transpose is the psum that completes each shard's
-            # partial [N, F] node cotangent
-            nodes_v = jax.lax.pcast(nodes, axis, to="varying")
-            if in_slots is not None and in_slots.shape[0] != 1:
-                # per-shard two-tier mappings arrive with a leading
-                # singleton from the shard-stack axis (graph.py
-                # shard_transpose_slots), squeezed below to this shard's
-                # mapping. A non-singleton means the mapping was built for a
-                # different shard count than this mesh — [0] would then
-                # silently drop cotangents, so refuse at trace time.
-                raise ValueError(
-                    f"per-shard transpose mapping was built for "
-                    f"{in_slots.shape[0]}x this mesh's graph-shard "
-                    f"count (pack with transpose_shards == the mesh's "
-                    f"'graph' axis size)"
-                )
-            over = [None if a is None else a[0]
-                    for a in (over_slots, over_nodes, over_last, over_runs)]
-
-            def gather_rows(p):  # [N, 2F], projected -> [N/D, M, 2F]
-                with jax.named_scope(phases.CONV_GATHER):
-                    if in_slots is not None:
-                        rows = gather_transpose(
-                            p, neighbors, in_slots[0], in_mask[0], *over)
-                    else:  # eval batches carry no transpose mapping
-                        rows = gather(p, neighbors)
-                    return rows.reshape(n_strip, m, p.shape[-1])
-
-            with jax.named_scope(phases.CONV_GATHER):
-                nodes_strip = jax.lax.dynamic_slice_in_dim(
-                    nodes, idx * n_strip, n_strip
-                )
-            z = _SplitFcFull(2 * f, dtype=self.dtype, name="fc_full")(
-                nodes_strip, nodes_v, e, gather_rows
-            )
-            emask = edge_mask.reshape(n_strip, m)
-            if self.use_batchnorm:
-                z = MaskedBatchNorm(
-                    dtype=self.dtype, name="bn1", axis_name=axis
-                )(z, mask=emask, use_running_average=not train)
-            with jax.named_scope(phases.CONV_GATE):
-                gate, core = jnp.split(z, 2, axis=-1)
-                msg = nn.sigmoid(gate) * nn.softplus(core)
-                # zero cotangent on padding slots — load-bearing for the
-                # scatter-free backward exactly as in the unsharded branch
-                msg = msg * emask[..., None].astype(msg.dtype)
-            with jax.named_scope(phases.CONV_AGGREGATE):
-                agg_strip = msg.sum(axis=1)  # [N/D, F], complete per node
-                agg = jax.lax.psum(
-                    jax.lax.dynamic_update_slice_in_dim(
-                        jnp.zeros((n_full, f), agg_strip.dtype), agg_strip,
-                        idx * n_strip, axis=0,
-                    ),
-                    axis,
-                )
-        elif self.dense_m is not None:
+        if self.dense_m is not None:
             m = self.dense_m
             n = nodes.shape[0]
 
@@ -286,11 +199,11 @@ class CGConv(nn.Module):
                 gate, core = jnp.split(z, 2, axis=-1)
                 msg = nn.sigmoid(gate) * nn.softplus(core)
                 # LOAD-BEARING for gradients, not just values:
-                # gather_transpose's scatter-free VJP assumes zero
+                # gather_slot_major's scatter-free VJP assumes zero
                 # cotangent on padding edge slots, which THIS mask
                 # (together with masked BN statistics) guarantees.
                 # Removing it would silently corrupt node gradients
-                # (ops/segment.py gather_transpose docstring; parity
+                # (ops/segment.py gather_slot_major docstring; parity
                 # test: tests/test_batching.py two-tier backward).
                 msg = msg * edge_mask.reshape(n, m, 1).astype(msg.dtype)
             with jax.named_scope(phases.CONV_AGGREGATE):
@@ -303,18 +216,15 @@ class CGConv(nn.Module):
                     [v_i, v_j, edges.astype(nodes.dtype)], axis=-1)
             z = nn.Dense(2 * f, dtype=self.dtype, name="fc_full")(z)
             if self.use_batchnorm:
-                z = MaskedBatchNorm(
-                    dtype=self.dtype, name="bn1", axis_name=self.edge_axis_name
-                )(z, mask=edge_mask, use_running_average=not train)
+                z = MaskedBatchNorm(dtype=self.dtype, name="bn1")(
+                    z, mask=edge_mask, use_running_average=not train
+                )
             with jax.named_scope(phases.CONV_GATE):
                 gate, core = jnp.split(z, 2, axis=-1)
                 msg = nn.sigmoid(gate) * nn.softplus(core)
                 msg = msg * edge_mask[:, None].astype(msg.dtype)
             with jax.named_scope(phases.CONV_AGGREGATE):
                 agg = aggregate_edge_messages(msg, centers, nodes.shape[0])
-                if self.edge_axis_name is not None:
-                    # partial per-node sums from this edge shard -> full sums
-                    agg = jax.lax.psum(agg, self.edge_axis_name)
         if self.node_norm not in _TAIL_PHASE:
             raise ValueError(f"node_norm must be one of "
                              f"{sorted(_TAIL_PHASE)}, got {self.node_norm!r}")
@@ -369,7 +279,6 @@ class CrystalGraphConvNet(nn.Module):
     dropout_rate: float = 0.0  # reference applies dropout for classification
     dtype: Any = jnp.float32
     head: nn.Module | None = None  # e.g. MultiTaskHead; replaces fc stack
-    edge_axis_name: str | None = None  # edge-sharded graph parallelism
     dense_m: int | None = None  # dense slot layout (see CGConv.dense_m)
     node_norm: str = "batch"  # after each conv's sum (see CGConv.node_norm)
     # softplus on the pooled vector before conv_to_fc (txie-93/cgcnn has
@@ -389,7 +298,6 @@ class CrystalGraphConvNet(nn.Module):
             nodes = CGConv(
                 features=self.atom_fea_len,
                 dtype=self.dtype,
-                edge_axis_name=self.edge_axis_name,
                 dense_m=self.dense_m,
                 node_norm=self.node_norm,
                 name=f"conv_{i}",

@@ -6,8 +6,7 @@ sum inside its conv layer (SURVEY.md §3.3): on GPU it is ATen
 ``gather_slot_major`` (a row gather whose transpose is a second row
 gather through the packer's mapping; the sum over M needs no op of its
 own; the rows it moves are the nodes' fc_full projections, 2F wide, and
-the force model's positions: models/cgcnn.py _SplitFcFull),
-the node-strip sharded conv through ``gather_transpose``, and the
+the force model's positions: models/cgcnn.py _SplitFcFull) and the
 flat COO conv through ``gather`` and ``aggregate_edge_messages``, a
 ``segment_sum`` over the sorted centres the packers emit. XLA compiles
 all of it; there is no hand-written kernel.
@@ -95,12 +94,25 @@ def _run_totals(ct, o_win, o_same):
 
 def _transpose_cotangent(ct, slots, msk, o_win, o_same, o_last,
                          degree_axis: int = 1):
-    """The shared cotangent transpose ([E, F] -> [N, F]) — ONE body for
-    both row orders.
+    """The gather's cotangent transpose ([E, F] -> [N, F]), scatter-free.
+
+    The autodiff transpose of a row gather is a scatter-add of the [E, F]
+    cotangent into [N, F], the XLA scatter the dense layout removed from
+    the forward (~50x below HBM bandwidth on TPU). Through the packer's
+    mapping (pack_graphs ``in_cap`` / ``over_cap``) it is gather(ct,
+    slots) and a masked sum over the in-degree axis: a row gather and a
+    dense reduction. Two tiers: tier 1 is [N, M] with no in-degree
+    padding (a single [N, 2M] tier was the step's largest op at mean
+    in-degree M, half of it padding), and the ~7% of edges of rank >= M
+    arrive through the node-sorted overflow list (``o_win``, ``o_same``,
+    ``o_last``; below). The ``segment_sum`` that used to close that tier
+    was the conv's last XLA scatter: ~10 ns a row where a gathered row
+    costs ~1.3-2 (PERF.md section 5).
 
     ``slots`` is flat and ``msk`` says how the gathered rows are viewed:
     [N, In] (node-major, ``degree_axis`` 1) or [In, N] (slot-major,
-    ``degree_axis`` 0: the sum is In slab adds, no sublane reduce).
+    ``degree_axis`` 0, ``gather_slot_major``'s: the sum is In slab adds,
+    no sublane reduce).
     in_slots arrives pre-flattened (pack_graphs): a device-side
     [N, In] -> [N*In] flatten of the *gathered rows* is a tiled->linear
     relayout that measured 0.75 ms/step under the epoch scan.
@@ -133,7 +145,7 @@ _linear = jax.custom_derivatives.linear_call
 
 
 def _gather_rows(res, x):
-    """The forward of both transposable gathers: ``x[res[0]]``."""
+    """The forward of the transposable gather: ``x[res[0]]``."""
     return gather(x, res[0])
 
 
@@ -151,69 +163,17 @@ def gather(values: jax.Array, indices: jax.Array) -> jax.Array:
     return jnp.take(values, indices, axis=0, mode="clip")
 
 
-def gather_transpose(
-    nodes: jax.Array,  # [N, F]
-    neighbors: jax.Array,  # [E] i32
-    in_slots: jax.Array,  # [N*In] i32 FLAT — edge slots grouped by neighbor
-    in_mask: jax.Array,  # [N, In] — 1 where the slot entry is a real edge
-    over_slots: jax.Array | None = None,  # [O] i32 overflow edge slots
-    over_nodes: jax.Array | None = None,  # [O] i32 (non-decreasing)
-    over_last: jax.Array | None = None,  # [N] i32 end of the node's run
-    over_runs: jax.Array | None = None,  # [K]: K is the longest run allowed
-) -> jax.Array:
-    """``nodes[neighbors]`` with a SCATTER-FREE backward.
-
-    The forward is the plain neighbor gather. Its autodiff backward is a
-    scatter-add of the [E, F] cotangent into [N, F] — the same XLA scatter
-    the dense edge-slot layout removed from the forward aggregation (it
-    runs ~50x below HBM bandwidth on TPU). Given the host-precomputed
-    transpose mapping ``in_slots`` (pack_graphs ``in_cap``/``over_cap``),
-    the backward becomes gather(ct, in_slots) + masked sum over the
-    in-degree axis — a row gather plus a dense reduction, both
-    full-bandwidth ops.
-
-    TWO-TIER mode (``over_*`` given; pack_graphs ``over_cap``): tier 1 is
-    [N, M] (no in-degree padding — the [N, 2M] single-tier gather was the
-    step's largest single op at mean in-degree M, half padding bytes), and
-    the ~7% of edges with rank >= M arrive through the node-sorted overflow
-    list: a row gather of the list's rows of the cotangent, a sum over
-    each node's run of at most K adjacent rows (``_run_windows``,
-    ``_run_totals``: a 0/1 matrix times the rows, a block of 128 entries
-    at a time; K is ``over_runs``' length, a shape) and a row gather of
-    every node's total through ``over_last``. The ``segment_sum`` that
-    used to close this tier was the conv's last XLA scatter: ~10 ns a row
-    where a gathered row costs ~1.3-2 (PERF.md §5).
-
-    Equivalence to the plain gather's VJP requires the cotangent to be
-    zero on edge slots missing from the mapping (padding slots). CGConv
-    guarantees this: messages are multiplied by ``edge_mask`` and masked
-    BatchNorm statistics exclude padding, so no gradient path reaches a
-    padded slot's gathered row (the row is a term of ``z``, so its
-    cotangent is ``dz`` itself).
-
-    The gather is linear in ``nodes`` and is declared so (``_linear``),
-    which is what lets the force task differentiate it twice.
-    """
-    o_win, o_same = (None, None) if over_slots is None else _run_windows(
-        over_slots, over_nodes, over_runs.shape[-1])
-
-    def trans(res, ct):  # ct: [E, F] -> [N, F]
-        return _transpose_cotangent(ct, *res[1:])
-
-    res = (neighbors, in_slots, in_mask, o_win, o_same, over_last)
-    return _linear(_gather_rows, trans, res, nodes)
-
-
 def gather_slot_major(
     nodes: jax.Array,  # [N, F]
     neighbors: jax.Array,  # [N*M] i32, dense layout: node n owns [n*M, (n+1)*M)
     dense_m: int,
-    in_slots: jax.Array | None = None,  # as gather_transpose; None -> plain AD
-    in_mask: jax.Array | None = None,
-    over_slots: jax.Array | None = None,
-    over_nodes: jax.Array | None = None,
-    over_last: jax.Array | None = None,
-    over_runs: jax.Array | None = None,
+    in_slots: jax.Array | None = None,  # [N*In] i32 FLAT: edge slots
+    #   grouped by neighbour; None -> plain AD
+    in_mask: jax.Array | None = None,  # [N, In]: 1 where the entry is an edge
+    over_slots: jax.Array | None = None,  # [O] i32 overflow edge slots
+    over_nodes: jax.Array | None = None,  # [O] i32 (non-decreasing)
+    over_last: jax.Array | None = None,  # [N] i32 end of the node's run
+    over_runs: jax.Array | None = None,  # [K]: K is the longest run allowed
 ) -> jax.Array:
     """``nodes[neighbors]`` as [N, M, F], gathered in SLOT-MAJOR row order.
 
@@ -228,22 +188,38 @@ def gather_slot_major(
     [M, N, F] is a bitcast, and the ``moveaxis`` back to the model's
     logical [N, M, F] is a choice of layout, not a pass.
 
-    The transpose (given ``in_slots``; same contract and two-tier mapping
-    as ``gather_transpose``) mirrors it: the cotangent arrives flattened
+    The transpose (given ``in_slots``, the host-precomputed mapping of
+    pack_graphs ``in_cap`` / ``over_cap``) mirrors it and holds no scatter
+    (``_transpose_cotangent``): the cotangent arrives flattened
     slot-major, ``in_slots``/``over_slots`` are renumbered on the device
     (flat slot ``s = n*M + m`` sits at ``m*N + n``), the gathered
     [In*N, F] is viewed [In, N, F] and the masked sum runs over the OUTER
-    axis. The forward is bit-identical to ``gather_transpose`` (the same
-    rows); the backward sums the same terms in another association.
+    axis. With ``over_*`` the mapping has TWO TIERS: the edges of rank
+    >= In come through the node-sorted overflow list, a row gather of the
+    list's rows of the cotangent, a sum over each node's run of at most K
+    adjacent rows (``_run_windows``, ``_run_totals``: a 0/1 matrix times
+    the rows, a block of 128 entries at a time; K is ``over_runs``'
+    length, a shape) and a row gather of every node's total through
+    ``over_last``. The forward is bit-identical to ``gather(nodes,
+    neighbors)`` (the same rows); the backward sums the plain gather's
+    terms in another association.
+
+    Equivalence to the plain gather's VJP requires the cotangent to be
+    ZERO on edge slots missing from the mapping (padding slots). CGConv
+    guarantees this: messages are multiplied by ``edge_mask`` and masked
+    BatchNorm statistics exclude padding, so no gradient path reaches a
+    padded slot's gathered row (the row is a term of ``z``, so its
+    cotangent is ``dz`` itself).
+
+    The gather is linear in ``nodes`` and is declared so (``_linear``),
+    which is what lets the force task differentiate it twice.
 
     This is NOT the round-3 "slot-space variant" that measured 19% slower
     (17.2 vs 14.5 ms/step, r3 trace5): that one gathered with
     two-dimensional (node, slot) indices, which changed the gather's
     lowering. This keeps the flat one-dimensional row gather and changes
-    only the order of its indices.
-
-    The callers that hold the flat [E, F] form keep ``gather_transpose``
-    (the node-strip sharded dense conv) and ``gather`` (the COO conv).
+    only the order of its indices. The COO conv, which holds the flat
+    [E, F] form, keeps ``gather``.
     """
     n, m = nodes.shape[0], dense_m
 

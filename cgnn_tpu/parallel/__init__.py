@@ -1,4 +1,4 @@
-"""Distributed layer: data and graph (edge-sharded) parallelism over a mesh.
+"""Distributed layer: data parallelism over a mesh.
 
 TPU-native replacement for the reference's NCCL DDP (SURVEY.md §1
 "Distributed layer", §2 parallelism inventory, §5 "Distributed communication
@@ -6,10 +6,6 @@ backend"): no process groups, no rendezvous, no gradient buckets — one SPMD
 program over ``Mesh(devices, ('data',))`` where XLA emits the ICI/DCN
 collectives from ``psum``/``pmean`` inside ``shard_map``. Scaling past one
 pod slice adds a DCN axis to the same mesh; the step body is unchanged.
-
-``edge_parallel`` adds the sequence-parallel analog for graphs (SURVEY.md §5
-"long-context analog"): the edge axis sharded over a ``'graph'`` mesh axis,
-composable with data parallelism as a 2-D ``('data', 'graph')`` mesh.
 """
 
 from cgnn_tpu.parallel.mesh import make_mesh, device_count
@@ -24,13 +20,6 @@ from cgnn_tpu.parallel.data_parallel import (
     fit_data_parallel,
 )
 from cgnn_tpu.parallel.executor import MeshExecutor
-from cgnn_tpu.parallel.edge_parallel import (
-    pad_edges_divisible,
-    shard_batch,
-    make_edge_parallel_train_step,
-    make_edge_parallel_eval_step,
-    make_dp_edge_parallel_train_step,
-)
 
 __all__ = [
     "make_mesh",
@@ -44,9 +33,4 @@ __all__ = [
     "replicate_state",
     "fit_data_parallel",
     "MeshExecutor",
-    "pad_edges_divisible",
-    "shard_batch",
-    "make_edge_parallel_train_step",
-    "make_edge_parallel_eval_step",
-    "make_dp_edge_parallel_train_step",
 ]

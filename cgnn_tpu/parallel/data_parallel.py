@@ -125,9 +125,6 @@ def parallel_batches(
     snug: bool = False,
     stats: PaddingStats | None = None,
     edge_dtype=np.float32,
-    prep_fn: Callable | None = None,
-    node_multiple: int = 1,
-    transpose_shards: int = 1,
     pack_fn: Callable | None = None,
     telemetry=None,
 ) -> Iterable[GraphBatch]:
@@ -144,11 +141,6 @@ def parallel_batches(
     batches per shape are dropped per training epoch (the per-shape
     drop_last tail).
 
-    ``prep_fn`` transforms each batch before shape-keying/stacking (dense
-    graph sharding attaches per-shard transpose mappings here);
-    ``node_multiple`` rounds bucket-computed node capacities up so strips
-    divide evenly (capacities_for).
-
     ``pack_fn`` is handed to the batch iterators as the one-chip path hands
     it (``data.compact.compact_pack_fn``: compact staging); a compact batch
     stacks on the device axis like any other pytree.
@@ -160,20 +152,16 @@ def parallel_batches(
         source = bucketed_batch_iterator(
             graphs, batch_size, buckets, shuffle=shuffle, rng=rng,
             dense_m=dense_m, in_cap=in_cap, snug=snug, stats=stats,
-            edge_dtype=edge_dtype, node_multiple=node_multiple,
-            transpose_shards=transpose_shards, pack_fn=pack_fn,
+            edge_dtype=edge_dtype, pack_fn=pack_fn,
         )
     else:
         source = batch_iterator(
             graphs, batch_size, node_cap, edge_cap, shuffle=shuffle, rng=rng,
             dense_m=dense_m, in_cap=in_cap, snug=snug,
-            edge_dtype=edge_dtype, transpose_shards=transpose_shards,
-            pack_fn=pack_fn,
+            edge_dtype=edge_dtype, pack_fn=pack_fn,
         )
         if stats is not None:
             source = stats.wrap(source)
-    if prep_fn is not None:
-        source = map(prep_fn, source)
     from cgnn_tpu.data import invariants
 
     pending: dict[tuple, list[GraphBatch]] = {}
@@ -212,8 +200,7 @@ def is_multiprocess_mesh(mesh: Mesh) -> bool:
 
 
 def shard_leading_axis(tree, mesh: Mesh):
-    """Stage a stacked batch: leading axis split over every replica
-    (non-'graph') mesh axis.
+    """Stage a stacked batch: leading axis split over every mesh axis.
 
     Single-process: a plain sharded ``device_put``. Multi-process
     (``jax.distributed``): ``tree`` is this HOST'S local ``[n_local,
@@ -235,7 +222,7 @@ def shard_leading_axis(tree, mesh: Mesh):
 def shard_scan_stack(tree, mesh: Mesh):
     """device_put a STACK of device-stacked batches ([B, D, ...] leaves):
     axis 0 is the scan/step axis (replicated), axis 1 the device axis
-    (split over the replica mesh axes) — the staging for ScanEpochDriver
+    (split over the mesh's axes) — the staging for ScanEpochDriver
     under data parallelism. A compact stack goes over with flat rows
     (``data.compact.flat_rows``: the same fields and bytes, laid out so
     that the chip never re-lays out the resident stack)."""
@@ -285,10 +272,17 @@ def count_deployment(telemetry, state: TrainState, n_replicas: int,
 
 
 def _replica_axes(mesh: Mesh) -> tuple[str, ...]:
-    """Every mesh axis that carries data replicas ('graph' shards edges,
-    not batches). A multi-host ('dcn', 'data') mesh reduces over both axes —
-    XLA routes each partial reduction over the matching fabric."""
-    return tuple(a for a in mesh.axis_names if a != "graph")
+    """The mesh's axes, every one of which carries data replicas. A
+    multi-host ('dcn', 'data') mesh reduces over both axes — XLA routes each
+    partial reduction over the matching fabric. A mesh comes from outside:
+    one with any other axis is refused, not folded into the replica count."""
+    for a in mesh.axis_names:
+        if a not in ("dcn", "data"):
+            raise ValueError(
+                f"mesh axis {a!r} is not a data-parallel axis: the step "
+                f"replicates over 'data' (and 'dcn' above it) and shards "
+                f"nothing else (mesh axes {mesh.axis_names})")
+    return tuple(mesh.axis_names)
 
 
 def make_parallel_train_step(
@@ -302,9 +296,9 @@ def make_parallel_train_step(
 ) -> Callable:
     """shard_map-wrapped train step: (replicated state, [D,...] batch).
 
-    The batch's leading device axis is split over every non-'graph' mesh
-    axis, so a 1-D ('data',) mesh and a hierarchical ('dcn', 'data')
-    multi-host mesh run the same step body.
+    The batch's leading device axis is split over every mesh axis, so a
+    1-D ('data',) mesh and a hierarchical ('dcn', 'data') multi-host mesh
+    run the same step body.
 
     ``inner_step`` overrides the default step body entirely (it must already
     be built with ``axis_name='data'`` — e.g. the force-task step; only
@@ -453,12 +447,6 @@ def fit_data_parallel(
     be built with ``axis_name='data'``); ``best_metric`` overrides the
     model-selection key.
 
-    A 2-D ``('data', 'graph')`` mesh (parallel.mesh.make_2d_mesh) activates
-    edge-sharded graph parallelism on top of DP: per-device batches keep
-    their 'data' row but their edge leaves are split over 'graph'. The
-    model in ``state.apply_fn`` must then be built with
-    ``edge_axis_name='graph'``.
-
     ``pack_once`` / ``device_resident`` mirror train.loop.fit: pack (and,
     for device_resident, mesh-shard into HBM) the stacked batches once,
     reshuffling stacked-batch order across epochs.
@@ -469,9 +457,8 @@ def fit_data_parallel(
     ``CompactBatch`` — vocabulary indices and scalar distances, ~12x fewer
     bytes — split over the replica axes like any other leaf, and each
     device rebuilds its own row inside the per-shard step body
-    (``make_parallel_train_step(expand=...)``). Without it, and always
-    under a 'graph' axis (whose edge leaves are split a second time), the
-    rows are full ``GraphBatch``es.
+    (``make_parallel_train_step(expand=...)``). Without it the rows are
+    full ``GraphBatch``es.
 
     ``telemetry`` mirrors train.loop.fit: spans, padding/HBM gauges, and
     — with ``scan_epochs`` at step level — the in-scan per-step stream
@@ -491,106 +478,41 @@ def fit_data_parallel(
 
     telemetry = telemetry or Telemetry.disabled()
     mesh = mesh or make_mesh()
+    _replica_axes(mesh)  # refuses a mesh with an axis that is no replica axis
+    n_dev = int(mesh.devices.size)
     if dense_m is not None:
         edge_cap = node_cap * dense_m
-    graph_shards = int(mesh.shape.get("graph", 1))
     pack_fn = expand = None
     if compact is not None:
         if not scan_epochs or dense_m is None:
             raise ValueError("compact staging requires scan_epochs and the "
                              "dense layout (dense_m), as in train.loop.fit")
-        if graph_shards > 1:
-            raise NotImplementedError(
-                "compact staging is not supported with edge-sharded "
-                "('graph') meshes (full staging only)")
         from cgnn_tpu.data.compact import compact_pack_fn, make_expander
 
         pack_fn, expand = compact_pack_fn(compact), make_expander(compact)
     multiproc = is_multiprocess_mesh(mesh)
     if multiproc:
-        if graph_shards > 1:
-            raise NotImplementedError(
-                "edge-sharded ('graph') meshes are single-host for now "
-                "(per-conv psums belong on ICI, not DCN)"
-            )
         if scan_epochs or device_resident or pack_once:
             raise NotImplementedError(
                 "multi-host DP runs the per-step loop (scan/"
                 "device-resident staging is host-local); drop "
                 "--scan-epochs/--device-resident/--pack-once"
             )
-    if graph_shards > 1 and profile_steps:
-        raise NotImplementedError(
-            "--profile is not supported with edge-sharded ('graph') "
-            "meshes; use a pure data mesh"
-        )
-    if graph_shards > 1 and buckets > 1 and dense_m is None:
-        raise NotImplementedError(
-            "--buckets with --graph-shards requires the dense layout "
-            "(per-size-class capacities shard by node strips)"
-        )
-    prep_train = prep_val = None
-    node_multiple = 1
-    transpose_shards = 1
-    if graph_shards > 1:
-        from cgnn_tpu.parallel.edge_parallel import (
-            make_dp_edge_parallel_eval_step,
-            make_dp_edge_parallel_train_step,
-            prepare_dense_sharded,
-            shard_stacked_batch,
-        )
-
-        if train_step_fn is not None or eval_step_fn is not None:
-            raise NotImplementedError(
-                "custom step bodies are not supported with graph sharding"
-            )
-        n_dev = int(mesh.shape["data"])
-        if dense_m is not None:
-            # dense fast path composed with node-strip graph sharding
-            # (VERDICT r4 #3): round node_cap so every shard owns a whole
-            # 8-aligned strip; train batches pack their per-shard
-            # transpose mappings DIRECTLY (pack_graphs transpose_shards —
-            # no pack-then-rebuild on the host critical path), eval
-            # batches drop their mapping fields (prepare_dense_sharded)
-            mult = 8 * graph_shards
-            node_cap = -(-node_cap // mult) * mult
-            edge_cap = node_cap * dense_m
-            node_multiple = mult
-            transpose_shards = graph_shards
-            prep_val = lambda b: prepare_dense_sharded(  # noqa: E731
-                b, graph_shards, train=False)
-            train_step = make_dp_edge_parallel_train_step(
-                mesh, classification, dense=True,
-                grad_health=telemetry.step_level, guard=guard)
-            eval_step = make_dp_edge_parallel_eval_step(
-                mesh, classification, dense=True)
-        else:
-            # pack at a shard-divisible edge capacity up front (cheaper
-            # than re-padding every batch after the fact)
-            edge_cap = -(-edge_cap // graph_shards) * graph_shards
-            train_step = make_dp_edge_parallel_train_step(
-                mesh, classification,
-                grad_health=telemetry.step_level, guard=guard)
-            eval_step = make_dp_edge_parallel_eval_step(mesh, classification)
-        shard_put = lambda b: shard_stacked_batch(b, mesh)  # noqa: E731
-    else:
-        n_dev = int(np.prod([mesh.shape[a] for a in mesh.axis_names]))
-        if multiproc:
-            # each host packs device groups for its LOCAL share of the
-            # mesh; the global batch is the process-order concatenation
-            # (shard_leading_axis stages it as one global array). The
-            # CALLER host-shards the graphs (dist.host_shard) so hosts
-            # pack disjoint data.
-            n_dev_global = n_dev
-            n_dev = max(1, n_dev // jax.process_count())
-        train_step = make_parallel_train_step(
-            mesh, classification, inner_step=train_step_fn,
-            grad_health=telemetry.step_level, guard=guard, expand=expand,
-        )
-        eval_step = make_parallel_eval_step(
-            mesh, classification, inner_step=eval_step_fn, expand=expand,
-        )
-        shard_put = lambda b: shard_leading_axis(b, mesh)  # noqa: E731
+        # each host packs device groups for its LOCAL share of the
+        # mesh; the global batch is the process-order concatenation
+        # (shard_leading_axis stages it as one global array). The
+        # CALLER host-shards the graphs (dist.host_shard) so hosts
+        # pack disjoint data.
+        n_dev_global = n_dev
+        n_dev = max(1, n_dev // jax.process_count())
+    train_step = make_parallel_train_step(
+        mesh, classification, inner_step=train_step_fn,
+        grad_health=telemetry.step_level, guard=guard, expand=expand,
+    )
+    eval_step = make_parallel_eval_step(
+        mesh, classification, inner_step=eval_step_fn, expand=expand,
+    )
+    shard_put = lambda b: shard_leading_axis(b, mesh)  # noqa: E731
     state = replicate_state(state, mesh)
     count_deployment(telemetry, state,
                      n_dev_global if multiproc else n_dev, batch_size)
@@ -620,17 +542,14 @@ def fit_data_parallel(
             train_graphs, n_dev, batch_size, node_cap, edge_cap,
             shuffle=True, rng=rng, dense_m=dense_m, buckets=buckets,
             snug=snug, stats=pad_stats, edge_dtype=edge_dtype,
-            prep_fn=prep_train, node_multiple=node_multiple,
-            transpose_shards=transpose_shards, pack_fn=pack_fn,
-            telemetry=telemetry,
+            pack_fn=pack_fn, telemetry=telemetry,
         ))
 
     def make_val_it():
         return parallel_batches(
             val_graphs, n_dev, batch_size, node_cap, edge_cap,
             pad_incomplete=True, dense_m=dense_m, in_cap=0, buckets=buckets,
-            snug=snug, edge_dtype=edge_dtype,
-            prep_fn=prep_val, node_multiple=node_multiple, pack_fn=pack_fn,
+            snug=snug, edge_dtype=edge_dtype, pack_fn=pack_fn,
         )
 
     if multiproc:
@@ -675,59 +594,16 @@ def fit_data_parallel(
         with telemetry.span("pack"):
             train_list = list(make_train_it())
             val_list = list(make_val_it())
-        # per-device share for the precheck: the stacked device axis
-        # splits everything over the data shards; under graph sharding
-        # the edge leaves (the dominant bytes: [N, M, G] stacks and the
-        # per-shard transpose mappings) additionally split over 'graph',
-        # while node/graph leaves replicate across it — dividing the
-        # whole total by data shards alone would overestimate the share
-        # by up to graph_shards x and spuriously kick sharded runs off
-        # the scan fast path
-        if graph_shards > 1:
-            import dataclasses as _dc
-
-            from cgnn_tpu.parallel.edge_parallel import (
-                _DENSE_ONLY_FIELDS,
-                EDGE_FIELDS,
-            )
-
-            sharded_fields = set(EDGE_FIELDS) | set(_DENSE_ONLY_FIELDS)
-            e_bytes = o_bytes = 0
-            for b in train_list + val_list:
-                for f in _dc.fields(b):
-                    x = getattr(b, f.name)
-                    if x is None:
-                        continue
-                    if f.name in sharded_fields:
-                        e_bytes += x.nbytes
-                    else:
-                        o_bytes += x.nbytes
-            per_device = (e_bytes / (n_dev * graph_shards)
-                          + o_bytes / n_dev)
-            fits = check_device_resident_fit(int(per_device), n_devices=1,
-                                             log_fn=log_fn)
-        else:
-            staged_bytes = staged_nbytes(train_list + val_list)
-            fits = check_device_resident_fit(staged_bytes, n_devices=n_dev,
-                                             log_fn=log_fn)
+        staged_bytes = staged_nbytes(train_list + val_list)
+        fits = check_device_resident_fit(staged_bytes, n_devices=n_dev,
+                                         log_fn=log_fn)
         if fits:
-            if graph_shards > 1:
-                # 2-D staging: edge leaves + per-shard transpose stacks
-                # split over 'graph' inside each data shard; the scan
-                # body's dynamic index preserves the inner shardings, so
-                # the shard_map step sees the per-step path's layout
-                from cgnn_tpu.parallel.edge_parallel import (
-                    shard_scan_stack_2d,
-                )
-
-                stage = lambda t: shard_scan_stack_2d(t, mesh)  # noqa: E731
-            else:
-                stage = lambda t: shard_scan_stack(t, mesh)  # noqa: E731
             with telemetry.span("stage_scan_stacks"):
                 driver = ScanEpochDriver(
-                    train_step, eval_step, train_list, val_list,
-                    rng, stage=stage, chunk_steps=chunk_steps,
-                    telemetry=telemetry, preempt=preempt,
+                    train_step, eval_step, train_list, val_list, rng,
+                    stage=lambda t: shard_scan_stack(t, mesh),
+                    chunk_steps=chunk_steps, telemetry=telemetry,
+                    preempt=preempt,
                 )
             telemetry.sample_hbm("post_staging")
         else:
@@ -837,9 +713,7 @@ def fit_data_parallel(
             best = metric
         history.append({"epoch": epoch, "train_loss": train_loss, "val": val_m})
         tag = (f"dp x{n_dev_global} over {jax.process_count()} hosts"
-               if multiproc else f"dp x{n_dev}") + (
-            f" * graph x{graph_shards}" if graph_shards > 1 else ""
-        )
+               if multiproc else f"dp x{n_dev}")
         log_fn(
             f"Epoch {epoch} [{tag}]: train loss {train_loss:.4f}"
             f"  val {best_key} {metric:.4f}"

@@ -30,27 +30,3 @@ def make_mesh(
         devs = devs[:n_devices]
     return Mesh(np.array(devs), (axis,))
 
-
-def make_2d_mesh(
-    graph_shards: int,
-    data_shards: int | None = None,
-    devices=None,
-    axes: tuple[str, str] = ("data", "graph"),
-) -> Mesh:
-    """('data', 'graph') mesh for DP x edge-sharded graph parallelism.
-
-    ``data_shards`` defaults to every remaining device
-    (``len(devices) // graph_shards``). Device order keeps graph shards on
-    adjacent devices (the per-conv psum over 'graph' is the latency-critical
-    collective; adjacency keeps it on the shortest ICI hops).
-    """
-    devs = list(devices if devices is not None else jax.devices())
-    if data_shards is None:
-        data_shards = max(1, len(devs) // graph_shards)
-    need = data_shards * graph_shards
-    if need > len(devs):
-        raise ValueError(
-            f"requested {data_shards}x{graph_shards} mesh, "
-            f"only {len(devs)} devices visible"
-        )
-    return Mesh(np.array(devs[:need]).reshape(data_shards, graph_shards), axes)

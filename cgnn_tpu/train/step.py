@@ -90,10 +90,13 @@ def make_train_step(
 
     ``loss_scale`` multiplies the loss before differentiation (metrics are
     unscaled) and ``pmean_grads=False`` skips the explicit grad allreduce —
-    both exist for steps running under shard_map with replication checking
+    both are for a step running under shard_map with replication checking
     ON, where the transpose already psums parameter cotangents over every
-    mesh axis: scaling by 1/axis_size turns that sum into the DDP mean
-    (cgnn_tpu.parallel.edge_parallel 2-D mesh step).
+    mesh axis: scaling by 1/axis_size turns that sum into the DDP mean. No
+    step of the package is built that way (parallel/data_parallel.py runs
+    with ``check_vma=False``); ``pmean_grads=False`` is the step without an
+    all-reduce that tests/benchmark/test_dp_cell.py holds the cell's check
+    against.
 
     ``grad_health`` adds in-graph grad-norm / update-norm / NaN-Inf-count
     metrics (observe.health) — extra metric OUTPUTS only, computed from
@@ -178,7 +181,7 @@ def make_eval_step(
 # parameter/optimizer buffers in place instead of allocating a second
 # copy per step. TRAIN_STEP_DONATE is the ONE declaration of WHICH
 # argument is donated — consumed by jit_train_step (single-device
-# bodies), the scan driver, and the DP/edge-sharded wrappers in
+# bodies), the scan driver, and the data-parallel wrappers in
 # parallel/ — and the graftaudit GA-DONATION check verifies XLA
 # actually applied the aliasing (analysis/program_audit).
 TRAIN_STEP_DONATE = (0,)

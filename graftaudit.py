@@ -3,8 +3,8 @@
 
 graftcheck lints what the SOURCE says; graftaudit verifies what XLA
 actually COMPILES. It lowers the repo's real entry programs — the train
-step (plain / guard / telemetry-tapped / dense / DP / edge-sharded
-where the backend allows), every (rung, staging form) predict program
+step (plain / guard / telemetry-tapped / dense / data-parallel where
+the backend allows), every (rung, staging form) predict program
 in the warm shape ladder, and the compact expander — on abstract args,
 then audits the artifacts: donation applied (GA-DONATION), no f64
 anywhere (GA-F64), no host calls beyond the sanctioned telemetry tap

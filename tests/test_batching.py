@@ -145,7 +145,7 @@ def test_transpose_slots_invariants():
 
 
 def test_transpose_backward_matches_plain_gather():
-    """The scatter-free gather backward (gather_transpose) must produce the
+    """The scatter-free gather backward (gather_slot_major) must produce the
     same gradients as autodiff through the plain gather."""
     import jax
     import jax.numpy as jnp
@@ -424,22 +424,13 @@ _CRAFTED_TIERS = {
     # nodes without a run point at is a block of its own
     "list_fills_its_block": ({i: 10 for i in range(16)}, 96, 128, 8),
 }
-_TIER_CASES = [(form, case)
-               for case in ("mp", *_CRAFTED_TIERS, "sharded")
-               for form in ("flat", "slot_major")
-               if (form, case) != ("slot_major", "sharded")]
-
-
-@pytest.mark.parametrize("form,case", _TIER_CASES)
-def test_two_tier_transpose_backward_matches_plain_gather(form, case):
+@pytest.mark.parametrize("case", ["mp", *_CRAFTED_TIERS])
+def test_two_tier_transpose_backward_matches_plain_gather(case):
     """Two-tier (tier-1 [N, M] + the overflow list's run sums, gathered
-    through over_last) gather_transpose gradients == plain-gather gradients
-    through a full CGConv-like masked consumer — in the flat node-major
-    form and in the slot-major form the unsharded dense conv takes
-    (gather_slot_major). ``mp``: packed crystals whose in-degree exceeds
-    dense_m, in a padded batch; the crafted cases: the edges of the run sum
-    (_CRAFTED_TIERS); ``sharded``: the per-shard mappings of
-    shard_transpose_slots, a strip of the gather each."""
+    through over_last) gather_slot_major gradients == plain-gather gradients
+    through a full CGConv-like masked consumer. ``mp``: packed crystals
+    whose in-degree exceeds dense_m, in a padded batch; the crafted cases:
+    the edges of the run sum (_CRAFTED_TIERS)."""
     import jax
     import jax.numpy as jnp
 
@@ -448,36 +439,22 @@ def test_two_tier_transpose_backward_matches_plain_gather(form, case):
         batch_iterator,
         capacities_for,
         overflow_rows,
-        shard_transpose_slots,
         transpose_slots,
     )
-    from cgnn_tpu.ops.segment import (
-        gather,
-        gather_slot_major,
-        gather_transpose,
-    )
+    from cgnn_tpu.ops.segment import gather, gather_slot_major
 
-    shards = 1
-    if case in ("mp", "sharded"):
+    if case == "mp":
         m = 12
         cfg = FeaturizeConfig(radius=6.0, max_num_nbr=m)
         graphs = load_synthetic_mp(64, cfg, seed=3)
-        nc, ec = capacities_for(graphs, 32, dense_m=m, snug=True,
-                                node_multiple=2)
-        shards = 2 if case == "sharded" else 1
-        b = next(batch_iterator(graphs, 32, nc, ec, dense_m=m, snug=True,
-                                transpose_shards=shards))
+        nc, ec = capacities_for(graphs, 32, dense_m=m, snug=True)
+        b = next(batch_iterator(graphs, 32, nc, ec, dense_m=m, snug=True))
         assert overflow_rows(b) > 0, "no overflow exercised"
         assert int(np.asarray(b.edge_mask).sum()) < b.edge_capacity, \
             "no padding"
         neighbors, real = np.asarray(b.neighbors), np.asarray(b.edge_mask) > 0
         mapping = (b.in_slots, b.in_mask, b.over_slots, b.over_nodes,
                    b.over_last, b.over_runs)
-        if shards > 1:  # the direct pack and the rebuild agree
-            for got, want in zip(mapping, shard_transpose_slots(
-                    neighbors, real, nc, m, shards, len(b.over_slots[0]),
-                    b.over_runs.shape[-1])):
-                np.testing.assert_array_equal(got, want)
     else:
         m = 2
         in_degree, nc, over_cap, run_cap = _CRAFTED_TIERS[case]
@@ -493,23 +470,15 @@ def test_two_tier_transpose_backward_matches_plain_gather(form, case):
         np.random.default_rng(0).normal(size=(nc, 16))
     ).astype(jnp.float32)
     emask = jnp.asarray(real, jnp.float32).reshape(-1, m, 1)
-    # a consumer that weighs every slot differently: an order mix-up
-    # between the forms cannot cancel
+    # a consumer that weighs every slot differently: a mix-up of the row
+    # order cannot cancel
     weight = jnp.asarray(np.random.default_rng(1).normal(
         size=(nc, m, 16))).astype(jnp.float32)
     neighbors = jnp.asarray(neighbors)
 
     def loss_two_tier(n):
-        if shards > 1:  # each shard gathers its strip through its mapping
-            strips = neighbors.reshape(shards, -1)
-            v_j = jnp.concatenate([
-                gather_transpose(n, strips[s], *(x[s] for x in mapping))
-                for s in range(shards)])
-        elif form == "flat":
-            v_j = gather_transpose(n, neighbors, *mapping)
-        else:
-            v_j = gather_slot_major(n, neighbors, m, *mapping)
-        return ((v_j.reshape(-1, m, 16) * emask * weight) ** 2).sum()
+        v_j = gather_slot_major(n, neighbors, m, *mapping)
+        return ((v_j * emask * weight) ** 2).sum()
 
     def loss_plain(n):
         v_j = gather(n, neighbors).reshape(-1, m, 16)

@@ -248,8 +248,7 @@ def test_train_cli_data_parallel_device_resident(tmp_path, staging, says):
     """``train.py --data-parallel --device-resident --compact-staging on``
     over four virtual devices: the deployment cell ``mp.train-dp4`` measures,
     from the CLI, two epochs through the scan driver, with the counters of
-    the deployment in the run's summary; ``off`` stages in full as before.
-    ``on`` is still refused with ``--graph-shards``."""
+    the deployment in the run's summary; ``off`` stages in full as before."""
     import json
     import subprocess
 
@@ -274,12 +273,6 @@ def test_train_cli_data_parallel_device_resident(tmp_path, staging, says):
     assert counters["dp_global_batch"] == 32
     assert counters["allreduce_bytes_per_step"] > 0
     assert 0 <= counters["dp_dropped_batches"] <= 2 * 3  # < D a shape
-    if staging == "on":
-        refused = subprocess.run(
-            cmd + ["--graph-shards", "2"], cwd=ROOT, env=env,
-            capture_output=True, text=True, timeout=600)
-        assert refused.returncode == 2
-        assert "--graph-shards" in refused.stderr
 
 
 def test_a_compact_stack_is_staged_with_flat_rows():

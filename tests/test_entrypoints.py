@@ -111,13 +111,13 @@ def test_train_resume_predict_cycle(tmp_path):
             assert "--wire raw: 0/16 structures can ride" in p4.stderr
 
 
-def test_train_cli_graph_shards(tmp_path):
-    """--graph-shards 2 --data-parallel over 8 virtual devices: the 2-D
-    ('data','graph') mesh trains end to end from the CLI."""
+def test_train_cli_data_parallel_checkpoint_predicts_on_one_device(tmp_path):
+    """--data-parallel at its defaults over 8 virtual devices (the per-step
+    loop, a 1-D 'data' mesh) trains end to end from the CLI, and what it
+    saved restores where there is no mesh."""
     proc = _run(
         [sys.executable, "train.py", "--synthetic", "48", "--device", "cpu",
-         "--epochs", "1", "-b", "8", "--radius", "5",
-         "--data-parallel", "--graph-shards", "2",
+         "--epochs", "1", "-b", "2", "--radius", "5", "--data-parallel",
          "--ckpt-dir", str(tmp_path / "ckpt"), "--print-freq", "0"],
         env_overrides={
             "JAX_PLATFORMS": "cpu",
@@ -125,11 +125,11 @@ def test_train_cli_graph_shards(tmp_path):
         },
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
-    assert "dp x4 * graph x2" in proc.stdout, proc.stdout
+    assert "Epoch 0 [dp x8]" in proc.stdout, proc.stdout
     assert "** test mae:" in proc.stdout
 
-    # a checkpoint saved from the 8-device 2-D mesh must restore in a
-    # plain single-device predict process (topology-independent saves)
+    # a checkpoint saved from the 8-device mesh must restore in a plain
+    # single-device predict process (topology-independent saves)
     out_csv = str(tmp_path / "preds.csv")
     p2 = _run(
         [sys.executable, "predict.py", str(tmp_path / "ckpt"), "unused",

@@ -1,7 +1,8 @@
 """Force-path correctness: LJ ground truth + stored-geometry consistency.
 
-Covers the two silent-corruption bugs ADVICE.md (round 1) identified:
-sign-flipped LJ forces and unwrapped stored geometry.
+Covers two bugs that corrupted results in silence: Lennard-Jones forces
+with the sign flipped, and stored geometry that was not wrapped into the
+cell.
 """
 
 import numpy as np

@@ -118,13 +118,13 @@ class TestManifest:
     def test_write_manifest(self, tmp_path):
         path = write_manifest(str(tmp_path), {"batch_size": 32, "lr": 0.01},
                               task="regression",
-                              mesh_shape={"data": 1, "graph": 1})
+                              mesh_shape={"dcn": 1, "data": 1})
         m = json.load(open(path))
         assert m["config"]["batch_size"] == 32
         assert m["device_count"] == len(jax.devices())
         assert m["devices"][0]["platform"] == "cpu"
         assert m["task"] == "regression"
-        assert m["mesh_shape"] == {"data": 1, "graph": 1}
+        assert m["mesh_shape"] == {"dcn": 1, "data": 1}
         # this repo is a git checkout, so the SHA must be present here
         assert len(m.get("git_sha", "")) == 40
 

@@ -12,7 +12,6 @@ from cgnn_tpu.ops.segment import (
     aggregate_edge_messages,
     gather,
     gather_slot_major,
-    gather_transpose,
     segment_mean,
     segment_sum,
 )
@@ -105,22 +104,23 @@ class TestSegmentOps:
 
 
 def test_linear_gather_survives_forward_over_reverse():
-    """``gather_transpose`` (the flat form the node-strip sharded conv
-    keeps) is declared linear with its transpose, so forward-mode over
-    reverse-mode composes — ``jax.jvp`` of ``jax.grad``, what a
-    ``custom_vjp`` rejects — and equals plain autodiff of ``jnp.take``."""
+    """``gather_slot_major`` with a two-tier mapping is declared linear
+    with its transpose (``_linear``), so forward-mode over reverse-mode
+    composes — ``jax.jvp`` of ``jax.grad``, what a ``custom_vjp`` rejects
+    — and equals plain autodiff of ``jnp.take``."""
     n, m, f = 24, 4, 5
     nodes, nbrs, mapping = _dense_gather_case(n, m, f, jnp.float32)
     rng = np.random.default_rng(11)
-    w = jnp.asarray(rng.normal(size=(n * m, f)).astype(np.float32))
+    w = jnp.asarray(rng.normal(size=(n, m, f)).astype(np.float32))
     tangent = jnp.asarray(rng.normal(size=(n, f)).astype(np.float32))
 
     def hvp(fn):
         grad = jax.grad(lambda x: (jnp.tanh(fn(x)) * w).sum())
         return jax.jvp(grad, (nodes,), (tangent,))
 
-    g_got, t_got = hvp(lambda x: gather_transpose(x, nbrs, *mapping))
-    g_want, t_want = hvp(lambda x: jnp.take(x, nbrs, axis=0))
+    g_got, t_got = hvp(lambda x: gather_slot_major(x, nbrs, m, *mapping))
+    g_want, t_want = hvp(
+        lambda x: jnp.take(x, nbrs, axis=0).reshape(n, m, f))
     np.testing.assert_allclose(g_got, g_want, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(t_got, t_want, rtol=1e-5, atol=1e-6)
 
@@ -613,6 +613,64 @@ def test_dense_conv_matches_coo_conv(dtype, mode, mapping, batchnorm):
                                     flat(gp_c[module])):
             assert ka == kb
             close(a, b, 5e-4, 5e-5, f"gradient {module}{ka}", scale)
+
+
+def _primitive_names(jaxpr) -> set:
+    """Every primitive of ``jaxpr`` and of the jaxprs its equations hold."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    names |= _primitive_names(sub)
+    return names
+
+
+@pytest.mark.parametrize("body", ["dense-two-tier", "dense-no-mapping",
+                                  "coo"])
+def test_conv_holds_no_collective(body):
+    """One mesh axis, and it is outside the conv: neither body of
+    ``CGConv``, forward or reverse, names a mesh axis (the replicas' sums
+    are the step's, train/step.py). The dense body with the two-tier
+    mapping (training), without one (eval batches), and the COO body."""
+    batch, m = _conv_case("none" if body == "dense-no-mapping"
+                          else "two-tier")
+    nodes, variables, dense, coo, args = _conv_pair(
+        batch, m, jnp.dtype("float32"), 16, True)
+    conv, edges, kw = coo if body == "coo" else dense
+    train = body != "dense-no-mapping"
+
+    def loss(params, x):
+        out, _ = conv.apply(
+            {"params": params, "batch_stats": variables["batch_stats"]},
+            x, *args(edges), train=train, mutable=["batch_stats"], **kw)
+        return (out ** 2).sum()
+
+    names = _primitive_names(jax.make_jaxpr(
+        jax.grad(loss, argnums=(0, 1)))(variables["params"], nodes).jaxpr)
+    assert "gather" in names and "dot_general" in names, names
+    across = {"psum", "psum2", "psum_invariant", "pmax", "pmin",
+              "all_gather", "all_gather_invariant", "all_to_all",
+              "ppermute", "pcast", "pvary", "pbroadcast", "axis_index",
+              "reduce_scatter"}
+    assert not names & across, names & across
+
+
+def test_conv_and_model_fields():
+    """The conv's options are these five and the model has no mesh axis of
+    its own: a sixth is a decision every cell's program would carry."""
+    import dataclasses
+
+    from cgnn_tpu.models.cgcnn import CGConv, CrystalGraphConvNet
+
+    own = lambda cls: [f.name for f in dataclasses.fields(cls)  # noqa: E731
+                       if f.name not in ("parent", "name")]
+    assert own(CGConv) == ["features", "dtype", "use_batchnorm",
+                           "node_norm", "dense_m"]
+    assert not [n for n in own(CrystalGraphConvNet) if "axis" in n]
 
 
 @pytest.mark.parametrize("mapping", _MAPPINGS)

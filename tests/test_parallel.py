@@ -192,80 +192,141 @@ class TestDataParallel:
 class TestDPFeatureParity:
     """VERDICT r2 #3: buckets / snug / scan_epochs inside the DP loop."""
 
-    def _dense_setup(self, graphs):
+    @staticmethod
+    def _fresh(graphs, dense_m=8):
+        """A factory of new states for ``graphs`` in one layout (``dense_m``
+        None: the COO body)."""
         from cgnn_tpu.data.graph import bucketed_batch_iterator
 
-        dense_model = CrystalGraphConvNet(
-            atom_fea_len=12, n_conv=2, h_fea_len=16, dense_m=8
+        model = CrystalGraphConvNet(
+            atom_fea_len=12, n_conv=2, h_fea_len=16, dense_m=dense_m
         )
         eb = next(iter(bucketed_batch_iterator(
-            graphs, 2, 2, dense_m=8, snug=True
+            graphs, 2, 2, dense_m=dense_m, snug=True
         )))
         tx = make_optimizer(optim="sgd", lr=0.05)
 
         def fresh():
             return create_train_state(
-                dense_model, eb, tx,
+                model, eb, tx,
                 Normalizer.fit(np.stack([g.target for g in graphs])),
             )
 
         return fresh
 
-    def test_fit_dp_bucketed_snug_trains(self, setup):
+    @pytest.mark.parametrize("scan_epochs", [False, True],
+                             ids=["per_step", "scan"])
+    def test_fit_dp_bucketed_snug_trains(self, setup, scan_epochs):
         from cgnn_tpu.parallel import fit_data_parallel
 
         graphs, *_ = setup
-        fresh = self._dense_setup(graphs)
+        fresh = self._fresh(graphs)
         quiet = lambda *a, **k: None  # noqa: E731
         _, result = fit_data_parallel(
             fresh(), graphs, graphs[:8], epochs=6, batch_size=2,
             node_cap=0, edge_cap=0, seed=5, mesh=make_mesh(4), log_fn=quiet,
-            buckets=2, snug=True, dense_m=8,
+            buckets=2, snug=True, dense_m=8, scan_epochs=scan_epochs,
         )
         h = result["history"]
         assert np.isfinite(h[-1]["train_loss"])
         assert h[-1]["train_loss"] < h[0]["train_loss"]
 
-    def test_fit_dp_scan_epochs_matches_per_step(self, setup):
-        """First epoch of DP scan_epochs == per-step DP (same seed/batches,
-        single shape group so the orders coincide): the scan folds
-        dispatches, not math. Multi-bucket scan ordering is chunk-granular
-        by design (ScanEpochDriver docstring), so exact parity is a
-        single-shape property."""
+    @pytest.mark.parametrize("buckets", [1, 3])
+    @pytest.mark.parametrize("dense_m", [8, None], ids=["dense", "coo"])
+    def test_fit_dp_scan_epochs_matches_per_step(self, dense_m, buckets):
+        """DP scan_epochs against per-step DP, same seed and batches, in
+        both layouts. One shape group: the orders coincide and the first
+        epoch is the same to rounding (the scan folds dispatches, not
+        math). Three size classes: the scan's order is chunk-granular by
+        design (ScanEpochDriver docstring), so what must agree is what
+        order cannot move, the structures each epoch trains on and scores,
+        and both runs train."""
         from cgnn_tpu.data.graph import capacities_for
         from cgnn_tpu.parallel import fit_data_parallel
 
-        graphs, *_ = setup
-        fresh = self._dense_setup(graphs)
+        graphs = load_synthetic(
+            96, FeaturizeConfig(radius=5.0, max_num_nbr=8), seed=9,
+            max_atoms=6)
+        train_g, val_g = graphs[:80], graphs[80:]
+        fresh = self._fresh(train_g, dense_m)
         quiet = lambda *a, **k: None  # noqa: E731
-        nc, ec = capacities_for(graphs, 2, dense_m=8, snug=True)
+        nc, ec = capacities_for(train_g, 2, dense_m=dense_m, snug=True)
 
         def run(**kw):
+            seen = []
             _, result = fit_data_parallel(
-                fresh(), graphs, graphs[:8], epochs=2, batch_size=2,
+                fresh(), train_g, val_g, epochs=3, batch_size=2,
                 node_cap=nc, edge_cap=ec, seed=5, mesh=make_mesh(4),
-                log_fn=quiet, snug=True, dense_m=8, **kw,
+                log_fn=quiet, snug=True, dense_m=dense_m, buckets=buckets,
+                on_epoch_metrics=lambda e, t, v: seen.append(
+                    (t["count"], v["count"])),
+                **kw,
             )
-            return result["history"]
+            return result["history"], seen
 
-        h_step = run(device_resident=True)
-        h_scan = run(scan_epochs=True)
-        assert h_scan[0]["train_loss"] == pytest.approx(
-            h_step[0]["train_loss"], rel=1e-5)
-        assert h_scan[0]["val"]["mae"] == pytest.approx(
-            h_step[0]["val"]["mae"], rel=1e-5)
-        assert np.isfinite(h_scan[1]["train_loss"])
+        h_step, n_step = run(device_resident=True)
+        h_scan, n_scan = run(scan_epochs=True)
+        assert n_scan == n_step and n_step[0][0] > 0
+        if buckets == 1:
+            assert h_scan[0]["train_loss"] == pytest.approx(
+                h_step[0]["train_loss"], rel=1e-5)
+            assert h_scan[0]["val"]["mae"] == pytest.approx(
+                h_step[0]["val"]["mae"], rel=1e-5)
+        for h in (h_step, h_scan):
+            assert np.isfinite(h[-1]["train_loss"])
+            assert h[-1]["train_loss"] < h[0]["train_loss"]
 
-    def test_graph_shards_reject_unsupported_flags(self, setup):
-        """Scan-epochs composes with graph shards since r5; per-step
-        profiling remains the one composition the scan cannot provide."""
+
+def _two_axis_mesh(names):
+    return jax.sharding.Mesh(
+        np.array(jax.devices()[:4]).reshape(2, 2), names)
+
+
+class TestRefusals:
+    """What the data-parallel layer refuses, by name and before it runs."""
+
+    @pytest.mark.parametrize("entry", ["train_step", "eval_step", "fit"])
+    def test_mesh_with_unknown_axis_is_refused(self, setup, entry):
+        """A mesh comes from outside: an axis other than 'data' / 'dcn'
+        is named in a ValueError, not folded into the replica count."""
         from cgnn_tpu.parallel import fit_data_parallel
-        from cgnn_tpu.parallel.mesh import make_2d_mesh
 
         graphs, batch, model, state, (node_cap, edge_cap) = setup
-        with pytest.raises(NotImplementedError, match="profile"):
-            fit_data_parallel(
-                state, graphs, graphs[:8], epochs=1, batch_size=2,
-                node_cap=node_cap, edge_cap=edge_cap,
-                mesh=make_2d_mesh(2, data_shards=2), profile_steps=4,
-            )
+        mesh = _two_axis_mesh(("data", "graph"))
+        with pytest.raises(ValueError, match="mesh axis 'graph'"):
+            if entry == "train_step":
+                make_parallel_train_step(mesh)
+            elif entry == "eval_step":
+                make_parallel_eval_step(mesh)
+            else:
+                fit_data_parallel(
+                    state, graphs, graphs[:8], epochs=1, batch_size=2,
+                    node_cap=node_cap, edge_cap=edge_cap, mesh=mesh,
+                )
+
+    @pytest.mark.parametrize("case", [
+        "compact_without_scan", "compact_without_dense", "custom_step_dcn"])
+    def test_fit_dp_standing_refusals(self, setup, case):
+        from cgnn_tpu.data.compact import CompactSpec
+        from cgnn_tpu.parallel import fit_data_parallel
+
+        graphs, batch, model, state, (node_cap, edge_cap) = setup
+        kw = dict(epochs=1, batch_size=2, node_cap=node_cap,
+                  edge_cap=edge_cap, mesh=make_mesh(4))
+        if case == "custom_step_dcn":
+            # a custom body was built with axis_name='data' alone: on a
+            # hierarchical mesh its gradient would skip the 'dcn' reduce
+            kw |= dict(mesh=_two_axis_mesh(("dcn", "data")),
+                       train_step_fn=make_train_step(axis_name="data"))
+            raises = pytest.raises(NotImplementedError,
+                                   match="custom step bodies")
+        else:
+            spec = CompactSpec.build(
+                graphs, FeaturizeConfig(radius=5.0, max_num_nbr=8).gdf(),
+                dense_m=8)
+            kw |= dict(compact=spec)
+            kw |= (dict(dense_m=8) if case == "compact_without_scan"
+                   else dict(scan_epochs=True))
+            raises = pytest.raises(ValueError, match="compact staging")
+        with raises:
+            fit_data_parallel(state, graphs, graphs[:8], **kw)

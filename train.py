@@ -218,11 +218,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "ICI); --batch-size is then per device, and the "
                         "staged form, scan driver, guard and --chunk-steps "
                         "are the one-chip path's")
-    p.add_argument("--graph-shards", type=int, default=1, metavar="G",
-                   help="shard every batch's edge axis over a G-way 'graph' "
-                        "mesh axis (edge-sharded message passing — the "
-                        "long-context analog for graphs too large for one "
-                        "chip; composes with --data-parallel as a 2-D mesh)")
     p.add_argument("--bf16", action="store_true",
                    help="bfloat16 compute on the MXU (f32 params/stats)")
     p.add_argument("--compact-staging", choices=["auto", "on", "off"],
@@ -233,15 +228,14 @@ def build_parser() -> argparse.ArgumentParser:
                         "(data/compact.py). Requires --scan-epochs + dense "
                         "layout and an energy/classification task; on one "
                         "device and under --data-parallel alike (each "
-                        "device expands its own rows), not with "
-                        "--graph-shards. auto = on when supported")
+                        "device expands its own rows). auto = on when "
+                        "supported")
     p.add_argument("--compile-cache", type=str, default=None,
                    metavar="DIR", help=COMPILE_CACHE_HELP)
     p.add_argument("--layout", choices=["dense", "coo"], default="dense",
                    help="edge batch layout: 'dense' (node-major slots, "
-                        "scatter-free aggregation — ~2x faster on TPU; "
-                        "composes with --graph-shards via node-strip "
-                        "sharding) or 'coo' (flat edge list)")
+                        "scatter-free aggregation — ~2x faster on TPU) "
+                        "or 'coo' (flat edge list)")
     return p
 
 
@@ -356,7 +350,7 @@ def main(argv=None) -> int:
     if (args.device_resident and not args.no_scan_epochs
             and not args.profile):
         # scan dispatch is the device-resident default since r3 (see
-        # --scan-epochs help; composes with --graph-shards since r5);
+        # --scan-epochs help);
         # --no-scan-epochs restores the per-step loop. Not auto-applied
         # for per-step profiling, which scan cannot provide — that keeps
         # the per-step loop rather than erroring on a flag the user
@@ -498,7 +492,7 @@ def main(argv=None) -> int:
     # default for every task incl. force (gather_slot_major is declared
     # linear, so the second-order force differentiation composes; parity is
     # pinned to training-step gradients, tests/test_forces.py). The flat COO
-    # layout remains for edge-sharded meshes.
+    # layout is the reference the tests hold it to (--layout coo).
     use_dense = args.layout == "dense"
     dense_m = args.max_num_nbr if use_dense else 0
 
@@ -510,21 +504,6 @@ def main(argv=None) -> int:
         multi_task_head=args.multi_task_head, dense_m=dense_m,
         node_norm=args.node_norm, pool_softplus=not args.no_pool_softplus,
     )
-    graph_shards = max(1, args.graph_shards)
-    if graph_shards > 1:
-        if force_task:
-            print("--graph-shards is not supported for --task force",
-                  file=sys.stderr)
-            return 2
-        if len(devices) < graph_shards:
-            print(f"--graph-shards {graph_shards} requested but only "
-                  f"{len(devices)} device(s) visible", file=sys.stderr)
-            return 2
-        if args.data_parallel and len(devices) % graph_shards:
-            stranded = len(devices) % graph_shards
-            print(f"warning: {len(devices)} devices not divisible by "
-                  f"--graph-shards {graph_shards}; {stranded} device(s) "
-                  f"idle", file=sys.stderr)
     model = build_model(model_cfg, data_cfg, args.task, log_fn=print)
 
     if classification:
@@ -668,11 +647,7 @@ def main(argv=None) -> int:
     telemetry.write_manifest(
         vars(args),
         task=args.task,
-        mesh_shape={
-            "data": (len(devices) // graph_shards
-                     if args.data_parallel else 1),
-            "graph": graph_shards,
-        },
+        mesh_shape={"data": len(devices) if args.data_parallel else 1},
     )
     log_epoch_metrics = telemetry.write_epoch
 
@@ -728,42 +703,12 @@ def main(argv=None) -> int:
                       f"staging", file=sys.stderr)
         return 0
 
-    if graph_shards > 1 or (args.data_parallel and len(devices) > 1):
-        if graph_shards > 1 and args.compact_staging == "on":
-            print("--compact-staging on is not supported with "
-                  "--graph-shards (full staging only); drop the flag or "
-                  "use auto", file=sys.stderr)
-            return 2
-        if graph_shards == 1:
-            rc = choose_compact()
-            if rc:
-                return rc
+    if args.data_parallel and len(devices) > 1:
+        rc = choose_compact()
+        if rc:
+            return rc
         from cgnn_tpu.parallel import fit_data_parallel
-        from cgnn_tpu.parallel.mesh import make_2d_mesh
 
-        mesh = None
-        fit_state = state
-        if graph_shards > 1 and args.profile:
-            print("--profile is not supported with --graph-shards "
-                  "(edge-sharded meshes)", file=sys.stderr)
-            return 2
-        if graph_shards > 1 and args.buckets > 1 and not use_dense:
-            print("--buckets with --graph-shards requires the dense layout "
-                  "(drop --layout coo)", file=sys.stderr)
-            return 2
-        if graph_shards > 1:
-            # edge-sharded model: same params, psum over 'graph' per conv;
-            # the plain `state` keeps the single-device apply_fn for the
-            # final test evaluation and checkpointing
-            sharded_model = build_model(
-                model_cfg, data_cfg, args.task, edge_axis_name="graph"
-            )
-            fit_state = state.replace(apply_fn=sharded_model.apply)
-            mesh = make_2d_mesh(
-                graph_shards,
-                data_shards=(len(devices) // graph_shards
-                             if args.data_parallel else 1),
-            )
         if force_task:
             step_overrides |= {
                 "train_step_fn": make_force_train_step(
@@ -782,13 +727,13 @@ def main(argv=None) -> int:
                 "eval_step_fn": make_eval_step(axis_name="data",
                                                loss_fn=loss_fn),
             }
-        fit_state, result = fit_data_parallel(
-            fit_state, train_g, val_g, epochs=args.epochs,
+        state, result = fit_data_parallel(
+            state, train_g, val_g, epochs=args.epochs,
             batch_size=args.batch_size,
             node_cap=node_cap, edge_cap=edge_cap, classification=classification,
             seed=args.seed, print_freq=args.print_freq,
             on_epoch_end=save_cb, start_epoch=start_epoch,
-            on_epoch_metrics=log_epoch_metrics, mesh=mesh,
+            on_epoch_metrics=log_epoch_metrics,
             pack_once=args.pack_once, device_resident=args.device_resident,
             dense_m=layout_m, buckets=args.buckets, snug=snug,
             scan_epochs=args.scan_epochs, profile_steps=args.profile,
@@ -796,7 +741,6 @@ def main(argv=None) -> int:
             chunk_steps=args.chunk_steps, telemetry=telemetry,
             **resilience_kw, **step_overrides,
         )
-        state = fit_state.replace(apply_fn=state.apply_fn)
         if dist.active():
             # post-fit the state is replicated over the GLOBAL mesh;
             # pull host-local copies so the single-device test eval and
