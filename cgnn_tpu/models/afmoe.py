@@ -19,12 +19,10 @@ sqrt(H)`` under ``mup_enabled``):
     x += RMSNorm(m)
 
 The stack is the leading dense layers, then the expert layers in periods of
-``layer_types`` (window, window, window, full as published). The dense
-layers are one stack that is scanned; the periods are scanned, and inside a
-period each run of layers of one kind is a scan of its own (``periods/run0``
-the three window layers, ``periods/run1`` the full one, each leaf ``[periods,
-layers of the run, ...]``): a layer of each kind is all the program text
-there is, and a deeper stage is a longer leading axis. Within a layer the
+``layer_types`` (window, window, window, full as published), scanned as
+``lm_blocks.Stack`` has it (``lm_blocks.scan_stack``, which models/lfm2.py
+shares: ``periods/run0`` the three window layers, ``periods/run1`` the full
+one, each leaf ``[periods, layers of the run, ...]``). Within a layer the
 step's sequences go one at a time, a ``jax.checkpoint`` a layer and sequence
 (``lm_blocks.by_sequence``) that keeps the layer's input, its attention's
 output and the routed experts' output (neither the attention's forward
@@ -43,8 +41,6 @@ held); the loss is next-token cross-entropy over the slice.
 from __future__ import annotations
 
 import dataclasses
-import functools
-import itertools
 import math
 
 import jax
@@ -70,7 +66,7 @@ OUTPUT_PROJECTIONS = ("wo", "w_down", "mlp_down", "shared_down")
 
 
 @dataclasses.dataclass(frozen=True)
-class AfmoeConfig:
+class AfmoeConfig(lm_blocks.Stack):
     # what train/lm_step.py makes of a batch (no field: the model's own)
     objective = "causal"
 
@@ -103,41 +99,7 @@ class AfmoeConfig:
     moe_impl: str = "auto"
 
     def __post_init__(self):
-        types = tuple(self.layer_types)
-        object.__setattr__(self, "layer_types", types)
-        if len(types) != self.num_hidden_layers \
-                or set(types) - {SLIDING, FULL}:
-            raise ValueError(f"layer_types {types} do not name "
-                             f"{self.num_hidden_layers} layers")
-        if len(set(types[:self.num_dense_layers])) > 1:
-            raise ValueError("the leading dense layers are one scanned "
-                             "stack: they have to be of one kind")
-        if not 0 <= self.num_dense_layers < self.num_hidden_layers:
-            raise ValueError("at least one expert layer")
-
-    @property
-    def period(self) -> tuple:
-        """The expert layers' kinds, one period of them."""
-        types = self.layer_types[self.num_dense_layers:]
-        for n in range(1, len(types) + 1):
-            if len(types) % n == 0 and types == types[:n] * (len(types) // n):
-                return types[:n]
-        raise AssertionError
-
-    @property
-    def runs(self) -> tuple:
-        """A period as runs of layers of one kind: ((kind, layers), ..)."""
-        return tuple((kind, len(list(run)))
-                     for kind, run in itertools.groupby(self.period))
-
-    @property
-    def n_periods(self) -> int:
-        return ((self.num_hidden_layers - self.num_dense_layers)
-                // len(self.period))
-
-    @property
-    def n_expert_layers(self) -> int:
-        return self.num_hidden_layers - self.num_dense_layers
+        self.check_stack((SLIDING, FULL))
 
     @property
     def compute_dtype(self):
@@ -159,7 +121,7 @@ class AfmoeConfig:
     def shapes(self) -> dict:
         """The parameter tree's shapes, float32 all."""
         h, i = self.hidden_size, self.moe_intermediate_size
-        nd, e = self.num_dense_layers, self.experts_held[1]
+        e = self.experts_held[1]
         shared = i * self.num_shared_experts
         expert_layer = {
             **self._attention_shapes(), "router": (h, self.n_experts),
@@ -169,24 +131,13 @@ class AfmoeConfig:
             **self._attention_shapes(),
             "mlp_gate_up": (h, 2 * self.intermediate_size),
             "mlp_down": (self.intermediate_size, h)}
-
-        def stacked(layer: dict, *leading) -> dict:
-            return {k: (*leading, *v) for k, v in layer.items()}
-
-        tree = {
+        return {
             "embed": (self.vocab_size, h),
-            "periods": {f"run{j}": stacked(expert_layer, self.n_periods, n)
-                        for j, (_, n) in enumerate(self.runs)},
+            **lm_blocks.stack_shapes(self, lambda kind: dense_layer,
+                                     lambda kind: expert_layer),
             "final_norm": (h,),
             "head": (h, self.vocab_size),
         }
-        if nd:
-            tree["dense"] = stacked(dense_layer, nd)
-        return tree
-
-    def n_params(self) -> int:
-        return sum(math.prod(s) for s in jax.tree_util.tree_leaves(
-            self.shapes(), is_leaf=lambda x: isinstance(x, tuple)))
 
     def stats_shapes(self) -> dict:
         """``batch_stats``: the selection biases, float32."""
@@ -283,44 +234,18 @@ def hidden_states(cfg: AfmoeConfig, params, router_bias, tokens,
             x = x * math.sqrt(cfg.hidden_size)
         x = x.astype(cfg.compute_dtype)
 
-    def dense_step(x, p):
-        # the casts to the compute dtype stay inside the layer: hoisted out
-        # of the scan they are a second copy of every layer's weights
-        p = jax.lax.optimization_barrier(p)
-        x, = by_sequence(
-            lambda x_seq, seg: _dense_layer(cfg, cfg.layer_types[0], x_seq,
-                                            p, seg), x, segment_ids)
-        return x, None
+    def dense_layer(kind, x, p):
+        return by_sequence(
+            lambda x_seq, seg: _dense_layer(cfg, kind, x_seq, p, seg), x,
+            segment_ids)[0]
 
-    def expert_step(kind, x, layer):
-        p, bias = jax.lax.optimization_barrier(layer)
-        x, sizes, rungs = by_sequence(
+    def expert_layer(kind, x, p, bias):
+        return by_sequence(
             lambda x_seq, seg: _expert_layer(cfg, kind, x_seq, p, bias, seg),
             x, segment_ids, keep=(ROUTED,))
-        return x, (sizes.sum(axis=0), rungs)
 
-    def period_step(x, period):
-        p, bias = period
-        routed, at = [], 0
-        for j, (kind, n) in enumerate(cfg.runs):
-            x, of_run = jax.lax.scan(
-                functools.partial(expert_step, kind), x,
-                (p[f"run{j}"], bias[at:at + n]))
-            routed.append(of_run)
-            at += n
-        return x, tuple(jnp.concatenate(parts) for parts in zip(*routed))
-
-    # the loops' own machinery (a layer's input and what its checkpoint
-    # keeps stacked for the reverse pass, the gradients stacked and summed
-    # over the sequences) is phase ``scan``; a layer's operations have
-    # their own
-    with jax.named_scope(phases.SCAN):
-        if cfg.num_dense_layers:
-            x, _ = jax.lax.scan(dense_step, x, params["dense"])
-        x, (group_sizes, rungs) = jax.lax.scan(
-            period_step, x, (params["periods"], router_bias))
-    return (x, group_sizes.reshape(-1, cfg.n_experts),
-            rungs.reshape(cfg.n_expert_layers, -1))
+    return lm_blocks.scan_stack(cfg, x, params, router_bias, dense_layer,
+                                expert_layer)
 
 
 def apply(cfg: AfmoeConfig, variables: dict, batch, train: bool = True):

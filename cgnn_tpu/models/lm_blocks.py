@@ -1,17 +1,21 @@
-"""What the decoders of this repo share (models/sdar.py, models/afmoe.py):
-the float32 norm and RoPE, the one op that makes a projection's output the
-attention's operand, the initialiser, the plan that keeps a layer's input
-and its attention's output for the reverse pass, and the head over the
-vocabulary slice a chunk at a time.
+"""What the decoders of this repo share (models/sdar.py, models/afmoe.py,
+models/lfm2.py): the float32 norm and RoPE, the one op that makes a
+projection's output the attention's operand, the initialiser, the plan that
+keeps a layer's input and its attention's output for the reverse pass, the
+stack whose layers differ in kind (``Stack``, ``stack_shapes``,
+``scan_stack``), and the head over the vocabulary slice a chunk at a time.
 """
 
 from __future__ import annotations
 
 import functools
+import itertools
+import math
 
 import jax
 import jax.numpy as jnp
 
+from cgnn_tpu.observe import phases
 from cgnn_tpu.ops import prepare_heads as fused
 from cgnn_tpu.ops.masked_attention import KEPT
 
@@ -103,6 +107,133 @@ def by_sequence(layer, x, segment_ids, keep=()):
         return (out[0], *aux)
 
     return jax.lax.map(one, (x, segment_ids))
+
+
+# the kind of a layer that mixes by ops/short_conv.py; every other kind of
+# ``layer_types`` is an attention under some mask
+CONV = "conv"
+
+
+class Stack:
+    """A stack of leading dense layers and then expert layers in periods of
+    ``layer_types``, for a frozen config dataclass with the fields
+    ``layer_types``, ``num_hidden_layers``, ``num_dense_layers`` and
+    ``shapes()``: the dense layers are one stack that is scanned; the
+    periods are scanned, and inside a period each run of layers of one kind
+    is a scan of its own (``periods/run<j>``, each leaf ``[periods, layers
+    of the run, ...]``): a layer of each kind is all the program text there
+    is, and a deeper stage is a longer leading axis."""
+
+    def check_stack(self, kinds: tuple) -> None:
+        """In ``__post_init__``: ``layer_types`` a tuple of ``kinds``."""
+        types = tuple(self.layer_types)
+        object.__setattr__(self, "layer_types", types)
+        if len(types) != self.num_hidden_layers or set(types) - set(kinds):
+            raise ValueError(f"layer_types {types} do not name "
+                             f"{self.num_hidden_layers} layers")
+        if len(set(types[:self.num_dense_layers])) > 1:
+            raise ValueError("the leading dense layers are one scanned "
+                             "stack: they have to be of one kind")
+        if not 0 <= self.num_dense_layers < self.num_hidden_layers:
+            raise ValueError("at least one expert layer")
+
+    @property
+    def period(self) -> tuple:
+        """The expert layers' kinds, one period of them."""
+        types = self.layer_types[self.num_dense_layers:]
+        for n in range(1, len(types) + 1):
+            if len(types) % n == 0 and types == types[:n] * (len(types) // n):
+                return types[:n]
+        raise AssertionError
+
+    @property
+    def runs(self) -> tuple:
+        """A period as runs of layers of one kind: ((kind, layers), ..)."""
+        return tuple((kind, len(list(run)))
+                     for kind, run in itertools.groupby(self.period))
+
+    @property
+    def n_periods(self) -> int:
+        return self.n_expert_layers // len(self.period)
+
+    @property
+    def n_expert_layers(self) -> int:
+        return self.num_hidden_layers - self.num_dense_layers
+
+    @property
+    def n_conv_layers(self) -> int:
+        """Layers whose mixer is the short convolution (``CONV``)."""
+        return self.layer_types.count(CONV)
+
+    @property
+    def n_attention_layers(self) -> int:
+        """Layers whose mixer is an attention of any mask."""
+        return self.num_hidden_layers - self.n_conv_layers
+
+    def n_params(self) -> int:
+        return sum(math.prod(s) for s in jax.tree_util.tree_leaves(
+            self.shapes(), is_leaf=lambda x: isinstance(x, tuple)))
+
+
+def stack_shapes(cfg: Stack, dense_layer, expert_layer) -> dict:
+    """The stack's part of a parameter tree's shapes: ``dense_layer(kind)``
+    and ``expert_layer(kind)`` give one layer's ({leaf: shape}) ->
+    {``periods``: {``run<j>``: ..}, ``dense``: .. where there is one}."""
+    def stacked(layer: dict, *leading) -> dict:
+        return {k: (*leading, *v) for k, v in layer.items()}
+
+    tree = {"periods": {
+        f"run{j}": stacked(expert_layer(kind), cfg.n_periods, n)
+        for j, (kind, n) in enumerate(cfg.runs)}}
+    if cfg.num_dense_layers:
+        tree["dense"] = stacked(dense_layer(cfg.layer_types[0]),
+                                cfg.num_dense_layers)
+    return tree
+
+
+def scan_stack(cfg: Stack, x, params, router_bias, dense_layer,
+               expert_layer):
+    """``x [S, L, H]`` through the stack (``Stack``): ``dense_layer(kind,
+    x, p) -> x`` and ``expert_layer(kind, x, p, bias [E]) -> (x,
+    group_sizes [S, E], rungs [S])`` over all the step's sequences (each
+    calls ``by_sequence`` with what its checkpoint keeps); ``params`` the
+    tree of ``stack_shapes``, ``router_bias [periods, layers a period, E]``.
+    -> (``x``, ``group_sizes [expert layers, E]``, ``rungs [expert layers,
+    S]``: the rung of each expert layer's and sequence's call,
+    ops/moe.py)."""
+    def dense_step(x, p):
+        # the casts to the compute dtype stay inside the layer: hoisted out
+        # of the scan they are a second copy of every layer's weights
+        p = jax.lax.optimization_barrier(p)
+        return dense_layer(cfg.layer_types[0], x, p), None
+
+    def expert_step(kind, x, layer):
+        p, bias = jax.lax.optimization_barrier(layer)
+        x, sizes, rungs = expert_layer(kind, x, p, bias)
+        return x, (sizes.sum(axis=0), rungs)
+
+    def period_step(x, period):
+        p, bias = period
+        routed, at = [], 0
+        for j, (kind, n) in enumerate(cfg.runs):
+            x, of_run = jax.lax.scan(
+                functools.partial(expert_step, kind), x,
+                (p[f"run{j}"], bias[at:at + n]))
+            routed.append(of_run)
+            at += n
+        return x, tuple(jnp.concatenate(parts) for parts in zip(*routed))
+
+    # the loops' own machinery (a layer's input and what its checkpoint
+    # keeps stacked for the reverse pass, the gradients stacked and summed
+    # over the sequences) is phase ``scan``; a layer's operations have
+    # their own
+    with jax.named_scope(phases.SCAN):
+        if cfg.num_dense_layers:
+            x, _ = jax.lax.scan(dense_step, x, params["dense"])
+        x, (group_sizes, rungs) = jax.lax.scan(
+            period_step, x, (params["periods"], router_bias))
+    return (x, group_sizes.reshape(cfg.n_expert_layers, -1),
+            rungs.reshape(cfg.n_expert_layers, -1))
 
 
 # positions whose logits are held at once

@@ -70,6 +70,13 @@ class SdarConfig:
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
 
+    # every layer mixes by attention (what train/lm_step.py counts by)
+    n_conv_layers = 0
+
+    @property
+    def n_attention_layers(self) -> int:
+        return self.num_hidden_layers
+
     def shapes(self) -> dict:
         """The parameter tree's shapes, float32 all."""
         h, d, n = self.hidden_size, self.head_dim, self.num_hidden_layers

@@ -7,8 +7,8 @@ stack — flax module scopes (``conv_1/bn1``), transforms (``jvp``,
 ``transpose``) and the ``jax.named_scope`` s the program adds where flax says
 nothing (models/cgcnn.py, models/forcefield.py, train/step.py,
 train/force_step.py, resilience/guard.py, data/compact.py, train/loop.py,
-models/sdar.py, models/afmoe.py, ops/moe.py, ops/prepare_heads.py,
-train/lm_step.py). ``classify`` maps such a path to one phase
+models/sdar.py, models/afmoe.py, models/lfm2.py, models/lm_blocks.py,
+ops/moe.py, ops/prepare_heads.py, train/lm_step.py). ``classify`` maps such a path to one phase
 of ``PHASES`` and a direction; ``phase_table`` does so for every instruction
 of an optimized HLO module that the device can report as an event.
 
@@ -67,13 +67,21 @@ ATTN_WINDOW = "attn.window"
 ATTN_FULL = "attn.full"
 MLP_DENSE = "mlp.dense"
 MOE_SHARED = "moe.shared"
+# models/lfm2.py: the hybrid decoder's convolution layers. ``sconv.proj`` is
+# the layer's first norm, ``W_in`` and ``W_out``; ``sconv.mix`` the gates and
+# the three taps alone (ops/short_conv.py). Its attention layer is under
+# ``attn.proj`` / ``attn.full``, its dense MLP under ``mlp.dense``, its
+# experts under ``moe.route`` (the layer's second norm and the residual sum
+# too) and ``moe.expert``
+SCONV_PROJ = "sconv.proj"
+SCONV_MIX = "sconv.mix"
 OTHER = "other"
 
 PHASES = (EXPAND, EMBED, EDGE_GEOM, CONV_GATHER, CONV_FC_FULL, CONV_BN1,
           CONV_GATE, CONV_AGGREGATE, CONV_BN2, CONV_LN, POOL_HEAD,
           FORCE_READOUT, LOSS, OPTIMIZER, SCAN, DP_ALLREDUCE, LM_EMBED,
           ATTN_PROJ, ATTN_BD, MOE_ROUTE, MOE_EXPERT, LM_HEAD, ATTN_WINDOW,
-          ATTN_FULL, MLP_DENSE, MOE_SHARED, OTHER)
+          ATTN_FULL, MLP_DENSE, MOE_SHARED, SCONV_PROJ, SCONV_MIX, OTHER)
 FWD, BWD, BWD2 = "fwd", "bwd", "bwd2"
 
 # a path component -> its phase: the named scopes themselves, and the flax

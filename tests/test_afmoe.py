@@ -583,6 +583,24 @@ def test_what_a_step_counts_of_the_kept_bytes():
     assert float(m["attn_kept_bytes_sum"]) == 7 * 2 * 4 * L * (16 * 4 + 4)
 
 
+def test_the_step_lowers_to_the_text_it_had_before_the_stack_moved():
+    """PR 49 moved the stack's scans out of models/afmoe.py into
+    ``lm_blocks.scan_stack``, which models/lfm2.py shares: the step of this
+    file's model lowers to the text PR 48's did (StableHLO without
+    locations, under the suite's x64 and eight CPU devices), so no number
+    of ``trinity.train`` moved with it. A change meant to reach this
+    model's program fails here: measure ``trinity.train`` with it, then
+    pin the new text."""
+    import hashlib
+
+    batch = tokens.split_batches(_pool(0), 2)[0]
+    step = make_lm_train_step(CFG, afmoe.attention_tiles(CFG, L))
+    text = jax.jit(step).lower(_state(_params(0), _bias(0)), batch).as_text()
+    assert len(text.splitlines()) == 9686
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "baccc1cc351065c547f6a26a59210027f481f17f6177b9ba88782fbbf569f14e")
+
+
 def test_parameter_count_and_the_stack():
     real = afmoe.AfmoeConfig()
     # ISSUE 45's table: 65.0 M dense + 4 x 84.1 M + 102.5 M = 504.1 M

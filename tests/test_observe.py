@@ -576,6 +576,15 @@ _CLASSIFIED = [
     # the held experts' weight casts are the expert phase's there
     (_LM + "jvp(while)/body/while/body/while/body/checkpoint/moe.expert/"
      "convert_element_type", ("moe.expert", "fwd")),
+    # models/lfm2.py: the convolution layers' projections and their taps
+    (_LM + "jvp(while)/body/while/body/while/body/checkpoint/sconv.proj/"
+     "dot_general", ("sconv.proj", "fwd")),
+    (_LM + "transpose(jvp(while))/body/while/body/while/body/checkpoint/"
+     "rematted_computation/sconv.proj/dot_general", ("sconv.proj", "bwd")),
+    (_LM + "jvp(while)/body/while/body/while/body/checkpoint/sconv.mix/"
+     "mul", ("sconv.mix", "fwd")),
+    (_LM + "transpose(jvp(while))/body/while/body/while/body/checkpoint/"
+     "sconv.mix/select_n", ("sconv.mix", "bwd")),
     ("jit(train_step)/add", ("other", "fwd")),
     (_SCAN + "mul", ("other", "fwd")),
     ("reduce_sum", ("other", "fwd")),
@@ -601,7 +610,7 @@ class TestPhases:
                    "pool_head", "loss", "edge_geom", "force_readout",
                    "lm.embed", "attn.proj", "attn.bd", "moe.route",
                    "moe.expert", "lm.head", "attn.window", "attn.full",
-                   "mlp.dense", "moe.shared"}
+                   "mlp.dense", "moe.shared", "sconv.proj", "sconv.mix"}
         assert {p for p, d in seen if d == "bwd"} == two_way
         # what the force step differentiates twice: the trunk without
         # BatchNorm, the geometry and the readout (not the embedding, which

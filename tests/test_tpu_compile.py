@@ -898,3 +898,106 @@ def test_trinity_scan_program_at_real_size_fits_the_chip(one_chip,
     # a switch forward and one in the reverse pass, each run of the period
     assert len(switches) == 2 * len(mc.runs) == 4, len(switches)
 
+
+
+def test_lfm2_scan_program_at_real_size_fits_the_chip(one_chip,
+                                                      no_compile_cache):
+    """``lfm2.train``'s window program (a chunk of two steps of the hybrid
+    short-convolution / attention decoder at the cell's real size: 1 dense
+    + 4 expert layers, 469.3 M parameters, 2 sequences of 8,192 tokens a
+    step) compiles for the described chip with its kernels in it (splash at
+    64 lanes a head under the causal mask, megablox at the experts' width of
+    1,536) and fits the chip's 15.75 GB by the compile's memory analysis,
+    the state's 5.63 GB aliased in place. It never holds a ``[*, 8192,
+    8192]`` score array. The one attention layer is one call site of the
+    splash kernels (its checkpoint keeps the output, so the forward kernel
+    runs once); the expert layers' switch over their three rungs is there
+    forward and reverse, each run of the period. At 64 lanes q and k take
+    ``lm_blocks.prepare_heads``'s composition: its kernel wants whole
+    128-lane tiles a head."""
+    import dataclasses
+    import json
+    import os
+
+    from benchmark.kinds import lfm2_train
+    from benchmark.weights import seed_key
+    from benchmark.weights_lfm2 import StateMaker
+    from cgnn_tpu.data import tokens
+    from cgnn_tpu.models import lfm2, lm_blocks
+    from cgnn_tpu.observe import phases
+    from cgnn_tpu.ops import moe
+    from cgnn_tpu.train import lm_step, make_optimizer
+    from cgnn_tpu.train.loop import ScanEpochDriver
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "lfm2-24b-a2b-ep8.json")) as f:
+        cfg = json.load(f)
+    # the described chip is not the default backend: name the kernels
+    mc = dataclasses.replace(lfm2_train.model_config(cfg),
+                             attn_impl="splash", moe_impl="megablox")
+    tr, length = cfg["train"], int(cfg["data"]["sequence_length"])
+    assert not lm_blocks.fused.supported(length, mc.head_dim)
+    tx = make_optimizer(optim="adamw", lr=tr["lr"], b1=tr["b1"], b2=tr["b2"],
+                        weight_decay=tr["weight_decay"], lr_milestones=[])
+    maker = StateMaker(mc, cfg["init"], tx,
+                       functools.partial(lfm2.apply, mc))
+    state = jax.eval_shape(maker._build, seed_key(1), jnp.float32(0.01))
+    assert sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(
+        state.params)) == mc.n_params() == 469_284_992
+    assert state.batch_stats["router_bias"].shape == (1, 4, 64)
+    batches = tokens.split_batches(
+        tokens.make_pool(8, length, vocab_size=mc.vocab_size, seed=0,
+                         kind="causal"), int(tr["batch_size"]))
+    tiles = lfm2.attention_tiles(mc, length)
+    assert tiles == {"full": (136, 256, 1)}
+    driver = ScanEpochDriver(
+        lm_step.make_lm_train_step(mc, tiles),
+        lm_step.make_lm_eval_step(mc, tiles), batches, [],
+        np.random.default_rng(0), chunk_steps=2)
+    (key, stacked), = driver._train_groups.items()
+    fn = driver._window_fn(driver._train_scans, (key, 2),
+                           driver._train_body, True)
+    assert fn.__name__ == "scan_train_n16384_l2"
+    shapes = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                       sharding=one_chip),
+        (state, stacked, np.zeros(len(batches), np.int32),
+         np.zeros((), np.int32)))
+    # the suite runs under jax_enable_x64 (conftest.py), which no entry
+    # point sets and under which the kernels' lowering never ends
+    with jax.enable_x64(False):
+        compiled = fn.lower(*shapes).compile()
+    mem = compiled.memory_analysis()
+    on_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"lfm2.train's window program: state "
+          f"{mem.alias_size_in_bytes / 1e9:.2f} GB aliased, temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.2f} GB, {on_chip / 1e9:.2f} GB "
+          f"on the chip")
+    assert mem.alias_size_in_bytes > 5.6e9  # the state is updated in place
+    assert 8e9 < on_chip < 15.75e9, on_chip
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 5  # splash and megablox
+    assert not re.search(r"\[(?:\d+,)*8192,8192\]", text)
+    splash = {k: _kernel_calls(text, f"splash_mqa_{k}")
+              for k in ("fwd", "dq", "dkv")}
+    print(f"splash kernels: {splash}")
+    assert splash == {"fwd": 1, "dq": 1, "dkv": 1}, splash
+    # the composition, at 64 lanes: no kernel of ops/prepare_heads.py
+    assert _kernel_calls(text, "prepare_heads_") == 0
+    pairs = length * mc.num_experts_per_tok
+    assert moe.ladder(pairs, 8, 64) == (8192, 16384, 32768)
+    table = phases.phase_table(text)
+    seen = {phase for phase, _ in table.values()}
+    assert {"sconv.proj", "sconv.mix", "attn.proj", "attn.full",
+            "mlp.dense", "moe.route", "moe.expert", "lm.embed", "lm.head",
+            "optimizer", "scan"} <= seen, seen
+    assert {("sconv.mix", "fwd"), ("sconv.mix", "bwd")} <= set(
+        table.values())
+    switches = [rest for comp in phases._parse(text).values()
+                for rest in comp["instrs"].values()
+                if re.search(r"\sconditional\(", rest)]
+    print(f"{len(switches)} conditionals")
+    # a switch forward and one in the reverse pass, each run of the period
+    assert len(switches) == 2 * len(mc.runs) == 4, len(switches)

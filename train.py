@@ -41,14 +41,17 @@ def build_parser() -> argparse.ArgumentParser:
                    help="'force' trains the differentiable force field on "
                         "energy+force labels (BASELINE config #5); "
                         "'blockdiff' the block-diffusion mixture-of-experts "
-                        "decoder on packed token sequences, 'lm' the "
-                        "window-and-full-attention mixture-of-experts "
-                        "decoder on next-token prediction (both "
+                        "decoder on packed token sequences, 'lm' a "
+                        "mixture-of-experts decoder on next-token "
+                        "prediction, window-and-full-attention or hybrid "
+                        "short-convolution by --lm-model (all "
                         "cgnn_tpu/train/blockdiff.py)")
     p.add_argument("--lm-model", default="tiny",
-                   help="the task's preset (--task blockdiff: tiny | "
-                        "sdar-ep8; --task lm: tiny | trinity-mini-ep16) or "
-                        "a JSON file of the fields of its config dataclass "
+                   help="the task's preset, which names its model too "
+                        "(--task blockdiff: tiny | sdar-ep8; --task lm: "
+                        "tiny | trinity-mini-ep16 | lfm2-tiny | "
+                        "lfm2-24b-a2b-ep8) or a JSON file of the fields of "
+                        "the task's config dataclass "
                         "(models.sdar.SdarConfig; models.afmoe.AfmoeConfig)")
     p.add_argument("--lm-seq-len", type=int, default=64,
                    help="--task blockdiff | lm: tokens a packed sequence "
