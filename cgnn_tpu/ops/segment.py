@@ -8,14 +8,24 @@ gather through the packer's mapping; the sum over M needs no op of its
 own; the rows it moves are the nodes' fc_full projections, 2F wide, and
 the force model's positions: models/cgcnn.py _SplitFcFull) and the
 flat COO conv through ``gather`` and ``aggregate_edge_messages``, a
-``segment_sum`` over the sorted centres the packers emit. XLA compiles
-all of it; there is no hand-written kernel.
+``jax.ops.segment_sum`` over the sorted centres the packers emit: the one
+XLA scatter left in this file, which no dense batch and no cell runs.
+
+The reductions over a batch's graphs live here too: ``segment_mean`` (the
+per-crystal pooling, models/cgcnn.py) and ``segment_sum`` (the force
+model's per-frame energy) are a matmul by the 0/1 matrix of the nodes'
+graphs (``_segment_totals``), with a row gather as its declared transpose.
+They were the step's last two scatters, ~10 ns a row to add ~24,000 rows
+of 64 numbers into ~500 (PERF.md section 6, PR 50).
+
+XLA compiles all of it; there is no hand-written kernel.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 # rows of the overflow list summed by one matmul: the MXU's tile on the v5e
@@ -251,9 +261,66 @@ def gather_slot_major(
     return view(_linear(_gather_rows, trans, res, nodes))
 
 
+def _segment_totals(cols, segment_ids: jax.Array, num_segments: int):
+    """Each segment's sum of the rows of ``cols`` (arrays [N, F_i] of one
+    dtype) -> arrays [G, F_i] in ``promote_types(dtype, float32)``: one
+    pass for all of them. Ids outside [0, G) belong to no segment.
+
+    The sum is a matmul by the [N, G] 0/1 matrix ``segment_ids[:, None] ==
+    arange(G)``, which XLA builds inside the matmul's fusion (no [N, G]
+    array is written), not the scatter-add of ``jax.ops.segment_sum``: a
+    scatter costs ~10 ns a row on the v5e whatever it adds and sums in the
+    data's own dtype (PERF.md section 6, PR 50). Products with 0 and 1 are
+    exact and the sums are float32 or wider (precision ``highest``:
+    float32 rows are not rounded to bfloat16 on the way in, bfloat16 rows
+    take one pass of the MXU either way), as ``_run_totals``' are.
+
+    The transpose is declared (``_linear``), so it can be taken twice, as
+    the force task does: every row takes its segment's row, a row gather
+    by ``segment_ids``, a group of columns at a time: a group whose
+    cotangent nobody reads (a mean's denominator) is then dead code and
+    not a wider row.
+
+    The work grows as N * G * sum(F_i), whatever the order of the ids. On
+    the v5e at ``mp.train``'s N = 23,944, G = 576, F = 64 in bfloat16 the
+    mean's pass takes 17 us (1.9 GFLOP, 10 us of the MXU at its peak; 1.2
+    ps a row and slot) where the two scatters took 466, and nothing is
+    measurable at ``ocp.train``'s 5,008 x 40 x 384 (PERF.md section 6,
+    PR 50). The packers emit a graph's rows together, so a block of 128
+    rows touches a few slots and a banded form (as ``_run_totals``: a
+    block's rows times the 0/1 matrix of the slots it touches, at ~5 ns a
+    row with its gathers) is due where G x 1.2 ps passes that: a few
+    thousand graph slots a batch at F = 64 (the cells' largest has 576).
+    """
+    rows = jnp.concatenate(cols, axis=1)
+    splits = np.cumsum([c.shape[1] for c in cols])[:-1]
+
+    def total(ids, x):
+        member = ids[:, None] == jnp.arange(num_segments, dtype=ids.dtype)
+        return jnp.einsum(
+            "ng,nf->gf", member.astype(x.dtype), x,
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.promote_types(x.dtype, jnp.float32),
+        )
+
+    def spread(ids, ct):
+        inside = ((ids >= 0) & (ids < num_segments))[:, None]
+        return jnp.concatenate(
+            [jnp.where(inside, gather(c, ids), 0)
+             for c in jnp.split(ct.astype(rows.dtype), splits, axis=1)],
+            axis=1)
+
+    return jnp.split(_linear(total, spread, segment_ids, rows), splits, axis=1)
+
+
 def segment_sum(data: jax.Array, segment_ids: jax.Array, num_segments: int) -> jax.Array:
-    """Sum ``data`` rows into ``num_segments`` buckets (deterministic on TPU)."""
-    return jax.ops.segment_sum(data, segment_ids, num_segments=num_segments)
+    """Sum ``data``'s rows ([N] or [N, F]) into ``num_segments`` buckets,
+    in any order of the ids; rows whose id is out of range are dropped.
+    Summed in float32 (or wider) and rounded once to ``data``'s dtype
+    (``_segment_totals``)."""
+    total, = _segment_totals(
+        [data.reshape(data.shape[0], -1)], segment_ids, num_segments)
+    return total.astype(data.dtype).reshape(num_segments, *data.shape[1:])
 
 
 def segment_mean(
@@ -266,15 +333,18 @@ def segment_mean(
 
     ``weights`` (e.g. a node mask) keeps padding rows out of both numerator
     and denominator — this is the masked pooling from SURVEY.md §7 "hard
-    parts" #3.
+    parts" #3. The denominator is one more column of the numerator's pass
+    (``_segment_totals``); the division is float32's (or wider), rounded
+    once to ``data``'s dtype.
     """
-    if weights is not None:
-        data = data * weights[..., None]
-        denom = segment_sum(weights, segment_ids, num_segments)
+    if weights is None:
+        weights = jnp.ones(data.shape[0], data.dtype)
     else:
-        denom = segment_sum(jnp.ones(data.shape[0], data.dtype), segment_ids, num_segments)
-    total = segment_sum(data, segment_ids, num_segments)
-    return total / jnp.maximum(denom, 1.0)[..., None]
+        data = data * weights[..., None]
+    total, denom = _segment_totals(
+        [data, weights[:, None].astype(data.dtype)], segment_ids,
+        num_segments)
+    return (total / jnp.maximum(denom, 1.0)).astype(data.dtype)
 
 
 def aggregate_edge_messages(

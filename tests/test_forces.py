@@ -326,12 +326,14 @@ def test_float32_force_model_asks_for_float32_matmuls(dtype):
     bfloat16 (PERF.md section 2, PR 27: the forces then read like the
     bfloat16 trunk's). In float32 every dot of the force train step, the
     image shifts' and both reverse passes' included, carries precision
-    ``highest``; the bfloat16 trunk asks for nothing. The one matmul that
-    is no layer's — the gather's transpose summing the overflow list's runs
+    ``highest``; the bfloat16 trunk asks for nothing. The two matmuls that
+    are no layer's — the gather's transpose summing the overflow list's runs
     with a 0/1 matrix a block (ops/segment.py _run_totals; told by its
-    [blocks, 128, halo + 128] left operand) — asks for ``highest`` whatever the trunk: its
+    [blocks, 128, halo + 128] left operand) and the frames' energies summed
+    with the [N, G] 0/1 matrix of the atoms' frames (_segment_totals, PR 50;
+    told by that operand) — ask for ``highest`` whatever the trunk: their
     sums stand in for a scatter-add's, and the position gather's cotangent
-    is float32 in both trunks."""
+    and the atoms' energies are float32 in both trunks."""
     import jax
     import jax.numpy as jnp
 
@@ -357,12 +359,17 @@ def test_float32_force_model_asks_for_float32_matmuls(dtype):
     # and their transposes under one and two reverse passes
     assert len(dots) > 12
     highest = (jax.lax.Precision.HIGHEST,) * 2
-    run_sums = 0
+    run_sums = frame_sums = 0
+    frames = (batch.node_capacity, batch.graph_capacity)
+    assert frames[1] not in (8, 16)  # no layer's width
     for eqn in dots:
         got = eqn.params["precision"]
         lhs = eqn.invars[0].aval.shape
-        if len(lhs) == 3 and lhs[1] == _RUN_BLOCK < lhs[2]:
-            run_sums += 1
+        run_sum = len(lhs) == 3 and lhs[1] == _RUN_BLOCK < lhs[2]
+        frame_sum = lhs == frames
+        if run_sum or frame_sum:
+            run_sums += run_sum
+            frame_sums += frame_sum
             assert tuple(got) == highest, eqn
             assert eqn.params["preferred_element_type"] == jnp.float32
         elif dtype == "float32":
@@ -370,3 +377,4 @@ def test_float32_force_model_asks_for_float32_matmuls(dtype):
         else:
             assert got is None, eqn
     assert run_sums >= 3  # the two convs' and the position gather's
+    assert frame_sums == 1  # forward; its transposes are row gathers

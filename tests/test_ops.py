@@ -35,6 +35,131 @@ class TestSegmentOps:
         got = segment_mean(data, ids, 3, weights=w)
         np.testing.assert_allclose(got, [[3.0], [6.0], [0.0]], atol=1e-6)
 
+    @staticmethod
+    def _pooling_case(dtype, rows_a_graph=None):
+        """Rows of ``dtype`` over 12 graph slots as a packed batch never
+        has them all at once: ids in no order, slot 5 with no rows, ids of
+        -1 and 12 and beyond (rows of no slot), the last rows padding of
+        weight 0 under a real id; or, with ``rows_a_graph``, that many rows
+        in each slot, all of one sign, where a running sum in the rows' own
+        dtype loses the most."""
+        rng = np.random.default_rng(3)
+        g = 12
+        if rows_a_graph:
+            ids = np.repeat(np.arange(g), rows_a_graph)
+            data = rng.uniform(0.5, 1.5, size=(len(ids), 7))
+            weights = np.ones(len(ids))
+        else:
+            ids = rng.integers(0, g, size=300)
+            ids[ids == 5] = 6
+            ids[::17] = rng.choice([-1, -7, g, g + 30], size=len(ids[::17]))
+            data = rng.normal(size=(300, 7))
+            weights = rng.uniform(0.5, 2.0, size=300).round(2)
+            weights[-40:] = 0.0
+        # what the device is handed, and the same numbers for the loop
+        data = np.asarray(jnp.asarray(data, dtype).astype(jnp.float64))
+        weights = np.asarray(jnp.asarray(weights, dtype).astype(jnp.float64))
+        return g, ids.astype(np.int32), data, weights
+
+    @staticmethod
+    def _pooling_loop(g, ids, rows, weights=None):
+        """The sums of ``rows`` a slot and, given ``weights``, those over
+        the weights' sums: float64, one row at a time."""
+        total, count = np.zeros((g, rows.shape[1])), np.zeros(g)
+        for n, i in enumerate(ids):
+            if 0 <= i < g:
+                total[i] += rows[n]
+                count[i] += 0.0 if weights is None else weights[n]
+        if weights is None:
+            return total
+        return total / np.maximum(count, 1.0)[:, None]
+
+    @pytest.mark.parametrize("weighted", [False, True],
+                             ids=["unweighted", "weighted"])
+    @pytest.mark.parametrize("op", ["sum", "mean"])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16", "float64"])
+    def test_pooling_matches_float64_loop(self, dtype, op, weighted):
+        """``segment_sum`` / ``segment_mean`` (a 0/1 matmul since PR 50)
+        against a float64 loop over the rows the device was handed (unsorted
+        ids, an empty slot, ids out of range, padding rows of weight 0), to
+        the dtype's rounding of ONE result: the sums are float32 or wider
+        and rounded once. In bfloat16 at 255 rows a graph they are no less
+        exact than ``jax.ops.segment_sum``, which adds in bfloat16."""
+        eps = float(jnp.finfo(dtype).eps)
+        for rows_a_graph in (None, 255):
+            g, ids, data, weights = self._pooling_case(dtype, rows_a_graph)
+            if not weighted:
+                weights = np.ones_like(weights)
+            x, w = jnp.asarray(data, dtype), jnp.asarray(weights, dtype)
+            # the weighted rows as the dtype rounds them: the sum is what
+            # is held to float64, not the product before it
+            xw = x * w[:, None] if weighted else x
+            rows = np.asarray(xw.astype(jnp.float64))
+            if op == "sum":
+                got = segment_sum(xw, jnp.asarray(ids), g)
+                want = self._pooling_loop(g, ids, rows)
+                assert segment_sum(xw[:, 0], jnp.asarray(ids), g).shape == (g,)
+            else:
+                got = segment_mean(x, jnp.asarray(ids), g,
+                                   weights=w if weighted else None)
+                want = self._pooling_loop(g, ids, rows, weights)
+            assert got.dtype == jnp.dtype(dtype) and got.shape == want.shape
+            err = np.abs(np.asarray(got.astype(jnp.float64)) - want)
+            # one rounding of the result, and the sum's own in float32
+            # (float64's for float64 rows) over a slot's rows
+            sum_eps = float(jnp.finfo(jnp.promote_types(dtype, "float32")).eps)
+            tol = (eps + (300 if rows_a_graph else 60) * sum_eps
+                   ) * np.maximum(np.abs(want), 1.0)
+            assert (err <= tol).all(), (err / tol).max()
+            if rows_a_graph is None:
+                assert not np.asarray(got)[5].any()  # the empty slot
+            if dtype == "bfloat16" and rows_a_graph and op == "sum":
+                plain = jax.ops.segment_sum(xw, jnp.asarray(ids), g)
+                plain_err = np.abs(
+                    np.asarray(plain.astype(jnp.float64)) - want)
+                assert err.max() <= plain_err.max()
+                assert plain_err.max() > 4 * err.max()  # and it shows
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    def test_pooling_derivatives_match_plain_segment_sum(self, dtype):
+        """First and second derivatives of a function of the pooled rows,
+        through the declared transpose (a row gather by the ids, and its
+        transpose the 0/1 matmul again: ``linear_call``) against plain
+        autodiff of ``jax.ops.segment_sum``, on the same unsorted ids with
+        an empty slot, ids out of range and padding rows."""
+        g, ids, data, weights = self._pooling_case(dtype)
+        ids = jnp.asarray(ids)
+        x, w = jnp.asarray(data, dtype), jnp.asarray(weights, dtype)
+        k = jnp.asarray(np.random.default_rng(4).normal(size=(7, 3)), dtype)
+
+        def plain_sum(v, i, n):
+            return jax.ops.segment_sum(v, i, num_segments=n)
+
+        def plain_mean(v, i, n, weights):
+            return plain_sum(v * weights[:, None], i, n) / jnp.maximum(
+                plain_sum(weights, i, n), 1.0)[:, None]
+
+        def energy(total, mean, x, k):
+            pooled = jnp.tanh(mean(x, ids, g, weights=w) @ k)
+            per_row = jnp.sin(x).sum(-1) * w  # [N]: the force head's shape
+            return ((pooled.astype(jnp.float32) ** 2).sum()
+                    + (total(per_row.astype(jnp.float32), ids, g) ** 3).sum())
+
+        def on_first_derivative(total, mean, x, k):
+            gx = jax.grad(energy, argnums=2)(total, mean, x, k)
+            return (gx.astype(jnp.float32) ** 2).sum()
+
+        tol = 2e-5 if dtype == "float32" else 0.06
+        for fn in (energy, on_first_derivative):
+            got = jax.grad(fn, argnums=(2, 3))(segment_sum, segment_mean, x, k)
+            want = jax.grad(fn, argnums=(2, 3))(plain_sum, plain_mean, x, k)
+            for a, b in zip(got, want):
+                b = np.asarray(b.astype(jnp.float64))
+                assert np.abs(b).max() > 0.1
+                np.testing.assert_allclose(
+                    np.asarray(a.astype(jnp.float64)), b, rtol=0,
+                    atol=tol * np.abs(b).max(), err_msg=fn.__name__)
+
     @pytest.mark.parametrize("shape", ["64x16x8", "1000x300x32",
                                        "2048x513x16", "skew"])
     def test_aggregate_matches_loop(self, shape):

@@ -284,49 +284,130 @@ def test_force_geometry_reads_the_dense_layout(one_chip, no_compile_cache):
     assert not lattices_an_edge, lattices_an_edge
 
 
-@pytest.mark.parametrize("task,dtype", [
-    ("regression", "bfloat16"), ("force", "float32"), ("ocp", "bfloat16")])
-def test_no_scatter_under_the_conv_gather(one_chip, no_compile_cache, task,
-                                          dtype):
-    """The pin that the gather's declared transpose (ops/segment.py
-    _transpose_cotangent) holds no scatter in the program the chip runs
-    (PR 33), for the ``mp-flagship`` trunk (bfloat16, BatchNorm), the
-    ``md17-force`` one (float32, two reverse passes) and ``ocp.train``'s
-    largest program at its real size (rows of 768 lanes, 50 slots a node,
-    six convs; PR 35): tier 1 is a row gather
-    and a masked sum, the overflow tier a row gather, one batched matmul
-    that sums each node's run of the list, and a row gather through
-    ``over_last``. The sorted scatter-add that XLA made of the tier's
-    ``segment_sum`` cost ~10 ns a row where a gathered row costs ~1.3-1.8
-    (PERF.md section 5), three times a step (five in the force step). The
-    pooling's ``segment_sum`` lives under ``pool_head`` / ``force_readout``
-    and stays: it shows that the count below can see a scatter."""
+def _fused_computations(comps: dict) -> set:
+    """The names of the computations that are some instruction's body (a
+    fusion's, a reduction's): their instructions are no operations of their
+    own on the device. A ``call``'s target is."""
     from cgnn_tpu.observe import phases
 
-    text, batch, _node_cap, *_sizes = _conv_program(one_chip, task, dtype)
-    n_blocks = batch.over_slots.shape[0] // 128 + 1
+    return {target for comp in comps.values()
+            for rest in comp["instrs"].values()
+            for kind, target in phases._CALLED.findall(rest)
+            if kind == "calls" or " call(" not in rest}
 
-    scatters, run_sums = {}, []
+
+def _scatters(text: str) -> dict:
+    """The compiled text's ``scatter`` instructions counted by model phase,
+    fused or not."""
+    from cgnn_tpu.observe import phases
+
+    found = {}
     for comp in phases._parse(text).values():
+        for rest in comp["instrs"].values():
+            m = _INSTR.match(rest)
+            if m and m.group(3) == "scatter":
+                op_name = phases._OP_NAME.search(rest)
+                phase = phases.classify(op_name.group(1))[0] if op_name else ""
+                found[phase] = found.get(phase, 0) + 1
+    return found
+
+
+def test_the_scatter_count_sees_a_scatter(one_chip, no_compile_cache):
+    """The control of ``test_no_scatter_under_the_conv_gather``: ``jax.ops.
+    segment_sum``, which the pooling called until PR 50, compiled for the
+    described chip at a tiny size, is a ``scatter`` under its scope to the
+    parser and the classifier that the test below reads with."""
+    from cgnn_tpu.observe import phases
+
+    def pool(x, ids):
+        with jax.named_scope(phases.POOL_HEAD):
+            return jax.ops.segment_sum(x, ids, num_segments=8)
+
+    text = jax.jit(pool).lower(
+        jax.ShapeDtypeStruct((96, 16), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((96,), jnp.int32, sharding=one_chip),
+    ).compile().as_text()
+    assert _scatters(text) == {phases.POOL_HEAD: 1}, _scatters(text)
+
+
+@pytest.mark.parametrize("program", [
+    "regression-bfloat16", "force-float32", "ocp-bfloat16", "four-chip"])
+def test_no_scatter_under_the_conv_gather(request, no_compile_cache, program):
+    """The pin that no phase of the step holds a scatter in the program the
+    chip runs, for the ``mp-flagship`` trunk (bfloat16, BatchNorm), the
+    ``md17-force`` one (float32, two reverse passes), ``ocp.train``'s
+    largest program at its real size (rows of 768 lanes, 50 slots a node,
+    six convs; PR 35) and ``mp.train-dp4``'s at real size on the described
+    host (PR 50).
+
+    The gather's declared transpose (ops/segment.py _transpose_cotangent,
+    PR 33): tier 1 is a row gather and a masked sum, the overflow tier a
+    row gather, one batched matmul that sums each node's run of the list,
+    and a row gather through ``over_last``. The sorted scatter-add that XLA
+    made of the tier's ``segment_sum`` cost ~10 ns a row where a gathered
+    row costs ~1.3-1.8 (PERF.md section 5), three times a step (five in the
+    force step).
+
+    The pooling (ops/segment.py _segment_totals, PR 50; the control that
+    the count sees a scatter is ``test_the_scatter_count_sees_a_scatter``):
+    ``pool_head``'s forward holds one matmul, by the 0/1 matrix of the nodes' graphs, which writes
+    the sums and the counts together, [G, F + 1]; the matrix is built in
+    that fusion, so no instruction that the device runs as an operation of
+    its own writes an [N, G] array; the reverse pass gathers rows of F, not
+    of F + 1."""
+    from cgnn_tpu.observe import phases
+
+    if program == "four-chip":
+        compiled, node_cap, _stack, _row, batch = _four_chip_program(
+            request.getfixturevalue("four_chips"))
+        text, f = compiled.as_text(), 64
+    else:
+        text, batch, node_cap, f, *_sizes = _conv_program(
+            request.getfixturevalue("one_chip"), *program.split("-"))
+    n_blocks = batch.over_slots.shape[-1] // 128 + 1
+    n_graphs = batch.graph_mask.shape[-1]
+    # the tiny programs' G = F = 16: there a [N, G] array is not told from
+    # a block of rows, and the two programs at real size decide
+    told_apart = n_graphs not in (f, 2 * f)
+    assert told_apart == (program in ("ocp-bfloat16", "four-chip"))
+
+    comps = phases._parse(text)
+    fused = _fused_computations(comps)
+    run_sums, pool_sums, pool_gathers, one_hot = [], [], [], []
+    for cname, comp in comps.items():
         for name, rest in comp["instrs"].items():
+            if told_apart and cname not in fused and not re.search(
+                    r"\s(" + "|".join(_MOVED) + r")\(", rest):
+                one_hot += [rest[:200] for _dt, dims in _ARRAY.findall(
+                    _result_type(rest))
+                    if sorted(dims.split(",")) == sorted(
+                        [str(node_cap), str(n_graphs)])]
             m = _INSTR.match(rest)
             op_name = phases._OP_NAME.search(rest)
             if not m or not op_name:
                 continue
             phase, direction = phases.classify(op_name.group(1))
             _dt, dims, op, _operands = m.groups()
-            if op == "scatter":
-                scatters.setdefault(phase, []).append(rest[:160])
             if (op in ("dot", "convolution") and dims.startswith(
                     f"{n_blocks},128,")
                     and phase in (phases.CONV_GATHER, phases.EDGE_GEOM)):
                 run_sums.append((phase, direction))
-    assert scatters, "the pooling's scatter is gone: does the count still see?"
-    assert not {phases.CONV_GATHER, phases.EDGE_GEOM} & set(scatters), scatters
+            if phase == phases.POOL_HEAD and direction == phases.FWD and (
+                    op in ("dot", "convolution")
+                    and _elements(dims) == n_graphs * (f + 1)):
+                pool_sums.append(rest[:200])
+            if phase == phases.POOL_HEAD and op == "gather":
+                pool_gathers.append(dims)
+    assert not _scatters(text), _scatters(text)
     # the run sums are there to be seen: one a conv and reverse pass that
     # needs the nodes' gradient, and in the force step the position gather's
     assert run_sums.count((phases.CONV_GATHER, phases.BWD)) >= 2, run_sums
-    assert ((phases.EDGE_GEOM, phases.BWD) in run_sums) == (task == "force")
+    assert ((phases.EDGE_GEOM, phases.BWD) in run_sums) == (
+        program == "force-float32")
+    assert not one_hot, one_hot
+    if program != "force-float32":  # its readout sums one number an atom
+        assert len(pool_sums) == 1, pool_sums
+        assert pool_gathers == [f"{node_cap},{f}"], pool_gathers
 
 
 @pytest.mark.parametrize("task,dtype", [
@@ -418,7 +499,7 @@ def test_no_float32_copy_of_z_is_written_beside_z(request, no_compile_cache,
     from cgnn_tpu.observe import phases
 
     if program == "four-chip":
-        compiled, node_cap, _stack, _row = _four_chip_program(
+        compiled, node_cap, _stack, _row, _batch = _four_chip_program(
             request.getfixturevalue("four_chips"))
         text, f, m, n_convs = compiled.as_text(), 64, 12, 3
     else:
@@ -427,10 +508,7 @@ def test_no_float32_copy_of_z_is_written_beside_z(request, no_compile_cache,
     z = node_cap * m * 2 * f
 
     comps = phases._parse(text)
-    fused = {target for comp in comps.values()
-             for rest in comp["instrs"].values()
-             for kind, target in phases._CALLED.findall(rest)
-             if kind == "calls" or " call(" not in rest}
+    fused = _fused_computations(comps)
     float32_z, z_written, bn1_sums, bn1_wide = [], 0, 0, []
     for cname, comp in comps.items():
         if cname in fused:
@@ -501,7 +579,7 @@ def _four_chip_program(mesh):
     ``benchmark/kinds/dp_train.py`` assemble it: 128 structures a chip,
     compact staging with flat rows, the guard, the published widths in
     bfloat16 -> (compiled, node capacity, stack length, bytes of one chip's
-    row of the stack).
+    row of the stack, one chip's batch as staged).
 
     This compile picks the entry layouts it likes best, so a relayout of
     the staged arrays that the chip pays for is not in its text (PERF.md
@@ -576,7 +654,7 @@ def _four_chip_program(mesh):
     compiled = fn.lower(*shapes).compile()
     row = sum(int(np.prod(np.shape(x)[2:])) * x.dtype.itemsize
               for x in jax.tree_util.tree_leaves(stacked))
-    return compiled, node_cap, stack, row
+    return compiled, node_cap, stack, row, largest
 
 
 def test_four_chip_scan_program_at_real_size(four_chips, no_compile_cache):
@@ -587,7 +665,7 @@ def test_four_chip_scan_program_at_real_size(four_chips, no_compile_cache):
     table across chips."""
     from cgnn_tpu.observe import phases
 
-    compiled, node_cap, stack, row = _four_chip_program(four_chips)
+    compiled, node_cap, stack, row, _batch = _four_chip_program(four_chips)
     m = 12
     mem = compiled.memory_analysis()
     on_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
