@@ -54,10 +54,12 @@ from cgnn_tpu.models.lm_blocks import (
 from cgnn_tpu.observe import phases
 from cgnn_tpu.ops import moe
 from cgnn_tpu.ops.masked_attention import (
-    StaticMask, mask_tiles, masked_attention,
+    StaticMask, live_tiles, mask_tiles, masked_attention,
 )
 
 SLIDING, FULL = "sliding_attention", "full_attention"
+# the attention layers' kinds under the names their counters go by
+_COUNTED = (("window", SLIDING), ("full", FULL))
 # what an expert layer's checkpoint keeps beside its input and its
 # attention's output (``lm_blocks.by_sequence``)
 ROUTED = "moe.routed"
@@ -143,6 +145,15 @@ class AfmoeConfig(lm_blocks.Stack):
         """``batch_stats``: the selection biases, float32."""
         return {"router_bias": (self.n_periods, len(self.period),
                                 self.n_experts)}
+
+    def live_tiles(self, segment_ids) -> dict:
+        """{``window`` | ``full``: (the tiles a head visits of each sequence
+        ``[S]``, its documents given, layers of the kind)}
+        (ops/masked_attention.py)."""
+        n = segment_ids.shape[-1]
+        return {name: (live_tiles(_mask(self, kind, n), segment_ids),
+                       self.layer_types.count(kind))
+                for name, kind in _COUNTED}
 
 
 def _mask(cfg: AfmoeConfig, kind: str, n: int) -> StaticMask:
@@ -287,7 +298,8 @@ def init_stats(cfg: AfmoeConfig) -> dict:
 
 def attention_tiles(cfg: AfmoeConfig, seq_len: int) -> dict:
     """{``window`` | ``full``: (live tiles, grid tiles a head and a
-    sequence, layers of the kind)} (ops/masked_attention.py)."""
+    sequence, layers of the kind)}, documents aside
+    (ops/masked_attention.py)."""
     return {name: (*mask_tiles(_mask(cfg, kind, seq_len)),
                    cfg.layer_types.count(kind))
-            for name, kind in (("window", SLIDING), ("full", FULL))}
+            for name, kind in _COUNTED}

@@ -56,7 +56,7 @@ from cgnn_tpu.models.lm_blocks import (
 from cgnn_tpu.observe import phases
 from cgnn_tpu.ops import moe
 from cgnn_tpu.ops.masked_attention import (
-    StaticMask, mask_tiles, masked_attention,
+    StaticMask, live_tiles, mask_tiles, masked_attention,
 )
 from cgnn_tpu.ops.short_conv import TAPS, short_conv
 
@@ -142,6 +142,12 @@ class Lfm2Config(lm_blocks.Stack):
         """``batch_stats``: the selection biases, float32."""
         return {"router_bias": (self.n_periods, len(self.period),
                                 self.n_experts)}
+
+    def live_tiles(self, segment_ids) -> dict:
+        """{``full``: (the tiles a head visits of each sequence ``[S]``, its
+        documents given, the attention layers)} (ops/masked_attention.py)."""
+        return {"full": (live_tiles(_mask(segment_ids.shape[-1]), segment_ids),
+                         self.n_attention_layers)}
 
 
 def _mask(n: int) -> StaticMask:
@@ -285,5 +291,5 @@ def init_stats(cfg: Lfm2Config) -> dict:
 
 def attention_tiles(cfg: Lfm2Config, seq_len: int) -> dict:
     """{``full``: (live tiles, grid tiles a head and a sequence, attention
-    layers)} (ops/masked_attention.py)."""
+    layers)}, documents aside (ops/masked_attention.py)."""
     return {"full": (*mask_tiles(_mask(seq_len)), cfg.n_attention_layers)}

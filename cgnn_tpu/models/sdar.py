@@ -34,7 +34,7 @@ from cgnn_tpu.models.lm_blocks import (
 )
 from cgnn_tpu.observe import phases
 from cgnn_tpu.ops import moe
-from cgnn_tpu.ops.bd_attention import bd_attention, bd_tiles
+from cgnn_tpu.ops.bd_attention import bd_attention, bd_live_tiles, bd_tiles
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,6 +76,12 @@ class SdarConfig:
     @property
     def n_attention_layers(self) -> int:
         return self.num_hidden_layers
+
+    def live_tiles(self, segment_ids) -> dict:
+        """{``bd``: (the tiles a head visits of each sequence ``[S]``, its
+        documents given, the layers)} (ops/bd_attention.py)."""
+        return {"bd": (bd_live_tiles(segment_ids, self.block_length),
+                       self.num_hidden_layers)}
 
     def shapes(self) -> dict:
         """The parameter tree's shapes, float32 all."""
@@ -191,5 +197,6 @@ def init_params(cfg: SdarConfig, rng, n_layers_published: int | None = None,
 
 
 def attention_tiles(cfg: SdarConfig, seq_len: int) -> tuple[int, int]:
-    """(live, grid) tiles a head and a sequence (ops/bd_attention.py)."""
+    """(live, grid) tiles a head and a sequence, documents aside
+    (ops/bd_attention.py)."""
     return bd_tiles(seq_len, cfg.block_length)

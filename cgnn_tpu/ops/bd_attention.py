@@ -14,7 +14,8 @@ i // block`` the block of a position within its half:
 The mask over positions is static (``bd_mask``); the documents are data
 (``segment_ids``). About a quarter of the ``2L x 2L`` grid is live. The op
 itself is ``ops/masked_attention.py``'s, given this mask: the splash kernel
-over the live tiles (``bd_tiles``) on the TPU, plain ``jnp`` elsewhere.
+over the live tiles (``bd_tiles``, less those that a call's documents hide:
+``bd_live_tiles``) on the TPU, plain ``jnp`` elsewhere.
 """
 
 from __future__ import annotations
@@ -23,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from cgnn_tpu.ops.masked_attention import (
-    StaticMask, mask_tiles, masked_attention,
+    StaticMask, live_tiles, mask_tiles, masked_attention,
 )
 
 
@@ -38,11 +39,23 @@ def bd_tiles(seq_len: int, block: int) -> tuple[int, int]:
     return mask_tiles(StaticMask("bd", 2 * seq_len, block=block))
 
 
+def _doubled(segment_ids):
+    """The documents of ``x_t (+) x_0``: those of a sequence, twice."""
+    return jnp.concatenate([segment_ids, segment_ids], axis=-1)
+
+
+def bd_live_tiles(segment_ids, block: int):
+    """``segment_ids [S, L]`` -> ``[S]`` int32: the tiles a head visits of
+    each doubled sequence, ``bd_tiles``' live ones less those that its
+    documents hide."""
+    seg = _doubled(segment_ids)
+    return live_tiles(StaticMask("bd", seg.shape[-1], block=block), seg)
+
+
 def bd_attention(q, k, v, segment_ids, *, block: int, impl: str = "auto"):
     """``q [S, Hq, 2L, D]`` (already scaled), ``k, v [S, Hkv, 2L, D]``,
     ``segment_ids [S, L]`` int32 -> ``[S, Hq, 2L, D]``; ``impl`` as
     ``masked_attention``'s."""
-    seg = jnp.concatenate([segment_ids, segment_ids], axis=-1)
-    return masked_attention(q, k, v, seg,
+    return masked_attention(q, k, v, _doubled(segment_ids),
                             StaticMask("bd", q.shape[2], block=block),
                             impl=impl)

@@ -6,9 +6,12 @@ block-diffusion mask over a doubled sequence, ``ops/bd_attention.py``;
 causal; causal within a window); the documents are data (``segment_ids``): a
 query sees a key only where the mask shows it and both are of one document.
 On the TPU the op is the splash-attention kernel of
-``jax.experimental.pallas`` given that mask: it visits the live tiles only
-(``mask_tiles``) and never writes a ``[heads, N, N]`` score array. Elsewhere
-it is the same arithmetic in plain ``jnp``, a block of queries at a time.
+``jax.experimental.pallas`` given that mask: it visits the live tiles only,
+those that the mask leaves (``mask_tiles``) less those that the call's
+documents hide (``dead_tiles``: the kernel's tables of the static mask are
+refined by ``segment_ids`` before every call), and never writes a ``[heads,
+N, N]`` score array. Elsewhere it is the same arithmetic in plain ``jnp``, a
+block of queries at a time.
 
 What the reverse pass needs of the forward one is named ``KEPT``: the
 kernel's output and its log-sum-exp, the plain path's output. A
@@ -83,13 +86,47 @@ def _tile(n: int, tile: int) -> int:
     return tile if n % tile == 0 else n
 
 
-def mask_tiles(mask: StaticMask) -> tuple[int, int]:
-    """(live, grid): tiles of the ``n x n`` grid that hold a visible pair,
-    and all of them, a head and a sequence."""
+def _shown_tiles(mask: StaticMask) -> np.ndarray:
+    """``[q tiles, kv tiles]`` bool: the tile holds a pair the mask shows."""
     n = mask.n
     tq, tk = _tile(n, TILE_Q), _tile(n, TILE_KV)
-    live = mask.dense().reshape(n // tq, tq, n // tk, tk).any(axis=(1, 3))
+    return mask.dense().reshape(n // tq, tq, n // tk, tk).any(axis=(1, 3))
+
+
+def mask_tiles(mask: StaticMask) -> tuple[int, int]:
+    """(live, grid): tiles of the ``n x n`` grid that hold a visible pair,
+    and all of them, a head and a sequence, documents aside."""
+    live = _shown_tiles(mask)
     return int(live.sum()), int(live.size)
+
+
+def dead_tiles(segment_ids):
+    """``segment_ids [..., N]`` int -> ``[..., N / TILE_Q, N / TILE_KV]``
+    bool: the tiles in which no query is of a key's document, by the range
+    of the documents a tile holds: dead where the queries' ``[min, max]`` and
+    the keys' do not meet. A pair of one document puts that document in both
+    ranges, so a tile that holds a visible pair is never called dead,
+    whatever the ids. The test errs the other way only: it calls a tile live
+    where the ranges meet and no document is shared, which ids that are
+    non-decreasing along the sequence (``TokenBatch.segment_ids``), or two
+    such halves that end on tiles' edges (``ops/bd_attention.py``), never
+    do; and it knows nothing of the static mask, which may hide a live
+    tile's every pair of one document (it does on none of the cells' pools:
+    tests/test_masked_attention.py)."""
+    *lead, n = segment_ids.shape
+    by_q = segment_ids.reshape(*lead, n // _tile(n, TILE_Q), -1)
+    by_kv = segment_ids.reshape(*lead, n // _tile(n, TILE_KV), -1)
+    q_lo, q_hi = by_q.min(axis=-1), by_q.max(axis=-1)
+    kv_lo, kv_hi = by_kv.min(axis=-1), by_kv.max(axis=-1)
+    return ((q_hi[..., :, None] < kv_lo[..., None, :])
+            | (kv_hi[..., None, :] < q_lo[..., :, None]))
+
+
+def live_tiles(mask: StaticMask, segment_ids):
+    """``segment_ids [..., N]`` -> ``[...]`` int32: the tiles a head visits
+    of each sequence, ``mask_tiles``' live ones less ``dead_tiles``."""
+    return (_shown_tiles(mask) & ~dead_tiles(segment_ids)).sum(
+        axis=(-2, -1), dtype=jnp.int32)
 
 
 def kept_bytes(heads: int, n: int, head_dim: int, dtype) -> int:
@@ -145,19 +182,56 @@ def _splash_kernel(mask: StaticMask, group: int):
             residual_checkpoint_name=KEPT)
 
 
+def _refined(kernel, dead):
+    """``kernel`` with the tiles ``dead [q tiles, kv tiles]`` taken off its
+    three tables. The library shrinks a table to the mask's live steps:
+    forward and ``dq`` are ``[1, q tile, j-th step]`` with ``data_next`` the
+    step's key tile, ``dkv`` is ``[1, i-th step, kv tile]`` with
+    ``data_next`` its query tile. A step whose tile is dead gets
+    ``block_mask`` 0 (skip), and every step the kernel skips, the mask's own
+    padding too, gets for ``data_next`` the tile of the next live step of
+    its row of steps (past the last, of the last): what that step fetches
+    anyway, so that a skipped step fetches nothing of its own. A live step
+    and everything else stay as built."""
+    def refine(info, by_kv: bool):
+        axis = 1 if by_kv else 2  # the steps of one row of the walk
+        there = info.data_next.astype(jnp.int32)
+        if by_kv:
+            hidden = dead[there, jnp.arange(dead.shape[1])]
+        else:
+            hidden = dead[jnp.arange(dead.shape[0])[:, None], there]
+        # a weak 0: the table keeps its dtype (int8 as the library built it)
+        block_mask = jnp.where(hidden, 0, info.block_mask)
+        live = block_mask > 0
+        steps = jax.lax.broadcasted_iota(jnp.int32, there.shape, axis)
+        after = jax.lax.cummin(jnp.where(live, steps, there.shape[axis]),
+                               axis=axis, reverse=True)
+        before = jax.lax.cummax(jnp.where(live, steps, 0), axis=axis)
+        source = jnp.where(after < there.shape[axis], after, before)
+        return info._replace(
+            block_mask=block_mask,
+            data_next=jnp.take_along_axis(info.data_next, source, axis=axis))
+
+    return type(kernel)(
+        refine(kernel.fwd_mask_info, False),
+        refine(kernel.dq_mask_info, False),
+        refine(kernel.dkv_mask_info, True), **kernel.kwargs)
+
+
 def _splash(q, k, v, segment_ids, mask: StaticMask):
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as splash,
     )
 
-    kernel = _splash_kernel(mask, q.shape[2])
+    static = _splash_kernel(mask, q.shape[2])
 
-    def one_kv_head(qg, kh, vh, seg):
-        return kernel(qg, kh, vh,
-                      segment_ids=splash.SegmentIds(q=seg, kv=seg))
+    def one_sequence(qs, ks, vs, seg):
+        kernel = _refined(static, dead_tiles(seg))
+        ids = splash.SegmentIds(q=seg, kv=seg)
+        return jax.vmap(lambda qg, kh, vh: kernel(qg, kh, vh,
+                                                  segment_ids=ids))(qs, ks, vs)
 
-    over_heads = jax.vmap(one_kv_head, in_axes=(0, 0, 0, None))
-    return jax.vmap(over_heads)(q, k, v, segment_ids)
+    return jax.vmap(one_sequence)(q, k, v, segment_ids)
 
 
 def masked_attention(q, k, v, segment_ids, mask: StaticMask, *,

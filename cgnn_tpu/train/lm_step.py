@@ -128,7 +128,10 @@ def step_metrics(cfg, batch: TokenBatch, loss_sum, routed, tiles,
     By ``cfg.objective``: a ``blockdiff`` step counts its masked tokens and
     the mask's tiles (``tiles`` = (live, grid) a head and a sequence, every
     layer alike); a ``causal`` one its weighted tokens and the tiles of each
-    kind of layer (``tiles`` = {kind: (live, grid, layers)}). Either counts
+    kind of layer (``tiles`` = {kind: (live, grid, layers)}). Of ``tiles``
+    the grid is counted; the live tiles are the step's own, those that the
+    mask and the batch's documents leave the kernel (``cfg.live_tiles``;
+    ``ops/masked_attention.py``). Either counts
     the bytes its layers' checkpoints keep of the attention
     (``lm_blocks.by_sequence``) and the (sequence, layer, operand) calls of
     ``lm_blocks.prepare_heads``, all and those that took the kernel, from
@@ -164,18 +167,15 @@ def step_metrics(cfg, batch: TokenBatch, loss_sum, routed, tiles,
                 cfg.n_conv_layers * s * n)
             metrics["sconv_taps_cut_sum"] = cfg.n_conv_layers * taps_cut(
                 batch.segment_ids).astype(jnp.float32)
-        heads = cfg.num_attention_heads * s
-        if tiles is not None and causal:
-            for kind, (live, grid, layers) in tiles.items():
-                metrics[f"attn_{kind}_tiles_live_sum"] = jnp.float32(
-                    heads * layers * live)
-                metrics[f"attn_{kind}_tiles_grid_sum"] = jnp.float32(
-                    heads * layers * grid)
-        elif tiles is not None:
-            metrics["bd_tiles_live_sum"] = jnp.float32(
-                heads * cfg.num_hidden_layers * tiles[0])
-            metrics["bd_tiles_grid_sum"] = jnp.float32(
-                heads * cfg.num_hidden_layers * tiles[1])
+        if tiles is not None:
+            for kind, (live, layers) in cfg.live_tiles(
+                    batch.segment_ids).items():
+                stem, grid = ((f"attn_{kind}_tiles", tiles[kind][1]) if causal
+                              else ("bd_tiles", tiles[1]))
+                calls = cfg.num_attention_heads * layers  # a sequence
+                metrics[stem + "_live_sum"] = calls * live.sum().astype(
+                    jnp.float32)
+                metrics[stem + "_grid_sum"] = jnp.float32(calls * s * grid)
         # each of these is a step's own number, not a sequence's: its mean
         # over an epoch divides by the steps
         for name in [m for m in metrics if m.endswith("_sum")

@@ -181,35 +181,112 @@ def test_attention_agrees_with_the_dense_mask(packed, window):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-6)
 
 
-def test_the_splash_kernel_agrees_with_the_dense_mask(monkeypatch):
-    """``impl="splash"``, the kernel interpreted on the CPU: window and
-    causal with documents, several tiles a side."""
+def _documents(lengths, doubled):
+    """One row of ``segment_ids``; ``doubled``: the block-diffusion call's,
+    the documents of ``x_t`` and again those of ``x_0``."""
+    row = np.repeat(np.arange(len(lengths)), lengths)
+    return np.concatenate([row, row]) if doubled else row
+
+
+# id: (kind of mask, its window or block, the documents of two unlike
+# sequences, the tiles of 128 a head visits of each)
+_SPLASH_CASES = {
+    "causal-several-documents": (
+        "causal", 0, ([100, 200, 12, 200], [128, 256, 128]), (8, 5)),
+    "window-several-documents": (
+        "causal", 160, ([100, 200, 12, 200], [128, 256, 128]), (8, 5)),
+    "bd-several-documents": (
+        "bd", 4, ([128, 256, 128], [256, 100, 156]), (14, 16)),
+    "causal-one-document": ("causal", 0, ([512], [512]), (10, 10)),
+    "window-one-document": ("causal", 160, ([512], [512]), (9, 9)),
+    "bd-one-document": ("bd", 4, ([512], [512]), (24, 24)),
+    "causal-boundary-off-an-edge": (
+        "causal", 0, ([130, 382], [254, 258]), (8, 8)),
+    "bd-boundary-off-an-edge": ("bd", 4, ([132, 380], [252, 260]), (20, 20)),
+}
+
+
+@pytest.mark.parametrize("case", list(_SPLASH_CASES))
+def test_the_splash_kernel_agrees_with_the_dense_mask(monkeypatch, case):
+    """``impl="splash"``, the kernel interpreted on the CPU, several tiles a
+    side and two sequences of unlike documents: the output and the gradients
+    of q, k and v against ``impl="blocked"``, and bit for bit those of the
+    kernel whose tables the documents did not refine; each of the three
+    refined tables holds the tiles that the dense mask leaves, and a step
+    it skips fetches a live step's tile."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as splash,
     )
 
     from cgnn_tpu.ops import masked_attention as op
 
-    n = 4 * 128
-    seg = jnp.asarray(np.repeat(np.arange(4), [100, 200, 12, 200])[None]
-                      .repeat(2, 0).astype(np.int32))
+    kind, arg, lengths, live = _SPLASH_CASES[case]
+    bd = kind == "bd"
+    seg = np.stack([_documents(x, bd) for x in lengths]).astype(np.int32)
+    n = seg.shape[1]
+    mask = (StaticMask("bd", n, block=arg) if bd
+            else StaticMask("causal", n, window=arg))
     monkeypatch.setattr(op, "TILE_Q", 128)
     monkeypatch.setattr(op, "TILE_KV", 128)
     monkeypatch.setattr(
         splash, "make_splash_mqa_single_device", functools.partial(
             splash.make_splash_mqa_single_device, interpret=True))
     op._splash_kernel.cache_clear()
+
+    def outputs(impl):
+        q, k, v, w = _qkv(2, n)
+        f = lambda q, k, v: masked_attention(  # noqa: E731
+            q, k, v, jnp.asarray(seg), mask, impl=impl)
+        return (f(q, k, v), *jax.grad(
+            lambda *qkv: (f(*qkv) * w).sum(), argnums=(0, 1, 2))(q, k, v))
+
     try:
         # the suite's x64 is no entry point's, and the kernel is float32's
         with jax.enable_x64(False):
-            q, k, v, _ = _qkv(2, n)
-            for window in (0, 160):
-                mask = StaticMask("causal", n, window=window)
-                assert mask_tiles(mask)[0] == (10 if not window else 9)
-                got = masked_attention(q, k, v, seg, mask, impl="splash")
-                np.testing.assert_allclose(
-                    got, _dense_attention(q, k, v, seg, window), rtol=2e-3,
-                    atol=2e-4)
+            static = op._splash_kernel(mask, 2)
+            grid = (n // 128, n // 128)
+            shown = mask.dense().reshape(grid[0], 128, grid[1], 128)
+            for row, count in zip(seg, live):
+                want = (shown & (row[:, None] == row[None, :]).reshape(
+                    shown.shape)).any(axis=(1, 3))
+                assert want.sum() == count
+                kernel = op._refined(static, op.dead_tiles(jnp.asarray(row)))
+                for name in ("fwd_mask_info", "dq_mask_info",
+                             "dkv_mask_info"):
+                    was, now = getattr(static, name), getattr(kernel, name)
+                    assert now.block_mask.dtype == was.block_mask.dtype
+                    steps = np.asarray(now.block_mask)[0] > 0
+                    there = np.asarray(now.data_next)[0]
+                    tiles = np.zeros(grid, bool)
+                    if name == "dkv_mask_info":
+                        tiles[there[steps], np.nonzero(steps)[1]] = True
+                    else:
+                        tiles[np.nonzero(steps)[0], there[steps]] = True
+                    np.testing.assert_array_equal(tiles, want)
+                    assert steps.sum() == count  # no tile twice
+                    assert now.data_next.dtype == was.data_next.dtype
+                    # a skipped step fetches what a live step of its row of
+                    # steps reads; a live step reads as built
+                    axis = 0 if name == "dkv_mask_info" else 1
+                    np.testing.assert_array_equal(
+                        there[steps], np.asarray(was.data_next)[0][steps])
+                    for at in np.argwhere(~steps):
+                        row = [slice(None), slice(None)]
+                        row[1 - axis] = at[1 - axis]
+                        assert there[tuple(at)] in there[tuple(row)][
+                            steps[tuple(row)]]
+                    if "one-document" in case:
+                        np.testing.assert_array_equal(now.block_mask,
+                                                      was.block_mask)
+                    for other in ("mask_next", "partial_mask_blocks"):
+                        assert getattr(now, other) is getattr(was, other)
+            assert "one-document" in case or min(live) < mask_tiles(mask)[0]
+            got = outputs("splash")
+            for a, b in zip(got, outputs("blocked")):
+                np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-4)
+            monkeypatch.setattr(op, "_refined", lambda kernel, dead: kernel)
+            for a, b in zip(got, outputs("splash")):
+                np.testing.assert_array_equal(a, b)
     finally:
         op._splash_kernel.cache_clear()
 
@@ -583,22 +660,68 @@ def test_what_a_step_counts_of_the_kept_bytes():
     assert float(m["attn_kept_bytes_sum"]) == 7 * 2 * 4 * L * (16 * 4 + 4)
 
 
-def test_the_step_lowers_to_the_text_it_had_before_the_stack_moved():
+def _tiles_left(mask, segment_ids, tile):
+    """The tiles a head visits of the sequences, by the dense mask."""
+    g = mask.n // tile
+    return sum(int((mask.dense() & (row[:, None] == row[None, :])).reshape(
+        g, tile, g, tile).any(axis=(1, 3)).sum()) for row in segment_ids)
+
+
+@pytest.mark.parametrize("packed", [True, False])
+def test_a_step_counts_the_tiles_its_documents_leave(monkeypatch, packed):
+    """``attn_<kind>_tiles_live``: what ``ops/masked_attention.py`` visits of
+    the step's sequences, tiles of 8 here; with one document a sequence the
+    static count of ``attention_tiles``; the grid either way."""
+    from cgnn_tpu.ops import masked_attention as op
+
+    monkeypatch.setattr(op, "TILE_Q", 8)
+    monkeypatch.setattr(op, "TILE_KV", 8)
+    # boundaries on the tiles' edges: a tile the kernel visits holds a pair
+    # that the dense mask shows (off them it may visit one that holds none)
+    documents = ([8, 24], [16, 8, 8]) if packed else ([L], [L])
+    batch = tokens.split_batches(_pool(0), 2)[0]._replace(
+        segment_ids=np.stack([np.repeat(np.arange(len(d)), d)
+                              for d in documents]).astype(np.int32))
+    tiles = afmoe.attention_tiles(CFG, L)
+    assert tiles == {"window": (7, 16, 5), "full": (10, 16, 2)}
+    m = step_metrics(CFG, batch, jnp.float32(0.0), (
+        jnp.ones((6, 16), jnp.int32), jnp.zeros((6, 2), jnp.int32)), tiles,
+        {"router_bias": _bias(0)})
+    for kind, (live, grid, layers) in tiles.items():
+        mask = StaticMask("causal", L, window=WINDOW * (kind == "window"))
+        left = _tiles_left(mask, batch.segment_ids, 8)
+        assert (left < 2 * live) is packed
+        assert float(m[f"attn_{kind}_tiles_live_sum"]) == 4 * layers * left
+        assert float(m[f"attn_{kind}_tiles_grid_sum"]) == (
+            4 * layers * 2 * grid)
+
+
+@pytest.mark.parametrize("counted,lines,digest", [
+    (False, 9678,
+     "da1636ba0af424ee931054477a891253d4fae03cf387ef5ddf55d038e6031fcc"),
+    (True, 9746,
+     "58964a3d1c45811fc347736a4f3ce9092b28daffca298c14b2b996f921197f5b")])
+def test_the_step_lowers_to_the_text_it_had_before_the_stack_moved(
+        counted, lines, digest):
     """PR 49 moved the stack's scans out of models/afmoe.py into
     ``lm_blocks.scan_stack``, which models/lfm2.py shares: the step of this
     file's model lowers to the text PR 48's did (StableHLO without
     locations, under the suite's x64 and eight CPU devices), so no number
-    of ``trinity.train`` moved with it. A change meant to reach this
-    model's program fails here: measure ``trinity.train`` with it, then
-    pin the new text."""
+    of ``trinity.train`` moved with it. PR 51 refines the splash kernel's
+    tables, which the CPU's path does not run: without the tiles' counters
+    the step lowers to the text it had on PR 51's parent (and PR 48's less
+    the counters' 8 lines); with them it is 68 lines longer, the tiles
+    that the batch's documents leave (``cfg.live_tiles``). A change meant
+    to reach this model's program fails here: measure ``trinity.train``
+    with it, then pin the new text."""
     import hashlib
 
     batch = tokens.split_batches(_pool(0), 2)[0]
-    step = make_lm_train_step(CFG, afmoe.attention_tiles(CFG, L))
+    step = make_lm_train_step(
+        CFG, afmoe.attention_tiles(CFG, L) if counted else None)
     text = jax.jit(step).lower(_state(_params(0), _bias(0)), batch).as_text()
-    assert len(text.splitlines()) == 9686
-    assert hashlib.sha256(text.encode()).hexdigest() == (
-        "baccc1cc351065c547f6a26a59210027f481f17f6177b9ba88782fbbf569f14e")
+    assert len(text.splitlines()) == lines
+    assert hashlib.sha256(text.encode()).hexdigest() == digest
 
 
 def test_parameter_count_and_the_stack():

@@ -31,7 +31,9 @@ from cgnn_tpu.ops.masked_attention import (  # noqa: E402
 )
 from cgnn_tpu.ops.short_conv import short_conv, taps_cut  # noqa: E402
 from cgnn_tpu.train import Normalizer, make_optimizer  # noqa: E402
-from cgnn_tpu.train.lm_step import make_lm_train_step  # noqa: E402
+from cgnn_tpu.train.lm_step import (  # noqa: E402
+    make_lm_train_step, step_metrics,
+)
 from cgnn_tpu.train.state import TrainState  # noqa: E402
 
 L = 32
@@ -345,6 +347,34 @@ def test_three_adamw_steps_agree_with_the_reference(followed):
     for k, v in want["delta_norm"].items():
         assert delta[k] == pytest.approx(v, rel=2e-3), k
     assert ref.median_leaf_diff(grad, want["grad"]) < 1e-4
+
+
+@pytest.mark.parametrize("packed", [True, False])
+def test_a_step_counts_the_tiles_its_documents_leave(monkeypatch, packed):
+    """``attn_full_tiles_live``: what ``ops/masked_attention.py`` visits of
+    the step's sequences, tiles of 8 here; with one document a sequence the
+    static count of ``attention_tiles``; the grid either way."""
+    from cgnn_tpu.ops import masked_attention as op
+
+    monkeypatch.setattr(op, "TILE_Q", 8)
+    monkeypatch.setattr(op, "TILE_KV", 8)
+    # boundaries on the tiles' edges: a tile the kernel visits holds a pair
+    # that the dense mask shows (off them it may visit one that holds none)
+    documents = ([8, 24], [16, 8, 8]) if packed else ([L], [L])
+    batch = tokens.split_batches(_pool(0), 2)[0]._replace(
+        segment_ids=np.stack([np.repeat(np.arange(len(d)), d)
+                              for d in documents]).astype(np.int32))
+    tiles = lfm2.attention_tiles(CFG, L)
+    assert tiles == {"full": (10, 16, 2)}
+    m = step_metrics(CFG, batch, jnp.float32(0.0), (
+        jnp.ones((6, 16), jnp.int32), jnp.zeros((6, 2), jnp.int32)), tiles)
+    dense = StaticMask("causal", L).dense()
+    left = sum(int((dense & (row[:, None] == row[None, :])).reshape(
+        4, 8, 4, 8).any(axis=(1, 3)).sum()) for row in batch.segment_ids)
+    assert (left < 2 * 10) is packed
+    # two attention layers of four heads
+    assert float(m["attn_full_tiles_live_sum"]) == 4 * 2 * left
+    assert float(m["attn_full_tiles_grid_sum"]) == 4 * 2 * 2 * 16
 
 
 def test_the_tied_embedding_s_gradient_is_the_sum_of_both_uses():

@@ -573,6 +573,19 @@ _CLASSIFIED = [
      "dot_general", ("moe.shared", "fwd")),
     (_LM + "transpose(jvp(while))/body/while/body/while/body/checkpoint/"
      "rematted_computation/moe.shared/dot_general", ("moe.shared", "bwd")),
+    # the splash kernels' tables refined by the call's documents
+    # (ops/masked_attention.py): the forward pass's are no part of what is
+    # differentiated; the reverse pass makes its own in the checkpoint
+    (_LM + "while/body/closed_call/attn.bd/vmap()/reduce_max",
+     ("attn.bd", "fwd")),
+    (_LM + "while/body/closed_call/while/body/closed_call/attn.full/"
+     "vmap(jit(_where))/select_n", ("attn.full", "fwd")),
+    (_LM + "transpose(jvp())/while/body/closed_call/while/body/closed_call/"
+     "checkpoint/rematted_computation/attn.bd/vmap(jit(_where))/select_n",
+     ("attn.bd", "bwd")),
+    (_LM + "transpose(jvp(scan))/while/body/closed_call/while/body/"
+     "closed_call/checkpoint/rematted_computation/attn.window/vmap()/"
+     "reduce_min", ("attn.window", "bwd")),
     # the held experts' weight casts are the expert phase's there
     (_LM + "jvp(while)/body/while/body/while/body/checkpoint/moe.expert/"
      "convert_element_type", ("moe.expert", "fwd")),

@@ -623,6 +623,35 @@ def test_what_a_step_counts_of_the_kept_bytes(dtype, width):
     assert 4 * 2 * kept_bytes(32, 8192, 128, jnp.bfloat16) == 545_259_520
 
 
+@pytest.mark.parametrize("packed", [True, False])
+def test_a_step_counts_the_tiles_its_documents_leave(monkeypatch, packed):
+    """``bd_tiles_live``: what ``ops/masked_attention.py`` visits of the
+    step's doubled sequences, tiles of 8 here; with one document a sequence
+    the static count of ``attention_tiles``; the grid either way."""
+    from cgnn_tpu.ops import masked_attention as op
+
+    monkeypatch.setattr(op, "TILE_Q", 8)
+    monkeypatch.setattr(op, "TILE_KV", 8)
+    # boundaries on the tiles' edges: a tile the kernel visits holds a pair
+    # that the dense mask shows (off them it may visit one that holds none)
+    documents = ([8, 24], [16, 8, 8]) if packed else ([L], [L])
+    batch = tokens.split_batches(_pool(0), 2)[0]._replace(
+        segment_ids=np.stack([np.repeat(np.arange(len(d)), d)
+                              for d in documents]).astype(np.int32))
+    live, grid = tiles = sdar.attention_tiles(CFG, L)
+    assert tiles == (24, 64)  # 4 + 10 + 0 + 10 of four quarters of 16
+    m = step_metrics(CFG, batch, jnp.float32(0.0), (
+        jnp.ones((2, 16), jnp.int32), jnp.zeros((2, 2), jnp.int32)), tiles)
+    seg = np.concatenate([batch.segment_ids, batch.segment_ids], axis=1)
+    left = sum(int((bd_mask(L, BLOCK) & (row[:, None] == row[None, :]))
+                   .reshape(8, 8, 8, 8).any(axis=(1, 3)).sum())
+               for row in seg)
+    assert (left < 2 * live) is packed
+    # two layers of four heads
+    assert float(m["bd_tiles_live_sum"]) == 4 * 2 * left
+    assert float(m["bd_tiles_grid_sum"]) == 4 * 2 * 2 * grid
+
+
 def test_parameter_count_and_init():
     real = sdar.SdarConfig()
     layer = (2048 * 4096 + 2 * 2048 * 512 + 4096 * 2048 + 2 * 128 + 2048

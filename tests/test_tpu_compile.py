@@ -702,6 +702,44 @@ def _kernel_calls(text: str, kernel: str) -> int:
     return len(re.findall(rf"%{kernel}[\w.]* = [^\n]*custom-call\(", text))
 
 
+def _check_the_refined_tables(text: str, calls: int):
+    """``ops/masked_attention.py`` ``_refined`` in a decoder's window
+    program (PR 51): of every splash kernel's scalar-prefetch tables the
+    first two, ``data_next`` and ``block_mask``, are what an operation of
+    the program wrote under the attention's scope before it called the
+    library (in the forward pass a sequence's slice, ``squeeze``, of the
+    tables that the scan over the sequences made of the step's
+    ``segment_ids`` beforehand), where the mask's partial blocks, the
+    kernel's last operand, are as built: a constant that the loops carry,
+    which the library's own call converts. That Mosaic
+    lowers a kernel whose tables are computed values is what the compile
+    itself shows."""
+    from cgnn_tpu.observe import phases
+
+    def made(comp, name):
+        wrote = phases._resolve(comps, comp, name)
+        return wrote.endswith("/squeeze") or (
+            "/attn." in wrote and "jit(_splash_attention)" not in wrote)
+
+    comps = phases._parse(text)
+    seen = 0
+    for comp, body in comps.items():
+        for name, rest in body["instrs"].items():
+            if not (name.startswith("splash_mqa_")
+                    and "custom-call(" in rest):
+                continue
+            operands = re.findall(
+                r"%([\w.\-]+)", re.search(r"custom-call\(([^)]*)\)",
+                                          rest).group(1))
+            data_next, block_mask, blocks = *operands[:2], operands[-1]
+            assert "s8[1," in body["instrs"][block_mask][:8], name
+            assert "s32[" in body["instrs"][blocks][:8], name
+            assert made(comp, data_next) and made(comp, block_mask), name
+            assert not made(comp, blocks), name
+            seen += 1
+    assert seen == calls
+
+
 def _take_the_head_kernels(monkeypatch):
     """``lm_blocks.prepare_heads`` asks the default backend, which is the
     CPU here: the described chip's answer is the shape's alone."""
@@ -830,6 +868,7 @@ def test_sdar_scan_program_at_real_size_fits_the_chip(one_chip,
     # one call site (the layers are scanned): the parent of PR 46, whose
     # checkpoint kept the layer's input alone, read fwd 2, dq 1, dkv 1
     assert splash == {"fwd": 1, "dq": 1, "dkv": 1}, splash
+    _check_the_refined_tables(text, 3)
     _check_the_prepared_heads(text, 1, 2 * length, (
         mc.num_attention_heads, mc.num_key_value_heads), mc.head_dim)
     # the expert layer's switch (ops/moe.py): one conditional forward and
@@ -963,6 +1002,7 @@ def test_trinity_scan_program_at_real_size_fits_the_chip(one_chip,
     # three call sites (the dense stack, the window run, the full run): the
     # parent of PR 46 read fwd 6, dq 3, dkv 3
     assert splash == {"fwd": 3, "dq": 3, "dkv": 3}, splash
+    _check_the_refined_tables(text, 9)
     _check_the_prepared_heads(text, 3, length, (
         mc.num_attention_heads, mc.num_key_value_heads), mc.head_dim)
     pairs = length * mc.num_experts_per_tok
@@ -1062,6 +1102,7 @@ def test_lfm2_scan_program_at_real_size_fits_the_chip(one_chip,
               for k in ("fwd", "dq", "dkv")}
     print(f"splash kernels: {splash}")
     assert splash == {"fwd": 1, "dq": 1, "dkv": 1}, splash
+    _check_the_refined_tables(text, 3)
     # the composition, at 64 lanes: no kernel of ops/prepare_heads.py
     assert _kernel_calls(text, "prepare_heads_") == 0
     pairs = length * mc.num_experts_per_tok
