@@ -71,6 +71,9 @@ OUTPUT_PROJECTIONS = ("wo", "w_down", "mlp_down", "shared_down")
 class AfmoeConfig(lm_blocks.Stack):
     # what train/lm_step.py makes of a batch (no field: the model's own)
     objective = "causal"
+    # both kinds mix by attention and route after the leading dense layer
+    layer_kinds = {SLIDING: (lm_blocks.ATTENTION, True),
+                   FULL: (lm_blocks.ATTENTION, True)}
 
     hidden_size: int = 2048
     num_attention_heads: int = 32
@@ -101,7 +104,7 @@ class AfmoeConfig(lm_blocks.Stack):
     moe_impl: str = "auto"
 
     def __post_init__(self):
-        self.check_stack((SLIDING, FULL))
+        self.check_stack()
 
     @property
     def compute_dtype(self):

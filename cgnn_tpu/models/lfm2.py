@@ -72,6 +72,9 @@ OUTPUT_PROJECTIONS = ("w_out", "wo", "w_down", "mlp_down")
 class Lfm2Config(lm_blocks.Stack):
     # what train/lm_step.py makes of a batch (no field: the model's own)
     objective = "causal"
+    # both kinds route after the leading dense layer
+    layer_kinds = {CONV: (lm_blocks.CONV, True),
+                   FULL: (lm_blocks.ATTENTION, True)}
 
     hidden_size: int = 2048
     num_attention_heads: int = 32
@@ -97,7 +100,7 @@ class Lfm2Config(lm_blocks.Stack):
     moe_impl: str = "auto"
 
     def __post_init__(self):
-        self.check_stack((CONV, FULL))
+        self.check_stack()
 
     @property
     def compute_dtype(self):

@@ -1,15 +1,15 @@
 """What the decoders of this repo share (models/sdar.py, models/afmoe.py,
-models/lfm2.py): the float32 norm and RoPE, the one op that makes a
-projection's output the attention's operand, the initialiser, the plan that
-keeps a layer's input and its attention's output for the reverse pass, the
-stack whose layers differ in kind (``Stack``, ``stack_shapes``,
-``scan_stack``), and the head over the vocabulary slice a chunk at a time.
+models/lfm2.py, models/nemotron_h.py): the float32 norm and RoPE, the one
+op that makes a projection's output the attention's operand, the
+initialiser, the plan that keeps a layer's input and its attention's output
+for the reverse pass, the stack whose layers differ in kind (``Stack``,
+``stack_shapes``, ``scan_stack``), and the head over the vocabulary slice a
+chunk at a time.
 """
 
 from __future__ import annotations
 
 import functools
-import itertools
 import math
 
 import jax
@@ -109,37 +109,65 @@ def by_sequence(layer, x, segment_ids, keep=()):
     return jax.lax.map(one, (x, segment_ids))
 
 
-# the kind of a layer that mixes by ops/short_conv.py; every other kind of
-# ``layer_types`` is an attention under some mask
-CONV = "conv"
+# what a layer mixes by (``Stack.mixer``); None: it is a feed-forward part
+# alone
+ATTENTION, CONV, SSM = "attention", "conv", "ssm"
 
 
 class Stack:
-    """A stack of leading dense layers and then expert layers in periods of
+    """A stack of leading dense layers and then layers in periods of
     ``layer_types``, for a frozen config dataclass with the fields
-    ``layer_types``, ``num_hidden_layers``, ``num_dense_layers`` and
-    ``shapes()``: the dense layers are one stack that is scanned; the
-    periods are scanned, and inside a period each run of layers of one kind
-    is a scan of its own (``periods/run<j>``, each leaf ``[periods, layers
-    of the run, ...]``): a layer of each kind is all the program text there
-    is, and a deeper stage is a longer leading axis."""
+    ``layer_types``, ``num_hidden_layers``, ``num_dense_layers``,
+    ``shapes()`` and ``layer_kinds``: {kind: (what a layer of the kind
+    mixes by, whether it holds routed experts past the leading dense
+    ones)}. Serves models/afmoe.py and models/lfm2.py, whose every layer is
+    a mixer (an attention under some mask, or the short convolution) AND a
+    feed-forward part that routes after the leading dense ones, and
+    models/nemotron_h.py, whose every layer is ONE of the two (a mixer of
+    None: a feed-forward part alone).
 
-    def check_stack(self, kinds: tuple) -> None:
-        """In ``__post_init__``: ``layer_types`` a tuple of ``kinds``."""
+    The dense layers are one stack that is scanned; the periods are scanned,
+    and inside a period each repeated group of kinds (``groups``) is a scan
+    of its own over the repeats, its body one layer of each kind of the
+    group (``periods/run<j>``: a group of one kind holds its leaves ``[periods,
+    layers of the run, ...]``; a group of several a dict of them a kind,
+    ``periods/run<j>/<kind>``): a layer of each kind is all the program text
+    there is (a period ``E M E M E M *`` is ``(E, M) x 3`` and ``*``, three
+    bodies), and a deeper stage is a longer leading axis."""
+
+    # (q and k) the calls of ``prepare_heads`` an attention layer makes
+    heads_prepared_a_layer = 2
+
+    def mixer(self, kind: str):
+        """What a layer of ``kind`` mixes by: ``ATTENTION``, ``CONV``,
+        ``SSM`` or None."""
+        return self.layer_kinds[kind][0]
+
+    def routes(self, kind: str) -> bool:
+        """Whether a layer of ``kind`` past the leading dense ones holds
+        routed experts."""
+        return self.layer_kinds[kind][1]
+
+    def check_stack(self) -> None:
+        """In ``__post_init__``: ``layer_types`` a tuple of the kinds that
+        ``layer_kinds`` names."""
         types = tuple(self.layer_types)
         object.__setattr__(self, "layer_types", types)
-        if len(types) != self.num_hidden_layers or set(types) - set(kinds):
+        if (len(types) != self.num_hidden_layers
+                or set(types) - set(self.layer_kinds)):
             raise ValueError(f"layer_types {types} do not name "
                              f"{self.num_hidden_layers} layers")
         if len(set(types[:self.num_dense_layers])) > 1:
             raise ValueError("the leading dense layers are one scanned "
                              "stack: they have to be of one kind")
-        if not 0 <= self.num_dense_layers < self.num_hidden_layers:
+        if not (0 <= self.num_dense_layers < self.num_hidden_layers
+                and self.n_expert_layers):
             raise ValueError("at least one expert layer")
 
     @property
     def period(self) -> tuple:
-        """The expert layers' kinds, one period of them."""
+        """The kinds of the layers past the dense ones, one period of
+        them."""
         types = self.layer_types[self.num_dense_layers:]
         for n in range(1, len(types) + 1):
             if len(types) % n == 0 and types == types[:n] * (len(types) // n):
@@ -147,28 +175,63 @@ class Stack:
         raise AssertionError
 
     @property
+    def groups(self) -> tuple:
+        """A period as repeated groups of kinds, ((kinds, repeats), ..):
+        from each place on, the group (of distinct kinds) whose repeats
+        cover the most layers, the shorter group of two that cover as many;
+        a group that does not repeat is one kind."""
+        period, out, at = self.period, [], 0
+        while at < len(period):
+            best = (period[at:at + 1], 1)
+            for size in range(1, (len(period) - at) // 2 + 1):
+                group = period[at:at + size]
+                if len(set(group)) < size:
+                    continue
+                repeats = 1
+                while period[at + repeats * size:
+                             at + (repeats + 1) * size] == group:
+                    repeats += 1
+                if repeats > 1 and size * repeats > len(best[0]) * best[1]:
+                    best = (group, repeats)
+            out.append(best)
+            at += len(best[0]) * best[1]
+        return tuple(out)
+
+    @property
     def runs(self) -> tuple:
-        """A period as runs of layers of one kind: ((kind, layers), ..)."""
-        return tuple((kind, len(list(run)))
-                     for kind, run in itertools.groupby(self.period))
+        """``groups``, a group of one kind under the kind's own name: a
+        period of runs of layers of one kind is ((kind, layers), ..)."""
+        return tuple((group[0] if len(group) == 1 else group, n)
+                     for group, n in self.groups)
 
     @property
     def n_periods(self) -> int:
-        return self.n_expert_layers // len(self.period)
+        return ((self.num_hidden_layers - self.num_dense_layers)
+                // len(self.period))
+
+    def _count(self, mixer) -> int:
+        return sum(self.mixer(kind) == mixer for kind in self.layer_types)
 
     @property
     def n_expert_layers(self) -> int:
-        return self.num_hidden_layers - self.num_dense_layers
+        """Layers that hold routed experts."""
+        return sum(self.routes(kind)
+                   for kind in self.layer_types[self.num_dense_layers:])
 
     @property
     def n_conv_layers(self) -> int:
-        """Layers whose mixer is the short convolution (``CONV``)."""
-        return self.layer_types.count(CONV)
+        """Layers whose mixer is the short convolution."""
+        return self._count(CONV)
 
     @property
     def n_attention_layers(self) -> int:
         """Layers whose mixer is an attention of any mask."""
-        return self.num_hidden_layers - self.n_conv_layers
+        return self._count(ATTENTION)
+
+    @property
+    def n_ssm_layers(self) -> int:
+        """Layers whose mixer is the state-space scan (ops/ssd.py)."""
+        return self._count(SSM)
 
     def n_params(self) -> int:
         return sum(math.prod(s) for s in jax.tree_util.tree_leaves(
@@ -177,14 +240,20 @@ class Stack:
 
 def stack_shapes(cfg: Stack, dense_layer, expert_layer) -> dict:
     """The stack's part of a parameter tree's shapes: ``dense_layer(kind)``
-    and ``expert_layer(kind)`` give one layer's ({leaf: shape}) ->
+    (a leading dense layer, or a layer of a kind that does not route) and
+    ``expert_layer(kind)`` give one layer's ({leaf: shape}) ->
     {``periods``: {``run<j>``: ..}, ``dense``: .. where there is one}."""
     def stacked(layer: dict, *leading) -> dict:
         return {k: (*leading, *v) for k, v in layer.items()}
 
+    def of(kind: str, n: int) -> dict:
+        layer = expert_layer if cfg.routes(kind) else dense_layer
+        return stacked(layer(kind), cfg.n_periods, n)
+
     tree = {"periods": {
-        f"run{j}": stacked(expert_layer(kind), cfg.n_periods, n)
-        for j, (kind, n) in enumerate(cfg.runs)}}
+        f"run{j}": (of(group[0], n) if len(group) == 1
+                    else {kind: of(kind, n) for kind in group})
+        for j, (group, n) in enumerate(cfg.groups)}}
     if cfg.num_dense_layers:
         tree["dense"] = stacked(dense_layer(cfg.layer_types[0]),
                                 cfg.num_dense_layers)
@@ -194,10 +263,11 @@ def stack_shapes(cfg: Stack, dense_layer, expert_layer) -> dict:
 def scan_stack(cfg: Stack, x, params, router_bias, dense_layer,
                expert_layer):
     """``x [S, L, H]`` through the stack (``Stack``): ``dense_layer(kind,
-    x, p) -> x`` and ``expert_layer(kind, x, p, bias [E]) -> (x,
-    group_sizes [S, E], rungs [S])`` over all the step's sequences (each
-    calls ``by_sequence`` with what its checkpoint keeps); ``params`` the
-    tree of ``stack_shapes``, ``router_bias [periods, layers a period, E]``.
+    x, p) -> x`` (a leading dense layer, or a layer of a kind that does not
+    route) and ``expert_layer(kind, x, p, bias [E]) -> (x, group_sizes [S,
+    E], rungs [S])`` over all the step's sequences (each calls
+    ``by_sequence`` with what its checkpoint keeps); ``params`` the tree of
+    ``stack_shapes``, ``router_bias [periods, routing layers a period, E]``.
     -> (``x``, ``group_sizes [expert layers, E]``, ``rungs [expert layers,
     S]``: the rung of each expert layer's and sequence's call,
     ops/moe.py)."""
@@ -207,20 +277,45 @@ def scan_stack(cfg: Stack, x, params, router_bias, dense_layer,
         p = jax.lax.optimization_barrier(p)
         return dense_layer(cfg.layer_types[0], x, p), None
 
-    def expert_step(kind, x, layer):
-        p, bias = jax.lax.optimization_barrier(layer)
-        x, sizes, rungs = expert_layer(kind, x, p, bias)
-        return x, (sizes.sum(axis=0), rungs)
+    def group_step(group, x, layers):
+        """One layer of each kind of ``group`` (a routing kind's with its
+        bias); what the routing kinds counted, a kind each."""
+        routed = []
+        for kind, layer in zip(group, layers):
+            if not cfg.routes(kind):
+                x = dense_layer(kind, x, jax.lax.optimization_barrier(layer))
+                continue
+            p, bias = jax.lax.optimization_barrier(layer)
+            x, sizes, rungs = expert_layer(kind, x, p, bias)
+            routed.append((sizes.sum(axis=0), rungs))
+        return x, tuple(routed)
+
+    def in_stack_order(parts):
+        """``[repeats, ..]`` a routing kind of a group -> ``[repeats x
+        kinds, ..]``, the layers in the stack's order."""
+        if len(parts) == 1:  # as it is: no op enters Trinity's or LFM2's text
+            return parts[0]
+        return jnp.stack(parts, axis=1).reshape(
+            len(parts) * parts[0].shape[0], -1)
 
     def period_step(x, period):
         p, bias = period
         routed, at = [], 0
-        for j, (kind, n) in enumerate(cfg.runs):
-            x, of_run = jax.lax.scan(
-                functools.partial(expert_step, kind), x,
-                (p[f"run{j}"], bias[at:at + n]))
-            routed.append(of_run)
-            at += n
+        for j, (group, n) in enumerate(cfg.groups):
+            # a group of one kind holds its leaves without the kind's name
+            leaves = p[f"run{j}"]
+            if len(group) == 1:
+                leaves = {group[0]: leaves}
+            routing = [kind for kind in group if cfg.routes(kind)]
+            r = len(routing)
+            layers = tuple(
+                (leaves[kind], bias[at + routing.index(kind):at + n * r:r])
+                if kind in routing else leaves[kind] for kind in group)
+            at += n * r
+            x, of_kinds = jax.lax.scan(
+                functools.partial(group_step, group), x, layers)
+            if routing:
+                routed.append(tuple(map(in_stack_order, zip(*of_kinds))))
         return x, tuple(jnp.concatenate(parts) for parts in zip(*routed))
 
     # the loops' own machinery (a layer's input and what its checkpoint

@@ -70,8 +70,10 @@ class SdarConfig:
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
 
-    # every layer mixes by attention (what train/lm_step.py counts by)
-    n_conv_layers = 0
+    # every layer mixes by attention and prepares q and k (what
+    # train/lm_step.py counts by)
+    n_conv_layers = n_ssm_layers = 0
+    heads_prepared_a_layer = 2
 
     @property
     def n_attention_layers(self) -> int:

@@ -7,8 +7,8 @@ stack — flax module scopes (``conv_1/bn1``), transforms (``jvp``,
 ``transpose``) and the ``jax.named_scope`` s the program adds where flax says
 nothing (models/cgcnn.py, models/forcefield.py, train/step.py,
 train/force_step.py, resilience/guard.py, data/compact.py, train/loop.py,
-models/sdar.py, models/afmoe.py, models/lfm2.py, models/lm_blocks.py,
-ops/moe.py, ops/prepare_heads.py, train/lm_step.py). ``classify`` maps such a path to one phase
+models/sdar.py, models/afmoe.py, models/lfm2.py, models/nemotron_h.py,
+models/lm_blocks.py, ops/moe.py, ops/prepare_heads.py, train/lm_step.py). ``classify`` maps such a path to one phase
 of ``PHASES`` and a direction; ``phase_table`` does so for every instruction
 of an optimized HLO module that the device can report as an event.
 
@@ -75,13 +75,26 @@ MOE_SHARED = "moe.shared"
 # too) and ``moe.expert``
 SCONV_PROJ = "sconv.proj"
 SCONV_MIX = "sconv.mix"
+# models/nemotron_h.py: the hybrid decoder's Mamba-2 layers. ``ssm.proj`` is
+# the layer's norm, ``W_in`` and ``W_out``; ``ssm.conv`` the four taps, their
+# bias and the ``silu`` (ops/short_conv.py); ``ssm.scan`` the steps ``dt``,
+# the decays, the chunks' products, the recurrence over the chunks' states
+# and the skip ``D`` (ops/ssd.py); ``ssm.gate`` the gate and the grouped
+# norm. Its attention layers are under ``attn.proj`` / ``attn.full``, its
+# expert layers under ``moe.route`` (the layer's norm and the residual sum
+# too), ``moe.expert`` and ``moe.shared``
+SSM_PROJ = "ssm.proj"
+SSM_CONV = "ssm.conv"
+SSM_SCAN = "ssm.scan"
+SSM_GATE = "ssm.gate"
 OTHER = "other"
 
 PHASES = (EXPAND, EMBED, EDGE_GEOM, CONV_GATHER, CONV_FC_FULL, CONV_BN1,
           CONV_GATE, CONV_AGGREGATE, CONV_BN2, CONV_LN, POOL_HEAD,
           FORCE_READOUT, LOSS, OPTIMIZER, SCAN, DP_ALLREDUCE, LM_EMBED,
           ATTN_PROJ, ATTN_BD, MOE_ROUTE, MOE_EXPERT, LM_HEAD, ATTN_WINDOW,
-          ATTN_FULL, MLP_DENSE, MOE_SHARED, SCONV_PROJ, SCONV_MIX, OTHER)
+          ATTN_FULL, MLP_DENSE, MOE_SHARED, SCONV_PROJ, SCONV_MIX, SSM_PROJ,
+          SSM_CONV, SSM_SCAN, SSM_GATE, OTHER)
 FWD, BWD, BWD2 = "fwd", "bwd", "bwd2"
 
 # a path component -> its phase: the named scopes themselves, and the flax

@@ -4,7 +4,10 @@ The layer is told which experts it holds (``first``, ``count``). It routes
 every token over ALL ``n_experts`` (``route``: softmax over the router's
 logits, the ``k`` largest kept and renormalised to sum 1; or a ``Router``'s
 other form: sigmoid scores, a bias that selects, a scale), computes the
-experts it holds on the rows routed to them, and adds nothing for the absent
+experts it holds on the rows routed to them (``EXPERT_FORMS``: an expert is
+``(silu(h W_g) * (h W_u)) W_d``, SwiGLU, which models/sdar.py, models/afmoe.py
+and models/lfm2.py have, or ``relu(h W_up)^2 W_down``, two matrices and no
+gate, which models/nemotron_h.py has), and adds nothing for the absent
 ones: what comes out is this chip's part of the layer's result. On one chip
 there is no exchange, and nothing here stands in for one.
 
@@ -172,6 +175,45 @@ def _swiglu_bwd(gate_up, d):
 swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)
 
 
+@jax.custom_vjp
+def relu2(up):
+    """``relu(u)^2``, computed in float32 and returned in the input's dtype;
+    as ``swiglu``, the reverse pass keeps the input alone."""
+    return _relu2(up)
+
+
+def _relu2(up):
+    r = jnp.maximum(up.astype(jnp.float32), 0.0)
+    return (r * r).astype(up.dtype)
+
+
+def _relu2_bwd(up, d):
+    r = jnp.maximum(up.astype(jnp.float32), 0.0)
+    return ((2.0 * r * d.astype(jnp.float32)).astype(up.dtype),)
+
+
+relu2.defvjp(lambda up: (_relu2(up), up), _relu2_bwd)
+
+# the forms of an expert's body: the activation between its two grouped
+# matmuls, over ``[W_g | W_u]``'s output (``swiglu``) or ``W_up``'s
+EXPERT_FORMS = {"swiglu": swiglu, "relu2": relu2}
+# the lanes of a tile: what the grouped matmul's kernels cut a width into
+LANES = 128
+
+
+def lane_aligned(w_up, w_down):
+    """``w_up [count, H, I]`` and ``w_down [count, I, H]`` of ``relu2``
+    experts with ``I`` padded by zero columns and rows to whole tiles of
+    ``LANES``: exact (``relu(0)^2 = 0``, and a zero row adds 0), and what
+    megablox needs (its reverse kernel takes no block of 1,856 lanes: 14.5
+    tiles). A width of whole tiles comes back as it is."""
+    pad = -w_up.shape[-1] % LANES
+    if not pad:
+        return w_up, w_down
+    return (jnp.pad(w_up, ((0, 0), (0, 0), (0, pad))),
+            jnp.pad(w_down, ((0, 0), (0, pad), (0, 0))))
+
+
 def _tile_of(n: int) -> int:
     for t in (1024, 768, 512, 256, 128):
         if n % t == 0:
@@ -201,8 +243,8 @@ def grouped_matmul(lhs, rhs, group_sizes, *, impl: str):
     raise ValueError(f"no grouped matmul {impl!r}")
 
 
-def _held_rows(capacity: int, held: tuple, k: int, impl: str,
-               x, w_flat, w_gate_up, w_down, counts, order, group_sizes):
+def _held_rows(capacity: int, held: tuple, k: int, impl: str, form: str,
+               x, w_flat, w_in, w_down, counts, order, group_sizes):
     """The held experts on their rows, carried in ``capacity`` rows: the
     one body of every rung. Right where the held rows are no more than
     ``capacity`` (``_held_experts`` picks the rung so; at ``T x k`` they
@@ -223,8 +265,8 @@ def _held_rows(capacity: int, held: tuple, k: int, impl: str,
     with jax.named_scope(phases.MOE_EXPERT):
         # rows that are not held hold whatever the kernel's buffer held
         gu = jnp.where(valid[:, None], grouped_matmul(
-            rows, w_gate_up, sizes, impl=impl), 0)
-        y = grouped_matmul(swiglu(gu), w_down, sizes, impl=impl)
+            rows, w_in, sizes, impl=impl), 0)
+        y = grouped_matmul(EXPERT_FORMS[form](gu), w_down, sizes, impl=impl)
     with jax.named_scope(phases.MOE_ROUTE):
         # the weights join the rows in the compute dtype; the sum over a
         # token's rows accumulates in float32 (combine)
@@ -240,30 +282,32 @@ def _rung(rungs: tuple, held: tuple, group_sizes):
     return (n_here > jnp.asarray(rungs[:-1], jnp.int32)).sum(dtype=jnp.int32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
-def _held_experts(rungs: tuple, held: tuple, k: int, impl: str,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
+def _held_experts(rungs: tuple, held: tuple, k: int, impl: str, form: str,
                   floats: tuple, ints: tuple):
     """``_held_rows`` in the first of ``rungs`` that holds the held rows,
     picked on the device -> (its output, the rung's index). ``floats`` are
-    ``(x, w_flat, w_gate_up, w_down)``, ``ints`` ``(counts, order,
+    ``(x, w_flat, w_in, w_down)``, ``ints`` ``(counts, order,
     group_sizes)``. The reverse pass is a switch of its own over ``jax.vjp``
     of the same bodies, from the operands alone: differentiated through, a
     ``lax.switch`` keeps the union of its branches' residuals and each branch
     writes zeros for the others' (the full rung's are ``T x k`` rows long)."""
     index = _rung(rungs, held, ints[-1])
-    bodies = [functools.partial(_held_rows, r, held, k, impl) for r in rungs]
+    bodies = [functools.partial(_held_rows, r, held, k, impl, form)
+              for r in rungs]
     return jax.lax.switch(index, bodies, *floats, *ints), index
 
 
-def _held_experts_fwd(rungs, held, k, impl, floats, ints):
-    return _held_experts(rungs, held, k, impl, floats, ints), (floats, ints)
+def _held_experts_fwd(rungs, held, k, impl, form, floats, ints):
+    return (_held_experts(rungs, held, k, impl, form, floats, ints),
+            (floats, ints))
 
 
-def _held_experts_bwd(rungs, held, k, impl, res, cts):
+def _held_experts_bwd(rungs, held, k, impl, form, res, cts):
     floats, ints = res
 
     def pull(capacity, g, *floats):
-        body = functools.partial(_held_rows, capacity, held, k, impl)
+        body = functools.partial(_held_rows, capacity, held, k, impl, form)
         return jax.vjp(lambda *f: body(*f, *ints), *floats)[1](g)
 
     grads = jax.lax.switch(
@@ -275,17 +319,20 @@ def _held_experts_bwd(rungs, held, k, impl, res, cts):
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
-def expert_share(x, router, w_gate_up, w_down, *, experts_held: tuple,
+def expert_share(x, router, w_in, w_down, *, experts_held: tuple,
                  k: int, impl: str = "auto", capacity: int | None = None,
-                 routing: Router = Router(), bias=None):
+                 routing: Router = Router(), bias=None,
+                 form: str = "swiglu"):
     """``x [T, H]`` (normed hidden states) -> (this share's part of the
     layer's output ``[T, H]`` in ``x``'s dtype, each token's sum accumulated
     in float32; ``group_sizes [E]`` int32: the rows each of ALL experts was
     routed; the index of the rung that carried the held rows).
 
-    ``router [H, E]`` float32; ``w_gate_up [count, H, 2I]`` and ``w_down
-    [count, I, H]`` in the compute dtype, the experts ``first .. first +
-    count - 1``: ``e(h) = (silu(h W_g) * (h W_u)) W_d``. ``capacity`` puts
+    ``router [H, E]`` float32; ``w_in`` and ``w_down [count, I, H]`` in the
+    compute dtype, the experts ``first .. first + count - 1``, by ``form``
+    (``EXPERT_FORMS``): ``swiglu``, ``w_in [count, H, 2I] = [W_g | W_u]``
+    and ``e(h) = (silu(h W_g) * (h W_u)) W_d``; ``relu2``, ``w_in [count,
+    H, I] = W_up`` and ``e(h) = relu(h W_up)^2 W_down``. ``capacity`` puts
     another compact rung under ``T x k`` in the ladder's place (tests, at
     sizes whose ladder is the one rung). ``routing`` and ``bias [E]``:
     ``route``'s.
@@ -308,7 +355,7 @@ def expert_share(x, router, w_gate_up, w_down, *, experts_held: tuple,
         counts = ((experts >= first) & (experts < first + count)).sum(
             axis=1, dtype=jnp.int32)
         out, rung = _held_experts(
-            rungs, (first, count), k, impl,
-            (x, weights.reshape(-1), w_gate_up, w_down),
+            rungs, (first, count), k, impl, form,
+            (x, weights.reshape(-1), w_in, w_down),
             (counts, order, group_sizes))
     return out, group_sizes, rung

@@ -1,16 +1,18 @@
 """``train.py --task blockdiff`` and ``--task lm``: the decoders
 (models/sdar.py, block diffusion; models/afmoe.py and models/lfm2.py,
-next-token) through ``fit`` and the scan epoch driver, as every task goes:
+next-token; models/nemotron_h.py, next-token) through ``fit`` and the scan epoch driver, as every task goes:
 token batches staged resident, ``TrainState`` and ``make_optimizer``, spans
 and phases.
 
 The model is a preset of its task (``PRESETS``, which names the preset's
-model too: ``tiny`` and ``lfm2-tiny`` for the CPU; ``sdar-ep8``, one chip's
+model too: ``tiny``, ``lfm2-tiny`` and ``nemotron-tiny`` for the CPU; ``sdar-ep8``, one chip's
 share of SDAR-30B-A3B-Chat as ``benchmark/configs/sdar-30b-a3b-ep8.json`` has
 it; ``trinity-mini-ep16``, one chip's share of Trinity-Mini as
 ``benchmark/configs/trinity-mini-ep16.json`` has it; ``lfm2-24b-a2b-ep8``,
 one chip's share of LFM2-24B-A2B as ``benchmark/configs/
-lfm2-24b-a2b-ep8.json`` has it) or a JSON file of the fields of the config
+lfm2-24b-a2b-ep8.json`` has it; ``nemotron-3-nano-30b-a3b-ep16``, one chip's
+share of NVIDIA-Nemotron-3-Nano-30B-A3B as ``benchmark/configs/
+nemotron-3-nano-30b-a3b-ep16.json`` has it) or a JSON file of the fields of the config
 dataclass of the task's first model. The data is a synthetic pool of packed
 sequences (data/tokens.py): a tokenizer and a corpus reader are not part of
 this repo.
@@ -24,7 +26,7 @@ import json
 
 # a model's module under cgnn_tpu.models -> its config dataclass
 CONFIGS = {"sdar": "SdarConfig", "afmoe": "AfmoeConfig",
-           "lfm2": "Lfm2Config"}
+           "lfm2": "Lfm2Config", "nemotron_h": "NemotronHConfig"}
 # task -> preset -> (the model, the fields of its config dataclass that
 # differ from its defaults); a task's first preset names the model a JSON
 # file of fields is read as
@@ -56,6 +58,16 @@ PRESETS = {
             num_experts_per_tok=4, experts_held=(0, 4), vocab_size=256,
             dtype="float32")),
         "lfm2-24b-a2b-ep8": ("lfm2", dict()),  # the dataclass's defaults
+        "nemotron-tiny": ("nemotron_h", dict(
+            hidden_size=64, num_attention_heads=4, num_key_value_heads=2,
+            head_dim=16, num_hidden_layers=5,
+            hybrid_override_pattern="EMEM*", mamba_num_heads=8,
+            mamba_head_dim=8, n_groups=2, ssm_state_size=16, chunk_size=8,
+            moe_intermediate_size=24, moe_shared_expert_intermediate_size=48,
+            n_experts=16, num_experts_per_tok=4, experts_held=(0, 4),
+            vocab_size=256, dtype="float32")),
+        # the dataclass's defaults
+        "nemotron-3-nano-30b-a3b-ep16": ("nemotron_h", dict()),
     },
 }
 

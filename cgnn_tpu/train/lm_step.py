@@ -1,16 +1,17 @@
 """The language-model steps (models/sdar.py, models/afmoe.py,
-models/lfm2.py), with the same carry as every task's: ``(state, batch) ->
-(state, metrics)``, metrics as sums so that an epoch's are exact.
+models/lfm2.py, models/nemotron_h.py), with the same carry as every task's:
+``(state, batch) -> (state, metrics)``, metrics as sums so that an epoch's
+are exact.
 
 The causal loss (models/afmoe.py) is next-token cross-entropy, ``-(1 / L)
 sum_i w_i log p(x^{i+1} | x^{<=i})`` a sequence with ``w_i`` 0 where the next
 token is another document's or there is none; after the optimizer's step the
 routers' selection biases move by the step's counts (``balanced_biases``):
 state that no gradient reaches, carried in ``TrainState.batch_stats`` as the
-conv models carry BatchNorm's statistics (models/lfm2.py's biases are
-fixed: its config has no ``load_balance_coeff`` and its step returns them as
-they were). The block-diffusion loss is
-BD3-LM's, read at the noised half only:
+conv models carry BatchNorm's statistics (models/lfm2.py's and
+models/nemotron_h.py's biases are fixed: their configs have no
+``load_balance_coeff`` and their steps return them as they were). The
+block-diffusion loss is BD3-LM's, read at the noised half only:
 
     -(1 / L) sum_i 1[x_t^i = MASK] (1 / t_b(i)) log p(x_0^i | x_t, x_0)
 
@@ -33,6 +34,7 @@ from cgnn_tpu.observe import phases
 from cgnn_tpu.ops import moe
 from cgnn_tpu.ops.masked_attention import kept_bytes
 from cgnn_tpu.ops.short_conv import taps_cut
+from cgnn_tpu.ops.ssd import ssd_counts
 from cgnn_tpu.train.state import TrainState
 
 
@@ -135,11 +137,15 @@ def step_metrics(cfg, batch: TokenBatch, loss_sum, routed, tiles,
     the bytes its layers' checkpoints keep of the attention
     (``lm_blocks.by_sequence``) and the (sequence, layer, operand) calls of
     ``lm_blocks.prepare_heads``, all and those that took the kernel, from
-    the shapes, over the layers that are attention
-    (``cfg.n_attention_layers``); a model with convolution layers
-    (``cfg.n_conv_layers``) counts their positions and, of these positions'
-    two earlier taps each, the taps a document's or the sequence's start
-    cut (``ops/short_conv.py``)."""
+    the shapes, over the layers that are attention by kind
+    (``cfg.n_attention_layers``; a model whose attention layers prepare no
+    heads says so, ``heads_prepared_a_layer``); a model with convolution
+    layers (``cfg.n_conv_layers``) counts their positions and, of these
+    positions' two earlier taps each, the taps a document's or the
+    sequence's start cut (``ops/short_conv.py``); one with state-space
+    layers (``cfg.n_ssm_layers``) their positions, the document starts at
+    which a state and a filter start empty, the scan's chunks and those a
+    document's start falls inside (``ops/ssd.py`` ``ssd_counts``)."""
     with jax.named_scope(phases.LM_HEAD):
         s, n = batch.tokens.shape
         causal = cfg.objective == "causal"
@@ -158,7 +164,8 @@ def step_metrics(cfg, batch: TokenBatch, loss_sum, routed, tiles,
         metrics["attn_kept_bytes_sum"] = jnp.float32(
             attn_layers * s * kept_bytes(
                 cfg.num_attention_heads, n, cfg.head_dim, cfg.compute_dtype))
-        prepared = 2 * attn_layers * s  # q and k
+        # q and k, where the model's attention prepares its heads
+        prepared = cfg.heads_prepared_a_layer * attn_layers * s
         metrics["heads_prepared_sum"] = jnp.float32(prepared)
         metrics["heads_prepared_fused_sum"] = jnp.float32(
             prepared * lm_blocks.heads_fused(n, cfg.head_dim))
@@ -167,6 +174,13 @@ def step_metrics(cfg, batch: TokenBatch, loss_sum, routed, tiles,
                 cfg.n_conv_layers * s * n)
             metrics["sconv_taps_cut_sum"] = cfg.n_conv_layers * taps_cut(
                 batch.segment_ids).astype(jnp.float32)
+        if cfg.n_ssm_layers:
+            met = ssd_counts(batch.segment_ids, cfg.chunk_size)
+            metrics["ssm_positions_sum"] = jnp.float32(
+                cfg.n_ssm_layers * s * n)
+            for name in ("resets", "chunks", "chunks_cut"):
+                metrics[f"ssm_{name}_sum"] = cfg.n_ssm_layers * met[
+                    name].astype(jnp.float32)
         if tiles is not None:
             for kind, (live, layers) in cfg.live_tiles(
                     batch.segment_ids).items():

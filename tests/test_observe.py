@@ -598,6 +598,27 @@ _CLASSIFIED = [
      "mul", ("sconv.mix", "fwd")),
     (_LM + "transpose(jvp(while))/body/while/body/while/body/checkpoint/"
      "sconv.mix/select_n", ("sconv.mix", "bwd")),
+    # models/nemotron_h.py: the Mamba-2 layers' projections, filter, scan
+    # (its two passes a block of chunks at a time are loops of their own)
+    # and gate
+    (_LM + "jvp(while)/body/while/body/while/body/checkpoint/ssm.proj/"
+     "dot_general", ("ssm.proj", "fwd")),
+    (_LM + "transpose(jvp(while))/body/while/body/while/body/checkpoint/"
+     "rematted_computation/ssm.proj/dot_general", ("ssm.proj", "bwd")),
+    (_LM + "jvp(while)/body/while/body/while/body/checkpoint/ssm.conv/"
+     "jit(silu)/mul", ("ssm.conv", "fwd")),
+    (_LM + "transpose(jvp(while))/body/while/body/while/body/checkpoint/"
+     "ssm.conv/select_n", ("ssm.conv", "bwd")),
+    (_LM + "jvp(while)/body/while/body/while/body/checkpoint/ssm.scan/"
+     "while/body/checkpoint/zchij,zcjhp->zcihp/dot_general",
+     ("ssm.scan", "fwd")),
+    (_LM + "transpose(jvp(while))/body/while/body/while/body/checkpoint/"
+     "ssm.scan/while/body/checkpoint/rematted_computation/exp",
+     ("ssm.scan", "bwd")),
+    (_LM + "jvp(while)/body/while/body/while/body/checkpoint/ssm.gate/"
+     "rsqrt", ("ssm.gate", "fwd")),
+    (_LM + "transpose(jvp(while))/body/while/body/while/body/checkpoint/"
+     "ssm.gate/mul", ("ssm.gate", "bwd")),
     ("jit(train_step)/add", ("other", "fwd")),
     (_SCAN + "mul", ("other", "fwd")),
     ("reduce_sum", ("other", "fwd")),
@@ -623,7 +644,8 @@ class TestPhases:
                    "pool_head", "loss", "edge_geom", "force_readout",
                    "lm.embed", "attn.proj", "attn.bd", "moe.route",
                    "moe.expert", "lm.head", "attn.window", "attn.full",
-                   "mlp.dense", "moe.shared", "sconv.proj", "sconv.mix"}
+                   "mlp.dense", "moe.shared", "sconv.proj", "sconv.mix",
+                   "ssm.proj", "ssm.conv", "ssm.scan", "ssm.gate"}
         assert {p for p, d in seen if d == "bwd"} == two_way
         # what the force step differentiates twice: the trunk without
         # BatchNorm, the geometry and the readout (not the embedding, which

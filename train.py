@@ -43,14 +43,16 @@ def build_parser() -> argparse.ArgumentParser:
                         "'blockdiff' the block-diffusion mixture-of-experts "
                         "decoder on packed token sequences, 'lm' a "
                         "mixture-of-experts decoder on next-token "
-                        "prediction, window-and-full-attention or hybrid "
-                        "short-convolution by --lm-model (all "
+                        "prediction, window-and-full-attention, hybrid "
+                        "short-convolution or hybrid Mamba-2 by --lm-model (all "
                         "cgnn_tpu/train/blockdiff.py)")
     p.add_argument("--lm-model", default="tiny",
                    help="the task's preset, which names its model too "
                         "(--task blockdiff: tiny | sdar-ep8; --task lm: "
                         "tiny | trinity-mini-ep16 | lfm2-tiny | "
-                        "lfm2-24b-a2b-ep8) or a JSON file of the fields of "
+                        "lfm2-24b-a2b-ep8 | nemotron-tiny | "
+                        "nemotron-3-nano-30b-a3b-ep16) or a JSON file of the "
+                        "fields of "
                         "the task's config dataclass "
                         "(models.sdar.SdarConfig; models.afmoe.AfmoeConfig)")
     p.add_argument("--lm-seq-len", type=int, default=64,
